@@ -1,0 +1,771 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — proves that the training main path still starts on the TPU.
+
+    python chip_smoke.py             # one chip: train, kernels, host plane
+    python chip_smoke.py --chips 4   # four chips: mesh + ring attention only
+
+The script drives the entry points a user calls (``hvd.init``, the mesh
+helpers, ``make_train_step``, the launcher) at the published widths of the
+repo's own benchmark rows, with random weights from ``--seed``, and checks
+what comes out by the repo's own means. It fails — no result line, exit
+code other than 0 — when JAX finds no TPU, when a phase fails, or when a
+Pallas kernel that was expected is not in the compiled program
+(``tpu_custom_call`` is read from the program text: "kernel expected,
+reference ran" is a failed assertion, not a fallback).
+
+A chip belongs to one process at a time. This parent never imports jax:
+each phase that needs the chip runs in a child of its own, one after the
+other, and the device line is taken from the child that ran on it. The
+last line of stdout on success is exactly
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+Step times, compile seconds and peak bytes on the earlier lines are
+information only; they are written nowhere under the name of a metric.
+"""
+
+import argparse
+import json
+import math
+import os
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+
+try:
+    from horovod_tpu.run.util import use_compile_cache
+except ImportError as e:
+    sys.exit("chip_smoke: the horovod_tpu package is not beside this "
+             "script (%s)" % e)
+
+# Sizes of the one-chip phases: the `_ZOO` rows of bench.py. A rehearsal
+# on the CPU overrides these from a scratch script; the program has no
+# option for it.
+SIZES = dict(
+    resnet_batch=256, image=224, classes=1000, train_steps=6,
+    lm=dict(vocab_size=32000, num_layers=12, num_heads=12, embed_dim=768,
+            mlp_dim=3072, max_seq_len=8192),
+    lm_batch=8, lm_len=1024, lm_steps=4,
+    # (B, H, G, L, D, fused rotary): the L=1024 LM row's attention and the
+    # long-context h6/gqa2/frope row's.
+    attn=[(8, 12, 12, 1024, 64, False), (2, 6, 2, 8192, 128, True)],
+    # (M, C) of the largest and the smallest BatchNorm of ResNet-50 at
+    # batch 256.
+    bn=[(256 * 112 * 112, 64), (256 * 7 * 7, 2048)],
+    # four chips
+    resnet_batch_4=64, ring_len=8192, ring_batch=2, ring_heads=6,
+)
+
+# bf16 agreement between two programs that do the same arithmetic in a
+# different order; f32 tolerances are the interpret-mode tests' own
+# (tests/test_ops.py, tests/test_batch_norm.py).
+TOL = dict(attn_bf16=2e-2, bn_out=2e-4, bn_grad=2e-3, loss_rel=1e-3,
+           checksum_rel=1e-6, update_cosine=0.99, grad_rel_l2=2e-2,
+           host=1e-4)
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+def check(cond, what):
+    """A failed check fails the phase; nothing downgrades it."""
+    print("  %s %s" % ("ok  " if cond else "FAIL", what), flush=True)
+    if not cond:
+        raise PhaseFailed(what)
+
+
+# --------------------------------------------------------------------------
+# Children: everything below imports jax and runs on the chip.
+# --------------------------------------------------------------------------
+
+def tpu_devices(expect=None):
+    """jax.devices(), or a failure: this script never runs on the CPU."""
+    import jax
+
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        raise SystemExit(
+            "chip_smoke: JAX found no TPU (jax.devices() -> %d x %s); "
+            "this script does not fall back to the CPU"
+            % (len(devs), devs[0].platform))
+    if expect is not None and len(devs) != expect:
+        raise SystemExit("chip_smoke: expected %d chips, JAX reports %d"
+                         % (expect, len(devs)))
+    print("DEVICE " + json.dumps({"platform": devs[0].platform,
+                                  "kind": devs[0].device_kind,
+                                  "count": len(devs)}), flush=True)
+    return devs
+
+
+def on_tpu(tree):
+    import jax
+
+    return all(d.platform == "tpu"
+               for x in jax.tree_util.tree_leaves(tree)
+               for d in x.devices())
+
+
+def kernel_calls(text):
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def host_leaves(tree):
+    """The tree's leaves as float32 numpy arrays."""
+    import jax
+    import numpy as np
+
+    return [np.asarray(x, np.float32)
+            for x in jax.tree_util.tree_leaves(tree)]
+
+
+def rel_err(a, b):
+    """max |a-b| over max |b|, in float32 on the host."""
+    import numpy as np
+
+    a = np.asarray(a, np.float32)
+    b = np.asarray(b, np.float32)
+    return float(np.max(np.abs(a - b)) / max(1e-30, np.max(np.abs(b))))
+
+
+def resnet_step(model_cls, mesh, per_chip_batch, seed, donate=True,
+                **model_kw):
+    """make_train_step on a ResNet as bench.py builds it: bf16, SGD with
+    momentum, synthetic ImageNet-shaped batch from a seed. Returns the
+    step and its (params, opt_state, batch), not yet placed."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from horovod_tpu.parallel import make_train_step
+    from horovod_tpu.parallel.train import cross_entropy_loss
+
+    s = SIZES["image"]
+    model = model_cls(num_classes=SIZES["classes"], dtype=jnp.bfloat16,
+                      **model_kw)
+    rng = jax.random.PRNGKey(seed)
+    variables = jax.jit(lambda r: model.init(
+        r, jnp.zeros((1, s, s, 3)), train=False))(rng)
+    params, batch_stats = variables["params"], variables["batch_stats"]
+
+    def loss_fn(params, batch):
+        logits, _ = model.apply(
+            {"params": params, "batch_stats": batch_stats}, batch["x"],
+            train=True, mutable=["batch_stats"])
+        return cross_entropy_loss(logits, batch["y"])
+
+    opt = optax.sgd(0.01, momentum=0.9)
+    step = make_train_step(loss_fn, opt, mesh, donate=donate)
+    n = per_chip_batch * mesh.size
+    kx, ky = jax.random.split(jax.random.PRNGKey(seed + 1))
+    batch = {"x": jax.random.normal(kx, (n, s, s, 3), jnp.float32),
+             "y": jax.random.randint(ky, (n,), 0, SIZES["classes"])}
+    return step, (params, opt.init(params), batch)
+
+
+def run_steps(step, params, opt_state, batch, n):
+    """n steps, each ending in block_until_ready; returns the losses and
+    the seconds of each step (the first includes compilation)."""
+    import jax
+
+    losses, secs = [], []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        params, opt_state, loss = step(params, opt_state, batch)
+        jax.block_until_ready((params, loss))
+        secs.append(time.perf_counter() - t0)
+        losses.append(float(loss))
+    return params, opt_state, losses, secs
+
+
+def check_losses(losses):
+    print("  losses: " + " ".join("%.4f" % v for v in losses), flush=True)
+    check(all(math.isfinite(v) for v in losses), "every loss is finite")
+    check(losses[-1] < losses[0],
+          "loss falls on the repeated batch (%.4f -> %.4f)"
+          % (losses[0], losses[-1]))
+
+
+def peak_bytes(dev):
+    stats = dev.memory_stats() or {}
+    return stats.get("peak_bytes_in_use")
+
+
+def phase_train(args):
+    devs = tpu_devices()
+    import horovod_tpu as hvd
+    from horovod_tpu import models, parallel
+
+    hvd.init()
+    check(hvd.size() == 1 and hvd.rank() == 0, "hvd.init(): rank 0 of 1")
+    mesh = parallel.data_parallel_mesh(devices=devs)
+    step, state = resnet_step(models.ResNet50, mesh, SIZES["resnet_batch"],
+                              args.seed)
+    params, opt_state, batch = step.place(*state)
+    params, opt_state, losses, secs = run_steps(
+        step, params, opt_state, batch, SIZES["train_steps"])
+    print("  ResNet-50 bf16 %dx%d batch %d: first step (with compile) "
+          "%.1f s, then %s ms; peak bytes %s"
+          % (SIZES["image"], SIZES["image"], SIZES["resnet_batch"], secs[0],
+             " ".join("%.1f" % (1e3 * s) for s in secs[1:]),
+             peak_bytes(devs[0])), flush=True)
+    check_losses(losses)
+    check(on_tpu((params, opt_state, batch)),
+          "parameters, optimizer state and batch live on the tpu")
+    hvd.shutdown()
+
+
+def lm_step(mesh, seed):
+    """The GPT-2-small-shaped LM row of bench.py: flash attention, dense
+    log-softmax loss, adam, through make_train_step. Returns the step and
+    its (params, opt_state, batch), not yet placed."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from horovod_tpu import models
+    from horovod_tpu.parallel import make_train_step
+
+    cfg = models.TransformerConfig(attention="flash", dtype=jnp.bfloat16,
+                                   **SIZES["lm"])
+    model = models.Transformer(cfg)
+    L = SIZES["lm_len"]
+    rng = jax.random.PRNGKey(seed)
+    tokens = jax.random.randint(rng, (SIZES["lm_batch"] * mesh.size, L), 0,
+                                cfg.vocab_size)
+    positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None],
+                                 tokens.shape)
+    params = jax.jit(lambda r: model.init(
+        r, tokens[:1], positions[:1]))(rng)["params"]
+
+    def loss_fn(params, batch):
+        logits = model.apply({"params": params}, batch["x"], batch["pos"])
+        tgt = jnp.roll(batch["x"], -1, axis=1)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        return -jnp.mean(jnp.take_along_axis(logp, tgt[..., None], axis=-1))
+
+    opt = optax.adam(1e-4)
+    step = make_train_step(loss_fn, opt, mesh)
+    return step, (params, opt.init(params), {"x": tokens, "pos": positions})
+
+
+def compile_with_text(jitted, *call_args):
+    """AOT-compiles a jitted callable; returns (compiled, text, seconds)."""
+    t0 = time.perf_counter()
+    compiled = jitted.lower(*call_args).compile()
+    return compiled, compiled.as_text(), time.perf_counter() - t0
+
+
+def attention_case(B, H, G, L, D, rotary, dtype, seed):
+    """flash_attention forward and backward alone at one shape, and
+    _blockwise_reference doing the same: (name, kernel, reference,
+    (q, k, v, cotangent)), both jitted and returning (out, dq, dk, dv)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.flash_attention import (_blockwise_reference,
+                                                 flash_attention)
+
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(kq, (B, L, H, D), jnp.float32).astype(dtype)
+    k = jax.random.normal(kk, (B, L, G, D), jnp.float32).astype(dtype)
+    v = jax.random.normal(kv, (B, L, G, D), jnp.float32).astype(dtype)
+    w = jax.random.normal(kw, (B, L, H, D), jnp.float32)
+    base = 10000.0 if rotary else None
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=True, rotary_base=base)
+
+    def reference(q, k, v):
+        t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+        return t(_blockwise_reference(t(q), t(k), t(v), D ** -0.5, True,
+                                      base))
+
+    def both(fn):
+        def f(q, k, v, w):
+            out, vjp = jax.vjp(fn, q, k, v)
+            return (out,) + vjp(w.astype(out.dtype))
+        return jax.jit(f)
+
+    name = "B%d H%d G%d L%d D%d%s %s" % (
+        B, H, G, L, D, " rotary" if rotary else "", jnp.dtype(dtype).name)
+    return name, both(kernel), both(reference), (q, k, v, w)
+
+
+def attention_vs_reference(case, tol):
+    """The kernel is in the program, and on the chip it agrees with the
+    reference."""
+    import jax
+
+    name, kernel, reference, qkvw = case
+    compiled, text, secs = compile_with_text(kernel, *qkvw)
+    n = kernel_calls(text)
+    check(n >= 3, "flash %s: %d tpu_custom_call in the program "
+          "(forward, dQ, dK/dV; compiled in %.1f s)" % (name, n, secs))
+    got = compiled(*qkvw)
+    with jax.default_matmul_precision("highest"):
+        want = reference(*qkvw)
+    jax.block_until_ready((got, want))
+    errs = [rel_err(g, r) for g, r in zip(got, want)]
+    check(max(errs) <= tol,
+          "flash %s vs _blockwise_reference on the chip: out %.2e dq %.2e "
+          "dk %.2e dv %.2e (max rel to max |ref|, tol %.0e)"
+          % ((name,) + tuple(errs) + (tol,)))
+
+
+def bn_case(M, C, seed):
+    """fused_batch_norm_train (Pallas statistics and gradient-statistics
+    kernels) and flax.linen.BatchNorm at one ResNet-50 shape: (fused,
+    flax, (x, gamma, beta, cotangent)), both jitted value_and_grad with y
+    as aux."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.batch_norm import fused_batch_norm_train
+
+    kx, kg, kb, kw = jax.random.split(jax.random.PRNGKey(seed), 4)
+    x = jax.random.normal(kx, (M, C), jnp.float32) * 2.0 + 0.5
+    gamma = jax.random.uniform(kg, (C,), jnp.float32) + 0.5
+    beta = jax.random.normal(kb, (C,), jnp.float32)
+    w = jax.random.normal(kw, (M, C), jnp.float32)
+    bn = nn.BatchNorm(use_running_average=False, momentum=0.9, epsilon=1e-5)
+    stats = {"mean": jnp.zeros(C), "var": jnp.ones(C)}
+
+    def flax_loss(x, gamma, beta, w):
+        y, _ = bn.apply({"params": {"scale": gamma, "bias": beta},
+                         "batch_stats": stats}, x, mutable=["batch_stats"])
+        return jnp.sum(y * w), y
+
+    def fused_loss(x, gamma, beta, w):
+        y, _, _ = fused_batch_norm_train(x, gamma, beta, 1e-5, False)
+        return jnp.sum(y.astype(jnp.float32) * w), y
+
+    def both(fn):
+        return jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    return both(fused_loss), both(flax_loss), (x, gamma, beta, w)
+
+
+def bn_vs_flax(M, C, seed):
+    fused, flax_bn, inputs = bn_case(M, C, seed)
+    compiled, text, secs = compile_with_text(fused, *inputs)
+    n = kernel_calls(text)
+    check(n >= 2, "BN (%d, %d): %d tpu_custom_call in the program "
+          "(statistics, gradient statistics; compiled in %.1f s)"
+          % (M, C, n, secs))
+    (_, y_k), g_k = compiled(*inputs)
+    (_, y_f), g_f = flax_bn(*inputs)
+    e_out = rel_err(y_k, y_f)
+    e_grad = [rel_err(a, b) for a, b in zip(g_k, g_f)]
+    check(e_out <= TOL["bn_out"] and max(e_grad) <= TOL["bn_grad"],
+          "BN (%d, %d) vs flax.linen.BatchNorm on the chip: y %.2e "
+          "(tol %.0e) dx %.2e dgamma %.2e dbeta %.2e (tol %.0e)"
+          % ((M, C, e_out, TOL["bn_out"]) + tuple(e_grad)
+             + (TOL["bn_grad"],)))
+
+
+def phase_kernels(args):
+    devs = tpu_devices()
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import models, parallel
+
+    mesh = parallel.data_parallel_mesh(devices=devs)
+
+    # The train phase's program again, in a second process: the persistent
+    # compile cache must have it.
+    events = []
+    jax.monitoring.register_event_listener(
+        lambda name, **kw: events.append(name))
+    step, state = resnet_step(models.ResNet50, mesh, SIZES["resnet_batch"],
+                              args.seed)
+    params, opt_state, batch = step.place(*state)
+    del events[:]
+    t0 = time.perf_counter()
+    jax.block_until_ready(step(params, opt_state, batch))
+    hits = events.count("/jax/compilation_cache/cache_hits")
+    misses = events.count("/jax/compilation_cache/cache_misses")
+    print("  compile cache %s: second process, ResNet-50 step: hits %d "
+          "misses %d, first call %.1f s"
+          % (os.environ["JAX_COMPILATION_CACHE_DIR"], hits, misses,
+             time.perf_counter() - t0), flush=True)
+    check(hits >= 1 and misses == 0,
+          "the second process found the train step in the compile cache")
+    del step, state, params, opt_state, batch
+
+    step, state = lm_step(mesh, args.seed)
+    state = step.place(*state)
+    compiled, text, secs = compile_with_text(step, *state)
+    n = kernel_calls(text)
+    check(n >= 3 * SIZES["lm"]["num_layers"],
+          "LM L=%d flash: %d tpu_custom_call in the train step (forward, "
+          "dQ, dK/dV for each of %d layers; compiled in %.1f s)"
+          % (SIZES["lm_len"], n, SIZES["lm"]["num_layers"], secs))
+    params, opt_state, losses, secs = run_steps(compiled, *state,
+                                                SIZES["lm_steps"])
+    print("  LM %dx%d L=%d batch %d: steps %s ms; peak bytes %s"
+          % (SIZES["lm"]["embed_dim"], SIZES["lm"]["num_layers"],
+             SIZES["lm_len"], SIZES["lm_batch"],
+             " ".join("%.1f" % (1e3 * s) for s in secs),
+             peak_bytes(devs[0])), flush=True)
+    check_losses(losses)
+    check(on_tpu((params, opt_state)), "LM state lives on the tpu")
+    del step, state, compiled, params, opt_state
+
+    for i, shape in enumerate(SIZES["attn"]):
+        attention_vs_reference(
+            attention_case(*shape, jnp.bfloat16, args.seed + i),
+            TOL["attn_bf16"])
+
+    step, state = resnet_step(models.ResNet50PBN, mesh,
+                              SIZES["resnet_batch"], args.seed)
+    state = step.place(*state)
+    compiled, text, secs = compile_with_text(step, *state)
+    n = kernel_calls(text)
+    check(n >= 2, "ResNet50PBN: %d tpu_custom_call in the train step "
+          "(Pallas BN statistics; compiled in %.1f s)" % (n, secs))
+    params, opt_state, losses, secs = run_steps(compiled, *state, 2)
+    print("  ResNet50PBN batch %d: steps %s ms"
+          % (SIZES["resnet_batch"],
+             " ".join("%.1f" % (1e3 * s) for s in secs)), flush=True)
+    check_losses(losses)
+    del step, state, compiled, params, opt_state
+    for i, (M, C) in enumerate(SIZES["bn"]):
+        bn_vs_flax(M, C, args.seed + i)
+
+
+def _mlp_grads_numpy(params, x, y):
+    """The host-plane worker's model in numpy: loss = mean((tanh(x w0)
+    w1 - y)^2), gradients for (w0, w1)."""
+    import numpy as np
+
+    w0, w1 = (np.asarray(p, np.float64) for p in params)
+    h = np.tanh(x @ w0)
+    d = 2.0 * (h @ w1 - y) / y.size
+    return [x.T @ ((d @ w1.T) * (1.0 - h * h)), h.T @ d]
+
+
+def phase_hostplane_worker(args):
+    """One of the launcher's two local workers. Local rank 0 keeps the
+    chip; every other rank pins itself to the CPU before importing jax."""
+    rank = int(os.environ["HVD_TPU_RANK"])
+    if rank != 0:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import horovod_tpu.jax as hvd
+
+    platform = (tpu_devices() if rank == 0 else jax.devices())[0].platform
+    if rank != 0 and platform != "cpu":
+        raise SystemExit("chip_smoke: rank %d runs on %s" % (rank, platform))
+    hvd.init()
+    size = hvd.size()
+
+    def data(r):
+        g = np.random.RandomState(args.seed + 100 * r)
+        return g.randn(64, 256), g.randn(64, 128)
+
+    g = np.random.RandomState(args.seed)
+    params0 = [g.randn(256, 256) * 0.05, g.randn(256, 128) * 0.05]
+    want = [sum(t) / size for t in zip(*(
+        _mlp_grads_numpy(params0, *data(r)) for r in range(size)))]
+
+    params = [jnp.asarray(p, jnp.float32) for p in params0]
+    x, y = (jnp.asarray(t, jnp.float32) for t in data(rank))
+
+    def loss(params, x, y):
+        return jnp.mean((jnp.tanh(x @ params[0]) @ params[1] - y) ** 2)
+
+    with jax.default_matmul_precision("highest"):
+        grads = jax.jit(jax.grad(loss))(params, x, y)
+        check(on_tpu(grads) == (rank == 0),
+              "rank %d: gradients computed on the %s" % (rank, platform))
+        eager = [hvd.allreduce(t, average=True, name="smoke.eager.%d" % i)
+                 for i, t in enumerate(grads)]
+
+        @jax.jit
+        def reduced_grads(params, x, y):
+            return [hvd.allreduce(t, average=True, name="smoke.jit.%d" % i)
+                    for i, t in enumerate(jax.grad(loss)(params, x, y))]
+
+        in_jit = jax.block_until_ready(reduced_grads(params, x, y))
+    e_eager = max(rel_err(a, b) for a, b in zip(eager, want))
+    e_jit = max(rel_err(a, b) for a, b in zip(in_jit, want))
+    check(e_eager <= TOL["host"] and e_jit <= TOL["host"],
+          "rank %d (%s): allreduce through the C++ core vs numpy: eager "
+          "%.2e, ordered io_callback inside jit %.2e (tol %.0e)"
+          % (rank, platform, e_eager, e_jit, TOL["host"]))
+    hvd.shutdown()
+    print("HOSTPLANE_OK rank=%d platform=%s" % (rank, platform), flush=True)
+
+
+def ring_lm_case(mesh, seed):
+    """The LM's loss and gradients with ring attention over the mesh's
+    (dp, sp) axes, and with flash attention on one device: (ring, flash,
+    params, (tokens, positions, targets)), both jitted. Parameters come
+    from the flash twin's init: a ring model cannot be initialised
+    outside shard_map."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu import models
+    from horovod_tpu.ops.losses import chunked_softmax_cross_entropy
+
+    lm = dict(SIZES["lm"], num_heads=SIZES["ring_heads"])
+    flash = models.Transformer(models.TransformerConfig(
+        attention="flash", dtype=jnp.bfloat16, **lm))
+    ring = models.Transformer(models.TransformerConfig(
+        attention="ring", sp_axis="sp", dtype=jnp.bfloat16, **lm))
+    L, B = SIZES["ring_len"], SIZES["ring_batch"]
+    rng = jax.random.PRNGKey(seed)
+    tokens = jax.random.randint(rng, (B, L), 0, lm["vocab_size"])
+    positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None],
+                                 tokens.shape)
+    targets = jnp.roll(tokens, -1, axis=1)
+    params = jax.jit(lambda r: flash.init(
+        r, tokens[:1], positions[:1]))(rng)["params"]
+
+    def loss_of(model):
+        def loss(params, x, pos, tgt):
+            hidden = model.apply({"params": params}, x, pos,
+                                 return_hidden=True)
+            return chunked_softmax_cross_entropy(
+                hidden, params["lm_head"]["kernel"], tgt,
+                chunk=min(512, x.shape[1]))
+        return loss
+
+    axes = ("dp", "sp")
+
+    def ring_shard(params, x, pos, tgt):
+        loss, grads = jax.value_and_grad(loss_of(ring))(params, x, pos, tgt)
+        grads = jax.tree_util.tree_map(
+            lambda t: jax.lax.pmean(t, axes), grads)
+        return jax.lax.pmean(loss, axes), grads
+
+    ring_fn = jax.jit(jax.shard_map(
+        ring_shard, mesh=mesh, in_specs=(P(), P(*axes), P(*axes), P(*axes)),
+        out_specs=(P(), P()), check_vma=False))
+    flash_fn = jax.jit(jax.value_and_grad(loss_of(flash)))
+    return ring_fn, flash_fn, params, (tokens, positions, targets)
+
+
+def phase_fourchip(args):
+    devs = tpu_devices(4)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from horovod_tpu import models, parallel
+
+    # (a) ResNet-50, sync BN over the mesh axis: four devices x 64 against
+    # one device x 256 on the same images.
+    results = {}
+    for n in (4, 1):
+        mesh = parallel.data_parallel_mesh(devices=devs[:n])
+        step, state = resnet_step(
+            models.ResNet50, mesh,
+            SIZES["resnet_batch_4"] * 4 // n, args.seed, donate=False,
+            bn_axis_name="hvd")
+        params, opt_state, batch = step.place(*state)
+        compiled, text, secs = compile_with_text(step, params, opt_state,
+                                                 batch)
+        if n == 4:
+            check("all-reduce" in text, "four-device ResNet-50 step holds "
+                  "all-reduce (compiled in %.1f s)" % secs)
+            shard_devs = {s.device for s in batch["x"].addressable_shards}
+            check(len(shard_devs) == 4,
+                  "the batch's addressable_shards sit on 4 distinct devices")
+            check(all(len(p.sharding.device_set) == 4
+                      for p in jax.tree_util.tree_leaves(params)),
+                  "every parameter is on all 4 devices")
+        new, _, loss = compiled(params, opt_state, batch)
+        old, new = host_leaves(params), host_leaves(new)
+        results[n] = dict(
+            loss=float(loss),
+            checksum=float(sum(np.abs(x).sum(dtype=np.float64)
+                               for x in new)),
+            delta=np.concatenate([(a - b).ravel()
+                                  for a, b in zip(new, old)]))
+        del compiled, state, params, opt_state, batch
+    r4, r1 = results[4], results[1]
+    e_loss = abs(r4["loss"] - r1["loss"]) / abs(r1["loss"])
+    e_sum = abs(r4["checksum"] - r1["checksum"]) / r1["checksum"]
+    cos = float(r4["delta"] @ r1["delta"] / (
+        np.linalg.norm(r4["delta"]) * np.linalg.norm(r1["delta"])))
+    check(np.isfinite(r4["loss"]) and e_loss <= TOL["loss_rel"]
+          and e_sum <= TOL["checksum_rel"] and cos >= TOL["update_cosine"],
+          "ResNet-50 4x%d vs 1x%d on the same images: loss %.5f vs %.5f "
+          "(rel %.1e, tol %.0e), updated-parameter checksum rel %.1e "
+          "(tol %.0e), cosine of the two updates %.4f (at least %.2f)"
+          % (SIZES["resnet_batch_4"], 4 * SIZES["resnet_batch_4"],
+             r4["loss"], r1["loss"], e_loss, TOL["loss_rel"], e_sum,
+             TOL["checksum_rel"], cos, TOL["update_cosine"]))
+    del results, r4, r1
+
+    # (b) The LM with ring attention over (dp=1, sp=4) against its flash
+    # twin on one device.
+    mesh = parallel.hybrid_mesh((1, 4), ("dp", "sp"), devices=devs)
+    ring_fn, flash_fn, params, (tokens, positions, targets) = ring_lm_case(
+        mesh, args.seed)
+    L = SIZES["ring_len"]
+    axes = ("dp", "sp")
+    rep, seq = NamedSharding(mesh, P()), NamedSharding(mesh, P(*axes))
+    ring_args = (jax.device_put(params, rep),) + tuple(
+        jax.device_put(t, seq) for t in (tokens, positions, targets))
+    compiled, text, secs = compile_with_text(ring_fn, *ring_args)
+    n = kernel_calls(text)
+    check(n >= 3 and "collective-permute" in text,
+          "ring LM L=%d (%d per chip): %d tpu_custom_call (the Pallas ring "
+          "kernel) and collective-permute in the program (compiled in "
+          "%.1f s)" % (L, L // 4, n, secs))
+    t0 = time.perf_counter()
+    loss_r, grads_r = jax.block_until_ready(compiled(*ring_args))
+    print("  ring LM step (loss and gradients): %.1f ms"
+          % (1e3 * (time.perf_counter() - t0)), flush=True)
+    grads_r = host_leaves(grads_r)
+    del compiled, ring_args
+
+    one = devs[0]
+    flash_args = tuple(jax.device_put(t, one)
+                       for t in (params, tokens, positions, targets))
+    loss_f, grads_f = jax.block_until_ready(flash_fn(*flash_args))
+    grads_f = host_leaves(grads_f)
+    e_loss = abs(float(loss_r) - float(loss_f)) / abs(float(loss_f))
+    num = np.sqrt(sum(float(((a - b) ** 2).sum(dtype=np.float64))
+                      for a, b in zip(grads_r, grads_f)))
+    den = np.sqrt(sum(float((b ** 2).sum(dtype=np.float64))
+                      for b in grads_f))
+    check(np.isfinite(float(loss_r)) and e_loss <= TOL["loss_rel"]
+          and num / den <= TOL["grad_rel_l2"],
+          "ring LM on 4 chips vs flash LM on 1: loss %.5f vs %.5f (rel "
+          "%.1e, tol %.0e), gradients relative L2 error %.2e (tol %.0e)"
+          % (float(loss_r), float(loss_f), e_loss, TOL["loss_rel"],
+             num / den, TOL["grad_rel_l2"]))
+
+
+PHASES = {"train": phase_train, "kernels": phase_kernels,
+          "hostplane-worker": phase_hostplane_worker,
+          "fourchip": phase_fourchip}
+
+
+# --------------------------------------------------------------------------
+# Parent: no jax here.
+# --------------------------------------------------------------------------
+
+def run_child(name, cmd, env, timeout):
+    """Runs one child to its end, echoing its stdout; returns its lines.
+    The child and whatever it started are gone when this returns."""
+    print("== phase %s" % name, flush=True)
+    t0 = time.monotonic()
+    proc = subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE, text=True,
+                            start_new_session=True)
+
+    def kill_group():
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+
+    killer = threading.Timer(timeout, kill_group)
+    killer.start()
+    lines = []
+    try:
+        for line in proc.stdout:
+            sys.stdout.write(line)
+            sys.stdout.flush()
+            lines.append(line.rstrip("\n"))
+        rc = proc.wait()
+    finally:
+        killer.cancel()
+        kill_group()
+        proc.wait()
+    if rc != 0:
+        sys.exit("chip_smoke: phase %s FAILED (exit code %d after %.0f s)"
+                 % (name, rc, time.monotonic() - t0))
+    print("== phase %s passed in %.0f s" % (name, time.monotonic() - t0),
+          flush=True)
+    return lines
+
+
+def device_line(lines):
+    for line in lines:
+        if line.startswith("DEVICE "):
+            return json.loads(line[len("DEVICE "):])
+    sys.exit("chip_smoke: the child printed no DEVICE line")
+
+
+def rebuild_native():
+    """The C++ core, rebuilt from the sources in the tree: a library that
+    was copied along with them may be older than they are."""
+    t0 = time.monotonic()
+    proc = subprocess.run(
+        ["make", "-B", "-j", str(os.cpu_count() or 4)],
+        cwd=os.path.join(REPO, "horovod_tpu", "native"),
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+    if proc.returncode != 0:
+        sys.stdout.write(proc.stdout[-4000:])
+        sys.exit("chip_smoke: phase hostplane FAILED (make exit code %d)"
+                 % proc.returncode)
+    print("== libhorovod_tpu.so rebuilt from the tree's sources in %.0f s"
+          % (time.monotonic() - t0), flush=True)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4 runs only the four-chip phase and what it is "
+                         "compared with")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--phase", choices=sorted(PHASES),
+                    help=argparse.SUPPRESS)
+    args = ap.parse_args()
+
+    if args.phase:
+        try:
+            PHASES[args.phase](args)
+        except PhaseFailed as e:
+            sys.exit("chip_smoke: check failed in phase %s: %s"
+                     % (args.phase, e))
+        return 0
+
+    assert "jax" not in sys.modules, "the parent must stay off jax"
+    env = dict(os.environ)
+    use_compile_cache(env)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("PYTHONUNBUFFERED", None)
+    me = [sys.executable, os.path.abspath(__file__), "--seed",
+          str(args.seed), "--phase"]
+
+    if args.chips == 4:
+        device = device_line(run_child("fourchip", me + ["fourchip"], env,
+                                       1100))
+    else:
+        device = device_line(run_child("train", me + ["train"], env, 500))
+        run_child("kernels", me + ["kernels"], env, 700)
+        rebuild_native()
+        lines = run_child(
+            "hostplane",
+            [sys.executable, "-m", "horovod_tpu.run.run", "-np", "2", "--"]
+            + me + ["hostplane-worker"], env, 300)
+        for want in ("HOSTPLANE_OK rank=0 platform=tpu",
+                     "HOSTPLANE_OK rank=1 platform=cpu"):
+            if not any(want in line for line in lines):
+                sys.exit("chip_smoke: phase hostplane FAILED (no %r line)"
+                         % want)
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

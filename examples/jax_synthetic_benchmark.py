@@ -66,9 +66,8 @@ def main():
     print("Model: %s, batch size/device: %d, devices: %d (%s)" %
           (args.model, args.batch_size, n, devices[0].platform))
 
-    # float(loss) is a true end-of-chain barrier (each loss depends on
-    # every prior step's params); block_until_ready alone is not reliable
-    # over remote-device transports.
+    # float(loss) is an end-of-chain barrier: each loss depends on every
+    # prior step's params.
     for _ in range(args.num_warmup_batches):
         params_p, opt_state, loss = step(params_p, opt_state, batch)
     float(loss)

@@ -25,20 +25,24 @@ _build_lock = threading.Lock()
 
 
 def _ensure_built():
-    """Builds the native core on first use (the .so is not checked in).
+    """Brings the native core up to date with its sources before every
+    first load. The .so is git-ignored but copied with the tree, so one
+    that merely exists may be older than the sources beside it: always
+    go through ``make`` (a no-op when fresh — the Makefile tracks header
+    dependencies). A directory without a Makefile is a prebuilt
+    alternate core (``HVD_TPU_NATIVE_DIR``, e.g. the sanitizer builds)
+    and is loaded as it is.
 
     Launcher-spawned worker processes hit this concurrently on a fresh
     checkout, so an inter-process flock serializes the build (the
     threading.Lock only covers threads within one process)."""
+    if not os.path.exists(os.path.join(_NATIVE_DIR, "Makefile")):
+        return
     with _build_lock:
-        if os.path.exists(_LIB_PATH):
-            return
         lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
         with open(lock_path, "w") as lock_file:
             fcntl.flock(lock_file, fcntl.LOCK_EX)
             try:
-                if os.path.exists(_LIB_PATH):
-                    return
                 subprocess.run(["make", "-j", str(os.cpu_count() or 4)],
                                cwd=_NATIVE_DIR, check=True,
                                stdout=subprocess.PIPE,

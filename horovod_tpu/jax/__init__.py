@@ -22,10 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from horovod_tpu.compat import ensure_jax_compat as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 import horovod_tpu as _hvd
 from horovod_tpu import compression as _wire
 from horovod_tpu import (  # noqa: F401

@@ -42,7 +42,7 @@ enum class Channel : uint8_t {
 };
 
 // Why the last frame-layer call on a Conn failed — the transport error
-// taxonomy the recoverable-error messages are built from.
+// classification the recoverable-error messages are built from.
 enum class NetError : uint8_t {
   NONE = 0,
   CLOSED,    // EOF / reset / refused — the peer (or a fault) closed it

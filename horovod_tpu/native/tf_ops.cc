@@ -3,7 +3,7 @@
 // Makes allreduce/allgather/broadcast real graph nodes: they compose with
 // tf.function, tf.gradients (gradients are registered on the Python side,
 // horovod_tpu/tensorflow/mpi_ops.py) and SavedModel export, instead of
-// tunnelling through tf.py_function. Capability parity with the reference
+// detouring through tf.py_function. Capability parity with the reference
 // async CPU kernels (/root/reference horovod/tensorflow/mpi_ops.cc:276-463);
 // fresh implementation: kernels call the framework-agnostic handle-based
 // C API of libhorovod_tpu.so (native/operations.cc), whose symbols are

@@ -9,8 +9,4 @@ played by Pallas TPU kernels:
   VMEM (O(L) memory), causal block skipping, custom VJP.
 """
 
-from horovod_tpu.compat import ensure_jax_compat as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from .flash_attention import flash_attention  # noqa: F401

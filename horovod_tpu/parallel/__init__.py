@@ -34,10 +34,6 @@ Ulysses), dp x pp, fsdp x tp, plus the dryrun's dp x {sp,tp,ep}
 train steps.
 """
 
-from horovod_tpu.compat import ensure_jax_compat as _ensure_jax_compat
-
-_ensure_jax_compat()
-
 from .mesh import (  # noqa: F401
     data_parallel_mesh,
     hybrid_mesh,

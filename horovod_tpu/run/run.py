@@ -130,9 +130,18 @@ def discover_tpu_pod():
 def make_parser():
     parser = argparse.ArgumentParser(
         prog="horovodrun_tpu",
-        description="Launch a horovod_tpu distributed job.")
+        description="Launch a horovod_tpu distributed job.",
+        epilog="On a TPU host ONE worker process drives all local chips "
+               "(a chip belongs to one process at a time): launch one "
+               "slot per host there (-H host1:1,host2:1 or --tpu-pod). "
+               "-np N workers on one host are CPU workers — the "
+               "launcher does not hand out chips, so unless each worker "
+               "is given its own chip, every rank but one must pin "
+               "itself to the CPU (JAX_PLATFORMS=cpu) before importing "
+               "jax, or the workers fail or hang on the chip.")
     parser.add_argument("-np", "--num-proc", type=int, default=None,
-                        help="number of processes to launch")
+                        help="number of processes to launch (on one TPU "
+                             "host: CPU workers, see the note below)")
     parser.add_argument("-H", "--hosts", default=None,
                         help='host slots, e.g. "localhost:4,host2:4"')
     parser.add_argument("--hostfile", default=None,

@@ -2,7 +2,7 @@
 
 Capability parity with the reference (`horovod/spark/__init__.py:35-233`):
 run `fn` as a data-parallel horovod job on `num_proc` Spark tasks and
-return the per-rank results. The reference tunnels `mpirun`'s remote shell
+return the per-rank results. The reference routes `mpirun`'s remote shell
 through Spark task RPC (mpirun_rsh); the TPU-native build needs no MPI —
 Spark's **barrier execution mode** gives every task a rendezvous
 (`BarrierTaskContext.allGather`), so each task exchanges its

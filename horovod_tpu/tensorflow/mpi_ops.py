@@ -45,8 +45,8 @@ def _build_tf_ops():
     with open(lock_path, "w") as lock_file:
         fcntl.flock(lock_file, fcntl.LOCK_EX)
         try:
-            if os.path.exists(_TF_LIB_PATH):
-                return
+            # Always through make (a no-op when fresh): a library that
+            # merely exists may be older than tf_ops.cc beside it.
             subprocess.run(["make", "tf"], cwd=_NATIVE_DIR, env=env,
                            check=True, stdout=subprocess.PIPE,
                            stderr=subprocess.STDOUT)
@@ -74,8 +74,7 @@ def _load():
             # The kernels resolve core symbols from libhorovod_tpu.so,
             # which basics loads RTLD_GLOBAL — load it first.
             get_basics()
-            if not os.path.exists(_TF_LIB_PATH):
-                _build_tf_ops()
+            _build_tf_ops()
             _lib = tf.load_op_library(_TF_LIB_PATH)
         except Exception as e:  # noqa: BLE001 — remember and fall back
             _load_error = str(e)

@@ -71,9 +71,13 @@ def train(state):
             print("worker %s crashing now" % WID, flush=True)
             os._exit(31)
         if state.step % COMMIT_EVERY == 0:
-            state.commit()
+            # Printed BEFORE the commit (the values are the same): the
+            # durable manifest of a step must never be on disk before
+            # its line is in the pipe, or a test that kills the job the
+            # moment it sees the manifest loses the line.
             print("worker %s commit step %d crc %08x"
                   % (WID, state.step, state_crc(state)), flush=True)
+            state.commit()
         time.sleep(STEP_SLEEP)
     return float(np.sum((state.w - TARGET) ** 2))
 

@@ -48,17 +48,7 @@ def main():
     def total(x):
         return jnp.sum(x)
 
-    try:
-        result = float(total(arr))
-    except Exception as e:  # jaxlib.xla_extension.XlaRuntimeError
-        if "Multiprocess computations aren't implemented" in str(e):
-            # Old jaxlib: the CPU backend has no cross-process
-            # collective runtime (landed later). The bootstrap itself
-            # (device view above) worked; report a capability skip so
-            # the test can distinguish "unsupported here" from broken.
-            print("SKIP multiprocess_cpu_unsupported", flush=True)
-            return 0
-        raise
+    result = float(total(arr))
     expected = sum((rr + 1.0) * local for rr in range(n))
     assert abs(result - expected) < 1e-6, (result, expected)
     if r == 0:

@@ -112,9 +112,11 @@ def train(state):
             # Collective: every rank materializes the full optimizer
             # state so the commit snapshot re-shards at any world size.
             state.opt_full = hvd_jax.sharded_state_full(s)
-            state.commit()
+            # Printed BEFORE the commit (same values): see
+            # durable_worker.py.
             print("worker %s commit step %d crc %08x"
                   % (WID, state.step, state_crc(state)), flush=True)
+            state.commit()
         time.sleep(STEP_SLEEP)
     state.params = {k: np.asarray(v, np.float32)
                     for k, v in params.items()}

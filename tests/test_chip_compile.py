@@ -1,0 +1,149 @@
+"""The main path's Pallas kernels compile for the chip, with no chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (`on-chip-measurement` guide, section 2). Interpret
+mode cannot see what it refuses — a slice not aligned to the tiling, more
+fast memory than a kernel may use — so the kernels `chip_smoke.py` runs are
+compiled here at its shapes, about two seconds each. A compile that passes
+is not a chip run; `python chip_smoke.py` is.
+
+Only one process may load the TPU's library, and it keeps it until it
+exits: the topology is described inside the module-scoped fixture below
+(never at import time), in this test's own process, and all such tests
+live in this one file.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from chip_smoke import kernel_calls as _kernels
+from horovod_tpu.ops import batch_norm
+from horovod_tpu.ops.flash_attention import (_flash, _pallas_forward_lse,
+                                             flash_ring_bwd_step,
+                                             flash_ring_step)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e chip to compile for. The persistent compile cache
+    is off around these compiles: an entry written for a described chip
+    cannot be read back without one, and the next run would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # The compiler otherwise logs under /tmp.
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    """Compiles fn for the described chip; returns the program text.
+
+    Under the ambient matmul precision the models run with, not the
+    "highest" that other test modules set process-wide at import: Mosaic
+    refuses an fp32-precision matmul on bf16 operands ("Bad lhs type"),
+    so with that setting every bf16 flash/ring kernel fails to compile
+    for the chip (found by this file; recorded in ROADMAP.md)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# (B, H, G, L, D, fused rotary): the attention of the L=1024 LM row of
+# bench.py's zoo and of its long-context h6 / gqa2 / fused-rope row.
+_LM_SHAPES = [(8, 12, 12, 1024, 64, None), (2, 6, 2, 8192, 128, 10000.0)]
+
+
+@pytest.mark.parametrize("B,H,G,L,D,rotary", _LM_SHAPES)
+def test_flash_forward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
+    fwd = functools.partial(_pallas_forward_lse, scale=D ** -0.5,
+                            causal=True, interpret=False,
+                            rotary_base=rotary)
+    bf16 = jnp.bfloat16
+    text = _compile(one_chip, fwd, ((B, H, L, D), bf16),
+                    ((B, G, L, D), bf16), ((B, G, L, D), bf16))
+    assert _kernels(text) == 1, text[:2000]
+
+
+@pytest.mark.parametrize("B,H,G,L,D,rotary", _LM_SHAPES)
+def test_flash_backward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
+    def bwd(q, k, v, g):
+        # interpret=False names the kernel path itself: the dispatcher in
+        # flash_attention() asks jax.default_backend(), which is the CPU
+        # here.
+        _, vjp = jax.vjp(
+            lambda q, k, v: _flash(q, k, v, D ** -0.5, True, False,
+                                   rotary), q, k, v)
+        return vjp(g)
+
+    bf16 = jnp.bfloat16
+    text = _compile(one_chip, bwd, ((B, H, L, D), bf16),
+                    ((B, G, L, D), bf16), ((B, G, L, D), bf16),
+                    ((B, H, L, D), bf16))
+    # forward (for the residuals), dQ, dK/dV
+    assert _kernels(text) == 3, text[:2000]
+
+
+# The ring LM of `chip_smoke.py --chips 4`: B2 x H6 per chip, L=8192 over
+# four chips, D=128.
+_RING = dict(BG=12, L=2048, D=128)
+
+
+def test_ring_forward_step_compiles_for_v5e(one_chip):
+    BG, L, D = _RING["BG"], _RING["L"], _RING["D"]
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    text = _compile(
+        one_chip, functools.partial(flash_ring_step, causal=True),
+        ((BG, L, D), bf16), ((BG, L, D), bf16), ((BG, L, D), bf16),
+        ((BG, L, D), f32), ((BG, L, 8), f32), ((BG, L, 8), f32),
+        ((), i32), ((), i32))
+    assert _kernels(text) == 1, text[:2000]
+
+
+def test_ring_backward_step_compiles_for_v5e(one_chip):
+    BG, L, D = _RING["BG"], _RING["L"], _RING["D"]
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    text = _compile(
+        one_chip, functools.partial(flash_ring_bwd_step, causal=True),
+        ((BG, L, D), bf16), ((BG, L, D), bf16), ((BG, L, D), bf16),
+        ((BG, L, D), bf16), ((BG, L, 8), f32), ((BG, L, 8), f32),
+        ((BG, L, D), f32), ((BG, L, D), f32), ((BG, L, D), f32),
+        ((), i32), ((), i32))
+    # dQ, dK/dV
+    assert _kernels(text) == 2, text[:2000]
+
+
+# (M, C) of the largest and the smallest BatchNorm of ResNet-50 at batch
+# 256 (bf16 activations, as the model feeds them).
+_BN_SHAPES = [(256 * 112 * 112, 64), (256 * 7 * 7, 2048)]
+
+
+@pytest.mark.parametrize("M,C", _BN_SHAPES)
+def test_bn_stats_compiles_for_v5e(one_chip, M, C):
+    text = _compile(one_chip, batch_norm.batch_norm_stats,
+                    ((M, C), jnp.bfloat16))
+    assert _kernels(text) == 1, text[:2000]
+
+
+@pytest.mark.parametrize("M,C", _BN_SHAPES)
+def test_bn_grad_stats_compiles_for_v5e(one_chip, M, C):
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    text = _compile(one_chip, batch_norm.batch_norm_grad_stats,
+                    ((M, C), bf16), ((M, C), bf16), ((C,), f32), ((C,), f32))
+    assert _kernels(text) == 1, text[:2000]

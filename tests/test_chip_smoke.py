@@ -1,0 +1,102 @@
+"""The no-fallback rules of the bring-up (ISSUE 21): the chip check and the
+throughput benchmark fail without a TPU, the compile cache is placed from
+outside, an unknown device is an error, and the native core always goes
+through make."""
+
+import os
+import subprocess
+import sys
+import time
+
+import pytest
+
+from conftest import REPO_ROOT
+
+
+def _run_on_cpu(script, *argv):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    t0 = time.monotonic()
+    proc = subprocess.run([sys.executable, os.path.join(REPO_ROOT, script)]
+                          + list(argv), env=env, capture_output=True,
+                          text=True, timeout=120)
+    return proc, time.monotonic() - t0
+
+
+def test_chip_smoke_fails_without_a_tpu():
+    """One short subprocess that must fail before it builds a model, with
+    no result line."""
+    proc, secs = _run_on_cpu("chip_smoke.py")
+    assert proc.returncode != 0, proc.stdout
+    assert '"ok"' not in proc.stdout, proc.stdout
+    assert "found no TPU" in proc.stderr, proc.stderr
+    assert secs < 60, secs
+
+
+def test_chip_smoke_four_chip_option_fails_without_a_tpu():
+    proc, _ = _run_on_cpu("chip_smoke.py", "--chips", "4")
+    assert proc.returncode != 0, proc.stdout
+    assert '"ok"' not in proc.stdout, proc.stdout
+
+
+@pytest.mark.parametrize("argv", [[], ["--model", "word2vec"],
+                                  ["--all-models"]],
+                         ids=["default", "word2vec", "all-models"])
+def test_bench_throughput_path_fails_without_a_tpu(argv):
+    """bench.py's measured path never times the CPU under the name of a
+    device metric: no TPU, no row."""
+    proc, _ = _run_on_cpu("bench.py", *argv)
+    assert proc.returncode != 0, proc.stdout
+    assert proc.stdout.strip() == "", proc.stdout
+    assert "needs a TPU" in proc.stderr, proc.stderr
+
+
+def test_peak_flops_unknown_device_is_an_error():
+    import bench
+
+    class Device:
+        device_kind = "TPU v5 lite"
+
+    assert bench.peak_flops(Device()) == 197e12
+    Device.device_kind = "cpu"
+    with pytest.raises(ValueError, match="not in the peaks table"):
+        bench.peak_flops(Device())
+
+
+def test_compile_cache_helper_honours_the_environment():
+    from horovod_tpu.run.util import cpu_worker_env, use_compile_cache
+
+    env = {"JAX_COMPILATION_CACHE_DIR": "/some/dir"}
+    assert use_compile_cache(env) == "/some/dir"
+    assert env["JAX_COMPILATION_CACHE_DIR"] == "/some/dir"
+    worker = cpu_worker_env(base_env=env)
+    assert worker["JAX_COMPILATION_CACHE_DIR"] == "/some/dir"
+
+
+def test_compile_cache_helper_defaults_inside_the_checkout():
+    from horovod_tpu.run.util import cpu_worker_env, use_compile_cache
+
+    env = {}
+    fixed = os.path.join(REPO_ROOT, ".jax_cache")
+    assert use_compile_cache(env) == fixed
+    assert env["JAX_COMPILATION_CACHE_DIR"] == fixed
+    # Every process of the checkout shares it: the launcher's CPU workers
+    # get the same directory.
+    assert cpu_worker_env(base_env={})["JAX_COMPILATION_CACHE_DIR"] == fixed
+    with open(os.path.join(REPO_ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_native_core_always_goes_through_make(monkeypatch):
+    """A libhorovod_tpu.so that merely exists may be older than the sources
+    copied with it: the loader runs make (a no-op when fresh) before every
+    first load instead of returning as soon as the file is there."""
+    from horovod_tpu.common import basics
+
+    assert os.path.exists(basics._LIB_PATH)  # the suite has loaded it
+    calls = []
+    monkeypatch.setattr(
+        basics.subprocess, "run",
+        lambda cmd, **kw: calls.append((cmd, kw["cwd"])))
+    basics._ensure_built()
+    assert len(calls) == 1 and calls[0][0][0] == "make", calls
+    assert os.path.samefile(calls[0][1], basics._NATIVE_DIR)

@@ -151,12 +151,17 @@ def test_structural_mismatch_falls_back_to_matching_older(tmp_path):
     assert np.array_equal(fresh.w, saved.w)
 
 
-def test_sticky_snapshots_guarantee_durable_progress(tmp_path):
+def test_sticky_snapshots_guarantee_durable_progress(tmp_path,
+                                                     monkeypatch):
     """The deterministic 1-in-K sticky slot: under storage far slower
     than the commit cadence, sticky steps are never displaced by newer
     non-sticky snapshots (every rank writes them — the cross-rank
     convergence anchor), while the newest snapshot still lands via the
     second slot."""
+    # Which snapshots land is the subject here, not which ones retention
+    # keeps: on a fast host four of the nine land (0, 3, 6, 8) and the
+    # default keep=3 would delete the first sticky one again.
+    monkeypatch.setenv("HVD_TPU_CKPT_KEEP", "16")
     d = str(tmp_path)
     state = make_state()
     ck = DurableCheckpointer(
@@ -561,6 +566,24 @@ def _launch(ckpt_dir, np_, extra_env=None, extra_args=(), pid_dir=None,
     return cmd, env
 
 
+def _popen_to_files(cmd, env, tmp_path):
+    """Starts a job that the test will poll and then kill, with its
+    output in files: nobody drains a pipe while the test polls, and a
+    chatty run (one line per step, runtime warnings on stderr) fills a
+    64 KiB pipe and then blocks in print() before it ever checkpoints."""
+    out = open(str(tmp_path / "killed_run.out"), "w+")
+    err = open(str(tmp_path / "killed_run.err"), "w+")
+    proc = subprocess.Popen(cmd, env=env, stdout=out, stderr=err,
+                            start_new_session=True)
+
+    def output():
+        out.seek(0)
+        err.seek(0)
+        return out.read(), err.read()
+
+    return proc, output
+
+
 def _commit_crcs(out):
     """{step: crc} from a run's commit lines (identical across ranks —
     asserted)."""
@@ -586,16 +609,14 @@ def test_kill_everything_then_relaunch_resumes_bitwise(tmp_path):
     # relaunches run the normal 24 steps (the trajectory is identical
     # either way — total only bounds the loop).
     cmd, env = _launch(ckpt_dir, np_=2, pid_dir=pid_dir, total=200)
-    proc = subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
-                            stderr=subprocess.PIPE, text=True,
-                            start_new_session=True)
+    proc, output = _popen_to_files(cmd, env, tmp_path)
     # Wait for a durable manifest covering a mid-training step.
     deadline = time.monotonic() + 120
     while True:
         manifest, _ = latest_valid_manifest(ckpt_dir)
         if manifest is not None and manifest["step"] >= 8:
             break
-        assert proc.poll() is None, proc.communicate()
+        assert proc.poll() is None, output()
         assert time.monotonic() < deadline, "no durable manifest in 120s"
         time.sleep(0.1)
 
@@ -608,7 +629,8 @@ def test_kill_everything_then_relaunch_resumes_bitwise(tmp_path):
             os.killpg(os.getpgid(pid), signal.SIGKILL)
         except (ProcessLookupError, PermissionError):
             pass
-    out1, _ = proc.communicate(timeout=30)
+    proc.wait(timeout=30)
+    out1, _ = output()
     crcs1 = _commit_crcs(out1)
     assert crcs1, out1
 
@@ -671,15 +693,13 @@ def test_sharded_update_kill_restore_half_and_double_world(tmp_path):
     cmd, env = _launch(ckpt, np_=2, script="sharded_durable_worker.py",
                        pid_dir=pid_dir, total=200,
                        extra_env={"DURABLE_TEST_STEP_SLEEP": "0.1"})
-    proc = subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
-                            stderr=subprocess.PIPE, text=True,
-                            start_new_session=True)
+    proc, output = _popen_to_files(cmd, env, tmp_path)
     deadline = time.monotonic() + 120
     while True:
         manifest, _ = latest_valid_manifest(ckpt)
         if manifest is not None and manifest["step"] >= 6:
             break
-        assert proc.poll() is None, proc.communicate()
+        assert proc.poll() is None, output()
         assert time.monotonic() < deadline, "no durable manifest in 120s"
         time.sleep(0.1)
     os.killpg(os.getpgid(proc.pid), signal.SIGKILL)
@@ -689,7 +709,8 @@ def test_sharded_update_kill_restore_half_and_double_world(tmp_path):
             os.killpg(os.getpgid(pid), signal.SIGKILL)
         except (ProcessLookupError, PermissionError):
             pass
-    out1, _ = proc.communicate(timeout=30)
+    proc.wait(timeout=30)
+    out1, _ = output()
     crcs1 = _commit_crcs(out1)
     assert crcs1, out1
     # The killed run's commits match the uninterrupted run's bitwise.

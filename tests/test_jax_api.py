@@ -8,7 +8,7 @@ import numpy as np
 import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import horovod_tpu.jax as hvd
 
@@ -100,7 +100,7 @@ def test_injit_allgather():
     mesh = Mesh(np.array(devices), (hvd.AXIS_NAME,))
     f = shard_map(lambda x: hvd.allgather(x), mesh=mesh,
                   in_specs=P(hvd.AXIS_NAME), out_specs=P(),
-                  check_rep=False)
+                  check_vma=False)
     x = jnp.arange(8, dtype=jnp.float32).reshape(8, 1)
     out = jax.jit(f)(x)
     assert out.shape == (8, 1)
@@ -220,10 +220,8 @@ def test_w2v_sparse_step_matches_dense_mesh():
 
     outs = {}
     for sparse in (True, False):
-        # donate=False: old jaxlib CPU runtimes flakily recycle donated
-        # buffers mid-scan (garbage outputs) — equivalence needs
-        # deterministic inputs, and donation is a memory optimization,
-        # not part of the semantics under test.
+        # donate=False: donation is a memory optimization, not part
+        # of the semantics under test.
         step = w2v_make_step(mesh, n, sparse, num_iters=3, donate=False)
         outs[sparse] = step(*tables(), center, context, neg)
 

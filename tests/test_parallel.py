@@ -185,14 +185,7 @@ def test_ring_flash_backward_multiblock(monkeypatch, causal):
         lambda q, k, v: jax.grad(loss, argnums=(0, 1, 2))(q, k, v),
         mesh=mesh, in_specs=(P(None, "sp"),) * 3,
         out_specs=(P(None, "sp"),) * 3, check_vma=False))
-    try:
-        gq, gk, gv = f(q, k, v)
-    except Exception as e:  # jaxlib.xla_extension.XlaRuntimeError
-        if "PartitionId instruction is not supported" in str(e):
-            # Old XLA: the SPMD partitioner rejects partition-id in this
-            # lowering; the causal variant (and real TPU lowering) work.
-            pytest.skip("old jaxlib cannot SPMD-partition this lowering")
-        raise
+    gq, gk, gv = f(q, k, v)
 
     def dense_loss(q, k, v):
         return jnp.sum(_dense_reference(q, k, v, causal) ** 2)
