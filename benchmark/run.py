@@ -1,0 +1,454 @@
+#!/usr/bin/env python3
+"""One run of one cell of the benchmark.
+
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+Run from the root of a checkout. Finds the cell in `BENCHMARK.json`, its
+configuration file, its traffic file (`benchmark/traffic/<traffic>.json`),
+the configuration's builder (`benchmark/builders/<builder>.py`) and, in a
+traced run, one reader per per-layer metric
+(`benchmark/layer_metrics/<metric>.py`) — all by name, so this file holds
+no cell, configuration, traffic or per-layer metric name. It does hold the
+four end-to-end metrics (`measured`, in `main`): they are what this loop
+measures, and only a `benchmark` PR, which may edit this file, adds one.
+The last line of stdout is the result; the lines before it that start with
+`INFO ` are for people.
+
+Without a TPU, or with fewer chips than the cell asks for, the command
+fails and prints no result. `--rehearse` is the one exception, for the
+control flow on the CPU: it applies the `rehearse` overrides of the
+configuration and the traffic file (tiny sizes), and its result line
+carries no metric at all.
+"""
+
+import time
+
+T_PROCESS = time.perf_counter()
+
+import argparse  # noqa: E402
+import importlib.util  # noqa: E402
+import json  # noqa: E402
+import math  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import sys  # noqa: E402
+from statistics import median  # noqa: E402
+
+BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(BENCH_DIR)
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+GIB = float(1 << 30)
+WARMUP_STEPS = 3
+TRACE_MIN_STEPS = 10
+TRACE_SECONDS = 2.0
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def info(**kw):
+    print("INFO " + json.dumps(kw, sort_keys=True), flush=True)
+
+
+def load_json(path):
+    with open(path) as f:
+        return json.load(f)
+
+
+def load_plugin(kind, name):
+    """The module `benchmark/<kind>/<name>.py`."""
+    path = os.path.join(BENCH_DIR, kind, name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit("benchmark: no %s named %r (%s)" % (kind, name, path))
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_%s_%s" % (kind, name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def find_cell(manifest, name):
+    """(cell, configuration entry) of the workload `name`."""
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    if name not in cells:
+        raise SystemExit("benchmark: no workload %r in BENCHMARK.json (have %s)"
+                         % (name, ", ".join(sorted(cells))))
+    cell = cells[name]
+    configs = {c["name"]: c for c in manifest["configs"]}
+    return cell, configs[cell["config"]]
+
+
+def metrics_of(manifest, group, cell_name):
+    """The metrics of `group` that this cell reports."""
+    return [m for m in manifest[group]
+            if "workloads" not in m or cell_name in m["workloads"]]
+
+
+def with_rehearsal(spec, rehearse):
+    """`spec` with its `rehearse` overrides applied (tiny sizes for a CPU
+    run of the control flow), or as it is."""
+    spec = dict(spec)
+    over = spec.pop("rehearse", {})
+    if rehearse:
+        spec.update(over)
+    return spec
+
+
+def peaks_for(kind):
+    table = load_json(os.path.join(BENCH_DIR, "peaks.json"))
+    if kind not in table["devices"]:
+        raise SystemExit("benchmark: device_kind %r is not in peaks.json; add "
+                         "its published peaks and their source first" % kind)
+    return table["devices"][kind]
+
+
+def program_needles(config, chips):
+    """Strings the compiled step's text must hold: the configuration's own
+    (its kernels), and an all-reduce wherever the step spans chips."""
+    return list(config.get("program_must_contain", [])) + (
+        ["all-reduce"] if chips > 1 else [])
+
+
+def percentile(values, q):
+    """Nearest-rank percentile: a value that was measured."""
+    s = sorted(values)
+    return s[max(0, math.ceil(q * len(s)) - 1)]
+
+
+def memory_gib(mem):
+    """`compiled.memory_analysis()` per device in GiB; `step` is what the
+    executable needs while it runs: argument + output + temporary - aliased."""
+    parts = {"argument": mem.argument_size_in_bytes,
+             "output": mem.output_size_in_bytes,
+             "temp": mem.temp_size_in_bytes,
+             "alias": mem.alias_size_in_bytes}
+    parts["step"] = (parts["argument"] + parts["output"] + parts["temp"]
+                     - parts["alias"])
+    return {k: v / GIB for k, v in parts.items()}
+
+
+def replica_checksums(tree, devices):
+    """For each device the wrap-around sum of the bit patterns of its copy
+    of every leaf of `tree`: equal numbers mean bit-identical replicas."""
+    import jax
+    import jax.numpy as jnp
+
+    unsigned = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}
+
+    @jax.jit
+    def checksum(leaves):
+        return sum(jnp.sum(jax.lax.bitcast_convert_type(
+            x, unsigned[x.dtype.itemsize]).astype(jnp.uint32),
+            dtype=jnp.uint32) for x in leaves)
+
+    leaves = jax.tree_util.tree_leaves(tree)
+    sums = []
+    for d in devices:
+        mine = [next(s.data for s in leaf.addressable_shards if s.device == d)
+                for leaf in leaves]
+        sums.append(int(checksum(mine)))
+    return sums
+
+
+class Loop:
+    """The closed loop every cell runs: one resident batch, one step in
+    flight. Step k is dispatched, then step k-1's loss is waited for and the
+    clock is stamped, so the host's dispatch overlaps the device as it does
+    in a training loop. `losses` keeps every loss on the device."""
+
+    def __init__(self, step, state):
+        self.step = step
+        self.params, self.opt_state, self.batch = state
+        self.losses = []
+
+    def run(self, seconds=None, steps=None, annotate=None):
+        """Runs until `seconds` have passed or `steps` are dispatched.
+        Returns dict(t0, t1, completed, stamps, dispatch_s, wait_s): the
+        clock after every completion, the host seconds inside each
+        `step(...)` call, and the host seconds of each wait for a loss
+        (`wait_s[k]` ends at `stamps[k]`, just after `dispatch_s[k + 1]`)."""
+        import contextlib
+
+        span = annotate or (lambda name: contextlib.nullcontext())
+        stamps, dispatch, wait = [], [], []
+        prev = None
+        t0 = time.perf_counter()
+        while True:
+            with span("bench_dispatch"):
+                d0 = time.perf_counter()
+                self.params, self.opt_state, loss = self.step(
+                    self.params, self.opt_state, self.batch)
+                d1 = time.perf_counter()
+            dispatch.append(d1 - d0)
+            self.losses.append(loss)
+            if prev is not None:
+                with span("bench_wait_loss"):
+                    prev.block_until_ready()
+                stamps.append(time.perf_counter())
+                wait.append(stamps[-1] - d1)
+            prev = loss
+            if (steps is not None and len(dispatch) >= steps) or (
+                    seconds is not None and d1 - t0 >= seconds):
+                break
+        w0 = time.perf_counter()
+        with span("bench_wait_loss"):
+            prev.block_until_ready()
+        stamps.append(time.perf_counter())
+        wait.append(stamps[-1] - w0)
+        return dict(t0=t0, t1=stamps[-1], completed=len(stamps),
+                    stamps=stamps, dispatch_s=dispatch, wait_s=wait)
+
+
+class Setup:
+    """Seconds of set-up by what was done, from the start of the process."""
+
+    def __init__(self):
+        self.phases = {}
+        self._mark = T_PROCESS
+
+    def done(self, name):
+        now = time.perf_counter()
+        self.phases[name] = now - self._mark
+        self._mark = now
+
+
+def parse_args():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--rehearse", action="store_true",
+                    help="CPU run of the control flow at the files' "
+                    "`rehearse` sizes; prints no metric")
+    return ap.parse_args()
+
+
+def find_devices(chips, rehearse):
+    """(all devices, the ones this cell uses), or no run at all."""
+    import jax
+
+    devices = jax.devices()
+    if not rehearse and devices[0].platform != "tpu":
+        raise SystemExit("benchmark: JAX found no TPU (%d x %s); this "
+                         "benchmark measures the chip and does not fall "
+                         "back" % (len(devices), devices[0].platform))
+    if len(devices) < chips:
+        raise SystemExit("benchmark: the cell needs %d chips, JAX reports %d"
+                         % (chips, len(devices)))
+    return devices, devices[:chips]
+
+
+def trace_steps(loop, n_steps, trace_dir):
+    """Runs `n_steps` of the loop under the profiler, with the loop's own
+    spans on the host plane, and returns the reduced trace."""
+    import jax
+
+    from benchmark import trace_reduce
+
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # the loop's spans only, not every call
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        ran = loop.run(steps=n_steps, annotate=jax.profiler.TraceAnnotation)
+    finally:
+        jax.profiler.stop_trace()
+    trace = trace_reduce.load(trace_reduce.find_xplane(trace_dir))
+    info(steps_in_traced_loop=ran["completed"],
+         programs_in_trace=trace.modules)
+    return trace
+
+
+def layer_metrics(manifest, cell, trace, context):
+    """(metrics, device fields, breakdown) of a traced run."""
+    from benchmark import trace_reduce as tr
+
+    metrics = {}
+    for m in metrics_of(manifest, "per_layer", cell["name"]):
+        value = load_plugin("layer_metrics", m["name"]).read(trace, context)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    device = {
+        "busy_s": tr.mean_over_devices(trace, tr.busy) / 1e9,
+        "window_s": tr.mean_over_devices(
+            trace, lambda ev: tr.window(ev)[1] - tr.window(ev)[0]) / 1e9}
+    ops = sorted(tr.mean_self_times(trace).items(), key=lambda kv: -kv[1])
+    gaps = tr.idle_gaps(trace.devices[min(trace.devices)], trace.host, top=5)
+    breakdown = {"device_ops": [[k, v / 1e9] for k, v in ops[:10]],
+                 "idle_gaps": [[k, v / 1e9] for k, v in gaps]}
+    return metrics, device, breakdown
+
+
+def main():
+    args = parse_args()
+    manifest = load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    cell, config_entry = find_cell(manifest, args.workload)
+    config = with_rehearsal(load_json(os.path.join(ROOT, config_entry["file"])),
+                            args.rehearse)
+    traffic = with_rehearsal(load_json(os.path.join(
+        BENCH_DIR, "traffic", cell["traffic"] + ".json")), args.rehearse)
+    chips = int(cell["chips"])
+    setup = Setup()
+
+    from horovod_tpu.run.util import use_compile_cache
+
+    cache_dir = use_compile_cache()
+    if args.rehearse and chips > 1:
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + " --xla_force_host_platform_device_count=%d" % chips)
+
+    import jax
+
+    devices, used = find_devices(chips, args.rehearse)
+    on_chip = devices[0].platform == "tpu"
+    peaks = None if args.rehearse else peaks_for(devices[0].device_kind)
+    compiles, cache_events = [], []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **kw: compiles.append(name)
+        if name == COMPILE_EVENT else None)
+    jax.monitoring.register_event_listener(
+        lambda name, **kw: cache_events.append(name))
+    setup.done("import_jax_and_find_devices")
+
+    import horovod_tpu as hvd
+    from horovod_tpu import parallel
+
+    hvd.init()
+    setup.done("hvd_init")
+    mesh = parallel.data_parallel_mesh(devices=used)
+    built = load_plugin("builders", config["builder"]).build(
+        config, traffic, mesh, args.seed)
+    step = built["step"]
+    state = step.place(*built["state"])
+    jax.block_until_ready(state)
+    setup.done("build_state_on_device")
+    # Compiled ahead of the first call only to be read (its bytes and its
+    # text). The loop calls `step(...)` itself; jit keeps the executable
+    # with the lowering, so that call runs this one and compiles nothing
+    # (the cache counts below show one compile or load of the step).
+    compiled = step.lower(*state).compile()
+    mem_gib = memory_gib(compiled.memory_analysis())
+    checks = []  # (what, ok, detail)
+    text = compiled.as_text()
+    for needle in program_needles(config, chips):
+        n = text.count(needle)
+        checks.append(("program text holds %r" % needle, n > 0 or not on_chip,
+                       "%d times%s" % (n, "" if on_chip else
+                                       " (not a TPU program: not required)")))
+    del compiled, text
+    setup.done("compile_or_load_step")
+
+    loop = Loop(step, state)
+    del state
+    warm = loop.run(steps=WARMUP_STEPS)
+    step_s = (warm["t1"] - warm["stamps"][0]) / (WARMUP_STEPS - 1)
+    misses = cache_events.count("/jax/compilation_cache/cache_misses")
+    hits = cache_events.count("/jax/compilation_cache/cache_hits")
+    setup.done("warm_up")
+    setup_s = time.perf_counter() - T_PROCESS
+
+    # The window. A traced run measures a quarter of it untraced (for the
+    # per-layer metrics that need no trace), then a profiler window.
+    compiles_before = len(compiles)
+    seconds = max(2.0, args.seconds / 4.0) if args.trace else args.seconds
+    win = loop.run(seconds=seconds)
+    compiles_in_window = len(compiles) - compiles_before
+    window_s = win["t1"] - win["t0"]
+    throughput = (built["items_per_step"] * win["completed"]
+                  / window_s / chips)
+    marks = [win["t0"]] + win["stamps"]
+    gaps_ms = [1e3 * (b - a) for a, b in zip(marks, marks[1:])]
+    trace = None
+    if args.trace:
+        trace = trace_steps(
+            loop, max(TRACE_MIN_STEPS, int(TRACE_SECONDS / step_s)),
+            os.path.join(ROOT, ".bench_trace", cell["name"]))
+    # The allocator's own peaks on the fullest chip: what was allocated
+    # (arguments, results, the loop's arrays) and what the runtime reserved
+    # for the executable's temporaries, which it counts apart.
+    stats = max(((d.memory_stats() or {}) for d in used),
+                key=lambda m: m.get("peak_bytes_in_use", 0)
+                + m.get("peak_bytes_reserved", 0))
+    stats_in_use = int(stats.get("peak_bytes_in_use", 0))
+    stats_reserved = int(stats.get("peak_bytes_reserved", 0))
+
+    # Correctness, outside the window.
+    losses = [float(x) for x in jax.device_get(loop.losses)]
+    failed = sum(1 for v in losses if not math.isfinite(v))
+    checks.append(("every loss is finite", failed == 0,
+                   "%d of %d are not" % (failed, len(losses))))
+    first, last = median(losses[:WARMUP_STEPS]), median(losses[-WARMUP_STEPS:])
+    checks.append(("loss falls on the resident batch", last < first,
+                   "%.6f -> %.6f" % (first, last)))
+    checks.append(("no compilation inside the window", compiles_in_window == 0,
+                   "%d" % compiles_in_window))
+    final_params = loop.params
+    del loop
+    if chips > 1:
+        sums = replica_checksums(final_params, used)
+        checks.append(("parameters are bit-identical on all %d chips after "
+                       "the window" % chips, len(set(sums)) == 1,
+                       "checksums %s" % sums))
+    checks.extend(built["verify"](final_params, losses[0]))
+    del final_params
+    hvd.shutdown()
+    for what, ok, detail in checks:
+        info(check=what, ok=bool(ok), detail=detail)
+
+    late = gaps_ms.index(max(gaps_ms))
+    info(cell=cell["name"], seed=args.seed, steps_in_window=win["completed"],
+         window_s=window_s, step_ms_median=median(gaps_ms),
+         step_ms_samples=len(gaps_ms), step_ms_max=gaps_ms[late],
+         step_ms_max_at=late, step_ms_max_wait_ms=1e3 * win["wait_s"][late],
+         step_ms_max_dispatch_ms=1e3 * sum(win["dispatch_s"][
+             0 if late == 0 else late + 1:late + 2]),
+         dispatch_ms_median=1e3 * median(win["dispatch_s"]),
+         compiles_in_window=compiles_in_window, compile_cache=cache_dir,
+         cache_hits=hits, cache_misses=misses, first_run_compiled=misses > 0,
+         setup_s=setup_s, setup_phases_s=setup.phases,
+         memory_stats=stats, memory_analysis_gib=mem_gib,
+         loss_first=losses[0], loss_last=losses[-1])
+
+    result = {"correct": all(ok for _, ok, _ in checks),
+              "attempted": len(losses), "failed": failed, "metrics": {}}
+    # `memory_peak_bytes` is the allocator's reading alone; its two parts
+    # and the executable's own count stand beside it.
+    device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
+              "count": len(devices),
+              "memory_peak_bytes": stats_in_use + stats_reserved,
+              "memory_stats_peak_bytes_in_use": stats_in_use,
+              "memory_stats_peak_bytes_reserved": stats_reserved,
+              "memory_analysis_step_bytes": int(mem_gib["step"] * GIB)}
+    if args.rehearse:
+        info(rehearsal="sizes are the files' `rehearse` overrides; no metric "
+             "is printed", traced_devices=sorted(trace.devices) if trace
+             else None)
+    elif args.trace:
+        context = {"cell": cell, "config": config, "traffic": traffic,
+                   "chips": chips, "peaks": peaks, "counts": built["counts"],
+                   "throughput": throughput,
+                   "steps_traced": trace.modules[min(trace.modules)],
+                   "dispatch_s": win["dispatch_s"], "gaps_ms": gaps_ms,
+                   "memory_stats_peak_bytes": stats_in_use + stats_reserved}
+        result["metrics"], traced_device, result["breakdown"] = \
+            layer_metrics(manifest, cell, trace, context)
+        device.update(traced_device)
+    else:
+        measured = {"throughput": throughput,
+                    "step_ms_p95": percentile(gaps_ms, 0.95),
+                    "peak_hbm_gib": mem_gib["step"], "setup_s": setup_s}
+        for m in metrics_of(manifest, "end_to_end", cell["name"]):
+            if m["name"] not in measured:
+                raise SystemExit("benchmark: the harness does not measure "
+                                 "the end-to-end metric %r" % m["name"])
+            result["metrics"][m["name"]] = {"value": measured[m["name"]],
+                                            "unit": m["unit"]}
+    result["device"] = device
+    print(json.dumps(result), flush=True)
+
+
+if __name__ == "__main__":
+    main()
