@@ -2630,16 +2630,18 @@ def main():
         params_p, opt_state, loss = step(params_p, opt_state, batch)
     jax.block_until_ready(loss)
 
-    # Optional profiler hook (examples/profile_step.py): trace a
-    # separate burst of steps BEFORE the timed rounds so trace
-    # collection overhead never contaminates the reported numbers.
+    # Optional profiler hook, through the program's one control for the
+    # profiler (hvd.profile): trace a separate burst of steps BEFORE the
+    # timed rounds so trace collection overhead never contaminates the
+    # reported numbers.
     profile_dir = os.environ.get("HVD_TPU_PROFILE_DIR")
     if profile_dir:
-        jax.profiler.start_trace(profile_dir)
+        from horovod_tpu import profile
+        profile.start(profile_dir)
         for _ in range(args.num_iters):
             params_p, opt_state, loss = step(params_p, opt_state, batch)
         jax.block_until_ready(loss)
-        jax.profiler.stop_trace()
+        print("trace: %s" % profile.stop(), file=sys.stderr)
 
     rates = []
     for r in range(args.num_rounds):

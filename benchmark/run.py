@@ -1,16 +1,19 @@
 #!/usr/bin/env python3
 """One run of one cell of the benchmark.
 
-    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 Run from the root of a checkout. Finds the cell in `BENCHMARK.json`, its
 configuration file, its traffic file (`benchmark/traffic/<traffic>.json`),
 the configuration's builder (`benchmark/builders/<builder>.py`) and, in a
-traced run, one reader per per-layer metric
-(`benchmark/layer_metrics/<metric>.py`) — all by name, so this file holds
-no cell, configuration, traffic or per-layer metric name. It does hold the
-four end-to-end metrics (`measured`, in `main`): they are what this loop
-measures, and only a `benchmark` PR, which may edit this file, adds one.
+traced run (`--trace 1`: a run of its own; `--trace 2`: a `--trace 0` run
+that, once its window has closed and its numbers are taken, traces a few
+more steps in the same process and prints both kinds of metric), one reader
+per per-layer metric (`benchmark/layer_metrics/<metric>.py`) — all by name,
+so this file holds no cell, configuration, traffic or per-layer metric name.
+It does hold the four end-to-end metrics (`measured`, in `main`): they are
+what this loop measures, and only a `benchmark` PR, which may edit this
+file, adds one.
 The last line of stdout is the result; the lines before it that start with
 `INFO ` are for people.
 
@@ -217,7 +220,7 @@ def parse_args():
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU run of the control flow at the files' "
                     "`rehearse` sizes; prints no metric")
@@ -240,25 +243,69 @@ def find_devices(chips, rehearse):
 
 
 def trace_steps(loop, n_steps, trace_dir):
-    """Runs `n_steps` of the loop under the profiler, with the loop's own
-    spans on the host plane, and returns the reduced trace."""
-    import jax
+    """Runs `n_steps` of the loop under the profiler (`hvd.profile`: the
+    program's one control for it), with the loop's own spans on the host
+    plane. Returns the reduced trace and what tracing cost: the seconds
+    that `start`, the steps, `stop` and loading the trace took, and the
+    median step inside the profiler window."""
+    import horovod_tpu as hvd
 
     from benchmark import trace_reduce
 
     shutil.rmtree(trace_dir, ignore_errors=True)
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0  # the loop's spans only, not every call
-    options.host_tracer_level = 2
-    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    t0 = time.perf_counter()
+    hvd.profile.start(trace_dir)
+    t1 = time.perf_counter()
     try:
-        ran = loop.run(steps=n_steps, annotate=jax.profiler.TraceAnnotation)
+        ran = loop.run(steps=n_steps, annotate=hvd.profile.span)
     finally:
-        jax.profiler.stop_trace()
-    trace = trace_reduce.load(trace_reduce.find_xplane(trace_dir))
+        t2 = time.perf_counter()
+        path = hvd.profile.stop()
+    t3 = time.perf_counter()
+    trace = trace_reduce.load(path)
     info(steps_in_traced_loop=ran["completed"],
          programs_in_trace=trace.modules)
-    return trace
+    stamps = ran["stamps"]
+    took = {"start_s": t1 - t0, "steps_s": t2 - t1, "stop_s": t3 - t2,
+            "load_s": time.perf_counter() - t3,
+            "step_ms_median_traced": median(
+                1e3 * (b - a) for a, b in zip(stamps, stamps[1:]))}
+    return trace, took
+
+
+def trace_after_window(loop, n_steps, trace_dir):
+    """`--trace 2`, after the measured window has closed: starts and stops
+    the profiler once and throws that trace away, so that what the first
+    start costs falls into no number; then `trace_steps`. Returns the
+    reduced trace and, for the INFO line, what tracing cost."""
+    import horovod_tpu as hvd
+
+    t0 = time.perf_counter()
+    hvd.profile.start(trace_dir + ".first")
+    hvd.profile.stop()
+    shutil.rmtree(trace_dir + ".first", ignore_errors=True)
+    first_s = time.perf_counter() - t0
+    trace, took = trace_steps(loop, n_steps, trace_dir)
+    return trace, dict(took, first_start_and_stop_s=first_s)
+
+
+def allocator_peaks(devices):
+    """`memory_stats()` of the fullest chip: the allocator's own peaks of
+    what was allocated (arguments, results, the loop's arrays) and of what
+    the runtime reserved for the executable's temporaries, which it counts
+    apart."""
+    return max(((d.memory_stats() or {}) for d in devices),
+               key=lambda m: m.get("peak_bytes_in_use", 0)
+               + m.get("peak_bytes_reserved", 0))
+
+
+def long_gaps(gaps_ms):
+    """(median gap, gaps over 1.5 times the median, their summed excess
+    over the median in ms): whether a window that reads low lost its time
+    in a few long waits or in every step."""
+    mid = median(gaps_ms)
+    over = [g for g in gaps_ms if g > 1.5 * mid]
+    return mid, len(over), sum(g - mid for g in over)
 
 
 def layer_metrics(manifest, cell, trace, context):
@@ -350,10 +397,14 @@ def main():
     setup.done("warm_up")
     setup_s = time.perf_counter() - T_PROCESS
 
-    # The window. A traced run measures a quarter of it untraced (for the
+    # The window. `--trace 1` measures a quarter of it untraced (for the
     # per-layer metrics that need no trace), then a profiler window.
+    # `--trace 0` and `--trace 2` share every statement down to the
+    # allocator's peaks: only after those does `--trace 2` touch the
+    # profiler, so the two kinds of run read alike.
     compiles_before = len(compiles)
-    seconds = max(2.0, args.seconds / 4.0) if args.trace else args.seconds
+    seconds = max(2.0, args.seconds / 4.0) if args.trace == 1 \
+        else args.seconds
     win = loop.run(seconds=seconds)
     compiles_in_window = len(compiles) - compiles_before
     window_s = win["t1"] - win["t0"]
@@ -361,22 +412,28 @@ def main():
                   / window_s / chips)
     marks = [win["t0"]] + win["stamps"]
     gaps_ms = [1e3 * (b - a) for a, b in zip(marks, marks[1:])]
-    trace = None
-    if args.trace:
-        trace = trace_steps(
-            loop, max(TRACE_MIN_STEPS, int(TRACE_SECONDS / step_s)),
-            os.path.join(ROOT, ".bench_trace", cell["name"]))
-    # The allocator's own peaks on the fullest chip: what was allocated
-    # (arguments, results, the loop's arrays) and what the runtime reserved
-    # for the executable's temporaries, which it counts apart.
-    stats = max(((d.memory_stats() or {}) for d in used),
-                key=lambda m: m.get("peak_bytes_in_use", 0)
-                + m.get("peak_bytes_reserved", 0))
-    stats_in_use = int(stats.get("peak_bytes_in_use", 0))
-    stats_reserved = int(stats.get("peak_bytes_reserved", 0))
+    step_ms_p95 = percentile(gaps_ms, 0.95)
+    trace, counted = None, None  # `counted`: the losses the checks count
+    trace_dir = os.path.join(ROOT, ".bench_trace", cell["name"])
+    traced_steps = max(TRACE_MIN_STEPS, int(TRACE_SECONDS / step_s))
+    if args.trace == 1:
+        trace, _ = trace_steps(loop, traced_steps, trace_dir)
+    stats = allocator_peaks(used)
+    # What `hbm_stats_gib` reads: taken before `--trace 2` starts the
+    # profiler.
+    stats_peak_bytes = int(stats.get("peak_bytes_in_use", 0)
+                           + stats.get("peak_bytes_reserved", 0))
+    if args.trace == 2:
+        # The traced steps' losses take no part in `attempted`, `failed`
+        # and the checks on the losses: those are the closed window's.
+        counted = len(loop.losses)
+        trace, tracing = trace_after_window(loop, traced_steps, trace_dir)
+        stats = allocator_peaks(used)  # the peaks of the whole run
+        info(tracing=tracing, compiles_while_tracing=len(compiles)
+             - compiles_before - compiles_in_window)
 
     # Correctness, outside the window.
-    losses = [float(x) for x in jax.device_get(loop.losses)]
+    losses = [float(x) for x in jax.device_get(loop.losses[:counted])]
     failed = sum(1 for v in losses if not math.isfinite(v))
     checks.append(("every loss is finite", failed == 0,
                    "%d of %d are not" % (failed, len(losses))))
@@ -399,8 +456,11 @@ def main():
         info(check=what, ok=bool(ok), detail=detail)
 
     late = gaps_ms.index(max(gaps_ms))
+    gap_median, gaps_long, gaps_long_excess_ms = long_gaps(gaps_ms)
     info(cell=cell["name"], seed=args.seed, steps_in_window=win["completed"],
-         window_s=window_s, step_ms_median=median(gaps_ms),
+         window_s=window_s, step_ms_median=gap_median,
+         step_ms_long_gaps=gaps_long,
+         step_ms_long_gaps_excess_ms=gaps_long_excess_ms,
          step_ms_samples=len(gaps_ms), step_ms_max=gaps_ms[late],
          step_ms_max_at=late, step_ms_max_wait_ms=1e3 * win["wait_s"][late],
          step_ms_max_dispatch_ms=1e3 * sum(win["dispatch_s"][
@@ -415,37 +475,50 @@ def main():
     result = {"correct": all(ok for _, ok, _ in checks),
               "attempted": len(losses), "failed": failed, "metrics": {}}
     # `memory_peak_bytes` is the allocator's reading alone; its two parts
-    # and the executable's own count stand beside it.
+    # and the executable's own count stand beside it. In a `--trace 2` run
+    # it is the peak of the whole run, the traced steps included.
+    stats_in_use = int(stats.get("peak_bytes_in_use", 0))
+    stats_reserved = int(stats.get("peak_bytes_reserved", 0))
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": len(devices),
               "memory_peak_bytes": stats_in_use + stats_reserved,
               "memory_stats_peak_bytes_in_use": stats_in_use,
               "memory_stats_peak_bytes_reserved": stats_reserved,
               "memory_analysis_step_bytes": int(mem_gib["step"] * GIB)}
-    if args.rehearse:
-        info(rehearsal="sizes are the files' `rehearse` overrides; no metric "
-             "is printed", traced_devices=sorted(trace.devices) if trace
-             else None)
-    elif args.trace:
-        context = {"cell": cell, "config": config, "traffic": traffic,
-                   "chips": chips, "peaks": peaks, "counts": built["counts"],
-                   "throughput": throughput,
-                   "steps_traced": trace.modules[min(trace.modules)],
-                   "dispatch_s": win["dispatch_s"], "gaps_ms": gaps_ms,
-                   "memory_stats_peak_bytes": stats_in_use + stats_reserved}
-        result["metrics"], traced_device, result["breakdown"] = \
-            layer_metrics(manifest, cell, trace, context)
-        device.update(traced_device)
-    else:
-        measured = {"throughput": throughput,
-                    "step_ms_p95": percentile(gaps_ms, 0.95),
+    end_to_end = {}
+    if args.trace != 1:
+        measured = {"throughput": throughput, "step_ms_p95": step_ms_p95,
                     "peak_hbm_gib": mem_gib["step"], "setup_s": setup_s}
         for m in metrics_of(manifest, "end_to_end", cell["name"]):
             if m["name"] not in measured:
                 raise SystemExit("benchmark: the harness does not measure "
                                  "the end-to-end metric %r" % m["name"])
-            result["metrics"][m["name"]] = {"value": measured[m["name"]],
-                                            "unit": m["unit"]}
+            end_to_end[m["name"]] = {"value": measured[m["name"]],
+                                     "unit": m["unit"]}
+    if args.rehearse:
+        info(rehearsal="sizes are the files' `rehearse` overrides; no metric "
+             "is printed", traced_devices=sorted(trace.devices) if trace
+             else None)
+    elif trace is not None:
+        # `throughput`, `dispatch_s` and `gaps_ms` are the untraced
+        # window's: a quarter of `--seconds` under `--trace 1`, all of it
+        # under `--trace 2`.
+        context = {"cell": cell, "config": config, "traffic": traffic,
+                   "chips": chips, "peaks": peaks, "counts": built["counts"],
+                   "throughput": throughput,
+                   "steps_traced": trace.modules[min(trace.modules)],
+                   "dispatch_s": win["dispatch_s"], "gaps_ms": gaps_ms,
+                   "memory_stats_peak_bytes": stats_peak_bytes}
+        t0 = time.perf_counter()
+        per_layer, traced_device, result["breakdown"] = \
+            layer_metrics(manifest, cell, trace, context)
+        info(reducing_the_trace_s=time.perf_counter() - t0)
+        result["metrics"] = dict(end_to_end, **per_layer)
+        device.update(traced_device)
+    else:
+        result["metrics"] = end_to_end
+    if args.trace == 2:
+        shutil.rmtree(trace_dir, ignore_errors=True)
     result["device"] = device
     print(json.dumps(result), flush=True)
 

@@ -23,8 +23,10 @@ def manifest():
 
 
 def test_keys_and_limits(manifest):
-    assert set(manifest) == {"command", "paths", "run_seconds", "configs",
-                             "workloads", "end_to_end", "per_layer"}
+    assert set(manifest) - {"trace_in_run"} == {
+        "command", "paths", "run_seconds", "configs", "workloads",
+        "end_to_end", "per_layer"}
+    assert manifest.get("trace_in_run", True) is True
     assert 1 <= manifest["run_seconds"] <= 51
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 << 10
     for p in manifest["paths"]:

@@ -33,6 +33,7 @@ from .groups import (  # noqa: F401
     group_size,
     new_group,
 )
+from . import profile  # noqa: F401
 
 __version__ = "0.4.0"
 

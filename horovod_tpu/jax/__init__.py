@@ -24,6 +24,7 @@ import numpy as np
 
 import horovod_tpu as _hvd
 from horovod_tpu import compression as _wire
+from horovod_tpu import profile as _profile
 from horovod_tpu import (  # noqa: F401
     init, shutdown, is_initialized, rank, local_rank, cross_rank, size,
     local_size, cross_size, is_homogeneous,
@@ -385,9 +386,12 @@ def allreduce_gradients(grads, average=True, name_prefix="grad",
     mode = _wire.Compression.none if legacy else _wire.resolve(compression)
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     if leaves and _is_traced(leaves[0]):
-        reduced = [allreduce(g, average=average, axis_name=axis_name,
-                             compression=compression, group=group)
-                   for g in leaves]
+        # Named for the profiler (hvd.profile): the collectives and what
+        # is fused around them (compression casts, the divide).
+        with jax.named_scope(_profile.GRAD_SYNC):
+            reduced = [allreduce(g, average=average, axis_name=axis_name,
+                                 compression=compression, group=group)
+                       for g in leaves]
         return jax.tree_util.tree_unflatten(treedef, reduced)
     # Host path: enqueue everything first so the core can fuse within a
     # cycle, then synchronize in order.
@@ -505,17 +509,18 @@ def DistributedOptimizer(optimizer, compression=None,
                                       name_prefix=name_prefix,
                                       compression=compression,
                                       axis_name=axis_name, group=grp)
-        if agc is not None:
-            # Clip AFTER the reduction: the threshold applies to the
-            # true global gradient, and every rank clips identically.
-            from horovod_tpu.ops.agc import agc_clip
-            if params is None:
-                raise ValueError(
-                    "agc= needs params: call update(grads, state, "
-                    "params) — the clip threshold is relative to each "
-                    "parameter's unit-wise norm")
-            updates = agc_clip(updates, params, clipping=agc)
-        return optimizer.update(updates, state, params)
+        with jax.named_scope(_profile.OPTIMIZER):
+            if agc is not None:
+                # Clip AFTER the reduction: the threshold applies to the
+                # true global gradient, and every rank clips identically.
+                from horovod_tpu.ops.agc import agc_clip
+                if params is None:
+                    raise ValueError(
+                        "agc= needs params: call update(grads, state, "
+                        "params) — the clip threshold is relative to each "
+                        "parameter's unit-wise norm")
+                updates = agc_clip(updates, params, clipping=agc)
+            return optimizer.update(updates, state, params)
 
     return optax.GradientTransformation(init_fn, update_fn)
 

@@ -19,7 +19,10 @@ from functools import partial
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+
+from horovod_tpu import profile
 
 ModuleDef = Any
 
@@ -174,24 +177,30 @@ class ResNet(nn.Module):
             from horovod_tpu.ops.batch_norm import bn_remat_policy
             block_cls = nn.remat(block_cls, policy=bn_remat_policy())
 
-        x = x.astype(self.dtype)
-        x = conv(self.num_filters, (7, 7), (2, 2), padding=[(3, 3), (3, 3)],
-                 name="conv_init")(x)
-        if norm_act is not None:
-            x = norm_act(name="bn_init")(x)
-        else:
-            x = act(norm(name="bn_init")(x))
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))
+        # The scopes are the profiler's names for the model's parts
+        # (hvd.profile); flax's module names sit inside them.
+        with jax.named_scope(profile.STEM):
+            x = x.astype(self.dtype)
+            x = conv(self.num_filters, (7, 7), (2, 2),
+                     padding=[(3, 3), (3, 3)], name="conv_init")(x)
+            if norm_act is not None:
+                x = norm_act(name="bn_init")(x)
+            else:
+                x = act(norm(name="bn_init")(x))
+            x = nn.max_pool(x, (3, 3), strides=(2, 2),
+                            padding=((1, 1), (1, 1)))
         for i, block_size in enumerate(self.stage_sizes):
-            for j in range(block_size):
-                strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                x = block_cls(self.num_filters * 2 ** i, conv=conv,
-                              norm=norm, act=act, strides=strides,
-                              norm_act=norm_act)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=self.dtype,
-                     param_dtype=jnp.float32)(x)
-        return x.astype(jnp.float32)
+            with jax.named_scope(profile.STAGES[i]):
+                for j in range(block_size):
+                    strides = (2, 2) if i > 0 and j == 0 else (1, 1)
+                    x = block_cls(self.num_filters * 2 ** i, conv=conv,
+                                  norm=norm, act=act, strides=strides,
+                                  norm_act=norm_act)(x)
+        with jax.named_scope(profile.HEAD):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=self.dtype,
+                         param_dtype=jnp.float32)(x)
+            return x.astype(jnp.float32)
 
 
 ResNet18 = partial(ResNet, stage_sizes=[2, 2, 2, 2], block_cls=ResNetBlock)

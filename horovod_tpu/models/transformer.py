@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu import profile
 from horovod_tpu.parallel.ring import ring_attention, ulysses_attention
 
 
@@ -199,15 +200,20 @@ class Block(nn.Module):
                        top_k=cfg.moe_top_k, dtype=cfg.dtype,
                        name="moe_mlp")(h)
             return x + h
-        h = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, param_dtype=jnp.float32,
-                     use_bias=False, name="mlp_in")(h)
-        h = nn.silu(h)
-        h = nn.Dense(cfg.embed_dim, dtype=cfg.dtype, param_dtype=jnp.float32,
-                     use_bias=False, name="mlp_out")(h)
-        if cfg.tp_axis is not None:
-            # Column-parallel mlp_in -> row-parallel mlp_out: the out
-            # product over the local hidden slice is a partial sum.
-            h = lax.psum(h, cfg.tp_axis)
+        # `mlp` beside flax's `attn`: the profiler's scope for this half
+        # of the block (hvd.profile), no module and no parameter name.
+        with jax.named_scope("mlp"):
+            h = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype,
+                         param_dtype=jnp.float32, use_bias=False,
+                         name="mlp_in")(h)
+            h = nn.silu(h)
+            h = nn.Dense(cfg.embed_dim, dtype=cfg.dtype,
+                         param_dtype=jnp.float32, use_bias=False,
+                         name="mlp_out")(h)
+            if cfg.tp_axis is not None:
+                # Column-parallel mlp_in -> row-parallel mlp_out: the out
+                # product over the local hidden slice is a partial sum.
+                h = lax.psum(h, cfg.tp_axis)
         return x + h
 
 
@@ -229,17 +235,23 @@ class Transformer(nn.Module):
             positions = jnp.broadcast_to(
                 jnp.arange(tokens.shape[1], dtype=jnp.int32)[None],
                 tokens.shape)
-        x = nn.Embed(cfg.vocab_size, cfg.embed_dim, param_dtype=jnp.float32,
-                     dtype=cfg.dtype, name="embed")(tokens)
+        # The scopes are the profiler's names for the model's parts
+        # (hvd.profile); flax's module names (`block_3/attn`) sit inside.
+        with jax.named_scope(profile.EMBED):
+            x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
+                         param_dtype=jnp.float32, dtype=cfg.dtype,
+                         name="embed")(tokens)
         for i in range(cfg.num_layers):
             moe = (cfg.moe_experts is not None and
                    i % cfg.moe_every == cfg.moe_every - 1)
-            x = Block(cfg, moe=moe, name="block_%d" % i)(x, positions)
-        x = nn.RMSNorm(dtype=cfg.dtype, param_dtype=jnp.float32,
-                       name="norm_f")(x)
-        if return_hidden:
-            return x
-        logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
-                          param_dtype=jnp.float32, use_bias=False,
-                          name="lm_head")(x)
-        return logits.astype(jnp.float32)
+            with jax.named_scope(profile.BLOCK):
+                x = Block(cfg, moe=moe, name="block_%d" % i)(x, positions)
+        with jax.named_scope(profile.HEAD):
+            x = nn.RMSNorm(dtype=cfg.dtype, param_dtype=jnp.float32,
+                           name="norm_f")(x)
+            if return_hidden:
+                return x
+            logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
+                              param_dtype=jnp.float32, use_bias=False,
+                              name="lm_head")(x)
+            return logits.astype(jnp.float32)

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from horovod_tpu import profile
 
 # ~16 MB VMEM/core; blocks are double-buffered (and the grad kernel
 # reads two operands), so stay well under: 4 MB for the one-input
@@ -120,6 +121,7 @@ def batch_norm_stats(x2d, interpret=False, block_m=None):
     xp = x2d.reshape(Mp, Cp) if k > 1 else x2d
     out = pl.pallas_call(
         _stats_kernel,
+        name=profile.BN_STATS,
         grid=(Mp // bm,),
         in_specs=[pl.BlockSpec((bm, Cp), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((2, Cp), lambda i: (0, 0)),
@@ -162,6 +164,7 @@ def batch_norm_grad_stats(dy2d, x2d, mean, rstd, interpret=False,
     rstdp = jnp.tile(rstd, k) if k > 1 else rstd
     out = pl.pallas_call(
         _grad_stats_kernel,
+        name=profile.BN_GRAD_STATS,
         grid=(Mp // bm,),
         in_specs=[
             pl.BlockSpec((bm, Cp), lambda i: (i, 0)),

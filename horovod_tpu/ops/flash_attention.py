@@ -50,6 +50,8 @@ from jax import lax
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu import profile
+
 BLOCK_Q = 128
 BLOCK_K = 128
 
@@ -400,6 +402,7 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
         inputs += [qc, qs, kc, ks]
     out, lse = pl.pallas_call(
         kernel,
+        name=profile.FLASH_FWD,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -598,6 +601,7 @@ def flash_ring_step(q, k, v, o, m, l, q_offset, kv_offset, causal=True,
         inputs += [qc, qs, kc, ks]
     return pl.pallas_call(
         kernel,
+        name=profile.RING_ATTN,
         grid=grid,
         in_specs=in_specs + state_specs,
         out_specs=state_specs,
@@ -775,6 +779,7 @@ def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
         functools.partial(_ring_bwd_dq_kernel, scale=scale, causal=causal,
                           num_kb=num_kb, bqp=bqp, group=group,
                           rotary=rotary),
+        name=profile.RING_ATTN_DQ,
         grid=(BG, num_qb, num_kb),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -806,6 +811,7 @@ def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
         functools.partial(_ring_bwd_dkv_kernel, scale=scale,
                           causal=causal, num_qb=num_qb, bqp=bqp,
                           group=group, rotary=rotary),
+        name=profile.RING_ATTN_DKV,
         grid=(BG, num_kb, num_qb),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -1000,6 +1006,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           num_kb=num_kb, bqp=bqp, group=group,
                           rotary=rotary),
+        name=profile.FLASH_DQ,
         grid=(B * G, rows // bq, num_kb),
         in_specs=[
             pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
@@ -1027,6 +1034,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           num_qb=num_qb, bqp=bqp, group=group,
                           rotary=rotary),
+        name=profile.FLASH_DKV,
         grid=(B * G, num_kb, num_qb),
         in_specs=[
             pl.BlockSpec((None, bq, D), q_im),

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu import profile
+
 
 def chunked_softmax_cross_entropy(hidden, kernel, targets, chunk=512):
     """Mean token cross entropy over chunked vocab projections.
@@ -38,21 +40,22 @@ def chunked_softmax_cross_entropy(hidden, kernel, targets, chunk=512):
     B, L, D = hidden.shape
     if L % chunk != 0:
         raise ValueError("L=%d not divisible by chunk=%d" % (L, chunk))
-    n = L // chunk
-    h = hidden.reshape(B, n, chunk, D).transpose(1, 0, 2, 3)
-    t = targets.reshape(B, n, chunk).transpose(1, 0, 2)
+    with jax.named_scope(profile.LOSS):
+        n = L // chunk
+        h = hidden.reshape(B, n, chunk, D).transpose(1, 0, 2, 3)
+        t = targets.reshape(B, n, chunk).transpose(1, 0, 2)
 
-    @jax.checkpoint
-    def chunk_loss(h_c, t_c):
-        logits = (h_c @ kernel.astype(h_c.dtype)).astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, t_c[..., None],
-                                  axis=-1)[..., 0]
-        return jnp.sum(lse - tgt)
+        @jax.checkpoint
+        def chunk_loss(h_c, t_c):
+            logits = (h_c @ kernel.astype(h_c.dtype)).astype(jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            tgt = jnp.take_along_axis(logits, t_c[..., None],
+                                      axis=-1)[..., 0]
+            return jnp.sum(lse - tgt)
 
-    def body(acc, xs):
-        h_c, t_c = xs
-        return acc + chunk_loss(h_c, t_c), None
+        def body(acc, xs):
+            h_c, t_c = xs
+            return acc + chunk_loss(h_c, t_c), None
 
-    total, _ = lax.scan(body, jnp.float32(0.0), (h, t))
-    return total / (B * L)
+        total, _ = lax.scan(body, jnp.float32(0.0), (h, t))
+        return total / (B * L)

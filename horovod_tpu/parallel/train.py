@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import horovod_tpu.jax as hvd_jax
+from horovod_tpu import profile
 
 
 def _aval_cache_key(*trees):
@@ -42,7 +43,8 @@ def _structure_cached_step(build):
         return cache[key]
 
     def step(params, opt_state, batch):
-        return compiled(params, opt_state)(params, opt_state, batch)
+        with profile.span(profile.SPAN_STEP_DISPATCH):
+            return compiled(params, opt_state)(params, opt_state, batch)
 
     step.lower = lambda params, opt_state, batch: \
         compiled(params, opt_state).lower(params, opt_state, batch)
@@ -162,10 +164,14 @@ def make_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
         (loss, grads), _ = jax.lax.scan(body, (0.0, zeros), micro)
         return loss, grads
 
+    # The scopes below are compile-time metadata for the profiler
+    # (`hvd.profile`): they add no operation to the program.
     def shard_step(params, opt_state, batch):
-        loss, grads = _local_loss_and_grads(params, batch)
+        with jax.named_scope(profile.FWD_BWD):
+            loss, grads = _local_loss_and_grads(params, batch)
         if zero1:
-            idx = jax.lax.axis_index(axis_name)
+            with jax.named_scope(profile.OPTIMIZER):
+                idx = jax.lax.axis_index(axis_name)
 
             def scatter(g):
                 if zero1_mode != _wire.Compression.none:
@@ -187,22 +193,29 @@ def make_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
                 chunk = v.shape[0] // n_shards
                 return jax.lax.dynamic_slice_in_dim(v, idx * chunk, chunk)
 
-            g_shards = jax.tree_util.tree_map(scatter, grads)
-            p_shards = jax.tree_util.tree_map(my_slice, params)
-            updates, opt_state = optimizer.update(g_shards, opt_state,
-                                                 p_shards)
-            new_shards = jax.tree_util.tree_map(lambda p, u: p + u,
-                                                p_shards, updates)
-            params = jax.tree_util.tree_map(
-                lambda ns, p: jax.lax.all_gather(
-                    ns, axis_name, tiled=True)[:p.size]
-                .reshape(p.shape).astype(p.dtype),
-                new_shards, params)
+            with jax.named_scope(profile.GRAD_SYNC):
+                g_shards = jax.tree_util.tree_map(scatter, grads)
+            with jax.named_scope(profile.OPTIMIZER):
+                p_shards = jax.tree_util.tree_map(my_slice, params)
+                updates, opt_state = optimizer.update(g_shards, opt_state,
+                                                     p_shards)
+                new_shards = jax.tree_util.tree_map(lambda p, u: p + u,
+                                                    p_shards, updates)
+            with jax.named_scope(profile.PARAM_GATHER):
+                params = jax.tree_util.tree_map(
+                    lambda ns, p: jax.lax.all_gather(
+                        ns, axis_name, tiled=True)[:p.size]
+                    .reshape(p.shape).astype(p.dtype),
+                    new_shards, params)
         else:
+            # dist_opt.update names its own halves: the gradients'
+            # allreduce GRAD_SYNC, the wrapped optimizer OPTIMIZER.
             updates, opt_state = dist_opt.update(grads, opt_state, params)
-            params = jax.tree_util.tree_map(
-                lambda p, u: (p + u).astype(p.dtype), params, updates)
-        loss = jax.lax.pmean(loss, axis_name)
+            with jax.named_scope(profile.OPTIMIZER):
+                params = jax.tree_util.tree_map(
+                    lambda p, u: (p + u).astype(p.dtype), params, updates)
+        with jax.named_scope(profile.GRAD_SYNC):
+            loss = jax.lax.pmean(loss, axis_name)
         return params, opt_state, loss
 
     replicated = P()
@@ -239,30 +252,31 @@ def make_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
         """Places params (replicated), optimizer state (replicated, or
         built flat-padded and dim-0 sharded under zero1 — the passed
         opt_state is ignored then), and batch (dim-0 sharded)."""
-        rep = NamedSharding(mesh, replicated)
-        dat = NamedSharding(mesh, sharded)
-        params = jax.device_put(params, rep)
-        if zero1:
-            # Build the state WITH sharded out_shardings so the full
-            # moments are never materialized per device (the whole
-            # point of zero1 is that they don't fit).
-            def init_flat(p):
-                return optimizer.init(
-                    jax.tree_util.tree_map(_flat_pad, p))
+        with profile.span(profile.SPAN_PLACE):
+            rep = NamedSharding(mesh, replicated)
+            dat = NamedSharding(mesh, sharded)
+            params = jax.device_put(params, rep)
+            if zero1:
+                # Build the state WITH sharded out_shardings so the full
+                # moments are never materialized per device (the whole
+                # point of zero1 is that they don't fit).
+                def init_flat(p):
+                    return optimizer.init(
+                        jax.tree_util.tree_map(_flat_pad, p))
 
-            template = jax.eval_shape(init_flat, params)
-            out_shardings = jax.tree_util.tree_map(
-                lambda x: NamedSharding(mesh, sharded)
-                if getattr(x, "ndim", 0) >= 1 else rep, template)
-            opt_state = jax.jit(
-                init_flat, out_shardings=out_shardings)(params)
-        else:
-            opt_state = jax.device_put(opt_state, rep)
-        if batch is None:
-            return params, opt_state
-        batch = jax.tree_util.tree_map(
-            partial(jax.device_put, device=dat), batch)
-        return params, opt_state, batch
+                template = jax.eval_shape(init_flat, params)
+                out_shardings = jax.tree_util.tree_map(
+                    lambda x: NamedSharding(mesh, sharded)
+                    if getattr(x, "ndim", 0) >= 1 else rep, template)
+                opt_state = jax.jit(
+                    init_flat, out_shardings=out_shardings)(params)
+            else:
+                opt_state = jax.device_put(opt_state, rep)
+            if batch is None:
+                return params, opt_state
+            batch = jax.tree_util.tree_map(
+                partial(jax.device_put, device=dat), batch)
+            return params, opt_state, batch
 
     step.place = place
     return step
@@ -345,5 +359,6 @@ def make_fsdp_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
 
 def cross_entropy_loss(logits, labels):
     """Mean softmax cross entropy with integer labels (benchmark loss)."""
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32))
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
+    with jax.named_scope(profile.LOSS):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))

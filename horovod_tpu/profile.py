@@ -1,0 +1,125 @@
+"""The profiler control of the in-`jit` train step, and the names the
+program gives its own work (`hvd.profile`; docs/TRACING.md, "The in-`jit`
+step").
+
+The train step is one XLA program: its `psum` never enters the native core,
+so `hvd-trace`, the timeline and the metrics registry read zero on it. What
+shows where a step's device time goes is the runtime's profiler
+(`jax.profiler`), and it can tell the step's phases and kernels apart only
+by the names the program gives them. Both are here:
+
+- the names: `jax.named_scope`s around the phases of the step
+  (`PHASE_SCOPES`) and the parts of the models (`MODEL_SCOPES`), and the
+  `name=` of every Pallas kernel (`KERNELS`). They are compile-time
+  metadata: the optimized program is the same with or without them, and no
+  Python runs for them on the per-step path.
+- the control: `start(logdir)` / `stop()` switch the profiler on and off in
+  the running process that holds the chip, any number of times;
+  `span(name)` is what host code puts around its own work, on the
+  profiler's clock (the clock of the device planes), and is one shared
+  no-op context while no trace is active.
+
+Nothing here reads the environment, and importing this module imports
+nothing, starts nothing and touches no device: jax is imported by the calls
+that need it, so `import horovod_tpu` stays free of it.
+"""
+
+import contextlib
+import glob
+import os
+
+# Phases of `parallel.make_train_step`'s program. Every device operation of
+# a step lies under exactly one of them (the outermost on its scope path).
+FWD_BWD = "hvd_fwd_bwd"            # loss_fn forward and backward
+GRAD_SYNC = "hvd_grad_sync"        # collectives on gradients, casts, divide
+OPTIMIZER = "hvd_optimizer"        # optimizer maths and the parameter write
+PARAM_GATHER = "hvd_param_gather"  # zero1: all_gather of the new shards
+PHASE_SCOPES = (FWD_BWD, GRAD_SYNC, OPTIMIZER, PARAM_GATHER)
+
+# Parts of the models, inside FWD_BWD. A backward operation carries the
+# scope of its forward inside `transpose(jvp(...))`.
+EMBED = "hvd_embed"
+BLOCK = "hvd_block"    # a Transformer block; flax's `attn` and `mlp` inside
+STEM = "hvd_stem"
+STAGES = ("hvd_stage1", "hvd_stage2", "hvd_stage3", "hvd_stage4")
+HEAD = "hvd_head"
+LOSS = "hvd_loss"
+MODEL_SCOPES = (EMBED, BLOCK, STEM) + STAGES + (HEAD, LOSS)
+
+# The `name=` of every `pl.pallas_call`: on the chip's trace the kernel's
+# instruction is `<name>.<n>` and its scope path ends in
+# `<name>/pallas_call`.
+FLASH_FWD = "hvd_flash_fwd"
+FLASH_DQ = "hvd_flash_dq"
+FLASH_DKV = "hvd_flash_dkv"
+RING_ATTN = "hvd_ring_attn"          # one forward step of ring attention
+RING_ATTN_DQ = "hvd_ring_attn_dq"    # one backward step: the dQ part
+RING_ATTN_DKV = "hvd_ring_attn_dkv"  # one backward step: the dK/dV part
+BN_STATS = "hvd_bn_stats"
+BN_GRAD_STATS = "hvd_bn_grad_stats"
+KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, RING_ATTN, RING_ATTN_DQ,
+           RING_ATTN_DKV, BN_STATS, BN_GRAD_STATS)
+
+# Host spans of the program's only per-call Python.
+SPAN_PLACE = "hvd_place"                  # `step.place`
+SPAN_STEP_DISPATCH = "hvd_step_dispatch"  # zero1's per-call wrapper
+HOST_SPANS = (SPAN_PLACE, SPAN_STEP_DISPATCH)
+
+# The runtime's profiler is one per process, so its state is too: the
+# directory of the trace `start` began, or None.
+_logdir = None
+_NO_SPAN = contextlib.nullcontext()
+
+
+def active():
+    """Whether a trace started by `start` is running in this process."""
+    return _logdir is not None
+
+
+def start(logdir):
+    """Starts the profiler in this process; the trace goes under `logdir`
+    at `stop()`. From Python only the spans of `span` are taken
+    (`python_tracer_level=0`: not every call), and the runtime's own host
+    events in full (`host_tracer_level=2`)."""
+    global _logdir
+    if _logdir is not None:
+        raise RuntimeError("hvd.profile.start: a trace into %r is already "
+                           "active; stop() it first" % _logdir)
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(logdir), profiler_options=options)
+    _logdir = str(logdir)
+
+
+def stop():
+    """Stops the profiler and returns the path of the `.xplane.pb` it wrote
+    (`jax.profiler.ProfileData.from_file` reads it; TensorBoard's profile
+    plugin and xprof open `logdir`). The runtime names a trace by the
+    second it stopped in, so two traces stopped into one `logdir` within a
+    second are one file: give each its own directory."""
+    global _logdir
+    if _logdir is None:
+        raise RuntimeError("hvd.profile.stop: no trace is active")
+    import jax
+
+    logdir, _logdir = _logdir, None
+    jax.profiler.stop_trace()
+    written = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    if not written:
+        raise RuntimeError("hvd.profile.stop: the profiler wrote no "
+                           ".xplane.pb under %r" % logdir)
+    return max(written, key=os.path.getmtime)
+
+
+def span(name):
+    """A host span named `name` on the profiler's clock while a trace is
+    active; the one shared no-op context while none is."""
+    if _logdir is None:
+        return _NO_SPAN
+    import jax
+
+    return jax.profiler.TraceAnnotation(name)
