@@ -47,4 +47,5 @@ from .ring import (ring_attention, ulysses_attention,  # noqa: F401
                    zigzag_shard, zigzag_unshard)
 from .tensor_parallel import (  # noqa: F401
     tp_grad_sync, tp_param_specs)
-from .train import make_fsdp_train_step, make_train_step  # noqa: F401
+from .train import (grad_overlap_options, make_fsdp_train_step,  # noqa: F401
+                    make_train_step)

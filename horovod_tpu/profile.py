@@ -27,6 +27,7 @@ that need it, so `import horovod_tpu` stays free of it.
 import contextlib
 import glob
 import os
+import re
 
 # Phases of `parallel.make_train_step`'s program. Every device operation of
 # a step lies under exactly one of them (the outermost on its scope path).
@@ -123,3 +124,114 @@ def span(name):
     import jax
 
     return jax.profiler.TraceAnnotation(name)
+
+
+# --- how far the step's gradient collectives are asynchronous -------------
+#
+# Read from the text of a compiled step (`compiled.as_text()`), so it needs
+# no chip: a compile for a described topology tells it. libtpu issues a
+# collective asynchronously in one of two forms: the `<op>-start` / `-done`
+# instruction pair, or a pair of fusions named `async-collective-start` /
+# `-done` whose computation holds the plain `<op>` instruction (further
+# steps of it ride inside compute fusions named `async_collective_fusion`).
+# Anything else (the plain `<op>` in the entry computation or a loop body)
+# holds the core for its whole wire time.
+_COLLECTIVE_OPS = ("all-reduce", "reduce-scatter", "all-gather",
+                   "collective-permute", "all-to-all")
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+             "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+             "f64": 8}
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{$")
+_HLO_LINE = re.compile(r"^\s+(?:ROOT )?%?([\w.-]+) = (.*)$")
+_HLO_OPCODE = re.compile(r" ([a-z][a-z0-9-]*)\(")
+_HLO_ARRAY = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.-]+)")
+_HLO_OPERAND = re.compile(r"%([\w.-]+)")
+
+
+def _closing(text, start):
+    """Index just past the parenthesis that closes the one at `start`."""
+    depth = 0
+    for i in range(start, len(text)):
+        depth += (text[i] == "(") - (text[i] == ")")
+        if depth == 0:
+            return i + 1
+    return len(text)
+
+
+def _type_end(rest):
+    """Index in `rest` (an instruction's text after ` = `) where its result
+    type ends: a tuple type is skipped to its closing parenthesis."""
+    return _closing(rest, 0) if rest.startswith("(") else rest.find(" ")
+
+
+def _array_bytes(type_text):
+    total = 0
+    for dtype, dims in _HLO_ARRAY.findall(type_text):
+        n = _ITEMSIZE.get(dtype, 0)
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        total += n
+    return total
+
+
+def grad_collectives(text):
+    """How the compiled step (`compiled.as_text()`) issues the gradient
+    collectives, those with `hvd_grad_sync` in their `op_name`:
+
+        {"sync": {"count": n, "bytes": b}, "async": {"count": n, "bytes": b}}
+
+    `bytes` are those of the collective's result on one device (for an
+    all-reduce, the gradients it sums, in the type it sums them in). A
+    combined all-reduce of several leaves is one collective. Text with no
+    such collective gives zeros."""
+    sizes = {}        # (computation, instruction) -> bytes of its result
+    fused_by = {}     # computation -> name of the fusion that calls it
+    found = []        # (computation, instruction, opcode, operands' text)
+    comp = None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _HLO_LINE.match(line)
+        if not m or comp is None:
+            continue
+        name, rest = m.groups()
+        end = _type_end(rest)
+        sizes[comp, name] = _array_bytes(rest[:end])
+        op = _HLO_OPCODE.match(rest, end)
+        if not op:
+            continue
+        opcode = op.group(1)
+        if opcode == "fusion":
+            called = _HLO_CALLS.search(rest, end)
+            if called:
+                fused_by[called.group(1)] = name
+        base = opcode[:-len("-start")] if opcode.endswith("-start") \
+            else opcode
+        if base in _COLLECTIVE_OPS and GRAD_SYNC in rest:
+            found.append((comp, name, opcode,
+                          rest[op.end():_closing(rest, op.end() - 1) - 1]))
+    out = {"sync": {"count": 0, "bytes": 0},
+           "async": {"count": 0, "bytes": 0}}
+    for comp, name, opcode, operands in found:
+        caller = fused_by.get(comp)
+        if comp.startswith(("fused_computation", "async_collective_fusion")):
+            # Inside a fusion: the start of an asynchronous collective
+            # counts; its later steps, its done and a computation that
+            # nothing calls (the compiler leaves some behind) do not.
+            if caller is None or not caller.startswith(
+                    "async-collective-start"):
+                continue
+            kind = "async"
+        else:
+            kind = "async" if opcode.endswith("-start") else "sync"
+        nbytes = sizes[comp, name]
+        if opcode.endswith("-start") and opcode != "all-reduce-start":
+            # Such a start returns its operands beside its results.
+            nbytes -= sum(sizes.get((comp, o), 0)
+                          for o in _HLO_OPERAND.findall(operands))
+        out[kind]["count"] += 1
+        out[kind]["bytes"] += nbytes
+    return out

@@ -29,8 +29,8 @@ from horovod_tpu.ops.flash_attention import (_flash, _pallas_forward_lse,
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described v5e chip to compile for. The persistent compile cache
+def topo():
+    """A described v5e 2x2 to compile for. The persistent compile cache
     is off around these compiles: an entry written for a described chip
     cannot be read back without one, and the next run would warn."""
     from jax.experimental import topologies
@@ -46,9 +46,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(one_chip, fn, *shapes):
@@ -147,3 +152,95 @@ def test_bn_grad_stats_compiles_for_v5e(one_chip, M, C):
     text = _compile(one_chip, batch_norm.batch_norm_grad_stats,
                     ((M, C), bf16), ((M, C), bf16), ((C,), f32), ((C,), f32))
     assert _kernels(text) == 1, text[:2000]
+
+
+# --- the data-parallel step's gradient all-reduces (PR 25) -----------------
+
+def _lm_step(topo, chips, monkeypatch):
+    """`make_train_step` around a small flash-attention LM on a mesh of the
+    first `chips` described devices, with its state as shapes: (step,
+    abstract state, mesh). The kernel dispatchers ask for the default backend,
+    which is the CPU here; the test steers them, not the program."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from horovod_tpu import models, parallel
+    from horovod_tpu.ops.losses import chunked_softmax_cross_entropy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = models.TransformerConfig(
+        vocab_size=32768, num_layers=2, num_heads=4, embed_dim=512,
+        mlp_dim=2048, max_seq_len=512, attention="flash",
+        dtype=jnp.bfloat16)
+    model = models.Transformer(cfg)
+    opt = optax.adam(1e-4)
+
+    def loss_fn(params, batch):
+        hid = model.apply({"params": params}, batch["x"], batch["pos"],
+                          return_hidden=True)
+        return chunked_softmax_cross_entropy(
+            hid, params["lm_head"]["kernel"],
+            jnp.roll(batch["x"], -1, axis=1), chunk=256)
+
+    mesh = parallel.data_parallel_mesh(devices=topo.devices[:chips])
+    step = parallel.make_train_step(loss_fn, opt, mesh)
+
+    def make_state(key):
+        params = model.init(key, jnp.zeros((1, 512), jnp.int32))["params"]
+        tokens = jnp.zeros((2 * chips, 512), jnp.int32)
+        return params, opt.init(params), {"x": tokens, "pos": tokens}
+
+    rep = NamedSharding(mesh, P())
+    dat = NamedSharding(mesh, P(mesh.axis_names[0]))
+    state = jax.eval_shape(
+        jax.jit(make_state, out_shardings=(rep, rep, dat)),
+        jax.random.PRNGKey(0))
+    return step, state, mesh
+
+
+def _step_text(step, state):
+    with jax.default_matmul_precision("default"):
+        return step.lower(*state).compile().as_text()
+
+
+def test_dp_step_gradient_allreduces_are_asynchronous_on_v5e(
+        topo, monkeypatch):
+    """Over the four described chips the step is compiled with
+    `grad_overlap_options`, and the compiler then issues the large
+    gradient all-reduces (the embedding's and the head's, 64 MiB each:
+    over the combiner's threshold, so each stays one collective)
+    asynchronously (read by `hvd.profile`)."""
+    from horovod_tpu import profile
+    from horovod_tpu.parallel import train
+
+    step, state, mesh = _lm_step(topo, 4, monkeypatch)
+    assert train.grad_overlap_options(mesh)
+    got = profile.grad_collectives(_step_text(step, state))
+    total = got["sync"]["bytes"] + got["async"]["bytes"]
+    n_params = sum(x.size for x in jax.tree_util.tree_leaves(state[0]))
+    assert total == 4 * n_params + 4  # every f32 leaf once, and the loss
+    assert got["async"]["count"] >= 2
+    assert got["async"]["bytes"] >= 2 * 4 * 32768 * 512, got
+    assert got["async"]["bytes"] > 0.5 * total, got
+    # The same step without the options: nothing asynchronous (what every
+    # PR before 25 compiled, and what the options are there to change).
+    monkeypatch.setattr(train, "grad_overlap_options", lambda *a: {})
+    step, state, _ = _lm_step(topo, 4, monkeypatch)
+    before = profile.grad_collectives(_step_text(step, state))
+    assert before["async"] == {"count": 0, "bytes": 0}
+    assert before["sync"]["bytes"] == total
+
+
+def test_one_device_step_is_compiled_as_before(topo, monkeypatch):
+    """With one device on the axis no option is passed: the program text
+    equals that of the step built with the rule switched off."""
+    from horovod_tpu.parallel import train
+
+    texts = []
+    for rule in (train.grad_overlap_options, lambda *a: {}):
+        monkeypatch.setattr(train, "grad_overlap_options", rule)
+        step, state, mesh = _lm_step(topo, 1, monkeypatch)  # one call site
+        assert rule(mesh) == {}
+        texts.append(_step_text(step, state))
+    assert texts[0] == texts[1]
+    assert _kernels(texts[0]) == 6  # flash fwd, dQ, dKdV in two layers

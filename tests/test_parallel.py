@@ -497,3 +497,115 @@ def test_ulysses_gqa_matches_dense():
     out = f(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
+
+
+class _FakeDevice:
+    def __init__(self, platform):
+        self.platform = platform
+
+
+class _FakeMesh:
+    """What `grad_overlap_options` reads of a mesh: the axis sizes and the
+    devices' platforms (a TPU mesh cannot be built on the CPU backend)."""
+
+    def __init__(self, platform, n, name="hvd"):
+        self.shape = {name: n}
+        self.devices = np.array([_FakeDevice(platform) for _ in range(n)])
+
+
+@pytest.mark.parametrize("platform,n,engages", [
+    ("tpu", 4, True), ("cpu", 4, False), ("tpu", 1, False)])
+def test_grad_overlap_rule(platform, n, engages):
+    """The step builder's rule: asynchronous gradient all-reduces are asked
+    of the compiler where the axis has more than one device AND they are
+    TPUs; otherwise the step is built as before — and on a CPU mesh of 4
+    that step still compiles and averages the gradients."""
+    import optax
+    from horovod_tpu.parallel import (data_parallel_mesh,
+                                      grad_overlap_options, make_train_step)
+
+    options = grad_overlap_options(_FakeMesh(platform, n))
+    assert bool(options) == engages
+    if engages:
+        assert options["xla_enable_async_all_reduce"] == "true"
+        assert all(isinstance(v, str) for v in options.values())
+        # A caller may edit its copy without changing the next step's.
+        options.clear()
+        assert grad_overlap_options(_FakeMesh(platform, n))
+        return
+    mesh = data_parallel_mesh(devices=jax.devices("cpu")[:n])
+    assert grad_overlap_options(mesh) == {}
+    w0 = jnp.ones((4,))
+    x = jnp.arange(32.0).reshape(8, 4) / 32.0
+    y = jnp.ones((8,))
+
+    def loss_fn(params, batch):
+        return jnp.mean((batch["x"] @ params - batch["y"]) ** 2)
+
+    opt = optax.sgd(0.1)
+    step = make_train_step(loss_fn, opt, mesh, donate=False)
+    params_p, opt_state = step.place(w0, opt.init(w0))
+    params_p, _, _ = step(params_p, opt_state, {"x": x, "y": y})
+    expected = w0 - 0.1 * jax.grad(loss_fn)(w0, {"x": x, "y": y})
+    np.testing.assert_allclose(np.asarray(params_p), np.asarray(expected),
+                               rtol=1e-6)
+
+
+# Lines as libtpu 0.0.34 writes them (shortened): a synchronous all-reduce
+# of two leaves, an `all-reduce-start`/`-done` pair, an
+# `async-collective-start` fusion whose computation holds the all-reduce
+# (its later step inside a compute fusion and its done must not count
+# again), a computation nothing calls, and a collective outside the scope.
+_HLO_SNIPPET = """\
+HloModule jit_shard_step, is_scheduled=true
+
+%fused_computation.7 (param_0.1: f32[8192,2048]) -> (f32[8192,2048], f32[8192,2048], u32[]) {
+  %param_0.1 = f32[8192,2048]{1,0:T(8,128)} parameter(0)
+  %all-reduce.5 = f32[8192,2048]{1,0:T(8,128)} all-reduce(%param_0.1), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(shard_step)/shard_map/hvd_grad_sync/psum"}
+  ROOT %custom-call.1 = (f32[8192,2048]{1,0}, f32[8192,2048]{1,0:S(1)}, u32[]{:S(2)}) custom-call(%all-reduce.5), custom_call_target="x"
+}
+
+%async_collective_fusion.9 (param_0.2: f32[8192,2048], param_1.2: f32[16,16]) -> (f32[16,16], f32[8192,2048]) {
+  %param_0.2 = f32[8192,2048]{1,0} parameter(0)
+  %param_1.2 = f32[16,16]{1,0} parameter(1)
+  %all-reduce.6 = f32[8192,2048]{1,0} all-reduce(%param_0.2), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(shard_step)/shard_map/hvd_grad_sync/psum"}
+  ROOT %tuple.3 = (f32[16,16]{1,0}, f32[8192,2048]{1,0}) tuple(%param_1.2, %all-reduce.6)
+}
+
+%fused_computation.8 (param_0.3: f32[8192,2048]) -> f32[8192,2048] {
+  %param_0.3 = f32[8192,2048]{1,0} parameter(0)
+  ROOT %all-reduce.7 = f32[8192,2048]{1,0} all-reduce(%param_0.3), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(shard_step)/shard_map/hvd_grad_sync/psum"}
+}
+
+%fused_computation.left_behind (param_0.4: f32[8192,2048]) -> f32[8192,2048] {
+  %param_0.4 = f32[8192,2048]{1,0} parameter(0)
+  ROOT %all-reduce.8 = f32[8192,2048]{1,0} all-reduce(%param_0.4), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(shard_step)/shard_map/hvd_grad_sync/psum"}
+}
+
+ENTRY %main.1_spmd (param.1: f32[2048,8192], param.2: f32[2048], param.3: f32[8192,2048], param.4: f32[512,512], param.5: f32[16,16]) -> f32[2048,8192] {
+  %param.1 = f32[2048,8192]{1,0:T(8,128)} parameter(0)
+  %param.2 = f32[2048]{0:T(1024)} parameter(1)
+  %param.3 = f32[8192,2048]{1,0:T(8,128)} parameter(2)
+  %param.4 = f32[512,512]{1,0:T(8,128)} parameter(3)
+  %param.5 = f32[16,16]{1,0} parameter(4)
+  %all-reduce.1 = (f32[2048,8192]{1,0:T(8,128)}, /*index=1*/f32[2048]{0:T(1024)}) all-reduce(%param.1, %param.2), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(shard_step)/shard_map/hvd_grad_sync/psum"}
+  %all-reduce-start.1 = f32[512,512]{1,0:T(8,128)} all-reduce-start(%param.4), channel_id=2, replica_groups={{0,1,2,3}}, to_apply=%add, metadata={op_name="jit(shard_step)/shard_map/hvd_grad_sync/psum"}
+  %all-reduce-done.1 = f32[512,512]{1,0:T(8,128)} all-reduce-done(%all-reduce-start.1), metadata={op_name="jit(shard_step)/shard_map/hvd_grad_sync/psum"}
+  %async-collective-start.2 = (f32[8192,2048]{1,0}, f32[8192,2048]{1,0:S(1)}, u32[]{:S(2)}) fusion(%param.3), kind=kCustom, output_to_operand_aliasing={{0}: (0, {})}, calls=%fused_computation.7
+  %fusion.9 = (f32[16,16]{1,0}, f32[8192,2048]{1,0}) fusion(%param.3, %param.5), kind=kLoop, calls=%async_collective_fusion.9
+  %async-collective-done.2 = f32[8192,2048]{1,0} fusion(%param.3), kind=kCustom, calls=%fused_computation.8
+  %all-gather.1 = f32[4,16,16]{2,1,0} all-gather(%param.5), channel_id=3, replica_groups={{0,1,2,3}}, dimensions={0}, metadata={op_name="jit(shard_step)/shard_map/hvd_param_gather/all_gather"}
+  ROOT %get-tuple-element.1 = f32[2048,8192]{1,0:T(8,128)} get-tuple-element(%all-reduce.1), index=0
+}
+"""
+
+
+def test_grad_collectives_reads_a_recorded_program():
+    from horovod_tpu import profile
+
+    got = profile.grad_collectives(_HLO_SNIPPET)
+    assert got == {
+        "sync": {"count": 1, "bytes": 4 * (2048 * 8192 + 2048)},
+        "async": {"count": 2, "bytes": 4 * (512 * 512 + 8192 * 2048)}}
+    assert profile.grad_collectives("") == {
+        "sync": {"count": 0, "bytes": 0}, "async": {"count": 0, "bytes": 0}}
