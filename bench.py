@@ -2488,9 +2488,10 @@ def main():
         # unit per "image" below is one sequence).
         moe = {}
         if args.moe_experts:
-            # Switch-MoE variant (single chip: all experts local, the
-            # dispatch/combine einsums + capacity machinery on the MXU;
-            # the ep all_to_all engages only on multi-chip meshes).
+            # Switch-MoE variant (single chip: all experts local, top-1
+            # routing into capacity buffers gathered from the sorted
+            # assignments; the ep all_to_all engages only on multi-chip
+            # meshes). The dropless path is the benchmark's OLMoE cell.
             moe = dict(moe_experts=args.moe_experts, moe_every=2,
                        moe_capacity_factor=1.25)
         cfg = models.TransformerConfig(

@@ -50,6 +50,13 @@ SIZES = dict(
     lm=dict(vocab_size=32000, num_layers=12, num_heads=12, embed_dim=768,
             mlp_dim=3072, max_seq_len=8192),
     lm_batch=8, lm_len=1024, lm_steps=4,
+    # A small OLMoE-shaped LM (QK-norm, every layer 16 gated experts, top-4,
+    # dropless): 4 x 1024 tokens put 16384 rows on 16 experts.
+    moe=dict(vocab_size=32000, num_layers=2, num_heads=8, embed_dim=1024,
+             mlp_dim=512, max_seq_len=1024, qk_norm=True, norm_eps=1e-5,
+             moe_experts=16, moe_every=1, moe_top_k=4, moe_gated=True,
+             moe_renormalize=False, moe_capacity_factor=None),
+    moe_batch=4, moe_len=1024, moe_steps=4,
     # (B, H, G, L, D, fused rotary): the L=1024 LM row's attention and the
     # long-context h6/gqa2/frope row's.
     attn=[(8, 12, 12, 1024, 64, False), (2, 6, 2, 8192, 128, True)],
@@ -253,6 +260,57 @@ def lm_step(mesh, seed):
     return step, (params, opt.init(params), {"x": tokens, "pos": positions})
 
 
+def moe_step(mesh, seed, attention="flash"):
+    """A dropless routed-feed-forward LM through make_train_step: the
+    cross-entropy plus the router's two auxiliary losses, adamw. Returns
+    the step, its (params, opt_state, batch), not yet placed, and
+    `routing(params, batch)`: the routing statistics of a forward pass."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from horovod_tpu import models
+    from horovod_tpu.parallel import (make_train_step, router_aux_losses,
+                                      routing_stats)
+
+    cfg = models.TransformerConfig(attention=attention, dtype=jnp.bfloat16,
+                                   **SIZES["moe"])
+    model = models.Transformer(cfg)
+    L = SIZES["moe_len"]
+    rng = jax.random.PRNGKey(seed)
+    tokens = jax.random.randint(rng, (SIZES["moe_batch"] * mesh.size, L), 0,
+                                cfg.vocab_size)
+    params = jax.jit(lambda r: model.init(r, tokens[:1]))(rng)["params"]
+
+    def forward(params, x):
+        logits, state = model.apply({"params": params}, x,
+                                    mutable=["intermediates"])
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+        xent = -jnp.mean(jnp.take_along_axis(
+            logp, jnp.roll(x, -1, axis=1)[..., None], axis=-1))
+        balance, z = router_aux_losses(state["intermediates"])
+        return (xent + 0.01 * balance + 0.001 * z,
+                routing_stats(state["intermediates"]))
+
+    opt = optax.adamw(1e-4)
+    step = make_train_step(lambda p, batch: forward(p, batch["x"])[0], opt,
+                           mesh)
+    routing = jax.jit(lambda p, batch: forward(p, batch["x"])[1])
+    return step, (params, opt.init(params), {"x": tokens}), routing
+
+
+def check_routing(stats, assigned):
+    """No assignment dropped: every layer's group sizes sum to `assigned`
+    (top_k x tokens)."""
+    sums = [int(v) for v in stats["assignments"].sum(axis=1)]
+    print("  routing: assignments a layer %s, dropped %d, largest expert "
+          "%d rows" % (sums, int(stats["dropped"]),
+                       int(stats["assignments"].max())), flush=True)
+    check(all(n == assigned for n in sums) and int(stats["dropped"]) == 0,
+          "no assignment dropped: every layer's group sizes sum to %d"
+          % assigned)
+
+
 def compile_with_text(jitted, *call_args):
     """AOT-compiles a jitted callable; returns (compiled, text, seconds)."""
     t0 = time.perf_counter()
@@ -418,6 +476,28 @@ def phase_kernels(args):
     check_losses(losses)
     check(on_tpu((params, opt_state)), "LM state lives on the tpu")
     del step, state, compiled, params, opt_state
+
+    step, state, routing = moe_step(mesh, args.seed)
+    state = step.place(*state)
+    compiled, text, secs = compile_with_text(step, *state)
+    n, layers = kernel_calls(text), SIZES["moe"]["num_layers"]
+    check(n >= 12 * layers and "hvd_moe_gmm_drhs" in text,
+          "MoE LM: %d tpu_custom_call in the train step (3 flash and 9 "
+          "grouped-matmul kernels for each of %d layers; compiled in %.1f s)"
+          % (n, layers, secs))
+    assigned = (SIZES["moe"]["moe_top_k"] * SIZES["moe_batch"]
+                * SIZES["moe_len"])
+    check_routing(routing(state[0], state[2]), assigned)
+    params, opt_state, losses, secs = run_steps(compiled, *state,
+                                                SIZES["moe_steps"])
+    print("  MoE LM %dx%d, %d experts top-%d, L=%d batch %d: steps %s ms"
+          % (SIZES["moe"]["embed_dim"], layers, SIZES["moe"]["moe_experts"],
+             SIZES["moe"]["moe_top_k"], SIZES["moe_len"], SIZES["moe_batch"],
+             " ".join("%.1f" % (1e3 * s) for s in secs)), flush=True)
+    check_losses(losses)
+    check_routing(routing(params, state[2]), assigned)
+    check(on_tpu((params, opt_state)), "MoE LM state lives on the tpu")
+    del step, state, compiled, params, opt_state, routing
 
     for i, shape in enumerate(SIZES["attn"]):
         attention_vs_reference(

@@ -40,7 +40,7 @@ def main():
 
     from horovod_tpu.models import Transformer, TransformerConfig
     from horovod_tpu.parallel import (ep_grad_sync, ep_param_specs,
-                                      hybrid_mesh)
+                                      hybrid_mesh, router_aux_losses)
 
     devices = jax.devices()
     n = len(devices)
@@ -83,7 +83,7 @@ def main():
             logp = jax.nn.log_softmax(logits)
             xent = -jnp.mean(
                 jnp.take_along_axis(logp, tgt[..., None], axis=-1))
-            aux = sum(jax.tree_util.tree_leaves(state["intermediates"]))
+            aux, _ = router_aux_losses(state["intermediates"])
             return xent + args.aux_weight * aux
 
         loss, grads = jax.value_and_grad(loss_fn)(params)

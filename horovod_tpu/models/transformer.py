@@ -62,7 +62,7 @@ class TransformerConfig:
     # Per-head width; defaults to embed_dim // num_heads. Set
     # explicitly when num_heads is a LOCAL (tp-sharded) count.
     head_dim: Optional[int] = None
-    # Switch-style mixture-of-experts: when moe_experts is set, every
+    # Mixture-of-experts: when moe_experts is set, every
     # `moe_every`-th block swaps its dense MLP for a MoeMlp
     # (parallel/expert.py); ep_axis/ep_size shard the expert dim inside
     # shard_map (tokens should then shard over (dp, ep)). Initialize
@@ -70,13 +70,37 @@ class TransformerConfig:
     # ep-sized config — the tp `local()` pattern.
     moe_experts: Optional[int] = None
     moe_every: int = 2
-    moe_capacity_factor: float = 1.25
-    moe_top_k: int = 1            # 1 = Switch; 2 = GShard-style
+    # Slots an expert has, as a multiple of tokens / experts; an
+    # assignment past them is dropped. None: dropless (sort, grouped
+    # matmul over ragged groups, unsort), on one device only.
+    moe_capacity_factor: Optional[float] = 1.25
+    # Experts a token is sent to, any k up to moe_experts (1: Switch;
+    # 2: GShard; OLMoE: 8 of 64).
+    moe_top_k: int = 1
+    # Divide a token's k routing weights by their sum (GShard); False
+    # keeps the softmax probabilities as they are (OLMoE). k = 1 always
+    # keeps the raw probability.
+    moe_renormalize: bool = True
+    # Gated experts, `w_down(silu(w_gate x) * w_up x)`, each of width
+    # mlp_dim; False: `w_out(silu(w_in x))`.
+    moe_gated: bool = False
     ep_axis: Optional[str] = None
     ep_size: int = 1
+    # RMSNorm over the whole query and the whole key projection, before
+    # the split into heads and before rotary (OLMoE's QK-norm).
+    qk_norm: bool = False
+    norm_eps: float = 1e-6        # every RMSNorm's epsilon
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
+        if (self.moe_experts is not None and self.ep_axis is not None
+                and self.moe_capacity_factor is None):
+            raise ValueError("dropless routing (moe_capacity_factor=None) "
+                             "is local; with ep_axis set give a capacity "
+                             "factor")
+        if self.qk_norm and self.tp_axis is not None:
+            raise ValueError("qk_norm normalises over all heads' "
+                             "projection, which tp_axis shards")
         if self.moe_experts is not None and self.tp_axis is not None:
             # The MoE branch neither psums like the dense row-parallel
             # mlp_out nor shards experts by tp — combining them would
@@ -104,6 +128,11 @@ class TransformerConfig:
             num_kv_heads=kv,
             mlp_dim=self.mlp_dim // tp_size,
             head_dim=self.head_dim or self.embed_dim // self.num_heads)
+
+
+def _rms_norm(cfg, name):
+    return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype,
+                      param_dtype=jnp.float32, name=name)
 
 
 def _rotary(x, positions, base=10000.0):
@@ -138,6 +167,11 @@ class Attention(nn.Module):
         q = heads(cfg.num_heads, "query")(x)
         k = heads(G, "key")(x)
         v = heads(G, "value")(x)
+        if cfg.qk_norm:
+            def whole(t, name):
+                flat = t.reshape(t.shape[:-2] + (-1,))
+                return _rms_norm(cfg, name)(flat).reshape(t.shape)
+            q, k = whole(q, "q_norm"), whole(k, "k_norm")
         fused = (cfg.rope_fused and
                  cfg.attention in ("flash", "ring", "ulysses"))
         if not fused:
@@ -183,25 +217,27 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        norm = lambda name: nn.RMSNorm(  # noqa: E731
-            dtype=cfg.dtype, param_dtype=jnp.float32, name=name)
         # Under rope_fused=True with a kernel attention (flash/ring/
         # ulysses), `positions` is IGNORED: the kernels apply rotary
         # in-kernel from global row offsets, which assumes the standard
         # contiguous 0..L-1 layout. Custom position ids (packing, shifted
         # windows) require rope_fused=False.
-        x = x + Attention(cfg, name="attn")(norm("norm1")(x), positions)
-        h = norm("norm2")(x)
+        x = x + Attention(cfg, name="attn")(_rms_norm(cfg, "norm1")(x),
+                                           positions)
+        h = _rms_norm(cfg, "norm2")(x)
+        # `mlp` beside flax's `attn`: the profiler's scope for this half
+        # of the block (hvd.profile), dense or routed; no module and no
+        # parameter name.
         if self.moe:
             from horovod_tpu.parallel.expert import MoeMlp
-            h = MoeMlp(num_experts=cfg.moe_experts, mlp_dim=cfg.mlp_dim,
-                       capacity_factor=cfg.moe_capacity_factor,
-                       ep_axis=cfg.ep_axis, ep_size=cfg.ep_size,
-                       top_k=cfg.moe_top_k, dtype=cfg.dtype,
-                       name="moe_mlp")(h)
+            with jax.named_scope("mlp"):
+                h = MoeMlp(num_experts=cfg.moe_experts, mlp_dim=cfg.mlp_dim,
+                           capacity_factor=cfg.moe_capacity_factor,
+                           ep_axis=cfg.ep_axis, ep_size=cfg.ep_size,
+                           top_k=cfg.moe_top_k, gated=cfg.moe_gated,
+                           renormalize=cfg.moe_renormalize,
+                           dtype=cfg.dtype, name="moe_mlp")(h)
             return x + h
-        # `mlp` beside flax's `attn`: the profiler's scope for this half
-        # of the block (hvd.profile), no module and no parameter name.
         with jax.named_scope("mlp"):
             h = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype,
                          param_dtype=jnp.float32, use_bias=False,
@@ -247,8 +283,7 @@ class Transformer(nn.Module):
             with jax.named_scope(profile.BLOCK):
                 x = Block(cfg, moe=moe, name="block_%d" % i)(x, positions)
         with jax.named_scope(profile.HEAD):
-            x = nn.RMSNorm(dtype=cfg.dtype, param_dtype=jnp.float32,
-                           name="norm_f")(x)
+            x = _rms_norm(cfg, "norm_f")(x)
             if return_hidden:
                 return x
             logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,

@@ -7,6 +7,10 @@ played by Pallas TPU kernels:
 
 * :mod:`.flash_attention` — blockwise attention with online softmax in
   VMEM (O(L) memory), causal block skipping, custom VJP.
+* :mod:`.grouped_matmul` — the experts of a dropless mixture-of-experts
+  layer: rows in contiguous ragged groups, each group its own matrix,
+  f32 matrices rounded in VMEM, custom VJP (rows' and matrices'
+  gradients).
 """
 
 from .flash_attention import flash_attention  # noqa: F401

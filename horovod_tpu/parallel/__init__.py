@@ -25,9 +25,9 @@ extension layered on the same mesh machinery.
 * :mod:`.pipeline` — GPipe pp over stage-stacked blocks, with the
   pinned in-shard_map gradient contract and a ``remat`` option
   (1F1B-class activation memory).
-* :mod:`.expert` — Switch/GShard MoE ep: top-1/top-2 routing with
-  static capacity, expert-dim all_to_all, `ep_param_specs` /
-  `ep_grad_sync`.
+* :mod:`.expert` — routed MoE feed-forward: top-k routing, dropless on
+  one device (sort, grouped matmul, unsort), static capacity and
+  expert-dim all_to_all under ep, `ep_param_specs` / `ep_grad_sync`.
 
 Pairwise compositions are test-pinned: tp x sp, sp x ep (ring AND
 Ulysses), dp x pp, fsdp x tp, plus the dryrun's dp x {sp,tp,ep}
@@ -41,7 +41,8 @@ from .mesh import (  # noqa: F401
     topology_summary,
 )
 from .expert import (  # noqa: F401
-    MoeMlp, ep_grad_sync, ep_param_specs, moe_ffn, switch_dispatch)
+    MoeMlp, ep_grad_sync, ep_param_specs, moe_ffn, router_aux_losses,
+    routing_stats)
 from .pipeline import pipeline_apply, stack_block_params  # noqa: F401
 from .ring import (ring_attention, ulysses_attention,  # noqa: F401
                    zigzag_shard, zigzag_unshard)

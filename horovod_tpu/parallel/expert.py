@@ -1,28 +1,43 @@
-"""Expert parallelism: Switch-style top-1 mixture-of-experts with the
-expert dimension sharded over an ``ep`` mesh axis.
+"""Expert parallelism: a routed mixture-of-experts feed-forward, dropless on
+one device and with a static capacity where the expert dimension is sharded
+over an ``ep`` mesh axis.
 
 The reference framework has no MoE (it is a gradient-reduction library);
 this is the TPU-first ``ep`` member of the parallelism family
-(dp/sp/tp/pp/ep), built the way GShard/Switch map onto XLA:
+(dp/sp/tp/pp/ep). One routing function serves every path:
 
-* **static shapes everywhere** — each expert has a fixed capacity
-  ``C = ceil(T/E * capacity_factor)``; overflow tokens are dropped
-  (their residual path passes through untouched), so the program never
-  depends on routing decisions at compile time;
-* **dispatch/combine as einsums** — routing is a [T, E, C] one-hot
-  tensor contraction (MXU work), not gather/scatter;
-* **all_to_all over ICI** — with ``ep_axis`` set (inside shard_map),
-  expert inputs [E, C, D] are exchanged so each rank runs only its
-  E/ep local experts on every rank's tokens, then exchanged back:
-  ``lax.all_to_all`` split on the expert dim, concat on capacity —
-  the MoE analogue of Ulysses' sequence all-to-all.
+* **route** — float32 softmax over all experts, ``lax.top_k`` for any k,
+  the chosen probabilities as weights, renormalised or as they are
+  (`route`); the load-balancing loss and the router z-loss beside it
+  (`router_losses`).
+* **sort** — the k*T assignments are sorted by expert (`sort_assignments`):
+  a permutation, its inverse and the group sizes, which always sum to k*T.
+  Nothing here builds a [T, E, C] tensor.
+* **dropless** (``capacity_factor=None``, the local path) — the rows are
+  gathered into the sorted order, the experts run as a grouped matmul over
+  the contiguous ragged groups (`grouped_matmul`), the results are gathered
+  back and summed with their weights. No assignment is dropped and an empty
+  group is legal; shapes are static ([k*T, D]) whatever the routing. Both
+  gathers are permutations, so their transposes are gathers too
+  (`custom_vjp`): no scatter-add of rows in either direction.
+* **capacity** (a ``capacity_factor``; required with ``ep_axis``) — each
+  expert has ``C = ceil(T/E * capacity_factor)`` slots, filled from the same
+  sorted order (first choices of all tokens before second choices, GShard's
+  ordering); an assignment past its expert's capacity is dropped (its
+  residual path passes through untouched). The [E, C, D] buffers are
+  gathered by index, exchanged so each rank runs only its E/ep local
+  experts on every rank's tokens (``lax.all_to_all`` split on the expert
+  dim, concat on capacity — the MoE analogue of Ulysses' sequence
+  all-to-all), and exchanged back.
 
 Router weights are replicated (every rank routes over all E experts);
 expert FFN weights are sharded [E/ep, ...] along the expert dim
 (PartitionSpec("ep") on axis 0 — see tests/test_expert.py and
-__graft_entry__.dryrun_multichip phase 4).
+__graft_entry__.dryrun_multichip phase 4). Experts are ``w_in -> act ->
+w_out`` or gated (``w_gate``, ``w_up``, ``w_down``: SwiGLU with SiLU).
 """
 
+import functools
 import math
 from typing import Any, Optional
 
@@ -31,64 +46,113 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu import profile
+from horovod_tpu.ops.grouped_matmul import grouped_matmul
 
-def switch_dispatch(router_logits, capacity):
-    """Top-1 (Switch) routing with a static per-expert capacity.
-
-    router_logits: [T, E] (any float dtype; softmax in f32).
-    Returns (dispatch [T, E, C] f32 one-hot, combine [T, E, C] f32
-    gate-weighted, aux_loss scalar — the Switch load-balancing loss
-    E * sum(frac_tokens_e * mean_prob_e)).
-    """
-    return topk_dispatch(router_logits, capacity, k=1)
+# Final key of every expert-sharded leaf: the contract between `MoeMlp`,
+# `ep_param_specs` and `ep_grad_sync`.
+EXPERT_LEAVES = ("w_in", "w_out", "w_gate", "w_up", "w_down")
 
 
-def topk_dispatch(router_logits, capacity, k=2):
-    """Top-k (GShard-style for k=2) routing with a static per-expert
-    capacity; gates of the chosen experts renormalized to sum to 1 per
-    token. Each choice occupies one capacity slot; queue positions
-    count both choices (first choices of all tokens enqueue before
-    second choices, GShard's ordering). Returns (dispatch [T, E, C],
-    combine [T, E, C], aux_loss) like :func:`switch_dispatch` — the
-    aux loss uses first-choice fractions (Switch eq. 4 / GShard's
-    l_aux)."""
-    T, E = router_logits.shape
+def route(router_logits, k, renormalize=True):
+    """Top-k routing. router_logits: [T, E] (any float dtype; softmax in
+    f32). Returns (weights [T, k] f32, experts [T, k] int32, probs [T, E]
+    f32): the chosen experts in order of falling probability and their
+    probabilities, divided by their sum per token when `renormalize` and
+    k > 1 (k = 1 keeps the raw probability: that term is what trains a
+    Switch router)."""
     probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    weights, experts = lax.top_k(probs, k)
+    if renormalize and k > 1:
+        weights = weights / jnp.maximum(
+            jnp.sum(weights, axis=-1, keepdims=True), 1e-9)
+    return weights, experts.astype(jnp.int32), probs
 
-    onehots = []
-    gates = []
-    masked = probs
-    for _ in range(k):
-        idx = jnp.argmax(masked, axis=-1)
-        oh = jax.nn.one_hot(idx, E, dtype=jnp.float32)
-        onehots.append(oh)
-        gates.append(jnp.sum(probs * oh, axis=-1))
-        masked = masked * (1.0 - oh)
-    if k > 1:
-        # GShard renormalizes the chosen gates; Switch (k=1) keeps the
-        # raw top-1 probability (that term is what trains the router).
-        denom = sum(gates)
-        gates = [g / jnp.maximum(denom, 1e-9) for g in gates]
 
-    # Queue positions: choice rounds enqueue in order — round r's
-    # tokens arrive after ALL of round r-1's (prior_counts offsets).
-    prior = jnp.zeros((E,), jnp.float32)
-    dispatch = jnp.zeros((T, E, capacity), jnp.float32)
-    combine = jnp.zeros((T, E, capacity), jnp.float32)
-    for oh, gate in zip(onehots, gates):
-        pos = jnp.sum((jnp.cumsum(oh, axis=0) + prior) * oh, axis=-1) \
-            .astype(jnp.int32) - 1                             # [T]
-        # one_hot of >= capacity (or negative) is all-zero: the drop.
-        d = oh[:, :, None] * \
-            jax.nn.one_hot(pos, capacity, dtype=jnp.float32)[:, None, :]
-        dispatch = dispatch + d
-        combine = combine + d * gate[:, None, None]
-        prior = prior + jnp.sum(oh, axis=0)
+def router_losses(router_logits, probs, group_sizes):
+    """(load-balancing loss, router z-loss) of one layer, both f32 scalars.
 
-    frac = jnp.mean(onehots[0], axis=0)
-    mean_prob = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac * mean_prob)
-    return dispatch, combine, aux
+    Load balancing as Hugging Face's `load_balancing_loss_func` has it for
+    one layer (Switch eq. 4 over all k choices): E * sum_e f_e * P_e with
+    f_e = assignments to e / T and P_e the mean probability of e. Even
+    routing gives k. The z-loss is mean_t logsumexp(logits_t)^2 (ST-MoE)."""
+    T, E = probs.shape
+    frac = group_sizes.astype(jnp.float32) / T
+    balance = E * jnp.sum(frac * jnp.mean(probs, axis=0))
+    z = jnp.mean(jax.nn.logsumexp(
+        router_logits.astype(jnp.float32), axis=-1) ** 2)
+    return balance, z
+
+
+def sort_assignments(experts, num_experts):
+    """Sorts the k*T assignments of `experts` [T, k] by expert.
+
+    Assignment a = j*T + t is token t's j-th choice (choice-major, so the
+    stable sort puts every first choice of an expert before its second
+    choices). Returns (flat [kT]: the expert of assignment a; order [kT]:
+    the assignment at sorted position s; inv [kT]: the sorted position of
+    assignment a; group_sizes [E] int32, which sum to k*T)."""
+    flat = experts.T.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inv = jnp.argsort(order).astype(jnp.int32)
+    # A count by comparison, not a scatter-add (0.29 ms for 32768
+    # assignments on the v5e against microseconds).
+    group_sizes = jnp.sum(
+        flat[:, None] == jnp.arange(num_experts, dtype=flat.dtype)[None, :],
+        axis=0, dtype=jnp.int32)
+    return flat, order, inv, group_sizes
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_to_sorted(x, order, inv, k):
+    """x [T, D] -> [kT, D]: row s is the token of the assignment at sorted
+    position s. Transposed as a gather by `inv` and a sum over the k
+    choices, not as a scatter-add."""
+    del inv, k
+    return x[order % x.shape[0]]
+
+
+def _rows_to_sorted_fwd(x, order, inv, k):
+    return _rows_to_sorted(x, order, inv, k), inv
+
+
+def _rows_to_sorted_bwd(k, inv, g):
+    dx = jnp.sum(g[inv].reshape(k, -1, g.shape[-1]), axis=0,
+                 dtype=jnp.float32)
+    return dx.astype(g.dtype), None, None
+
+
+_rows_to_sorted.defvjp(_rows_to_sorted_fwd, _rows_to_sorted_bwd)
+
+
+@jax.custom_vjp
+def _rows_from_sorted(ys, order, inv):
+    """ys [kT, D] in sorted order -> [kT, D] in assignment order; the
+    transpose of a permutation is the inverse permutation."""
+    del order
+    return ys[inv]
+
+
+def _rows_from_sorted_fwd(ys, order, inv):
+    return ys[inv], order
+
+
+def _rows_from_sorted_bwd(order, g):
+    return g[order], None, None
+
+
+_rows_from_sorted.defvjp(_rows_from_sorted_fwd, _rows_from_sorted_bwd)
+
+
+def _experts(xs, w_in, w_out, w_gate, act, matmul):
+    """The experts' feed-forward on rows `xs`; `matmul(rows, weights)` is
+    grouped (dropless) or batched (capacity)."""
+    h = matmul(xs, w_in)
+    if w_gate is not None:
+        h = act(matmul(xs, w_gate)) * h
+    else:
+        h = act(h)
+    return matmul(h, w_out)
 
 
 def moe_capacity(tokens, num_experts, capacity_factor):
@@ -97,17 +161,27 @@ def moe_capacity(tokens, num_experts, capacity_factor):
 
 
 def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
-            ep_axis=None, act=nn.silu, top_k=1):
-    """Switch (top_k=1) / GShard-style (top_k=2) MoE feed-forward over
-    flattened tokens.
+            ep_axis=None, act=nn.silu, top_k=1, w_gate=None,
+            renormalize=True):
+    """Routed feed-forward over flattened tokens.
 
-    x: [T, D]; router_w: [D, E] (replicated); w_in: [E_local, D, F],
-    w_out: [E_local, F, D] — E_local = E with ``ep_axis=None``, E/ep
-    inside shard_map with the expert dim sharded. With top_k>1 each
-    token consumes top_k capacity slots — size capacity_factor
-    accordingly (>= top_k for comparable drop rates).
+    x: [T, D], whose dtype is the compute dtype (the experts' weights may
+    be kept in f32: they are rounded to it where they are used, and their
+    gradients come back in their own dtype); router_w: [D, E]
+    (replicated); w_in: [E_local, D, F],
+    w_out: [E_local, F, D] and, for gated experts, w_gate: [E_local, D, F]
+    (`w_out(act(w_gate x) * w_in x)`, so `w_in` is the up projection) —
+    E_local = E with ``ep_axis=None``, E/ep inside shard_map with the
+    expert dim sharded. ``capacity_factor=None`` is dropless (local only);
+    with a factor each assignment consumes one of its expert's capacity
+    slots — size it accordingly (>= top_k for comparable drop rates).
 
-    Returns (y [T, D] in x.dtype, aux_loss scalar f32).
+    Returns (y [T, D] in x.dtype, stats): ``load_balance_loss`` and
+    ``router_z_loss`` (f32 scalars, `router_losses`), ``assignments`` ([E]
+    int32: what the router sent to each expert, summing to top_k * T),
+    ``chosen`` ([T, top_k] int32: each token's experts) and ``dropped``
+    (int32 scalar: assignments past their expert's capacity; 0 by
+    construction when dropless).
     """
     T, D = x.shape
     E = router_w.shape[1]
@@ -116,29 +190,73 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
         raise ValueError(
             "expert shards (%d local x ep=%d) != num_experts %d" %
             (w_in.shape[0], ep, E))
-    capacity = moe_capacity(T, E, capacity_factor)
-    logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
-    dispatch, combine, aux = topk_dispatch(logits, capacity, k=top_k)
+    if capacity_factor is None and ep_axis is not None:
+        raise ValueError("the dropless path (capacity_factor=None) is "
+                         "local; with ep_axis set a capacity_factor is "
+                         "required (all_to_all needs static buffers)")
+    with jax.named_scope(profile.MOE_ROUTE):
+        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+        weights, experts, probs = route(logits, top_k, renormalize)
+    with jax.named_scope(profile.MOE_DISPATCH):
+        flat, order, inv, group_sizes = sort_assignments(experts, E)
+    with jax.named_scope(profile.MOE_ROUTE):
+        balance, z = router_losses(logits, probs, group_sizes)
+    stats = {"load_balance_loss": balance, "router_z_loss": z,
+             "assignments": group_sizes, "chosen": experts}
+    weights = weights.T  # [k, T], the assignments' order
 
-    expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(x.dtype), x)
-    if ep_axis is not None:
-        # [E, C, D] -> [E/ep, ep*C, D]: each rank keeps its local
-        # experts' slots from EVERY rank's tokens.
-        expert_in = lax.all_to_all(expert_in, ep_axis, split_axis=0,
-                                   concat_axis=1, tiled=True)
-    h = act(jnp.einsum("ecd,edf->ecf", expert_in, w_in))
-    out = jnp.einsum("ecf,efd->ecd", h, w_out)
-    if ep_axis is not None:
-        # Reverse exchange: [E/ep, ep*C, D] -> [E, C, D].
-        out = lax.all_to_all(out, ep_axis, split_axis=1,
-                             concat_axis=0, tiled=True)
-    y = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), out)
-    return y.astype(x.dtype), aux
+    if capacity_factor is None:
+        with jax.named_scope(profile.MOE_DISPATCH):
+            xs = _rows_to_sorted(x, order, inv, top_k)
+        with jax.named_scope(profile.MOE_EXPERTS):
+            ys = _experts(xs, w_in, w_out, w_gate, act,
+                          lambda rows, w: grouped_matmul(rows, w,
+                                                         group_sizes))
+        with jax.named_scope(profile.MOE_COMBINE):
+            rows = _rows_from_sorted(ys, order, inv)
+        stats["dropped"] = jnp.zeros((), jnp.int32)
+    else:
+        C = moe_capacity(T, E, capacity_factor)
+        with jax.named_scope(profile.MOE_DISPATCH):
+            starts = jnp.cumsum(group_sizes) - group_sizes
+            slot = jnp.arange(C, dtype=jnp.int32)[None, :]
+            # Slot (e, c) holds the assignment at sorted position
+            # starts[e] + c, if expert e has that many.
+            taken = slot < group_sizes[:, None]
+            token = order[jnp.minimum(starts[:, None] + slot,
+                                      order.shape[0] - 1)] % T
+            expert_in = jnp.where(taken[..., None], x[token], 0)
+            if ep_axis is not None:
+                # [E, C, D] -> [E/ep, ep*C, D]: each rank keeps its local
+                # experts' slots from EVERY rank's tokens.
+                expert_in = lax.all_to_all(expert_in, ep_axis, split_axis=0,
+                                           concat_axis=1, tiled=True)
+        with jax.named_scope(profile.MOE_EXPERTS):
+            out = _experts(expert_in, w_in, w_out, w_gate, act,
+                           lambda rows, w: jnp.einsum(
+                               "ecd,edf->ecf", rows, w.astype(rows.dtype)))
+        with jax.named_scope(profile.MOE_COMBINE):
+            if ep_axis is not None:
+                # Reverse exchange: [E/ep, ep*C, D] -> [E, C, D].
+                out = lax.all_to_all(out, ep_axis, split_axis=1,
+                                     concat_axis=0, tiled=True)
+            place = inv - starts[flat]  # the assignment's place in its queue
+            kept = place < C
+            rows = out.reshape(E * C, D)[flat * C + jnp.minimum(place, C - 1)]
+            weights = jnp.where(kept.reshape(weights.shape), weights, 0.0)
+        stats["dropped"] = jnp.sum(~kept, dtype=jnp.int32)
+    with jax.named_scope(profile.MOE_COMBINE):
+        y = jnp.einsum("ktd,kt->td", rows.reshape(top_k, T, D), weights,
+                       preferred_element_type=jnp.float32)
+    return y.astype(x.dtype), stats
 
 
 class MoeMlp(nn.Module):
     """Drop-in MoE replacement for a transformer MLP: [B, L, D] ->
-    [B, L, D] plus a sown ``intermediates/moe_aux_loss``.
+    [B, L, D], with `moe_ffn`'s statistics sown under ``intermediates``
+    (``moe_aux_loss``: the load-balancing loss, ``moe_z_loss``,
+    ``moe_assignments``, ``moe_dropped``, ``moe_chosen``;
+    `router_aux_losses` and `routing_stats` collect them over the layers).
 
     ``num_experts`` is GLOBAL; ``ep_size`` is the expert-parallel
     degree the module will be APPLIED under — inside shard_map each
@@ -148,10 +266,12 @@ class MoeMlp(nn.Module):
     `ep_param_specs`, apply with the ep-sized module."""
     num_experts: int
     mlp_dim: int
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25   # None: dropless
     ep_axis: Optional[str] = None
     ep_size: int = 1
     top_k: int = 1
+    gated: bool = False
+    renormalize: bool = True
     dtype: Any = jnp.bfloat16
 
     @nn.compact
@@ -163,18 +283,65 @@ class MoeMlp(nn.Module):
         e_local = self.num_experts // self.ep_size
         router_w = self.param("router", nn.initializers.normal(0.02),
                               (D, self.num_experts), jnp.float32)
-        w_in = self.param("w_in", nn.initializers.normal(0.02),
-                          (e_local, D, self.mlp_dim),
-                          jnp.float32)
-        w_out = self.param("w_out", nn.initializers.normal(0.02),
-                           (e_local, self.mlp_dim, D),
-                           jnp.float32)
-        y, aux = moe_ffn(x.reshape(-1, D), router_w,
-                         w_in.astype(self.dtype), w_out.astype(self.dtype),
-                         capacity_factor=self.capacity_factor,
-                         ep_axis=self.ep_axis, top_k=self.top_k)
-        self.sow("intermediates", "moe_aux_loss", aux)
+
+        def expert(name, rows, cols):
+            return self.param(name, nn.initializers.normal(0.02),
+                              (e_local, rows, cols), jnp.float32)
+
+        if self.gated:
+            w_gate = expert("w_gate", D, self.mlp_dim)
+            w_in = expert("w_up", D, self.mlp_dim)
+            w_out = expert("w_down", self.mlp_dim, D)
+        else:
+            w_gate = None
+            w_in = expert("w_in", D, self.mlp_dim)
+            w_out = expert("w_out", self.mlp_dim, D)
+        with jax.named_scope(profile.MOE):
+            y, stats = moe_ffn(x.reshape(-1, D).astype(self.dtype), router_w,
+                               w_in, w_out,
+                               capacity_factor=self.capacity_factor,
+                               ep_axis=self.ep_axis, top_k=self.top_k,
+                               w_gate=w_gate, renormalize=self.renormalize)
+        for name, key in (("moe_aux_loss", "load_balance_loss"),
+                          ("moe_z_loss", "router_z_loss"),
+                          ("moe_assignments", "assignments"),
+                          ("moe_dropped", "dropped"),
+                          ("moe_chosen", "chosen")):
+            self.sow("intermediates", name, stats[key])
         return y.reshape(B, L, D)
+
+
+def _sown(intermediates, name):
+    """Every value sown as `name` anywhere in the tree, in the tree's
+    order (one per MoE layer)."""
+    found = []
+    for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
+        if any(getattr(k, "key", None) == name for k in path):
+            found.append(leaf)
+    return found
+
+
+def router_aux_losses(intermediates):
+    """(load-balancing loss, router z-loss), each the mean over the MoE
+    layers whose `MoeMlp` sowed into `intermediates` (`model.apply(...,
+    mutable=["intermediates"])`): what a loss function adds to the
+    cross-entropy at the weights its job states."""
+    balance = _sown(intermediates, "moe_aux_loss")
+    z = _sown(intermediates, "moe_z_loss")
+    if not balance:
+        raise ValueError("no MoeMlp sowed into these intermediates")
+    return sum(balance) / len(balance), sum(z) / len(z)
+
+
+def routing_stats(intermediates):
+    """A step's routing statistics from the same tree: ``assignments``
+    [layers, E] int32 (each row sums to top_k * tokens), ``chosen``
+    [layers, tokens, top_k] int32 (each token's experts) and ``dropped``
+    (int32 scalar over all layers; 0 on the dropless path)."""
+    return {"assignments": jnp.stack(_sown(intermediates,
+                                           "moe_assignments")),
+            "chosen": jnp.stack(_sown(intermediates, "moe_chosen")),
+            "dropped": sum(_sown(intermediates, "moe_dropped"))}
 
 
 def ep_grad_sync(grads, ep_axis="ep", dp_axis=None, average=False):
@@ -184,7 +351,7 @@ def ep_grad_sync(grads, ep_axis="ep", dp_axis=None, average=False):
     Contract: differentiate a LOCAL (un-psummed) loss per rank, then
     call this. With tokens sharded over (dp x ep), raw gradients are:
 
-    * expert-sharded leaves (param name ``w_in``/``w_out``): already
+    * expert-sharded leaves (final key in `EXPERT_LEAVES`): already
       summed along ep (the all_to_all transpose routes every ep peer's
       cotangents back to the owning rank) — psum over the dp axes only;
     * replicated leaves (router, norms, ...): this rank's token shard
@@ -205,11 +372,10 @@ def ep_grad_sync(grads, ep_axis="ep", dp_axis=None, average=False):
             total = total * lax.axis_size(ax)
 
     def sync(path, g):
-        names = [getattr(k, "key", None) for k in path]
         axes = list(dp_axes)
         # Same final-key rule as ep_param_specs — the two halves of
         # the placement/sync contract must classify leaves identically.
-        if not (names and names[-1] in ("w_in", "w_out")):
+        if not _is_expert_leaf(path):
             axes.append(ep_axis)
         for ax in axes:
             g = lax.psum(g, ax)
@@ -220,20 +386,19 @@ def ep_grad_sync(grads, ep_axis="ep", dp_axis=None, average=False):
     return jax.tree_util.tree_map_with_path(sync, grads)
 
 
+def _is_expert_leaf(path):
+    return bool(path) and getattr(path[-1], "key", None) in EXPERT_LEAVES
+
+
 def ep_param_specs(params, ep_axis, replicated_spec=None):
     """PartitionSpecs for a params tree containing MoeMlp leaves:
-    expert-dim sharding for w_in/w_out, replication elsewhere.
+    expert-dim sharding for the expert weights, replication elsewhere.
 
     Walks the tree by key name (the MoeMlp param names are the
     contract), mirroring `tensor_parallel.tp_param_specs`."""
     from jax.sharding import PartitionSpec as P
 
     rep = replicated_spec if replicated_spec is not None else P()
-
-    def spec_for(path, leaf):
-        names = [getattr(k, "key", None) for k in path]
-        if names and names[-1] in ("w_in", "w_out"):
-            return P(ep_axis)
-        return rep
-
-    return jax.tree_util.tree_map_with_path(spec_for, params)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: P(ep_axis) if _is_expert_leaf(path) else rep,
+        params)

@@ -47,6 +47,15 @@ HEAD = "hvd_head"
 LOSS = "hvd_loss"
 MODEL_SCOPES = (EMBED, BLOCK, STEM) + STAGES + (HEAD, LOSS)
 
+# The routed feed-forward (`parallel/expert.py::MoeMlp`), inside a block's
+# `mlp` half: `MOE` around all of it, the four others inside `MOE`.
+MOE = "hvd_moe"
+MOE_ROUTE = "hvd_moe_route"        # router matmul, softmax, top-k, losses
+MOE_DISPATCH = "hvd_moe_dispatch"  # sort, group sizes, gather of the rows
+MOE_EXPERTS = "hvd_moe_experts"    # the grouped matmuls and the gate
+MOE_COMBINE = "hvd_moe_combine"    # gather back, weights, sum over choices
+MOE_SCOPES = (MOE, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
+
 # The `name=` of every `pl.pallas_call`: on the chip's trace the kernel's
 # instruction is `<name>.<n>` and its scope path ends in
 # `<name>/pallas_call`.
@@ -58,8 +67,12 @@ RING_ATTN_DQ = "hvd_ring_attn_dq"    # one backward step: the dQ part
 RING_ATTN_DKV = "hvd_ring_attn_dkv"  # one backward step: the dK/dV part
 BN_STATS = "hvd_bn_stats"
 BN_GRAD_STATS = "hvd_bn_grad_stats"
+MOE_GMM = "hvd_moe_gmm"            # grouped matmul of the experts, forward
+MOE_GMM_DLHS = "hvd_moe_gmm_dlhs"  # backward: the gradient of the rows
+MOE_GMM_DRHS = "hvd_moe_gmm_drhs"  # backward: the gradient of the matrices
+MOE_GMM_KERNELS = (MOE_GMM, MOE_GMM_DLHS, MOE_GMM_DRHS)
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, RING_ATTN, RING_ATTN_DQ,
-           RING_ATTN_DKV, BN_STATS, BN_GRAD_STATS)
+           RING_ATTN_DKV, BN_STATS, BN_GRAD_STATS) + MOE_GMM_KERNELS
 
 # Host spans of the program's only per-call Python.
 SPAN_PLACE = "hvd_place"                  # `step.place`

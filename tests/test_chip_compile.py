@@ -15,6 +15,7 @@ live in this one file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -152,6 +153,31 @@ def test_bn_grad_stats_compiles_for_v5e(one_chip, M, C):
     text = _compile(one_chip, batch_norm.batch_norm_grad_stats,
                     ((M, C), bf16), ((M, C), bf16), ((C,), f32), ((C,), f32))
     assert _kernels(text) == 1, text[:2000]
+
+
+# The experts of OLMoE-1B-7B on one chip: 8 x 4096 assigned rows in 64
+# groups, f32 matrices of 2048 x 1024 under bf16 rows (`benchmark`'s cell
+# `olmoe1b7_1chip`), the up projection's shapes and the down projection's.
+@pytest.mark.parametrize("K,N", [(2048, 1024), (1024, 2048)])
+def test_grouped_matmul_compiles_for_v5e(one_chip, K, N):
+    from horovod_tpu import profile
+    from horovod_tpu.ops.grouped_matmul import grouped_matmul
+
+    def fwd_bwd(lhs, rhs, sizes, g):
+        out, vjp = jax.vjp(
+            lambda l, r: grouped_matmul(l, r, sizes, interpret=False),
+            lhs, rhs)
+        return out, vjp(g)
+
+    text = _compile(one_chip, fwd_bwd, ((32768, K), jnp.bfloat16),
+                    ((64, K, N), jnp.float32), ((64,), jnp.int32),
+                    ((32768, N), jnp.bfloat16))
+    # forward, the rows' gradient, the matrices' gradient
+    assert _kernels(text) == 3, text[:2000]
+    for name in profile.MOE_GMM_KERNELS:
+        # `jvp(hvd_moe_gmm)/pallas_call` here; inside a model's scopes the
+        # path ends `.../hvd_moe_gmm/pallas_call`.
+        assert re.search(r"\b%s\)*/pallas_call" % name, text), name
 
 
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------

@@ -100,3 +100,30 @@ def test_native_core_always_goes_through_make(monkeypatch):
     basics._ensure_built()
     assert len(calls) == 1 and calls[0][0][0] == "make", calls
     assert os.path.samefile(calls[0][1], basics._NATIVE_DIR)
+
+
+def test_moe_phase_steps_on_the_cpu(monkeypatch):
+    """The smoke's routed-feed-forward step (`chip_smoke.moe_step`: QK-norm,
+    dropless top-k over gated experts, the router's auxiliary losses,
+    `make_train_step`) at a tiny size on the CPU: the loss falls and no
+    assignment is dropped, by the smoke's own checks."""
+    import jax
+
+    import chip_smoke
+    from horovod_tpu import parallel
+
+    monkeypatch.setitem(chip_smoke.SIZES, "moe", dict(
+        chip_smoke.SIZES["moe"], vocab_size=128, num_heads=2, embed_dim=32,
+        mlp_dim=16, max_seq_len=32, moe_experts=8, moe_top_k=3))
+    monkeypatch.setitem(chip_smoke.SIZES, "moe_batch", 2)
+    monkeypatch.setitem(chip_smoke.SIZES, "moe_len", 32)
+    mesh = parallel.data_parallel_mesh(devices=jax.devices("cpu")[:1])
+    step, state, routing = chip_smoke.moe_step(mesh, 3, attention="dense")
+    params, opt_state, batch = step.place(*state)
+    chip_smoke.check_routing(routing(params, batch), 3 * 2 * 32)
+    params, opt_state, losses, _ = chip_smoke.run_steps(
+        step, params, opt_state, batch, 3)
+    chip_smoke.check_losses(losses)
+    chip_smoke.check_routing(routing(params, batch), 3 * 2 * 32)
+    with pytest.raises(chip_smoke.PhaseFailed):
+        chip_smoke.check_routing(routing(params, batch), 3 * 2 * 32 + 1)

@@ -1,6 +1,7 @@
-"""Expert parallelism (Switch MoE + ep all_to_all): routing semantics,
-dense equivalence, sharded-vs-unsharded equality, gradients, and the
-MoeMlp module (virtual 8-device CPU mesh)."""
+"""Expert parallelism (routed MoE feed-forward + ep all_to_all): routing
+semantics, capacity and drops, dense equivalence, sharded-vs-unsharded
+equality, gradients, and the MoeMlp module (virtual 8-device CPU mesh).
+The dropless path and OLMoE are in tests/test_moe_dropless.py."""
 
 import numpy as np
 import pytest
@@ -10,30 +11,39 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.parallel.expert import (MoeMlp, ep_param_specs,
-                                         moe_capacity, moe_ffn,
-                                         switch_dispatch)
+                                         moe_capacity, moe_ffn, route,
+                                         router_aux_losses,
+                                         sort_assignments)
 
 jax.config.update("jax_default_matmul_precision", "highest")
 
 
-def test_switch_dispatch_routing_and_capacity():
-    # 4 tokens, 2 experts: tokens 0,1,3 -> expert 1; token 2 -> expert 0.
+def test_capacity_routing_queues_and_drops():
+    """(Replaces the one-hot `switch_dispatch` test.) 4 tokens, 2 experts,
+    capacity 2: tokens 0, 1, 3 choose expert 1 and token 2 expert 0, so
+    expert 1's queue is token 0, token 1 and token 3 is DROPPED (its output
+    is zero: the residual path passes through untouched)."""
+    import flax.linen as nn
+
     logits = jnp.asarray([[0.0, 2.0],
                           [0.0, 3.0],
                           [4.0, 0.0],
                           [0.0, 1.0]], jnp.float32)
-    dispatch, combine, aux = switch_dispatch(logits, capacity=2)
-    d = np.asarray(dispatch)
-    # Expert 1 queue: token0 -> slot0, token1 -> slot1, token3 DROPPED
-    # (capacity 2 full).
-    assert d[0, 1, 0] == 1 and d[1, 1, 1] == 1
-    assert d[3].sum() == 0
-    assert d[2, 0, 0] == 1
-    # Combine carries the softmax gate of the chosen expert.
+    rng = np.random.RandomState(0)
+    w_in = jnp.asarray(rng.randn(2, 2, 3).astype(np.float32))
+    w_out = jnp.asarray(rng.randn(2, 3, 2).astype(np.float32))
+    # x = the logits themselves, through an identity router
+    y, stats = moe_ffn(logits, jnp.eye(2), w_in, w_out, capacity_factor=1.0)
+    assert moe_capacity(4, 2, 1.0) == 2
+    assert int(stats["dropped"]) == 1
+    assert list(np.asarray(stats["assignments"])) == [1, 3]
+    np.testing.assert_array_equal(np.asarray(y)[3], 0.0)
+    # A kept token carries the softmax gate of its expert.
     probs = np.asarray(jax.nn.softmax(logits, -1))
-    np.testing.assert_allclose(np.asarray(combine)[0, 1, 0], probs[0, 1],
-                               rtol=1e-6)
-    assert float(aux) > 0
+    expect = probs[0, 1] * np.asarray(
+        nn.silu(logits[0] @ w_in[1]) @ w_out[1])
+    np.testing.assert_allclose(np.asarray(y)[0], expect, rtol=1e-5)
+    assert float(stats["load_balance_loss"]) > 0
 
 
 def test_moe_ffn_matches_per_token_expert_computation():
@@ -79,9 +89,9 @@ def test_ep_sharded_matches_unsharded():
     mesh = _mesh_dp_ep(2, 4)
 
     def sharded(x, router, w_in, w_out):
-        y, aux = moe_ffn(x, router, w_in, w_out, capacity_factor=cf,
-                         ep_axis="ep")
-        return y, lax_pmean_all(aux)
+        y, stats = moe_ffn(x, router, w_in, w_out, capacity_factor=cf,
+                           ep_axis="ep")
+        return y, lax_pmean_all(stats["load_balance_loss"])
 
     from jax import lax
 
@@ -171,9 +181,16 @@ def test_moe_mlp_module_and_param_specs():
     assert y.shape == x.shape
     aux = state["intermediates"]["moe_aux_loss"][0]
     assert float(aux) > 0
+    balance, z = router_aux_losses(state["intermediates"])
+    assert float(balance) == float(aux) and float(z) > 0
     specs = ep_param_specs(variables["params"], "ep")
     assert specs["w_in"] == P("ep") and specs["w_out"] == P("ep")
     assert specs["router"] == P()
+    gated = MoeMlp(num_experts=4, mlp_dim=32, gated=True, dtype=jnp.float32)
+    gspecs = ep_param_specs(gated.init(jax.random.PRNGKey(0), x)["params"],
+                            "ep")
+    assert gspecs == {"router": P(), "w_gate": P("ep"), "w_up": P("ep"),
+                      "w_down": P("ep")}
 
 
 def test_capacity_helper():
@@ -222,7 +239,7 @@ def test_moe_transformer_train_step_dp_ep():
         tgt = jnp.roll(tokens, -1, axis=1)
         logp = jax.nn.log_softmax(logits)
         xent = -jnp.mean(jnp.take_along_axis(logp, tgt[..., None], -1))
-        aux = sum(jax.tree_util.tree_leaves(state["intermediates"]))
+        aux, _ = router_aux_losses(state["intermediates"])
         return xent + 0.01 * aux
 
     def step(params, opt_state, tokens):
@@ -299,25 +316,28 @@ def test_moe_with_ring_attention_sp_ep_mesh():
                                rtol=2e-4, atol=2e-4)
 
 
-def test_top2_dispatch_routing():
-    """Top-2: both chosen experts get slots, gates renormalize to 1,
-    second choices queue after ALL first choices (GShard ordering)."""
-    from horovod_tpu.parallel.expert import topk_dispatch
-
+def test_top2_routing_and_queue_order():
+    """(Replaces the one-hot `topk_dispatch` test.) Top-2: both chosen
+    experts, gates renormalized to 1, and in the sorted order the second
+    choices queue after ALL first choices (GShard ordering)."""
     logits = jnp.asarray([[3.0, 2.0, -5.0],
                           [2.5, 3.5, -5.0]], jnp.float32)
-    dispatch, combine, aux = topk_dispatch(logits, capacity=4, k=2)
-    d = np.asarray(dispatch)
-    c = np.asarray(combine)
-    # First choices: t0 -> e0 slot0, t1 -> e1 slot0.
-    assert d[0, 0, 0] == 1 and d[1, 1, 0] == 1
-    # Second choices enqueue after first-round counts: t0 -> e1 gets
-    # slot 1 (e1 already has t1's first choice), t1 -> e0 slot 1.
-    assert d[0, 1, 1] == 1 and d[1, 0, 1] == 1
-    # Gates renormalized per token: the two combine weights sum to 1.
-    np.testing.assert_allclose(c[0].sum(), 1.0, rtol=1e-6)
-    np.testing.assert_allclose(c[1].sum(), 1.0, rtol=1e-6)
-    assert float(aux) > 0
+    weights, experts, probs = route(logits, 2, renormalize=True)
+    np.testing.assert_array_equal(np.asarray(experts), [[0, 1], [1, 0]])
+    np.testing.assert_allclose(np.asarray(weights).sum(-1), 1.0, rtol=1e-6)
+    raw, _, _ = route(logits, 2, renormalize=False)
+    np.testing.assert_allclose(np.asarray(raw)[0],
+                               np.asarray(probs)[0, :2], rtol=1e-6)
+    flat, order, inv, sizes = sort_assignments(experts, 3)
+    # assignment a = choice * T + token: 0: t0 -> e0, 1: t1 -> e1 (first
+    # choices), 2: t0 -> e1, 3: t1 -> e0 (second choices)
+    np.testing.assert_array_equal(np.asarray(flat), [0, 1, 1, 0])
+    # e0's queue: t0's first choice, then t1's second; e1's: t1's first
+    # choice, then t0's second.
+    np.testing.assert_array_equal(np.asarray(order), [0, 3, 1, 2])
+    np.testing.assert_array_equal(np.asarray(order)[np.asarray(inv)],
+                                  np.arange(4))
+    np.testing.assert_array_equal(np.asarray(sizes), [2, 2, 0])
 
 
 def test_top2_moe_ffn_matches_per_token():
