@@ -354,6 +354,23 @@ def attention_case(B, H, G, L, D, rotary, dtype, seed):
     return name, both(kernel), both(reference), (q, k, v, w)
 
 
+def print_flash_plan(B, H, G, L, D, rotary, dtype):
+    """Which path each flash kernel of this shape takes (`hvd.profile`)."""
+    from horovod_tpu import profile
+
+    for backward in (False, True):
+        for name, plan in profile.flash_plan(B, H, L, D, H // G, dtype,
+                                             backward, rotary).items():
+            print("  %s: %s, blocks %d x %d, grid %s = %d steps, VMEM %.1f "
+                  "MiB%s" % (name, plan.path, plan.block_q, plan.block_k,
+                             plan.grid, plan.grid_steps,
+                             plan.vmem_bytes / 2 ** 20,
+                             "" if plan.vmem_limit_bytes is None else
+                             " of a limit of %.0f" % (
+                                 plan.vmem_limit_bytes / 2 ** 20)),
+                  flush=True)
+
+
 def attention_vs_reference(case, tol):
     """The kernel is in the program, and on the chip it agrees with the
     reference."""
@@ -500,6 +517,7 @@ def phase_kernels(args):
     del step, state, compiled, params, opt_state, routing
 
     for i, shape in enumerate(SIZES["attn"]):
+        print_flash_plan(*shape, jnp.bfloat16)
         attention_vs_reference(
             attention_case(*shape, jnp.bfloat16, args.seed + i),
             TOL["attn_bf16"])

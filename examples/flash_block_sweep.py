@@ -1,11 +1,19 @@
-"""Flash-kernel block-size sweep at a given attention shape.
+"""Flash-kernel block-size sweep at a given attention shape, on both
+paths of the plain kernels.
 
-The default (bq=256, bk=512) was tuned at D=128; the GPT-2-shaped
-bench runs D=64 H=12 where the VMEM budget and the VPU/MXU balance
-differ. Sweeps (block_q, block_k) for fwd and fwd+bwd with the
-single-dispatch lax.scan recipe and prints a table.
+`flash_plan` (ops/flash_attention.py) sends a kernel down the resident
+path (the other sequence whole in VMEM, walked by a loop inside the
+kernel) when its whole-sequence operands fit the VMEM budget, and down
+the gridded path (one pipeline step a tile) otherwise. This sweeps
+(block_q, block_k) on each path for the three kernels alone — forward,
+dQ, dK/dV; a backward kernel whose gradients are unused is dropped by
+XLA, so each is timed by itself — with the single-dispatch lax.scan
+recipe, and prints the plan the defaults give. The block tables
+(`_resident_blocks`; `_default_blocks` and `_grouped_blocks` for the
+gridded path) were read off it.
 
-Usage: python examples/flash_block_sweep.py [--B 8 --L 2048 --H 12 --D 64]
+Usage: python examples/flash_block_sweep.py [--B 2 --L 2048 --H 16 --D 128]
+           [--path both|resident|gridded]
 GQA/MQA (--G < --H) sweeps the grouped-rows layout: the q-block
 candidates become bqp*group rows. The `_grouped_blocks` policy was
 tuned from this sweep at two points — B2 H6 G2 L8192 D128 (1536/512)
@@ -14,7 +22,6 @@ grouped layouts want bigger row blocks and bk=512 at long L.
 """
 
 import argparse
-import functools
 import time
 
 import jax
@@ -28,14 +35,15 @@ import importlib
 # same name; import the module itself for the block-size internals.
 fa = importlib.import_module("horovod_tpu.ops.flash_attention")
 
+# `vmem_budget` that forces a path: nothing fits 0, everything fits 2**40.
+BUDGETS = {"gridded": 0, "resident": 2 ** 40}
+
 
 def timed(fn, args, iters=30):
     def body(carry, _):
         out = fn(*carry)
-        if isinstance(out, tuple):
-            out = out[0]
-        # Cast: fwd returns a bf16 tensor but the fwd+bwd probe
-        # returns an f32 scalar, which would promote the carry.
+        # Cast: fwd returns a bf16 tensor but the backward probes
+        # return an f32 scalar, which would promote the carry.
         return (carry[0] + (1e-30 * out).astype(carry[0].dtype),) \
             + carry[1:], ()
 
@@ -53,21 +61,33 @@ def timed(fn, args, iters=30):
     return sorted(times)[1]
 
 
+def total(*grads):
+    return sum(jnp.sum(x.astype(jnp.float32)) for x in grads)
+
+
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--B", type=int, default=8)
+    ap.add_argument("--B", type=int, default=2)
     ap.add_argument("--L", type=int, default=2048)
-    ap.add_argument("--H", type=int, default=12)
+    ap.add_argument("--H", type=int, default=16)
     ap.add_argument("--G", type=int, default=0,
                     help="kv heads (GQA/MQA; 0 = H, plain MHA). The "
                          "q-block candidates become bqp*group rows in "
                          "the grouped layout")
-    ap.add_argument("--D", type=int, default=64)
+    ap.add_argument("--D", type=int, default=128)
+    ap.add_argument("--rotary", action="store_true",
+                    help="fused rotary (base 10000)")
+    ap.add_argument("--path", default="both",
+                    choices=("both", "resident", "gridded"))
+    ap.add_argument("--bqp", default="128,256,512",
+                    help="q-block candidates, in positions")
+    ap.add_argument("--bk", default="256,512,1024")
     ap.add_argument("--iters", type=int, default=30)
     args = ap.parse_args()
     B, L, H, D = args.B, args.L, args.H, args.D
     G = args.G or H
     group = H // G
+    base = 10000.0 if args.rotary else None
 
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(B, H, L, D), jnp.bfloat16)
@@ -76,37 +96,45 @@ def main():
     g = jnp.asarray(rng.randn(B, H, L, D), jnp.bfloat16)
     scale = D ** -0.5
     rows = L * group
+    out, lse = jax.jit(lambda q, k, v: fa._pallas_forward_lse(
+        q, k, v, scale, True, False, rotary_base=base))(q, k, v)
 
-    print("shape B=%d L=%d H=%d G=%d D=%d (kernel layout, %d rows/slab)"
-          % (B, L, H, G, D, rows))
-    print("%8s %8s | %9s | %9s" % ("bq", "bk", "fwd ms", "fwd+bwd ms"))
-    for bqp in (128, 256, 512):
-        bq = bqp * group
-        for bk in (256, 512, 1024):
-            if rows % bq or L % bk or L % bqp:
-                continue
-            try:
-                fwd = functools.partial(
-                    fa._pallas_forward, scale=scale, causal=True,
-                    interpret=False, block_q=bq, block_k=bk)
-                t_fwd = timed(lambda q: fwd(q, k, v), (q,), args.iters)
+    print("shape B=%d L=%d H=%d G=%d D=%d%s (kernel layout, %d rows/slab)"
+          % (B, L, H, G, D, " rotary" if base else "", rows))
+    for backward in (False, True):
+        for name, plan in fa.flash_plan(B, H, L, D, group, q.dtype,
+                                        backward, base is not None).items():
+            print("default plan %s: %s" % (name, plan._asdict()))
+    paths = ("gridded", "resident") if args.path == "both" else (args.path,)
+    print("%9s %6s %6s | %9s %9s %9s" % ("path", "bq", "bk", "fwd ms",
+                                         "dq ms", "dkv ms"))
+    for path in paths:
+        budget = BUDGETS[path]
+        for bqp in (int(x) for x in args.bqp.split(",")):
+            bq = bqp * group
+            for bk in (int(x) for x in args.bk.split(",")):
+                if rows % bq or L % bk or L % bqp:
+                    continue
 
-                def fb(q, k, v, g, bq=bq, bk=bk):
-                    out, lse = fa._pallas_forward_lse(
-                        q, k, v, scale, True, False, bq, bk)
-                    dq, dk, dv = fa._pallas_backward(
-                        q, k, v, out, lse, g, scale, True, False, bq, bk)
-                    # All three grads live (dq/dk shapes differ under
-                    # GQA; a dead output would let XLA drop a kernel).
-                    return (jnp.sum(dq.astype(jnp.float32)) +
-                            jnp.sum(dk.astype(jnp.float32)) +
-                            jnp.sum(dv.astype(jnp.float32)))
+                def fwd(q, bq=bq, bk=bk):
+                    return fa._pallas_forward_lse(
+                        q, k, v, scale, True, False, bq, bk, base,
+                        budget)[0]
 
-                t_fb = timed(lambda q: fb(q, k, v, g), (q,), args.iters)
-                print("%8d %8d | %9.3f | %9.3f" %
-                      (bq, bk, t_fwd * 1e3, t_fb * 1e3))
-            except Exception as e:
-                print("%8d %8d | failed: %s" % (bq, bk, str(e)[:60]))
+                def bwd(q, bq=bq, bk=bk):
+                    return fa._pallas_backward(
+                        q, k, v, out, lse, g, scale, True, False, bq, bk,
+                        base, budget)
+
+                cells = []
+                for probe in (fwd, lambda q: total(bwd(q)[0]),
+                              lambda q: total(*bwd(q)[1:])):
+                    try:
+                        cells.append("%9.3f" % (
+                            timed(probe, (q,), args.iters) * 1e3))
+                    except Exception as e:  # a block table Mosaic refuses
+                        cells.append("failed: %s" % str(e)[:40])
+                print("%9s %6d %6d | %s" % (path, bq, bk, " ".join(cells)))
 
 
 if __name__ == "__main__":

@@ -1,15 +1,40 @@
 """Flash attention as a Pallas TPU kernel.
 
-Forward: a (batch*kv_head, q-block, k-block) grid. The k dimension is the
-innermost sequential axis: each step's k/v block is streamed HBM->VMEM by
-the Pallas pipeline (double-buffered against the MXU work of the previous
-block), while the online-softmax state (acc, running max, running sum)
-lives in VMEM scratch that persists across the k steps of one q block —
-the standard TPU flash recipe (128-aligned blocks, bf16 inputs, f32
-accumulation). Causal masking skips both the compute (`pl.when`) and
-the fetch (index maps clamp above-diagonal steps to the frontier
-block; Pallas elides the DMA for a revisited block index) of k-blocks
-above the diagonal — at long L this halves attention HBM traffic.
+The three plain kernels (forward, dQ, dK/dV) are one algorithm in two
+forms, and `flash_plan` chooses between them per kernel from the call's
+shapes alone (L, D, the head group, the dtype, fused rotary) — no
+argument, no environment variable:
+
+- resident, when the whole-sequence operands fit a VMEM budget (forward
+  and dQ: k and v; dK/dV: q, dO, lse and delta), which at D=128 and one
+  head a kv head is every kernel up to L=8192, and forward and dQ at
+  16384. Grid (batch*kv_head, q-block) — (batch*kv_head,
+  k-block) for dK/dV. The other sequence comes in as ONE block per
+  batch*kv_head: its block index does not change across the inner grid
+  axis, so Pallas fetches it once and double-buffers the next one behind
+  this one's work. The kernel walks its row (column) of the causal
+  triangle itself: a `fori_loop` over the blocks wholly below the
+  diagonal with no mask, then the one or two blocks that straddle it,
+  peeled, masked unconditionally. The online-softmax state (acc,
+  running max, running sum; dq; dk and dv) is carried by the loop, not
+  stored to and loaded from scratch on every tile, and no tile above
+  the diagonal costs anything. A grid step costs 0.43 us on the v5e
+  and the gridded form pays one per tile: at L=2048 that was 44% of
+  the kernels' time (PERF.md, PR 28).
+- gridded, beyond the budget (what the ring-step kernels always are): a
+  (batch*kv_head, q-block, k-block) grid, k innermost and sequential.
+  Each step's k/v block is streamed HBM->VMEM by the
+  Pallas pipeline (double-buffered against the MXU work of the previous
+  block), while the online-softmax state lives in VMEM scratch that
+  persists across the k steps of one q block — the standard TPU flash
+  recipe. Causal masking skips both the compute (`pl.when`) and the
+  fetch (index maps clamp above-diagonal steps to the frontier block;
+  Pallas elides the DMA for a revisited block index) of k-blocks above
+  the diagonal — at long L this halves attention HBM traffic.
+
+Both forms visit the same tiles in the same order with the same
+arithmetic: 128-aligned blocks, bf16 operands into every matmul, f32
+accumulation and f32 softmax.
 
 GQA/MQA (num_kv_heads < num_heads) uses a grouped-rows layout: the
 `group = H / G` query heads sharing one kv head are interleaved into the
@@ -29,11 +54,12 @@ MXU step and measured ~2x whole-kernel cost at L=8192. Instead the
 caller builds full-width (C, S) tables once per call (f32, sign folded
 into S; XLA CSEs them across layers) and the kernels stream table
 blocks through the same index maps as q/k — per-visit work drops to
-one lane-roll + 2 mul + 1 add (`_rot_apply`), and rotated q is cached
-in VMEM scratch for the whole k sweep. Rotation is linear-orthogonal
+one lane-roll + 2 mul + 1 add (`_rot`), and q is rotated once for the
+whole k sweep (gridded: cached in VMEM scratch; resident: k's tables
+are whole in VMEM beside k). Rotation is linear-orthogonal
 per row, so the backward kernels rotate q/k the same way to recompute
 scores and counter-rotate finished dQ/dK blocks (the S sign flips —
-see `_rot_apply(neg=True)`) at finalize. The ring-step kernels instead
+see `_rot(neg=True)`) at finalize. The ring-step kernels instead
 accumulate gradients in rotated space across ring steps; the caller
 counter-rotates once after the last step (`apply_rotary(neg=True)`).
 
@@ -42,6 +68,7 @@ style). On non-TPU backends the same kernels run in Pallas interpret
 mode (tests) or fall back to the blockwise JAX implementation.
 """
 
+import collections
 import functools
 
 import jax
@@ -92,16 +119,21 @@ def _rope_tables(positions, D, base):
             jnp.concatenate([-s, s], axis=-1))
 
 
-def _rot_apply(x, cos_ref, sin_ref, neg=False):
-    """Rotate a [R, D] block by streamed tables: each row's pair
+def _rot(x, cos, sin, neg=False):
+    """Rotate a [R, D] block by its table rows: each row's pair
     partner sits half a lane-width away, fetched with one lane-roll.
     ``neg=True`` is the transpose rotation (gradient counter-rotation;
     for the baked-sign tables that is exactly an S sign flip)."""
     xf = x.astype(jnp.float32)
     partner = pltpu.roll(xf, x.shape[-1] // 2, 1)
-    ps = partner * sin_ref[...]
-    out = xf * cos_ref[...] + (-ps if neg else ps)
+    ps = partner * sin
+    out = xf * cos + (-ps if neg else ps)
     return out.astype(x.dtype)
+
+
+def _rot_apply(x, cos_ref, sin_ref, neg=False):
+    """`_rot` by streamed table blocks."""
+    return _rot(x, cos_ref[...], sin_ref[...], neg)
 
 
 def _to_rows(x, group):
@@ -122,29 +154,38 @@ def _from_rows(x, B, group):
             .reshape(B, G * group, L, D))
 
 
-def _masked_scores(q, k, scale, causal, q_off, kv_off, fill, group=1):
-    """s = (q.k^T)*scale with causal masking by global positions: q row
-    r is position q_off + r//group (grouped GQA layout; group=1 is the
-    plain layout). Only blocks straddling the diagonal pay the
-    elementwise mask pass (the kernels are VPU-bound, every pass
-    counts); `fill` is -inf for scores, 0 for probabilities."""
-    block_q, block_k = q.shape[0], k.shape[0]
-    s = jax.lax.dot_general(
+def _scores(q, k, scale):
+    """s = (q.k^T)*scale, f32 [BQ, BK], from native-dtype operands."""
+    return jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # [BQ, BK]
+        preferred_element_type=jnp.float32) * scale
+
+
+def _causal_mask(s, q_off, kv_off, fill, group=1):
+    """Causal masking of a score block by global positions: q row r is
+    position q_off + r//group (grouped GQA layout; group=1 is the plain
+    layout), column c is position kv_off + c."""
+    block_q, block_k = s.shape
+    riota = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    rows = q_off + (riota // group if group > 1 else riota)
+    cols = kv_off + lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    return jnp.where(rows >= cols, s, fill)
+
+
+def _masked_scores(q, k, scale, causal, q_off, kv_off, fill, group=1):
+    """Scores of one grid step with causal masking. Only blocks
+    straddling the diagonal pay the elementwise mask pass (the kernels
+    are VPU-bound, every pass counts); `fill` is -inf for scores, 0 for
+    probabilities."""
+    s = _scores(q, k, scale)  # [BQ, BK]
     if not causal:
         return s
-
-    def _mask(s):
-        riota = lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        rows = q_off + (riota // group if group > 1 else riota)
-        cols = kv_off + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        return jnp.where(rows >= cols, s, fill)
-
     # q_off is the POSITION of the block's first row.
-    straddles = kv_off + (block_k - 1) > q_off
-    return jax.lax.cond(straddles, _mask, lambda s: s, s)
+    straddles = kv_off + (k.shape[0] - 1) > q_off
+    return jax.lax.cond(
+        straddles,
+        lambda s: _causal_mask(s, q_off, kv_off, fill, group),
+        lambda s: s, s)
 
 
 def _online_softmax_update(s, v_ref, acc_ref, m_ref, l_ref, guard_empty):
@@ -354,72 +395,411 @@ def _row_positions(L, group):
     return jnp.repeat(jnp.arange(L, dtype=jnp.int32), group)
 
 
+# --- the plan: resident or gridded, per kernel ----------------------------
+#
+# One algorithm with one parameter that follows from the call's shapes: is
+# the sequence resident in VMEM? If it is, the innermost grid axis of the
+# gridded kernels above becomes a loop inside the kernel (below); if not,
+# the gridded kernels run as they always have.
+
+# What the whole-sequence operands of one resident kernel may take in
+# VMEM, both pipeline buffers counted. The v5e has 128 MiB of VMEM; the
+# budget is set by what was swept, not by what would fit: the resident
+# form beat the gridded one by 32-50% a kernel at every shape tried (D=128
+# and 64, group 1 and 3, fused rotary, L=1024 to 8192; PERF.md, PR 28),
+# the largest of them L=8192's dK/dV at D=128, 24 MiB.
+RESIDENT_VMEM_BUDGET = 24 * 2 ** 20
+# Mosaic's default scoped-VMEM limit on the v5e: a resident kernel asks
+# for more through `vmem_limit_bytes` when its own sum says so.
+_DEFAULT_VMEM_LIMIT = 16 * 2 ** 20
+
+FlashKernelPlan = collections.namedtuple(
+    "FlashKernelPlan",
+    "path block_q block_k grid grid_steps resident_bytes vmem_bytes "
+    "vmem_limit_bytes")
+FlashKernelPlan.__doc__ = """How one flash kernel of a call runs.
+
+path: "resident" (grid (B*G, blocks); the other sequence whole in VMEM,
+walked by a loop in the kernel) or "gridded" (grid (B*G, blocks, blocks),
+one pipeline step a tile). block_q counts ROWS of the grouped layout.
+grid_steps: pipeline steps the call issues. resident_bytes: the
+whole-sequence operands, double-buffered (0 when gridded). vmem_bytes:
+what the call's block specs and scratch take as padded in VMEM, both
+pipeline buffers counted. vmem_limit_bytes: what the call passes to
+Mosaic, from its own sum — the buffers, the kernel's values (s, p, dp,
+ds, the carried state) and a quarter more — never under the compiler's
+default (None: the default itself, which every gridded block table
+fits)."""
+
+
+def _vmem(rows, cols, itemsize):
+    """Bytes of a [rows, cols] array in VMEM: the lanes pad to 128."""
+    return rows * -(-cols // 128) * 128 * itemsize
+
+
+# Per kernel: arrays of width D in the inputs' dtype on the q side (q, o
+# / dO, dq) and on the k side (k, v, dk, dv), and 8-wide f32 stripes on
+# the q side (lse, delta).
+_OPERANDS = {profile.FLASH_FWD: (2, 2, 1), profile.FLASH_DQ: (3, 2, 2),
+             profile.FLASH_DKV: (2, 4, 2)}
+
+
+def _resident_blocks(D, L, group, kernel):
+    """Preferred (rows cap, block_k) inside the resident kernels' loop.
+    The reason for wide blocks on the gridded path, amortising a grid
+    step, is gone here; what is left is a loop turn's own overhead and
+    the size of s [BQ, BK] in VMEM. v5e sweep (PR 28,
+    examples/flash_block_sweep.py --path resident; ms a kernel and
+    layer). Group 1, the same answer at D=128 (L=2048, 4096, 8192, and
+    2048 with fused rotary) and D=64 (L=1024, 2048): forward and dQ
+    (512, 512), dK/dV (512, 1024) — at 2 x 16 x 2048 x 128: 0.442 /
+    0.532 / 0.714 against 0.466 / 0.572 / 0.857 for the gridded table's
+    (256, 512) on this path, and 0.998 / 0.977 / 1.283 gridded. Group 3
+    at D=128 (L=2048): k blocks of 512 and as many rows as the gridded
+    long-sequence cap, (1536, 512): 0.537 / 0.472 / 0.548 (384 x 512:
+    0.514 / 0.497 / 0.646). Grouped layouts at D<=64 were not swept on
+    this path and keep the gridded tables."""
+    if group == 1:
+        return (512, 1024) if kernel == profile.FLASH_DKV else (512, 512)
+    if D > 64:
+        return (1536, 512)
+    return _grouped_blocks(D, L, group, kernel != profile.FLASH_FWD)
+
+
+def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
+                 block_k, vmem_budget):
+    backward = kernel != profile.FLASH_FWD
+    dkv = kernel == profile.FLASH_DKV
+    n_q, n_k, n_stripes = _OPERANDS[kernel]
+    tables = 2 * 4 if rotary else 0  # (C, S) f32, per side
+
+    def q_side(n):  # one pipeline buffer of n rows of every q-side operand
+        return _vmem(n, D, n_q * isz + tables) + n_stripes * _vmem(n, 8, 4)
+
+    def k_side(n):
+        return _vmem(n, D, n_k * isz + tables)
+
+    def blocks(preferred):
+        bq = block_q or _pick_rows_block(L, preferred[0], group)
+        bk = block_k or _pick_block(L, preferred[1])
+        _check_blocks(rows, L, bq, bk, group)
+        return bq, bk
+
+    whole = q_side(rows) if dkv else k_side(L)
+    if 2 * whole <= vmem_budget:
+        bq, bk = blocks(_resident_blocks(D, L, group, kernel))
+        bqp = bq // group
+        # The loop's peel is static only where one block tiles the other
+        # (every pair the tables give; a caller's own blocks may not).
+        if bqp % bk == 0 or bk % bqp == 0:
+            buffers = 2 * (whole + (k_side(bk) if dkv else q_side(bq)))
+            # Beside the buffers the kernel's values live in VMEM too: s,
+            # p, dp, ds, their low-precision copies, the carried state.
+            # Mosaic's own need, by a compile for a described v5e, is
+            # 0.3-0.8 of this sum; the room matters beyond the kernel:
+            # XLA keeps arrays of its own in VMEM between operations, and
+            # with a fifth less asked here it placed them worse by 0.14 ms
+            # of copies a step in `lm1b4_1chip` (PERF.md, PR 28).
+            values = 6 * bq * bk * 4 + 4 * _vmem(max(bq, bk), D, 4)
+            limit = -(-(buffers + values) * 5 // 4 // 2 ** 20) * 2 ** 20
+            grid = (BG, L // bk if dkv else rows // bq)
+            return FlashKernelPlan(
+                "resident", bq, bk, grid, grid[0] * grid[1], 2 * whole,
+                buffers, max(_DEFAULT_VMEM_LIMIT, limit))
+    bq, bk = blocks(_grouped_blocks(D, L, group, backward))
+    num_qb, num_kb = rows // bq, L // bk
+    # acc / dq_acc (and m, l) per q block, or dk_acc + dv_acc per k block,
+    # and the block rotated once.
+    scratch = ((2 * _vmem(bk, D, 4) if dkv else _vmem(bq, D, 4))
+               + (0 if backward else 2 * _vmem(bq, 128, 4))
+               + (_vmem(bk if dkv else bq, D, isz) if rotary else 0))
+    grid = (BG, num_kb, num_qb) if dkv else (BG, num_qb, num_kb)
+    return FlashKernelPlan("gridded", bq, bk, grid, BG * num_qb * num_kb,
+                           0, 2 * (q_side(bq) + k_side(bk)) + scratch, None)
+
+
+def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
+               rotary=False, block_q=None, block_k=None,
+               vmem_budget=RESIDENT_VMEM_BUDGET):
+    """How `flash_attention` runs q [B, H, L, D] against H // group kv
+    heads: {kernel name: FlashKernelPlan} for the forward kernel
+    (`hvd_flash_fwd`) or, with ``backward``, the two backward ones
+    (`hvd_flash_dq`, `hvd_flash_dkv`). THE place where the path is
+    chosen, from what a call can see and nothing else: a kernel is
+    resident when its whole-sequence operands, double-buffered, fit
+    ``vmem_budget`` (forward and dQ hold k + v, and k's rotary tables;
+    dK/dV holds q + dO, q's tables, and lse + delta, whose 8-wide f32
+    rows pad to 128 lanes) and one of its blocks tiles the other;
+    gridded otherwise. `_pallas_forward_lse` and `_pallas_backward` run
+    what this returns, so it is also the counter that says which path a
+    program took (docs/TRACING.md; `hvd.profile.flash_plan`)."""
+    BG, rows = B * H // group, L * group
+    isz = jnp.dtype(dtype).itemsize
+    names = ((profile.FLASH_DQ, profile.FLASH_DKV) if backward
+             else (profile.FLASH_FWD,))
+    return {name: _kernel_plan(BG, rows, L, D, group, isz, name, rotary,
+                               block_q, block_k, vmem_budget)
+            for name in names}
+
+
+def _compiler_params(plan):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * len(plan.grid)
+        if plan.path == "resident"
+        else ("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=plan.vmem_limit_bytes)
+
+
+def _q_walk_specs(plan, L, D, group, causal):
+    """Block specs of a kernel that holds a q block and walks the k
+    blocks (forward, dQ) under `plan`: (q-side index map, k/v spec, q's
+    table spec, k's table spec). Resident: k/v and k's tables whole,
+    their block index constant across q blocks; gridded: per tile, the
+    index clamped to the causal frontier (`_kv_index_map`)."""
+    bq, bk = plan.block_q, plan.block_k
+    if plan.path == "resident":
+        return (lambda b, i: (b, i, 0),
+                pl.BlockSpec((None, L, D), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((bq, D), lambda b, i: (i, 0)),
+                pl.BlockSpec((L, D), lambda b, i: (0, 0)))
+    bqp = bq // group
+    return (lambda b, i, j: (b, i, 0),
+            pl.BlockSpec((None, bk, D), _kv_index_map(bqp, bk, causal)),
+            pl.BlockSpec((bq, D), lambda b, i, j: (i, 0)),
+            pl.BlockSpec((bk, D), _kv_index_map(bqp, bk, causal,
+                                                rank2=True)))
+
+
+# --- the resident kernels --------------------------------------------------
+
+def _walk_k_blocks(visit, carry, qi, bqp, bk, num_kb, causal):
+    """Runs ``visit(j, carry, masked)`` over the k blocks q block `qi`
+    sees, ascending (the gridded kernels' order): the blocks wholly at
+    or below the diagonal in a loop with no mask, then the one block
+    (bqp <= bk) or bqp // bk blocks that straddle it, peeled, masked
+    unconditionally. Not causal: one loop over all of them."""
+    if not causal:
+        return lax.fori_loop(0, num_kb, lambda j, c: visit(j, c, False),
+                             carry)
+    n_full = (qi * bqp) // bk
+    carry = lax.fori_loop(0, n_full, lambda j, c: visit(j, c, False),
+                          carry)
+    for t in range(max(1, bqp // bk)):
+        carry = visit(n_full + t, carry, True)
+    return carry
+
+
+def _walk_q_blocks(visit, carry, kj, bqp, bk, num_qb, causal):
+    """The dK/dV twin of `_walk_k_blocks`: the q blocks k block `kj`
+    is seen by, ascending from the first at or below the diagonal
+    ((kj*bk) // bqp, as `_q_index_map` clamps to) — first the one
+    (bk <= bqp) or bk // bqp that straddle it, peeled and masked, then
+    the rest to the end in a loop."""
+    if not causal:
+        return lax.fori_loop(0, num_qb, lambda i, c: visit(i, c, False),
+                             carry)
+    first = (kj * bk) // bqp
+    n_peel = max(1, bk // bqp)
+    for t in range(n_peel):
+        carry = visit(first + t, carry, True)
+    return lax.fori_loop(first + n_peel, num_qb,
+                         lambda i, c: visit(i, c, False), carry)
+
+
+def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary):
+    # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [L, D], fetched once per b (the
+    # block index does not change across q blocks); lse_ref [BQ, 8]. The
+    # online-softmax state (acc, m, l) is carried by the loop. Under
+    # fused rotary kc/ks are whole [L, D] tables, q is rotated once.
+    if rotary:
+        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, o_ref,
+         lse_ref) = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref = refs
+    qi = pl.program_id(1)
+    bq, D = q_ref.shape
+    q = q_ref[...]
+    if rotary:
+        q = _rot(q, qc_ref[...], qs_ref[...])
+
+    def visit(j, carry, masked):
+        acc, m_prev, l_prev = carry
+        at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        k = k_ref[at, :]
+        if rotary:
+            k = _rot(k, kc_ref[at, :], ks_ref[at, :])
+        s = _scores(q, k, scale)
+        if masked:
+            s = _causal_mask(s, qi * bqp, j * bk, -jnp.inf, group)
+        # The first visited block covers every row (ascending order), so
+        # no row's running max is still -inf after it.
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[at, :], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return acc, m_new, l_new
+
+    acc, m, l = _walk_k_blocks(
+        visit, (jnp.zeros((bq, D), jnp.float32),
+                jnp.full((bq, 1), -jnp.inf, jnp.float32),
+                jnp.zeros((bq, 1), jnp.float32)),
+        qi, bqp, bk, k_ref.shape[0] // bk, causal)
+    l = jnp.where(l == 0.0, 1.0, l)  # rows with no visible keys
+    o_ref[...] = (acc / l).astype(o_ref.dtype)
+    lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape)
+
+
+def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary):
+    """dQ with k and v whole in VMEM: `_bwd_dq_kernel`'s arithmetic,
+    the dq accumulator carried by the loop."""
+    if rotary:
+        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
+         lse_ref, delta_ref, dq_ref) = refs
+    else:
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
+    qi = pl.program_id(1)
+    q = q_ref[...]
+    if rotary:
+        q = _rot(q, qc_ref[...], qs_ref[...])
+    do = do_ref[...]
+    lse = lse_ref[:, :1]
+    delta = delta_ref[:, :1]
+
+    def visit(j, dq, masked):
+        at = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        k = k_ref[at, :]
+        if rotary:
+            k = _rot(k, kc_ref[at, :], ks_ref[at, :])
+        s = _scores(q, k, scale)
+        if masked:
+            s = _causal_mask(s, qi * bqp, j * bk, -jnp.inf, group)
+        p = jnp.exp(s - lse)  # masked entries: exp(-inf) = 0
+        dp = jax.lax.dot_general(
+            do, v_ref[at, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - delta) * scale
+        return dq + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    dq = _walk_k_blocks(visit, jnp.zeros(q.shape, jnp.float32), qi, bqp,
+                        bk, k_ref.shape[0] // bk, causal)
+    if rotary:
+        dq = _rot(dq, qc_ref[...], qs_ref[...], neg=True)
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+
+
+def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary):
+    """dK/dV with q, dO, lse and delta whole in VMEM: `_bwd_dkv_kernel`'s
+    arithmetic, the dk and dv accumulators carried by the loop; k is
+    rotated once, q per visit."""
+    if rotary:
+        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
+         lse_ref, delta_ref, dk_ref, dv_ref) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+         dv_ref) = refs
+    kj = pl.program_id(1)
+    bk = k_ref.shape[0]
+    k = k_ref[...]
+    if rotary:
+        k = _rot(k, kc_ref[...], ks_ref[...])
+    v = v_ref[...]
+
+    def visit(i, carry, masked):
+        dk, dv = carry
+        at = pl.ds(pl.multiple_of(i * bq, bq), bq)
+        q = q_ref[at, :]
+        if rotary:
+            q = _rot(q, qc_ref[at, :], qs_ref[at, :])
+        do = do_ref[at, :]
+        s = _scores(q, k, scale)
+        if masked:
+            s = _causal_mask(s, i * bqp, kj * bk, -jnp.inf, group)
+        p = jnp.exp(s - lse_ref[at, :1])  # masked entries: exp(-inf) = 0
+        dv = dv + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[at, :1]) * scale).astype(q.dtype)
+        dk = dk + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dk, dv
+
+    zeros = jnp.zeros(k.shape, jnp.float32)
+    dk, dv = _walk_q_blocks(visit, (zeros, zeros), kj, bqp, bk,
+                            q_ref.shape[0] // bq, causal)
+    if rotary:
+        dk = _rot(dk, kc_ref[...], ks_ref[...], neg=True)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+
+
 def _pallas_forward_lse(q, k, v, scale, causal, interpret,
-                        block_q=None, block_k=None, rotary_base=None):
+                        block_q=None, block_k=None, rotary_base=None,
+                        vmem_budget=RESIDENT_VMEM_BUDGET):
     """q [B, H, L, D], k/v [B, G, L, D] with G | H. Returns
     (out [B,H,L,D], lse [B*G, L*group, 8] f32) — lse is the per-row
     log-sum-exp the backward kernels need, in the grouped-rows layout
     (replicated over an 8-wide trailing dim: keeps the block
     Mosaic-tileable and the DMA a contiguous stripe; 1-wide measured
-    slower, 128-wide wastes 16x the memory)."""
+    slower, 128-wide wastes 16x the memory). `flash_plan` chooses the
+    path and the blocks; ``vmem_budget`` is its argument (tests and the
+    block sweep force a path with it)."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
     qf = _to_rows(q, group)
     kf = k.reshape(B * G, L, D)
     vf = v.reshape(B * G, L, D)
-
-    # Bigger blocks amortize per-grid-step overhead (the MXU work per
-    # step is tiny); bounded so s [BQ, BK] and the double-buffered k/v
-    # blocks stay well inside VMEM. Preferences are D-aware — see
-    # _default_blocks.
-    pq, pk = _grouped_blocks(D, L, group)
-    bq = block_q or _pick_rows_block(L, pq, group)
-    bk = block_k or _pick_block(L, pk)
-    rows = L * group
-    _check_blocks(rows, L, bq, bk, group)
-    bqp = bq // group
-    num_kb = L // bk
     rotary = rotary_base is not None
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               num_kb=num_kb, bqp=bqp, group=group,
-                               rotary=rotary)
-    grid = (B * G, rows // bq, num_kb)
-    kv_im = _kv_index_map(bqp, bk, causal)
-    q_spec = pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0))
-    in_specs = [q_spec,
-                pl.BlockSpec((None, bk, D), kv_im),
-                pl.BlockSpec((None, bk, D), kv_im)]
+    plan = flash_plan(B, H, L, D, group, q.dtype, False, rotary, block_q,
+                      block_k, vmem_budget)[profile.FLASH_FWD]
+    bq, bk = plan.block_q, plan.block_k
+    rows = L * group
+    bqp = bq // group
     inputs = [qf, kf, vf]
     if rotary:
         qc, qs = _rope_tables(_row_positions(L, group), D, rotary_base)
         kc, ks = _rope_tables(jnp.arange(L, dtype=jnp.int32), D,
                               rotary_base)
-        tq_spec = pl.BlockSpec((bq, D), lambda b, i, j: (i, 0))
-        tk_spec = pl.BlockSpec((bk, D),
-                               _kv_index_map(bqp, bk, causal, rank2=True))
-        in_specs += [tq_spec, tq_spec, tk_spec, tk_spec]
         inputs += [qc, qs, kc, ks]
+    q_im, kv_spec, tq_spec, tk_spec = _q_walk_specs(plan, L, D, group,
+                                                    causal)
+    if plan.path == "resident":
+        kernel = functools.partial(_fwd_resident_kernel, scale=scale,
+                                   causal=causal, bk=bk, bqp=bqp,
+                                   group=group, rotary=rotary)
+        scratch = []
+    else:
+        kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                                   num_kb=L // bk, bqp=bqp, group=group,
+                                   rotary=rotary)
+        scratch = [
+            pltpu.VMEM((bq, D), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
+        ] + ([pltpu.VMEM((bq, D), q.dtype)] if rotary else [])
+    q_spec = pl.BlockSpec((None, bq, D), q_im)
     out, lse = pl.pallas_call(
         kernel,
         name=profile.FLASH_FWD,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            q_spec,
-            pl.BlockSpec((None, bq, 8), lambda b, i, j: (b, i, 0)),
-        ],
+        grid=plan.grid,
+        in_specs=[q_spec, kv_spec, kv_spec] + (
+            [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []),
+        out_specs=[q_spec, pl.BlockSpec((None, bq, 8), q_im)],
         out_shape=[
             jax.ShapeDtypeStruct((B * G, rows, D), q.dtype),
             jax.ShapeDtypeStruct((B * G, rows, 8), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-        ] + ([pltpu.VMEM((bq, D), q.dtype)] if rotary else []),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(plan),
         interpret=interpret,
     )(*inputs)
     return _from_rows(out, B, group), lse
@@ -963,10 +1343,11 @@ def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary):
 
 
 def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
-                     block_q=None, block_k=None, rotary_base=None):
+                     block_q=None, block_k=None, rotary_base=None,
+                     vmem_budget=RESIDENT_VMEM_BUDGET):
     """Pallas backward: q/out/g [B,H,L,D], k/v [B,G,L,D], lse in the
     grouped-rows layout. Returns (dq [B,H,L,D], dk/dv [B,G,L,D]) in the
-    inputs' dtypes."""
+    inputs' dtypes. Path and blocks per kernel from `flash_plan`."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -979,17 +1360,13 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     delta = jnp.broadcast_to(
         jnp.sum(gf.astype(jnp.float32) * outf.astype(jnp.float32),
                 axis=-1, keepdims=True), lse.shape)
-    # Backward blocks are independent of the forward's (lse/delta
-    # stripes are block-agnostic); see _default_blocks for the swept
-    # preferences.
-    pq, pk = _grouped_blocks(D, L, group, backward=True)
-    bq = block_q or _pick_rows_block(L, pq, group)
-    bk = block_k or _pick_block(L, pk)
     rows = L * group
-    _check_blocks(rows, L, bq, bk, group)
-    bqp = bq // group
-    num_kb, num_qb = L // bk, rows // bq
     rotary = rotary_base is not None
+    # Backward blocks are independent of the forward's (lse/delta
+    # stripes are block-agnostic); see _resident_blocks and
+    # _default_blocks for the swept preferences.
+    plans = flash_plan(B, H, L, D, group, q.dtype, True, rotary, block_q,
+                       block_k, vmem_budget)
     if rotary:
         qc, qs = _rope_tables(_row_positions(L, group), D, rotary_base)
         kc, ks = _rope_tables(jnp.arange(L, dtype=jnp.int32), D,
@@ -997,70 +1374,84 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         tables = [qc, qs, kc, ks]
     else:
         tables = []
+    inputs = [qf, kf, vf] + tables + [gf, lse, delta]
 
-    kv_im = _kv_index_map(bqp, bk, causal)
-    tq_spec = pl.BlockSpec((bq, D), lambda b, i, j: (i, 0))
-    tk_spec = pl.BlockSpec((bk, D),
-                           _kv_index_map(bqp, bk, causal, rank2=True))
+    plan = plans[profile.FLASH_DQ]
+    bq, bk = plan.block_q, plan.block_k
+    bqp = bq // group
+    q_im, kv_spec, tq_spec, tk_spec = _q_walk_specs(plan, L, D, group,
+                                                    causal)
+    if plan.path == "resident":
+        kernel = functools.partial(_bwd_dq_resident_kernel, scale=scale,
+                                   causal=causal, bk=bk, bqp=bqp,
+                                   group=group, rotary=rotary)
+        scratch = []
+    else:
+        kernel = functools.partial(_bwd_dq_kernel, scale=scale,
+                                   causal=causal, num_kb=L // bk, bqp=bqp,
+                                   group=group, rotary=rotary)
+        scratch = [pltpu.VMEM((bq, D), jnp.float32)] + (
+            [pltpu.VMEM((bq, D), q.dtype)] if rotary else [])
+    q_spec = pl.BlockSpec((None, bq, D), q_im)
+    stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          num_kb=num_kb, bqp=bqp, group=group,
-                          rotary=rotary),
+        kernel,
         name=profile.FLASH_DQ,
-        grid=(B * G, rows // bq, num_kb),
-        in_specs=[
-            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bk, D), kv_im),
-            pl.BlockSpec((None, bk, D), kv_im),
-        ] + ([tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
-            pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bq, 8), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, bq, 8), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0)),
+        grid=plan.grid,
+        in_specs=[q_spec, kv_spec, kv_spec] + (
+            [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
+            q_spec, stripe_spec, stripe_spec],
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B * G, rows, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)] + (
-            [pltpu.VMEM((bq, D), q.dtype)] if rotary else []),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(plan),
         interpret=interpret,
-    )(qf, kf, vf, *tables, gf, lse, delta)
+    )(*inputs)
 
-    q_im = _q_index_map(bqp, bk, causal)
-    tq2_spec = pl.BlockSpec((bq, D), _q_index_map(bqp, bk, causal,
-                                                  rank2=True))
-    tk2_spec = pl.BlockSpec((bk, D), lambda b, j, i: (j, 0))
+    plan = plans[profile.FLASH_DKV]
+    bq, bk = plan.block_q, plan.block_k
+    bqp = bq // group
+    if plan.path == "resident":
+        kernel = functools.partial(_bwd_dkv_resident_kernel, scale=scale,
+                                   causal=causal, bq=bq, bqp=bqp,
+                                   group=group, rotary=rotary)
+        k_im = lambda b, j: (b, j, 0)                       # noqa: E731
+        q_spec = pl.BlockSpec((None, rows, D), lambda b, j: (b, 0, 0))
+        stripe_spec = pl.BlockSpec((None, rows, 8), lambda b, j: (b, 0, 0))
+        tq_spec = pl.BlockSpec((rows, D), lambda b, j: (0, 0))
+        tk_spec = pl.BlockSpec((bk, D), lambda b, j: (j, 0))
+        scratch = []
+    else:
+        kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
+                                   causal=causal, num_qb=rows // bq,
+                                   bqp=bqp, group=group, rotary=rotary)
+        k_im = lambda b, j, i: (b, j, 0)                    # noqa: E731
+        q_im = _q_index_map(bqp, bk, causal)
+        q_spec = pl.BlockSpec((None, bq, D), q_im)
+        stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
+        tq_spec = pl.BlockSpec((bq, D), _q_index_map(bqp, bk, causal,
+                                                     rank2=True))
+        tk_spec = pl.BlockSpec((bk, D), lambda b, j, i: (j, 0))
+        scratch = [pltpu.VMEM((bk, D), jnp.float32),
+                   pltpu.VMEM((bk, D), jnp.float32)] + (
+            [pltpu.VMEM((bk, D), k.dtype)] if rotary else [])
+    k_spec = pl.BlockSpec((None, bk, D), k_im)
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          num_qb=num_qb, bqp=bqp, group=group,
-                          rotary=rotary),
+        kernel,
         name=profile.FLASH_DKV,
-        grid=(B * G, num_kb, num_qb),
-        in_specs=[
-            pl.BlockSpec((None, bq, D), q_im),
-            pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
-        ] + ([tq2_spec, tq2_spec, tk2_spec, tk2_spec]
-             if rotary else []) + [
-            pl.BlockSpec((None, bq, D), q_im),
-            pl.BlockSpec((None, bq, 8), q_im),
-            pl.BlockSpec((None, bq, 8), q_im),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, bk, D), lambda b, j, i: (b, j, 0)),
-        ],
+        grid=plan.grid,
+        in_specs=[q_spec, k_spec, k_spec] + (
+            [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
+            q_spec, stripe_spec, stripe_spec],
+        out_specs=[k_spec, k_spec],
         out_shape=[
             jax.ShapeDtypeStruct((B * G, L, D), k.dtype),
             jax.ShapeDtypeStruct((B * G, L, D), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)] + (
-            [pltpu.VMEM((bk, D), k.dtype)] if rotary else []),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(plan),
         interpret=interpret,
-    )(qf, kf, vf, *tables, gf, lse, delta)
+    )(*inputs)
 
     return (_from_rows(dq, B, group), dk.reshape(B, G, L, D),
             dv.reshape(B, G, L, D))

@@ -248,3 +248,20 @@ def grad_collectives(text):
         out[kind]["count"] += 1
         out[kind]["bytes"] += nbytes
     return out
+
+
+# --- which path the flash kernels of a call take ---------------------------
+
+def flash_plan(*args, **kwargs):
+    """How `ops.flash_attention` runs a call of the given shape, kernel by
+    kernel: `ops.flash_attention.flash_plan` (its arguments and result),
+    here beside the other program-side counters. Per kernel name
+    (`FLASH_FWD`, or `FLASH_DQ` and `FLASH_DKV` with ``backward=True``): the
+    path (`resident`: the other sequence whole in VMEM, one grid step per
+    block; `gridded`: one grid step per tile), the blocks, the grid and the
+    grid steps a call issues, and the VMEM bytes it asks for. The kernels
+    run what this returns, so like `grad_collectives` it needs no chip."""
+    # `ops.flash_attention` imports this module for its kernels' names.
+    from horovod_tpu.ops.flash_attention import flash_plan as plan
+
+    return plan(*args, **kwargs)

@@ -24,7 +24,9 @@ from jax.sharding import SingleDeviceSharding
 
 from chip_smoke import kernel_calls as _kernels
 from horovod_tpu.ops import batch_norm
+from horovod_tpu import profile
 from horovod_tpu.ops.flash_attention import (_flash, _pallas_forward_lse,
+                                             flash_plan,
                                              flash_ring_bwd_step,
                                              flash_ring_step)
 
@@ -72,8 +74,21 @@ def _compile(one_chip, fn, *shapes):
 
 
 # (B, H, G, L, D, fused rotary): the attention of the L=1024 LM row of
-# bench.py's zoo and of its long-context h6 / gqa2 / fused-rope row.
-_LM_SHAPES = [(8, 12, 12, 1024, 64, None), (2, 6, 2, 8192, 128, 10000.0)]
+# bench.py's zoo and of its long-context h6 / gqa2 / fused-rope row (the
+# one shape here whose dK/dV `flash_plan` sends down the gridded path:
+# three heads' rows and q's rotary tables do not fit), and of the
+# benchmark's two LM configurations on a chip (`neox1b4_w2048`: 2 x 2048;
+# `olmoe1b7_w2048`: 1 x 4096), whose resident dK/dV asks for more than
+# the default VMEM limit.
+_LM_SHAPES = [(8, 12, 12, 1024, 64, None), (2, 6, 2, 8192, 128, 10000.0),
+              (2, 16, 16, 2048, 128, None), (1, 16, 16, 4096, 128, None)]
+
+
+def _named(text, name):
+    """Whether the program holds a Pallas kernel called `name`:
+    `jvp(<name>)/pallas_call` here, `.../<name>/pallas_call` inside a
+    model's scopes."""
+    return re.search(r"\b%s\)*/pallas_call" % name, text)
 
 
 @pytest.mark.parametrize("B,H,G,L,D,rotary", _LM_SHAPES)
@@ -85,6 +100,7 @@ def test_flash_forward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
     text = _compile(one_chip, fwd, ((B, H, L, D), bf16),
                     ((B, G, L, D), bf16), ((B, G, L, D), bf16))
     assert _kernels(text) == 1, text[:2000]
+    assert _named(text, profile.FLASH_FWD)
 
 
 @pytest.mark.parametrize("B,H,G,L,D,rotary", _LM_SHAPES)
@@ -102,8 +118,17 @@ def test_flash_backward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
     text = _compile(one_chip, bwd, ((B, H, L, D), bf16),
                     ((B, G, L, D), bf16), ((B, G, L, D), bf16),
                     ((B, H, L, D), bf16))
-    # forward (for the residuals), dQ, dK/dV
+    # forward (for the residuals), dQ, dK/dV: three custom calls a layer
+    # under the three names, on either path.
     assert _kernels(text) == 3, text[:2000]
+    for name in (profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV):
+        assert _named(text, name), name
+    paths = {name: p.path for backward in (False, True)
+             for name, p in flash_plan(B, H, L, D, H // G, jnp.bfloat16,
+                                       backward, rotary is not None).items()}
+    assert paths == {
+        profile.FLASH_FWD: "resident", profile.FLASH_DQ: "resident",
+        profile.FLASH_DKV: "gridded" if L == 8192 else "resident"}
 
 
 # The ring LM of `chip_smoke.py --chips 4`: B2 x H6 per chip, L=8192 over
@@ -160,7 +185,6 @@ def test_bn_grad_stats_compiles_for_v5e(one_chip, M, C):
 # `olmoe1b7_1chip`), the up projection's shapes and the down projection's.
 @pytest.mark.parametrize("K,N", [(2048, 1024), (1024, 2048)])
 def test_grouped_matmul_compiles_for_v5e(one_chip, K, N):
-    from horovod_tpu import profile
     from horovod_tpu.ops.grouped_matmul import grouped_matmul
 
     def fwd_bwd(lhs, rhs, sizes, g):
@@ -175,9 +199,7 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, K, N):
     # forward, the rows' gradient, the matrices' gradient
     assert _kernels(text) == 3, text[:2000]
     for name in profile.MOE_GMM_KERNELS:
-        # `jvp(hvd_moe_gmm)/pallas_call` here; inside a model's scopes the
-        # path ends `.../hvd_moe_gmm/pallas_call`.
-        assert re.search(r"\b%s\)*/pallas_call" % name, text), name
+        assert _named(text, name), name
 
 
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------
@@ -236,7 +258,6 @@ def test_dp_step_gradient_allreduces_are_asynchronous_on_v5e(
     gradient all-reduces (the embedding's and the head's, 64 MiB each:
     over the combiner's threshold, so each stays one collective)
     asynchronously (read by `hvd.profile`)."""
-    from horovod_tpu import profile
     from horovod_tpu.parallel import train
 
     step, state, mesh = _lm_step(topo, 4, monkeypatch)

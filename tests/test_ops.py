@@ -24,20 +24,28 @@ def _dense(q, k, v, causal):
     return o.astype(q.dtype)
 
 
+# `vmem_budget` values that force `flash_plan` down one path whatever the
+# shape: nothing fits 0 (gridded), everything fits 2**40 (resident).
+_PATHS = {"gridded": 0, "resident": 2 ** 40}
+_path = pytest.mark.parametrize("path", sorted(_PATHS))
+
+
 def _rand_qkv(B, L, H, D, seed=0):
     rng = np.random.RandomState(seed)
     return tuple(jnp.asarray(rng.randn(B, L, H, D), jnp.float32)
                  for _ in range(3))
 
 
+@_path
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_kernel_interpret_matches_dense(causal):
-    from horovod_tpu.ops.flash_attention import _pallas_forward
+def test_flash_kernel_interpret_matches_dense(causal, path):
+    from horovod_tpu.ops.flash_attention import _pallas_forward_lse
     B, L, H, D = 2, 256, 2, 64  # L multiple of BLOCK_Q=128
     q, k, v = _rand_qkv(B, L, H, D)
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = _pallas_forward(qt, kt, vt, D ** -0.5, causal,
-                          interpret=True).transpose(0, 2, 1, 3)
+    out = _pallas_forward_lse(
+        qt, kt, vt, D ** -0.5, causal, interpret=True,
+        vmem_budget=_PATHS[path])[0].transpose(0, 2, 1, 3)
     expected = _dense(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
@@ -68,18 +76,22 @@ def test_flash_fallback_and_grads():
                                    rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("bq,bk", [(256, 512), (128, 128)])
-def test_flash_kernel_block_shapes_interpret(bq, bk):
+@_path
+@pytest.mark.parametrize("bq,bk", [(256, 512), (128, 128), (512, 256)])
+def test_flash_kernel_block_shapes_interpret(bq, bk, path):
     """(256, 512): the production default's unequal q/k tiling, where
     every visible causal block straddles the diagonal. (128, 128): equal
     tiling at L=512 has fully-below-diagonal blocks, exercising the
-    mask-skip (straddles=False) branch the default tiling never hits."""
-    from horovod_tpu.ops.flash_attention import _pallas_forward
+    mask-skip (straddles=False) branch the default tiling never hits —
+    on the resident path, the unmasked loop before the peel. (512, 256):
+    a q block over two k blocks, so the resident peel is two blocks."""
+    from horovod_tpu.ops.flash_attention import _pallas_forward_lse
     B, L, H, D = 1, 512, 1, 32
     q, k, v = _rand_qkv(B, L, H, D, seed=7)
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    out = _pallas_forward(qt, kt, vt, D ** -0.5, True, interpret=True,
-                          block_q=bq, block_k=bk).transpose(0, 2, 1, 3)
+    out = _pallas_forward_lse(
+        qt, kt, vt, D ** -0.5, True, interpret=True, block_q=bq,
+        block_k=bk, vmem_budget=_PATHS[path])[0].transpose(0, 2, 1, 3)
     expected = _dense(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
@@ -122,6 +134,148 @@ def test_flash_pallas_backward_interpret(causal, bq, bk):
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gd.transpose(0, 2, 1, 3)),
             rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("causal,G,rotary,bqp,bk", [
+    (True, 4, None, 256, 512),      # group 1; peel: 1 block fwd/dQ, 2 dK/dV
+    (True, 4, None, 512, 256),      # peel: 2 blocks fwd/dQ, 1 dK/dV
+    (False, 4, None, 256, 512),     # not causal: one loop, no peel
+    (False, 2, 10000.0, 512, 256),
+    (True, 2, None, 256, 512),      # group 2
+    (True, 2, 10000.0, 512, 256),
+    (True, 1, None, 512, 256),      # group 4 (MQA)
+    (True, 1, 10000.0, 256, 512),
+    (True, 4, 10000.0, 128, 128),   # equal blocks: the longest loops
+])
+def test_flash_resident_path_interpret(causal, G, rotary, bqp, bk):
+    """The resident kernels (k/v, or q/dO/lse/delta, whole in VMEM and
+    walked by a loop inside the kernel): out, dQ, dK and dV against dense
+    attention, and against the gridded kernels on the same blocks, which
+    visit the same tiles in the same order with the same arithmetic."""
+    from horovod_tpu.ops.flash_attention import (
+        _pallas_backward, _pallas_forward_lse, flash_plan)
+    B, L, H, D = 1, 1024, 4, 32
+    group = H // G
+    q, k, v = _rand_gqa(B, L, H, G, D, seed=21)
+    w = jnp.asarray(np.random.RandomState(22).randn(B, L, H, D),
+                    jnp.float32)
+    t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    got = {}
+    for path, budget in _PATHS.items():
+        for backward in (False, True):
+            plans = flash_plan(B, H, L, D, group, q.dtype, backward,
+                               rotary is not None, bqp * group, bk, budget)
+            assert {p.path for p in plans.values()} == {path}
+        out, lse = _pallas_forward_lse(
+            t(q), t(k), t(v), D ** -0.5, causal, True, bqp * group, bk,
+            rotary, budget)
+        got[path] = (out,) + _pallas_backward(
+            t(q), t(k), t(v), out, lse, t(w), D ** -0.5, causal, True,
+            bqp * group, bk, rotary, budget)
+    dense = lambda q, k, v: _dense_gqa(q, k, v, causal, rotary)  # noqa: E731
+    want = (dense(q, k, v),) + jax.grad(
+        lambda q, k, v: jnp.sum(dense(q, k, v) * w),
+        argnums=(0, 1, 2))(q, k, v)
+    for r, g, d, nm in zip(got["resident"], got["gridded"], want,
+                           ("out", "dq", "dk", "dv")):
+        tol = 2e-5 if nm == "out" else 2e-4
+        np.testing.assert_allclose(np.asarray(t(r)), np.asarray(d),
+                                   rtol=tol, atol=tol, err_msg=nm)
+        np.testing.assert_allclose(np.asarray(r), np.asarray(g),
+                                   rtol=1e-6, atol=1e-6, err_msg=nm)
+
+
+def test_flash_resident_equals_gridded_in_bf16():
+    """bf16 inputs, as the models feed them: the shape goes down the
+    gridded path under a budget it does not fit and down the resident
+    one under the default, and the two agree to bf16 rounding (2^-8
+    relative) in the output and all three gradients."""
+    from horovod_tpu.ops.flash_attention import (
+        _pallas_backward, _pallas_forward_lse, flash_plan)
+    B, L, H, D = 1, 1024, 2, 64
+    bf16 = jnp.bfloat16
+    q, k, v = (x.transpose(0, 2, 1, 3).astype(bf16)
+               for x in _rand_qkv(B, L, H, D, seed=31))
+    w = jnp.asarray(np.random.RandomState(32).randn(B, H, L, D), bf16)
+    got = []
+    for budget, path in ((None, "resident"), (2 ** 18, "gridded")):
+        kw = {} if budget is None else {"vmem_budget": budget}
+        for backward in (False, True):
+            plans = flash_plan(B, H, L, D, 1, bf16, backward, **kw)
+            assert {p.path for p in plans.values()} == {path}
+        out, lse = _pallas_forward_lse(q, k, v, D ** -0.5, True, True,
+                                       **kw)
+        got.append((out,) + _pallas_backward(q, k, v, out, lse, w,
+                                             D ** -0.5, True, True, **kw))
+    for r, g, nm in zip(got[0], got[1], ("out", "dq", "dk", "dv")):
+        r, g = (np.asarray(x, np.float32) for x in (r, g))
+        assert np.max(np.abs(r - g)) <= 2 ** -8 * np.max(np.abs(g)), nm
+
+
+# B, H, L, D of the benchmark's cells: `lm1b4_1chip` and `lm1b4_dp4` (2
+# sequences of 2048 a chip) and `olmoe1b7_1chip` (one of 4096), 16 heads x
+# 128, bf16, group 1, no fused rotary. Expected: blocks, grid, path.
+@pytest.mark.parametrize("B,L,expected", [
+    (2, 2048, {"hvd_flash_fwd": (512, 512, (32, 4)),
+               "hvd_flash_dq": (512, 512, (32, 4)),
+               "hvd_flash_dkv": (512, 1024, (32, 2))}),
+    (1, 4096, {"hvd_flash_fwd": (512, 512, (16, 8)),
+               "hvd_flash_dq": (512, 512, (16, 8)),
+               "hvd_flash_dkv": (512, 1024, (16, 4))}),
+])
+def test_flash_plan_benchmark_shapes_are_resident(B, L, expected):
+    """`flash_plan` alone: the benchmark's shapes choose the resident
+    path with one grid step per (batch*head, block) — the gridded grid
+    had a third axis — and the VMEM sum each reports is under the limit
+    it sets."""
+    from horovod_tpu.ops.flash_attention import (RESIDENT_VMEM_BUDGET,
+                                                 flash_plan)
+    H, D = 16, 128
+    plans = {**flash_plan(B, H, L, D, 1, jnp.bfloat16),
+             **flash_plan(B, H, L, D, 1, jnp.bfloat16, backward=True)}
+    assert sorted(plans) == sorted(expected)
+    for name, (bq, bk, grid) in expected.items():
+        plan = plans[name]
+        assert (plan.path, plan.block_q, plan.block_k, plan.grid) == (
+            "resident", bq, bk, grid), (name, plan)
+        assert plan.grid_steps == grid[0] * grid[1]
+        assert 0 < plan.resident_bytes <= RESIDENT_VMEM_BUDGET
+        assert plan.resident_bytes < plan.vmem_bytes < plan.vmem_limit_bytes
+    # k + v, bf16, two buffers; dK/dV: q + dO and the two 8-wide f32
+    # stripes padded to 128 lanes, two buffers.
+    assert plans["hvd_flash_fwd"].resident_bytes == 2 * 2 * L * D * 2
+    assert plans["hvd_flash_dkv"].resident_bytes == 2 * (
+        2 * L * D * 2 + 2 * L * 128 * 4)
+
+
+def test_flash_plan_past_the_budget_is_gridded():
+    """A length whose whole-sequence operands do not fit the budget
+    takes the gridded path, kernel by kernel (L=16384: dK/dV's 48 MiB do
+    not fit 24, k + v do), with the grid of old and no VMEM limit asked;
+    so does a block pair neither of which tiles the other."""
+    from horovod_tpu.ops.flash_attention import flash_plan
+    bf16 = jnp.bfloat16
+    plans = flash_plan(1, 16, 16384, 128, 1, bf16, backward=True)
+    dkv = plans["hvd_flash_dkv"]
+    assert (dkv.path, dkv.block_q, dkv.block_k, dkv.grid,
+            dkv.grid_steps) == ("gridded", 512, 1024, (16, 16, 32), 8192)
+    assert dkv.resident_bytes == 0 and dkv.vmem_limit_bytes is None
+    assert plans["hvd_flash_dq"].path == "resident"
+    for backward in (False, True):
+        for plan in flash_plan(1, 16, 8192, 128, 1, bf16,
+                               backward).values():
+            assert plan.path == "resident"  # the longest shape swept
+        for plan in flash_plan(1, 16, 32768, 128, 1, bf16,
+                               backward).values():
+            assert plan.path == "gridded" and len(plan.grid) == 3
+    # Fused rotary adds two f32 tables to what a kernel holds, and a head
+    # group multiplies dK/dV's rows.
+    rot = flash_plan(2, 6, 8192, 128, 3, bf16, backward=True, rotary=True)
+    assert rot["hvd_flash_dkv"].path == "gridded"
+    assert flash_plan(1, 16, 16384, 128, 1, bf16, rotary=True)[
+        "hvd_flash_fwd"].path == "gridded"
+    odd = flash_plan(1, 2, 768, 128, 1, bf16, block_q=384, block_k=256)
+    assert odd["hvd_flash_fwd"].path == "gridded"
 
 
 def test_flash_default_block_policy():

@@ -270,6 +270,21 @@ def test_flash_kernels_carry_their_three_names():
                                    profile.FLASH_DKV}
 
 
+@pytest.mark.parametrize("backward", [False, True])
+def test_flash_plan_under_hvd_profile_is_the_kernels_own(backward):
+    """`hvd.profile.flash_plan` is the plan the kernels run (the ops
+    module imports `profile`, so the import is made at the call), keyed by
+    the kernels' names."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    fa = sys.modules[fa.__module__]  # the module, not the function
+    got = profile.flash_plan(2, 16, 2048, 128, backward=backward)
+    assert got == fa.flash_plan(2, 16, 2048, 128, backward=backward)
+    assert set(got) == ({profile.FLASH_DQ, profile.FLASH_DKV} if backward
+                        else {profile.FLASH_FWD})
+    assert all(p.path == "resident" for p in got.values())
+
+
 def test_ring_kernels_carry_their_three_names():
     B, H, L, D = 1, 2, 128, 64
     q = jnp.ones((B * H, L, D), jnp.float32)
