@@ -42,9 +42,10 @@ except ImportError as e:
     sys.exit("chip_smoke: the horovod_tpu package is not beside this "
              "script (%s)" % e)
 
-# Sizes of the one-chip phases: the `_ZOO` rows of bench.py. A rehearsal
-# on the CPU overrides these from a scratch script; the program has no
-# option for it.
+# Sizes of the one-chip phases: ResNet-50 at batch 256 of 224 x 224
+# images, a 12-layer 768-wide LM (12 heads of 64) at 8 x 1024 tokens, a
+# small routed-MoE LM. A rehearsal on the CPU overrides these from a
+# scratch script; the program has no option for it.
 SIZES = dict(
     resnet_batch=256, image=224, classes=1000, train_steps=6,
     lm=dict(vocab_size=32000, num_layers=12, num_heads=12, embed_dim=768,
@@ -141,8 +142,8 @@ def rel_err(a, b):
 
 def resnet_step(model_cls, mesh, per_chip_batch, seed, donate=True,
                 **model_kw):
-    """make_train_step on a ResNet as bench.py builds it: bf16, SGD with
-    momentum, synthetic ImageNet-shaped batch from a seed. Returns the
+    """make_train_step on a ResNet: bf16, SGD 0.01 with momentum 0.9, a
+    synthetic ImageNet-shaped batch from a seed. Returns the
     step and its (params, opt_state, batch), not yet placed."""
     import jax
     import jax.numpy as jnp
@@ -227,7 +228,7 @@ def phase_train(args):
 
 
 def lm_step(mesh, seed):
-    """The GPT-2-small-shaped LM row of bench.py: flash attention, dense
+    """The 12-layer 768-wide LM of SIZES["lm"]: flash attention, dense
     log-softmax loss, adam, through make_train_step. Returns the step and
     its (params, opt_state, batch), not yet placed."""
     import jax

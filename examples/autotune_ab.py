@@ -1,4 +1,4 @@
-"""Defaults-vs-autotuned A/B of the runtime knobs (VERDICT r4 item 1).
+"""Defaults-vs-autotuned A/B of the host plane's runtime knobs.
 
 Three 4-rank localhost runs of the same gradient-bucket workload
 (`tests/autotune_ab_worker.py`):
@@ -13,9 +13,11 @@ Three 4-rank localhost runs of the same gradient-bucket workload
                   THRESHOLD / HVD_TPU_CYCLE_TIME on a fresh run
                   (tuning value clean of any in-process residue)
 
-Writes AUTOTUNE_AB_r05.json at the repo root (runs, converged knobs,
-CSV sample log) and prints a summary table. CPU-plane only — safe to
-run without TPU access, but it IS load-sensitive: run it alone.
+Writes autotune_ab.json and autotune_ab_samples.csv into the working
+directory (runs, converged knobs, CSV sample log) and prints a summary
+table. CPU-plane only — safe to run without TPU access, but it IS
+load-sensitive: run it alone. Its timings are the host plane's on the
+CPU, never device metrics.
 
 Usage: python examples/autotune_ab.py [--np 4] [--iters 80]
 """
@@ -57,13 +59,12 @@ def main():
     ap.add_argument("--iters", type=int, default=80)
     ap.add_argument("--tensors", type=int, default=48)
     ap.add_argument("--elems", type=int, default=32768)
-    ap.add_argument("--out", default=os.path.join(REPO,
-                                                  "AUTOTUNE_AB_r05.json"))
+    ap.add_argument("--out", default="autotune_ab.json")
     args = ap.parse_args()
 
     base = {"AB_ITERS": str(args.iters), "AB_TENSORS": str(args.tensors),
             "AB_ELEMS": str(args.elems)}
-    log_path = os.path.join(REPO, "autotune_ab_samples.csv")
+    log_path = os.path.abspath("autotune_ab_samples.csv")
 
     print("== defaults ==", file=sys.stderr)
     defaults = run_once(args.np, dict(base))

@@ -4,8 +4,8 @@ protocol: warmup, N rounds x M iters, `Img/sec per device` mean ± 1.96σ).
 
 Run single chip:   python examples/jax_synthetic_benchmark.py
 All local devices train over a 1-D data-parallel mesh automatically.
-`bench.py` at the repo root is the driver-facing JSON wrapper around the
-same loop.
+An example of the protocol, not the repository's yardstick: numbers that
+count come from `benchmark/run.py` (cell `resnet50_b256`).
 """
 
 import argparse

@@ -15,9 +15,8 @@ run pure data-parallel — the full parameter set exceeds the per-rank
 budget (HVD_TPU_TP_BUDGET_BYTES models the chip's HBM headroom), and
 the example refuses to start unless model_parallel shards it under
 budget. ``--reference`` lifts the budget to produce the single-process
-reference loss trajectory the distributed run must match (bench.py
---model-parallel asserts it; the "big host" stand-in for a run that
-would not fit the real chip).
+reference loss trajectory the distributed run must match (the "big
+host" stand-in for a run that would not fit the real chip).
 
 Run::
 

@@ -4,8 +4,7 @@ ring, as a user writes it.
 Contiguous causal ring attention leaves the last rank doing all the
 lower-triangle work while early ranks idle; `schedule="zigzag"` splits
 the sequence into 2n chunks and gives rank r chunks (r, 2n-1-r), so
-every rank does equal work at every ring step (SCALING.md "Causal-run
-load balance"). The recipe is three moves:
+every rank does equal work at every ring step. The recipe is three moves:
 
 1. zigzag_shard the per-sequence arrays (tokens, positions, shifted
    labels) BEFORE feeding shard_map — the model's rotary embedding

@@ -6,8 +6,8 @@ compiled custom-op collectives, warmup + timed batches, `Img/sec per
 rank` with the mean +/- 1.96 sigma summary the reference prints.
 
 Note: this exercises the TF-on-host-CPU compatibility surface (the TF
-binding's role here); for TPU-resident XLA training use `bench.py` /
-the jax binding.
+binding's role here); for TPU-resident XLA training use the jax
+binding (`examples/jax_synthetic_benchmark.py`).
 
 Run: python -m horovod_tpu.run.run -np 2 -- \
          python examples/tensorflow2_synthetic_benchmark.py

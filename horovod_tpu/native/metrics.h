@@ -192,7 +192,7 @@ class Metrics {
   std::atomic<uint64_t> allreduce_int8_total{0};
   // Data-ring wire accounting (frame headers included): the quantity
   // the compression stage shrinks, measured at the transport layer —
-  // bench.py --compression reads the A/B from these. Counts data-plane
+  // tests/test_compression.py reads the A/B from these. Counts data-plane
   // bytes WHATEVER the transport (loopback TCP or an intra-host shm
   // ring), so a compression ratio A/B is transport-independent; the
   // net_shm_* counters below split out the shm share.
@@ -202,7 +202,7 @@ class Metrics {
   // --- shared-memory data plane (tcp_context.cc / docs/TRANSPORT.md) ---
   // Payload+header bytes ring legs moved through shared-memory segments
   // (also counted in net_ring_bytes_* above — these isolate the shm
-  // share so bench.py --shm can prove the plane engaged).
+  // share so tests/test_shm.py can prove the plane engaged).
   std::atomic<uint64_t> net_shm_bytes_sent_total{0};
   std::atomic<uint64_t> net_shm_bytes_recv_total{0};
 

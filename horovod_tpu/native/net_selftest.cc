@@ -345,7 +345,7 @@ bool ShmClosedWakesPeer() {
   return read_failed && elapsed < 5.0;
 }
 
-// ---- per-hop transport microbench (bench.py --shm) ----
+// ---- per-hop transport microbench (horovod_tpu_hop_bench) ----
 //
 // One ring hop = a full-duplex neighbor exchange: each side sends
 // `nbytes` while receiving `nbytes` (exactly PairExchange's payload
@@ -579,7 +579,7 @@ uint32_t horovod_tpu_crc32c_extend(uint32_t crc, const void* data,
   return hvdtpu::Crc32c(data, static_cast<std::size_t>(len), crc);
 }
 
-// Per-hop transport microbench (bench.py --shm): microseconds for one
+// Per-hop transport microbench (called through ctypes): microseconds for one
 // full-duplex `nbytes` neighbor exchange (header + incremental CRC, the
 // production pump shape) between two in-process threads over shared
 // memory (use_shm=1) or a socketpair (0). Returns -1.0 on failure.

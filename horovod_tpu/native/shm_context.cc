@@ -338,7 +338,7 @@ int64_t ShmRing::ReadSome(void* buf, std::size_t len) {
 // burning a core while it encodes a big chunk. Sized in PAUSE terms:
 // a modern PAUSE is ~140 cycles, so 64 of them is a few microseconds —
 // a longer spin would cost more than the futex park it avoids
-// (measured on this container; bench.py --shm).
+// (measured on the CPU container, round 11).
 static constexpr int kSpinIters = 64;
 
 // Polite spin: the PAUSE hint keeps a spinning hyperthread/core from

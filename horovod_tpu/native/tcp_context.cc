@@ -1842,7 +1842,7 @@ bool TcpContext::PairExchange(Conn* next, Conn* prev, Channel chan,
   // compression stage shrinks, counted at the transport layer so a
   // bench/test A/B measures actual bytes moved, not payload intent —
   // whatever the transport. The net_shm_* counters split out the
-  // shared-memory share (bench.py --shm's engagement proof).
+  // shared-memory share (tests/test_shm.py's engagement proof).
   Metrics& m = GlobalMetrics();
   m.net_ring_bytes_sent_total.fetch_add(
       static_cast<uint64_t>(send_len) + kFrameHeaderBytes,

@@ -22,8 +22,9 @@ masks — the `multiply_add_fusion` lines), which a Pallas island cannot.
 
 The reference delegates BN to cuDNN (no analogue source); this is the
 TPU-native equivalent of its fused-BN dependence. Correctness is pinned
-against `flax.linen.BatchNorm` in tests (interpret mode on CPU); v5e
-measurement via `bench.py --model resnet50pbn`.
+against `flax.linen.BatchNorm` in tests (interpret mode on CPU); on the
+v5e it lost to XLA's fusions (1348 against 2355 img/s, r04 capture;
+PERF.md section 6), and no benchmark cell runs it.
 
 Layout contract: activations reshaped to (M, C), stats over axis 0.
 M must be divisible by the block size (the caller picks the largest

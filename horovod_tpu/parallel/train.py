@@ -92,7 +92,8 @@ def _aval_cache_key(*trees):
 def _structure_cached_step(build):
     """step(params, opt_state, batch) dispatching through a cache of
     compiled callables keyed on (structure, shapes, dtypes); exposes
-    .lower for XLA cost analysis (bench.py's contract)."""
+    .lower, which the benchmark compiles ahead of the first call to read
+    the step's bytes and text (benchmark/run.py)."""
     cache = {}
 
     def compiled(params, opt_state):

@@ -12,8 +12,8 @@ Every request's input is derived from the seed, so the expected answer
 is recomputable: pass ``leaves_by_crc`` mapping a weights fingerprint
 to its leaves and every response is checked against the numpy forward
 for the weight set it CLAIMS (by fingerprint) to have used — the
-rolling-swap e2e and ``bench.py --serve`` both lean on this to turn
-"zero dropped, right answers, right weights" into an assert.
+rolling-swap e2e leans on this to turn "zero dropped, right answers,
+right weights" into an assert.
 """
 
 import threading

@@ -95,10 +95,19 @@ def main():
     x = np.ones(100, np.float32)
     for _ in range(3):
         ops.allreduce(x, "ck", compression="none")  # hvd-lint: disable=verify-mixed-modes
-    inval_before = counters()["cache_invalid_total"]
+    # The ranks whose call reaches the cycle first read the stale entry
+    # (INVALID); the invalid bit is OR-synced and every rank erases the
+    # entry in that same cycle, so a rank that enqueues a cycle later
+    # finds no entry (a MISS). No rank may HIT, and one must invalidate.
+    before = counters()
     out = ops.allreduce(x, "ck", compression="bf16")  # hvd-lint: disable=duplicate-collective-name
     assert np.allclose(out, n), out
-    assert counters()["cache_invalid_total"] > inval_before
+    after = counters()
+    hit, invalid, miss = (after[k] - before[k] for k in (
+        "cache_hit_total", "cache_invalid_total", "cache_miss_total"))
+    assert hit == 0 and invalid + miss >= 1, (hit, invalid, miss)
+    seen = ops.allgather(np.array([invalid], np.int64), "ck.invalidated")
+    assert seen.sum() >= 1, seen
 
     # Mode accounting: per-mode allreduce counters moved.
     c = counters()

@@ -4,91 +4,37 @@ allreduces, whose cost is dominated by the per-cycle coordinator negotiation
 HVD_TPU_CYCLE_TIME=0 so the cycle pacing sleep doesn't mask the control
 plane. Prints `NEGOTIATION_US_PER_OP <us>` on rank 0."""
 
-import json
-import os
 import sys
 import time
 
 import numpy as np
 
 import horovod_tpu as hvd
-from horovod_tpu.common.basics import get_basics
+from horovod_tpu.common import ops
+
+ITERS = 200
+WARMUP = 20  # populates the response cache
 
 
 def main():
     hvd.init()
     r = hvd.rank()
-    basics = get_basics()
     # Zero-element tensor: the negotiation/cycle machinery runs in full but
     # the ring data phase is skipped, isolating control-plane latency (a
     # payload allreduce would add the ring's inherent Theta(n) hop latency).
     x = np.zeros(0, dtype=np.float32)
-    iters = int(os.environ.get("HVD_TPU_BENCH_ITERS", "200"))
-    # HVD_TPU_BENCH_TENSORS > 1 simulates one training step's gradient
-    # bucket: k async ops with realistic long names negotiated together.
-    # Uncached negotiation traffic scales with k x name length; the
-    # cached bit vector doesn't — the fast path's actual win.
-    k = int(os.environ.get("HVD_TPU_BENCH_TENSORS", "1"))
-    if k > 1:
-        names = ["nb.layer%03d.weight_gradient_accumulator" % i
-                 for i in range(k)]
-    else:
-        names = ["nb"]
-    from horovod_tpu.common import ops
 
     def step():
-        handles = [ops.allreduce_async(x, nm) for nm in names]
-        for h in handles:
-            ops.synchronize(h)
+        ops.synchronize(ops.allreduce_async(x, "nb"))
 
-    # Warmup (populates the response cache); tunable because at the
-    # 1024-rank oversubscribed sweep every step costs a full fleet
-    # round-robin on one core.
-    warmup = int(os.environ.get("HVD_TPU_BENCH_WARMUP", "20"))
-    for i in range(warmup):
+    for _ in range(WARMUP):
         step()
-    basics.protocol_counters_reset()
-    # Coordinator CPU time (user+sys of THIS process, coordinator
-    # thread included) over the measured window: wall clock on a
-    # 1-core host measures the OS scheduler, CPU time measures the
-    # protocol. cpu_us / work cycles = the per-cycle coordinator cost
-    # whose O(n) constant SCALING.md §2.3 pins.
-    import resource
-    ru0 = resource.getrusage(resource.RUSAGE_SELF)
     t0 = time.perf_counter()
-    for i in range(iters):
+    for _ in range(ITERS):
         step()
     dt = time.perf_counter() - t0
-    ru1 = resource.getrusage(resource.RUSAGE_SELF)
-    cpu_us = ((ru1.ru_utime - ru0.ru_utime) +
-              (ru1.ru_stime - ru0.ru_stime)) * 1e6
-    counters = basics.protocol_counters()
-    counters.update(rank=r, iters=iters, tensors_per_step=k,
-                    cpu_us=round(cpu_us, 1))
-    # Ranks 0 (coordinator, O(n) traffic) and 1 (representative worker,
-    # O(1) traffic) carry the protocol-cost evidence.
-    if r <= 1:
-        print("PROTOCOL_COUNTERS %s" % json.dumps(counters))
     if r == 0:
-        # Per OP also in bucket mode (k ops ride each step).
-        print("NEGOTIATION_US_PER_OP %.1f" % (dt / (iters * k) * 1e6))
-        # Live-metrics snapshot for the BENCH json (docs/METRICS.md):
-        # the cycle-time histogram, fused-bytes total, and cache hit
-        # rate of this run's coordinator.
-        m = hvd.metrics()
-        c = m["counters"]
-        looked_up = c["cache_hit_total"] + c["cache_miss_total"]
-        print("METRICS_SNAPSHOT %s" % json.dumps({
-            "cycle_seconds": m["histograms"]["cycle_seconds"],
-            "fused_bytes_total": c["fused_bytes_total"],
-            "fused_tensors_total": c["fused_tensors_total"],
-            "cache_hit_rate": round(c["cache_hit_total"] / looked_up, 4)
-            if looked_up else None,
-        }))
-        # Trace-recorder counters for bench.py --trace-overhead: the A/B
-        # there asserts spans flowed when tracing was on AND nothing was
-        # dropped at the default ring size.
-        print("TRACE_COUNTERS %s" % json.dumps(basics.trace_counters()))
+        print("NEGOTIATION_US_PER_OP %.1f" % (dt / ITERS * 1e6))
     print("rank %d done" % r)
     return 0
 

@@ -1,6 +1,6 @@
 """Protocol-counter worker: runs repeated same-name collectives and
 prints this rank's control-plane accounting as one JSON line, so the
-test (and bench.py --scaling) can compare the response-cache fast path
+test can compare the response-cache fast path
 against full negotiation at the PROTOCOL level — bytes and cycle
 kinds, independent of wall clock (the fast path's design goal;
 reference: response_cache.cc:308-409).

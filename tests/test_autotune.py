@@ -146,7 +146,7 @@ def test_autotune_e2e(run_launcher, tmp_path):
 
 @pytest.mark.e2e
 def test_autotune_ab_worker_symmetric_exit(run_launcher):
-    """The A/B worker's broadcast-gated tune loop (SCALING.md §2.2):
+    """The A/B worker's broadcast-gated tune loop (examples/autotune_ab.py):
     rank 0 alone decides exit (converged / step-capped / timed out)
     and broadcasts the verdict, so every rank leaves at the SAME step
     — per-rank polling of `active` exits ranks at different collective

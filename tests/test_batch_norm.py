@@ -1,6 +1,6 @@
 """Fused Pallas BatchNorm correctness, pinned against flax BatchNorm
-(interpret mode on CPU; the kernels themselves run on v5e via
-`bench.py --model resnet50pbn`)."""
+(interpret mode on CPU; `tests/test_chip_compile.py` compiles the
+kernels for the v5e)."""
 
 import jax
 import jax.numpy as jnp
@@ -603,7 +603,7 @@ def test_lean_resnet_matches_stock_resnet():
 
 def test_resnet_lean_variant_one_step():
     """ResNet50Lean end to end: one train step, finite loss and grads
-    (the zoo variant bench.py measures as resnet50lean)."""
+    (`norm="lean"`; ROADMAP S7 decides it against stock on the chip)."""
     from horovod_tpu.models import ResNet50Lean
 
     model = ResNet50Lean(num_classes=10, dtype=jnp.float32)

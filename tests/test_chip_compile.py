@@ -73,8 +73,8 @@ def _compile(one_chip, fn, *shapes):
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
-# (B, H, G, L, D, fused rotary): the attention of the L=1024 LM row of
-# bench.py's zoo and of its long-context h6 / gqa2 / fused-rope row (the
+# (B, H, G, L, D, fused rotary): the attention of chip_smoke.py's L=1024
+# LM and of its long-context h6 / gqa2 / fused-rope shape (the
 # one shape here whose dK/dV `flash_plan` sends down the gridded path:
 # three heads' rows and q's rotary tables do not fit), and of the
 # benchmark's two LM configurations on a chip (`neox1b4_w2048`: 2 x 2048;

@@ -192,7 +192,7 @@ def test_plain_jit_single_process_identity():
 
 
 def test_w2v_sparse_step_matches_dense_mesh():
-    """The bench's sparse (indices,values) allgather+scatter-add plane
+    """The word2vec step's sparse (indices,values) allgather+scatter-add plane
     must produce bit-comparable tables to the dense psum path after
     multiple steps on a real 4-device mesh — pins the jax-plane
     IndexedSlices analogue end to end (duplicate ids accumulate, the
@@ -201,7 +201,7 @@ def test_w2v_sparse_step_matches_dense_mesh():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh
-    from bench import w2v_make_step
+    from horovod_tpu.models.word2vec import w2v_make_step
 
     jax.config.update("jax_default_matmul_precision", "highest")
     n = 4
