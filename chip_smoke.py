@@ -227,6 +227,18 @@ def phase_train(args):
     hvd.shutdown()
 
 
+def print_loss_plan(B, L, D, V, chunk, dtype):
+    """How the chunked vocabulary loss cuts a [B, L] call (`hvd.profile`)."""
+    from horovod_tpu import profile
+
+    plan = profile.loss_plan(B, L, D, V, chunk, dtype)
+    print("  hvd_loss [%d, %d] x [%d, %d]: %d x %d rows, %d passes of the "
+          "head, logits %.0f MiB a chunk, residuals %.0f MiB"
+          % (B, L, D, V, plan["iterations"], plan["rows"],
+             plan["head_passes"], plan["logits_bytes"] / 2 ** 20,
+             plan["residual_bytes"] / 2 ** 20), flush=True)
+
+
 def lm_step(mesh, seed):
     """The 12-layer 768-wide LM of SIZES["lm"]: flash attention, dense
     log-softmax loss, adam, through make_train_step. Returns the step and
@@ -718,6 +730,10 @@ def phase_fourchip(args):
     ring_fn, flash_fn, params, (tokens, positions, targets) = ring_lm_case(
         mesh, args.seed)
     L = SIZES["ring_len"]
+    for length in (L // 4, L):  # a ring shard's call, the flash twin's
+        print_loss_plan(SIZES["ring_batch"], length,
+                        SIZES["lm"]["embed_dim"], SIZES["lm"]["vocab_size"],
+                        min(512, length), jnp.bfloat16)
     axes = ("dp", "sp")
     rep, seq = NamedSharding(mesh, P()), NamedSharding(mesh, P(*axes))
     ring_args = (jax.device_put(params, rep),) + tuple(

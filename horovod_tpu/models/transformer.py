@@ -261,7 +261,10 @@ class Transformer(nn.Module):
     final normed hidden states — pair with
     `horovod_tpu.ops.losses.chunked_softmax_cross_entropy` (and the
     lm_head kernel from the params tree) to train without ever
-    materializing the [B, L, vocab] f32 logits."""
+    materializing the [B, L, vocab] logits: that loss projects a chunk
+    of rows at a time and forms both gradients in the same pass, so
+    its peak is O(rows x vocab) plus the [D, vocab] f32 and [B, L, D]
+    gradients it hands to the backward."""
     cfg: TransformerConfig
 
     @nn.compact

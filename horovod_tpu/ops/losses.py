@@ -2,18 +2,24 @@
 
 `chunked_softmax_cross_entropy` computes causal-LM cross entropy
 without ever materializing the full [B, L, vocab] logits tensor in
-f32: it scans over sequence chunks, projecting each chunk to the
-vocabulary, reducing it to logsumexp + target-logit immediately, and
-rematerializing the chunk projection in the backward
-(``jax.checkpoint``) — peak live memory is O(B * chunk * vocab)
-instead of O(B * L * vocab). At GPT-2-small shapes (V=32k) the dense
-f32 logits + softmax of a [8, 2048] batch is ~4 GB of HBM traffic per
-pass; at L=8192 the dense form does not fit a single v5e at all, the
-chunked form does.
+f32: it flattens the tokens to [B * L] rows and scans over chunks of
+rows, projecting each chunk to the vocabulary, reducing it to
+logsumexp + target-logit immediately. Under differentiation the same
+scan forms the gradient while the chunk's logits are there
+(``softmax - onehot`` is known the moment they are): a step makes
+three passes of the head (logits, d-hidden, d-kernel), nothing is
+formed twice, and the backward rule only scales what the forward
+left. Peak live memory is O(rows * vocab) for a chunk's logits plus
+the residuals, the [D, vocab] f32 kernel gradient and the [B, L, D]
+hidden gradient, instead of O(B * L * vocab). How many rows
+a chunk holds follows from the shapes (`loss_plan`); at L=8192 the
+dense form does not fit a single v5e at all, the chunked form does.
 
 No reference analogue (the reference never sees model internals); this
 is part of the long-context extension the flash kernels anchor.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,41 +27,132 @@ from jax import lax
 
 from horovod_tpu import profile
 
+# The most logits a chunk may hold, counted in f32 (the widest a caller's
+# hidden states make them), unless the caller's `chunk` asks for more:
+# 1024 rows at V=50304 (206 MB in f32, 103 MB as a bf16 call keeps them).
+# The sweep on a v5e (PERF.md §6, PR 31; examples/loss_rows_sweep.py; ms a
+# call of value and gradient at D=2048, V=50304, bf16 hidden states over
+# an f32 head; [2, 2048] and [1, 4096] equal to 0.01): 512 rows 20.85,
+# 1024 rows 16.90, 2048 rows 15.70, 4096 rows (one shot) 14.76. More rows
+# are faster and what holds the budget here is memory: each doubling is
+# 98 MiB more of live logits where the step's memory peaks, and the
+# benchmark's four-chip cell has no room for it.
+LOGITS_BUDGET_BYTES = 256 * 2**20
+
+
+def loss_plan(B, L, D, V, chunk=512, dtype=jnp.bfloat16):
+    """How `chunked_softmax_cross_entropy` runs a call of the given
+    shapes (`hvd.profile.loss_plan`; the function runs what this
+    returns, so it needs no chip):
+
+        {"rows": rows of a scan iteration, "iterations": B * L / rows,
+         "head_passes": matmuls over the whole head a training step
+         makes, "logits_bytes": a chunk's logits live in HBM,
+         "residual_bytes": what the forward keeps for the backward}
+
+    `rows` is the largest divisor of B * L whose logits, were they f32,
+    fit `LOGITS_BUDGET_BYTES`, and never fewer than the B * chunk rows
+    the caller's `chunk` already allows. `dtype` is the hidden states':
+    a chunk's logits are kept in it, and so is the residual by `hidden`;
+    the residual by the kernel is counted in f32, as the scan carries it.
+    """
+    if L % chunk != 0:
+        raise ValueError("L=%d not divisible by chunk=%d" % (L, chunk))
+    total = B * L
+    fit = min(total, max(1, LOGITS_BUDGET_BYTES // (4 * V)))
+    rows = max(B * chunk,
+               next(r for r in range(fit, 0, -1) if total % r == 0))
+    itemsize = jnp.dtype(dtype).itemsize
+    return {"rows": rows, "iterations": total // rows, "head_passes": 3,
+            "logits_bytes": rows * V * itemsize,
+            "residual_bytes": 4 * D * V + total * D * itemsize}
+
+
+def _scan_chunks(rows, hidden, kernel, targets, with_grads):
+    """The mean loss; with `with_grads` also its gradients by `hidden`
+    and `kernel`, formed chunk by chunk while the logits are there."""
+    with jax.named_scope(profile.LOSS):
+        w = kernel.astype(hidden.dtype)   # once, not once an iteration
+        scale = 1.0 / targets.size
+
+        def body(carry, xs):
+            h_c, t_c = xs
+            # The MXU accumulates in f32; what it hands on is rounded to
+            # the compute dtype, and softmax and lse are f32 on that. A
+            # chunk's logits so live in HBM in the compute dtype: asked
+            # for in f32 they are 98 MiB more a 1024 rows at V=50304.
+            logits = (h_c @ w).astype(jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            # The target's logit by a masked sum, which fuses with the
+            # other reductions; a gather makes XLA keep a second, f32,
+            # copy of the logits for it.
+            onehot = t_c[:, None] == jnp.arange(logits.shape[-1])
+            tgt = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
+            total = carry[0] + jnp.sum(lse - tgt)
+            if not with_grads:
+                return (total,), None
+            # Rounded to the compute dtype as autodiff's cotangent of
+            # the projection would be.
+            dl = ((jnp.exp(logits - lse[:, None]) - onehot)
+                  * scale).astype(w.dtype)
+            dh_c = lax.dot_general(dl, w, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+            # The MXU's f32 accumulator goes into the f32 sum as it is.
+            dw = carry[1] + lax.dot_general(
+                h_c, dl, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return (total, dw), dh_c.astype(hidden.dtype)
+
+        init = (jnp.float32(0.0),)
+        if with_grads:
+            init += (jnp.zeros(kernel.shape, jnp.float32),)
+        carry, dh = lax.scan(
+            body, init, (hidden.reshape(-1, rows, hidden.shape[-1]),
+                         targets.reshape(-1, rows)))
+        loss = carry[0] * scale
+        if not with_grads:
+            return loss
+        return loss, (dh.reshape(hidden.shape),
+                      carry[1].astype(kernel.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _mean_nll(rows, hidden, kernel, targets):
+    return _scan_chunks(rows, hidden, kernel, targets, with_grads=False)
+
+
+def _mean_nll_fwd(rows, hidden, kernel, targets):
+    return _scan_chunks(rows, hidden, kernel, targets, with_grads=True)
+
+
+def _mean_nll_bwd(rows, residuals, g):
+    dh, dw = residuals
+    with jax.named_scope(profile.LOSS):
+        # In g's f32, then rounded: a g rounded to bf16 first would bias
+        # every row alike.
+        return (g * dh).astype(dh.dtype), (g * dw).astype(dw.dtype), None
+
+
+_mean_nll.defvjp(_mean_nll_fwd, _mean_nll_bwd)
+
 
 def chunked_softmax_cross_entropy(hidden, kernel, targets, chunk=512):
     """Mean token cross entropy over chunked vocab projections.
 
     Args:
       hidden: [B, L, D] final hidden states (any float dtype; the
-        projection runs in the kernel's compute dtype and reduces in
-        f32).
+        projection runs in this dtype and reduces in f32).
       kernel: [D, V] lm-head kernel (no bias, the standard LM head).
       targets: [B, L] int target token ids.
       chunk: sequence chunk length; L must be divisible by it (pass
-        chunk=L for one-shot).
+        chunk=L for one-shot). A scan iteration takes at least
+        B * chunk of the B * L rows, and more where their logits fit
+        `LOGITS_BUDGET_BYTES` (`loss_plan`).
 
     Returns the scalar mean loss = mean(logsumexp(logits) -
-    logits[target]) — identical math to log_softmax + gather.
+    logits[target]) — identical math to log_softmax + gather. Both
+    gradients are formed in the forward pass (module text).
     """
     B, L, D = hidden.shape
-    if L % chunk != 0:
-        raise ValueError("L=%d not divisible by chunk=%d" % (L, chunk))
-    with jax.named_scope(profile.LOSS):
-        n = L // chunk
-        h = hidden.reshape(B, n, chunk, D).transpose(1, 0, 2, 3)
-        t = targets.reshape(B, n, chunk).transpose(1, 0, 2)
-
-        @jax.checkpoint
-        def chunk_loss(h_c, t_c):
-            logits = (h_c @ kernel.astype(h_c.dtype)).astype(jnp.float32)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            tgt = jnp.take_along_axis(logits, t_c[..., None],
-                                      axis=-1)[..., 0]
-            return jnp.sum(lse - tgt)
-
-        def body(acc, xs):
-            h_c, t_c = xs
-            return acc + chunk_loss(h_c, t_c), None
-
-        total, _ = lax.scan(body, jnp.float32(0.0), (h, t))
-        return total / (B * L)
+    plan = loss_plan(B, L, D, kernel.shape[1], chunk, hidden.dtype)
+    return _mean_nll(plan["rows"], hidden, kernel, targets)

@@ -265,3 +265,20 @@ def flash_plan(*args, **kwargs):
     from horovod_tpu.ops.flash_attention import flash_plan as plan
 
     return plan(*args, **kwargs)
+
+
+# --- how the vocabulary loss cuts a call into chunks ------------------------
+
+def loss_plan(*args, **kwargs):
+    """How `ops.losses.chunked_softmax_cross_entropy` runs a call of the
+    given shapes: `ops.losses.loss_plan(B, L, D, V, chunk, dtype)` (its
+    arguments and result), here beside the other program-side counters. The
+    rows of a scan iteration and how many iterations that makes, the passes
+    of the head a training step makes (3: logits, d-hidden, d-kernel; the
+    gradient is formed in the forward pass), the bytes of a chunk's live
+    logits and of the residuals the forward keeps. The loss runs what this
+    returns, so like `flash_plan` it needs no chip."""
+    # `ops.losses` imports this module for its scope's name.
+    from horovod_tpu.ops.losses import loss_plan as plan
+
+    return plan(*args, **kwargs)

@@ -189,7 +189,9 @@ def test_lowered_step_names_its_phases(model, kind):
         assert some(inside + r"jvp\(", part), (part, "forward")
         assert some(inside + r"transpose\(jvp\(", part), (part, "backward")
     assert some(inside + r"jvp\(%s\)" % profile.LOSS)
-    assert some(inside + r"transpose\(jvp\(%s\)\)" % profile.LOSS)
+    # the chunked loss forms its gradient in the forward pass; what its
+    # backward rule does (scale by the cotangent) is under the scope too
+    assert some(inside + r"transpose\(", profile.LOSS)
     assert some(profile.OPTIMIZER + "/")
     assert some(profile.PARAM_GATHER + "/all_gather") == (kind == "zero1")
     wanted = "reduce_scatter" if kind == "zero1" else "psum"
@@ -283,6 +285,47 @@ def test_flash_plan_under_hvd_profile_is_the_kernels_own(backward):
     assert set(got) == ({profile.FLASH_DQ, profile.FLASH_DKV} if backward
                         else {profile.FLASH_FWD})
     assert all(p.path == "resident" for p in got.values())
+
+
+# (B, L, D, V, chunk) -> (rows, iterations): the two LM cells' shapes cut
+# alike though one passes B=2 and the other B=1; `chunk=L` stays one shot; a
+# caller's B * chunk past the budget is kept; B * L = 3 * 1021 has no
+# divisor under the budget's 1334 rows but 1021, which is B * chunk.
+LOSS_PLANS = {
+    "lm1b4": ((2, 2048, 2048, 50304, 512), (1024, 4)),
+    "olmoe1b7": ((1, 4096, 2048, 50304, 512), (1024, 4)),
+    "one_shot": ((1, 4096, 2048, 50304, 4096), (4096, 1)),
+    "callers_chunk_past_the_budget": ((8, 2048, 2048, 50304, 512),
+                                      (4096, 4)),
+    "no_divisor_but_the_callers": ((1, 3063, 2048, 50304, 1021),
+                                   (1021, 3)),
+    "small_vocabulary_one_iteration": ((8, 32, 16, 64, 16), (256, 1)),
+    "one_token": ((1, 1, 2048, 50304, 1), (1, 1)),
+}
+
+
+@pytest.mark.parametrize("case", list(LOSS_PLANS))
+def test_loss_plan_under_hvd_profile_is_the_losss_own(case):
+    """`hvd.profile.loss_plan` is the plan `chunked_softmax_cross_entropy`
+    runs: rows from B * L, V and the budget, never fewer than B * chunk,
+    three passes of the head, and the bytes a chunk and the residuals take."""
+    from horovod_tpu.ops import losses
+
+    (B, L, D, V, chunk), (rows, iterations) = LOSS_PLANS[case]
+    plan = profile.loss_plan(B, L, D, V, chunk, jnp.bfloat16)
+    assert plan == losses.loss_plan(B, L, D, V, chunk, jnp.bfloat16)
+    assert (plan["rows"], plan["iterations"]) == (rows, iterations)
+    assert plan["head_passes"] == 3
+    assert plan["rows"] >= B * chunk and B * L % plan["rows"] == 0
+    assert plan["logits_bytes"] == 2 * rows * V
+    assert plan["logits_bytes"] <= max(2 * B * chunk * V,
+                                       losses.LOGITS_BUDGET_BYTES // 2)
+    assert plan["residual_bytes"] == 4 * D * V + 2 * B * L * D
+
+
+def test_loss_plan_rejects_what_the_loss_rejects():
+    with pytest.raises(ValueError, match="divisible"):
+        profile.loss_plan(1, 10, 4, 7, 3)
 
 
 def test_ring_kernels_carry_their_three_names():
