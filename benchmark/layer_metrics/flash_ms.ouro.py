@@ -1,0 +1,4 @@
+"""`flash_ms` for the Ouro cell, whose only `tpu_custom_call`s are the flash
+kernels (three a layer pass, T x N layer passes a step)."""
+
+from benchmark.layer_metrics.flash_ms import read  # noqa: F401

@@ -12,6 +12,7 @@ rotary position embeddings computed from *global* positions so sequence
 shards agree.
 """
 
+import contextlib
 import dataclasses
 from typing import Any, Optional
 
@@ -90,6 +91,21 @@ class TransformerConfig:
     # the split into heads and before rotary (OLMoE's QK-norm).
     qk_norm: bool = False
     norm_eps: float = 1e-6        # every RMSNorm's epsilon
+    # Passes over the ONE stack of blocks, on the same weights (a looped
+    # or universal transformer; Ouro's `total_ut_steps`): `norm_f` closes
+    # every pass and its output is the next pass's input. 1: the plain
+    # decoder.
+    num_passes: int = 1
+    # A second RMSNorm on each branch's OUTPUT, before the residual add
+    # (`x + rms(attn(rms(x)))`; Ouro's sandwich norm).
+    sandwich_norm: bool = False
+    # Gated dense feed-forward, `mlp_out(silu(mlp_gate x) * mlp_up x)`,
+    # both of width mlp_dim; False: `mlp_out(silu(mlp_in x))`.
+    mlp_gated: bool = False
+    # An exit gate, Dense(1) with bias, on every pass's normed output;
+    # `return_hidden=True` then hands back every pass's hidden states
+    # and gate logits for `ops.losses.expected_exit_loss`.
+    exit_gate: bool = False
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
@@ -108,6 +124,27 @@ class TransformerConfig:
             raise ValueError("moe_experts cannot be combined with "
                              "tp_axis (MoE blocks are ep-parallel, "
                              "not tensor-parallel)")
+        if self.num_passes < 1:
+            raise ValueError("num_passes=%d: the stack runs at least once"
+                             % self.num_passes)
+        if self.num_passes > 1:
+            # Not built, so refused by name: the placement rules
+            # (tp_param_specs, ep_param_specs) and the sequence-parallel
+            # tests know one use of a weight a step, and a routed block
+            # would sow its auxiliary losses once a pass.
+            for field in ("tp_axis", "sp_axis", "ep_axis", "moe_experts"):
+                if getattr(self, field) is not None:
+                    raise ValueError("num_passes=%d cannot be combined "
+                                     "with %s (the looped stack is built "
+                                     "for dense blocks on one device a "
+                                     "replica)" % (self.num_passes, field))
+        if self.exit_gate and self.num_passes < 2:
+            raise ValueError("exit_gate reads the passes of a looped "
+                             "stack: give num_passes > 1")
+        if self.mlp_gated and self.tp_axis is not None:
+            raise ValueError("mlp_gated cannot be combined with tp_axis "
+                             "(tp_param_specs places mlp_in and mlp_out "
+                             "only)")
 
     def local(self, tp_size):
         """The per-shard config for `tp_size`-way tensor parallelism."""
@@ -222,8 +259,11 @@ class Block(nn.Module):
         # in-kernel from global row offsets, which assumes the standard
         # contiguous 0..L-1 layout. Custom position ids (packing, shifted
         # windows) require rope_fused=False.
-        x = x + Attention(cfg, name="attn")(_rms_norm(cfg, "norm1")(x),
-                                           positions)
+        # `out`: the sandwich norm on a branch's output, or nothing.
+        out = (lambda name, h: _rms_norm(cfg, name)(h)) \
+            if cfg.sandwich_norm else (lambda name, h: h)
+        x = x + out("norm1_out", Attention(cfg, name="attn")(
+            _rms_norm(cfg, "norm1")(x), positions))
         h = _rms_norm(cfg, "norm2")(x)
         # `mlp` beside flax's `attn`: the profiler's scope for this half
         # of the block (hvd.profile), dense or routed; no module and no
@@ -237,20 +277,22 @@ class Block(nn.Module):
                            top_k=cfg.moe_top_k, gated=cfg.moe_gated,
                            renormalize=cfg.moe_renormalize,
                            dtype=cfg.dtype, name="moe_mlp")(h)
-            return x + h
+            return x + out("norm2_out", h)
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, dtype=cfg.dtype, param_dtype=jnp.float32, use_bias=False,
+            name=name)
         with jax.named_scope("mlp"):
-            h = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype,
-                         param_dtype=jnp.float32, use_bias=False,
-                         name="mlp_in")(h)
-            h = nn.silu(h)
-            h = nn.Dense(cfg.embed_dim, dtype=cfg.dtype,
-                         param_dtype=jnp.float32, use_bias=False,
-                         name="mlp_out")(h)
+            if cfg.mlp_gated:
+                h = nn.silu(dense(cfg.mlp_dim, "mlp_gate")(h)) \
+                    * dense(cfg.mlp_dim, "mlp_up")(h)
+            else:
+                h = nn.silu(dense(cfg.mlp_dim, "mlp_in")(h))
+            h = dense(cfg.embed_dim, "mlp_out")(h)
             if cfg.tp_axis is not None:
                 # Column-parallel mlp_in -> row-parallel mlp_out: the out
                 # product over the local hidden slice is a partial sum.
                 h = lax.psum(h, cfg.tp_axis)
-        return x + h
+        return x + out("norm2_out", h)
 
 
 class Transformer(nn.Module):
@@ -264,7 +306,13 @@ class Transformer(nn.Module):
     materializing the [B, L, vocab] logits: that loss projects a chunk
     of rows at a time and forms both gradients in the same pass, so
     its peak is O(rows x vocab) plus the [D, vocab] f32 and [B, L, D]
-    gradients it hands to the backward."""
+    gradients it hands to the backward.
+
+    With ``cfg.num_passes`` > 1 the blocks run that many times on the same
+    weights and the logits are the last pass's. With ``cfg.exit_gate``,
+    ``return_hidden=True`` returns ``(hidden [T, B, L, D], gate logits
+    [T, B, L] f32)`` of all T passes: what
+    `horovod_tpu.ops.losses.expected_exit_loss` takes."""
     cfg: TransformerConfig
 
     @nn.compact
@@ -280,15 +328,47 @@ class Transformer(nn.Module):
             x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
                          param_dtype=jnp.float32, dtype=cfg.dtype,
                          name="embed")(tokens)
+        blocks = []
         for i in range(cfg.num_layers):
             moe = (cfg.moe_experts is not None and
                    i % cfg.moe_every == cfg.moe_every - 1)
-            with jax.named_scope(profile.BLOCK):
-                x = Block(cfg, moe=moe, name="block_%d" % i)(x, positions)
-        with jax.named_scope(profile.HEAD):
-            x = _rms_norm(cfg, "norm_f")(x)
+            blocks.append(Block(cfg, moe=moe, name="block_%d" % i))
+        norm_f = _rms_norm(cfg, "norm_f")
+
+        def loop_scope(name):
+            # The loop's names exist only where there is a loop.
+            return jax.named_scope(name) if cfg.num_passes > 1 \
+                else contextlib.nullcontext()
+
+        # The same blocks and the one final norm, `num_passes` times: a
+        # pass's normed output is its exit's hidden state and the next
+        # pass's input. Unrolled, so that every pass keeps its own name in
+        # the profiler's trace.
+        exits = []
+        with loop_scope(profile.LOOP):
+            for t in range(cfg.num_passes):
+                with loop_scope(profile.LOOP_PASS % (t + 1)):
+                    for block in blocks:
+                        with jax.named_scope(profile.BLOCK):
+                            x = block(x, positions)
+                    with jax.named_scope(profile.HEAD):
+                        x = norm_f(x)
+                exits.append(x)
+        if cfg.exit_gate:
+            # Formed in either mode, so that `init` makes the gate beside
+            # the head; unused, it is no part of the program.
+            with jax.named_scope(profile.EXIT):
+                hidden = jnp.stack(exits)
+                # In f32: a matrix of one column, and its logits decide
+                # every exit's weight in the loss.
+                gates = nn.Dense(1, dtype=jnp.float32,
+                                 param_dtype=jnp.float32,
+                                 name="exit_gate")(hidden)[..., 0]
             if return_hidden:
-                return x
+                return hidden, gates
+        if return_hidden:
+            return x
+        with jax.named_scope(profile.HEAD):
             logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
                               param_dtype=jnp.float32, use_bias=False,
                               name="lm_head")(x)

@@ -40,7 +40,7 @@ from horovod_tpu import profile
 LOGITS_BUDGET_BYTES = 256 * 2**20
 
 
-def loss_plan(B, L, D, V, chunk=512, dtype=jnp.bfloat16):
+def loss_plan(B, L, D, V, chunk=512, dtype=jnp.bfloat16, weighted=False):
     """How `chunked_softmax_cross_entropy` runs a call of the given
     shapes (`hvd.profile.loss_plan`; the function runs what this
     returns, so it needs no chip):
@@ -55,6 +55,8 @@ def loss_plan(B, L, D, V, chunk=512, dtype=jnp.bfloat16):
     the caller's `chunk` already allows. `dtype` is the hidden states':
     a chunk's logits are kept in it, and so is the residual by `hidden`;
     the residual by the kernel is counted in f32, as the scan carries it.
+    `weighted`: a call with per-row weights, which keeps each row's loss
+    in f32 as well (the gradient by its weight).
     """
     if L % chunk != 0:
         raise ValueError("L=%d not divisible by chunk=%d" % (L, chunk))
@@ -65,18 +67,22 @@ def loss_plan(B, L, D, V, chunk=512, dtype=jnp.bfloat16):
     itemsize = jnp.dtype(dtype).itemsize
     return {"rows": rows, "iterations": total // rows, "head_passes": 3,
             "logits_bytes": rows * V * itemsize,
-            "residual_bytes": 4 * D * V + total * D * itemsize}
+            "residual_bytes": 4 * D * V + total * D * itemsize
+            + (4 * total if weighted else 0)}
 
 
-def _scan_chunks(rows, hidden, kernel, targets, with_grads):
-    """The mean loss; with `with_grads` also its gradients by `hidden`
-    and `kernel`, formed chunk by chunk while the logits are there."""
+def _scan_chunks(rows, hidden, kernel, targets, weights, with_grads):
+    """sum_i w_i * nll_i over the rows, `weights` None standing for
+    1 / rows-in-all on every row (the mean); with `with_grads` also its
+    gradients by `hidden` and `kernel`, formed chunk by chunk while the
+    logits are there, and with `weights` each row's nll, which is the
+    gradient by its weight."""
     with jax.named_scope(profile.LOSS):
         w = kernel.astype(hidden.dtype)   # once, not once an iteration
         scale = 1.0 / targets.size
 
         def body(carry, xs):
-            h_c, t_c = xs
+            h_c, t_c = xs[:2]
             # The MXU accumulates in f32; what it hands on is rounded to
             # the compute dtype, and softmax and lse are f32 on that. A
             # chunk's logits so live in HBM in the compute dtype: asked
@@ -88,55 +94,88 @@ def _scan_chunks(rows, hidden, kernel, targets, with_grads):
             # copy of the logits for it.
             onehot = t_c[:, None] == jnp.arange(logits.shape[-1])
             tgt = jnp.sum(jnp.where(onehot, logits, 0.0), axis=-1)
-            total = carry[0] + jnp.sum(lse - tgt)
+            nll = lse - tgt
+            if weights is None:
+                total, row_scale = carry[0] + jnp.sum(nll), scale
+            else:
+                w_c = xs[2]
+                total, row_scale = carry[0] + jnp.sum(w_c * nll), w_c[:, None]
             if not with_grads:
                 return (total,), None
             # Rounded to the compute dtype as autodiff's cotangent of
             # the projection would be.
             dl = ((jnp.exp(logits - lse[:, None]) - onehot)
-                  * scale).astype(w.dtype)
+                  * row_scale).astype(w.dtype)
             dh_c = lax.dot_general(dl, w, (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32)
             # The MXU's f32 accumulator goes into the f32 sum as it is.
             dw = carry[1] + lax.dot_general(
                 h_c, dl, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            return (total, dw), dh_c.astype(hidden.dtype)
+            dh_c = dh_c.astype(hidden.dtype)
+            return (total, dw), dh_c if weights is None else (dh_c, nll)
 
         init = (jnp.float32(0.0),)
         if with_grads:
             init += (jnp.zeros(kernel.shape, jnp.float32),)
-        carry, dh = lax.scan(
-            body, init, (hidden.reshape(-1, rows, hidden.shape[-1]),
-                         targets.reshape(-1, rows)))
-        loss = carry[0] * scale
+        xs = (hidden.reshape(-1, rows, hidden.shape[-1]),
+              targets.reshape(-1, rows))
+        if weights is not None:
+            xs += (weights.astype(jnp.float32).reshape(-1, rows),)
+        carry, ys = lax.scan(body, init, xs)
+        loss = carry[0] * scale if weights is None else carry[0]
         if not with_grads:
             return loss
-        return loss, (dh.reshape(hidden.shape),
-                      carry[1].astype(kernel.dtype))
+        dw = carry[1].astype(kernel.dtype)
+        if weights is None:
+            return loss, (ys.reshape(hidden.shape), dw)
+        return loss, (ys[0].reshape(hidden.shape), dw,
+                      ys[1].reshape(weights.shape))
+
+
+def _scale(g, residual):
+    # In g's f32, then rounded: a g rounded to bf16 first would bias
+    # every row alike.
+    return (g * residual).astype(residual.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _mean_nll(rows, hidden, kernel, targets):
-    return _scan_chunks(rows, hidden, kernel, targets, with_grads=False)
+    return _scan_chunks(rows, hidden, kernel, targets, None, False)
 
 
 def _mean_nll_fwd(rows, hidden, kernel, targets):
-    return _scan_chunks(rows, hidden, kernel, targets, with_grads=True)
+    return _scan_chunks(rows, hidden, kernel, targets, None, True)
 
 
 def _mean_nll_bwd(rows, residuals, g):
-    dh, dw = residuals
     with jax.named_scope(profile.LOSS):
-        # In g's f32, then rounded: a g rounded to bf16 first would bias
-        # every row alike.
-        return (g * dh).astype(dh.dtype), (g * dw).astype(dw.dtype), None
+        return tuple(_scale(g, r) for r in residuals) + (None,)
 
 
 _mean_nll.defvjp(_mean_nll_fwd, _mean_nll_bwd)
 
 
-def chunked_softmax_cross_entropy(hidden, kernel, targets, chunk=512):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _weighted_nll(rows, hidden, kernel, targets, weights):
+    return _scan_chunks(rows, hidden, kernel, targets, weights, False)
+
+
+def _weighted_nll_fwd(rows, hidden, kernel, targets, weights):
+    return _scan_chunks(rows, hidden, kernel, targets, weights, True)
+
+
+def _weighted_nll_bwd(rows, residuals, g):
+    dh, dw, nll = residuals
+    with jax.named_scope(profile.LOSS):
+        return _scale(g, dh), _scale(g, dw), None, g * nll
+
+
+_weighted_nll.defvjp(_weighted_nll_fwd, _weighted_nll_bwd)
+
+
+def chunked_softmax_cross_entropy(hidden, kernel, targets, chunk=512,
+                                  weights=None):
     """Mean token cross entropy over chunked vocab projections.
 
     Args:
@@ -148,11 +187,85 @@ def chunked_softmax_cross_entropy(hidden, kernel, targets, chunk=512):
         chunk=L for one-shot). A scan iteration takes at least
         B * chunk of the B * L rows, and more where their logits fit
         `LOGITS_BUDGET_BYTES` (`loss_plan`).
+      weights: optional [B, L] float, a weight a row; the result is then
+        ``sum(weights * nll)`` (None stands for 1 / (B * L) on every
+        row), differentiable by the weights too: a row's gradient is
+        its nll.
 
     Returns the scalar mean loss = mean(logsumexp(logits) -
-    logits[target]) — identical math to log_softmax + gather. Both
+    logits[target]) — identical math to log_softmax + gather. All
     gradients are formed in the forward pass (module text).
     """
     B, L, D = hidden.shape
     plan = loss_plan(B, L, D, kernel.shape[1], chunk, hidden.dtype)
-    return _mean_nll(plan["rows"], hidden, kernel, targets)
+    if weights is None:
+        return _mean_nll(plan["rows"], hidden, kernel, targets)
+    return _weighted_nll(plan["rows"], hidden, kernel, targets, weights)
+
+
+# --------------------------------------------------------------------------
+# The loss of a looped stack with an exit after every pass
+# (`models.Transformer` with `num_passes` > 1 and `exit_gate`)
+# --------------------------------------------------------------------------
+
+def exit_distribution(gate_logits):
+    """(p, log p), each [T, ...] f32: the share of every token that
+    leaves at exit t, from the gates' logits [T, ...]. lam^t =
+    sigmoid(logit^t) is the share of what reached exit t that leaves
+    there; the last exit takes what is left, so its own gate is not read:
+
+        p^t = lam^t * prod_{j<t} (1 - lam^j)   (t < T)
+        p^T = prod_{j<T} (1 - lam^j)
+
+    Formed in logs (`log_sigmoid`), so that log p is finite wherever the
+    logits are (a trained gate saturates, and 0 * log 0 would poison the
+    entropy's gradient); p sums to 1 over t as closely as the device's
+    exp and log are exact (1.5e-4 on a v5e)."""
+    g = gate_logits.astype(jnp.float32)
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-g[:-1]), axis=0)
+    reached = jnp.concatenate([jnp.zeros_like(g[:1]), stay], axis=0)
+    leave = jnp.concatenate([jax.nn.log_sigmoid(g[:-1]),
+                             jnp.zeros_like(g[:1])], axis=0)
+    logp = reached + leave
+    return jnp.exp(logp), logp
+
+
+def expected_exit_loss(hidden, gate_logits, kernel, targets, beta=0.0,
+                       chunk=512):
+    """The training objective of a looped LM with learned exits (Ouro's
+    Stage I, arXiv:2510.25741 section 3): the cross-entropy expected
+    under each token's exit distribution, less `beta` times that
+    distribution's entropy (a uniform prior over the exits),
+
+        mean_i [ sum_t p^t_i * nll^t_i  -  beta * H(p_i) ]
+
+    hidden [T, B, L, D] and gate_logits [T, B, L] are every pass's, as
+    `models.Transformer` returns them; kernel [D, V] the one head of all
+    exits; targets [B, L]. All T * B * L rows go through ONE call of
+    `chunked_softmax_cross_entropy` with p / (B * L) as the rows'
+    weights: one scan, one f32 gradient of the head, and the gradient by
+    the gates comes back through the weights."""
+    T, B, L, D = hidden.shape
+    with jax.named_scope(profile.EXIT):
+        p, logp = exit_distribution(gate_logits)
+        weights = p / (B * L)
+    # One sequence of rows: the plan's floor of B * chunk rows a chunk
+    # would otherwise grow with the exits.
+    expected = chunked_softmax_cross_entropy(
+        hidden.reshape(1, T * B * L, D), kernel,
+        jnp.broadcast_to(targets, (T, B, L)).reshape(1, T * B * L),
+        chunk=chunk, weights=weights.reshape(1, T * B * L))
+    with jax.named_scope(profile.EXIT):
+        entropy = -jnp.sum(p * logp) / (B * L)
+        return expected - beta * entropy
+
+
+def exit_stats(gate_logits):
+    """A step's exit statistics from the gates' logits [T, ...]:
+    ``p_mean`` [T], the mean share of a token that leaves at each exit
+    (sums to 1), and ``entropy``, the mean entropy of a token's exit
+    distribution in nats (at most ln T)."""
+    p, logp = exit_distribution(gate_logits)
+    p = p.reshape(p.shape[0], -1)
+    return {"p_mean": jnp.mean(p, axis=1),
+            "entropy": -jnp.sum(p * logp.reshape(p.shape)) / p.shape[1]}

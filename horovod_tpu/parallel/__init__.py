@@ -43,6 +43,9 @@ from .mesh import (  # noqa: F401
 from .expert import (  # noqa: F401
     MoeMlp, ep_grad_sync, ep_param_specs, moe_ffn, router_aux_losses,
     routing_stats)
+# The exits of a looped stack: the counter beside `routing_stats`; the loss
+# itself is `ops.losses.expected_exit_loss`.
+from horovod_tpu.ops.losses import exit_stats  # noqa: F401
 from .pipeline import pipeline_apply, stack_block_params  # noqa: F401
 from .ring import (ring_attention, ulysses_attention,  # noqa: F401
                    zigzag_shard, zigzag_unshard)

@@ -47,6 +47,16 @@ HEAD = "hvd_head"
 LOSS = "hvd_loss"
 MODEL_SCOPES = (EMBED, BLOCK, STEM) + STAGES + (HEAD, LOSS)
 
+# The looped stack (`models/transformer.py`, `num_passes` > 1), inside
+# FWD_BWD: `LOOP` around all the passes with `LOOP/pass_<t>` (t from 1)
+# around each, the blocks' own scopes beneath; `EXIT` around the exit
+# gate, the exit distribution, its entropy and the weights it gives the
+# loss (`ops/losses.py::expected_exit_loss`). Neither is in MODEL_SCOPES:
+# a block under a pass is still told apart as `hvd_block/attn`.
+LOOP = "hvd_loop"
+LOOP_PASS = "pass_%d"
+EXIT = "hvd_exit"
+
 # The routed feed-forward (`parallel/expert.py::MoeMlp`), inside a block's
 # `mlp` half: `MOE` around all of it, the four others inside `MOE`.
 MOE = "hvd_moe"
