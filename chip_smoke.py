@@ -28,6 +28,7 @@ import argparse
 import json
 import math
 import os
+import re
 import signal
 import subprocess
 import sys
@@ -58,9 +59,12 @@ SIZES = dict(
              moe_experts=16, moe_every=1, moe_top_k=4, moe_gated=True,
              moe_renormalize=False, moe_capacity_factor=None),
     moe_batch=4, moe_len=1024, moe_steps=4,
-    # (B, H, G, L, D, fused rotary): the L=1024 LM row's attention and the
-    # long-context h6/gqa2/frope row's.
-    attn=[(8, 12, 12, 1024, 64, False), (2, 6, 2, 8192, 128, True)],
+    # (B, H, G, L, D, fused rotary): the L=1024 LM row's attention, the
+    # long-context h6/gqa2/frope row's (its backward two kernels, dK/dV
+    # gridded) and that of the benchmark's 2 x 2048 LM cells (its backward
+    # one kernel, as at L=1024).
+    attn=[(8, 12, 12, 1024, 64, False), (2, 6, 2, 8192, 128, True),
+          (2, 16, 16, 2048, 128, False)],
     # (M, C) of the largest and the smallest BatchNorm of ResNet-50 at
     # batch 256.
     bn=[(256 * 112 * 112, 64), (256 * 7 * 7, 2048)],
@@ -120,6 +124,13 @@ def on_tpu(tree):
 
 def kernel_calls(text):
     return text.count('custom_call_target="tpu_custom_call"')
+
+
+def kernel_named(text, name):
+    """Whether the program holds a Pallas kernel called `name`:
+    `jvp(<name>)/pallas_call`, or `.../<name>/pallas_call` inside a
+    model's scopes."""
+    return re.search(r"\b%s\)*/pallas_call" % name, text) is not None
 
 
 def host_leaves(tree):
@@ -367,6 +378,25 @@ def attention_case(B, H, G, L, D, rotary, dtype, seed):
     return name, both(kernel), both(reference), (q, k, v, w)
 
 
+def flash_kernels(B, H, G, L, D, rotary, dtype):
+    """The names of the kernels a forward and backward of this shape run
+    (`hvd.profile.flash_plan`): the forward's, then the backward's one
+    (`hvd_flash_bwd`) or two."""
+    from horovod_tpu import profile
+
+    return [name for backward in (False, True)
+            for name in profile.flash_plan(B, H, L, D, H // G, dtype,
+                                           backward, rotary)]
+
+
+def model_flash_kernels(model, batch, length, dtype):
+    """`flash_kernels` of a layer of one of SIZES' LMs (plain heads, rotary
+    outside the kernels)."""
+    heads = model["num_heads"]
+    return flash_kernels(batch, heads, heads, length,
+                         model["embed_dim"] // heads, False, dtype)
+
+
 def print_flash_plan(B, H, G, L, D, rotary, dtype):
     """Which path each flash kernel of this shape takes (`hvd.profile`)."""
     from horovod_tpu import profile
@@ -384,16 +414,17 @@ def print_flash_plan(B, H, G, L, D, rotary, dtype):
                   flush=True)
 
 
-def attention_vs_reference(case, tol):
-    """The kernel is in the program, and on the chip it agrees with the
-    reference."""
+def attention_vs_reference(case, tol, kernels):
+    """The kernels the plan names are in the program, and on the chip they
+    agree with the reference."""
     import jax
 
     name, kernel, reference, qkvw = case
     compiled, text, secs = compile_with_text(kernel, *qkvw)
     n = kernel_calls(text)
-    check(n >= 3, "flash %s: %d tpu_custom_call in the program "
-          "(forward, dQ, dK/dV; compiled in %.1f s)" % (name, n, secs))
+    check(n == len(kernels) and all(kernel_named(text, k) for k in kernels),
+          "flash %s: %d tpu_custom_call in the program (%s; compiled in "
+          "%.1f s)" % (name, n, ", ".join(kernels), secs))
     got = compiled(*qkvw)
     with jax.default_matmul_precision("highest"):
         want = reference(*qkvw)
@@ -492,10 +523,13 @@ def phase_kernels(args):
     state = step.place(*state)
     compiled, text, secs = compile_with_text(step, *state)
     n = kernel_calls(text)
-    check(n >= 3 * SIZES["lm"]["num_layers"],
-          "LM L=%d flash: %d tpu_custom_call in the train step (forward, "
-          "dQ, dK/dV for each of %d layers; compiled in %.1f s)"
-          % (SIZES["lm_len"], n, SIZES["lm"]["num_layers"], secs))
+    flash = model_flash_kernels(SIZES["lm"], SIZES["lm_batch"],
+                                SIZES["lm_len"], jnp.bfloat16)
+    check(n >= len(flash) * SIZES["lm"]["num_layers"],
+          "LM L=%d flash: %d tpu_custom_call in the train step (%s for "
+          "each of %d layers; compiled in %.1f s)"
+          % (SIZES["lm_len"], n, ", ".join(flash),
+             SIZES["lm"]["num_layers"], secs))
     params, opt_state, losses, secs = run_steps(compiled, *state,
                                                 SIZES["lm_steps"])
     print("  LM %dx%d L=%d batch %d: steps %s ms; peak bytes %s"
@@ -511,10 +545,12 @@ def phase_kernels(args):
     state = step.place(*state)
     compiled, text, secs = compile_with_text(step, *state)
     n, layers = kernel_calls(text), SIZES["moe"]["num_layers"]
-    check(n >= 12 * layers and "hvd_moe_gmm_drhs" in text,
-          "MoE LM: %d tpu_custom_call in the train step (3 flash and 9 "
+    flash = model_flash_kernels(SIZES["moe"], SIZES["moe_batch"],
+                                SIZES["moe_len"], jnp.bfloat16)
+    check(n >= (len(flash) + 9) * layers and "hvd_moe_gmm_drhs" in text,
+          "MoE LM: %d tpu_custom_call in the train step (%d flash and 9 "
           "grouped-matmul kernels for each of %d layers; compiled in %.1f s)"
-          % (n, layers, secs))
+          % (n, len(flash), layers, secs))
     assigned = (SIZES["moe"]["moe_top_k"] * SIZES["moe_batch"]
                 * SIZES["moe_len"])
     check_routing(routing(state[0], state[2]), assigned)
@@ -533,7 +569,7 @@ def phase_kernels(args):
         print_flash_plan(*shape, jnp.bfloat16)
         attention_vs_reference(
             attention_case(*shape, jnp.bfloat16, args.seed + i),
-            TOL["attn_bf16"])
+            TOL["attn_bf16"], flash_kernels(*shape, jnp.bfloat16))
 
     step, state = resnet_step(models.ResNet50PBN, mesh,
                               SIZES["resnet_batch"], args.seed)

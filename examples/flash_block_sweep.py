@@ -1,19 +1,24 @@
-"""Flash-kernel block-size sweep at a given attention shape, on both
-paths of the plain kernels.
+"""Flash-kernel block-size sweep at a given attention shape, on every
+path of the plain kernels.
 
 `flash_plan` (ops/flash_attention.py) sends a kernel down the resident
 path (the other sequence whole in VMEM, walked by a loop inside the
 kernel) when its whole-sequence operands fit the VMEM budget, and down
-the gridded path (one pipeline step a tile) otherwise. This sweeps
-(block_q, block_k) on each path for the three kernels alone — forward,
-dQ, dK/dV; a backward kernel whose gradients are unused is dropped by
-XLA, so each is timed by itself — with the single-dispatch lax.scan
-recipe, and prints the plan the defaults give. The block tables
-(`_resident_blocks`; `_default_blocks` and `_grouped_blocks` for the
-gridded path) were read off it.
+the gridded path (one pipeline step a tile) otherwise; where the whole
+backward is resident it is ONE kernel (`hvd_flash_bwd`), else dQ and
+dK/dV apart. This sweeps (block_q, block_k) on each path — `resident`
+(the one-kernel backward where its blocks tile), `split` (a budget one
+byte short of the one kernel's: the two resident backward kernels) and
+`gridded` — for the kernels alone: forward, dQ, dK/dV, and the whole
+backward (one kernel, or the sum of the two). A backward kernel whose
+gradients are unused is dropped by XLA, so each is timed by itself, with
+the single-dispatch lax.scan recipe. The first row of a path is the
+plan's own blocks. The block tables (`_resident_blocks`;
+`_default_blocks` and `_grouped_blocks` for the gridded path) were read
+off it.
 
 Usage: python examples/flash_block_sweep.py [--B 2 --L 2048 --H 16 --D 128]
-           [--path both|resident|gridded]
+           [--path all|resident|split|gridded] [--kernels all|bwd]
 GQA/MQA (--G < --H) sweeps the grouped-rows layout: the q-block
 candidates become bqp*group rows. The `_grouped_blocks` policy was
 tuned from this sweep at two points — B2 H6 G2 L8192 D128 (1536/512)
@@ -31,12 +36,23 @@ from jax import lax
 
 import importlib
 
+from horovod_tpu import profile
+
 # The ops package re-exports the flash_attention FUNCTION under the
 # same name; import the module itself for the block-size internals.
 fa = importlib.import_module("horovod_tpu.ops.flash_attention")
 
-# `vmem_budget` that forces a path: nothing fits 0, everything fits 2**40.
-BUDGETS = {"gridded": 0, "resident": 2 ** 40}
+BWD = profile.FLASH_BWD
+
+
+def budgets(B, H, L, D, group, dtype, rotary):
+    """`vmem_budget` that forces a path: nothing fits 0, everything fits
+    2**40, and one byte less than the one-kernel backward holds keeps the
+    two resident backward kernels (each holds less)."""
+    fused = fa.flash_plan(B, H, L, D, group, dtype, True, rotary,
+                          vmem_budget=2 ** 40)
+    return {"gridded": 0, "split": fused[BWD].resident_bytes - 1,
+            "resident": 2 ** 40}
 
 
 def timed(fn, args, iters=30):
@@ -77,8 +93,10 @@ def main():
     ap.add_argument("--D", type=int, default=128)
     ap.add_argument("--rotary", action="store_true",
                     help="fused rotary (base 10000)")
-    ap.add_argument("--path", default="both",
-                    choices=("both", "resident", "gridded"))
+    ap.add_argument("--path", default="all",
+                    choices=("all", "resident", "split", "gridded"))
+    ap.add_argument("--kernels", default="all", choices=("all", "bwd"),
+                    help="bwd: leave the forward kernel out")
     ap.add_argument("--bqp", default="128,256,512",
                     help="q-block candidates, in positions")
     ap.add_argument("--bk", default="256,512,1024")
@@ -105,36 +123,55 @@ def main():
         for name, plan in fa.flash_plan(B, H, L, D, group, q.dtype,
                                         backward, base is not None).items():
             print("default plan %s: %s" % (name, plan._asdict()))
-    paths = ("gridded", "resident") if args.path == "both" else (args.path,)
-    print("%9s %6s %6s | %9s %9s %9s" % ("path", "bq", "bk", "fwd ms",
-                                         "dq ms", "dkv ms"))
+    by_path = budgets(B, H, L, D, group, q.dtype, base is not None)
+    paths = tuple(by_path) if args.path == "all" else (args.path,)
+    print("%9s %6s %6s | %9s %9s %9s %9s" % (
+        "path", "bq", "bk", "fwd ms", "dq ms", "dkv ms", "bwd ms"))
+
+    def ms(probe):
+        try:
+            return timed(probe, (q,), args.iters) * 1e3
+        except Exception as e:  # a block table Mosaic refuses
+            return "failed: %s" % str(e)[:40]
+
+    def cell(x):
+        return "%9s" % x if isinstance(x, str) else "%9.3f" % x
+
     for path in paths:
-        budget = BUDGETS[path]
-        for bqp in (int(x) for x in args.bqp.split(",")):
-            bq = bqp * group
-            for bk in (int(x) for x in args.bk.split(",")):
-                if rows % bq or L % bk or L % bqp:
-                    continue
+        budget = by_path[path]
+        # The plan's own blocks first, then the candidates.
+        candidates = [(None, None)] + [
+            (bqp * group, bk)
+            for bqp in (int(x) for x in args.bqp.split(",") if x)
+            for bk in (int(x) for x in args.bk.split(",") if x)
+            if not (rows % (bqp * group) or L % bk or L % bqp)]
+        for bq, bk in candidates:
+            def fwd(q, bq=bq, bk=bk):
+                return fa._pallas_forward_lse(
+                    q, k, v, scale, True, False, bq, bk, base, budget)[0]
 
-                def fwd(q, bq=bq, bk=bk):
-                    return fa._pallas_forward_lse(
-                        q, k, v, scale, True, False, bq, bk, base,
-                        budget)[0]
+            def bwd(q, bq=bq, bk=bk):
+                return fa._pallas_backward(
+                    q, k, v, out, lse, g, scale, True, False, bq, bk,
+                    base, budget)
 
-                def bwd(q, bq=bq, bk=bk):
-                    return fa._pallas_backward(
-                        q, k, v, out, lse, g, scale, True, False, bq, bk,
-                        base, budget)
-
-                cells = []
-                for probe in (fwd, lambda q: total(bwd(q)[0]),
-                              lambda q: total(*bwd(q)[1:])):
-                    try:
-                        cells.append("%9.3f" % (
-                            timed(probe, (q,), args.iters) * 1e3))
-                    except Exception as e:  # a block table Mosaic refuses
-                        cells.append("failed: %s" % str(e)[:40])
-                print("%9s %6d %6d | %s" % (path, bq, bk, " ".join(cells)))
+            plan = fa.flash_plan(B, H, L, D, group, q.dtype, True,
+                                 base is not None, bq, bk, budget)
+            t_fwd = ms(fwd) if args.kernels == "all" else "-"
+            if BWD in plan:  # one kernel: its three results at once
+                t_dq = t_dkv = "-"
+                t_bwd = ms(lambda q: total(*bwd(q)))
+            else:
+                if path == "resident" and bq is not None:
+                    continue  # blocks that do not tile: the split row's
+                t_dq = ms(lambda q: total(bwd(q)[0]))
+                t_dkv = ms(lambda q: total(*bwd(q)[1:]))
+                t_bwd = (t_dq + t_dkv if isinstance(t_dq, float)
+                         and isinstance(t_dkv, float) else "-")
+            print("%9s %6s %6s | %s" % (
+                path, bq or "plan", bk or "plan",
+                " ".join(cell(x) for x in (t_fwd, t_dq, t_dkv, t_bwd))),
+                flush=True)
 
 
 if __name__ == "__main__":

@@ -3,7 +3,10 @@
 Times forward and forward+backward with the lax.scan single-dispatch
 recipe (one dispatch covers the loop, so per-call host overhead stays
 out of a sub-millisecond kernel's timing), reporting ms/iter and
-effective TFLOP/s from the analytic causal FLOP count.
+effective TFLOP/s from the causal count of the matmuls the kernels
+execute: `hvd.profile.flash_plan` says whether the backward is one kernel
+(`hvd_flash_bwd`, 5 matmuls a tile) or two (`hvd_flash_dq` +
+`hvd_flash_dkv`, 7: s and dp are formed twice).
 """
 
 import argparse
@@ -14,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from horovod_tpu import profile
 from horovod_tpu.ops import flash_attention
 
 
@@ -53,10 +57,13 @@ def main():
     k = jnp.asarray(rng.randn(B, L, H, D), jnp.bfloat16)
     v = jnp.asarray(rng.randn(B, L, H, D), jnp.bfloat16)
 
-    # Causal-halved analytic FLOPs: fwd = 2 matmuls, bwd = 7 (see
-    # flash_attention analytic_attention_flops).
+    # Causal-halved executed FLOPs: fwd = 2 matmuls, bwd = 5 as one
+    # kernel or 7 as two.
+    bwd_kernels = list(profile.flash_plan(B, H, L, D, dtype=q.dtype,
+                                          backward=True))
+    bwd_matmuls = 5 if bwd_kernels == [profile.FLASH_BWD] else 7
     fwd_flops = 2 * 2 * B * H * L * L * D / 2
-    bwd_flops = 7 * 2 * B * H * L * L * D / 2
+    bwd_flops = bwd_matmuls * 2 * B * H * L * L * D / 2
 
     t_fwd = timed(lambda q: flash_attention(q, k, v, causal=True),
                   (q,), args.iters)
@@ -76,8 +83,9 @@ def main():
     print("B=%d L=%d H=%d D=%d causal:" % (B, L, H, D))
     print("  fwd:     %6.2f ms  %6.1f TFLOP/s" %
           (t_fwd * 1e3, fwd_flops / t_fwd / 1e12))
-    print("  fwd+bwd: %6.2f ms  %6.1f TFLOP/s" %
-          (t_fb * 1e3, (fwd_flops + bwd_flops) / t_fb / 1e12))
+    print("  fwd+bwd: %6.2f ms  %6.1f TFLOP/s (backward: %s, %d matmuls a "
+          "tile)" % (t_fb * 1e3, (fwd_flops + bwd_flops) / t_fb / 1e12,
+                     " + ".join(bwd_kernels), bwd_matmuls))
 
 
 if __name__ == "__main__":

@@ -9,7 +9,13 @@ argument, no environment variable:
   and dQ: k and v; dK/dV: q, dO, lse and delta), which at D=128 and one
   head a kv head is every kernel up to L=8192, and forward and dQ at
   16384. Grid (batch*kv_head, q-block) — (batch*kv_head,
-  k-block) for dK/dV. The other sequence comes in as ONE block per
+  k-block) for dK/dV. Where dQ's block and an f32 accumulator of dQ's
+  shape fit beside dK/dV's operands (D=128: up to L=4096) the backward
+  is ONE kernel, `hvd_flash_bwd`: the dK/dV kernel, adding each tile's
+  ds.k to dQ's rows as it goes, so s, p, dp and ds are formed once a
+  tile (5 matmuls and one exp where the two kernels make 7 and two);
+  its k-block axis carries dQ and runs in order.
+  The other sequence comes in as ONE block per
   batch*kv_head: its block index does not change across the inner grid
   axis, so Pallas fetches it once and double-buffers the next one behind
   this one's work. The kernel walks its row (column) of the causal
@@ -441,7 +447,12 @@ def _vmem(rows, cols, itemsize):
 # / dO, dq) and on the k side (k, v, dk, dv), and 8-wide f32 stripes on
 # the q side (lse, delta).
 _OPERANDS = {profile.FLASH_FWD: (2, 2, 1), profile.FLASH_DQ: (3, 2, 2),
-             profile.FLASH_DKV: (2, 4, 2)}
+             profile.FLASH_DKV: (2, 4, 2), profile.FLASH_BWD: (3, 4, 2)}
+
+
+# The kernels that hold a k block and walk the q blocks (the others hold a
+# q block and walk the k blocks).
+_K_HELD = (profile.FLASH_DKV, profile.FLASH_BWD)
 
 
 def _resident_blocks(D, L, group, kernel):
@@ -458,9 +469,23 @@ def _resident_blocks(D, L, group, kernel):
     at D=128 (L=2048): k blocks of 512 and as many rows as the gridded
     long-sequence cap, (1536, 512): 0.537 / 0.472 / 0.548 (384 x 512:
     0.514 / 0.497 / 0.646). Grouped layouts at D<=64 were not swept on
-    this path and keep the gridded tables."""
+    this path and keep the gridded tables.
+
+    The one-kernel backward (`hvd_flash_bwd`) keeps dK/dV's blocks: v5e
+    sweep (PR 33, `--path resident` against `--path split`; ms a layer,
+    the whole backward). 2 x 16 x 2048 x 128: dQ 0.523 + dK/dV 0.722 =
+    1.246 as two, as one (512, 1024) 0.876, (512, 512) 0.869, (1024, 512)
+    0.862, (1024, 1024) 0.889, (256, 1024) 0.891, (256, 512) 0.914,
+    bk 256: 1.02-1.45. 1 x 16 x 4096 x 128: 1.961 as two; (512, 1024)
+    1.387, (1024, 512) 1.378, (1024, 1024) 1.384, (256, 1024) 1.449,
+    (512, 512) 1.456. D=64 at 2 x 16 x 2048: 1.246; (512, 1024) 0.883,
+    (512, 512) 0.867. Fused rotary at D=128, L=2048: 1.338; (512, 1024)
+    0.951, (512, 512) 0.935. Group 3 (2 x 6 heads on 2, L=2048, D=128):
+    0.605; (1536, 512) 0.358, (768, 512) 0.371, (1536, 1024) 0.408. The
+    same pair read twice differs by up to 0.05, so nothing here beats the
+    dK/dV kernel's table by more than the reading's own spread."""
     if group == 1:
-        return (512, 1024) if kernel == profile.FLASH_DKV else (512, 512)
+        return (512, 1024) if kernel in _K_HELD else (512, 512)
     if D > 64:
         return (1536, 512)
     return _grouped_blocks(D, L, group, kernel != profile.FLASH_FWD)
@@ -469,8 +494,11 @@ def _resident_blocks(D, L, group, kernel):
 def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
                  block_k, vmem_budget):
     backward = kernel != profile.FLASH_FWD
-    dkv = kernel == profile.FLASH_DKV
+    dkv = kernel in _K_HELD
     n_q, n_k, n_stripes = _OPERANDS[kernel]
+    # The whole backward in one kernel: dQ's f32 accumulator, one buffer.
+    fused = kernel == profile.FLASH_BWD
+    dq_acc = _vmem(rows, D, 4) if fused else 0
     tables = 2 * 4 if rotary else 0  # (C, S) f32, per side
 
     def q_side(n):  # one pipeline buffer of n rows of every q-side operand
@@ -486,13 +514,14 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
         return bq, bk
 
     whole = q_side(rows) if dkv else k_side(L)
-    if 2 * whole <= vmem_budget:
+    resident = 2 * whole + dq_acc
+    if resident <= vmem_budget:
         bq, bk = blocks(_resident_blocks(D, L, group, kernel))
         bqp = bq // group
         # The loop's peel is static only where one block tiles the other
         # (every pair the tables give; a caller's own blocks may not).
         if bqp % bk == 0 or bk % bqp == 0:
-            buffers = 2 * (whole + (k_side(bk) if dkv else q_side(bq)))
+            buffers = resident + 2 * (k_side(bk) if dkv else q_side(bq))
             # Beside the buffers the kernel's values live in VMEM too: s,
             # p, dp, ds, their low-precision copies, the carried state.
             # Mosaic's own need, by a compile for a described v5e, is
@@ -504,8 +533,10 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
             limit = -(-(buffers + values) * 5 // 4 // 2 ** 20) * 2 ** 20
             grid = (BG, L // bk if dkv else rows // bq)
             return FlashKernelPlan(
-                "resident", bq, bk, grid, grid[0] * grid[1], 2 * whole,
+                "resident", bq, bk, grid, grid[0] * grid[1], resident,
                 buffers, max(_DEFAULT_VMEM_LIMIT, limit))
+    if fused:
+        return None  # no gridded form: a grid carries dQ or dK/dV, not both
     bq, bk = blocks(_grouped_blocks(D, L, group, backward))
     num_qb, num_kb = rows // bq, L // bk
     # acc / dq_acc (and m, l) per q block, or dk_acc + dv_acc per k block,
@@ -523,28 +554,44 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
                vmem_budget=RESIDENT_VMEM_BUDGET):
     """How `flash_attention` runs q [B, H, L, D] against H // group kv
     heads: {kernel name: FlashKernelPlan} for the forward kernel
-    (`hvd_flash_fwd`) or, with ``backward``, the two backward ones
-    (`hvd_flash_dq`, `hvd_flash_dkv`). THE place where the path is
+    (`hvd_flash_fwd`) or, with ``backward``, the backward: ONE kernel
+    (`hvd_flash_bwd`: s, p, dp and ds of a tile formed once for dQ, dK and
+    dV) where its operands are resident, else two (`hvd_flash_dq`,
+    `hvd_flash_dkv`), which form them twice. THE place where the path is
     chosen, from what a call can see and nothing else: a kernel is
     resident when its whole-sequence operands, double-buffered, fit
     ``vmem_budget`` (forward and dQ hold k + v, and k's rotary tables;
     dK/dV holds q + dO, q's tables, and lse + delta, whose 8-wide f32
-    rows pad to 128 lanes) and one of its blocks tiles the other;
-    gridded otherwise. `_pallas_forward_lse` and `_pallas_backward` run
-    what this returns, so it is also the counter that says which path a
-    program took (docs/TRACING.md; `hvd.profile.flash_plan`)."""
+    rows pad to 128 lanes; the one-kernel backward holds dK/dV's and
+    dQ's output block, and one f32 accumulator of dQ's shape) and one of
+    its blocks tiles the other; gridded otherwise. At D=128 in bf16 with
+    one head a kv head the one-kernel backward holds 8 MiB at L=2048, 16
+    at 4096 and 32 at 8192, where the budget keeps the two.
+    `_pallas_forward_lse` and `_pallas_backward` run what this returns,
+    so it is also the counter that says which path a program took
+    (docs/TRACING.md; `hvd.profile.flash_plan`)."""
     BG, rows = B * H // group, L * group
     isz = jnp.dtype(dtype).itemsize
-    names = ((profile.FLASH_DQ, profile.FLASH_DKV) if backward
-             else (profile.FLASH_FWD,))
-    return {name: _kernel_plan(BG, rows, L, D, group, isz, name, rotary,
-                               block_q, block_k, vmem_budget)
-            for name in names}
+
+    def plan(kernel):
+        return _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary,
+                            block_q, block_k, vmem_budget)
+
+    if not backward:
+        return {profile.FLASH_FWD: plan(profile.FLASH_FWD)}
+    fused = plan(profile.FLASH_BWD)
+    if fused is not None:
+        return {profile.FLASH_BWD: fused}
+    return {name: plan(name)
+            for name in (profile.FLASH_DQ, profile.FLASH_DKV)}
 
 
-def _compiler_params(plan):
+def _compiler_params(plan, carries=False):
+    """``carries``: the resident grid's block axis carries state in
+    scratch (the one-kernel backward's dQ), so its steps run in order."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * len(plan.grid)
+        dimension_semantics=("parallel", "arbitrary" if carries
+                             else "parallel")
         if plan.path == "resident"
         else ("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=plan.vmem_limit_bytes)
@@ -692,10 +739,20 @@ def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary):
     dq_ref[...] = dq.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary):
+def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
+                             with_dq):
     """dK/dV with q, dO, lse and delta whole in VMEM: `_bwd_dkv_kernel`'s
     arithmetic, the dk and dv accumulators carried by the loop; k is
-    rotated once, q per visit."""
+    rotated once, q per visit. ``with_dq`` (`hvd_flash_bwd`): the whole
+    backward in this one kernel. s, p, dp and ds of a tile are formed
+    once and serve dQ too: every visit adds ds.k to the rows of its q
+    block in an f32 [rows, D] accumulator that lives in VMEM scratch
+    across the grid's k-block axis, zeroed at the first k block of a
+    (batch, kv head) and written to the dQ output, counter-rotated and
+    cast once, at the last. dQ's sum over k blocks runs in ascending k
+    order in f32, as `_bwd_dq_resident_kernel`'s loop runs it."""
+    if with_dq:  # the third result and the one scratch
+        *refs, dq_ref, dq_acc = refs
     if rotary:
         (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
          lse_ref, delta_ref, dk_ref, dv_ref) = refs
@@ -708,6 +765,11 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary):
     if rotary:
         k = _rot(k, kc_ref[...], ks_ref[...])
     v = v_ref[...]
+
+    if with_dq:
+        @pl.when(kj == 0)
+        def _init():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def visit(i, carry, masked):
         dk, dv = carry
@@ -730,6 +792,10 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary):
         dk = dk + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if with_dq:
+            dq_acc[at, :] += jax.lax.dot_general(
+                ds, k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         return dk, dv
 
     zeros = jnp.zeros(k.shape, jnp.float32)
@@ -739,6 +805,19 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary):
         dk = _rot(dk, kc_ref[...], ks_ref[...], neg=True)
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
+
+    if with_dq:
+        @pl.when(kj == pl.num_programs(1) - 1)
+        def _finalize():
+            def store(i, carry):  # a q block at a time: bounded values
+                at = pl.ds(pl.multiple_of(i * bq, bq), bq)
+                dq = dq_acc[at, :]
+                if rotary:
+                    dq = _rot(dq, qc_ref[at, :], qs_ref[at, :], neg=True)
+                dq_ref[at, :] = dq.astype(dq_ref.dtype)
+                return carry
+
+            lax.fori_loop(0, q_ref.shape[0] // bq, store, 0)
 
 
 def _pallas_forward_lse(q, k, v, scale, causal, interpret,
@@ -1375,52 +1454,59 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     else:
         tables = []
     inputs = [qf, kf, vf] + tables + [gf, lse, delta]
+    dq_shape = jax.ShapeDtypeStruct((B * G, rows, D), q.dtype)
 
-    plan = plans[profile.FLASH_DQ]
-    bq, bk = plan.block_q, plan.block_k
-    bqp = bq // group
-    q_im, kv_spec, tq_spec, tk_spec = _q_walk_specs(plan, L, D, group,
-                                                    causal)
-    if plan.path == "resident":
-        kernel = functools.partial(_bwd_dq_resident_kernel, scale=scale,
-                                   causal=causal, bk=bk, bqp=bqp,
-                                   group=group, rotary=rotary)
-        scratch = []
-    else:
-        kernel = functools.partial(_bwd_dq_kernel, scale=scale,
-                                   causal=causal, num_kb=L // bk, bqp=bqp,
-                                   group=group, rotary=rotary)
-        scratch = [pltpu.VMEM((bq, D), jnp.float32)] + (
-            [pltpu.VMEM((bq, D), q.dtype)] if rotary else [])
-    q_spec = pl.BlockSpec((None, bq, D), q_im)
-    stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
-    dq = pl.pallas_call(
-        kernel,
-        name=profile.FLASH_DQ,
-        grid=plan.grid,
-        in_specs=[q_spec, kv_spec, kv_spec] + (
-            [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
-            q_spec, stripe_spec, stripe_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B * G, rows, D), q.dtype),
-        scratch_shapes=scratch,
-        compiler_params=_compiler_params(plan),
-        interpret=interpret,
-    )(*inputs)
+    # One kernel for the whole backward where the plan says so, else dQ
+    # by a kernel of its own.
+    fused = profile.FLASH_BWD in plans
+    if not fused:
+        plan = plans[profile.FLASH_DQ]
+        bq, bk = plan.block_q, plan.block_k
+        bqp = bq // group
+        q_im, kv_spec, tq_spec, tk_spec = _q_walk_specs(plan, L, D, group,
+                                                        causal)
+        if plan.path == "resident":
+            kernel = functools.partial(
+                _bwd_dq_resident_kernel, scale=scale, causal=causal, bk=bk,
+                bqp=bqp, group=group, rotary=rotary)
+            scratch = []
+        else:
+            kernel = functools.partial(
+                _bwd_dq_kernel, scale=scale, causal=causal, num_kb=L // bk,
+                bqp=bqp, group=group, rotary=rotary)
+            scratch = [pltpu.VMEM((bq, D), jnp.float32)] + (
+                [pltpu.VMEM((bq, D), q.dtype)] if rotary else [])
+        q_spec = pl.BlockSpec((None, bq, D), q_im)
+        stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
+        dq = pl.pallas_call(
+            kernel,
+            name=profile.FLASH_DQ,
+            grid=plan.grid,
+            in_specs=[q_spec, kv_spec, kv_spec] + (
+                [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
+                q_spec, stripe_spec, stripe_spec],
+            out_specs=q_spec,
+            out_shape=dq_shape,
+            scratch_shapes=scratch,
+            compiler_params=_compiler_params(plan),
+            interpret=interpret,
+        )(*inputs)
 
-    plan = plans[profile.FLASH_DKV]
+    plan = plans[profile.FLASH_BWD if fused else profile.FLASH_DKV]
     bq, bk = plan.block_q, plan.block_k
     bqp = bq // group
     if plan.path == "resident":
         kernel = functools.partial(_bwd_dkv_resident_kernel, scale=scale,
                                    causal=causal, bq=bq, bqp=bqp,
-                                   group=group, rotary=rotary)
+                                   group=group, rotary=rotary,
+                                   with_dq=fused)
         k_im = lambda b, j: (b, j, 0)                       # noqa: E731
         q_spec = pl.BlockSpec((None, rows, D), lambda b, j: (b, 0, 0))
         stripe_spec = pl.BlockSpec((None, rows, 8), lambda b, j: (b, 0, 0))
         tq_spec = pl.BlockSpec((rows, D), lambda b, j: (0, 0))
         tk_spec = pl.BlockSpec((bk, D), lambda b, j: (j, 0))
-        scratch = []
+        # dQ's accumulator across the k blocks of a (batch, kv head).
+        scratch = [pltpu.VMEM((rows, D), jnp.float32)] if fused else []
     else:
         kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
                                    causal=causal, num_qb=rows // bq,
@@ -1436,22 +1522,27 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
                    pltpu.VMEM((bk, D), jnp.float32)] + (
             [pltpu.VMEM((bk, D), k.dtype)] if rotary else [])
     k_spec = pl.BlockSpec((None, bk, D), k_im)
-    dk, dv = pl.pallas_call(
+    # dQ's block does not change across the k blocks: written back once.
+    results = pl.pallas_call(
         kernel,
-        name=profile.FLASH_DKV,
+        name=profile.FLASH_BWD if fused else profile.FLASH_DKV,
         grid=plan.grid,
         in_specs=[q_spec, k_spec, k_spec] + (
             [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
             q_spec, stripe_spec, stripe_spec],
-        out_specs=[k_spec, k_spec],
+        out_specs=[k_spec, k_spec] + ([q_spec] if fused else []),
         out_shape=[
             jax.ShapeDtypeStruct((B * G, L, D), k.dtype),
             jax.ShapeDtypeStruct((B * G, L, D), v.dtype),
-        ],
+        ] + ([dq_shape] if fused else []),
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(plan),
+        compiler_params=_compiler_params(plan, carries=fused),
         interpret=interpret,
     )(*inputs)
+    if fused:
+        dk, dv, dq = results
+    else:
+        dk, dv = results
 
     return (_from_rows(dq, B, group), dk.reshape(B, G, L, D),
             dv.reshape(B, G, L, D))
@@ -1530,13 +1621,16 @@ def analytic_attention_flops(B, H, L, D, causal=True, training=False):
     """FLOPs the Pallas attention kernels execute per call — XLA's
     compiled-cost analysis reports custom calls as ZERO flops, so
     benchmarks add this analytic count to keep MFU honest. Forward runs
-    2 matmuls per (q,k) block pair (QK^T, PV); the backward kernels run
-    7 matmul-equivalents (s and dp are recomputed in both the dQ and
+    2 matmuls per (q,k) block pair (QK^T, PV); the two backward kernels
+    run 7 matmul-equivalents (s and dp are recomputed in both the dQ and
     dK/dV kernels, plus the dQ/dK/dV products). ``training=True``
     therefore returns the FULL forward+backward step count (2 + 7 = 9
     per block pair) — callers must NOT add a separate forward term.
-    Causal halves the visited block pairs. H is the number of QUERY
-    heads — GQA/MQA change kv memory traffic, not attention FLOPs."""
+    Where the backward is one kernel (`flash_plan` names it
+    `hvd_flash_bwd`) it executes 5, s and dp formed once: 2 + 5 = 7, so
+    this count is then an upper bound. Causal halves the visited block
+    pairs. H is the number of QUERY heads — GQA/MQA change kv memory
+    traffic, not attention FLOPs."""
     per_matmul = 2.0 * B * H * L * L * D
     if causal:
         per_matmul /= 2.0

@@ -72,6 +72,7 @@ MOE_SCOPES = (MOE, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
 FLASH_FWD = "hvd_flash_fwd"
 FLASH_DQ = "hvd_flash_dq"
 FLASH_DKV = "hvd_flash_dkv"
+FLASH_BWD = "hvd_flash_bwd"  # dQ, dK and dV in one kernel (resident)
 RING_ATTN = "hvd_ring_attn"          # one forward step of ring attention
 RING_ATTN_DQ = "hvd_ring_attn_dq"    # one backward step: the dQ part
 RING_ATTN_DKV = "hvd_ring_attn_dkv"  # one backward step: the dK/dV part
@@ -81,8 +82,9 @@ MOE_GMM = "hvd_moe_gmm"            # grouped matmul of the experts, forward
 MOE_GMM_DLHS = "hvd_moe_gmm_dlhs"  # backward: the gradient of the rows
 MOE_GMM_DRHS = "hvd_moe_gmm_drhs"  # backward: the gradient of the matrices
 MOE_GMM_KERNELS = (MOE_GMM, MOE_GMM_DLHS, MOE_GMM_DRHS)
-KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, RING_ATTN, RING_ATTN_DQ,
-           RING_ATTN_DKV, BN_STATS, BN_GRAD_STATS) + MOE_GMM_KERNELS
+KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
+           RING_ATTN_DQ, RING_ATTN_DKV, BN_STATS,
+           BN_GRAD_STATS) + MOE_GMM_KERNELS
 
 # Host spans of the program's only per-call Python.
 SPAN_PLACE = "hvd_place"                  # `step.place`
@@ -266,7 +268,8 @@ def flash_plan(*args, **kwargs):
     """How `ops.flash_attention` runs a call of the given shape, kernel by
     kernel: `ops.flash_attention.flash_plan` (its arguments and result),
     here beside the other program-side counters. Per kernel name
-    (`FLASH_FWD`, or `FLASH_DQ` and `FLASH_DKV` with ``backward=True``): the
+    (`FLASH_FWD`; with ``backward=True`` `FLASH_BWD` alone where the whole
+    backward is one resident kernel, else `FLASH_DQ` and `FLASH_DKV`): the
     path (`resident`: the other sequence whole in VMEM, one grid step per
     block; `gridded`: one grid step per tile), the blocks, the grid and the
     grid steps a call issues, and the VMEM bytes it asks for. The kernels

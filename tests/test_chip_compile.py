@@ -15,14 +15,13 @@ live in this one file.
 
 import functools
 import os
-import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from chip_smoke import kernel_calls as _kernels
+from chip_smoke import kernel_calls as _kernels, kernel_named as _named
 from horovod_tpu.ops import batch_norm
 from horovod_tpu import profile
 from horovod_tpu.ops.flash_attention import (_flash, _pallas_forward_lse,
@@ -77,18 +76,14 @@ def _compile(one_chip, fn, *shapes):
 # LM and of its long-context h6 / gqa2 / fused-rope shape (the
 # one shape here whose dK/dV `flash_plan` sends down the gridded path:
 # three heads' rows and q's rotary tables do not fit), and of the
-# benchmark's two LM configurations on a chip (`neox1b4_w2048`: 2 x 2048;
-# `olmoe1b7_w2048`: 1 x 4096), whose resident dK/dV asks for more than
-# the default VMEM limit.
+# benchmark's LM configurations on a chip (`neox1b4_w2048`: 2 x 2048;
+# `olmoe1b7_w2048` and `ouro2b6_w2048`: 1 x 4096), whose resident
+# backward, one kernel, asks for more than the default VMEM limit, and of
+# a grouped, fused-rotary shape short enough that the one kernel holds it
+# (three heads' rows, q's tables and dQ's accumulator: 17 MiB).
 _LM_SHAPES = [(8, 12, 12, 1024, 64, None), (2, 6, 2, 8192, 128, 10000.0),
-              (2, 16, 16, 2048, 128, None), (1, 16, 16, 4096, 128, None)]
-
-
-def _named(text, name):
-    """Whether the program holds a Pallas kernel called `name`:
-    `jvp(<name>)/pallas_call` here, `.../<name>/pallas_call` inside a
-    model's scopes."""
-    return re.search(r"\b%s\)*/pallas_call" % name, text)
+              (2, 16, 16, 2048, 128, None), (1, 16, 16, 4096, 128, None),
+              (2, 6, 2, 1024, 128, 10000.0)]
 
 
 @pytest.mark.parametrize("B,H,G,L,D,rotary", _LM_SHAPES)
@@ -118,17 +113,19 @@ def test_flash_backward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
     text = _compile(one_chip, bwd, ((B, H, L, D), bf16),
                     ((B, G, L, D), bf16), ((B, G, L, D), bf16),
                     ((B, H, L, D), bf16))
-    # forward (for the residuals), dQ, dK/dV: three custom calls a layer
-    # under the three names, on either path.
-    assert _kernels(text) == 3, text[:2000]
-    for name in (profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV):
-        assert _named(text, name), name
+    # forward (for the residuals) and the backward: one kernel where
+    # `flash_plan` finds it resident, two custom calls a layer; dQ and
+    # dK/dV apart where not, three.
     paths = {name: p.path for backward in (False, True)
              for name, p in flash_plan(B, H, L, D, H // G, jnp.bfloat16,
                                        backward, rotary is not None).items()}
-    assert paths == {
+    assert paths == ({
         profile.FLASH_FWD: "resident", profile.FLASH_DQ: "resident",
-        profile.FLASH_DKV: "gridded" if L == 8192 else "resident"}
+        profile.FLASH_DKV: "gridded"} if L == 8192 else {
+        profile.FLASH_FWD: "resident", profile.FLASH_BWD: "resident"})
+    assert _kernels(text) == len(paths), text[:2000]
+    for name in paths:
+        assert _named(text, name), name
 
 
 # The ring LM of `chip_smoke.py --chips 4`: B2 x H6 per chip, L=8192 over
@@ -290,4 +287,7 @@ def test_one_device_step_is_compiled_as_before(topo, monkeypatch):
         assert rule(mesh) == {}
         texts.append(_step_text(step, state))
     assert texts[0] == texts[1]
-    assert _kernels(texts[0]) == 6  # flash fwd, dQ, dKdV in two layers
+    # Two a layer: flash forward, and the backward as one kernel.
+    assert _kernels(texts[0]) == 4
+    for name in (profile.FLASH_FWD, profile.FLASH_BWD):
+        assert _named(texts[0], name), name

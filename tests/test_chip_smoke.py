@@ -102,3 +102,27 @@ def test_moe_phase_steps_on_the_cpu(monkeypatch):
     chip_smoke.check_routing(routing(params, batch), 3 * 2 * 32)
     with pytest.raises(chip_smoke.PhaseFailed):
         chip_smoke.check_routing(routing(params, batch), 3 * 2 * 32 + 1)
+
+
+def test_kernels_phase_expects_what_the_plan_names(capsys):
+    """The smoke's attention shapes: the kernels it requires in the
+    compiled program are `hvd.profile.flash_plan`'s, so the backward as
+    one kernel at the L=1024 LM's shape and at a benchmark cell's, as two
+    at the long grouped fused-rotary shape; `print_flash_plan` prints
+    the one-entry backward plan."""
+    import jax.numpy as jnp
+
+    import chip_smoke
+
+    got = [chip_smoke.flash_kernels(*shape, jnp.bfloat16)
+           for shape in chip_smoke.SIZES["attn"]]
+    one, two = (["hvd_flash_fwd", "hvd_flash_bwd"],
+                ["hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"])
+    assert got == [one, two, one]
+    assert (2, 16, 16, 2048, 128, False) in chip_smoke.SIZES["attn"]
+    chip_smoke.print_flash_plan(2, 16, 16, 2048, 128, False, jnp.bfloat16)
+    lines = capsys.readouterr().out.splitlines()
+    assert len(lines) == 2
+    assert lines[1].startswith(
+        "  hvd_flash_bwd: resident, blocks 512 x 1024, grid (32, 2) = 64 "
+        "steps, VMEM 10.0 MiB of a limit of")

@@ -136,36 +136,62 @@ def test_flash_pallas_backward_interpret(causal, bq, bk):
             rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("causal,G,rotary,bqp,bk", [
-    (True, 4, None, 256, 512),      # group 1; peel: 1 block fwd/dQ, 2 dK/dV
-    (True, 4, None, 512, 256),      # peel: 2 blocks fwd/dQ, 1 dK/dV
-    (False, 4, None, 256, 512),     # not causal: one loop, no peel
-    (False, 2, 10000.0, 512, 256),
-    (True, 2, None, 256, 512),      # group 2
-    (True, 2, 10000.0, 512, 256),
-    (True, 1, None, 512, 256),      # group 4 (MQA)
-    (True, 1, 10000.0, 256, 512),
-    (True, 4, 10000.0, 128, 128),   # equal blocks: the longest loops
+def _split_budget(B, H, L, D, group, dtype, rotary, block_q=None,
+                  block_k=None):
+    """A `vmem_budget` one byte short of what the one-kernel backward
+    holds: `flash_plan` then keeps the backward's two kernels, resident
+    (each holds less), where everything fits it would choose the one."""
+    from horovod_tpu.ops.flash_attention import flash_plan
+    fused = flash_plan(B, H, L, D, group, dtype, True, rotary, block_q,
+                       block_k, 2 ** 40)
+    assert list(fused) == ["hvd_flash_bwd"], fused
+    return fused["hvd_flash_bwd"].resident_bytes - 1
+
+
+@pytest.mark.parametrize("causal,H,G,rotary,bqp,bk", [
+    (True, 4, 4, None, 256, 512),   # group 1; peel: 1 block fwd/dQ, 2 dK/dV
+    (True, 4, 4, None, 512, 256),   # peel: 2 blocks fwd/dQ, 1 dK/dV
+    (False, 4, 4, None, 256, 512),  # not causal: one loop, no peel
+    (False, 4, 2, 10000.0, 512, 256),
+    (True, 4, 2, None, 256, 512),   # group 2
+    (True, 4, 2, 10000.0, 512, 256),
+    (True, 4, 1, None, 512, 256),   # group 4 (MQA)
+    (True, 4, 1, 10000.0, 256, 512),
+    (True, 4, 4, 10000.0, 128, 128),  # equal blocks: the longest loops
+    (True, 3, 1, None, 256, 512),   # group 3, bqp < bk
+    (True, 3, 1, 10000.0, 512, 256),  # group 3, bqp > bk, fused rotary
+    (False, 6, 2, 10000.0, 256, 512),  # group 3 of two kv heads, not causal
 ])
-def test_flash_resident_path_interpret(causal, G, rotary, bqp, bk):
+def test_flash_resident_path_interpret(causal, H, G, rotary, bqp, bk):
     """The resident kernels (k/v, or q/dO/lse/delta, whole in VMEM and
     walked by a loop inside the kernel): out, dQ, dK and dV against dense
     attention, and against the gridded kernels on the same blocks, which
-    visit the same tiles in the same order with the same arithmetic."""
+    visit the same tiles in the same order with the same arithmetic. The
+    backward three ways: one kernel (`hvd_flash_bwd`, what everything
+    fitting chooses), the two resident kernels (a budget the one does not
+    fit), the two gridded ones."""
     from horovod_tpu.ops.flash_attention import (
         _pallas_backward, _pallas_forward_lse, flash_plan)
-    B, L, H, D = 1, 1024, 4, 32
+    B, L, D = 1, 1024, 32
     group = H // G
     q, k, v = _rand_gqa(B, L, H, G, D, seed=21)
     w = jnp.asarray(np.random.RandomState(22).randn(B, L, H, D),
                     jnp.float32)
     t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    budgets = dict(_PATHS, split=_split_budget(
+        B, H, L, D, group, q.dtype, rotary is not None, bqp * group, bk))
+    names = {"gridded": ["hvd_flash_dq", "hvd_flash_dkv"],
+             "split": ["hvd_flash_dq", "hvd_flash_dkv"],
+             "resident": ["hvd_flash_bwd"]}
     got = {}
-    for path, budget in _PATHS.items():
+    for path, budget in budgets.items():
         for backward in (False, True):
             plans = flash_plan(B, H, L, D, group, q.dtype, backward,
                                rotary is not None, bqp * group, bk, budget)
-            assert {p.path for p in plans.values()} == {path}
+            assert {p.path for p in plans.values()} == {
+                "gridded" if path == "gridded" else "resident"}
+            if backward:
+                assert list(plans) == names[path]
         out, lse = _pallas_forward_lse(
             t(q), t(k), t(v), D ** -0.5, causal, True, bqp * group, bk,
             rotary, budget)
@@ -176,20 +202,24 @@ def test_flash_resident_path_interpret(causal, G, rotary, bqp, bk):
     want = (dense(q, k, v),) + jax.grad(
         lambda q, k, v: jnp.sum(dense(q, k, v) * w),
         argnums=(0, 1, 2))(q, k, v)
-    for r, g, d, nm in zip(got["resident"], got["gridded"], want,
-                           ("out", "dq", "dk", "dv")):
+    for r, s, g, d, nm in zip(got["resident"], got["split"], got["gridded"],
+                              want, ("out", "dq", "dk", "dv")):
         tol = 2e-5 if nm == "out" else 2e-4
         np.testing.assert_allclose(np.asarray(t(r)), np.asarray(d),
                                    rtol=tol, atol=tol, err_msg=nm)
-        np.testing.assert_allclose(np.asarray(r), np.asarray(g),
-                                   rtol=1e-6, atol=1e-6, err_msg=nm)
+        for other in (s, g):
+            np.testing.assert_allclose(np.asarray(r), np.asarray(other),
+                                       rtol=1e-6, atol=1e-6, err_msg=nm)
 
 
-def test_flash_resident_equals_gridded_in_bf16():
-    """bf16 inputs, as the models feed them: the shape goes down the
-    gridded path under a budget it does not fit and down the resident
-    one under the default, and the two agree to bf16 rounding (2^-8
-    relative) in the output and all three gradients."""
+@pytest.mark.parametrize("other", ["gridded", "split"])
+def test_flash_resident_equals_gridded_in_bf16(other):
+    """bf16 inputs, as the models feed them: under the default budget the
+    shape goes down the resident path, its backward one kernel; under a
+    budget it does not fit, down the gridded one; under a budget the
+    one-kernel backward alone does not fit, the two resident backward
+    kernels. They agree to bf16 rounding (2^-8 relative) in the output
+    and all three gradients."""
     from horovod_tpu.ops.flash_attention import (
         _pallas_backward, _pallas_forward_lse, flash_plan)
     B, L, H, D = 1, 1024, 2, 64
@@ -197,12 +227,17 @@ def test_flash_resident_equals_gridded_in_bf16():
     q, k, v = (x.transpose(0, 2, 1, 3).astype(bf16)
                for x in _rand_qkv(B, L, H, D, seed=31))
     w = jnp.asarray(np.random.RandomState(32).randn(B, H, L, D), bf16)
+    budget = (2 ** 18 if other == "gridded"
+              else _split_budget(B, H, L, D, 1, bf16, False))
     got = []
-    for budget, path in ((None, "resident"), (2 ** 18, "gridded")):
+    for budget, path, kernels in (
+            (None, "resident", 1),
+            (budget, "gridded" if other == "gridded" else "resident", 2)):
         kw = {} if budget is None else {"vmem_budget": budget}
         for backward in (False, True):
             plans = flash_plan(B, H, L, D, 1, bf16, backward, **kw)
             assert {p.path for p in plans.values()} == {path}
+            assert len(plans) == (kernels if backward else 1)
         out, lse = _pallas_forward_lse(q, k, v, D ** -0.5, True, True,
                                        **kw)
         got.append((out,) + _pallas_backward(q, k, v, out, lse, w,
@@ -213,21 +248,20 @@ def test_flash_resident_equals_gridded_in_bf16():
 
 
 # B, H, L, D of the benchmark's cells: `lm1b4_1chip` and `lm1b4_dp4` (2
-# sequences of 2048 a chip) and `olmoe1b7_1chip` (one of 4096), 16 heads x
-# 128, bf16, group 1, no fused rotary. Expected: blocks, grid, path.
+# sequences of 2048 a chip) and `olmoe1b7_1chip` and `ouro2b6_1chip` (one
+# of 4096), 16 heads x 128, bf16, group 1, no fused rotary. Expected:
+# blocks, grid, path.
 @pytest.mark.parametrize("B,L,expected", [
     (2, 2048, {"hvd_flash_fwd": (512, 512, (32, 4)),
-               "hvd_flash_dq": (512, 512, (32, 4)),
-               "hvd_flash_dkv": (512, 1024, (32, 2))}),
+               "hvd_flash_bwd": (512, 1024, (32, 2))}),
     (1, 4096, {"hvd_flash_fwd": (512, 512, (16, 8)),
-               "hvd_flash_dq": (512, 512, (16, 8)),
-               "hvd_flash_dkv": (512, 1024, (16, 4))}),
+               "hvd_flash_bwd": (512, 1024, (16, 4))}),
 ])
 def test_flash_plan_benchmark_shapes_are_resident(B, L, expected):
     """`flash_plan` alone: the benchmark's shapes choose the resident
     path with one grid step per (batch*head, block) — the gridded grid
-    had a third axis — and the VMEM sum each reports is under the limit
-    it sets."""
+    had a third axis — the whole backward one kernel, and the VMEM sum
+    each reports is under the limit it sets."""
     from horovod_tpu.ops.flash_attention import (RESIDENT_VMEM_BUDGET,
                                                  flash_plan)
     H, D = 16, 128
@@ -241,11 +275,46 @@ def test_flash_plan_benchmark_shapes_are_resident(B, L, expected):
         assert plan.grid_steps == grid[0] * grid[1]
         assert 0 < plan.resident_bytes <= RESIDENT_VMEM_BUDGET
         assert plan.resident_bytes < plan.vmem_bytes < plan.vmem_limit_bytes
-    # k + v, bf16, two buffers; dK/dV: q + dO and the two 8-wide f32
-    # stripes padded to 128 lanes, two buffers.
+    # k + v, bf16, two buffers; the backward: q + dO + dQ's block and the
+    # two 8-wide f32 stripes padded to 128 lanes, two buffers, and dQ's f32
+    # accumulator, one.
     assert plans["hvd_flash_fwd"].resident_bytes == 2 * 2 * L * D * 2
-    assert plans["hvd_flash_dkv"].resident_bytes == 2 * (
-        2 * L * D * 2 + 2 * L * 128 * 4)
+    assert plans["hvd_flash_bwd"].resident_bytes == 2 * (
+        3 * L * D * 2 + 2 * L * 128 * 4) + L * D * 4
+
+
+# (B, L) a chip of the four LM cells, then lengths past what the
+# one-kernel backward holds in 24 MiB (D=128, bf16: 8 MiB at 2048, 16 at
+# 4096, 32 at 8192), then a budget nothing fits. Expected: {kernel: path}.
+@pytest.mark.parametrize("B,L,budget,expected", [
+    pytest.param(2, 2048, None, {"hvd_flash_bwd": "resident"},
+                 id="lm1b4_1chip"),
+    pytest.param(2, 2048, None, {"hvd_flash_bwd": "resident"},
+                 id="lm1b4_dp4"),
+    pytest.param(1, 4096, None, {"hvd_flash_bwd": "resident"},
+                 id="olmoe1b7_1chip"),
+    pytest.param(1, 4096, None, {"hvd_flash_bwd": "resident"},
+                 id="ouro2b6_1chip"),
+    pytest.param(1, 8192, None, {"hvd_flash_dq": "resident",
+                                 "hvd_flash_dkv": "resident"}, id="L8192"),
+    pytest.param(1, 16384, None, {"hvd_flash_dq": "resident",
+                                  "hvd_flash_dkv": "gridded"}, id="L16384"),
+    pytest.param(2, 2048, 0, {"hvd_flash_dq": "gridded",
+                              "hvd_flash_dkv": "gridded"}, id="budget0"),
+])
+def test_flash_plan_backward_kernels(B, L, budget, expected):
+    """The backward is ONE kernel exactly where its whole-sequence
+    operands with dQ's accumulator fit the budget — every benchmark cell —
+    and the two kernels of old, each resident or gridded as before,
+    where they do not."""
+    from horovod_tpu.ops.flash_attention import (RESIDENT_VMEM_BUDGET,
+                                                 flash_plan)
+    kw = {} if budget is None else {"vmem_budget": budget}
+    plans = flash_plan(B, 16, L, 128, 1, jnp.bfloat16, backward=True, **kw)
+    assert {n: p.path for n, p in plans.items()} == expected
+    whole = 2 * (3 * L * 128 * 2 + 2 * L * 128 * 4) + L * 128 * 4
+    assert (whole <= (RESIDENT_VMEM_BUDGET if budget is None else budget)
+            ) == ("hvd_flash_bwd" in plans)
 
 
 def test_flash_plan_past_the_budget_is_gridded():
@@ -276,6 +345,11 @@ def test_flash_plan_past_the_budget_is_gridded():
         "hvd_flash_fwd"].path == "gridded"
     odd = flash_plan(1, 2, 768, 128, 1, bf16, block_q=384, block_k=256)
     assert odd["hvd_flash_fwd"].path == "gridded"
+    # The same for the backward: no static peel, so not the one kernel.
+    odd = flash_plan(1, 2, 768, 128, 1, bf16, backward=True, block_q=384,
+                     block_k=256)
+    assert {n: p.path for n, p in odd.items()} == {
+        "hvd_flash_dq": "gridded", "hvd_flash_dkv": "gridded"}
 
 
 def test_flash_default_block_policy():

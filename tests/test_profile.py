@@ -261,29 +261,51 @@ def _kernel_names(jaxpr_text):
             if re.search(r"name=%s\b" % name, jaxpr_text)}
 
 
-def test_flash_kernels_carry_their_three_names():
+# `vmem_budget` -> the kernels of a forward and backward: everything
+# resident makes the backward one kernel, nothing resident the two of old.
+@pytest.mark.parametrize("budget,expected", [
+    (2 ** 40, {profile.FLASH_FWD, profile.FLASH_BWD}),
+    (0, {profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV}),
+])
+def test_flash_kernels_carry_their_names(budget, expected):
+    from horovod_tpu.ops.flash_attention import (_pallas_backward,
+                                                 _pallas_forward_lse)
     q = jnp.ones((1, 2, 128, 64), jnp.float32)
+
+    def grads(q, k, v):
+        out, lse = _pallas_forward_lse(q, k, v, 0.125, True, True,
+                                       vmem_budget=budget)
+        return _pallas_backward(q, k, v, out, lse, out, 0.125, True, True,
+                                vmem_budget=budget)
+
+    assert _kernel_names(str(jax.make_jaxpr(grads)(q, q, q))) == expected
 
     def loss(q, k, v):
         return _flash(q, k, v, 0.125, True, True, None).sum()
 
+    # What a model's call runs: the default budget, which this shape fits.
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
-    assert _kernel_names(text) == {profile.FLASH_FWD, profile.FLASH_DQ,
-                                   profile.FLASH_DKV}
+    assert _kernel_names(text) == {profile.FLASH_FWD, profile.FLASH_BWD}
 
 
+# A benchmark cell's shape, whose backward is one kernel, and L=8192,
+# where the budget keeps the two.
+@pytest.mark.parametrize("B,L,bwd_names", [
+    (2, 2048, {profile.FLASH_BWD}),
+    (1, 8192, {profile.FLASH_DQ, profile.FLASH_DKV}),
+])
 @pytest.mark.parametrize("backward", [False, True])
-def test_flash_plan_under_hvd_profile_is_the_kernels_own(backward):
+def test_flash_plan_under_hvd_profile_is_the_kernels_own(backward, B, L,
+                                                         bwd_names):
     """`hvd.profile.flash_plan` is the plan the kernels run (the ops
     module imports `profile`, so the import is made at the call), keyed by
-    the kernels' names."""
+    the kernels' names, on both sides of the backward's choice."""
     from horovod_tpu.ops import flash_attention as fa
 
     fa = sys.modules[fa.__module__]  # the module, not the function
-    got = profile.flash_plan(2, 16, 2048, 128, backward=backward)
-    assert got == fa.flash_plan(2, 16, 2048, 128, backward=backward)
-    assert set(got) == ({profile.FLASH_DQ, profile.FLASH_DKV} if backward
-                        else {profile.FLASH_FWD})
+    got = profile.flash_plan(B, 16, L, 128, backward=backward)
+    assert got == fa.flash_plan(B, 16, L, 128, backward=backward)
+    assert set(got) == (bwd_names if backward else {profile.FLASH_FWD})
     assert all(p.path == "resident" for p in got.values())
 
 
@@ -348,9 +370,17 @@ def test_ring_kernels_carry_their_three_names():
                                    profile.RING_ATTN_DKV}
 
 
+def _name_choices(value):
+    """The expressions a `name=` may evaluate to: both arms of `a if c else
+    b` (one call site that carries two of the program's names)."""
+    if isinstance(value, ast.IfExp):
+        return _name_choices(value.body) + _name_choices(value.orelse)
+    return [ast.unparse(value)]
+
+
 def _pallas_call_names(path):
-    """The `name=` keyword of every `pl.pallas_call(...)` in a source file,
-    None where one has no name."""
+    """The `name=` keyword of every `pl.pallas_call(...)` in a source file
+    (each arm of a conditional one), None where one has no name."""
     with open(path) as f:
         tree = ast.parse(f.read())
     out = []
@@ -359,7 +389,7 @@ def _pallas_call_names(path):
                 and isinstance(node.func, ast.Attribute)
                 and node.func.attr == "pallas_call"):
             named = [kw.value for kw in node.keywords if kw.arg == "name"]
-            out.append(ast.unparse(named[0]) if named else None)
+            out.extend(_name_choices(named[0]) if named else [None])
     return out
 
 
