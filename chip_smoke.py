@@ -65,6 +65,10 @@ SIZES = dict(
     # one kernel, as at L=1024).
     attn=[(8, 12, 12, 1024, 64, False), (2, 6, 2, 8192, 128, True),
           (2, 16, 16, 2048, 128, False)],
+    # (B, H, L, D, D2) of latent attention's scores of two products at the
+    # benchmark's `xing29b_1chip` (printed only: that cell's own comparison
+    # with its reference runs the kernels).
+    attn_two_products=(1, 32, 4096, 128, 64),
     # (M, C) of the largest and the smallest BatchNorm of ResNet-50 at
     # batch 256.
     bn=[(256 * 112 * 112, 64), (256 * 7 * 7, 2048)],
@@ -397,13 +401,15 @@ def model_flash_kernels(model, batch, length, dtype):
                          model["embed_dim"] // heads, False, dtype)
 
 
-def print_flash_plan(B, H, G, L, D, rotary, dtype):
-    """Which path each flash kernel of this shape takes (`hvd.profile`)."""
+def print_flash_plan(B, H, G, L, D, rotary, dtype, shared_dim=0):
+    """Which path each flash kernel of this shape takes (`hvd.profile`);
+    `shared_dim`: the width of a second score product on one shared key."""
     from horovod_tpu import profile
 
     for backward in (False, True):
-        for name, plan in profile.flash_plan(B, H, L, D, H // G, dtype,
-                                             backward, rotary).items():
+        for name, plan in profile.flash_plan(
+                B, H, L, D, H // G, dtype, backward, rotary,
+                shared_dim=shared_dim).items():
             print("  %s: %s, blocks %d x %d, grid %s = %d steps, VMEM %.1f "
                   "MiB%s" % (name, plan.path, plan.block_q, plan.block_k,
                              plan.grid, plan.grid_steps,
@@ -570,6 +576,10 @@ def phase_kernels(args):
         attention_vs_reference(
             attention_case(*shape, jnp.bfloat16, args.seed + i),
             TOL["attn_bf16"], flash_kernels(*shape, jnp.bfloat16))
+    B, H, L, D, D2 = SIZES["attn_two_products"]
+    print("  scores of two products, %d + %d wide on %d heads at L=%d:"
+          % (D, D2, H, L), flush=True)
+    print_flash_plan(B, H, H, L, D, False, jnp.bfloat16, shared_dim=D2)
 
     step, state = resnet_step(models.ResNet50PBN, mesh,
                               SIZES["resnet_batch"], args.seed)

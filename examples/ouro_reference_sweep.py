@@ -1,14 +1,17 @@
-"""The readings behind the limits of `benchmark/builders/ouro.py`: how far
-the bf16 system is from the float32 reference of the looped LM at the
-benchmark's own sizes, over several seeds, three ways:
+"""The readings behind the limits of `benchmark/builders/ouro.py` and of
+`benchmark/builders/xing.py` (`--workload xing29b_1chip`; any cell whose
+builder returns `readings`): how far the bf16 system is from the float32
+reference at the benchmark's own sizes, over several seeds, three ways:
 
 - `bf16`: the system as the cell runs it;
 - `fp8`: the same system with every matrix of its parameters rounded to
   fp8's precision (e4m3) and back, against the reference on the unrounded
   parameters: the nearest precision below the configuration's, which the
   limits must refuse;
-- `one_pass`: the reference of the stack run once, which they must refuse
-  as well (the comparison sees the loop).
+- `one_pass` (the looped LM): the reference of the stack run once, which
+  they must refuse as well (the comparison sees the loop). The Xing
+  builder's `readings` hold their own references of another model (no
+  shared expert, one Sinkhorn iteration, no module's loss) in every call.
 
 Chip only (the reference at L=4096 wants the device's memory), about two
 minutes for eight seeds.
@@ -18,6 +21,7 @@ Usage: python examples/ouro_reference_sweep.py [--seeds 8] [--first 1]
 """
 
 import argparse
+import inspect
 import json
 import os
 import sys
@@ -78,9 +82,10 @@ def main():
         seq = make_tokens(k_tok)[0]
         out = {"seed": seed,
                "bf16": built["readings"](params, params, seq),
-               "fp8": built["readings"](fp8(params), params, seq),
-               "one_pass": built["readings"](params, params, seq,
-                                             ref_passes=1)}
+               "fp8": built["readings"](fp8(params), params, seq)}
+        if "ref_passes" in inspect.signature(built["readings"]).parameters:
+            out["one_pass"] = built["readings"](params, params, seq,
+                                                ref_passes=1)
         print(json.dumps(out), flush=True)
 
 

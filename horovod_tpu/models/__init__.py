@@ -23,5 +23,6 @@ from .resnet import (ResNet, ResNet18, ResNet34, ResNet50, ResNet50GN,  # noqa: 
                      ResNet101NF, ResNet152)
 from .mnist import MnistCNN  # noqa: F401
 from .word2vec import SkipGram  # noqa: F401
-from .transformer import Transformer, TransformerConfig  # noqa: F401
+from .transformer import (Transformer, TransformerConfig, Yarn,  # noqa: F401
+                          hc_stats)
 from .imagenet_extras import VGG16, InceptionV3  # noqa: F401

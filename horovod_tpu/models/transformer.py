@@ -14,7 +14,8 @@ shards agree.
 
 import contextlib
 import dataclasses
-from typing import Any, Optional
+import math
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -23,6 +24,20 @@ from jax import lax
 
 from horovod_tpu import profile
 from horovod_tpu.parallel.ring import ring_attention, ulysses_attention
+
+
+class Yarn(NamedTuple):
+    """YaRN's static rescaling of the rotary frequencies (Peng et al.,
+    arXiv:2309.00071, as DeepSeek-V2's `rope_scaling` states it): the
+    frequencies that turn less than `beta_slow` times over
+    `original_len` positions are divided by `factor`, those that turn more
+    than `beta_fast` times are kept, the ones between are blended."""
+    factor: float
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    original_len: int = 4096
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,6 +121,61 @@ class TransformerConfig:
     # `return_hidden=True` then hands back every pass's hidden states
     # and gate logits for `ops.losses.expected_exit_loss`.
     exit_gate: bool = False
+    # The first `first_k_dense` blocks keep the dense feed-forward whatever
+    # `moe_every` says (DeepSeek's `first_k_dense_replace`): block i is
+    # routed where i >= first_k_dense and i % moe_every == moe_every - 1.
+    first_k_dense: int = 0
+    # Width of ONE routed expert where it differs from the dense
+    # feed-forward's `mlp_dim` (None: `mlp_dim` serves both).
+    moe_dim: Optional[int] = None
+    # "softmax" over the experts, or each expert's own "sigmoid" with a
+    # selection bias, the weights renormalised and multiplied by
+    # `moe_route_scale` (DeepSeek-V3; `parallel.expert.route`).
+    moe_scoring: str = "softmax"
+    moe_route_scale: float = 1.0
+    # Width of an always-on gated expert beside the routed ones.
+    moe_shared_dim: Optional[int] = None
+    # (first, count): the experts of `moe_experts` this device HOLDS. The
+    # router scores all of them; what the absent ones would add is left out
+    # (one rank's share of an expert-parallel layer, without the exchange).
+    moe_held: Optional[Tuple[int, int]] = None
+    # Latent attention (DeepSeek-V2's MLA) where `kv_lora_rank` is set: keys
+    # and values from one normed projection of that width, queries through
+    # one of `q_lora_rank` (required beside it); a head's q.k is
+    # `qk_nope_dim` wide without position and `qk_rope_dim` wide with
+    # rotary, the rotary key ONE a token for all heads; values are
+    # `v_head_dim` wide. `head_dim`, `num_kv_heads`, `qk_norm` and
+    # `rope_fused` do not apply. `rope_yarn` rescales the rotary slice's
+    # frequencies and the softmax scale.
+    kv_lora_rank: Optional[int] = None
+    q_lora_rank: Optional[int] = None
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    rope_yarn: Optional[Yarn] = None
+    # Hyper-connections (Zhu et al., arXiv:2409.19606; manifold-constrained,
+    # arXiv:2512.24880): the residual path is `hc_mult` streams; each branch
+    # of a block reads ONE input mixed from them and writes its output back
+    # into all, the streams themselves mixed by a doubly-stochastic map of
+    # `hc_sinkhorn_iters` Sinkhorn iterations (`HyperConnection`). 1: the
+    # plain residual. `hc_remat` recomputes the maps and the read in the
+    # backward pass instead of keeping their stream-wide intermediates.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    hc_remat: bool = False
+    # The first `block_remat` blocks keep only their input for the backward
+    # pass and run their forward again there (`jax.checkpoint` around the
+    # block): about 1 GiB a block at 4096 tokens, 3584 wide and four
+    # streams, for a third more of the block's time.
+    block_remat: int = 0
+    # Multi-token prediction modules behind the stack (DeepSeek-V3 §2.2),
+    # 0 or 1: the module takes the stack's last state and the NEXT token's
+    # embedding through one more (routed) block, and `return_hidden=True`
+    # hands back its normed state beside the stack's, for the loss on the
+    # token after next through the same head.
+    mtp_depth: int = 0
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
@@ -145,6 +215,53 @@ class TransformerConfig:
             raise ValueError("mlp_gated cannot be combined with tp_axis "
                              "(tp_param_specs places mlp_in and mlp_out "
                              "only)")
+        # Built for one device a replica, so refused by name elsewhere: the
+        # placement rules and the sequence-parallel kernels know none of
+        # these parameters, and a looped stack would sow a routed block's
+        # statistics once a pass.
+        new = [name for name, on in (
+            ("first_k_dense", self.first_k_dense > 0),
+            ("moe_dim", self.moe_dim is not None),
+            ("moe_scoring", self.moe_scoring != "softmax"),
+            ("moe_shared_dim", self.moe_shared_dim is not None),
+            ("moe_held", self.moe_held is not None),
+            ("kv_lora_rank", self.kv_lora_rank is not None),
+            ("hc_mult", self.hc_mult > 1),
+            ("block_remat", self.block_remat > 0),
+            ("mtp_depth", self.mtp_depth > 0)) if on]
+        for field in ("tp_axis", "sp_axis", "num_passes"):
+            on = (self.num_passes > 1 if field == "num_passes"
+                  else getattr(self, field) is not None)
+            if on and new:
+                raise ValueError("%s cannot be combined with %s (built for "
+                                 "one stack of blocks on one device a "
+                                 "replica)" % (field, ", ".join(new)))
+        if self.moe_held is not None and self.ep_axis is not None:
+            raise ValueError("moe_held cannot be combined with ep_axis (a "
+                             "device that is told which experts it holds "
+                             "runs no exchange)")
+        if self.moe_held is not None and self.moe_capacity_factor is not None:
+            raise ValueError("moe_held is the dropless path: give "
+                             "moe_capacity_factor=None")
+        if self.kv_lora_rank is not None and self.q_lora_rank is None:
+            raise ValueError("kv_lora_rank (latent attention) needs "
+                             "q_lora_rank too: the queries' low-rank "
+                             "projection is the one form built")
+        if self.kv_lora_rank is not None and self.attention not in (
+                "dense", "flash"):
+            raise ValueError("kv_lora_rank (latent attention) runs with "
+                             "attention='dense' or 'flash', not %r"
+                             % self.attention)
+        if self.rope_yarn is not None and self.kv_lora_rank is None:
+            raise ValueError("rope_yarn rescales latent attention's rotary "
+                             "slice: give kv_lora_rank")
+        if self.hc_mult < 1:
+            raise ValueError("hc_mult=%d: the residual path has at least "
+                             "one stream" % self.hc_mult)
+        if self.mtp_depth not in (0, 1):
+            raise ValueError("mtp_depth=%d: one multi-token prediction "
+                             "module is built, not a chain of them"
+                             % self.mtp_depth)
 
     def local(self, tp_size):
         """The per-shard config for `tp_size`-way tensor parallelism."""
@@ -184,6 +301,224 @@ def _rotary(x, positions, base=10000.0):
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos],
         axis=-1).astype(x.dtype)
+
+
+def yarn_inv_freq(dim, base, yarn):
+    """The `dim // 2` rotary frequencies of a `dim`-wide slice under
+    `yarn` (python floats: static). f_i = base^(-2i/dim) is divided by
+    `factor` where i is past `hi`, kept where it is before `lo`, blended
+    linearly between: (lo, hi) the indices whose frequencies turn
+    `beta_fast` and `beta_slow` times over `original_len` positions."""
+    def turns_at(turns):
+        return (dim * math.log(yarn.original_len / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    lo = max(math.floor(turns_at(yarn.beta_fast)), 0)
+    hi = min(math.ceil(turns_at(yarn.beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    out = []
+    for i in range(dim // 2):
+        f = base ** (-2.0 * i / dim)
+        keep = 1.0 - min(max((i - lo) / (hi - lo), 0.0), 1.0)
+        out.append(f / yarn.factor * (1.0 - keep) + f * keep)
+    return out
+
+
+def yarn_mscale(factor, mscale):
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _rotary_freq(x, positions, inv_freq, mscale=1.0):
+    """`_rotary` with given frequencies `inv_freq` [D/2]; cos and sin are
+    multiplied by `mscale`."""
+    half = x.shape[-1] // 2
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(
+        inv_freq, jnp.float32)
+    ang = ang[:, :, None, :]
+    cos, sin = jnp.cos(ang) * mscale, jnp.sin(ang) * mscale
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+        axis=-1).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
+
+        c_q = rms(W_qa h);  [q_nope | q_rope] = W_qb c_q   per head
+        [c_kv | k_rope] = W_kva h;  c_kv = rms(c_kv)
+        [k_nope | v] = W_kvb c_kv                           per head
+        s = scale * (q_nope.k_nope + rot(q_rope).rot(k_rope)),  causal
+
+    with k_rope ONE key a token for all heads. ``attention="flash"`` hands
+    the two products to the flash kernels as they are (`q_shared`,
+    `k_shared`: the shared key is never repeated over the heads);
+    ``"dense"`` is the plain einsum form. scale = (nope + rope)^-1/2, times
+    YaRN's mscale squared under `rope_yarn`."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.cfg
+        H, nope, rope, vd = (cfg.num_heads, cfg.qk_nope_dim,
+                             cfg.qk_rope_dim, cfg.v_head_dim)
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, dtype=cfg.dtype, param_dtype=jnp.float32, use_bias=False,
+            name=name)
+        heads = lambda d, name: nn.DenseGeneral(  # noqa: E731
+            (H, d), dtype=cfg.dtype, param_dtype=jnp.float32,
+            use_bias=False, name=name)
+        q = heads(nope + rope, "q_b")(_rms_norm(cfg, "q_norm")(
+            dense(cfg.q_lora_rank, "q_a")(x)))
+        kv = dense(cfg.kv_lora_rank + rope, "kv_a")(x)
+        k_rope = kv[..., None, cfg.kv_lora_rank:]        # [B, L, 1, rope]
+        kv = heads(nope + vd, "kv_b")(_rms_norm(cfg, "kv_norm")(
+            kv[..., :cfg.kv_lora_rank]))
+        q_nope, q_rope = q[..., :nope], q[..., nope:]
+        k_nope, v = kv[..., :nope], kv[..., nope:]
+        scale = (nope + rope) ** -0.5
+        yarn = cfg.rope_yarn
+        if yarn is None:
+            inv_freq = [cfg.rope_base ** (-2.0 * i / rope)
+                        for i in range(rope // 2)]
+            m = 1.0
+        else:
+            inv_freq = yarn_inv_freq(rope, cfg.rope_base, yarn)
+            m = (yarn_mscale(yarn.factor, yarn.mscale)
+                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+            scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+        q_rope = _rotary_freq(q_rope, positions, inv_freq, m)
+        k_rope = _rotary_freq(k_rope, positions, inv_freq, m)
+        if cfg.attention == "flash":
+            from horovod_tpu.ops import flash_attention
+            o = flash_attention(q_nope, k_nope, v, causal=True, scale=scale,
+                                q_shared=q_rope, k_shared=k_rope)
+        else:
+            s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope[:, :, 0],
+                              preferred_element_type=jnp.float32)) * scale
+            L = s.shape[-1]
+            mask = lax.broadcasted_iota(jnp.int32, (L, L), 0) >= \
+                lax.broadcasted_iota(jnp.int32, (L, L), 1)
+            s = jnp.where(mask[None, None], s, -jnp.inf)
+            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+            o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        return nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), dtype=cfg.dtype,
+                               param_dtype=jnp.float32, use_bias=False,
+                               name="out")(o)
+
+
+def sinkhorn(m, iters, eps):
+    """`iters` times rows then columns of a positive [..., n, n] map: each
+    row divided by its sum + eps, then each column by its: doubly stochastic
+    in the limit."""
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return m
+
+
+def hc_maps(X, phi, bias, alpha, iters, eps, clamp, norm_eps):
+    """The three maps of a hyper-connection from the streams X [n, ..., C]
+    (mHC, arXiv:2512.24880): (H_pre [..., n], H_post [..., n], H_res
+    [..., n, n]), all f32.
+
+        x~    = rms(vec(X)), no learned scale             [..., n*C]
+        pre   = a_pre  * (x~ phi[:, :n])    + b[:n]
+        post  = a_post * (x~ phi[:, n:2n])  + b[n:2n]
+        res   = a_res  * (x~ phi[:, 2n:])   + b[2n:]      as [n, n]
+        H_pre = sigmoid(pre);  H_post = 2 sigmoid(post)
+        H_res = sinkhorn(exp(clip(res, clamp)))
+
+    phi [n*C, 2n + n*n] with vec(X) stream-major, bias [2n + n*n], alpha
+    [3] = (a_pre, a_post, a_res). The projection runs in X's dtype with f32
+    accumulation; everything after it is f32."""
+    n, C = X.shape[0], X.shape[-1]
+    xf = X.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.sum(xf * xf, axis=(0, -1)) / (n * C) + norm_eps)
+    xn = (xf * inv[None, ..., None]).astype(X.dtype)
+    raw = jnp.einsum("n...c,nck->...k", xn,
+                     phi.reshape(n, C, -1).astype(X.dtype),
+                     preferred_element_type=jnp.float32)
+    bias = bias.astype(jnp.float32)
+    a = alpha.astype(jnp.float32)
+    pre = a[0] * raw[..., :n] + bias[:n]
+    post = a[1] * raw[..., n:2 * n] + bias[n:2 * n]
+    res = (a[2] * raw[..., 2 * n:] + bias[2 * n:]).reshape(
+        raw.shape[:-1] + (n, n))
+    return (jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post),
+            sinkhorn(jnp.exp(jnp.clip(res, clamp[0], clamp[1])), iters, eps))
+
+
+def hc_read(h_pre, X):
+    """The branch's input H_pre X [..., C] from the streams X [n, ..., C]:
+    a weighted sum over the streams, in f32, as X's dtype."""
+    return sum(h_pre[..., i, None] * X[i].astype(jnp.float32)
+               for i in range(X.shape[0])).astype(X.dtype)
+
+
+def hc_write(h_res, h_post, X, y):
+    """The next streams H_res X + H_post^T y, [n, ..., C] in X's dtype:
+    stream m is sum_i H_res[m, i] X_i + H_post[m] y."""
+    n = X.shape[0]
+    xf = [X[i].astype(jnp.float32) for i in range(n)]
+    yf = y.astype(jnp.float32)
+    return jnp.stack([
+        (sum(h_res[..., m, i, None] * xf[i] for i in range(n))
+         + h_post[..., m, None] * yf).astype(X.dtype) for m in range(n)])
+
+
+def hc_stats(intermediates):
+    """The largest deviation of any H_res's row or column sums from 1 over
+    a batch (f32 scalar), from the ``hc_res_sums`` the hyper-connections
+    sowed under ``intermediates``: how doubly stochastic the Sinkhorn
+    iterations left the maps."""
+    from horovod_tpu.parallel.expert import _sown
+    found = _sown(intermediates, "hc_res_sums")
+    if not found:
+        raise ValueError("no HyperConnection sowed into these intermediates")
+    return jnp.max(jnp.stack(found))
+
+
+class HyperConnection(nn.Module):
+    """The maps of ONE branch's hyper-connection and the branch's input:
+    X [n, B, L, C] -> (h [B, L, C], H_post, H_res) (`hc_maps`, `hc_read`).
+    Parameters `phi`, `bias`, `alpha` as `hc_maps` takes them. They start
+    where one stream is read, every stream is written and the streams pass
+    through unmixed: alpha 0.01, phi small, the bias of `res` a large
+    diagonal, of `pre` large on the first stream alone, of `post` zero
+    (H_post = 1)."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, X):
+        cfg = self.cfg
+        n, C = cfg.hc_mult, X.shape[-1]
+
+        def bias_init(key, shape, dtype):
+            del key
+            pre = jnp.full((n,), -8.0).at[0].set(8.0)
+            res = jnp.where(jnp.eye(n, dtype=bool), 8.0, -8.0).reshape(-1)
+            return jnp.concatenate([pre, jnp.zeros((n,)),
+                                    res]).astype(dtype).reshape(shape)
+
+        k = 2 * n + n * n
+        phi = self.param("phi", nn.initializers.normal(0.02), (n * C, k),
+                         jnp.float32)
+        bias = self.param("bias", bias_init, (k,), jnp.float32)
+        alpha = self.param("alpha", nn.initializers.constant(0.01), (3,),
+                           jnp.float32)
+        with jax.named_scope(profile.HC_MAP):
+            h_pre, h_post, h_res = hc_maps(
+                X, phi, bias, alpha, cfg.hc_sinkhorn_iters, cfg.hc_eps,
+                cfg.hc_res_clamp, cfg.norm_eps)
+            self.sow("intermediates", "hc_res_sums", jnp.maximum(
+                jnp.max(jnp.abs(jnp.sum(h_res, axis=-1) - 1.0)),
+                jnp.max(jnp.abs(jnp.sum(h_res, axis=-2) - 1.0))))
+        with jax.named_scope(profile.HC_MIX):
+            return hc_read(h_pre, X), h_post, h_res
 
 
 class Attention(nn.Module):
@@ -262,37 +597,83 @@ class Block(nn.Module):
         # `out`: the sandwich norm on a branch's output, or nothing.
         out = (lambda name, h: _rms_norm(cfg, name)(h)) \
             if cfg.sandwich_norm else (lambda name, h: h)
-        x = x + out("norm1_out", Attention(cfg, name="attn")(
+        attention = LatentAttention if cfg.kv_lora_rank is not None \
+            else Attention
+        if cfg.hc_mult > 1:
+            return _hyper_connected(cfg, self.moe, x, positions, attention,
+                                    out)
+        x = x + out("norm1_out", attention(cfg, name="attn")(
             _rms_norm(cfg, "norm1")(x), positions))
         h = _rms_norm(cfg, "norm2")(x)
         # `mlp` beside flax's `attn`: the profiler's scope for this half
         # of the block (hvd.profile), dense or routed; no module and no
         # parameter name.
-        if self.moe:
-            from horovod_tpu.parallel.expert import MoeMlp
-            with jax.named_scope("mlp"):
-                h = MoeMlp(num_experts=cfg.moe_experts, mlp_dim=cfg.mlp_dim,
-                           capacity_factor=cfg.moe_capacity_factor,
-                           ep_axis=cfg.ep_axis, ep_size=cfg.ep_size,
-                           top_k=cfg.moe_top_k, gated=cfg.moe_gated,
-                           renormalize=cfg.moe_renormalize,
-                           dtype=cfg.dtype, name="moe_mlp")(h)
-            return x + out("norm2_out", h)
-        dense = lambda n, name: nn.Dense(  # noqa: E731
-            n, dtype=cfg.dtype, param_dtype=jnp.float32, use_bias=False,
-            name=name)
+        return x + out("norm2_out", _feed_forward(cfg, self.moe, h))
+
+
+# Plain functions, not methods: flax names a scope after every method it
+# wraps, and the blocks' scope paths stay what they were.
+
+def _feed_forward(cfg, moe, h):
+    """A block's feed-forward branch on the normed `h`, dense or routed,
+    under the profiler's `mlp`."""
+    if moe:
+        from horovod_tpu.parallel.expert import MoeMlp
+        new = {}  # only what a configuration sets: the others' modules
+        if cfg.moe_scoring != "softmax":  # stay as they were
+            new.update(scoring=cfg.moe_scoring,
+                       route_scale=cfg.moe_route_scale)
+        if cfg.moe_held is not None:
+            new["held"] = cfg.moe_held
+        if cfg.moe_shared_dim is not None:
+            new["shared_dim"] = cfg.moe_shared_dim
         with jax.named_scope("mlp"):
-            if cfg.mlp_gated:
-                h = nn.silu(dense(cfg.mlp_dim, "mlp_gate")(h)) \
-                    * dense(cfg.mlp_dim, "mlp_up")(h)
-            else:
-                h = nn.silu(dense(cfg.mlp_dim, "mlp_in")(h))
-            h = dense(cfg.embed_dim, "mlp_out")(h)
-            if cfg.tp_axis is not None:
-                # Column-parallel mlp_in -> row-parallel mlp_out: the out
-                # product over the local hidden slice is a partial sum.
-                h = lax.psum(h, cfg.tp_axis)
-        return x + out("norm2_out", h)
+            return MoeMlp(num_experts=cfg.moe_experts,
+                          mlp_dim=cfg.moe_dim or cfg.mlp_dim,
+                          capacity_factor=cfg.moe_capacity_factor,
+                          ep_axis=cfg.ep_axis, ep_size=cfg.ep_size,
+                          top_k=cfg.moe_top_k, gated=cfg.moe_gated,
+                          renormalize=cfg.moe_renormalize,
+                          dtype=cfg.dtype, name="moe_mlp", **new)(h)
+    dense = lambda n, name: nn.Dense(  # noqa: E731
+        n, dtype=cfg.dtype, param_dtype=jnp.float32, use_bias=False,
+        name=name)
+    with jax.named_scope("mlp"):
+        if cfg.mlp_gated:
+            h = nn.silu(dense(cfg.mlp_dim, "mlp_gate")(h)) \
+                * dense(cfg.mlp_dim, "mlp_up")(h)
+        else:
+            h = nn.silu(dense(cfg.mlp_dim, "mlp_in")(h))
+        h = dense(cfg.embed_dim, "mlp_out")(h)
+        if cfg.tp_axis is not None:
+            # Column-parallel mlp_in -> row-parallel mlp_out: the out
+            # product over the local hidden slice is a partial sum.
+            h = lax.psum(h, cfg.tp_axis)
+    return h
+
+
+def _hyper_connected(cfg, moe, X, positions, attention, out):
+    """The block on streams X [n, B, L, C]: each branch inside its own
+    hyper-connection, X' = H_res X + H_post^T F(H_pre X), the branch F
+    with its pre-norm as in the plain block. The connection's work lies
+    under `hvd_hc` (`hvd_hc_map`, `hvd_hc_mix` inside), the branches
+    under `attn` and `mlp` as ever."""
+    connection = nn.remat(HyperConnection) if cfg.hc_remat \
+        else HyperConnection
+
+    def connected(X, name, branch):
+        with jax.named_scope(profile.HC):
+            h, h_post, h_res = connection(cfg, name=name)(X)
+        y = branch(h)
+        with jax.named_scope(profile.HC), \
+                jax.named_scope(profile.HC_MIX):
+            return hc_write(h_res, h_post, X, y)
+
+    X = connected(X, "hc_attn", lambda h: out(
+        "norm1_out", attention(cfg, name="attn")(
+            _rms_norm(cfg, "norm1")(h), positions)))
+    return connected(X, "hc_mlp", lambda h: out(
+        "norm2_out", _feed_forward(cfg, moe, _rms_norm(cfg, "norm2")(h))))
 
 
 class Transformer(nn.Module):
@@ -312,7 +693,17 @@ class Transformer(nn.Module):
     weights and the logits are the last pass's. With ``cfg.exit_gate``,
     ``return_hidden=True`` returns ``(hidden [T, B, L, D], gate logits
     [T, B, L] f32)`` of all T passes: what
-    `horovod_tpu.ops.losses.expected_exit_loss` takes."""
+    `horovod_tpu.ops.losses.expected_exit_loss` takes.
+
+    With ``cfg.hc_mult`` = n > 1 the embedding fills n residual streams, the
+    blocks run on [n, B, L, C] and the streams' sum goes to `norm_f`. With
+    ``cfg.mtp_depth`` = 1, ``return_hidden=True`` returns ``(hidden,
+    hidden_mtp)``, both [B, L, D]: the stack's normed state, for the next
+    token, and the prediction module's, for the token after it (the module:
+    `mtp_proj` on [`mtp_norm_h`(the stack's state before `norm_f`) |
+    `mtp_norm_e`(the NEXT token's embedding, the sequence closed on
+    itself)], one more block `mtp_block`, `mtp_norm_f`; the same embedding
+    and head)."""
     cfg: TransformerConfig
 
     @nn.compact
@@ -331,9 +722,13 @@ class Transformer(nn.Module):
         blocks = []
         for i in range(cfg.num_layers):
             moe = (cfg.moe_experts is not None and
+                   i >= cfg.first_k_dense and
                    i % cfg.moe_every == cfg.moe_every - 1)
-            blocks.append(Block(cfg, moe=moe, name="block_%d" % i))
+            block = nn.remat(Block) if i < cfg.block_remat else Block
+            blocks.append(block(cfg, moe=moe, name="block_%d" % i))
         norm_f = _rms_norm(cfg, "norm_f")
+        if cfg.hc_mult > 1 or cfg.mtp_depth:
+            return _streams(cfg, positions, return_hidden, x, blocks, norm_f)
 
         def loop_scope(name):
             # The loop's names exist only where there is a loop.
@@ -373,3 +768,50 @@ class Transformer(nn.Module):
                               param_dtype=jnp.float32, use_bias=False,
                               name="lm_head")(x)
             return logits.astype(jnp.float32)
+
+
+def _streams(cfg, positions, return_hidden, x, blocks, norm_f):
+    """The forward pass where the residual path is several streams
+    (`hc_mult`) or a prediction module follows the stack (`mtp_depth`):
+    one pass (`num_passes` > 1 is refused beside them)."""
+    n = cfg.hc_mult
+
+    def fill(h):   # the streams start as copies
+        return jnp.broadcast_to(h, (n,) + h.shape) if n > 1 else h
+
+    def close(X):  # and end as their sum
+        return jnp.sum(X, axis=0, dtype=jnp.float32).astype(X.dtype) \
+            if n > 1 else X
+
+    embedded = x
+    X = fill(x)
+    for block in blocks:
+        with jax.named_scope(profile.BLOCK):
+            X = block(X, positions)
+    with jax.named_scope(profile.HEAD):
+        last = close(X)
+        x = norm_f(last)
+    hidden_mtp = None
+    if cfg.mtp_depth:
+        # Formed in either mode, so that `init` makes the module.
+        with jax.named_scope(profile.MTP):
+            # The NEXT token's embedding is the embedded sequence turned by
+            # one position: no second lookup.
+            e_next = jnp.roll(embedded, -1, axis=1)
+            h = nn.Dense(cfg.embed_dim, dtype=cfg.dtype,
+                         param_dtype=jnp.float32, use_bias=False,
+                         name="mtp_proj")(jnp.concatenate(
+                             [_rms_norm(cfg, "mtp_norm_h")(last),
+                              _rms_norm(cfg, "mtp_norm_e")(e_next)],
+                             axis=-1))
+            with jax.named_scope(profile.BLOCK):
+                X = Block(cfg, moe=cfg.moe_experts is not None,
+                          name="mtp_block")(fill(h), positions)
+            hidden_mtp = _rms_norm(cfg, "mtp_norm_f")(close(X))
+    if return_hidden:
+        return (x, hidden_mtp) if cfg.mtp_depth else x
+    with jax.named_scope(profile.HEAD):
+        logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
+                          param_dtype=jnp.float32, use_bias=False,
+                          name="lm_head")(x)
+        return logits.astype(jnp.float32)

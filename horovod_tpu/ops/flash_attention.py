@@ -69,6 +69,16 @@ see `_rot(neg=True)`) at finalize. The ring-step kernels instead
 accumulate gradients in rotated space across ring steps; the caller
 counter-rotates once after the last step (`apply_rotary(neg=True)`).
 
+Scores of two products (`q_shared`, `k_shared`: latent attention's
+rotary slice): s = scale * (q.k^T + q2.k2^T) with k2 ONE key a position for
+every head, never broadcast in memory: its block's index is the batch's, so
+consecutive heads of a batch reuse the fetched block. The resident kernels
+take the second pair beside the first (the forward and the one-kernel
+backward at D=128, D2=64, L=4096: `flash_plan(..., shared_dim=64)`); the
+shared key's gradient leaves the kernel a head at a time and is summed over
+the heads outside. The gridded kernels have no such form: a call whose plan
+is not resident takes the blockwise jnp path.
+
 Backward: custom VJP over saved per-row log-sum-exp (FlashAttention-2
 style). On non-TPU backends the same kernels run in Pallas interpret
 mode (tests) or fall back to the blockwise JAX implementation.
@@ -165,6 +175,15 @@ def _scores(q, k, scale):
     return jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
+
+
+def _scores2(q, k, q2, k2, scale):
+    """s = (q.k^T + q2.k2^T)*scale: the scores of two products."""
+    dims = (((1,), (1,)), ((), ()))
+    return (jax.lax.dot_general(q, k, dims,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(q2, k2, dims,
+                                  preferred_element_type=jnp.float32)) * scale
 
 
 def _causal_mask(s, q_off, kv_off, fill, group=1):
@@ -448,6 +467,10 @@ def _vmem(rows, cols, itemsize):
 # the q side (lse, delta).
 _OPERANDS = {profile.FLASH_FWD: (2, 2, 1), profile.FLASH_DQ: (3, 2, 2),
              profile.FLASH_DKV: (2, 4, 2), profile.FLASH_BWD: (3, 4, 2)}
+# Under a second score product, the arrays of ITS width on the q side (q2,
+# dq2) and on the k side (k2, dk2).
+_SHARED_OPERANDS = {profile.FLASH_FWD: (1, 1), profile.FLASH_DQ: (2, 1),
+                    profile.FLASH_DKV: (1, 2), profile.FLASH_BWD: (2, 2)}
 
 
 # The kernels that hold a k block and walk the q blocks (the others hold a
@@ -492,20 +515,23 @@ def _resident_blocks(D, L, group, kernel):
 
 
 def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
-                 block_k, vmem_budget):
+                 block_k, vmem_budget, D2=0):
     backward = kernel != profile.FLASH_FWD
     dkv = kernel in _K_HELD
     n_q, n_k, n_stripes = _OPERANDS[kernel]
-    # The whole backward in one kernel: dQ's f32 accumulator, one buffer.
+    n_q2, n_k2 = _SHARED_OPERANDS[kernel]
+    # The whole backward in one kernel: dQ's f32 accumulator, one buffer
+    # (and the second product's dQ2's beside it).
     fused = kernel == profile.FLASH_BWD
-    dq_acc = _vmem(rows, D, 4) if fused else 0
+    dq_acc = _vmem(rows, D, 4) + _vmem(rows, D2, 4) if fused else 0
     tables = 2 * 4 if rotary else 0  # (C, S) f32, per side
 
     def q_side(n):  # one pipeline buffer of n rows of every q-side operand
-        return _vmem(n, D, n_q * isz + tables) + n_stripes * _vmem(n, 8, 4)
+        return (_vmem(n, D, n_q * isz + tables) + n_stripes * _vmem(n, 8, 4)
+                + _vmem(n, D2, n_q2 * isz))
 
     def k_side(n):
-        return _vmem(n, D, n_k * isz + tables)
+        return _vmem(n, D, n_k * isz + tables) + _vmem(n, D2, n_k2 * isz)
 
     def blocks(preferred):
         bq = block_q or _pick_rows_block(L, preferred[0], group)
@@ -529,7 +555,8 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
             # XLA keeps arrays of its own in VMEM between operations, and
             # with a fifth less asked here it placed them worse by 0.14 ms
             # of copies a step in `lm1b4_1chip` (PERF.md, PR 28).
-            values = 6 * bq * bk * 4 + 4 * _vmem(max(bq, bk), D, 4)
+            values = (6 * bq * bk * 4 + 4 * _vmem(max(bq, bk), D, 4)
+                      + 2 * _vmem(max(bq, bk), D2, 4))
             limit = -(-(buffers + values) * 5 // 4 // 2 ** 20) * 2 ** 20
             grid = (BG, L // bk if dkv else rows // bq)
             return FlashKernelPlan(
@@ -551,7 +578,7 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
 
 def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
                rotary=False, block_q=None, block_k=None,
-               vmem_budget=RESIDENT_VMEM_BUDGET):
+               vmem_budget=RESIDENT_VMEM_BUDGET, shared_dim=0):
     """How `flash_attention` runs q [B, H, L, D] against H // group kv
     heads: {kernel name: FlashKernelPlan} for the forward kernel
     (`hvd_flash_fwd`) or, with ``backward``, the backward: ONE kernel
@@ -567,6 +594,18 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     its blocks tiles the other; gridded otherwise. At D=128 in bf16 with
     one head a kv head the one-kernel backward holds 8 MiB at L=2048, 16
     at 4096 and 32 at 8192, where the budget keeps the two.
+
+    ``shared_dim`` = D2 > 0: the scores are of two products, q [.., D] on k
+    and q2 [.., D2] on ONE key k2 a position for all H heads
+    (`flash_attention`'s ``q_shared``, ``k_shared``); v is D wide. The sums
+    above then hold q2, k2 and their gradients too (D2 pads to 128 lanes):
+    at D=128, D2=64 in bf16 with one head a kv head the one-kernel backward
+    holds 22 MiB at L=4096 (16 without the second product) and stays one
+    kernel; at L=8192 dK/dV's 28 MiB is past the budget. Only the resident
+    kernels have this form: where one of a call's kernels would be gridded
+    the result is ``{}``, no kernel at all, and the call is the blockwise
+    jnp form.
+
     `_pallas_forward_lse` and `_pallas_backward` run what this returns,
     so it is also the counter that says which path a program took
     (docs/TRACING.md; `hvd.profile.flash_plan`)."""
@@ -575,15 +614,20 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
 
     def plan(kernel):
         return _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary,
-                            block_q, block_k, vmem_budget)
+                            block_q, block_k, vmem_budget, shared_dim)
+
+    def resident_or_none(plans):
+        if shared_dim and any(p.path != "resident" for p in plans.values()):
+            return {}
+        return plans
 
     if not backward:
-        return {profile.FLASH_FWD: plan(profile.FLASH_FWD)}
+        return resident_or_none({profile.FLASH_FWD: plan(profile.FLASH_FWD)})
     fused = plan(profile.FLASH_BWD)
     if fused is not None:
         return {profile.FLASH_BWD: fused}
-    return {name: plan(name)
-            for name in (profile.FLASH_DQ, profile.FLASH_DKV)}
+    return resident_or_none({name: plan(name) for name in (
+        profile.FLASH_DQ, profile.FLASH_DKV)})
 
 
 def _compiler_params(plan, carries=False):
@@ -653,11 +697,17 @@ def _walk_q_blocks(visit, carry, kj, bqp, bk, num_qb, causal):
                          lambda i, c: visit(i, c, False), carry)
 
 
-def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary):
+def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
+                         shared=False):
     # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [L, D], fetched once per b (the
     # block index does not change across q blocks); lse_ref [BQ, 8]. The
     # online-softmax state (acc, m, l) is carried by the loop. Under
     # fused rotary kc/ks are whole [L, D] tables, q is rotated once.
+    # `shared`: q2_ref [BQ, D2] and k2_ref [L, D2] follow v (`_scores2`).
+    if shared:
+        q2_ref, k2_ref = refs[3:5]
+        refs = refs[:3] + refs[5:]
+        q2 = q2_ref[...]
     if rotary:
         (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, o_ref,
          lse_ref) = refs
@@ -675,7 +725,8 @@ def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary):
         k = k_ref[at, :]
         if rotary:
             k = _rot(k, kc_ref[at, :], ks_ref[at, :])
-        s = _scores(q, k, scale)
+        s = _scores2(q, k, q2, k2_ref[at, :], scale) if shared \
+            else _scores(q, k, scale)
         if masked:
             s = _causal_mask(s, qi * bqp, j * bk, -jnp.inf, group)
         # The first visited block covers every row (ascending order), so
@@ -699,9 +750,17 @@ def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary):
     lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape)
 
 
-def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary):
+def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
+                            shared=False):
     """dQ with k and v whole in VMEM: `_bwd_dq_kernel`'s arithmetic,
-    the dq accumulator carried by the loop."""
+    the dq accumulator carried by the loop. `shared`: q2_ref [BQ, D2] and
+    k2_ref [L, D2] follow v and dq2_ref [BQ, D2] is the last result; the
+    loop carries (dq, dq2)."""
+    if shared:
+        *refs, dq2_ref = refs
+        q2_ref, k2_ref = refs[3:5]
+        refs = refs[:3] + refs[5:]
+        q2 = q2_ref[...]
     if rotary:
         (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
          lse_ref, delta_ref, dq_ref) = refs
@@ -716,11 +775,14 @@ def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary):
     delta = delta_ref[:, :1]
 
     def visit(j, dq, masked):
+        if shared:
+            dq, dq2 = dq
         at = pl.ds(pl.multiple_of(j * bk, bk), bk)
         k = k_ref[at, :]
         if rotary:
             k = _rot(k, kc_ref[at, :], ks_ref[at, :])
-        s = _scores(q, k, scale)
+        s = _scores2(q, k, q2, k2_ref[at, :], scale) if shared \
+            else _scores(q, k, scale)
         if masked:
             s = _causal_mask(s, qi * bqp, j * bk, -jnp.inf, group)
         p = jnp.exp(s - lse)  # masked entries: exp(-inf) = 0
@@ -728,19 +790,29 @@ def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary):
             do, v_ref[at, :], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot_general(
+        dq = dq + jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if shared:
+            return dq, dq2 + jax.lax.dot_general(
+                ds.astype(k.dtype), k2_ref[at, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        return dq
 
-    dq = _walk_k_blocks(visit, jnp.zeros(q.shape, jnp.float32), qi, bqp,
-                        bk, k_ref.shape[0] // bk, causal)
+    zeros = jnp.zeros(q.shape, jnp.float32)
+    dq = _walk_k_blocks(
+        visit, (zeros, jnp.zeros(q2.shape, jnp.float32)) if shared
+        else zeros, qi, bqp, bk, k_ref.shape[0] // bk, causal)
+    if shared:
+        dq, dq2 = dq
+        dq2_ref[...] = dq2.astype(dq2_ref.dtype)
     if rotary:
         dq = _rot(dq, qc_ref[...], qs_ref[...], neg=True)
     dq_ref[...] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
-                             with_dq):
+                             with_dq, shared=False):
     """dK/dV with q, dO, lse and delta whole in VMEM: `_bwd_dkv_kernel`'s
     arithmetic, the dk and dv accumulators carried by the loop; k is
     rotated once, q per visit. ``with_dq`` (`hvd_flash_bwd`): the whole
@@ -750,9 +822,21 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
     across the grid's k-block axis, zeroed at the first k block of a
     (batch, kv head) and written to the dQ output, counter-rotated and
     cast once, at the last. dQ's sum over k blocks runs in ascending k
-    order in f32, as `_bwd_dq_resident_kernel`'s loop runs it."""
-    if with_dq:  # the third result and the one scratch
+    order in f32, as `_bwd_dq_resident_kernel`'s loop runs it.
+
+    ``shared``: the scores are of two products (`_scores2`). q2_ref [rows,
+    D2] whole and k2_ref [BK, D2] follow v; dk2_ref [BK, D2], THIS head's
+    part of the shared key's gradient, follows dv, and with ``with_dq``
+    dq2_ref [rows, D2] follows dq and its f32 accumulator dq's."""
+    if with_dq and shared:
+        *refs, dq_ref, dq2_ref, dq_acc, dq2_acc = refs
+    elif with_dq:  # the third result and the one scratch
         *refs, dq_ref, dq_acc = refs
+    if shared:
+        *refs, dk2_ref = refs
+        q2_ref, k2_ref = refs[3:5]
+        refs = refs[:3] + refs[5:]
+        k2 = k2_ref[...]
     if rotary:
         (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
          lse_ref, delta_ref, dk_ref, dv_ref) = refs
@@ -770,15 +854,21 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
         @pl.when(kj == 0)
         def _init():
             dq_acc[...] = jnp.zeros_like(dq_acc)
+            if shared:
+                dq2_acc[...] = jnp.zeros_like(dq2_acc)
 
     def visit(i, carry, masked):
-        dk, dv = carry
+        dk, dv, *dk2 = carry
         at = pl.ds(pl.multiple_of(i * bq, bq), bq)
         q = q_ref[at, :]
         if rotary:
             q = _rot(q, qc_ref[at, :], qs_ref[at, :])
         do = do_ref[at, :]
-        s = _scores(q, k, scale)
+        if shared:
+            q2 = q2_ref[at, :]
+            s = _scores2(q, k, q2, k2, scale)
+        else:
+            s = _scores(q, k, scale)
         if masked:
             s = _causal_mask(s, i * bqp, kj * bk, -jnp.inf, group)
         p = jnp.exp(s - lse_ref[at, :1])  # masked entries: exp(-inf) = 0
@@ -796,11 +886,23 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
             dq_acc[at, :] += jax.lax.dot_general(
                 ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+        if shared:
+            if with_dq:
+                dq2_acc[at, :] += jax.lax.dot_general(
+                    ds, k2, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            return dk, dv, dk2[0] + jax.lax.dot_general(
+                ds, q2, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         return dk, dv
 
     zeros = jnp.zeros(k.shape, jnp.float32)
-    dk, dv = _walk_q_blocks(visit, (zeros, zeros), kj, bqp, bk,
-                            q_ref.shape[0] // bq, causal)
+    dk, dv, *dk2 = _walk_q_blocks(
+        visit, (zeros, zeros) + (
+            (jnp.zeros(k2.shape, jnp.float32),) if shared else ()),
+        kj, bqp, bk, q_ref.shape[0] // bq, causal)
+    if shared:
+        dk2_ref[...] = dk2[0].astype(dk2_ref.dtype)
     if rotary:
         dk = _rot(dk, kc_ref[...], ks_ref[...], neg=True)
     dk_ref[...] = dk.astype(dk_ref.dtype)
@@ -815,14 +917,30 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
                 if rotary:
                     dq = _rot(dq, qc_ref[at, :], qs_ref[at, :], neg=True)
                 dq_ref[at, :] = dq.astype(dq_ref.dtype)
+                if shared:
+                    dq2_ref[at, :] = dq2_acc[at, :].astype(dq2_ref.dtype)
                 return carry
 
             lax.fori_loop(0, q_ref.shape[0] // bq, store, 0)
 
 
+def _shared_operands(shared, B, G, group, plans):
+    """(q2 in the grouped-rows layout, k2 [B, L, D2], the index of k2's
+    batch from a kernel's first grid index) of a call with a second score
+    product; refuses a plan that is not resident."""
+    q2, k2 = shared
+    if not plans or any(p.path != "resident" for p in plans.values()):
+        raise NotImplementedError(
+            "scores of two products (q_shared, k_shared) exist in the "
+            "resident flash kernels only; `flash_plan(..., shared_dim=%d)` "
+            "says this call's are not" % q2.shape[-1])
+    return (_to_rows(q2, group), k2.reshape(B, k2.shape[2], k2.shape[3]),
+            lambda b: b // G)
+
+
 def _pallas_forward_lse(q, k, v, scale, causal, interpret,
                         block_q=None, block_k=None, rotary_base=None,
-                        vmem_budget=RESIDENT_VMEM_BUDGET):
+                        vmem_budget=RESIDENT_VMEM_BUDGET, shared=None):
     """q [B, H, L, D], k/v [B, G, L, D] with G | H. Returns
     (out [B,H,L,D], lse [B*G, L*group, 8] f32) — lse is the per-row
     log-sum-exp the backward kernels need, in the grouped-rows layout
@@ -830,7 +948,8 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     Mosaic-tileable and the DMA a contiguous stripe; 1-wide measured
     slower, 128-wide wastes 16x the memory). `flash_plan` chooses the
     path and the blocks; ``vmem_budget`` is its argument (tests and the
-    block sweep force a path with it)."""
+    block sweep force a path with it). ``shared``: (q2 [B, H, L, D2], k2
+    [B, 1, L, D2]), the second score product's operands."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -838,12 +957,16 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     kf = k.reshape(B * G, L, D)
     vf = v.reshape(B * G, L, D)
     rotary = rotary_base is not None
-    plan = flash_plan(B, H, L, D, group, q.dtype, False, rotary, block_q,
-                      block_k, vmem_budget)[profile.FLASH_FWD]
+    D2 = shared[0].shape[-1] if shared else 0
+    plans = flash_plan(B, H, L, D, group, q.dtype, False, rotary, block_q,
+                       block_k, vmem_budget, D2)
+    if shared:
+        q2f, k2f, of_batch = _shared_operands(shared, B, G, group, plans)
+    plan = plans[profile.FLASH_FWD]
     bq, bk = plan.block_q, plan.block_k
     rows = L * group
     bqp = bq // group
-    inputs = [qf, kf, vf]
+    inputs = [qf, kf, vf] + ([q2f, k2f] if shared else [])
     if rotary:
         qc, qs = _rope_tables(_row_positions(L, group), D, rotary_base)
         kc, ks = _rope_tables(jnp.arange(L, dtype=jnp.int32), D,
@@ -854,7 +977,8 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     if plan.path == "resident":
         kernel = functools.partial(_fwd_resident_kernel, scale=scale,
                                    causal=causal, bk=bk, bqp=bqp,
-                                   group=group, rotary=rotary)
+                                   group=group, rotary=rotary,
+                                   **({"shared": True} if shared else {}))
         scratch = []
     else:
         kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -866,11 +990,17 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
             pltpu.VMEM((bq, 128), jnp.float32),
         ] + ([pltpu.VMEM((bq, D), q.dtype)] if rotary else [])
     q_spec = pl.BlockSpec((None, bq, D), q_im)
+    # The shared key whole, by its batch: the block index is the same for
+    # every head of a batch, so it is fetched once a batch.
+    shared_specs = [pl.BlockSpec((None, bq, D2), q_im),
+                    pl.BlockSpec((None, L, D2),
+                                 lambda b, i: (of_batch(b), 0, 0))] \
+        if shared else []
     out, lse = pl.pallas_call(
         kernel,
         name=profile.FLASH_FWD,
         grid=plan.grid,
-        in_specs=[q_spec, kv_spec, kv_spec] + (
+        in_specs=[q_spec, kv_spec, kv_spec] + shared_specs + (
             [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []),
         out_specs=[q_spec, pl.BlockSpec((None, bq, 8), q_im)],
         out_shape=[
@@ -1423,10 +1553,12 @@ def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary):
 
 def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
                      block_q=None, block_k=None, rotary_base=None,
-                     vmem_budget=RESIDENT_VMEM_BUDGET):
+                     vmem_budget=RESIDENT_VMEM_BUDGET, shared=None):
     """Pallas backward: q/out/g [B,H,L,D], k/v [B,G,L,D], lse in the
     grouped-rows layout. Returns (dq [B,H,L,D], dk/dv [B,G,L,D]) in the
-    inputs' dtypes. Path and blocks per kernel from `flash_plan`."""
+    inputs' dtypes. Path and blocks per kernel from `flash_plan`. With
+    ``shared`` = (q2 [B,H,L,D2], k2 [B,1,L,D2]) also (dq2, dk2) of those
+    shapes, dk2 summed over the heads in f32."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -1444,8 +1576,15 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     # Backward blocks are independent of the forward's (lse/delta
     # stripes are block-agnostic); see _resident_blocks and
     # _default_blocks for the swept preferences.
+    D2 = shared[0].shape[-1] if shared else 0
     plans = flash_plan(B, H, L, D, group, q.dtype, True, rotary, block_q,
-                       block_k, vmem_budget)
+                       block_k, vmem_budget, D2)
+    if shared:
+        q2f, k2f, of_batch = _shared_operands(shared, B, G, group, plans)
+        extra = {"shared": True}
+        dq2_shape = jax.ShapeDtypeStruct((B * G, rows, D2), q2f.dtype)
+    else:
+        extra = {}
     if rotary:
         qc, qs = _rope_tables(_row_positions(L, group), D, rotary_base)
         kc, ks = _rope_tables(jnp.arange(L, dtype=jnp.int32), D,
@@ -1453,7 +1592,8 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         tables = [qc, qs, kc, ks]
     else:
         tables = []
-    inputs = [qf, kf, vf] + tables + [gf, lse, delta]
+    inputs = [qf, kf, vf] + ([q2f, k2f] if shared else []) + tables + [
+        gf, lse, delta]
     dq_shape = jax.ShapeDtypeStruct((B * G, rows, D), q.dtype)
 
     # One kernel for the whole backward where the plan says so, else dQ
@@ -1468,7 +1608,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         if plan.path == "resident":
             kernel = functools.partial(
                 _bwd_dq_resident_kernel, scale=scale, causal=causal, bk=bk,
-                bqp=bqp, group=group, rotary=rotary)
+                bqp=bqp, group=group, rotary=rotary, **extra)
             scratch = []
         else:
             kernel = functools.partial(
@@ -1478,19 +1618,25 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
                 [pltpu.VMEM((bq, D), q.dtype)] if rotary else [])
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
+        q2_spec = pl.BlockSpec((None, bq, D2), q_im)
         dq = pl.pallas_call(
             kernel,
             name=profile.FLASH_DQ,
             grid=plan.grid,
-            in_specs=[q_spec, kv_spec, kv_spec] + (
+            in_specs=[q_spec, kv_spec, kv_spec] + ([
+                q2_spec, pl.BlockSpec((None, L, D2),
+                                      lambda b, i: (of_batch(b), 0, 0))]
+                if shared else []) + (
                 [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
                 q_spec, stripe_spec, stripe_spec],
-            out_specs=q_spec,
-            out_shape=dq_shape,
+            out_specs=[q_spec, q2_spec] if shared else q_spec,
+            out_shape=[dq_shape, dq2_shape] if shared else dq_shape,
             scratch_shapes=scratch,
             compiler_params=_compiler_params(plan),
             interpret=interpret,
         )(*inputs)
+        if shared:
+            dq, dq2 = dq
 
     plan = plans[profile.FLASH_BWD if fused else profile.FLASH_DKV]
     bq, bk = plan.block_q, plan.block_k
@@ -1499,14 +1645,17 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         kernel = functools.partial(_bwd_dkv_resident_kernel, scale=scale,
                                    causal=causal, bq=bq, bqp=bqp,
                                    group=group, rotary=rotary,
-                                   with_dq=fused)
+                                   with_dq=fused, **extra)
         k_im = lambda b, j: (b, j, 0)                       # noqa: E731
         q_spec = pl.BlockSpec((None, rows, D), lambda b, j: (b, 0, 0))
+        q2_spec = pl.BlockSpec((None, rows, D2), lambda b, j: (b, 0, 0))
         stripe_spec = pl.BlockSpec((None, rows, 8), lambda b, j: (b, 0, 0))
         tq_spec = pl.BlockSpec((rows, D), lambda b, j: (0, 0))
         tk_spec = pl.BlockSpec((bk, D), lambda b, j: (j, 0))
         # dQ's accumulator across the k blocks of a (batch, kv head).
         scratch = [pltpu.VMEM((rows, D), jnp.float32)] if fused else []
+        if fused and shared:
+            scratch.append(pltpu.VMEM((rows, D2), jnp.float32))
     else:
         kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
                                    causal=causal, num_qb=rows // bq,
@@ -1527,18 +1676,35 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         kernel,
         name=profile.FLASH_BWD if fused else profile.FLASH_DKV,
         grid=plan.grid,
-        in_specs=[q_spec, k_spec, k_spec] + (
+        in_specs=[q_spec, k_spec, k_spec] + ([
+            q2_spec, pl.BlockSpec((None, bk, D2),
+                                  lambda b, j: (of_batch(b), j, 0))]
+            if shared else []) + (
             [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
             q_spec, stripe_spec, stripe_spec],
-        out_specs=[k_spec, k_spec] + ([q_spec] if fused else []),
+        # Results in the kernel's order: dk, dv, [dk2], [dq, [dq2]].
+        out_specs=[k_spec, k_spec] + (
+            [pl.BlockSpec((None, bk, D2), k_im)] if shared else []) + (
+            [q_spec] + ([q2_spec] if shared else []) if fused else []),
         out_shape=[
             jax.ShapeDtypeStruct((B * G, L, D), k.dtype),
             jax.ShapeDtypeStruct((B * G, L, D), v.dtype),
-        ] + ([dq_shape] if fused else []),
+        ] + ([jax.ShapeDtypeStruct((B * G, L, D2), k2f.dtype)]
+             if shared else []) + (
+            [dq_shape] + ([dq2_shape] if shared else []) if fused else []),
         scratch_shapes=scratch,
         compiler_params=_compiler_params(plan, carries=fused),
         interpret=interpret,
     )(*inputs)
+    if shared:
+        dk, dv, dk2, *rest = results
+        if fused:
+            dq, dq2 = rest
+        # A head's part of the shared key's gradient each: summed in f32.
+        dk2 = jnp.sum(dk2.reshape(B, G, L, D2), axis=1, keepdims=True,
+                      dtype=jnp.float32).astype(dk2.dtype)
+        return (_from_rows(dq, B, group), dk.reshape(B, G, L, D),
+                dv.reshape(B, G, L, D), _from_rows(dq2, B, group), dk2)
     if fused:
         dk, dv, dq = results
     else:
@@ -1617,6 +1783,50 @@ def _flash_bwd(scale, causal, interpret, rotary_base, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _blockwise_shared(q, k, v, q2, k2, scale, causal):
+    """The blockwise jnp form of scores of two products: the two pairs side
+    by side, the shared key repeated over the heads (HERE only: the kernels
+    never do)."""
+    H, G = q.shape[1], k.shape[1]
+    return _blockwise_reference(
+        jnp.concatenate([q, q2], axis=-1),
+        jnp.concatenate([jnp.repeat(k, H // G, axis=1),
+                         jnp.repeat(k2, H, axis=1)], axis=-1),
+        jnp.repeat(v, H // G, axis=1), scale, causal)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash_shared(q, k, v, q2, k2, scale, causal, interpret):
+    """`_flash` with scores of two products: q2 [B,H,L,D2] on the one key
+    k2 [B,1,L,D2] of every head beside q on k."""
+    if interpret is None:
+        return _blockwise_shared(q, k, v, q2, k2, scale, causal)
+    return _pallas_forward_lse(q, k, v, scale, causal, interpret,
+                               shared=(q2, k2))[0]
+
+
+def _flash_shared_fwd(q, k, v, q2, k2, scale, causal, interpret):
+    if interpret is None:
+        return (_blockwise_shared(q, k, v, q2, k2, scale, causal),
+                (q, k, v, q2, k2, None, None))
+    out, lse = _pallas_forward_lse(q, k, v, scale, causal, interpret,
+                                   shared=(q2, k2))
+    return out, (q, k, v, q2, k2, out, lse)
+
+
+def _flash_shared_bwd(scale, causal, interpret, res, g):
+    q, k, v, q2, k2, out, lse = res
+    if interpret is None:
+        _, vjp = jax.vjp(
+            lambda *a: _blockwise_shared(*a, scale, causal), q, k, v, q2, k2)
+        return vjp(g)
+    return _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
+                            shared=(q2, k2))
+
+
+_flash_shared.defvjp(_flash_shared_fwd, _flash_shared_bwd)
+
+
 def analytic_attention_flops(B, H, L, D, causal=True, training=False):
     """FLOPs the Pallas attention kernels execute per call — XLA's
     compiled-cost analysis reports custom calls as ZERO flops, so
@@ -1637,7 +1847,8 @@ def analytic_attention_flops(B, H, L, D, causal=True, training=False):
     return (9.0 if training else 2.0) * per_matmul
 
 
-def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None):
+def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
+                    q_shared=None, k_shared=None):
     """Flash attention over [B, L, H, D] inputs (same layout as
     `parallel.ring.ring_attention`); returns [B, L, H, D] in q.dtype.
 
@@ -1646,6 +1857,13 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None):
     query heads share a kv head, the llama convention). ``rotary_base``
     fuses rotary position embedding (positions 0..L-1) into the
     kernels' q/k load path — do not also rotate outside.
+
+    Scores of two products (latent attention): ``q_shared`` [B, L, H, D2]
+    and ``k_shared`` [B, L, 1, D2], ONE key a position for every head; the
+    scores are ``scale * (q.k + q_shared.k_shared)``, ``scale`` by default
+    (D + D2) ** -0.5, and v is as wide as k. The kernels read the shared
+    key by its batch and never repeat it over the heads in memory; rotate
+    it outside (`rotary_base` is refused beside it).
 
     L must be a multiple of 128 to hit the Pallas kernel; other shapes
     (and non-TPU backends without interpret mode) use the blockwise JAX
@@ -1657,8 +1875,21 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None):
         raise ValueError(
             f"num_heads={H} must be a multiple of num_kv_heads={G}")
     group = H // G
+    if (q_shared is None) != (k_shared is None):
+        raise ValueError("q_shared and k_shared come together")
+    D2 = 0 if q_shared is None else q_shared.shape[-1]
+    if D2:
+        if rotary_base is not None:
+            raise ValueError("rotary_base cannot be combined with q_shared "
+                             "/ k_shared: rotate the shared slice outside")
+        if (k_shared.shape != (B, L, 1, D2) or q_shared.shape[:3] != (B, L, H)
+                or v.shape[-1] != D):
+            raise ValueError(
+                "q_shared %s / k_shared %s / v %s: want [B, L, H, D2], "
+                "[B, L, 1, D2] and v as wide as k"
+                % (q_shared.shape, k_shared.shape, v.shape))
     if scale is None:
-        scale = D ** -0.5
+        scale = (D + D2) ** -0.5
     # Kernel layout: [B, H, L, D] / [B, G, L, D].
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -1671,6 +1902,14 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None):
         is not None and _pick_rows_block(
             L, _grouped_blocks(D, L, group, backward=True)[0], group)
         is not None)
+    if D2:
+        kernel_ok = kernel_ok and all(
+            flash_plan(B, H, L, D, group, q.dtype, backward,
+                       shared_dim=D2) for backward in (False, True))
+        out = _flash_shared(qt, kt, vt, q_shared.transpose(0, 2, 1, 3),
+                            k_shared.transpose(0, 2, 1, 3), scale, causal,
+                            False if kernel_ok else None)
+        return out.transpose(0, 2, 1, 3)
     out = _flash(qt, kt, vt, scale, causal, False if kernel_ok else None,
                  rotary_base)
     return out.transpose(0, 2, 1, 3)

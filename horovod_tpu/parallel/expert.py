@@ -9,7 +9,17 @@ this is the TPU-first ``ep`` member of the parallelism family
 * **route** — float32 softmax over all experts, ``lax.top_k`` for any k,
   the chosen probabilities as weights, renormalised or as they are
   (`route`); the load-balancing loss and the router z-loss beside it
-  (`router_losses`).
+  (`router_losses`). Or by sigmoid (DeepSeek-V3): every expert's score by
+  itself, the choice made on score + a selection bias, the weights the
+  chosen scores without it, renormalised and scaled.
+* **held** (``held=(first, count)``, local) — the layer is told which
+  experts this device holds: the router scores and picks over ALL experts,
+  the sorted run of the held experts' rows is taken to the front of the
+  k*T-row buffer, `count` groups are multiplied, and what the absent
+  experts would add is left out (the one rank's share of an
+  expert-parallel layer, without its exchange).
+* **shared** — an always-on gated expert beside the routed ones (`MoeMlp`
+  with ``shared_dim``).
 * **sort** — the k*T assignments are sorted by expert (`sort_assignments`):
   a permutation, its inverse and the group sizes, which always sum to k*T.
   Nothing here builds a [T, E, C] tensor.
@@ -39,7 +49,7 @@ w_out`` or gated (``w_gate``, ``w_up``, ``w_down``: SwiGLU with SiLU).
 
 import functools
 import math
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -54,13 +64,38 @@ from horovod_tpu.ops.grouped_matmul import grouped_matmul
 EXPERT_LEAVES = ("w_in", "w_out", "w_gate", "w_up", "w_down")
 
 
-def route(router_logits, k, renormalize=True):
+def route(router_logits, k, renormalize=True, scoring="softmax", bias=None,
+          scale=1.0):
     """Top-k routing. router_logits: [T, E] (any float dtype; softmax in
     f32). Returns (weights [T, k] f32, experts [T, k] int32, probs [T, E]
     f32): the chosen experts in order of falling probability and their
     probabilities, divided by their sum per token when `renormalize` and
     k > 1 (k = 1 keeps the raw probability: that term is what trains a
-    Switch router)."""
+    Switch router).
+
+    ``scoring="sigmoid"`` (DeepSeek-V3's `noaux_tc`): probs are each
+    expert's own sigmoid; the k experts are those of largest
+    ``probs + bias`` (`bias` [E]: a selection bias that moves the choice
+    and never the weights; no gradient reaches it), the weights the chosen
+    experts' probs, divided by their sum (+ 1e-20) when `renormalize`, then
+    multiplied by `scale`."""
+    if scoring == "sigmoid":
+        probs = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+        choice = probs if bias is None else \
+            probs + lax.stop_gradient(bias.astype(jnp.float32))
+        experts = lax.top_k(choice, k)[1]
+        # The chosen scores by comparison, not by a gather (whose transpose
+        # is a scatter-add, and which XLA expands under a bare op_name).
+        weights = jnp.sum(jnp.where(
+            experts[..., None] == jnp.arange(probs.shape[-1]),
+            probs[..., None, :], 0.0), axis=-1)
+        if renormalize:
+            weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                                 + 1e-20)
+        return weights * scale, experts.astype(jnp.int32), probs
+    if scoring != "softmax" or bias is not None or scale != 1.0:
+        raise ValueError("route: scoring=%r; a selection bias and a scale "
+                         "belong to scoring='sigmoid'" % (scoring,))
     probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
     weights, experts = lax.top_k(probs, k)
     if renormalize and k > 1:
@@ -162,7 +197,8 @@ def moe_capacity(tokens, num_experts, capacity_factor):
 
 def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
             ep_axis=None, act=nn.silu, top_k=1, w_gate=None,
-            renormalize=True):
+            renormalize=True, scoring="softmax", bias=None, scale=1.0,
+            held=None):
     """Routed feed-forward over flattened tokens.
 
     x: [T, D], whose dtype is the compute dtype (the experts' weights may
@@ -175,6 +211,14 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
     expert dim sharded. ``capacity_factor=None`` is dropless (local only);
     with a factor each assignment consumes one of its expert's capacity
     slots — size it accordingly (>= top_k for comparable drop rates).
+    `scoring`, `bias` and `scale` are `route`'s.
+
+    ``held=(first, count)`` (dropless and local only): this device holds
+    the experts [first, first + count) of the E the router scores, so
+    w_in, w_out, w_gate are [count, ...]. Every token is routed over all E
+    with weights normalised over all its k choices; the result is the sum
+    over its choices that are held, and ``stats["held"]`` counts those
+    assignments (int32 scalar; the key exists only with `held`).
 
     Returns (y [T, D] in x.dtype, stats): ``load_balance_loss`` and
     ``router_z_loss`` (f32 scalars, `router_losses`), ``assignments`` ([E]
@@ -186,7 +230,17 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
     T, D = x.shape
     E = router_w.shape[1]
     ep = 1 if ep_axis is None else lax.axis_size(ep_axis)
-    if w_in.shape[0] * ep != E:
+    if held is not None:
+        first, count = held
+        if ep_axis is not None or capacity_factor is not None:
+            raise ValueError("held=%r is the dropless local path: it cannot "
+                             "be combined with ep_axis or a capacity_factor"
+                             % (held,))
+        if first < 0 or count < 1 or first + count > E \
+                or w_in.shape[0] != count:
+            raise ValueError("held=%r of %d experts with %d matrices held"
+                             % (held, E, w_in.shape[0]))
+    elif w_in.shape[0] * ep != E:
         raise ValueError(
             "expert shards (%d local x ep=%d) != num_experts %d" %
             (w_in.shape[0], ep, E))
@@ -196,7 +250,8 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
                          "required (all_to_all needs static buffers)")
     with jax.named_scope(profile.MOE_ROUTE):
         logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
-        weights, experts, probs = route(logits, top_k, renormalize)
+        weights, experts, probs = route(logits, top_k, renormalize,
+                                        scoring, bias, scale)
     with jax.named_scope(profile.MOE_DISPATCH):
         flat, order, inv, group_sizes = sort_assignments(experts, E)
     with jax.named_scope(profile.MOE_ROUTE):
@@ -205,7 +260,34 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
              "assignments": group_sizes, "chosen": experts}
     weights = weights.T  # [k, T], the assignments' order
 
-    if capacity_factor is None:
+    if held is not None:
+        kT = order.shape[0]
+        with jax.named_scope(profile.MOE_DISPATCH):
+            # The held experts' rows are one run of the sorted order, from
+            # `start`: turned to the front of the k*T-row buffer, which so
+            # holds them whatever the router does. The rows behind them
+            # belong to no group: the grouped matmuls leave them undefined,
+            # and they are zeroed on the way in (so that no gradient comes
+            # back through them) and on the way out.
+            start = jnp.sum(group_sizes[:first])
+            sizes = group_sizes[first:first + count]
+            n_held = jnp.sum(sizes)
+            at = jnp.arange(kT, dtype=jnp.int32)
+            order_h = order[(at + start) % kT]
+            inv_h = (inv - start) % kT
+            mine = (at < n_held)[:, None]
+            xs = jnp.where(mine, _rows_to_sorted(x, order_h, inv_h, top_k),
+                           0)
+        with jax.named_scope(profile.MOE_EXPERTS):
+            ys = _experts(xs, w_in, w_out, w_gate, act,
+                          lambda rows, w: grouped_matmul(rows, w, sizes))
+        with jax.named_scope(profile.MOE_COMBINE):
+            rows = _rows_from_sorted(jnp.where(mine, ys, 0), order_h, inv_h)
+            weights = jnp.where((experts.T >= first)
+                                & (experts.T < first + count), weights, 0.0)
+        stats["dropped"] = jnp.zeros((), jnp.int32)
+        stats["held"] = n_held
+    elif capacity_factor is None:
         with jax.named_scope(profile.MOE_DISPATCH):
             xs = _rows_to_sorted(x, order, inv, top_k)
         with jax.named_scope(profile.MOE_EXPERTS):
@@ -263,7 +345,17 @@ class MoeMlp(nn.Module):
     rank holds [num_experts/ep_size, ...] expert weights, so the
     declared param shapes divide by it (the tp path's `cfg.local()`
     trick). Initialize with ``ep_size=1`` (full shapes), place with
-    `ep_param_specs`, apply with the ep-sized module."""
+    `ep_param_specs`, apply with the ep-sized module.
+
+    ``scoring="sigmoid"`` routes as DeepSeek-V3 does (`route`): the
+    parameter ``select_bias`` [num_experts] f32, zeros, is the selection
+    bias, which no gradient reaches, and ``route_scale`` multiplies the
+    weights. ``held=(first, count)``: the module holds only those experts'
+    matrices ([count, ...]) and routes over all ``num_experts``
+    (`moe_ffn`); it sows ``moe_held`` too. ``shared_dim``: an always-on
+    gated expert of that width (``shared_gate``, ``shared_up``,
+    ``shared_down``), added to the routed sum under scope
+    `hvd_moe_shared`."""
     num_experts: int
     mlp_dim: int
     capacity_factor: Optional[float] = 1.25   # None: dropless
@@ -273,6 +365,10 @@ class MoeMlp(nn.Module):
     gated: bool = False
     renormalize: bool = True
     dtype: Any = jnp.bfloat16
+    scoring: str = "softmax"
+    route_scale: float = 1.0
+    held: Optional[Tuple[int, int]] = None
+    shared_dim: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
@@ -281,8 +377,14 @@ class MoeMlp(nn.Module):
             raise ValueError("ep_size=%d must divide num_experts=%d" %
                              (self.ep_size, self.num_experts))
         e_local = self.num_experts // self.ep_size
+        if self.held is not None:  # `moe_ffn` refuses it beside ep_axis
+            e_local = self.held[1]
         router_w = self.param("router", nn.initializers.normal(0.02),
                               (D, self.num_experts), jnp.float32)
+        bias = None
+        if self.scoring == "sigmoid":
+            bias = self.param("select_bias", nn.initializers.zeros,
+                              (self.num_experts,), jnp.float32)
 
         def expert(name, rows, cols):
             return self.param(name, nn.initializers.normal(0.02),
@@ -301,13 +403,26 @@ class MoeMlp(nn.Module):
                                w_in, w_out,
                                capacity_factor=self.capacity_factor,
                                ep_axis=self.ep_axis, top_k=self.top_k,
-                               w_gate=w_gate, renormalize=self.renormalize)
+                               w_gate=w_gate, renormalize=self.renormalize,
+                               scoring=self.scoring, bias=bias,
+                               scale=self.route_scale, held=self.held)
+            if self.shared_dim is not None:
+                with jax.named_scope(profile.MOE_SHARED):
+                    dense = lambda n, name: nn.Dense(  # noqa: E731
+                        n, dtype=self.dtype, param_dtype=jnp.float32,
+                        use_bias=False, name=name)
+                    xs = x.reshape(-1, D)
+                    y = y + dense(D, "shared_down")(
+                        nn.silu(dense(self.shared_dim, "shared_gate")(xs))
+                        * dense(self.shared_dim, "shared_up")(xs))
         for name, key in (("moe_aux_loss", "load_balance_loss"),
                           ("moe_z_loss", "router_z_loss"),
                           ("moe_assignments", "assignments"),
                           ("moe_dropped", "dropped"),
-                          ("moe_chosen", "chosen")):
-            self.sow("intermediates", name, stats[key])
+                          ("moe_chosen", "chosen"),
+                          ("moe_held", "held")):
+            if key in stats:
+                self.sow("intermediates", name, stats[key])
         return y.reshape(B, L, D)
 
 
@@ -337,11 +452,20 @@ def routing_stats(intermediates):
     """A step's routing statistics from the same tree: ``assignments``
     [layers, E] int32 (each row sums to top_k * tokens), ``chosen``
     [layers, tokens, top_k] int32 (each token's experts) and ``dropped``
-    (int32 scalar over all layers; 0 on the dropless path)."""
-    return {"assignments": jnp.stack(_sown(intermediates,
-                                           "moe_assignments")),
-            "chosen": jnp.stack(_sown(intermediates, "moe_chosen")),
-            "dropped": sum(_sown(intermediates, "moe_dropped"))}
+    (int32 scalar over all layers; 0 on the dropless path). Where the
+    layers hold a part of their experts (`MoeMlp` with ``held``) also
+    ``held_share`` [layers] f32: the share of a layer's assignments that
+    fell on the experts it holds (count / num_experts under even
+    routing)."""
+    out = {"assignments": jnp.stack(_sown(intermediates,
+                                          "moe_assignments")),
+           "chosen": jnp.stack(_sown(intermediates, "moe_chosen")),
+           "dropped": sum(_sown(intermediates, "moe_dropped"))}
+    held = _sown(intermediates, "moe_held")
+    if held:
+        out["held_share"] = jnp.stack(held).astype(jnp.float32) \
+            / jnp.sum(out["assignments"], axis=1)
+    return out
 
 
 def ep_grad_sync(grads, ep_axis="ep", dp_axis=None, average=False):

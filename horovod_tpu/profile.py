@@ -64,7 +64,24 @@ MOE_ROUTE = "hvd_moe_route"        # router matmul, softmax, top-k, losses
 MOE_DISPATCH = "hvd_moe_dispatch"  # sort, group sizes, gather of the rows
 MOE_EXPERTS = "hvd_moe_experts"    # the grouped matmuls and the gate
 MOE_COMBINE = "hvd_moe_combine"    # gather back, weights, sum over choices
-MOE_SCOPES = (MOE, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
+MOE_SHARED = "hvd_moe_shared"      # the always-on expert beside the routed
+MOE_SCOPES = (MOE, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
+              MOE_SHARED)
+
+# The hyper-connection around each of a block's two branches
+# (`models/transformer.py`, `hc_mult` > 1), inside `BLOCK` and beside the
+# branches' own `attn` / `mlp`: `HC` around all of it, `HC_MAP` (the norm
+# over the streams, the projection, the Sinkhorn iterations) and `HC_MIX`
+# (reading the branch's input from the streams; writing the mixed streams
+# and the branch's output back) inside. `MTP` is around the multi-token
+# prediction module: its embedding, two norms, projection, block and final
+# norm. Like `LOOP`, neither is in MODEL_SCOPES: a block under `MTP` is
+# still told apart as `hvd_block/attn`.
+HC = "hvd_hc"
+HC_MAP = "hvd_hc_map"
+HC_MIX = "hvd_hc_mix"
+HC_SCOPES = (HC, HC_MAP, HC_MIX)
+MTP = "hvd_mtp"
 
 # The `name=` of every `pl.pallas_call`: on the chip's trace the kernel's
 # instruction is `<name>.<n>` and its scope path ends in
@@ -272,8 +289,14 @@ def flash_plan(*args, **kwargs):
     backward is one resident kernel, else `FLASH_DQ` and `FLASH_DKV`): the
     path (`resident`: the other sequence whole in VMEM, one grid step per
     block; `gridded`: one grid step per tile), the blocks, the grid and the
-    grid steps a call issues, and the VMEM bytes it asks for. The kernels
-    run what this returns, so like `grad_collectives` it needs no chip."""
+    grid steps a call issues, and the VMEM bytes it asks for. With
+    ``shared_dim=D2`` the call's scores are of two products (latent
+    attention's rotary slice on one key shared by the heads): the same
+    kernels hold the second pair of operands and the sums count them
+    (D=128, D2=64, L=4096: the one-kernel backward at 22 MiB of the 24);
+    ``{}`` says no kernel has that form at the shape (a gridded one would
+    be needed) and the call is the blockwise jnp path. The kernels run what
+    this returns, so like `grad_collectives` it needs no chip."""
     # `ops.flash_attention` imports this module for its kernels' names.
     from horovod_tpu.ops.flash_attention import flash_plan as plan
 
