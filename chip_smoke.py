@@ -69,6 +69,9 @@ SIZES = dict(
     # benchmark's `xing29b_1chip` (printed only: that cell's own comparison
     # with its reference runs the kernels).
     attn_two_products=(1, 32, 4096, 128, 64),
+    # (n, T, C, K) of a hyper-connection at the benchmark's `xing29b_1chip`:
+    # four streams of 4096 tokens, 3584 wide, onto phi's 24 columns.
+    hc=(4, 4096, 3584, 24),
     # (M, C) of the largest and the smallest BatchNorm of ResNet-50 at
     # batch 256.
     bn=[(256 * 112 * 112, 64), (256 * 7 * 7, 2048)],
@@ -81,7 +84,7 @@ SIZES = dict(
 # (tests/test_ops.py, tests/test_batch_norm.py).
 TOL = dict(attn_bf16=2e-2, bn_out=2e-4, bn_grad=2e-3, loss_rel=1e-3,
            checksum_rel=1e-6, update_cosine=0.99, grad_rel_l2=2e-2,
-           host=1e-4)
+           host=1e-4, hc_stat=1e-5)
 
 
 class PhaseFailed(Exception):
@@ -442,6 +445,61 @@ def attention_vs_reference(case, tol, kernels):
           % ((name,) + tuple(errs) + (tol,)))
 
 
+def hc_stat_vs_jnp(n, T, C, K, dtype, seed):
+    """How a hyper-connection's statistic is formed at this shape
+    (`hvd.profile.hc_plan`), that the two kernels it names are in the
+    program, and that on the chip the two results and both gradients agree
+    with the plain sums and products formed in f32."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import profile
+    from horovod_tpu.ops.hc_stat import hc_stat
+
+    plan = profile.hc_plan(n, T, C, K, dtype, hc_remat=True,
+                           block_remat=True)
+    print("  %s [%d, %d, %d] x %d columns: %s, %d x %d tokens, VMEM %.1f "
+          "MiB, %d pass over the streams, %d evaluation a step under both "
+          "recomputations"
+          % (profile.HC_STAT, n, T, C, K, plan["path"], plan["steps"],
+             plan["rows"], plan["vmem_bytes"] / 2 ** 20, plan["passes"],
+             plan["evaluations"]), flush=True)
+    kx, kp, ks, kw = jax.random.split(jax.random.PRNGKey(seed), 4)
+    X = jax.random.normal(kx, (n, 1, T, C), dtype)
+    phi = 0.02 * jax.random.normal(kp, (n * C, K), jnp.float32)
+    w = (jax.random.normal(ks, (1, T)), jax.random.normal(kw, (1, T, K)))
+
+    def both(fn):
+        def f(X, phi):
+            out, vjp = jax.vjp(fn, X, phi)
+            return out + vjp(w)
+        return jax.jit(f)
+
+    def plain(X, phi):
+        xf = X.astype(jnp.float32)
+        return (jnp.sum(xf * xf, axis=(0, -1)), jnp.einsum(
+            "n...c,nck->...k", xf,
+            phi.astype(dtype).astype(jnp.float32).reshape(n, C, K)))
+
+    kernels = (profile.HC_STAT, profile.HC_STAT_DPHI)
+    compiled, text, secs = compile_with_text(both(hc_stat), X, phi)
+    check(plan["path"] == "kernel" and kernel_calls(text) == len(kernels)
+          and all(kernel_named(text, k) for k in kernels),
+          "%s: %d tpu_custom_call in the program (%s; compiled in %.1f s)"
+          % (profile.HC_STAT, kernel_calls(text), ", ".join(kernels), secs))
+    got = compiled(X, phi)
+    with jax.default_matmul_precision("highest"):
+        want = both(plain)(X, phi)
+    errs = [rel_err(g, r) for g, r in zip(got, want)]
+    check(max(errs[:2]) <= TOL["hc_stat"]
+          and max(errs[2:]) <= TOL["attn_bf16"],
+          "%s vs jnp in f32 on the chip: sum of squares %.2e projection "
+          "%.2e (tol %.0e) dX %.2e dphi %.2e (bf16 operands, tol %.0e; max "
+          "rel to max |ref|)"
+          % ((profile.HC_STAT,) + tuple(errs[:2]) + (TOL["hc_stat"],)
+             + tuple(errs[2:]) + (TOL["attn_bf16"],)))
+
+
 def bn_case(M, C, seed):
     """fused_batch_norm_train (Pallas statistics and gradient-statistics
     kernels) and flax.linen.BatchNorm at one ResNet-50 shape: (fused,
@@ -580,6 +638,8 @@ def phase_kernels(args):
     print("  scores of two products, %d + %d wide on %d heads at L=%d:"
           % (D, D2, H, L), flush=True)
     print_flash_plan(B, H, H, L, D, False, jnp.bfloat16, shared_dim=D2)
+
+    hc_stat_vs_jnp(*SIZES["hc"], jnp.bfloat16, args.seed)
 
     step, state = resnet_step(models.ResNet50PBN, mesh,
                               SIZES["resnet_batch"], args.seed)

@@ -21,6 +21,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from horovod_tpu import profile
 from horovod_tpu.parallel.ring import ring_attention, ulysses_attention
@@ -434,14 +435,16 @@ def hc_maps(X, phi, bias, alpha, iters, eps, clamp, norm_eps):
 
     phi [n*C, 2n + n*n] with vec(X) stream-major, bias [2n + n*n], alpha
     [3] = (a_pre, a_post, a_res). The projection runs in X's dtype with f32
-    accumulation; everything after it is f32."""
+    accumulation; everything after it is f32. The norm's factor is a scalar
+    a token, so it multiplies the PROJECTION and x~ is never made: the sum
+    of squares and X phi come from one read of the streams
+    (`ops.hc_stat`), and carry the name under which a recomputation keeps
+    them (`_keep_hc_stat`)."""
+    from horovod_tpu.ops.hc_stat import hc_stat
     n, C = X.shape[0], X.shape[-1]
-    xf = X.astype(jnp.float32)
-    inv = lax.rsqrt(jnp.sum(xf * xf, axis=(0, -1)) / (n * C) + norm_eps)
-    xn = (xf * inv[None, ..., None]).astype(X.dtype)
-    raw = jnp.einsum("n...c,nck->...k", xn,
-                     phi.reshape(n, C, -1).astype(X.dtype),
-                     preferred_element_type=jnp.float32)
+    sumsq, proj = (checkpoint_name(t, profile.HC_STAT)
+                   for t in hc_stat(X, phi))
+    raw = lax.rsqrt(sumsq / (n * C) + norm_eps)[..., None] * proj
     bias = bias.astype(jnp.float32)
     a = alpha.astype(jnp.float32)
     pre = a[0] * raw[..., :n] + bias[:n]
@@ -450,6 +453,16 @@ def hc_maps(X, phi, bias, alpha, iters, eps, clamp, norm_eps):
         raw.shape[:-1] + (n, n))
     return (jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post),
             sinkhorn(jnp.exp(jnp.clip(res, clamp[0], clamp[1])), iters, eps))
+
+
+def _keep_hc_stat():
+    """The policy of this module's recomputations (`hc_remat`,
+    `block_remat`): they run everything again but what `hc_maps` derives
+    from a full pass over the streams, each token's sum of squares and
+    its projection (16 KB + 384 KB a connection at 4096 tokens and four
+    streams), which they keep by name. A model without hyper-connections
+    names nothing, and its recomputation keeps nothing, as ever."""
+    return jax.checkpoint_policies.save_only_these_names(profile.HC_STAT)
 
 
 def hc_read(h_pre, X):
@@ -658,8 +671,8 @@ def _hyper_connected(cfg, moe, X, positions, attention, out):
     with its pre-norm as in the plain block. The connection's work lies
     under `hvd_hc` (`hvd_hc_map`, `hvd_hc_mix` inside), the branches
     under `attn` and `mlp` as ever."""
-    connection = nn.remat(HyperConnection) if cfg.hc_remat \
-        else HyperConnection
+    connection = nn.remat(HyperConnection, policy=_keep_hc_stat()) \
+        if cfg.hc_remat else HyperConnection
 
     def connected(X, name, branch):
         with jax.named_scope(profile.HC):
@@ -724,7 +737,8 @@ class Transformer(nn.Module):
             moe = (cfg.moe_experts is not None and
                    i >= cfg.first_k_dense and
                    i % cfg.moe_every == cfg.moe_every - 1)
-            block = nn.remat(Block) if i < cfg.block_remat else Block
+            block = nn.remat(Block, policy=_keep_hc_stat()) \
+                if i < cfg.block_remat else Block
             blocks.append(block(cfg, moe=moe, name="block_%d" % i))
         norm_f = _rms_norm(cfg, "norm_f")
         if cfg.hc_mult > 1 or cfg.mtp_depth:

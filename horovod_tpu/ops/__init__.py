@@ -11,6 +11,10 @@ played by Pallas TPU kernels:
   layer: rows in contiguous ragged groups, each group its own matrix,
   f32 matrices rounded in VMEM, custom VJP (rows' and matrices'
   gradients).
+* :mod:`.hc_stat` — a hyper-connection's per-token sum of squares and
+  its projection onto the maps' columns from one read of the residual
+  streams, custom VJP (the projection's gradient from one more; the
+  streams' own gradient is jnp).
 """
 
 from .flash_attention import flash_attention  # noqa: F401

@@ -99,9 +99,14 @@ MOE_GMM = "hvd_moe_gmm"            # grouped matmul of the experts, forward
 MOE_GMM_DLHS = "hvd_moe_gmm_dlhs"  # backward: the gradient of the rows
 MOE_GMM_DRHS = "hvd_moe_gmm_drhs"  # backward: the gradient of the matrices
 MOE_GMM_KERNELS = (MOE_GMM, MOE_GMM_DLHS, MOE_GMM_DRHS)
+# A hyper-connection's per-token sum of squares and projection from one read
+# of the streams (`ops/hc_stat.py`, under `HC_MAP`); also the name under which
+# the recomputations of `models/transformer.py` keep its two results.
+HC_STAT = "hvd_hc_stat"
+HC_STAT_DPHI = "hvd_hc_stat_dphi"  # backward: the gradient of phi
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
            RING_ATTN_DQ, RING_ATTN_DKV, BN_STATS,
-           BN_GRAD_STATS) + MOE_GMM_KERNELS
+           BN_GRAD_STATS) + MOE_GMM_KERNELS + (HC_STAT, HC_STAT_DPHI)
 
 # Host spans of the program's only per-call Python.
 SPAN_PLACE = "hvd_place"                  # `step.place`
@@ -316,5 +321,23 @@ def loss_plan(*args, **kwargs):
     returns, so like `flash_plan` it needs no chip."""
     # `ops.losses` imports this module for its scope's name.
     from horovod_tpu.ops.losses import loss_plan as plan
+
+    return plan(*args, **kwargs)
+
+
+# --- how a hyper-connection's statistic is formed, and how often ------------
+
+def hc_plan(*args, **kwargs):
+    """How `ops.hc_stat.hc_stat` runs a call on the streams [n, T, C]
+    against phi's K columns, and how often a training step makes it for one
+    connection: `ops.hc_stat.hc_plan(n, T, C, K, dtype, hc_remat=...,
+    block_remat=...)` (its arguments and result), here beside the other
+    program-side counters. The path (`kernel`: `HC_STAT`, one pass over the
+    streams; `jnp`: two), a tile's tokens, the grid steps a call issues, the
+    VMEM bytes its blocks take, and the evaluations a step makes under the
+    model's recomputations. The op runs what this returns, so like
+    `flash_plan` it needs no chip."""
+    # `ops.hc_stat` imports this module for its kernel's name.
+    from horovod_tpu.ops.hc_stat import hc_plan as plan
 
     return plan(*args, **kwargs)

@@ -199,6 +199,27 @@ def test_grouped_matmul_compiles_for_v5e(one_chip, K, N):
         assert _named(text, name), name
 
 
+# A hyper-connection of Xing4.0 on one chip: four streams of 4096 tokens,
+# 3584 wide, against phi's 24 columns (`benchmark`'s cell `xing29b_1chip`).
+def test_hc_stat_compiles_for_v5e(one_chip):
+    from horovod_tpu.ops.hc_stat import hc_plan, hc_stat
+
+    def fwd_bwd(X, phi, g_s, g_p):
+        out, vjp = jax.vjp(lambda X, phi: hc_stat(X, phi, interpret=False),
+                           X, phi)
+        return out, vjp((g_s, g_p))
+
+    f32 = jnp.float32
+    text = _compile(one_chip, fwd_bwd, ((4, 1, 4096, 3584), jnp.bfloat16),
+                    ((4 * 3584, 24), f32), ((1, 4096), f32),
+                    ((1, 4096, 24), f32))
+    assert hc_plan(4, 4096, 3584, 24, jnp.bfloat16)["path"] == "kernel"
+    # the forward's, and phi's gradient; X's own gradient is XLA's
+    assert _kernels(text) == 2, text[:2000]
+    for name in (profile.HC_STAT, profile.HC_STAT_DPHI):
+        assert _named(text, name), name
+
+
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------
 
 def _lm_step(topo, chips, monkeypatch):

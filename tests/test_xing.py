@@ -599,3 +599,97 @@ def test_the_program_names_the_new_parts():
     assert "%s/%s/mtp_block/attn" % (profile.MTP, profile.BLOCK) in text
     assert "%s/mtp_block/%s/hc_mlp/%s" % (profile.BLOCK, profile.HC,
                                           profile.HC_MAP) in text
+
+
+# --------------------------------------------------------------------------
+# The statistic of a connection is formed once a step (PR 35)
+# --------------------------------------------------------------------------
+
+def _count(jaxpr, wanted):
+    """Equations of `jaxpr`, and of every jaxpr inside one, that `wanted`
+    holds for."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += bool(wanted(eqn))
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) \
+                    else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    total += _count(inner, wanted)
+    return total
+
+
+def _is_stat_kernel(eqn):
+    return (eqn.primitive.name == "pallas_call"
+            and eqn.params["name"] == profile.HC_STAT)
+
+
+@pytest.mark.parametrize("block_remat", [0, 1])
+def test_a_recomputation_keeps_the_statistic_and_forms_no_second(
+        monkeypatch, block_remat):
+    """Under `hc_remat`, and in a block that `block_remat` runs again, the
+    backward pass holds no second evaluation: each of the 6 connections (2
+    blocks and the module's) calls the kernel once in the whole step (and
+    the kernel of phi's gradient once), its
+    two results are saved by name, and no reduction over the stream and
+    the lane dimension (the form the statistic had) is in the program."""
+    from horovod_tpu.ops import hc_stat as hs
+
+    # The kernel itself (in the interpreter), so that an evaluation is one
+    # equation to count.
+    real = hs.hc_stat
+    monkeypatch.setattr(hs, "hc_stat", lambda X, phi: real(
+        X, phi, interpret=True))
+    cfg = _cfg(num_layers=2, embed_dim=128, hc_remat=True,
+               block_remat=block_remat)
+    model, params, tokens = _seeded(cfg)
+    loss = lambda p: _system(model, p, tokens)[2]  # noqa: E731
+    step = jax.make_jaxpr(jax.grad(loss))(params)
+    assert _count(step.jaxpr, _is_stat_kernel) == 6
+    assert _count(step.jaxpr, lambda eqn: eqn.primitive.name == "pallas_call"
+                  and eqn.params["name"] == profile.HC_STAT_DPHI) == 6
+    forward = jax.make_jaxpr(loss)(params)
+    assert _count(forward.jaxpr, _is_stat_kernel) == 6
+    streams = (cfg.hc_mult, 1, LENGTH, cfg.embed_dim)
+
+    def reduces_the_streams(eqn):
+        return (eqn.primitive.name == "reduce_sum"
+                and eqn.invars[0].aval.shape == streams
+                and {0, 3} <= set(eqn.params["axes"]))
+
+    assert _count(step.jaxpr, reduces_the_streams) == 0
+    # the sum of squares and the projection of each carry the name
+    assert _count(forward.jaxpr, lambda eqn: eqn.primitive.name == "name"
+                  and eqn.params["name"] == profile.HC_STAT) == 2 * 6
+    for c in range(6):
+        plan = profile.hc_plan(cfg.hc_mult, LENGTH, cfg.embed_dim, 24,
+                               jnp.float32, hc_remat=True,
+                               block_remat=c < 2 * block_remat)
+        assert (plan["path"], plan["evaluations"]) == ("kernel", 1)
+
+
+def test_without_streams_the_blocks_recomputation_is_what_it_was(
+        monkeypatch):
+    """A model with `hc_mult` 1 names nothing, so `block_remat`'s policy
+    (keep the named values alone) keeps nothing: the step lowers to the
+    text of the recomputation without a policy."""
+    cfg = models.TransformerConfig(
+        vocab_size=VOCAB, num_layers=2, num_heads=HEADS, embed_dim=HIDDEN,
+        mlp_dim=96, max_seq_len=LENGTH, block_remat=1, dtype=jnp.float32)
+    tokens = jnp.arange(LENGTH, dtype=jnp.int32)[None] % VOCAB
+    params = models.Transformer(cfg).init(jax.random.PRNGKey(0),
+                                          tokens)["params"]
+
+    def text():
+        model = models.Transformer(cfg)
+        return jax.jit(jax.grad(lambda p: jnp.sum(model.apply(
+            {"params": p}, tokens) ** 2))).lower(params).as_text()
+
+    with_policy = text()
+    monkeypatch.setattr(transformer, "_keep_hc_stat", lambda: None)
+    assert text() == with_policy
+    # and the recomputation is there: the first block's, not the second's
+    step = jax.make_jaxpr(jax.grad(lambda p: jnp.sum(models.Transformer(
+        cfg).apply({"params": p}, tokens) ** 2)))(params)
+    assert _count(step.jaxpr, lambda eqn: eqn.primitive.name == "remat2") == 1
