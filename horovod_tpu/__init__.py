@@ -146,6 +146,7 @@ def _maybe_rendezvous():
 _mesh = None
 
 
+@profile.phase(profile.SPAN_INIT)
 def init(ranks=None, model_parallel=None):
     """Initializes the core runtime (rendezvous + background thread).
 

@@ -15,6 +15,8 @@ import threading
 
 import numpy as np
 
+from .. import profile
+
 _MOD_DIR = os.path.dirname(os.path.abspath(__file__))
 # HVD_TPU_NATIVE_DIR points at an alternate build of the core (e.g. a
 # `make SANITIZE=thread` TSAN build, or a system-installed location).
@@ -38,7 +40,7 @@ def _ensure_built():
     threading.Lock only covers threads within one process)."""
     if not os.path.exists(os.path.join(_NATIVE_DIR, "Makefile")):
         return
-    with _build_lock:
+    with profile.phase(profile.SPAN_NATIVE_BUILD), _build_lock:
         lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
         with open(lock_path, "w") as lock_file:
             fcntl.flock(lock_file, fcntl.LOCK_EX)
@@ -256,7 +258,9 @@ class HorovodBasics:
 
     # -- lifecycle ---------------------------------------------------------
     def init(self):
-        if not self.lib.horovod_tpu_init():
+        with profile.phase(profile.SPAN_NATIVE_INIT):
+            ok = self.lib.horovod_tpu_init()
+        if not ok:
             raise RuntimeError(
                 "horovod_tpu initialization failed (rendezvous error?). "
                 "Check HVD_TPU_ADDRS / HVD_TPU_RANK / HVD_TPU_SIZE.")

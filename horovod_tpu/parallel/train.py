@@ -111,6 +111,7 @@ def _structure_cached_step(build):
     return step
 
 
+@profile.phase(profile.SPAN_MAKE_STEP)
 def make_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
                     compression=None, donate=True, zero1=False,
                     accum_steps=1, agc=None):
@@ -169,6 +170,7 @@ def make_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
     ``compression``, ``accum_steps`` — is built as before; their
     collectives are synchronous as far as anyone has read, and unmeasured.
     """
+    profile.listen()  # the step's trace, lowering and compile: `phases()`
     from horovod_tpu import compression as _wire
     # zero1 + WIRE compression composes: the gradient scatter runs the
     # explicit ring_reduce_scatter with the codec fused per hop (f32
@@ -330,7 +332,7 @@ def make_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
         """Places params (replicated), optimizer state (replicated, or
         built flat-padded and dim-0 sharded under zero1 — the passed
         opt_state is ignored then), and batch (dim-0 sharded)."""
-        with profile.span(profile.SPAN_PLACE):
+        with profile.phase(profile.SPAN_PLACE):
             rep = NamedSharding(mesh, replicated)
             dat = NamedSharding(mesh, sharded)
             params = jax.device_put(params, rep)
@@ -360,6 +362,7 @@ def make_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
     return step
 
 
+@profile.phase(profile.SPAN_MAKE_STEP)
 def make_fsdp_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
                          donate=True, min_size=1024):
     """Fully-sharded data parallelism (ZeRO-3-style) the XLA-native
@@ -382,6 +385,7 @@ def make_fsdp_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
 
     Returns ``step(params, opt_state, batch)`` plus ``step.place``.
     """
+    profile.listen()
     n = int(mesh.shape[axis_name])
 
     def _spec(p):
@@ -411,6 +415,7 @@ def make_fsdp_train_step(loss_fn, optimizer, mesh, axis_name="hvd",
 
     step = _structure_cached_step(_build)
 
+    @profile.phase(profile.SPAN_PLACE)
     def place(params, opt_state=None, batch=None):
         """Shards params per the FSDP rule, BUILDS the optimizer state
         under jit with sharded out_shardings (the full state is never

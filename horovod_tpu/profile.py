@@ -15,9 +15,16 @@ by the names the program gives them. Both are here:
   Python runs for them on the per-step path.
 - the control: `start(logdir)` / `stop()` switch the profiler on and off in
   the running process that holds the chip, any number of times;
-  `span(name)` is what host code puts around its own work, on the
-  profiler's clock (the clock of the device planes), and is one shared
+  `span(name)` is what host code puts around its own per-call work, on
+  the profiler's clock (the clock of the device planes), and is one shared
   no-op context while no trace is active.
+- getting going: `phase(name)` is what host code puts around work a process
+  does a bounded number of times (`hvd.init()`, building and placing a
+  step), and the runtime's own trace, lowering and compile-or-load of every
+  jitted function arrive through jax's monitoring hooks (`SETUP_SPANS`).
+  Both are kept in memory, always, on the clock of the profiler's host
+  plane: `phases()` is the record, `compiles()` what it says per function
+  (which one recompiled, and when), `dropped()` what the cap turned away.
 
 Nothing here reads the environment, and importing this module imports
 nothing, starts nothing and touches no device: jax is imported by the calls
@@ -28,6 +35,8 @@ import contextlib
 import glob
 import os
 import re
+import threading
+import time
 
 # Phases of `parallel.make_train_step`'s program. Every device operation of
 # a step lies under exactly one of them (the outermost on its scope path).
@@ -108,10 +117,34 @@ KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
            RING_ATTN_DQ, RING_ATTN_DKV, BN_STATS,
            BN_GRAD_STATS) + MOE_GMM_KERNELS + (HC_STAT, HC_STAT_DPHI)
 
-# Host spans of the program's only per-call Python.
+# Host spans a traced window shows: the program's only per-call Python
+# (`span`), and `step.place`, which is a `phase` (below) and so shows there
+# too.
 SPAN_PLACE = "hvd_place"                  # `step.place`
 SPAN_STEP_DISPATCH = "hvd_step_dispatch"  # zero1's per-call wrapper
 HOST_SPANS = (SPAN_PLACE, SPAN_STEP_DISPATCH)
+
+# Getting going: what a process does a bounded number of times before its
+# first step, each a `phase` where the work happens (docs/TRACING.md has
+# the table), and the three stages the runtime reports for every jitted
+# function (`_RUNTIME_SPANS`).
+SPAN_INIT = "hvd_init"                  # `hvd.init()`
+SPAN_NATIVE_BUILD = "hvd_native_build"  # the lock and `make` before a load
+SPAN_NATIVE_INIT = "hvd_native_init"    # rendezvous, background thread
+SPAN_MAKE_STEP = "hvd_make_step"        # `make_train_step`'s host side
+SPAN_JAX_TRACE = "jax_trace"            # Python -> jaxpr
+SPAN_JAX_LOWER = "jax_lower"            # jaxpr -> MLIR module
+SPAN_JAX_COMPILE = "jax_compile"        # XLA's compile, or the cache's load
+SETUP_SPANS = (SPAN_INIT, SPAN_NATIVE_BUILD, SPAN_NATIVE_INIT,
+               SPAN_MAKE_STEP, SPAN_PLACE, SPAN_JAX_TRACE, SPAN_JAX_LOWER,
+               SPAN_JAX_COMPILE)
+# The name the runtime knows `make_train_step`'s program by: that of the
+# function it jits (`parallel/train.py`; a test holds the two together).
+# The name is in the module's name, so in the compiled text and the key of
+# the compile cache: it is read here, never changed for the record's sake.
+STEP_FUN_NAME = "shard_step"
+# The record holds this many spans; what comes after is counted and dropped.
+PHASES_CAP = 4096
 
 # The runtime's profiler is one per process, so its state is too: the
 # directory of the trace `start` began, or None.
@@ -171,6 +204,190 @@ def span(name):
     import jax
 
     return jax.profiler.TraceAnnotation(name)
+
+
+# --- getting going: the spans a process makes a bounded number of times ----
+#
+# The record is on all the time, because nothing in it is per step: a dozen
+# phases a process, and from the runtime one span for each trace, lowering
+# and compile-or-load that no other runtime span encloses. Tracing a large
+# step calls thousands of small jitted functions (`jnp.mean`, `einsum`, a
+# flax module's own), each with a span of its own inside the step's: those
+# are counted on the enclosing span (`attrs["nested"]`), whose duration
+# holds them, and not kept. Spans are stamped with `time.time_ns()`, which
+# is the clock of the profiler's host plane (an event of `/host:CPU` starts
+# `start_ns` after the `profile_start_time` of the trace's
+# `Task Environment` plane, in Unix nanoseconds) and the clock of the
+# runtime's own spans (`time.time()`).
+_RUNTIME_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": SPAN_JAX_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": SPAN_JAX_LOWER,
+    "/jax/core/compile/backend_compile_duration": SPAN_JAX_COMPILE}
+# What the persistent cache says inside a `jax_compile`: a request that
+# consults it is a miss until the cache reports the hit.
+_CACHE_EVENTS = {"/jax/compilation_cache/compile_requests_use_cache": "miss",
+                 "/jax/compilation_cache/cache_hits": "hit"}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_WRAPPED_NAME = re.compile(r"^\w+\((.*)\)$")  # `jit(f)`, `pmap(f)` -> `f`
+
+_phases = []  # the record, in the order the spans began
+_dropped = 0
+_record_lock = threading.Lock()
+_listening = False
+
+
+class _Open(threading.local):
+    """What is open in this thread."""
+
+    def __init__(self):
+        # Indices into the record, innermost last; None for a span the cap
+        # dropped.
+        self.stack = []
+        # Runtime spans open, and the record's index of the outermost (the
+        # one that is kept), or None.
+        self.runtime = 0
+        self.outermost = None
+
+
+_open = _Open()
+
+
+def _begin(name, start_ns, attrs):
+    global _dropped
+    parent = next((i for i in reversed(_open.stack) if i is not None), None)
+    with _record_lock:
+        index = len(_phases) if len(_phases) < PHASES_CAP else None
+        if index is None:
+            _dropped += 1
+        else:
+            _phases.append({"name": name, "start_ns": start_ns,
+                            "end_ns": None, "parent": parent,
+                            "attrs": attrs})
+    _open.stack.append(index)
+    return index
+
+
+def _end(end_ns):
+    index = _open.stack.pop()
+    if index is not None:
+        _phases[index]["end_ns"] = end_ns
+
+
+@contextlib.contextmanager
+def phase(name):
+    """A span named `name` around work the process does a bounded number of
+    times: always recorded in memory (`phases()`), and while a trace is
+    active a `span` of the same name as well, so that the same work shows
+    on `/host:CPU` beside the device planes. Per-call work takes `span`,
+    which records nothing. Also a decorator."""
+    with span(name):
+        _begin(name, time.time_ns(), {})
+        try:
+            yield
+        finally:
+            _end(time.time_ns())
+
+
+def phases():
+    """The record, a copy: one dict per span with `name`, `start_ns` and
+    `end_ns` (Unix nanoseconds; `end_ns` None while it is open), `parent`
+    (the index of the span that was open in the same thread when it began;
+    None at the top) and `attrs` (a runtime span's `fun_name` and `nested`;
+    on `jax_compile` also `cache`: `hit`, `miss`, or `off` where no
+    persistent cache was consulted, and `retrieval_s` on a hit). It is the
+    process's, not the core's: `hvd.shutdown()` leaves it as it is."""
+    with _record_lock:
+        return [dict(p, attrs=dict(p["attrs"])) for p in _phases]
+
+
+def dropped():
+    """How many spans came after the record held `PHASES_CAP`."""
+    return _dropped
+
+
+def compiles(record=None):
+    """What the record (`phases()`, or the one given) says per function
+    name: `requests` (its `jax_compile` spans), the `hits` and `misses` of
+    the persistent cache among them, `seconds` by stage, and `recompiles`
+    (`requests` - 1) with the start of each in `recompiled_at_ns`: which
+    function was compiled again, and when. Functions that share a name
+    (`<lambda>`, two steps of one process) count together."""
+    out = {}
+    for p in phases() if record is None else record:
+        if p["name"] not in _RUNTIME_SPANS.values() or p["end_ns"] is None:
+            continue
+        fun = out.setdefault(p["attrs"]["fun_name"], {
+            "requests": 0, "hits": 0, "misses": 0, "recompiles": 0,
+            "recompiled_at_ns": [],
+            "seconds": dict.fromkeys(_RUNTIME_SPANS.values(), 0.0)})
+        fun["seconds"][p["name"]] += (p["end_ns"] - p["start_ns"]) / 1e9
+        if p["name"] == SPAN_JAX_COMPILE:
+            fun["requests"] += 1
+            fun["hits"] += p["attrs"]["cache"] == "hit"
+            fun["misses"] += p["attrs"]["cache"] == "miss"
+            if fun["requests"] > 1:
+                fun["recompiles"] += 1
+                fun["recompiled_at_ns"].append(p["start_ns"])
+    return out
+
+
+def _on_runtime_begin(event, value, fun_name=None, **_):
+    """jax's scalar listener: a trace, lowering or compile begins, at
+    `value` on `time.time()`."""
+    name = _RUNTIME_SPANS.get(event)
+    if name is None:
+        return
+    _open.runtime += 1
+    if _open.runtime > 1:
+        if _open.outermost is not None:
+            _phases[_open.outermost]["attrs"]["nested"] += 1
+        return
+    attrs = {"fun_name": _WRAPPED_NAME.sub(r"\1", str(fun_name)),
+             "nested": 0}
+    if name == SPAN_JAX_COMPILE:
+        attrs.update(cache="off", retrieval_s=None)
+    _open.outermost = _begin(name, int(value * 1e9), attrs)
+
+
+def _on_runtime_end(event, start, end, **_):
+    """jax's time-span listener: the span that began last has ended."""
+    if event not in _RUNTIME_SPANS or _open.runtime == 0:
+        return  # not ours, or it began before `listen`
+    _open.runtime -= 1
+    if _open.runtime == 0:
+        _end(int(end * 1e9))
+        _open.outermost = None
+
+
+def _on_cache_event(event, duration_secs=None, **_):
+    """jax's event and duration listener: what the persistent cache did
+    for the `jax_compile` that is open in this thread."""
+    if _open.outermost is None:
+        return
+    attrs = _phases[_open.outermost]["attrs"]
+    if "cache" not in attrs:
+        return  # a trace or a lowering is open, not a compile
+    if event == _CACHE_RETRIEVAL:
+        attrs["retrieval_s"] = duration_secs
+    elif event in _CACHE_EVENTS:
+        attrs["cache"] = _CACHE_EVENTS[event]
+
+
+def listen():
+    """Has the runtime report its traces, lowerings and compiles to the
+    record, from now on and once a process: `make_train_step` calls it. A
+    job whose first jitted function is not a train step calls it itself,
+    as early as it likes."""
+    global _listening
+    if _listening:
+        return
+    _listening = True
+    from jax import monitoring
+
+    monitoring.register_scalar_listener(_on_runtime_begin)
+    monitoring.register_event_time_span_listener(_on_runtime_end)
+    monitoring.register_event_listener(_on_cache_event)
+    monitoring.register_event_duration_secs_listener(_on_cache_event)
 
 
 # --- how far the step's gradient collectives are asynchronous -------------

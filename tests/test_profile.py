@@ -1,14 +1,17 @@
 """`hvd.profile` (docs/TRACING.md, "The in-`jit` step"): the profiler control
 on the CPU backend, and the names the program puts on the train step —
 scopes in the lowered step's scope paths, `name=` on every Pallas kernel —
-which change no number and put no Python on the per-step path."""
+which change no number and put no Python on the per-step path; and the
+record of getting going, which is kept in memory all the time."""
 
 import ast
 import contextlib
+import json
 import os
 import re
 import subprocess
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -250,6 +253,239 @@ def test_host_spans_of_the_zero1_wrapper_land_on_the_host_plane(tmp_path):
     finally:
         path = profile.stop()
     assert set(profile.HOST_SPANS) <= _host_event_names(path)
+
+
+# --------------------------------------------------------------------------
+# Getting going: the spans a process makes a bounded number of times
+# --------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def getting_going(tmp_path_factory):
+    """The record of `tests/profile_phases_worker.py`, run twice on one
+    fresh compile cache: {"cold": ..., "warm": ...}."""
+    from conftest import clean_worker_env
+
+    env = clean_worker_env({"JAX_COMPILATION_CACHE_DIR": str(
+        tmp_path_factory.mktemp("compile_cache"))})
+    out = {}
+    for run in ("cold", "warm"):
+        done = subprocess.run(
+            [sys.executable, os.path.join(REPO_ROOT, "tests",
+                                          "profile_phases_worker.py")],
+            env=env, cwd=REPO_ROOT, capture_output=True, text=True,
+            timeout=300)
+        assert done.returncode == 0, done.stderr[-2000:]
+        out[run] = json.loads(done.stdout.splitlines()[-1])
+    return out
+
+
+@pytest.fixture(autouse=True)
+def room_in_the_record(monkeypatch):
+    """The record is the process's, and a worker of the suite may have
+    filled it with other files' compiles before these tests run."""
+    monkeypatch.setattr(profile, "PHASES_CAP", len(profile.phases()) + 4096)
+
+
+def _named(record, name, fun_name=None):
+    return [(i, p) for i, p in enumerate(record) if p["name"] == name
+            and fun_name in (None, p["attrs"].get("fun_name"))]
+
+
+def test_record_holds_every_setup_span_under_its_parent(getting_going):
+    record = getting_going["cold"]["phases"]
+    assert {p["name"] for p in record} == set(profile.SETUP_SPANS)
+    (init, p), = _named(record, profile.SPAN_INIT)
+    assert p["parent"] is None
+    for inside in (profile.SPAN_NATIVE_BUILD, profile.SPAN_NATIVE_INIT):
+        (_, child), = _named(record, inside)
+        assert child["parent"] == init
+    (_, made), = _named(record, profile.SPAN_MAKE_STEP)
+    assert made["parent"] is None
+    assert len(_named(record, profile.SPAN_PLACE)) == 2
+    for stage in (profile.SPAN_JAX_TRACE, profile.SPAN_JAX_LOWER,
+                  profile.SPAN_JAX_COMPILE):
+        # the first call and the second batch shape
+        assert len(_named(record, stage, profile.STEP_FUN_NAME)) == 2, stage
+    assert getting_going["cold"]["dropped"] == 0
+
+
+@pytest.mark.parametrize("run", ["cold", "warm"])
+def test_children_lie_inside_their_parents(getting_going, run):
+    record = getting_going[run]["phases"]
+    inside = [0] * len(record)
+    for p in record:
+        assert p["end_ns"] >= p["start_ns"]
+        if p["parent"] is not None:
+            parent = record[p["parent"]]
+            assert parent["start_ns"] <= p["start_ns"]
+            assert p["end_ns"] <= parent["end_ns"]
+            inside[p["parent"]] += p["end_ns"] - p["start_ns"]
+    for p, children in zip(record, inside):  # self time is not negative
+        assert children <= p["end_ns"] - p["start_ns"], p
+
+
+def test_a_steps_nested_traces_are_counted_and_not_kept(getting_going):
+    record = getting_going["cold"]["phases"]
+    (_, first), _ = _named(record, profile.SPAN_JAX_TRACE,
+                           profile.STEP_FUN_NAME)
+    assert first["attrs"]["nested"] > 0  # `jnp.mean` and the like
+    for p in record:
+        if p["parent"] is not None:
+            assert record[p["parent"]]["name"].startswith("hvd_")
+
+
+def test_compile_is_a_miss_then_a_hit_on_one_cache(getting_going):
+    for run, cache in (("cold", "miss"), ("warm", "hit")):
+        found = _named(getting_going[run]["phases"],
+                       profile.SPAN_JAX_COMPILE, profile.STEP_FUN_NAME)
+        assert [p["attrs"]["cache"] for _, p in found] == [cache] * 2
+        for _, p in found:
+            assert (p["attrs"]["retrieval_s"] is not None) == (cache == "hit")
+        count = getting_going[run]["compiles"][profile.STEP_FUN_NAME]
+        assert (count["hits"], count["misses"]) == (
+            (0, 2) if cache == "miss" else (2, 0))
+
+
+def test_second_batch_shape_is_the_steps_one_recompile(getting_going):
+    ran = getting_going["cold"]
+    count = ran["compiles"][profile.STEP_FUN_NAME]
+    assert count["requests"] == 2 and count["recompiles"] == 1
+    (at,) = count["recompiled_at_ns"]
+    (_, first), (i, second) = _named(ran["phases"], profile.SPAN_JAX_COMPILE,
+                                     profile.STEP_FUN_NAME)
+    assert at == second["start_ns"] > first["end_ns"]
+    # the repeated call came before the second shape, and compiled nothing
+    assert i >= ran["before_second_shape"]
+    assert all(v > 0 for v in count["seconds"].values())
+    # `compiles` of a part of the record: the first shape alone
+    alone = profile.compiles(ran["phases"][:ran["before_second_shape"]])
+    assert alone[profile.STEP_FUN_NAME]["recompiles"] == 0
+
+
+def test_record_survives_shutdown(getting_going):
+    assert getting_going["cold"]["survived_shutdown"]
+
+
+def test_step_fun_name_is_the_jitted_functions():
+    step, state = _placed_step("transformer", "plain")
+    text = step.lower(*state).as_text()
+    assert "module @jit_%s " % profile.STEP_FUN_NAME in text
+
+
+def test_phase_is_recorded_while_idle_and_a_span_is_not():
+    assert not profile.active()
+    before = len(profile.phases())
+    with profile.span("per_call"):
+        with profile.phase("outer_phase"):
+            with profile.phase("inner_phase"):
+                pass
+    outer, inner = profile.phases()[before:]
+    assert (outer["name"], outer["parent"]) == ("outer_phase", None)
+    assert (inner["name"], inner["parent"]) == ("inner_phase", before)
+    assert outer["start_ns"] <= inner["start_ns"] <= inner["end_ns"] \
+        <= outer["end_ns"]
+
+
+def test_phase_of_another_thread_is_not_a_child_of_this_ones():
+    before = len(profile.phases())
+
+    def other():
+        with profile.phase("other_thread"):
+            pass
+
+    with profile.phase("this_thread"):
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+    mine, theirs = profile.phases()[before:]
+    assert theirs["name"] == "other_thread" and theirs["parent"] is None
+
+
+def test_phases_of_many_threads_lose_nothing():
+    threads, depth, rounds = 32, 3, 10
+    before = len(profile.phases())
+
+    def work(k):
+        for _ in range(rounds):
+            with contextlib.ExitStack() as stack:
+                for d in range(depth):
+                    stack.enter_context(profile.phase("t%d_d%d" % (k, d)))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        pool = [threading.Thread(target=work, args=(k,))
+                for k in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(timeout=60)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+    record = profile.phases()
+    assert len(record) == before + threads * depth * rounds
+    for p in record[before:]:
+        assert p["end_ns"] is not None
+        thread, d = p["name"].split("_d")
+        if d == "0":
+            assert p["parent"] is None
+        else:  # the span one level up, of the same thread
+            assert record[p["parent"]]["name"] == "%s_d%d" % (thread,
+                                                             int(d) - 1)
+
+
+def test_phase_agrees_with_its_twin_on_the_host_plane(tmp_path):
+    before = len(profile.phases())
+    profile.start(tmp_path)
+    try:
+        with profile.phase("traced_phase"):
+            _busy()
+    finally:
+        path = profile.stop()
+    # (a first `_busy()` compiles inside the phase: spans of its own)
+    (kept,) = [p for p in profile.phases()[before:]
+               if p["name"] == "traced_phase"]
+    data = jax.profiler.ProfileData.from_file(path)
+    (began,) = [dict(plane.stats)["profile_start_time"]
+                for plane in data.planes if plane.name == "Task Environment"]
+    (event,) = [e for plane in data.planes if plane.name == "/host:CPU"
+                for line in plane.lines for e in line.events
+                if e.name == "traced_phase"]
+    assert abs(began + event.start_ns - kept["start_ns"]) < 1e6
+    assert abs(event.duration_ns
+               - (kept["end_ns"] - kept["start_ns"])) < 1e6
+
+
+def test_cap_holds_and_counts_what_it_drops(monkeypatch):
+    held, dropped = len(profile.phases()), profile.dropped()
+    monkeypatch.setattr(profile, "PHASES_CAP", held + 1)
+    with profile.phase("kept"):
+        with profile.phase("dropped_1"):
+            with profile.phase("dropped_2"):
+                pass
+    assert len(profile.phases()) == held + 1
+    assert profile.dropped() == dropped + 2
+    assert profile.phases()[-1]["end_ns"] is not None
+    monkeypatch.setattr(profile, "PHASES_CAP", held + 2)
+    with profile.phase("after"):  # nothing was left open
+        pass
+    assert profile.phases()[-1]["parent"] is None
+
+
+def test_listening_twice_adds_one_listener():
+    from jax._src import monitoring
+
+    _placed_step("transformer", "plain")  # `make_train_step` listens
+    profile.listen()
+    for listeners, ours in (
+            (monitoring.get_scalar_listeners(), profile._on_runtime_begin),
+            (monitoring.get_event_time_span_listeners(),
+             profile._on_runtime_end),
+            (monitoring.get_event_listeners(), profile._on_cache_event),
+            (monitoring.get_event_duration_listeners(),
+             profile._on_cache_event)):
+        assert listeners.count(ours) == 1
 
 
 # --------------------------------------------------------------------------
