@@ -72,6 +72,9 @@ SIZES = dict(
     # (n, T, C, K) of a hyper-connection at the benchmark's `xing29b_1chip`:
     # four streams of 4096 tokens, 3584 wide, onto phi's 24 columns.
     hc=(4, 4096, 3584, 24),
+    # (T, k, D, experts, held) of a routed layer there: 4 x 4096
+    # assignments over 64 experts of which this rank holds 8.
+    moe_rows=(4096, 4, 3584, 64, 8),
     # (M, C) of the largest and the smallest BatchNorm of ResNet-50 at
     # batch 256.
     bn=[(256 * 112 * 112, 64), (256 * 7 * 7, 2048)],
@@ -500,6 +503,80 @@ def hc_stat_vs_jnp(n, T, C, K, dtype, seed):
              + tuple(errs[2:]) + (TOL["attn_bf16"],)))
 
 
+def moe_rows_vs_jnp(T, k, D, experts, held, dtype, seed):
+    """How a routed layer that holds `held` of its experts moves its rows
+    at this shape (`hvd.profile.moe_rows_plan`), that the two kernels it
+    names are in the program, and that on the chip the dispatch, the
+    combine and every gradient agree with jnp's gathers and selects over
+    all k*T rows, with the held experts' rows live (an eighth) and with
+    every row live."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import profile
+    from horovod_tpu.ops import moe_rows
+    from horovod_tpu.parallel import expert
+
+    plan = profile.moe_rows_plan(T, k, D, dtype, held=(0, held))
+    print("  %s, %s [%d, %d] x %d choices: %s, tiles of %d of the buffer's "
+          "%d rows, %d columns of the tokens resident, VMEM %.1f MiB, %d + "
+          "%d kernel calls a layer"
+          % (profile.MOE_ROWS, profile.MOE_SUM, T, D, k, plan["path"],
+             plan["tile_rows"], plan["buffer_rows"], plan["block_cols"],
+             plan["vmem_bytes"] / 2 ** 20, plan["calls_a_layer"]["forward"],
+             plan["calls_a_layer"]["backward"]), flush=True)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(keys[0], (T, D), dtype)
+    ys, g_xs = (jax.random.normal(key, (k * T, D), dtype)
+                for key in keys[1:3])
+    g_y = jax.random.normal(keys[3], (T, D), dtype)
+    weights = jax.random.uniform(keys[4], (k, T), jnp.float32)
+    chosen = jnp.argsort(jax.random.uniform(keys[5], (T, experts)),
+                         axis=1)[:, :k].astype(jnp.int32)
+    _, order, inv, sizes = expert.sort_assignments(chosen, experts)
+
+    def both(fn):
+        def f(x, ys, weights, n_live, g_xs, g_y):
+            out, vjp = jax.vjp(lambda x, ys, w: fn(x, ys, w, n_live),
+                               x, ys, weights)
+            return out + vjp((g_xs, g_y))
+        return jax.jit(f)
+
+    def through_the_op(x, ys, w, n_live):
+        return (moe_rows.dispatch(x, order, inv, n_live, k)[0],
+                moe_rows.combine(ys, w, order, inv, n_live))
+
+    def plain(x, ys, w, n_live):  # gathers and selects over all k*T rows
+        mine = (jnp.arange(k * T) < n_live)[:, None]
+        rows = jnp.where(mine, ys, 0)[inv].reshape(k, T, D)
+        y = jnp.einsum(
+            "ktd,kt->td", rows,
+            jnp.where(inv.reshape(k, T) < n_live, w, 0.0),
+            preferred_element_type=jnp.float32)
+        return jnp.where(mine, x[order % T], 0), y.astype(x.dtype)
+
+    kernels = profile.MOE_ROWS_KERNELS
+    args = (x, ys, weights, jnp.sum(sizes[:held]), g_xs, g_y)
+    compiled, text, secs = compile_with_text(both(through_the_op), *args)
+    check(plan["path"] == "kernel" and kernel_calls(text) == 4
+          and all(kernel_named(text, name) for name in kernels),
+          "%s: %d tpu_custom_call in the program (%s, forward and "
+          "backward; compiled in %.1f s)"
+          % (profile.MOE_ROWS, kernel_calls(text), ", ".join(kernels), secs))
+    for what, n_live in (("the held experts' rows", args[3]),
+                         ("every row", jnp.int32(k * T))):
+        args = args[:3] + (n_live,) + args[4:]
+        got, want = compiled(*args), both(plain)(*args)
+        live = (jnp.arange(k * T) < n_live)[:, None]
+        errs = [rel_err(jnp.where(live, g, 0) if g.shape[0] == k * T else g,
+                        r) for g, r in zip(got, want)]
+        check(max(errs) <= TOL["attn_bf16"],
+              "%s vs jnp on the chip, %d of %d rows live (%s): xs %.2e y "
+              "%.2e dx %.2e dys %.2e dw %.2e (max rel to max |ref|, tol "
+              "%.0e)" % ((profile.MOE_ROWS, int(n_live), k * T, what)
+                         + tuple(errs) + (TOL["attn_bf16"],)))
+
+
 def bn_case(M, C, seed):
     """fused_batch_norm_train (Pallas statistics and gradient-statistics
     kernels) and flax.linen.BatchNorm at one ResNet-50 shape: (fused,
@@ -640,6 +717,7 @@ def phase_kernels(args):
     print_flash_plan(B, H, H, L, D, False, jnp.bfloat16, shared_dim=D2)
 
     hc_stat_vs_jnp(*SIZES["hc"], jnp.bfloat16, args.seed)
+    moe_rows_vs_jnp(*SIZES["moe_rows"], jnp.bfloat16, args.seed)
 
     step, state = resnet_step(models.ResNet50PBN, mesh,
                               SIZES["resnet_batch"], args.seed)

@@ -15,6 +15,10 @@ played by Pallas TPU kernels:
   its projection onto the maps' columns from one read of the residual
   streams, custom VJP (the projection's gradient from one more; the
   streams' own gradient is jnp).
+* :mod:`.moe_rows` — the dispatch and the combine of a routed layer that
+  holds a part of the experts: the rows of its k*T-row buffer moved by
+  the count of those that are live, each op's transpose the other
+  kernel.
 """
 
 from .flash_attention import flash_attention  # noqa: F401

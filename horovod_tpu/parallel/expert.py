@@ -17,7 +17,9 @@ this is the TPU-first ``ep`` member of the parallelism family
   the sorted run of the held experts' rows is taken to the front of the
   k*T-row buffer, `count` groups are multiplied, and what the absent
   experts would add is left out (the one rank's share of an
-  expert-parallel layer, without its exchange).
+  expert-parallel layer, without its exchange). The rows there are live;
+  the dispatch and the combine are told their count and touch no other
+  (`ops/moe_rows.py`).
 * **shared** — an always-on gated expert beside the routed ones (`MoeMlp`
   with ``shared_dim``).
 * **sort** — the k*T assignments are sorted by expert (`sort_assignments`):
@@ -57,6 +59,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu import profile
+from horovod_tpu.ops import moe_rows
 from horovod_tpu.ops.grouped_matmul import grouped_matmul
 
 # Final key of every expert-sharded leaf: the contract between `MoeMlp`,
@@ -181,10 +184,14 @@ _rows_from_sorted.defvjp(_rows_from_sorted_fwd, _rows_from_sorted_bwd)
 
 def _experts(xs, w_in, w_out, w_gate, act, matmul):
     """The experts' feed-forward on rows `xs`; `matmul(rows, weights)` is
-    grouped (dropless) or batched (capacity)."""
-    h = matmul(xs, w_in)
+    grouped (dropless) or batched (capacity). `xs` may be a tuple: the
+    rows once for each of a gated expert's two first matmuls, from a
+    dispatch that sums their gradients itself (`ops/moe_rows.dispatch`)."""
+    if not isinstance(xs, tuple):
+        xs = (xs,)
+    h = matmul(xs[0], w_in)
     if w_gate is not None:
-        h = act(matmul(xs, w_gate)) * h
+        h = act(matmul(xs[-1], w_gate)) * h
     else:
         h = act(h)
     return matmul(h, w_out)
@@ -265,29 +272,27 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
         with jax.named_scope(profile.MOE_DISPATCH):
             # The held experts' rows are one run of the sorted order, from
             # `start`: turned to the front of the k*T-row buffer, which so
-            # holds them whatever the router does. The rows behind them
-            # belong to no group: the grouped matmuls leave them undefined,
-            # and they are zeroed on the way in (so that no gradient comes
-            # back through them) and on the way out.
+            # holds them whatever the router does. The `n_held` rows there
+            # are live; the rows behind them belong to no group, and the
+            # dispatch and the combine are told the count (`ops/moe_rows`:
+            # kernels that touch the live rows alone where a TPU runs them).
             start = jnp.sum(group_sizes[:first])
             sizes = group_sizes[first:first + count]
             n_held = jnp.sum(sizes)
             at = jnp.arange(kT, dtype=jnp.int32)
             order_h = order[(at + start) % kT]
             inv_h = (inv - start) % kT
-            mine = (at < n_held)[:, None]
-            xs = jnp.where(mine, _rows_to_sorted(x, order_h, inv_h, top_k),
-                           0)
+            xs = moe_rows.dispatch(x, order_h, inv_h, n_held, top_k,
+                                   1 if w_gate is None else 2)
         with jax.named_scope(profile.MOE_EXPERTS):
             ys = _experts(xs, w_in, w_out, w_gate, act,
                           lambda rows, w: grouped_matmul(rows, w, sizes))
         with jax.named_scope(profile.MOE_COMBINE):
-            rows = _rows_from_sorted(jnp.where(mine, ys, 0), order_h, inv_h)
-            weights = jnp.where((experts.T >= first)
-                                & (experts.T < first + count), weights, 0.0)
+            y = moe_rows.combine(ys, weights, order_h, inv_h, n_held)
         stats["dropped"] = jnp.zeros((), jnp.int32)
         stats["held"] = n_held
-    elif capacity_factor is None:
+        return y, stats
+    if capacity_factor is None:
         with jax.named_scope(profile.MOE_DISPATCH):
             xs = _rows_to_sorted(x, order, inv, top_k)
         with jax.named_scope(profile.MOE_EXPERTS):

@@ -113,9 +113,16 @@ MOE_GMM_KERNELS = (MOE_GMM, MOE_GMM_DLHS, MOE_GMM_DRHS)
 # the recomputations of `models/transformer.py` keep its two results.
 HC_STAT = "hvd_hc_stat"
 HC_STAT_DPHI = "hvd_hc_stat_dphi"  # backward: the gradient of phi
+# The rows of a routed layer that is told its live-row count, moved by the
+# count (`ops/moe_rows.py`, under `MOE_DISPATCH` / `MOE_COMBINE`). They are
+# no grouped matmuls: `MOE_GMM_KERNELS` does not hold them.
+MOE_ROWS = "hvd_moe_rows"  # out[s] = scale[s] * src[idx[s]], s live
+MOE_SUM = "hvd_moe_sum"    # y[t] = the sum of the live rows s of token t
+MOE_ROWS_KERNELS = (MOE_ROWS, MOE_SUM)
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
            RING_ATTN_DQ, RING_ATTN_DKV, BN_STATS,
-           BN_GRAD_STATS) + MOE_GMM_KERNELS + (HC_STAT, HC_STAT_DPHI)
+           BN_GRAD_STATS) + MOE_GMM_KERNELS + (HC_STAT, HC_STAT_DPHI) \
+    + MOE_ROWS_KERNELS
 
 # Host spans a traced window shows: the program's only per-call Python
 # (`span`), and `step.place`, which is a `phase` (below) and so shows there
@@ -556,5 +563,26 @@ def hc_plan(*args, **kwargs):
     `flash_plan` it needs no chip."""
     # `ops.hc_stat` imports this module for its kernel's name.
     from horovod_tpu.ops.hc_stat import hc_plan as plan
+
+    return plan(*args, **kwargs)
+
+
+# --- how a routed layer that holds a part of the experts moves its rows -----
+
+def moe_rows_plan(*args, **kwargs):
+    """How the dispatch and the combine of `parallel.moe_ffn` move the rows
+    of a call: `ops.moe_rows.rows_plan(T, k, D, dtype, held=...)` (its
+    arguments and result), here beside the other program-side counters. The
+    path (`kernel`: `MOE_ROWS` and `MOE_SUM`, which touch the live rows
+    alone, where the layer is told which experts it holds, the shapes fit
+    and a TPU runs it; `jnp`: gathers and sums over all k * T rows of the
+    buffer), a tile's rows, the columns of the token side resident at a
+    time, the buffer's rows, the VMEM bytes a kernel's blocks take and the
+    kernel calls a layer makes in each direction. How many of the buffer's
+    rows are live is the router's to decide each step:
+    `parallel.routing_stats`' `held_share` counts it. The ops run what this
+    returns."""
+    # `ops.moe_rows` imports this module for its kernels' names.
+    from horovod_tpu.ops.moe_rows import rows_plan as plan
 
     return plan(*args, **kwargs)

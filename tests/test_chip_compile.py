@@ -220,6 +220,35 @@ def test_hc_stat_compiles_for_v5e(one_chip):
         assert _named(text, name), name
 
 
+# A routed layer of Xing4.0 on one rank of eight: 4 x 4096 assignments of
+# 3584-wide tokens, of which the router decides how many are this rank's
+# (`benchmark`'s cell `xing29b_1chip`).
+def test_moe_rows_compile_for_v5e(one_chip, monkeypatch):
+    from horovod_tpu.ops import moe_rows
+
+    T, k, D = 4096, 4, 3584
+    bf16, i32 = jnp.bfloat16, jnp.int32
+
+    def fwd_bwd(x, ys, w, order, inv, n_live, g_xs, g_gate, g_y):
+        # the rows twice, as a gated expert's two first matmuls use them
+        out, vjp = jax.vjp(lambda x, ys, w: (
+            moe_rows.dispatch(x, order, inv, n_live, k, 2, False),
+            moe_rows.combine(ys, w, order, inv, n_live, False)), x, ys, w)
+        return out, vjp(((g_xs, g_gate), g_y))
+
+    text = _compile(one_chip, fwd_bwd, ((T, D), bf16), ((k * T, D), bf16),
+                    ((k, T), jnp.float32), ((k * T,), i32), ((k * T,), i32),
+                    ((), i32), ((k * T, D), bf16), ((k * T, D), bf16),
+                    ((T, D), bf16))
+    # each kernel once forward and once, as the other's transpose, backward
+    assert _kernels(text) == 4, text[:2000]
+    for name in profile.MOE_ROWS_KERNELS:
+        assert _named(text, name), name
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = profile.moe_rows_plan(T, k, D, bf16, held=(0, 8))
+    assert plan["path"] == "kernel" and plan["tile_rows"] == 1024
+
+
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------
 
 def _lm_step(topo, chips, monkeypatch):

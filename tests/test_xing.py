@@ -5,6 +5,7 @@ multi-token prediction module: the system against the plain reference
 `benchmark/references/xing.py` at small sizes, values and gradients."""
 
 import dataclasses
+import functools
 import importlib
 
 import numpy as np
@@ -334,6 +335,35 @@ def test_sigmoid_router_bias_moves_the_choice_and_not_the_weights():
         expert.route(logits, TOP_K, True, "softmax", bias)
 
 
+@pytest.fixture(params=["jnp", "kernel"])
+def rows_path(request, monkeypatch):
+    """How a held layer moves its rows (`ops/moe_rows.py`): by jnp, as the
+    CPU does, or by the two kernels in Pallas' interpreter, on tiles small
+    enough that a test's buffer crosses them. Returns `_layer`'s sizes:
+    the kernels take a width that is a multiple of 128."""
+    if request.param == "jnp":
+        return {}
+    from horovod_tpu.ops import grouped_matmul as gm
+    from horovod_tpu.ops import moe_rows as mr
+
+    monkeypatch.setattr(gm, "SUB_ROWS_DRHS", 16)
+    monkeypatch.setattr(mr, "TILE_ROWS", 32)
+    for name in ("dispatch", "combine"):
+        monkeypatch.setattr(mr, name, functools.partial(
+            getattr(mr, name), interpret=True))
+    return {"D": 128, "T": 64}
+
+
+def _rows_kernels(fn, *args):
+    """How often the jaxpr of `fn(*args)` calls each kernel of
+    `ops/moe_rows.py`."""
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return {name: _count(jaxpr, lambda eqn, name=name: (
+        eqn.primitive.name == "pallas_call"
+        and eqn.params["name"] == name))
+        for name in profile.MOE_ROWS_KERNELS}
+
+
 def _layer(E=16, D=32, F=24, T=96, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     return dict(
@@ -355,8 +385,10 @@ def _held_call(c, first, count, x=None, weights=None):
 
 
 @pytest.mark.parametrize("ranks", [1, 2, 4, 8])
-def test_the_shares_of_the_ranks_add_up_to_the_uncut_layer(ranks):
-    c = _layer()
+def test_the_shares_of_the_ranks_add_up_to_the_uncut_layer(ranks, rows_path):
+    c = _layer(**rows_path)
+    calls = _rows_kernels(lambda x: _held_call(c, 0, 2, x)[0], c["x"])
+    assert set(calls.values()) == {1 if rows_path else 0}
     E = c["router"].shape[1]
     whole, stats = expert.moe_ffn(
         c["x"], c["router"], c["w_up"], c["w_down"], capacity_factor=None,
@@ -374,8 +406,8 @@ def test_the_shares_of_the_ranks_add_up_to_the_uncut_layer(ranks):
         assert int(s["dropped"]) == 0
 
 
-def test_held_gradients_add_up_and_reach_no_absent_expert():
-    c = _layer()
+def test_held_gradients_add_up_and_reach_no_absent_expert(rows_path):
+    c = _layer(**rows_path)
     g = jax.random.normal(jax.random.PRNGKey(9), c["x"].shape)
 
     def whole(x, w):
@@ -394,6 +426,29 @@ def test_held_gradients_add_up_and_reach_no_absent_expert():
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         _close(a, b, 2e-5)
+
+
+def test_a_models_step_moves_the_held_rows_by_the_kernels_alone(rows_path):
+    """The lowered step of a small model with held experts: each routed
+    layer calls `hvd_moe_rows` and `hvd_moe_sum` once forward and once
+    backward, and no gather makes a [k*T, D] array. By jnp, off the TPU:
+    no kernel, and the rows in sorted order gathered once each way."""
+    cfg = _cfg(num_layers=2, embed_dim=128, moe_top_k=2)
+    model, params, tokens = _seeded(cfg)
+    step = lambda p: jax.grad(  # noqa: E731
+        lambda q: _system(model, q, tokens)[2])(p)
+    routed = 2  # the second block's and the module's
+    calls = _rows_kernels(step, params)
+    buffer = (cfg.moe_top_k * LENGTH, cfg.embed_dim)
+    gathers = _count(jax.make_jaxpr(step)(params).jaxpr, lambda eqn: (
+        eqn.primitive.name == "gather"
+        and eqn.outvars[0].aval.shape == buffer))
+    if rows_path:
+        assert calls == {profile.MOE_ROWS: 2 * routed,
+                         profile.MOE_SUM: 2 * routed}
+        assert gathers == 0
+    else:
+        assert set(calls.values()) == {0} and gathers == 2 * routed
 
 
 @pytest.mark.parametrize("shared", [True, False])
