@@ -1,0 +1,5 @@
+"""`flash_dkv_ms` for the SDAR cell (by the kernel's own name, under the
+block-diffusion mask; see `flash_dkv_ms.py`). With the two others it adds
+up to `flash_ms.sdar`."""
+
+from benchmark.layer_metrics.flash_dkv_ms import read  # noqa: F401

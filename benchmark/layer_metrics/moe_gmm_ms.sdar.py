@@ -1,0 +1,5 @@
+"""`moe_gmm_ms` for the SDAR cell: the grouped-matmul kernels over the rows
+the router's top-8 of 128 put on the 16 held experts (see `moe_gmm_ms.py`;
+`hvd_moe_rows` and `hvd_moe_sum` are not among them)."""
+
+from benchmark.layer_metrics.moe_gmm_ms import read  # noqa: F401

@@ -69,6 +69,11 @@ SIZES = dict(
     # benchmark's `xing29b_1chip` (printed only: that cell's own comparison
     # with its reference runs the kernels).
     attn_two_products=(1, 32, 4096, 128, 64),
+    # (B, H, G, data length, D, block) of block-diffusion training's
+    # attention at the benchmark's `sdar30b_1chip`: 32 heads on 4, a noisy
+    # and a clean copy of 4096 tokens (8192 positions) under the block mask
+    # the kernels take by rule.
+    attn_block_diffusion=(1, 32, 4, 4096, 128, 4),
     # (n, T, C, K) of a hyper-connection at the benchmark's `xing29b_1chip`:
     # four streams of 4096 tokens, 3584 wide, onto phi's 24 columns.
     hc=(4, 4096, 3584, 24),
@@ -352,10 +357,14 @@ def compile_with_text(jitted, *call_args):
     return compiled, compiled.as_text(), time.perf_counter() - t0
 
 
-def attention_case(B, H, G, L, D, rotary, dtype, seed):
+def attention_case(B, H, G, L, D, rotary, dtype, seed, mask=None):
     """flash_attention forward and backward alone at one shape, and
     _blockwise_reference doing the same: (name, kernel, reference,
-    (q, k, v, cotangent)), both jitted and returning (out, dq, dk, dv)."""
+    (q, k, v, cotangent)), both jitted and returning (out, dq, dk, dv).
+    `mask`: a rule in place of the causal triangle (L counts all its
+    positions); the reference is then `benchmark/references/sdar.py`'s
+    dense masked softmax, its mask made from the rule's three clauses, a
+    block of query rows at a time."""
     import jax
     import jax.numpy as jnp
 
@@ -370,9 +379,20 @@ def attention_case(B, H, G, L, D, rotary, dtype, seed):
     base = 10000.0 if rotary else None
 
     def kernel(q, k, v):
+        if mask is not None:
+            return flash_attention(q, k, v, mask=mask)
         return flash_attention(q, k, v, causal=True, rotary_base=base)
 
     def reference(q, k, v):
+        if mask is not None:
+            # Not the program's `rule.visible`: the dense mask written
+            # from the rule's three clauses, as the benchmark's reference
+            # has it, in float32.
+            from benchmark.references import sdar
+            f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+            return jax.vmap(lambda q, k, v: sdar.attention(
+                q, k, v, mask.length, mask.block, 0))(
+                    f32(q), f32(k), f32(v)).astype(q.dtype)
         t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
         return t(_blockwise_reference(t(q), t(k), t(v), D ** -0.5, True,
                                       base))
@@ -383,12 +403,15 @@ def attention_case(B, H, G, L, D, rotary, dtype, seed):
             return (out,) + vjp(w.astype(out.dtype))
         return jax.jit(f)
 
-    name = "B%d H%d G%d L%d D%d%s %s" % (
-        B, H, G, L, D, " rotary" if rotary else "", jnp.dtype(dtype).name)
+    name = "B%d H%d G%d L%d D%d%s%s %s" % (
+        B, H, G, L, D, " rotary" if rotary else "",
+        "" if mask is None else " %s(%d, %d)" % ((type(mask).__name__,)
+                                                + tuple(mask)),
+        jnp.dtype(dtype).name)
     return name, both(kernel), both(reference), (q, k, v, w)
 
 
-def flash_kernels(B, H, G, L, D, rotary, dtype):
+def flash_kernels(B, H, G, L, D, rotary, dtype, mask=None):
     """The names of the kernels a forward and backward of this shape run
     (`hvd.profile.flash_plan`): the forward's, then the backward's one
     (`hvd_flash_bwd`) or two."""
@@ -396,7 +419,7 @@ def flash_kernels(B, H, G, L, D, rotary, dtype):
 
     return [name for backward in (False, True)
             for name in profile.flash_plan(B, H, L, D, H // G, dtype,
-                                           backward, rotary)]
+                                           backward, rotary, mask=mask)]
 
 
 def model_flash_kernels(model, batch, length, dtype):
@@ -407,22 +430,28 @@ def model_flash_kernels(model, batch, length, dtype):
                          model["embed_dim"] // heads, False, dtype)
 
 
-def print_flash_plan(B, H, G, L, D, rotary, dtype, shared_dim=0):
+def print_flash_plan(B, H, G, L, D, rotary, dtype, shared_dim=0, mask=None):
     """Which path each flash kernel of this shape takes (`hvd.profile`);
-    `shared_dim`: the width of a second score product on one shared key."""
+    `shared_dim`: the width of a second score product on one shared key;
+    `mask`: a rule in place of the causal triangle, whose plans count the
+    score tiles each kernel visits, masks and skips."""
     from horovod_tpu import profile
 
     for backward in (False, True):
         for name, plan in profile.flash_plan(
                 B, H, L, D, H // G, dtype, backward, rotary,
-                shared_dim=shared_dim).items():
+                shared_dim=shared_dim, mask=mask).items():
             print("  %s: %s, blocks %d x %d, grid %s = %d steps, VMEM %.1f "
-                  "MiB%s" % (name, plan.path, plan.block_q, plan.block_k,
-                             plan.grid, plan.grid_steps,
-                             plan.vmem_bytes / 2 ** 20,
-                             "" if plan.vmem_limit_bytes is None else
-                             " of a limit of %.0f" % (
-                                 plan.vmem_limit_bytes / 2 ** 20)),
+                  "MiB%s%s" % (name, plan.path, plan.block_q, plan.block_k,
+                               plan.grid, plan.grid_steps,
+                               plan.vmem_bytes / 2 ** 20,
+                               "" if plan.vmem_limit_bytes is None else
+                               " of a limit of %.0f" % (
+                                   plan.vmem_limit_bytes / 2 ** 20),
+                               "" if mask is None else
+                               "; tiles visited %d (masked %d), skipped %d"
+                               % (plan.tiles_visited, plan.tiles_masked,
+                                  plan.tiles_skipped)),
                   flush=True)
 
 
@@ -715,6 +744,17 @@ def phase_kernels(args):
     print("  scores of two products, %d + %d wide on %d heads at L=%d:"
           % (D, D2, H, L), flush=True)
     print_flash_plan(B, H, H, L, D, False, jnp.bfloat16, shared_dim=D2)
+    from horovod_tpu.ops import BlockDiffusionMask
+
+    B, H, G, L, D, block = SIZES["attn_block_diffusion"]
+    rule = BlockDiffusionMask(L, block)
+    shape = (B, H, G, 2 * L, D, False, jnp.bfloat16)
+    print("  block diffusion, a noisy and a clean copy of %d tokens in "
+          "blocks of %d:" % (L, block), flush=True)
+    print_flash_plan(*shape, mask=rule)
+    attention_vs_reference(
+        attention_case(*shape, args.seed + len(SIZES["attn"]), mask=rule),
+        TOL["attn_bf16"], flash_kernels(*shape, mask=rule))
 
     hc_stat_vs_jnp(*SIZES["hc"], jnp.bfloat16, args.seed)
     moe_rows_vs_jnp(*SIZES["moe_rows"], jnp.bfloat16, args.seed)

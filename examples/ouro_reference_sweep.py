@@ -1,5 +1,6 @@
 """The readings behind the limits of `benchmark/builders/ouro.py` and of
-`benchmark/builders/xing.py` (`--workload xing29b_1chip`; any cell whose
+`benchmark/builders/xing.py` (`--workload xing29b_1chip`) and of
+`benchmark/builders/sdar.py` (`--workload sdar30b_1chip`; any cell whose
 builder returns `readings`): how far the bf16 system is from the float32
 reference at the benchmark's own sizes, over several seeds, three ways:
 
@@ -80,10 +81,20 @@ def main():
         k_param, k_tok = jax.random.split(jax.random.PRNGKey(seed))
         params = init_params(k_param)
         seq = make_tokens(k_tok)[0]
+        # A batch that carries a noise key beside its tokens (block
+        # diffusion: `sdar30b_1chip`) is read under the seed's own.
+        kw = {"key": built["make_noise_key"](k_tok)} \
+            if "make_noise_key" in built else {}
+        takes = inspect.signature(built["readings"]).parameters
+        # Where `readings` also holds the first gradient (`others`), two
+        # sets of parameters and a gradient do not fit a chip: the fp8
+        # system's gradient is the builder's `verify`'s to read.
+        low = {"others": False} if "others" in takes else {}
         out = {"seed": seed,
-               "bf16": built["readings"](params, params, seq),
-               "fp8": built["readings"](fp8(params), params, seq)}
-        if "ref_passes" in inspect.signature(built["readings"]).parameters:
+               "bf16": built["readings"](params, params, seq, **kw),
+               "fp8": built["readings"](fp8(params), params, seq, **kw,
+                                        **low)}
+        if "ref_passes" in takes:
             out["one_pass"] = built["readings"](params, params, seq,
                                                 ref_passes=1)
         print(json.dumps(out), flush=True)

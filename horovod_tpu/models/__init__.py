@@ -10,6 +10,9 @@
   `examples/tensorflow_word2vec.py`).
 * :mod:`.transformer` — decoder-only transformer with optional ring
   attention for long-context sequence parallelism (TPU-first extension).
+* :mod:`.block_diffusion` — block-diffusion training of the decoder (a
+  noisy and a clean copy of every sequence under one block mask, a 1/t
+  weighted masked loss): the batch, the noisy half, the noise's counters.
 * :mod:`.imagenet_extras` — VGG-16 and Inception V3, the other models in
   the reference's published 512-GPU scaling table
   (`docs/benchmarks.rst:13-14`).
@@ -25,4 +28,7 @@ from .mnist import MnistCNN  # noqa: F401
 from .word2vec import SkipGram  # noqa: F401
 from .transformer import (Transformer, TransformerConfig, Yarn,  # noqa: F401
                           hc_stats)
+from .block_diffusion import (block_diffusion_batch,  # noqa: F401
+                              block_diffusion_noisy_half,
+                              block_diffusion_stats)
 from .imagenet_extras import VGG16, InceptionV3  # noqa: F401

@@ -15,7 +15,7 @@ shards agree.
 import contextlib
 import dataclasses
 import math
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
@@ -104,8 +104,18 @@ class TransformerConfig:
     ep_axis: Optional[str] = None
     ep_size: int = 1
     # RMSNorm over the whole query and the whole key projection, before
-    # the split into heads and before rotary (OLMoE's QK-norm).
-    qk_norm: bool = False
+    # the split into heads and before rotary (True: OLMoE's QK-norm), or
+    # "head": over each head's `head_dim` alone, one learned [head_dim]
+    # scale for the queries and one for the keys shared by the heads
+    # (Qwen3's).
+    qk_norm: Union[bool, str] = False
+    # The attention mask of every block: None is causal; else a rule over
+    # (query position, key position) that `ops.flash_attention` knows
+    # (`ops.BlockDiffusionMask`: a noisy and a clean copy of the sequence
+    # side by side, `models.block_diffusion_batch`). The rule's positions
+    # are the ROWS of the sequence handed in; `positions` (rotary) are the
+    # caller's and may repeat. attention="dense" or "flash".
+    attention_mask: Optional[Any] = None
     norm_eps: float = 1e-6        # every RMSNorm's epsilon
     # Passes over the ONE stack of blocks, on the same weights (a looped
     # or universal transformer; Ouro's `total_ut_steps`): `norm_f` closes
@@ -185,7 +195,10 @@ class TransformerConfig:
             raise ValueError("dropless routing (moe_capacity_factor=None) "
                              "is local; with ep_axis set give a capacity "
                              "factor")
-        if self.qk_norm and self.tp_axis is not None:
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError("qk_norm=%r: True (the whole projection) or "
+                             "'head' (each head's own)" % (self.qk_norm,))
+        if self.qk_norm is True and self.tp_axis is not None:
             raise ValueError("qk_norm normalises over all heads' "
                              "projection, which tp_axis shards")
         if self.moe_experts is not None and self.tp_axis is not None:
@@ -229,7 +242,9 @@ class TransformerConfig:
             ("kv_lora_rank", self.kv_lora_rank is not None),
             ("hc_mult", self.hc_mult > 1),
             ("block_remat", self.block_remat > 0),
-            ("mtp_depth", self.mtp_depth > 0)) if on]
+            ("mtp_depth", self.mtp_depth > 0),
+            ("qk_norm='head'", self.qk_norm == "head"),
+            ("attention_mask", self.attention_mask is not None)) if on]
         for field in ("tp_axis", "sp_axis", "num_passes"):
             on = (self.num_passes > 1 if field == "num_passes"
                   else getattr(self, field) is not None)
@@ -237,6 +252,13 @@ class TransformerConfig:
                 raise ValueError("%s cannot be combined with %s (built for "
                                  "one stack of blocks on one device a "
                                  "replica)" % (field, ", ".join(new)))
+        if self.rope_fused and (self.qk_norm == "head"
+                                or self.attention_mask is not None):
+            # The kernels' own rotary knows positions 0..L-1 only.
+            raise ValueError("rope_fused cannot be combined with %s (built "
+                             "for positions as given)" % ", ".join(
+                                 n for n in new if n in (
+                                     "qk_norm='head'", "attention_mask")))
         if self.moe_held is not None and self.ep_axis is not None:
             raise ValueError("moe_held cannot be combined with ep_axis (a "
                              "device that is told which experts it holds "
@@ -253,6 +275,13 @@ class TransformerConfig:
             raise ValueError("kv_lora_rank (latent attention) runs with "
                              "attention='dense' or 'flash', not %r"
                              % self.attention)
+        if self.attention_mask is not None and (
+                self.attention not in ("dense", "flash")
+                or self.kv_lora_rank is not None):
+            raise ValueError("attention_mask runs with attention='dense' or "
+                             "'flash' and plain attention, not %r%s"
+                             % (self.attention, " with kv_lora_rank"
+                                if self.kv_lora_rank is not None else ""))
         if self.rope_yarn is not None and self.kv_lora_rank is None:
             raise ValueError("rope_yarn rescales latent attention's rotary "
                              "slice: give kv_lora_rank")
@@ -552,7 +581,11 @@ class Attention(nn.Module):
         q = heads(cfg.num_heads, "query")(x)
         k = heads(G, "key")(x)
         v = heads(G, "value")(x)
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":
+            # Over each head's own width: the norm acts on the last axis,
+            # its one scale [head_dim] shared by the heads.
+            q, k = _rms_norm(cfg, "q_norm")(q), _rms_norm(cfg, "k_norm")(k)
+        elif cfg.qk_norm:
             def whole(t, name):
                 flat = t.reshape(t.shape[:-2] + (-1,))
                 return _rms_norm(cfg, name)(flat).reshape(t.shape)
@@ -571,7 +604,10 @@ class Attention(nn.Module):
                                   rotary_base=rb)
         elif cfg.attention == "flash":
             from horovod_tpu.ops import flash_attention
-            o = flash_attention(q, k, v, causal=True, rotary_base=rb)
+            if cfg.attention_mask is not None:
+                o = flash_attention(q, k, v, mask=cfg.attention_mask)
+            else:
+                o = flash_attention(q, k, v, causal=True, rotary_base=rb)
         else:
             if G != cfg.num_heads:
                 k = jnp.repeat(k, cfg.num_heads // G, axis=2)
@@ -580,8 +616,10 @@ class Attention(nn.Module):
                            preferred_element_type=jnp.float32)
             s = s * (head_dim ** -0.5)
             L = s.shape[-1]
-            mask = lax.broadcasted_iota(jnp.int32, (L, L), 0) >= \
-                lax.broadcasted_iota(jnp.int32, (L, L), 1)
+            rows = lax.broadcasted_iota(jnp.int32, (L, L), 0)
+            cols = lax.broadcasted_iota(jnp.int32, (L, L), 1)
+            mask = rows >= cols if cfg.attention_mask is None \
+                else cfg.attention_mask.visible(rows, cols)
             s = jnp.where(mask[None, None], s, -jnp.inf)
             p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", p, v)

@@ -21,4 +21,4 @@ played by Pallas TPU kernels:
   kernel.
 """
 
-from .flash_attention import flash_attention  # noqa: F401
+from .flash_attention import BlockDiffusionMask, flash_attention  # noqa: F401

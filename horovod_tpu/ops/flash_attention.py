@@ -79,6 +79,19 @@ shared key's gradient leaves the kernel a head at a time and is summed over
 the heads outside. The gridded kernels have no such form: a call whose plan
 is not resident takes the blockwise jnp path.
 
+A mask by RULE over (query position, key position) (`mask=`; the first
+rule is `BlockDiffusionMask`): the rule says, for a tile of queries, which
+runs of key tiles hold a visible pair and which of those need the mask
+pass, as closed forms in the tile's position (`key_runs`, `query_runs`). The
+resident kernels loop over those runs alone (a tile the rule empties is
+never computed, a tile it fills runs with no mask pass, a tile it cuts is
+masked by `visible`); the gridded dK/dV kernel gates the compute by the same
+runs and clamps its q-side block index into them, so an empty tile is not
+fetched either. `flash_plan(..., mask=)` counts the tiles from the same
+runs. The forward and dQ take a rule in their resident form only (k and v
+whole in VMEM: at D=128 up to 24576 positions); where that does not fit the
+call is the blockwise jnp form.
+
 Backward: custom VJP over saved per-row log-sum-exp (FlashAttention-2
 style). On non-TPU backends the same kernels run in Pallas interpret
 mode (tests) or fall back to the blockwise JAX implementation.
@@ -89,6 +102,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 import jax.experimental.pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -392,6 +406,17 @@ def _q_index_map(bqp, bk, causal, rank2=False):
     return lambda b, j, i: (b, jnp.maximum(i, (j * bk) // bqp), 0)
 
 
+def _rule_q_index_map(rule, bqp, bk, rank2=False):
+    """`_q_index_map` for a rule: the q-block index clamped into the runs
+    of the step's k block (`_clamp_to_runs`)."""
+    def at(j, i):
+        return _clamp_to_runs(i, rule.query_runs(j * bk, bk, bqp))
+
+    if rank2:
+        return lambda b, j, i: (at(j, i), 0)
+    return lambda b, j, i: (b, at(j, i), 0)
+
+
 def _require_rows_block(L, preferred, group, what):
     b = _pick_rows_block(L, preferred, group)
     if b is None:
@@ -420,6 +445,170 @@ def _row_positions(L, group):
     return jnp.repeat(jnp.arange(L, dtype=jnp.int32), group)
 
 
+# --- a mask by rule --------------------------------------------------------
+#
+# `causal=True` is one rule, written into the kernels before there were
+# others (`_causal_mask`, `_walk_k_blocks`, `_walk_q_blocks`,
+# `_kv_index_map`), and stays as it is. Any other mask is an object with
+# three methods, all closed forms in positions, so that the kernels need no
+# table: `visible(rows, cols)` elementwise, and `key_runs` / `query_runs`,
+# which give for one tile of queries (keys) the RUNS of key (query) tiles
+# that hold a visible pair: (first tile, one past the last, whether the run
+# needs the mask pass), ascending, a fixed number of them, empty where
+# first >= end. The runs are evaluated with `xp=jnp` on the kernel's program
+# ids and with `xp=np` on all tiles at once by `flash_plan`.
+
+def _cdiv(a, n):
+    return (a + n - 1) // n
+
+
+class BlockDiffusionMask(collections.namedtuple("BlockDiffusionMask",
+                                                "length block")):
+    """The attention mask of block-diffusion training (BD3-LM, Arriola et
+    al., arXiv:2503.09573): the sequence is [x_t ; x_0], a NOISY copy at
+    positions 0..length-1 and the CLEAN one at length..2*length-1, both in
+    blocks of `block` tokens. With N(i) = i < length and B(i) = (i mod
+    length) // block, query i sees key j iff
+
+        (N(i) and N(j) and B(i) == B(j))          noisy on its own block
+        or (N(i) and not N(j) and B(j) < B(i))    noisy on earlier clean
+        or (not N(i) and not N(j) and B(j) <= B(i))   clean, block-causal
+
+    No row is empty. `block` divides `length`; a kernel's tiles must divide
+    `length` too (none straddles the halves)."""
+    __slots__ = ()
+
+    def _local(self, pos, xp):
+        """(is noisy, the block's index within its half) of positions."""
+        noisy = pos < self.length
+        return noisy, xp.where(noisy, pos, pos - self.length) // self.block
+
+    def visible(self, rows, cols, xp=jnp):
+        """Elementwise: whether the query at `rows` sees the key at `cols`
+        (int arrays that broadcast; the kernels pass [R, 1] and [1, C], so
+        that all but three passes run on thin vectors)."""
+        noisy_q, bq = self._local(rows, xp)
+        noisy_k, bk = self._local(cols, xp)
+        # As two comparisons of integer codes and one `or` (Mosaic has no
+        # select between masks): a noisy key is seen by the noisy queries
+        # of its block; a clean key where B(j) < B(i) for a noisy query,
+        # <= for a clean one.
+        own = xp.where(noisy_k, bk, -2) == xp.where(noisy_q, bq, -1)
+        reach = xp.where(noisy_q, bq, bq + 1)
+        return own | (xp.where(noisy_k, 2 ** 30, bk) < reach)
+
+    def key_runs(self, q_lo, n, bk, xp=jnp):
+        """The key tiles (of `bk` positions) that the queries [q_lo, q_lo +
+        n) see, as three runs: the noisy tiles of their own blocks (masked),
+        the clean tiles every one of them sees whole, the clean tiles some
+        of them see in part (masked)."""
+        L, b = self
+        noisy = q_lo < L
+        a = xp.where(noisy, q_lo, q_lo - L)
+        first, last = a // b * b, (a + n - 1) // b * b  # the blocks' starts
+        own_lo = first // bk
+        own_hi = xp.where(noisy, _cdiv(last + b, bk), own_lo)
+        all_hi = xp.where(noisy, first, first + b)   # clean keys below this
+        any_hi = xp.where(noisy, last, last + b)     # are seen by all / some
+        return ((own_lo, own_hi, True),
+                (L // bk, (L + all_hi) // bk, False),
+                ((L + all_hi) // bk, _cdiv(L + any_hi, bk), True))
+
+    def query_runs(self, k_lo, n, bqp, xp=jnp):
+        """The query tiles (of `bqp` positions) that see the keys [k_lo,
+        k_lo + n), as five runs: of noisy keys, the noisy tiles of their
+        own blocks (masked); of clean keys, the noisy tiles that see some of
+        them (masked) and all of them, then the clean tiles likewise."""
+        L, b = self
+        noisy = k_lo < L
+        c = xp.where(noisy, k_lo, k_lo - L)
+        first, last = c // b * b, (c + n - 1) // b * b
+        own_lo = first // bqp
+        own_hi = xp.where(noisy, _cdiv(last + b, bqp), own_lo)
+        half, end = L // bqp, 2 * L // bqp
+
+        def clean(t):  # nothing of a noisy key's tile: an empty run at end
+            return xp.where(noisy, end, t)
+
+        return ((own_lo, own_hi, True),
+                (clean((first + b) // bqp), clean(_cdiv(last + b, bqp)),
+                 True),
+                (clean(_cdiv(last + b, bqp)), clean(half), False),
+                (clean((L + first) // bqp), clean(_cdiv(L + last, bqp)),
+                 True),
+                (clean(_cdiv(L + last, bqp)), end, False))
+
+    def check(self, positions, bqp, bk):
+        """Refuses a call this rule does not describe."""
+        L, b = self
+        if L % b or positions != 2 * L:
+            raise ValueError(
+                "%r wants blocks that divide its length and 2 x length = %d "
+                "positions, not %d" % (self, 2 * L, positions))
+        if L % bqp or L % bk:
+            raise ValueError(
+                "flash blocks of %d query and %d key positions must divide "
+                "%r's length: no tile may straddle the two halves"
+                % (bqp, bk, self))
+
+
+def _rule_mask(s, rule, q_off, kv_off, fill, group=1):
+    """`_causal_mask` for a rule: rows and columns as thin vectors."""
+    block_q, block_k = s.shape
+    riota = lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    rows = q_off + (riota // group if group > 1 else riota)
+    cols = kv_off + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    return jnp.where(rule.visible(rows, cols), s, fill)
+
+
+def _walk_runs(visit, carry, runs):
+    """``visit(j, carry, masked)`` over the tiles of `runs`, a loop a run:
+    what `_walk_k_blocks` and `_walk_q_blocks` are to the causal rule."""
+    for lo, hi, masked in runs:
+        carry = lax.fori_loop(
+            lo, hi, lambda j, c, masked=masked: visit(j, c, masked), carry)
+    return carry
+
+
+def _in_runs(i, runs, masked_only=False):
+    """Whether tile `i` lies in one of `runs` (in a masked one)."""
+    hit = i < 0
+    for lo, hi, masked in runs:
+        if masked or not masked_only:
+            hit = hit | ((lo <= i) & (i < hi))
+    return hit
+
+
+def _clamp_to_runs(i, runs):
+    """The tile a gridded step fetches: its own where a run holds it, else
+    the next visited one (so it is there when its step comes), else the last
+    visited. A revisited index costs no DMA."""
+    big = 2 ** 30
+    nxt, last = big, 0
+    for lo, hi, _ in runs:
+        nxt = jnp.minimum(nxt, jnp.where((hi > lo) & (hi > i),
+                                         jnp.maximum(lo, i), big))
+        last = jnp.maximum(last, jnp.where(hi > lo, hi - 1, 0))
+    return jnp.where(nxt < big, nxt, last)
+
+
+def _rule_tiles(rule, kernel, positions, bqp, bk):
+    """(visited, masked, skipped) score tiles of ONE (batch, kv head) of a
+    kernel under `rule`, from the runs the kernel walks."""
+    if kernel in _K_HELD:
+        runs = rule.query_runs(np.arange(0, positions, bk), bk, bqp, np)
+    else:
+        runs = rule.key_runs(np.arange(0, positions, bqp), bqp, bk, np)
+    def tiles(masked_only):
+        return int(sum(np.sum(np.maximum(hi - lo, 0))
+                       for lo, hi, masked in runs
+                       if masked or not masked_only))
+
+    visited = tiles(False)
+    return (visited, tiles(True),
+            (positions // bqp) * (positions // bk) - visited)
+
+
 # --- the plan: resident or gridded, per kernel ----------------------------
 #
 # One algorithm with one parameter that follows from the call's shapes: is
@@ -441,7 +630,8 @@ _DEFAULT_VMEM_LIMIT = 16 * 2 ** 20
 FlashKernelPlan = collections.namedtuple(
     "FlashKernelPlan",
     "path block_q block_k grid grid_steps resident_bytes vmem_bytes "
-    "vmem_limit_bytes")
+    "vmem_limit_bytes tiles_visited tiles_masked tiles_skipped",
+    defaults=(None, None, None))
 FlashKernelPlan.__doc__ = """How one flash kernel of a call runs.
 
 path: "resident" (grid (B*G, blocks); the other sequence whole in VMEM,
@@ -454,7 +644,10 @@ pipeline buffers counted. vmem_limit_bytes: what the call passes to
 Mosaic, from its own sum — the buffers, the kernel's values (s, p, dp,
 ds, the carried state) and a quarter more — never under the compiler's
 default (None: the default itself, which every gridded block table
-fits)."""
+fits). tiles_visited / tiles_masked / tiles_skipped (with `mask=` only, else
+None): the [block_q // group, block_k] score tiles of one call, over all
+batch x kv heads, that the kernel computes, that it computes with the
+rule's mask pass, and that it neither computes nor fetches."""
 
 
 def _vmem(rows, cols, itemsize):
@@ -515,7 +708,7 @@ def _resident_blocks(D, L, group, kernel):
 
 
 def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
-                 block_k, vmem_budget, D2=0):
+                 block_k, vmem_budget, D2=0, rule=None):
     backward = kernel != profile.FLASH_FWD
     dkv = kernel in _K_HELD
     n_q, n_k, n_stripes = _OPERANDS[kernel]
@@ -533,10 +726,16 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
     def k_side(n):
         return _vmem(n, D, n_k * isz + tables) + _vmem(n, D2, n_k2 * isz)
 
+    # Under a rule the blocks divide ITS length, so that no tile straddles
+    # the halves of the sequence.
+    tiled = L if rule is None else rule.length
+
     def blocks(preferred):
-        bq = block_q or _pick_rows_block(L, preferred[0], group)
-        bk = block_k or _pick_block(L, preferred[1])
+        bq = block_q or _pick_rows_block(tiled, preferred[0], group)
+        bk = block_k or _pick_block(tiled, preferred[1])
         _check_blocks(rows, L, bq, bk, group)
+        if rule is not None:
+            rule.check(L, bq // group, bk)
         return bq, bk
 
     whole = q_side(rows) if dkv else k_side(L)
@@ -578,7 +777,7 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
 
 def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
                rotary=False, block_q=None, block_k=None,
-               vmem_budget=RESIDENT_VMEM_BUDGET, shared_dim=0):
+               vmem_budget=RESIDENT_VMEM_BUDGET, shared_dim=0, mask=None):
     """How `flash_attention` runs q [B, H, L, D] against H // group kv
     heads: {kernel name: FlashKernelPlan} for the forward kernel
     (`hvd_flash_fwd`) or, with ``backward``, the backward: ONE kernel
@@ -606,18 +805,42 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     the result is ``{}``, no kernel at all, and the call is the blockwise
     jnp form.
 
+    ``mask``: a rule (`BlockDiffusionMask`) in place of the causal
+    triangle; L counts ALL positions of the call (2 x the rule's length).
+    The same choice of path and kernels, with blocks that divide the rule's
+    length, and every plan says how many score tiles its kernel visits,
+    masks and skips. The forward and dQ take a rule in their resident form
+    only and the one-kernel backward as ever; dK/dV alone also gridded (at
+    D=128 in bf16 with 8 heads a kv head and 8192 positions: the forward
+    and dQ resident on k + v, 8 MiB; dK/dV gridded, q + dO of a kv head
+    being 64 MiB). Where the forward or dQ would be gridded the result is
+    ``{}`` and the call is the blockwise jnp form; fused rotary and a second
+    score product are refused beside a rule.
+
     `_pallas_forward_lse` and `_pallas_backward` run what this returns,
     so it is also the counter that says which path a program took
     (docs/TRACING.md; `hvd.profile.flash_plan`)."""
     BG, rows = B * H // group, L * group
     isz = jnp.dtype(dtype).itemsize
 
+    if mask is not None and (rotary or shared_dim):
+        raise ValueError("a mask by rule repeats positions and has one "
+                         "score product: rotate outside the kernels")
+
     def plan(kernel):
-        return _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary,
-                            block_q, block_k, vmem_budget, shared_dim)
+        p = _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary,
+                         block_q, block_k, vmem_budget, shared_dim, mask)
+        if mask is None or p is None:
+            return p
+        return p._replace(**dict(zip(
+            ("tiles_visited", "tiles_masked", "tiles_skipped"),
+            (BG * n for n in _rule_tiles(mask, kernel, L,
+                                         p.block_q // group, p.block_k)))))
 
     def resident_or_none(plans):
-        if shared_dim and any(p.path != "resident" for p in plans.values()):
+        if any(p.path != "resident" for name, p in plans.items()
+               if shared_dim or (mask is not None
+                                 and name != profile.FLASH_DKV)):
             return {}
         return plans
 
@@ -697,13 +920,36 @@ def _walk_q_blocks(visit, carry, kj, bqp, bk, num_qb, causal):
                          lambda i, c: visit(i, c, False), carry)
 
 
+def _walk_k(visit, carry, qi, bqp, bk, num_kb, causal, rule):
+    """The k blocks q block `qi` sees: a rule's runs, or the causal walk."""
+    if rule is None:
+        return _walk_k_blocks(visit, carry, qi, bqp, bk, num_kb, causal)
+    return _walk_runs(visit, carry, rule.key_runs(qi * bqp, bqp, bk))
+
+
+def _walk_q(visit, carry, kj, bqp, bk, num_qb, causal, rule):
+    """The q blocks k block `kj` is seen by: likewise."""
+    if rule is None:
+        return _walk_q_blocks(visit, carry, kj, bqp, bk, num_qb, causal)
+    return _walk_runs(visit, carry, rule.query_runs(kj * bk, bk, bqp))
+
+
+def _mask_tile(s, rule, q_off, kv_off, group):
+    """The mask pass of a tile the walk marked: the rule's, or causal."""
+    if rule is None:
+        return _causal_mask(s, q_off, kv_off, -jnp.inf, group)
+    return _rule_mask(s, rule, q_off, kv_off, -jnp.inf, group)
+
+
 def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
-                         shared=False):
+                         shared=False, rule=None):
     # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [L, D], fetched once per b (the
     # block index does not change across q blocks); lse_ref [BQ, 8]. The
     # online-softmax state (acc, m, l) is carried by the loop. Under
     # fused rotary kc/ks are whole [L, D] tables, q is rotated once.
     # `shared`: q2_ref [BQ, D2] and k2_ref [L, D2] follow v (`_scores2`).
+    # `rule`: a mask by rule in place of the causal triangle; the loop
+    # walks the rule's runs of k blocks (`_walk_runs`).
     if shared:
         q2_ref, k2_ref = refs[3:5]
         refs = refs[:3] + refs[5:]
@@ -728,30 +974,36 @@ def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
         s = _scores2(q, k, q2, k2_ref[at, :], scale) if shared \
             else _scores(q, k, scale)
         if masked:
-            s = _causal_mask(s, qi * bqp, j * bk, -jnp.inf, group)
+            s = _mask_tile(s, rule, qi * bqp, j * bk, group)
         # The first visited block covers every row (ascending order), so
         # no row's running max is still -inf after it.
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
+        m_base = m_new
+        if masked and rule is not None:
+            # A rule's cut tile may hold no key of a row that later tiles
+            # do: its max is then still -inf, and the row's terms must read
+            # exp(-inf) = 0, not exp(-inf + inf).
+            m_base = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        alpha = jnp.exp(m_prev - m_base)
+        p = jnp.exp(s - m_base)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[at, :], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return acc, m_new, l_new
 
-    acc, m, l = _walk_k_blocks(
+    acc, m, l = _walk_k(
         visit, (jnp.zeros((bq, D), jnp.float32),
                 jnp.full((bq, 1), -jnp.inf, jnp.float32),
                 jnp.zeros((bq, 1), jnp.float32)),
-        qi, bqp, bk, k_ref.shape[0] // bk, causal)
+        qi, bqp, bk, k_ref.shape[0] // bk, causal, rule)
     l = jnp.where(l == 0.0, 1.0, l)  # rows with no visible keys
     o_ref[...] = (acc / l).astype(o_ref.dtype)
     lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape)
 
 
 def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
-                            shared=False):
+                            shared=False, rule=None):
     """dQ with k and v whole in VMEM: `_bwd_dq_kernel`'s arithmetic,
     the dq accumulator carried by the loop. `shared`: q2_ref [BQ, D2] and
     k2_ref [L, D2] follow v and dq2_ref [BQ, D2] is the last result; the
@@ -784,7 +1036,7 @@ def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
         s = _scores2(q, k, q2, k2_ref[at, :], scale) if shared \
             else _scores(q, k, scale)
         if masked:
-            s = _causal_mask(s, qi * bqp, j * bk, -jnp.inf, group)
+            s = _mask_tile(s, rule, qi * bqp, j * bk, group)
         p = jnp.exp(s - lse)  # masked entries: exp(-inf) = 0
         dp = jax.lax.dot_general(
             do, v_ref[at, :], (((1,), (1,)), ((), ())),
@@ -800,9 +1052,9 @@ def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
         return dq
 
     zeros = jnp.zeros(q.shape, jnp.float32)
-    dq = _walk_k_blocks(
+    dq = _walk_k(
         visit, (zeros, jnp.zeros(q2.shape, jnp.float32)) if shared
-        else zeros, qi, bqp, bk, k_ref.shape[0] // bk, causal)
+        else zeros, qi, bqp, bk, k_ref.shape[0] // bk, causal, rule)
     if shared:
         dq, dq2 = dq
         dq2_ref[...] = dq2.astype(dq2_ref.dtype)
@@ -812,7 +1064,7 @@ def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
 
 
 def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
-                             with_dq, shared=False):
+                             with_dq, shared=False, rule=None):
     """dK/dV with q, dO, lse and delta whole in VMEM: `_bwd_dkv_kernel`'s
     arithmetic, the dk and dv accumulators carried by the loop; k is
     rotated once, q per visit. ``with_dq`` (`hvd_flash_bwd`): the whole
@@ -870,7 +1122,7 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
         else:
             s = _scores(q, k, scale)
         if masked:
-            s = _causal_mask(s, i * bqp, kj * bk, -jnp.inf, group)
+            s = _mask_tile(s, rule, i * bqp, kj * bk, group)
         p = jnp.exp(s - lse_ref[at, :1])  # masked entries: exp(-inf) = 0
         dv = dv + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -897,10 +1149,10 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
         return dk, dv
 
     zeros = jnp.zeros(k.shape, jnp.float32)
-    dk, dv, *dk2 = _walk_q_blocks(
+    dk, dv, *dk2 = _walk_q(
         visit, (zeros, zeros) + (
             (jnp.zeros(k2.shape, jnp.float32),) if shared else ()),
-        kj, bqp, bk, q_ref.shape[0] // bq, causal)
+        kj, bqp, bk, q_ref.shape[0] // bq, causal, rule)
     if shared:
         dk2_ref[...] = dk2[0].astype(dk2_ref.dtype)
     if rotary:
@@ -938,9 +1190,35 @@ def _shared_operands(shared, B, G, group, plans):
             lambda b: b // G)
 
 
+_RULED_CALLS = {}  # {what decides a ruled kernel's call: its jitted call}
+
+
+def _ruled_call(call, name, rule, plan, inputs, *static):
+    """`call` (a `pl.pallas_call`) as it is where `rule` is None (the
+    accepted cells' program text is pinned); under a rule JITTED, one
+    function for all calls that `static`, the plan and the operands'
+    shapes make alike, as `ops/moe_rows.py`'s are: a model's blocks share
+    one trace and one lowering of the kernel (a quarter of a second of a
+    step's lowering each time), and the call site's scope path still
+    reaches each call's `op_name`. Only the kernel's own call is inside:
+    the change of layout around it stays where XLA fuses it with the
+    caller's transposes (jitted with it, a layer kept 72 MiB more)."""
+    if rule is None:
+        return call
+    key = (name, rule, plan, static,
+           tuple((x.shape, x.dtype.name) for x in inputs))
+    if key not in _RULED_CALLS:
+        def ruled(*operands):
+            return call(*operands)
+        ruled.__name__ = ruled.__qualname__ = "_ruled_" + name
+        _RULED_CALLS[key] = jax.jit(ruled)
+    return _RULED_CALLS[key]
+
+
 def _pallas_forward_lse(q, k, v, scale, causal, interpret,
                         block_q=None, block_k=None, rotary_base=None,
-                        vmem_budget=RESIDENT_VMEM_BUDGET, shared=None):
+                        vmem_budget=RESIDENT_VMEM_BUDGET, shared=None,
+                        rule=None):
     """q [B, H, L, D], k/v [B, G, L, D] with G | H. Returns
     (out [B,H,L,D], lse [B*G, L*group, 8] f32) — lse is the per-row
     log-sum-exp the backward kernels need, in the grouped-rows layout
@@ -949,7 +1227,8 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     slower, 128-wide wastes 16x the memory). `flash_plan` chooses the
     path and the blocks; ``vmem_budget`` is its argument (tests and the
     block sweep force a path with it). ``shared``: (q2 [B, H, L, D2], k2
-    [B, 1, L, D2]), the second score product's operands."""
+    [B, 1, L, D2]), the second score product's operands. ``rule``: a mask
+    by rule in place of ``causal`` (resident only: `flash_plan`)."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -959,9 +1238,13 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     rotary = rotary_base is not None
     D2 = shared[0].shape[-1] if shared else 0
     plans = flash_plan(B, H, L, D, group, q.dtype, False, rotary, block_q,
-                       block_k, vmem_budget, D2)
+                       block_k, vmem_budget, D2, rule)
     if shared:
         q2f, k2f, of_batch = _shared_operands(shared, B, G, group, plans)
+    if rule is not None and not plans:
+        raise NotImplementedError(
+            "a mask by rule exists in the resident forward kernel only; "
+            "`flash_plan(..., mask=%r)` says this call's is not" % (rule,))
     plan = plans[profile.FLASH_FWD]
     bq, bk = plan.block_q, plan.block_k
     rows = L * group
@@ -978,7 +1261,9 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
         kernel = functools.partial(_fwd_resident_kernel, scale=scale,
                                    causal=causal, bk=bk, bqp=bqp,
                                    group=group, rotary=rotary,
-                                   **({"shared": True} if shared else {}))
+                                   **({"shared": True} if shared else {}),
+                                   **({} if rule is None
+                                      else {"rule": rule}))
         scratch = []
     else:
         kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -996,7 +1281,7 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
                     pl.BlockSpec((None, L, D2),
                                  lambda b, i: (of_batch(b), 0, 0))] \
         if shared else []
-    out, lse = pl.pallas_call(
+    out, lse = _ruled_call(pl.pallas_call(
         kernel,
         name=profile.FLASH_FWD,
         grid=plan.grid,
@@ -1010,7 +1295,7 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
         scratch_shapes=scratch,
         compiler_params=_compiler_params(plan),
         interpret=interpret,
-    )(*inputs)
+    ), profile.FLASH_FWD, rule, plan, inputs, scale, interpret)(*inputs)
     return _from_rows(out, B, group), lse
 
 
@@ -1487,7 +1772,8 @@ def _bwd_dq_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
         dq_ref[...] = dq.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary):
+def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary,
+                    rule=None):
     """dK/dV: grid (bg, k-block, q-block), q innermost sequential.
     dV = sum_q P^T.dO; dK = sum_q dS^T.Q * scale. In the grouped GQA
     layout the q rows interleave the whole head group, so the group
@@ -1514,9 +1800,15 @@ def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary):
         if rotary:
             krot_ref[...] = _rot_apply(k_ref[...], kc_ref, ks_ref)
 
-    # Causal: q blocks entirely above this k block see none of it.
-    visible = (qi * bqp + (bqp - 1) >= kj * block_k) if causal \
-        else qi >= 0
+    # Causal: q blocks entirely above this k block see none of it. A rule:
+    # the q blocks of its runs for this k block (`_rule_q_index_map`
+    # fetches no others), the mask pass on the runs that ask for it.
+    if rule is not None:
+        runs = rule.query_runs(kj * block_k, block_k, bqp)
+        visible = _in_runs(qi, runs)
+    else:
+        visible = (qi * bqp + (bqp - 1) >= kj * block_k) if causal \
+            else qi >= 0
 
     @pl.when(visible)
     def _compute():
@@ -1526,9 +1818,16 @@ def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary):
         else:
             q = q_ref[...]
             k = k_ref[...]
-        s = _masked_scores(q, k, scale, causal,
-                           q_off=qi * bqp, kv_off=kj * block_k,
-                           fill=-jnp.inf, group=group)
+        if rule is not None:
+            s = jax.lax.cond(
+                _in_runs(qi, runs, masked_only=True),
+                lambda s: _rule_mask(s, rule, qi * bqp, kj * block_k,
+                                     -jnp.inf, group),
+                lambda s: s, _scores(q, k, scale))
+        else:
+            s = _masked_scores(q, k, scale, causal,
+                               q_off=qi * bqp, kv_off=kj * block_k,
+                               fill=-jnp.inf, group=group)
         p = jnp.exp(s - lse_ref[:, :1])  # masked entries: exp(-inf) = 0
         p_lo = p.astype(do_ref.dtype)
         dv_acc[...] += jax.lax.dot_general(
@@ -1553,12 +1852,14 @@ def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary):
 
 def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
                      block_q=None, block_k=None, rotary_base=None,
-                     vmem_budget=RESIDENT_VMEM_BUDGET, shared=None):
+                     vmem_budget=RESIDENT_VMEM_BUDGET, shared=None,
+                     rule=None):
     """Pallas backward: q/out/g [B,H,L,D], k/v [B,G,L,D], lse in the
     grouped-rows layout. Returns (dq [B,H,L,D], dk/dv [B,G,L,D]) in the
     inputs' dtypes. Path and blocks per kernel from `flash_plan`. With
     ``shared`` = (q2 [B,H,L,D2], k2 [B,1,L,D2]) also (dq2, dk2) of those
-    shapes, dk2 summed over the heads in f32."""
+    shapes, dk2 summed over the heads in f32. ``rule``: a mask by rule in
+    place of ``causal`` (dQ resident only, dK/dV in either form)."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -1578,13 +1879,20 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     # _default_blocks for the swept preferences.
     D2 = shared[0].shape[-1] if shared else 0
     plans = flash_plan(B, H, L, D, group, q.dtype, True, rotary, block_q,
-                       block_k, vmem_budget, D2)
+                       block_k, vmem_budget, D2, rule)
     if shared:
         q2f, k2f, of_batch = _shared_operands(shared, B, G, group, plans)
         extra = {"shared": True}
         dq2_shape = jax.ShapeDtypeStruct((B * G, rows, D2), q2f.dtype)
     else:
         extra = {}
+    if rule is not None:
+        if not plans:
+            raise NotImplementedError(
+                "a mask by rule exists in the resident dQ kernel only; "
+                "`flash_plan(..., backward=True, mask=%r)` says this "
+                "call's is not" % (rule,))
+        extra = {"rule": rule}
     if rotary:
         qc, qs = _rope_tables(_row_positions(L, group), D, rotary_base)
         kc, ks = _rope_tables(jnp.arange(L, dtype=jnp.int32), D,
@@ -1619,7 +1927,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
         q2_spec = pl.BlockSpec((None, bq, D2), q_im)
-        dq = pl.pallas_call(
+        dq = _ruled_call(pl.pallas_call(
             kernel,
             name=profile.FLASH_DQ,
             grid=plan.grid,
@@ -1634,7 +1942,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
             scratch_shapes=scratch,
             compiler_params=_compiler_params(plan),
             interpret=interpret,
-        )(*inputs)
+        ), profile.FLASH_DQ, rule, plan, inputs, scale, interpret)(*inputs)
         if shared:
             dq, dq2 = dq
 
@@ -1659,9 +1967,12 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     else:
         kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
                                    causal=causal, num_qb=rows // bq,
-                                   bqp=bqp, group=group, rotary=rotary)
+                                   bqp=bqp, group=group, rotary=rotary,
+                                   **({} if rule is None
+                                      else {"rule": rule}))
         k_im = lambda b, j, i: (b, j, 0)                    # noqa: E731
-        q_im = _q_index_map(bqp, bk, causal)
+        q_im = _q_index_map(bqp, bk, causal) if rule is None \
+            else _rule_q_index_map(rule, bqp, bk)
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
         tq_spec = pl.BlockSpec((bq, D), _q_index_map(bqp, bk, causal,
@@ -1672,7 +1983,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
             [pltpu.VMEM((bk, D), k.dtype)] if rotary else [])
     k_spec = pl.BlockSpec((None, bk, D), k_im)
     # dQ's block does not change across the k blocks: written back once.
-    results = pl.pallas_call(
+    results = _ruled_call(pl.pallas_call(
         kernel,
         name=profile.FLASH_BWD if fused else profile.FLASH_DKV,
         grid=plan.grid,
@@ -1695,7 +2006,8 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         scratch_shapes=scratch,
         compiler_params=_compiler_params(plan, carries=fused),
         interpret=interpret,
-    )(*inputs)
+    ), profile.FLASH_BWD if fused else profile.FLASH_DKV, rule, plan, inputs,
+        scale, interpret)(*inputs)
     if shared:
         dk, dv, dk2, *rest = results
         if fused:
@@ -1714,11 +2026,13 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
             dv.reshape(B, G, L, D))
 
 
-def _blockwise_reference(q, k, v, scale, causal, rotary_base=None):
+def _blockwise_reference(q, k, v, scale, causal, rotary_base=None,
+                         rule=None):
     """Blockwise JAX attention, O(BLOCK_Q * L) live memory; used for the
     backward recompute and as the non-TPU fallback. q [B,H,L,D], k/v
     [B,G,L,D] — GQA repeats kv across each head group here (the kernel
-    path never materializes that)."""
+    path never materializes that). ``rule``: a mask by rule in place of
+    ``causal``."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -1735,7 +2049,11 @@ def _blockwise_reference(q, k, v, scale, causal, rotary_base=None):
         qs = lax.slice_in_dim(q, start, start + size, axis=2)
         s = jnp.einsum("bhqd,bhkd->bhqk", qs.astype(jnp.float32),
                        k.astype(jnp.float32)) * scale
-        if causal:
+        if rule is not None:
+            rows = start + lax.broadcasted_iota(jnp.int32, (size, 1), 0)
+            cols = lax.broadcasted_iota(jnp.int32, (1, L), 1)
+            s = jnp.where(rule.visible(rows, cols)[None, None], s, -jnp.inf)
+        elif causal:
             rows = start + lax.broadcasted_iota(jnp.int32, (size, L), 0)
             cols = lax.broadcasted_iota(jnp.int32, (size, L), 1)
             s = jnp.where((rows >= cols)[None, None], s, -jnp.inf)
@@ -1750,34 +2068,36 @@ def _blockwise_reference(q, k, v, scale, causal, rotary_base=None):
     return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, interpret, rotary_base=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale, causal, interpret, rotary_base=None, rule=None):
     if interpret is None:
-        return _blockwise_reference(q, k, v, scale, causal, rotary_base)
-    return _pallas_forward(q, k, v, scale, causal, interpret,
-                           rotary_base=rotary_base)
+        return _blockwise_reference(q, k, v, scale, causal, rotary_base,
+                                    rule)
+    return _pallas_forward_lse(q, k, v, scale, causal, interpret,
+                               rotary_base=rotary_base, rule=rule)[0]
 
 
-def _flash_fwd(q, k, v, scale, causal, interpret, rotary_base=None):
+def _flash_fwd(q, k, v, scale, causal, interpret, rotary_base=None,
+               rule=None):
     if interpret is None:
-        return _blockwise_reference(q, k, v, scale, causal,
-                                    rotary_base), (q, k, v, None, None)
+        return _blockwise_reference(q, k, v, scale, causal, rotary_base,
+                                    rule), (q, k, v, None, None)
     out, lse = _pallas_forward_lse(q, k, v, scale, causal, interpret,
-                                   rotary_base=rotary_base)
+                                   rotary_base=rotary_base, rule=rule)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, interpret, rotary_base, res, g):
+def _flash_bwd(scale, causal, interpret, rotary_base, rule, res, g):
     q, k, v, out, lse = res
     if interpret is None:
         # Non-kernel path: recompute-blockwise VJP in plain JAX.
         _, vjp = jax.vjp(
             lambda q, k, v: _blockwise_reference(q, k, v, scale, causal,
-                                                 rotary_base),
+                                                 rotary_base, rule),
             q, k, v)
         return vjp(g)
     return _pallas_backward(q, k, v, out, lse, g, scale, causal,
-                            interpret, rotary_base=rotary_base)
+                            interpret, rotary_base=rotary_base, rule=rule)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1848,7 +2168,7 @@ def analytic_attention_flops(B, H, L, D, causal=True, training=False):
 
 
 def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
-                    q_shared=None, k_shared=None):
+                    q_shared=None, k_shared=None, mask=None):
     """Flash attention over [B, L, H, D] inputs (same layout as
     `parallel.ring.ring_attention`); returns [B, L, H, D] in q.dtype.
 
@@ -1864,6 +2184,14 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
     (D + D2) ** -0.5, and v is as wide as k. The kernels read the shared
     key by its batch and never repeat it over the heads in memory; rotate
     it outside (`rotary_base` is refused beside it).
+
+    ``mask``: a rule over (query position, key position) in place of
+    ``causal`` (`BlockDiffusionMask(length, block)`, with L = 2 x length:
+    a noisy and a clean copy of a sequence under block-diffusion training's
+    mask). The kernels compute the tiles the rule leaves non-empty and mask
+    only those it cuts (`flash_plan(..., mask=)` counts them); positions
+    repeat, so rotate outside (``rotary_base`` and the second score product
+    are refused beside it).
 
     L must be a multiple of 128 to hit the Pallas kernel; other shapes
     (and non-TPU backends without interpret mode) use the blockwise JAX
@@ -1888,6 +2216,11 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
                 "q_shared %s / k_shared %s / v %s: want [B, L, H, D2], "
                 "[B, L, 1, D2] and v as wide as k"
                 % (q_shared.shape, k_shared.shape, v.shape))
+    if mask is not None:
+        if rotary_base is not None or D2:
+            raise ValueError("mask=%r cannot be combined with rotary_base or "
+                             "q_shared / k_shared" % (mask,))
+        mask.check(L, 1, 1)
     if scale is None:
         scale = (D + D2) ** -0.5
     # Kernel layout: [B, H, L, D] / [B, G, L, D].
@@ -1896,6 +2229,14 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
     vt = v.transpose(0, 2, 1, 3)
 
     on_tpu = jax.default_backend() == "tpu"
+    if mask is not None:
+        # The rule's kernels tile ITS length; no plan, no kernel.
+        kernel_ok = on_tpu and mask.length % BLOCK_Q == 0 and all(
+            flash_plan(B, H, L, D, group, q.dtype, backward, mask=mask)
+            for backward in (False, True))
+        out = _flash(qt, kt, vt, scale, False, False if kernel_ok else None,
+                     None, mask)
+        return out.transpose(0, 2, 1, 3)
     kernel_ok = (
         on_tpu and L % BLOCK_Q == 0 and
         _pick_rows_block(L, _grouped_blocks(D, L, group)[0], group)

@@ -92,6 +92,12 @@ HC_MIX = "hvd_hc_mix"
 HC_SCOPES = (HC, HC_MAP, HC_MIX)
 MTP = "hvd_mtp"
 
+# Block-diffusion training (`models/block_diffusion.py`), inside FWD_BWD and
+# outside the model: the noise draw, the doubled ids, positions and row
+# weights (`block_diffusion_batch`), and the slice of the noisy half before
+# the head (`block_diffusion_noisy_half`). Not in MODEL_SCOPES.
+BD = "hvd_bd"
+
 # The `name=` of every `pl.pallas_call`: on the chip's trace the kernel's
 # instruction is `<name>.<n>` and its scope path ends in
 # `<name>/pallas_call`.
@@ -524,8 +530,14 @@ def flash_plan(*args, **kwargs):
     kernels hold the second pair of operands and the sums count them
     (D=128, D2=64, L=4096: the one-kernel backward at 22 MiB of the 24);
     ``{}`` says no kernel has that form at the shape (a gridded one would
-    be needed) and the call is the blockwise jnp path. The kernels run what
-    this returns, so like `grad_collectives` it needs no chip."""
+    be needed) and the call is the blockwise jnp path. With ``mask=`` a
+    rule (`ops.BlockDiffusionMask(length, block)`; L counts all 2 x length
+    positions) every plan also says how many score tiles of a call its
+    kernel visits, masks and skips (`tiles_visited`, `tiles_masked`,
+    `tiles_skipped`: the rule's own runs, which the kernel walks); the
+    forward and dQ take a rule resident only, dK/dV also gridded, ``{}``
+    otherwise. The kernels run what this returns, so like
+    `grad_collectives` it needs no chip."""
     # `ops.flash_attention` imports this module for its kernels' names.
     from horovod_tpu.ops.flash_attention import flash_plan as plan
 

@@ -128,6 +128,77 @@ def test_flash_backward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
         assert _named(text, name), name
 
 
+# The programs the plain kernels compiled to before the kernels learnt to
+# take a mask by rule (PR 38), as `benchmark/rehearse_text.py` hashes a
+# program: forward + backward of `_flash` with every source location taken
+# out. The one-kernel backward (1 x 16 x 4096 x 128) and the two-kernel one
+# with a gridded dK/dV under grouped heads (2 x 6 on 2 x 8192 x 128). A
+# change that is MEANT to move these kernels replaces the hashes; one that
+# adds a rule, a product or a path beside them does not.
+_PLAIN_PROGRAMS = {
+    (1, 16, 16, 4096, 128, True): "3e42888fafb106fc",
+    (1, 16, 16, 4096, 128, False): "0a992eb5acbb888b",
+    (2, 6, 2, 8192, 128, True): "741832a4c4a4c8f9",
+    (2, 6, 2, 8192, 128, False): "c369474e518861dd"}
+
+
+@pytest.mark.parametrize("B,H,G,L,D,causal", list(_PLAIN_PROGRAMS))
+def test_causal_and_full_calls_lower_to_the_text_they_had(one_chip, B, H, G,
+                                                          L, D, causal):
+    import hashlib
+
+    from benchmark.rehearse_text import without_locations
+
+    def fwd_bwd(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v: _flash(q, k, v, D ** -0.5, causal, False),
+            q, k, v)
+        return (out,) + vjp(g)
+
+    bf16 = jnp.bfloat16
+    text, _ = without_locations(_compile(
+        one_chip, fwd_bwd, ((B, H, L, D), bf16), ((B, G, L, D), bf16),
+        ((B, G, L, D), bf16), ((B, H, L, D), bf16)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _PLAIN_PROGRAMS[B, H, G, L, D, causal]
+
+
+# The attention of the benchmark's block-diffusion cell (`sdar30b_1chip`):
+# 32 heads on 4, a noisy and a clean copy of 4096 tokens, blocks of 4; and
+# a shape short enough that the whole backward is one kernel.
+@pytest.mark.parametrize("H,G,length,expected", [
+    (32, 4, 4096, {profile.FLASH_FWD: "resident",
+                   profile.FLASH_DQ: "resident",
+                   profile.FLASH_DKV: "gridded"}),
+    (16, 16, 1024, {profile.FLASH_FWD: "resident",
+                    profile.FLASH_BWD: "resident"})])
+def test_ruled_flash_compiles_for_v5e(one_chip, H, G, length, expected):
+    from horovod_tpu.ops import BlockDiffusionMask
+
+    D, S = 128, 2 * length
+    rule = BlockDiffusionMask(length, 4)
+
+    def fwd_bwd(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v: _flash(q, k, v, D ** -0.5, False, False, None,
+                                   rule), q, k, v)
+        return (out,) + vjp(g)
+
+    bf16 = jnp.bfloat16
+    text = _compile(one_chip, fwd_bwd, ((1, H, S, D), bf16),
+                    ((1, G, S, D), bf16), ((1, G, S, D), bf16),
+                    ((1, H, S, D), bf16))
+    paths = {name: p.path for backward in (False, True)
+             for name, p in flash_plan(1, H, S, D, H // G, bf16, backward,
+                                       mask=rule).items()}
+    assert paths == expected
+    assert _kernels(text) == len(paths), text[:2000]
+    for name in paths:
+        assert _named(text, name), name
+    # no score array of the whole sequence anywhere in the program
+    assert "[%d,%d]" % (S, S) not in text
+
+
 # The ring LM of `chip_smoke.py --chips 4`: B2 x H6 per chip, L=8192 over
 # four chips, D=128.
 _RING = dict(BG=12, L=2048, D=128)

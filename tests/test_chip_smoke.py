@@ -126,3 +126,35 @@ def test_kernels_phase_expects_what_the_plan_names(capsys):
     assert lines[1].startswith(
         "  hvd_flash_bwd: resident, blocks 512 x 1024, grid (32, 2) = 64 "
         "steps, VMEM 10.0 MiB of a limit of")
+
+
+def test_kernels_phase_knows_the_block_diffusion_shape(capsys):
+    """The smoke's mask-ruled attention: the kernels it requires at the
+    benchmark cell's shape are the plan's (forward and dQ resident, dK/dV
+    gridded), `print_flash_plan` prints the tiles each visits, and its case
+    (kernel against the dense masked softmax) agrees on the CPU at a small
+    size, where `flash_attention` is the blockwise form."""
+    import jax
+    import jax.numpy as jnp
+
+    import chip_smoke
+    from horovod_tpu.ops import BlockDiffusionMask
+
+    B, H, G, L, D, block = chip_smoke.SIZES["attn_block_diffusion"]
+    rule = BlockDiffusionMask(L, block)
+    shape = (B, H, G, 2 * L, D, False, jnp.bfloat16)
+    assert chip_smoke.flash_kernels(*shape, mask=rule) == [
+        "hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"]
+    chip_smoke.print_flash_plan(*shape, mask=rule)
+    lines = capsys.readouterr().out.splitlines()
+    assert len(lines) == 3
+    for line in lines:
+        assert line.endswith("tiles visited 1280 (masked 384), skipped 2816")
+    assert lines[2].startswith("  hvd_flash_dkv: gridded, blocks 1024 x 512")
+    small = BlockDiffusionMask(128, 4)
+    name, kernel, reference, qkvw = chip_smoke.attention_case(
+        1, 4, 2, 256, 64, False, jnp.float32, 0, mask=small)
+    assert "BlockDiffusionMask(128, 4)" in name
+    with jax.default_matmul_precision("highest"):
+        for got, want in zip(kernel(*qkvw), reference(*qkvw)):
+            assert chip_smoke.rel_err(got, want) < 1e-5
