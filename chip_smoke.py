@@ -78,8 +78,10 @@ SIZES = dict(
     # four streams of 4096 tokens, 3584 wide, onto phi's 24 columns.
     hc=(4, 4096, 3584, 24),
     # (T, k, D, experts, held) of a routed layer there: 4 x 4096
-    # assignments over 64 experts of which this rank holds 8.
-    moe_rows=(4096, 4, 3584, 64, 8),
+    # assignments over 64 experts of which this rank holds 8; and of
+    # `olmoe1b7_1chip`'s: 8 x 4096 over 64 experts, all held, every row of
+    # [32768, 2048] live.
+    moe_rows=[(4096, 4, 3584, 64, 8), (4096, 8, 2048, 64, 64)],
     # (M, C) of the largest and the smallest BatchNorm of ResNet-50 at
     # batch 256.
     bn=[(256 * 112 * 112, 64), (256 * 7 * 7, 2048)],
@@ -537,8 +539,8 @@ def moe_rows_vs_jnp(T, k, D, experts, held, dtype, seed):
     at this shape (`hvd.profile.moe_rows_plan`), that the two kernels it
     names are in the program, and that on the chip the dispatch, the
     combine and every gradient agree with jnp's gathers and selects over
-    all k*T rows, with the held experts' rows live (an eighth) and with
-    every row live."""
+    all k*T rows, with the held experts' rows live (an eighth at Xing's
+    shape) and with every row live (OLMoE's layer holds them all)."""
     import jax
     import jax.numpy as jnp
 
@@ -546,7 +548,7 @@ def moe_rows_vs_jnp(T, k, D, experts, held, dtype, seed):
     from horovod_tpu.ops import moe_rows
     from horovod_tpu.parallel import expert
 
-    plan = profile.moe_rows_plan(T, k, D, dtype, held=(0, held))
+    plan = profile.moe_rows_plan(T, k, D, dtype)
     print("  %s, %s [%d, %d] x %d choices: %s, tiles of %d of the buffer's "
           "%d rows, %d columns of the tokens resident, VMEM %.1f MiB, %d + "
           "%d kernel calls a layer"
@@ -592,8 +594,10 @@ def moe_rows_vs_jnp(T, k, D, experts, held, dtype, seed):
           "%s: %d tpu_custom_call in the program (%s, forward and "
           "backward; compiled in %.1f s)"
           % (profile.MOE_ROWS, kernel_calls(text), ", ".join(kernels), secs))
-    for what, n_live in (("the held experts' rows", args[3]),
-                         ("every row", jnp.int32(k * T))):
+    counts = [("every row", jnp.int32(k * T))]
+    if held < experts:
+        counts.insert(0, ("the held experts' rows", args[3]))
+    for what, n_live in counts:
         args = args[:3] + (n_live,) + args[4:]
         got, want = compiled(*args), both(plain)(*args)
         live = (jnp.arange(k * T) < n_live)[:, None]
@@ -757,7 +761,8 @@ def phase_kernels(args):
         TOL["attn_bf16"], flash_kernels(*shape, mask=rule))
 
     hc_stat_vs_jnp(*SIZES["hc"], jnp.bfloat16, args.seed)
-    moe_rows_vs_jnp(*SIZES["moe_rows"], jnp.bfloat16, args.seed)
+    for shape in SIZES["moe_rows"]:
+        moe_rows_vs_jnp(*shape, jnp.bfloat16, args.seed)
 
     step, state = resnet_step(models.ResNet50PBN, mesh,
                               SIZES["resnet_batch"], args.seed)

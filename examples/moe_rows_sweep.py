@@ -8,12 +8,13 @@ ones alone), the token side is resident in VMEM a block of columns at a time
 times, on the chip, the dispatch and the combine together WITHOUT the
 experts between them (forward: x -> xs, (ys, weights) -> y; forward and
 backward: + the three gradients from given cotangents), two ways: `xla`, the
-expressions `moe_ffn` had before the op (a gather and a select over all k*T
-rows each way, the weighted sum over the k choices), and the kernels at each
-candidate. Two cases: Xing4.0's routed layer on one rank of eight ([16384,
-3584] bf16, the first 8 of 64 experts held: an eighth live) and OLMoE's
-([32768, 2048], all 64 held: every row live, where there is nothing to skip
-and the question is one row move at a time against XLA's gather).
+ops' own jnp form, which is what runs where no tile takes the shape (a
+gather and a select over all k*T rows each way, the weighted sum over the k
+choices), and the kernels at each candidate. Two cases: Xing4.0's routed
+layer on one rank of eight ([16384, 3584] bf16, the first 8 of 64 experts
+held: an eighth live) and OLMoE's ([32768, 2048], all 64 held: every row
+live, where there is nothing to skip and the question is one row move at a
+time against XLA's gather).
 
 Usage: python examples/moe_rows_sweep.py [--rows 256 512 1024 2048]
            [--resident-mib 12 24 48] [--unroll 8] [--iters 20]
@@ -40,18 +41,7 @@ CASES = {"xing_eighth_live": (4096, 4, 3584, 64, 8),
          "olmoe_all_live": (4096, 8, 2048, 64, 64)}
 
 
-def xla(x, ys, weights, order, inv, n_live, k):
-    """The held branch of `moe_ffn` as it was: (xs, y)."""
-    mine = (jnp.arange(order.shape[0]) < n_live)[:, None]
-    xs = jnp.where(mine, expert._rows_to_sorted(x, order, inv, k), 0)
-    rows = expert._rows_from_sorted(jnp.where(mine, ys, 0), order, inv)
-    w = jnp.where(inv.reshape(weights.shape) < n_live, weights, 0.0)
-    y = jnp.einsum("ktd,kt->td", rows.reshape(k, -1, x.shape[1]), w,
-                   preferred_element_type=jnp.float32)
-    return xs, y.astype(x.dtype)
-
-
-def kernels(x, ys, weights, order, inv, n_live, k):
+def ops(x, ys, weights, order, inv, n_live, k):
     return (mr.dispatch(x, order, inv, n_live, k)[0],
             mr.combine(ys, weights, order, inv, n_live))
 
@@ -97,17 +87,19 @@ def main():
         _, order, inv, sizes = expert.sort_assignments(chosen, E)
         n_live = jnp.sum(sizes[:held])
 
+        # No block of columns fits: the ops take their jnp form.
+        mr.RESIDENT_BYTES = 0
         want = jax.jit(lambda *a: jax.vjp(
-            lambda x, ys, w: xla(x, ys, w, *a[3:6], k), *a[:3])[1](a[6:]))(
+            lambda x, ys, w: ops(x, ys, w, *a[3:6], k), *a[:3])[1](a[6:]))(
                 x, ys, weights, order, inv, n_live, g_xs, g_y)
 
-        def report(form, fn, **more):
+        def report(form, **more):
             def forward(x, ys, w, order, inv, n):
-                return fn(x, ys, w, order, inv, n, k)
+                return ops(x, ys, w, order, inv, n, k)
 
             def both(x, ys, w, order, inv, n, g_xs, g_y):
                 out, vjp = jax.vjp(
-                    lambda x, ys, w: fn(x, ys, w, order, inv, n, k),
+                    lambda x, ys, w: ops(x, ys, w, order, inv, n, k),
                     x, ys, w)
                 return out, vjp((g_xs, g_y))
 
@@ -128,7 +120,7 @@ def main():
                     jax.jit(both), operands + (g_xs, g_y), args.iters), 4)}),
                 flush=True)
 
-        report("xla", xla)
+        report("xla")
         seen = set()
         for rows in args.rows:
             for mib in args.resident_mib:
@@ -139,7 +131,7 @@ def main():
                         continue
                     seen.add(tiles + (mr.UNROLL_ROWS,))
                     jax.clear_caches()  # the kernels' calls are jitted
-                    report("kernel", kernels, tile_rows=tiles[0],
+                    report("kernel", tile_rows=tiles[0],
                            block_cols=tiles[1], unroll=mr.UNROLL_ROWS)
 
 

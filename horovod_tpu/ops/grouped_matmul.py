@@ -146,25 +146,38 @@ def _gmm_kernel(starts_ref, group_ref, tile_ref, total_ref, lhs_ref, rhs_ref,
                             sub, out_ref.shape[1], part)
 
 
-def _gmm(lhs, rhs, group_sizes, transpose_rhs, interpret):
+def _whole_tiles(rows, block):
+    return -(-rows // block) * block
+
+
+# The kernels' calls are jitted, as `ops/moe_rows.py`'s are: the nine calls
+# of a gated layer (three products, and of each the rows' and the matrices'
+# gradient) are six distinct ones, traced and lowered once for all the
+# layers of a model, and each call site's scope path still reaches its
+# call's `op_name`. Only the call is inside, with its pad and slice: the
+# visits are vector work that XLA fuses and times, and an instruction it
+# forms inside a shared function carries no call site's scopes. The tiles'
+# rows are arguments: what a trace depends on is in its key.
+@functools.partial(jax.jit, static_argnames=("transpose_rhs", "block", "sub",
+                                             "interpret"))
+def _gmm(lhs, rhs, meta, transpose_rhs, block, sub, interpret):
     """lhs [M, K] x rhs [G, K, N] -> [M, N] in lhs.dtype, the forward
     kernel; with `transpose_rhs` rhs is [G, N, K] and the kernel is the
-    rows' gradient."""
+    rows' gradient. Tiles of `block` rows in parts of `sub`; `meta`:
+    `visits` of the rows in whole tiles."""
     M, K = lhs.shape
     N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-    block = BLOCK_ROWS
-    rows = -(-M // block) * block
+    rows = _whole_tiles(M, block)
     if rows != M:
         lhs = jnp.pad(lhs, ((0, rows - M), (0, 0)))
     cols = _cols_block(K, N, rhs.dtype.itemsize)
-    meta = visits(group_sizes, rows, block)
     if transpose_rhs:
         rhs_spec = pl.BlockSpec((None, cols, K),
                                 lambda n, v, s, g, t, c: (g[v], n, 0))
     else:
         rhs_spec = pl.BlockSpec((None, K, cols),
                                 lambda n, v, s, g, t, c: (g[v], 0, n))
-    kernel = functools.partial(_gmm_kernel, block=block, sub=SUB_ROWS,
+    kernel = functools.partial(_gmm_kernel, block=block, sub=sub,
                                transpose_rhs=transpose_rhs)
     how = dict(
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -212,21 +225,22 @@ def _drhs_kernel(starts_ref, group_ref, tile_ref, total_ref, lhs_ref, g_ref,
                             sub, g_ref.shape[1], part)
 
 
-def _drhs(lhs, g, group_sizes, interpret):
+@functools.partial(jax.jit, static_argnames=("block", "sub", "interpret"))
+def _drhs(lhs, g, meta, block, sub, interpret):
     """The matrices' gradient: lhs [M, K], g [M, N] -> [G, K, N] f32, group
-    g's the product of its rows of lhs, transposed, and of g."""
+    g's the product of its rows of lhs, transposed, and of g. Tiles of
+    `block` rows in parts of `sub`; `meta`: `visits` of the rows in whole
+    tiles, the empty groups visited too."""
     M, K = lhs.shape
     N = g.shape[1]
-    G = group_sizes.shape[0]
-    block = BLOCK_ROWS
-    rows = -(-M // block) * block
+    G = meta[0].shape[0] - 1  # the groups' starts, and the last one's end
+    rows = _whole_tiles(M, block)
     if rows != M:
         lhs = jnp.pad(lhs, ((0, rows - M), (0, 0)))
         g = jnp.pad(g, ((0, rows - M), (0, 0)))
     cols = _cols_block(K, N, 4)
-    meta = visits(group_sizes, rows, block, visit_empty=True)
     return pl.pallas_call(
-        functools.partial(_drhs_kernel, block=block, sub=SUB_ROWS_DRHS),
+        functools.partial(_drhs_kernel, block=block, sub=sub),
         name=profile.MOE_GMM_DRHS,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -245,9 +259,15 @@ def _drhs(lhs, g, group_sizes, interpret):
     )(*meta, lhs, g)
 
 
+def _visits(group_sizes, rows, visit_empty=False):
+    return visits(group_sizes, _whole_tiles(rows, BLOCK_ROWS), BLOCK_ROWS,
+                  visit_empty)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _grouped(lhs, rhs, group_sizes, interpret):
-    return _gmm(lhs, rhs, group_sizes, False, interpret)
+    return _gmm(lhs, rhs, _visits(group_sizes, lhs.shape[0]), False,
+                BLOCK_ROWS, SUB_ROWS, interpret)
 
 
 def _grouped_fwd(lhs, rhs, group_sizes, interpret):
@@ -257,8 +277,11 @@ def _grouped_fwd(lhs, rhs, group_sizes, interpret):
 
 def _grouped_bwd(interpret, res, g):
     lhs, rhs, group_sizes = res
-    d_lhs = _gmm(g, rhs, group_sizes, True, interpret)
-    d_rhs = _drhs(lhs, g, group_sizes, interpret)
+    rows = lhs.shape[0]
+    d_lhs = _gmm(g, rhs, _visits(group_sizes, rows), True, BLOCK_ROWS,
+                 SUB_ROWS, interpret)
+    d_rhs = _drhs(lhs, g, _visits(group_sizes, rows, visit_empty=True),
+                  BLOCK_ROWS, SUB_ROWS_DRHS, interpret)
     return d_lhs, d_rhs.astype(rhs.dtype), None
 
 

@@ -1,15 +1,16 @@
 """The rows of a routed layer that is told how many of them are live, moved
-by that count (Pallas, TPU): the dispatch and the combine of
-`parallel/expert.py::moe_ffn` where the layer holds a part of the experts
-(``held=``).
+by that count (Pallas, TPU): the dispatch and the combine of the dropless
+local path of `parallel/expert.py::moe_ffn`.
 
-Such a layer sorts k*T assignments, turns the held experts' run to the front
-of a k*T-row buffer and multiplies `n_live` rows, a number the router
-decides each step (an eighth of the buffer in the benchmark's cell
-`xing29b_1chip`). XLA's gathers, selects and sums run over the buffer's
-static shape; the grouped matmuls between them visit the live tiles alone
-(`ops/grouped_matmul.py::visits`). These two kernels take the count as a
-prefetched scalar and do the same for the shuffle around them:
+Such a layer sorts k*T assignments, turns the run of the experts it holds to
+the front of a k*T-row buffer and multiplies `n_live` rows: a number the
+router decides each step where the layer holds a part of the experts
+(``held=``: an eighth of the buffer in the benchmark's cell `xing29b_1chip`),
+and k*T itself where it holds them all (`olmoe1b7_1chip`). XLA's gathers,
+selects and sums run over the buffer's static shape, at a third of the
+memory's bandwidth; the grouped matmuls between them visit the live tiles
+alone (`ops/grouped_matmul.py::visits`). These two kernels take the count as
+a prefetched scalar and do the same for the shuffle around them:
 
 - `hvd_moe_rows` (`rows_out`): ``out[s] = scale[s] * src[idx[s]]`` for
   ``s < n_live``; the last live tile is written WHOLE, zeros past the count,
@@ -98,10 +99,11 @@ def _vmem_bytes(T, rows, cols, itemsize):
             + 2 * rows * _LANES * 4 * 2)
 
 
-def rows_plan(T, k, D, dtype=jnp.bfloat16, held=None):
-    """How the dispatch and the combine of a routed layer move their rows,
-    x [T, D] in `dtype` with k choices a token (`hvd.profile.moe_rows_plan`;
-    the ops run what this returns, where a TPU runs them):
+def rows_plan(T, k, D, dtype=jnp.bfloat16):
+    """How the dispatch and the combine of a dropless local routed layer
+    move their rows, x [T, D] in `dtype` with k choices a token
+    (`hvd.profile.moe_rows_plan`; the ops run what this returns, where a
+    TPU runs them):
 
         {"path": "kernel" or "jnp",
          "tile_rows": rows of a tile of the buffer,
@@ -110,13 +112,11 @@ def rows_plan(T, k, D, dtype=jnp.bfloat16, held=None):
          "vmem_bytes": what a kernel's blocks take of VMEM at most,
          "calls_a_layer": {"forward": 2, "backward": 2} kernel calls}
 
-    The path is "kernel" where the layer has a live count (`held`: the
-    experts it holds; without it every row is live and there is nothing to
-    skip), the shapes fit (`_tiles`) and the backend is a TPU; else "jnp":
-    gathers, selects and sums over all k * T rows."""
-    tiles = None
-    if held is not None and jax.default_backend() == "tpu":
-        tiles = _tiles(T, k, D, dtype)
+    The path is "kernel" where the shapes fit (`_tiles`) and the backend is
+    a TPU, whatever the layer holds of the experts (the count it tells the
+    kernels is k * T where it holds them all); else "jnp": gathers, selects
+    and sums over all k * T rows."""
+    tiles = _kernel_tiles(T, k, D, dtype, None)
     rows, cols = tiles or (0, 0)
     calls = 2 if tiles else 0
     return {"path": "kernel" if tiles else "jnp", "tile_rows": rows,

@@ -12,26 +12,28 @@ this is the TPU-first ``ep`` member of the parallelism family
   (`router_losses`). Or by sigmoid (DeepSeek-V3): every expert's score by
   itself, the choice made on score + a selection bias, the weights the
   chosen scores without it, renormalised and scaled.
-* **held** (``held=(first, count)``, local) — the layer is told which
-  experts this device holds: the router scores and picks over ALL experts,
-  the sorted run of the held experts' rows is taken to the front of the
-  k*T-row buffer, `count` groups are multiplied, and what the absent
+* **held** (``held=(first, count)``, dropless and local) — the layer is told
+  which experts this device holds: the router scores and picks over ALL
+  experts, the sorted run of the held experts' rows is taken to the front
+  of the k*T-row buffer, `count` groups are multiplied, and what the absent
   experts would add is left out (the one rank's share of an
-  expert-parallel layer, without its exchange). The rows there are live;
-  the dispatch and the combine are told their count and touch no other
-  (`ops/moe_rows.py`).
+  expert-parallel layer, without its exchange). Without it every expert
+  is held: the run is the whole sorted order.
 * **shared** — an always-on gated expert beside the routed ones (`MoeMlp`
   with ``shared_dim``).
 * **sort** — the k*T assignments are sorted by expert (`sort_assignments`):
   a permutation, its inverse and the group sizes, which always sum to k*T.
   Nothing here builds a [T, E, C] tensor.
-* **dropless** (``capacity_factor=None``, the local path) — the rows are
-  gathered into the sorted order, the experts run as a grouped matmul over
-  the contiguous ragged groups (`grouped_matmul`), the results are gathered
-  back and summed with their weights. No assignment is dropped and an empty
-  group is legal; shapes are static ([k*T, D]) whatever the routing. Both
-  gathers are permutations, so their transposes are gathers too
-  (`custom_vjp`): no scatter-add of rows in either direction.
+* **dropless** (``capacity_factor=None``, the local path) — the tokens' rows
+  are put into the sorted order (`ops/moe_rows.dispatch`), the experts run
+  as a grouped matmul over the contiguous ragged groups (`grouped_matmul`),
+  and each token's results are summed with their weights
+  (`ops/moe_rows.combine`). No assignment is dropped and an empty group is
+  legal; shapes are static ([k*T, D]) whatever the routing. The rows that
+  belong to a held expert are live; the dispatch and the combine are told
+  their count (k*T where every expert is held) and touch no other. Each
+  one's transpose is the other's kernel: no scatter-add of rows in either
+  direction.
 * **capacity** (a ``capacity_factor``; required with ``ep_axis``) — each
   expert has ``C = ceil(T/E * capacity_factor)`` slots, filled from the same
   sorted order (first choices of all tokens before second choices, GShard's
@@ -49,7 +51,6 @@ __graft_entry__.dryrun_multichip phase 4). Experts are ``w_in -> act ->
 w_out`` or gated (``w_gate``, ``w_up``, ``w_down``: SwiGLU with SiLU).
 """
 
-import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -141,47 +142,6 @@ def sort_assignments(experts, num_experts):
     return flat, order, inv, group_sizes
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _rows_to_sorted(x, order, inv, k):
-    """x [T, D] -> [kT, D]: row s is the token of the assignment at sorted
-    position s. Transposed as a gather by `inv` and a sum over the k
-    choices, not as a scatter-add."""
-    del inv, k
-    return x[order % x.shape[0]]
-
-
-def _rows_to_sorted_fwd(x, order, inv, k):
-    return _rows_to_sorted(x, order, inv, k), inv
-
-
-def _rows_to_sorted_bwd(k, inv, g):
-    dx = jnp.sum(g[inv].reshape(k, -1, g.shape[-1]), axis=0,
-                 dtype=jnp.float32)
-    return dx.astype(g.dtype), None, None
-
-
-_rows_to_sorted.defvjp(_rows_to_sorted_fwd, _rows_to_sorted_bwd)
-
-
-@jax.custom_vjp
-def _rows_from_sorted(ys, order, inv):
-    """ys [kT, D] in sorted order -> [kT, D] in assignment order; the
-    transpose of a permutation is the inverse permutation."""
-    del order
-    return ys[inv]
-
-
-def _rows_from_sorted_fwd(ys, order, inv):
-    return ys[inv], order
-
-
-def _rows_from_sorted_bwd(order, g):
-    return g[order], None, None
-
-
-_rows_from_sorted.defvjp(_rows_from_sorted_fwd, _rows_from_sorted_bwd)
-
-
 def _experts(xs, w_in, w_out, w_gate, act, matmul):
     """The experts' feed-forward on rows `xs`; `matmul(rows, weights)` is
     grouped (dropless) or batched (capacity). `xs` may be a tuple: the
@@ -220,7 +180,8 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
     slots — size it accordingly (>= top_k for comparable drop rates).
     `scoring`, `bias` and `scale` are `route`'s.
 
-    ``held=(first, count)`` (dropless and local only): this device holds
+    ``held=(first, count)`` (dropless and local only; without it all E
+    are held): this device holds
     the experts [first, first + count) of the E the router scores, so
     w_in, w_out, w_gate are [count, ...]. Every token is routed over all E
     with weights normalised over all its k choices; the result is the sum
@@ -267,71 +228,64 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
              "assignments": group_sizes, "chosen": experts}
     weights = weights.T  # [k, T], the assignments' order
 
-    if held is not None:
+    if capacity_factor is None:
         kT = order.shape[0]
         with jax.named_scope(profile.MOE_DISPATCH):
-            # The held experts' rows are one run of the sorted order, from
-            # `start`: turned to the front of the k*T-row buffer, which so
-            # holds them whatever the router does. The `n_held` rows there
-            # are live; the rows behind them belong to no group, and the
-            # dispatch and the combine are told the count (`ops/moe_rows`:
-            # kernels that touch the live rows alone where a TPU runs them).
-            start = jnp.sum(group_sizes[:first])
-            sizes = group_sizes[first:first + count]
-            n_held = jnp.sum(sizes)
-            at = jnp.arange(kT, dtype=jnp.int32)
-            order_h = order[(at + start) % kT]
-            inv_h = (inv - start) % kT
-            xs = moe_rows.dispatch(x, order_h, inv_h, n_held, top_k,
+            # The rows of the experts held here are one run of the sorted
+            # order, and the dispatch and the combine are told how many
+            # they are (`ops/moe_rows`: kernels that touch the live rows
+            # alone where a TPU runs them). With every expert held the
+            # run is the whole order and the count k*T, a constant.
+            sizes, n_live = group_sizes, jnp.int32(kT)
+            if held is not None:
+                # From `start`: turned to the front of the k*T-row buffer,
+                # which so holds the run whatever the router does. The
+                # rows behind the `n_live` belong to no group.
+                start = jnp.sum(group_sizes[:first])
+                sizes = group_sizes[first:first + count]
+                n_live = jnp.sum(sizes)
+                at = jnp.arange(kT, dtype=jnp.int32)
+                order = order[(at + start) % kT]
+                inv = (inv - start) % kT
+                stats["held"] = n_live
+            xs = moe_rows.dispatch(x, order, inv, n_live, top_k,
                                    1 if w_gate is None else 2)
         with jax.named_scope(profile.MOE_EXPERTS):
             ys = _experts(xs, w_in, w_out, w_gate, act,
                           lambda rows, w: grouped_matmul(rows, w, sizes))
         with jax.named_scope(profile.MOE_COMBINE):
-            y = moe_rows.combine(ys, weights, order_h, inv_h, n_held)
+            y = moe_rows.combine(ys, weights, order, inv, n_live)
         stats["dropped"] = jnp.zeros((), jnp.int32)
-        stats["held"] = n_held
         return y, stats
-    if capacity_factor is None:
-        with jax.named_scope(profile.MOE_DISPATCH):
-            xs = _rows_to_sorted(x, order, inv, top_k)
-        with jax.named_scope(profile.MOE_EXPERTS):
-            ys = _experts(xs, w_in, w_out, w_gate, act,
-                          lambda rows, w: grouped_matmul(rows, w,
-                                                         group_sizes))
-        with jax.named_scope(profile.MOE_COMBINE):
-            rows = _rows_from_sorted(ys, order, inv)
-        stats["dropped"] = jnp.zeros((), jnp.int32)
-    else:
-        C = moe_capacity(T, E, capacity_factor)
-        with jax.named_scope(profile.MOE_DISPATCH):
-            starts = jnp.cumsum(group_sizes) - group_sizes
-            slot = jnp.arange(C, dtype=jnp.int32)[None, :]
-            # Slot (e, c) holds the assignment at sorted position
-            # starts[e] + c, if expert e has that many.
-            taken = slot < group_sizes[:, None]
-            token = order[jnp.minimum(starts[:, None] + slot,
-                                      order.shape[0] - 1)] % T
-            expert_in = jnp.where(taken[..., None], x[token], 0)
-            if ep_axis is not None:
-                # [E, C, D] -> [E/ep, ep*C, D]: each rank keeps its local
-                # experts' slots from EVERY rank's tokens.
-                expert_in = lax.all_to_all(expert_in, ep_axis, split_axis=0,
-                                           concat_axis=1, tiled=True)
-        with jax.named_scope(profile.MOE_EXPERTS):
-            out = _experts(expert_in, w_in, w_out, w_gate, act,
-                           lambda rows, w: jnp.einsum(
-                               "ecd,edf->ecf", rows, w.astype(rows.dtype)))
-        with jax.named_scope(profile.MOE_COMBINE):
-            if ep_axis is not None:
-                # Reverse exchange: [E/ep, ep*C, D] -> [E, C, D].
-                out = lax.all_to_all(out, ep_axis, split_axis=1,
-                                     concat_axis=0, tiled=True)
-            place = inv - starts[flat]  # the assignment's place in its queue
-            kept = place < C
-            rows = out.reshape(E * C, D)[flat * C + jnp.minimum(place, C - 1)]
-            weights = jnp.where(kept.reshape(weights.shape), weights, 0.0)
-        stats["dropped"] = jnp.sum(~kept, dtype=jnp.int32)
+    C = moe_capacity(T, E, capacity_factor)
+    with jax.named_scope(profile.MOE_DISPATCH):
+        starts = jnp.cumsum(group_sizes) - group_sizes
+        slot = jnp.arange(C, dtype=jnp.int32)[None, :]
+        # Slot (e, c) holds the assignment at sorted position
+        # starts[e] + c, if expert e has that many.
+        taken = slot < group_sizes[:, None]
+        token = order[jnp.minimum(starts[:, None] + slot,
+                                  order.shape[0] - 1)] % T
+        expert_in = jnp.where(taken[..., None], x[token], 0)
+        if ep_axis is not None:
+            # [E, C, D] -> [E/ep, ep*C, D]: each rank keeps its local
+            # experts' slots from EVERY rank's tokens.
+            expert_in = lax.all_to_all(expert_in, ep_axis, split_axis=0,
+                                       concat_axis=1, tiled=True)
+    with jax.named_scope(profile.MOE_EXPERTS):
+        out = _experts(expert_in, w_in, w_out, w_gate, act,
+                       lambda rows, w: jnp.einsum(
+                           "ecd,edf->ecf", rows, w.astype(rows.dtype)))
+    with jax.named_scope(profile.MOE_COMBINE):
+        if ep_axis is not None:
+            # Reverse exchange: [E/ep, ep*C, D] -> [E, C, D].
+            out = lax.all_to_all(out, ep_axis, split_axis=1,
+                                 concat_axis=0, tiled=True)
+        place = inv - starts[flat]  # the assignment's place in its queue
+        kept = place < C
+        rows = out.reshape(E * C, D)[flat * C + jnp.minimum(place, C - 1)]
+        weights = jnp.where(kept.reshape(weights.shape), weights, 0.0)
+    stats["dropped"] = jnp.sum(~kept, dtype=jnp.int32)
     with jax.named_scope(profile.MOE_COMBINE):
         y = jnp.einsum("ktd,kt->td", rows.reshape(top_k, T, D), weights,
                        preferred_element_type=jnp.float32)

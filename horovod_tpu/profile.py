@@ -579,21 +579,21 @@ def hc_plan(*args, **kwargs):
     return plan(*args, **kwargs)
 
 
-# --- how a routed layer that holds a part of the experts moves its rows -----
+# --- how a dropless routed layer moves its rows -----------------------------
 
 def moe_rows_plan(*args, **kwargs):
-    """How the dispatch and the combine of `parallel.moe_ffn` move the rows
-    of a call: `ops.moe_rows.rows_plan(T, k, D, dtype, held=...)` (its
-    arguments and result), here beside the other program-side counters. The
-    path (`kernel`: `MOE_ROWS` and `MOE_SUM`, which touch the live rows
-    alone, where the layer is told which experts it holds, the shapes fit
-    and a TPU runs it; `jnp`: gathers and sums over all k * T rows of the
-    buffer), a tile's rows, the columns of the token side resident at a
-    time, the buffer's rows, the VMEM bytes a kernel's blocks take and the
-    kernel calls a layer makes in each direction. How many of the buffer's
-    rows are live is the router's to decide each step:
-    `parallel.routing_stats`' `held_share` counts it. The ops run what this
-    returns."""
+    """How the dispatch and the combine of `parallel.moe_ffn`'s dropless
+    local path move the rows of a call: `ops.moe_rows.rows_plan(T, k, D,
+    dtype)` (its arguments and result), here beside the other program-side
+    counters. The path (`kernel`: `MOE_ROWS` and `MOE_SUM`, which touch the
+    live rows alone, where the shapes fit and a TPU runs it; `jnp`: gathers
+    and sums over all k * T rows of the buffer), a tile's rows, the columns
+    of the token side resident at a time, the buffer's rows, the VMEM bytes
+    a kernel's blocks take and the kernel calls a layer makes in each
+    direction. How many of the buffer's rows are live: all where the layer
+    holds every expert, else the router's to decide each step
+    (`parallel.routing_stats`' `held_share` counts it). The ops run what
+    this returns."""
     # `ops.moe_rows` imports this module for its kernels' names.
     from horovod_tpu.ops.moe_rows import rows_plan as plan
 

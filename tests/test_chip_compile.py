@@ -316,17 +316,18 @@ def test_moe_rows_compile_for_v5e(one_chip, monkeypatch):
     for name in profile.MOE_ROWS_KERNELS:
         assert _named(text, name), name
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    plan = profile.moe_rows_plan(T, k, D, bf16, held=(0, 8))
+    plan = profile.moe_rows_plan(T, k, D, bf16)
     assert plan["path"] == "kernel" and plan["tile_rows"] == 1024
 
 
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------
 
-def _lm_step(topo, chips, monkeypatch):
-    """`make_train_step` around a small flash-attention LM on a mesh of the
-    first `chips` described devices, with its state as shapes: (step,
-    abstract state, mesh). The kernel dispatchers ask for the default backend,
-    which is the CPU here; the test steers them, not the program."""
+def _lm_step(topo, chips, monkeypatch, **more):
+    """`make_train_step` around a small flash-attention LM (`more`: further
+    fields of its configuration) on a mesh of the first `chips` described
+    devices, with its state as shapes: (step, abstract state, mesh). The
+    kernel dispatchers ask for the default backend, which is the CPU here;
+    the test steers them, not the program."""
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -334,10 +335,10 @@ def _lm_step(topo, chips, monkeypatch):
     from horovod_tpu.ops.losses import chunked_softmax_cross_entropy
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = models.TransformerConfig(
+    cfg = models.TransformerConfig(**dict(dict(
         vocab_size=32768, num_layers=2, num_heads=4, embed_dim=512,
         mlp_dim=2048, max_seq_len=512, attention="flash",
-        dtype=jnp.bfloat16)
+        dtype=jnp.bfloat16), **more))
     model = models.Transformer(cfg)
     opt = optax.adam(1e-4)
 
@@ -412,3 +413,42 @@ def test_one_device_step_is_compiled_as_before(topo, monkeypatch):
     assert _kernels(texts[0]) == 4
     for name in (profile.FLASH_FWD, profile.FLASH_BWD):
         assert _named(texts[0], name), name
+
+
+def test_a_routed_steps_kernel_calls_share_one_lowering_each(topo,
+                                                             monkeypatch):
+    """Two gated dropless routed layers (every expert held, as OLMoE's) in
+    one step: the lowered module holds each DISTINCT kernel call of the
+    routed feed-forward once, as a private function every call site calls
+    (the grouped matmuls' 6 of 2 x 9: the gate's and the up projection's
+    calls share their shapes; the rows' 4 of 2 x 4), where the plain flash
+    calls are lowered a call site (2 x 2); the compiled step has them all
+    inlined, each under its own site's scope path; and no XLA gather moves
+    the [k*T, D] rows under the dispatch and the combine."""
+    import re
+
+    k, T, D = 2, 2 * 512, 512
+    step, state, _ = _lm_step(
+        topo, 1, monkeypatch, mlp_dim=256, moe_experts=8, moe_every=1,
+        moe_top_k=k, moe_capacity_factor=None, moe_gated=True)
+    assert profile.moe_rows_plan(T, k, D, jnp.bfloat16)["path"] == "kernel"
+    with jax.default_matmul_precision("default"):
+        lowered = step.lower(*state)
+        text = lowered.compile().as_text()
+    assert lowered.as_text().count("tpu_custom_call") == 6 + 4 + 2 * 2
+    assert _kernels(text) == 2 * (9 + 4 + 2)
+    ops = re.findall(r'op_name="([^"]*/pallas_call)"', text)
+    parts = {profile.MOE_EXPERTS: profile.MOE_GMM_KERNELS,
+             profile.MOE_DISPATCH: profile.MOE_ROWS_KERNELS,
+             profile.MOE_COMBINE: profile.MOE_ROWS_KERNELS}
+    for layer in range(2):
+        for scope, names in parts.items():
+            for name in names:  # forward or, transposed, backward
+                path = r"\bblock_%d\b.*\b%s/%s\b.*\b%s\)*/pallas_call$" % (
+                    layer, profile.MOE, scope, name)
+                assert any(re.search(path, op) for op in ops), path
+    rows = re.compile(r"\[%d,%d\]\S* gather\(" % (k * T, D))
+    moved = [line for line in text.splitlines() if rows.search(line)
+             and re.search("%s|%s" % (profile.MOE_DISPATCH,
+                                      profile.MOE_COMBINE), line)]
+    assert not moved, moved[:2]

@@ -1,7 +1,8 @@
 """Expert parallelism (routed MoE feed-forward + ep all_to_all): routing
 semantics, capacity and drops, dense equivalence, sharded-vs-unsharded
 equality, gradients, and the MoeMlp module (virtual 8-device CPU mesh).
-The dropless path and OLMoE are in tests/test_moe_dropless.py."""
+The dropless path and OLMoE are in tests/test_moe_dropless.py, its rows'
+kernels in tests/test_moe_rows.py."""
 
 import numpy as np
 import pytest
@@ -67,6 +68,41 @@ def test_moe_ffn_matches_per_token_expert_computation():
         h = np.asarray(nn.silu(x[t] @ w_in[e]))
         expect[t] = float(probs[t, e]) * np.asarray(h @ w_out[e])
     np.testing.assert_allclose(np.asarray(y), expect, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_the_dropless_path_is_the_capacity_path_with_room_for_everything(
+        gated):
+    """One routing and one sorted order under both: with a slot for every
+    assignment the capacity path drops nothing, and the dropless path
+    (`capacity_factor=None`: `ops/moe_rows`' dispatch and combine around the
+    grouped matmul) gives its output and its gradients by x, the router and
+    every matrix."""
+    rng = np.random.RandomState(11)
+    T, D, F, E, k = 24, 16, 12, 4, 2
+    args = [jnp.asarray(a.astype(np.float32)) for a in (
+        rng.randn(T, D), rng.randn(D, E) * 0.5, rng.randn(E, D, F) * 0.3,
+        rng.randn(E, F, D) * 0.3, rng.randn(E, D, F) * 0.3)]
+    ct = jnp.asarray(rng.randn(T, D).astype(np.float32))
+
+    def layer(factor):
+        def loss(x, router, w_in, w_out, w_gate):
+            y, stats = moe_ffn(x, router, w_in, w_out, capacity_factor=factor,
+                               top_k=k, w_gate=w_gate if gated else None)
+            return jnp.sum(ct * y), (y, stats)
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                  has_aux=True)(*args)
+
+    ((_, (y, stats)), grads) = layer(None)
+    ((_, (y_cap, stats_cap)), grads_cap) = layer(float(E))  # C = T
+    assert int(stats["dropped"]) == int(stats_cap["dropped"]) == 0
+    assert "held" not in stats
+    np.testing.assert_array_equal(stats["assignments"],
+                                  stats_cap["assignments"])
+    np.testing.assert_allclose(y, y_cap, atol=1e-5, rtol=1e-5)
+    for g, g_cap in zip(grads[:4 + gated], grads_cap):
+        assert float(jnp.max(jnp.abs(g))) > 0
+        np.testing.assert_allclose(g, g_cap, atol=2e-5, rtol=1e-4)
 
 
 def _mesh_dp_ep(dp, ep):

@@ -172,12 +172,39 @@ def test_bfloat16_limits_fail_float8_precision():
                 or err[~flipped].max() > TOL_BF16), (seed, flipped.mean())
 
 
-def test_overloaded_and_empty_expert_lose_nothing():
+@pytest.fixture(params=["jnp", "kernels"])
+def layer_path(request, monkeypatch):
+    """How the dropless layer runs: by jnp and `lax.ragged_dot`, as the CPU
+    does, or by its kernels in Pallas' interpreter (the rows' two and the
+    grouped matmuls' three), on tiles small enough that a test's buffer
+    crosses them. Returns (tokens, width): the rows' kernels take a width
+    that is a multiple of 128 and whole sublane tiles of tokens."""
+    if request.param == "jnp":
+        return 40, 16
+    import functools
+
+    from horovod_tpu.ops import grouped_matmul as gm
+    from horovod_tpu.ops import moe_rows as mr
+    from horovod_tpu.parallel import expert
+
+    monkeypatch.setattr(gm, "BLOCK_ROWS", 32)
+    monkeypatch.setattr(gm, "SUB_ROWS", 8)
+    monkeypatch.setattr(gm, "SUB_ROWS_DRHS", 16)
+    monkeypatch.setattr(mr, "TILE_ROWS", 48)
+    monkeypatch.setattr(expert, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, interpret=True))
+    for name in ("dispatch", "combine"):
+        monkeypatch.setattr(mr, name, functools.partial(
+            getattr(mr, name), interpret=True))
+    return 64, 128
+
+
+def test_overloaded_and_empty_expert_lose_nothing(layer_path):
     """A routing so uneven that expert 0 is chosen by every token and
     expert 5 by none: the group sizes still sum to k*T, the empty group is
     legal, and output and gradients equal the dense masked computation."""
     rng = np.random.RandomState(3)
-    T, D, F, E, k = 40, 16, 12, 8, 3
+    (T, D), F, E, k = layer_path, 12, 8, 3
     x = rng.randn(T, D).astype(np.float32)
     x[:, 0] = 1.0 + 0.1 * rng.rand(T)
     router = rng.randn(D, E).astype(np.float32) * 0.1
@@ -207,8 +234,9 @@ def test_overloaded_and_empty_expert_lose_nothing():
                    argnums=(0, 1, 2, 3, 4))(*args)
     want = jax.grad(lambda *a: jnp.sum(dense(*a) * ct),
                     argnums=(0, 1, 2, 3, 4))(*args)
-    for g, w in zip(got, want):
-        np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-4)
+    for g, w in zip(got, want):  # float32 sums in another order
+        np.testing.assert_allclose(
+            g, w, atol=2e-5 + 1e-6 * float(jnp.max(jnp.abs(w))), rtol=1e-4)
     # the empty expert's matrices get exactly zero
     assert float(jnp.max(jnp.abs(got[2][5]))) == 0.0
 

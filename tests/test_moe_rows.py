@@ -2,7 +2,8 @@
 both kernels in Pallas' interpreter, and the jnp path, against autodiff of
 the expressions `moe_ffn` had (`x[order % T]`, `ys[inv]`, the einsum over the
 k choices): values and every gradient, at counts of 0, 1, a tile's edge, a
-tile's middle and all rows; dead rows full of NaN; the plan."""
+tile's middle and all rows; dead rows full of NaN; the plan; `moe_ffn` on
+the kernels alone, holding a part of the experts and holding them all."""
 
 import functools
 import re
@@ -180,40 +181,59 @@ def test_a_ragged_width_takes_the_jnp_path_even_when_asked_to_interpret(
 
 
 PLANS = {
-    # (T, k, D, dtype, held, backend) -> path
-    "xings_shape_on_a_tpu": ((4096, 4, 3584, "bfloat16", (0, 8), "tpu"),
-                             "kernel"),
-    "olmoes_shape_has_no_count": ((4096, 8, 2048, "bfloat16", None, "tpu"),
-                                  "jnp"),
+    # (T, k, D, dtype, backend) -> (path, columns resident at a time)
+    "xings_shape_on_a_tpu": ((4096, 4, 3584, "bfloat16", "tpu"),
+                             ("kernel", 896)),
+    "olmoes_shape_every_row_live_on_a_tpu": (
+        (4096, 8, 2048, "bfloat16", "tpu"), ("kernel", 1024)),
+    "olmoes_shape_off_the_tpu": ((4096, 8, 2048, "bfloat16", "cpu"),
+                                 ("jnp", 0)),
+    "a_width_of_192": ((4096, 8, 192, "bfloat16", "tpu"), ("jnp", 0)),
     "a_width_that_is_no_multiple_of_128": (
-        (4096, 4, 3600, "bfloat16", (0, 8), "tpu"), "jnp"),
+        (4096, 4, 3600, "bfloat16", "tpu"), ("jnp", 0)),
     "a_buffer_that_is_no_whole_tile": (
-        (1000, 4, 3584, "bfloat16", (0, 8), "tpu"), "jnp"),
-    "off_the_tpu": ((4096, 4, 3584, "bfloat16", (0, 8), "cpu"), "jnp"),
+        (1000, 4, 3584, "bfloat16", "tpu"), ("jnp", 0)),
+    "off_the_tpu": ((4096, 4, 3584, "bfloat16", "cpu"), ("jnp", 0)),
 }
 
 
 @pytest.mark.parametrize("case", sorted(PLANS))
 def test_rows_plan_says_which_path_a_call_takes(monkeypatch, case):
-    (tokens, k, width, dtype, held, backend), path = PLANS[case]
+    """The plan is of the shapes and the backend alone: a layer that holds
+    every expert (OLMoE's: no `held`, the count k * T) takes the kernels
+    as one that holds a part does."""
+    (tokens, k, width, dtype, backend), (path, cols) = PLANS[case]
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    plan = profile.moe_rows_plan(tokens, k, width, jnp.dtype(dtype),
-                                 held=held)
-    assert plan == mr.rows_plan(tokens, k, width, jnp.dtype(dtype), held=held)
+    plan = profile.moe_rows_plan(tokens, k, width, jnp.dtype(dtype))
+    assert plan == mr.rows_plan(tokens, k, width, jnp.dtype(dtype))
     assert plan["path"] == path and plan["buffer_rows"] == k * tokens
     if path == "jnp":
         assert plan["calls_a_layer"] == {"forward": 0, "backward": 0}
         return
     assert plan["tile_rows"] == mr.TILE_ROWS == 1024
     assert plan["tile_rows"] % gm.SUB_ROWS_DRHS == 0
-    assert plan["block_cols"] == 896 and width % plan["block_cols"] == 0
+    assert plan["block_cols"] == cols and width % cols == 0
     assert plan["calls_a_layer"] == {"forward": 2, "backward": 2}
-    assert plan["vmem_bytes"] <= mr._VMEM_LIMIT_BYTES
+    assert plan["vmem_bytes"] <= mr.RESIDENT_BYTES + (16 << 20) \
+        <= mr._VMEM_LIMIT_BYTES
 
 
 # --------------------------------------------------------------------------
 # Through `moe_ffn`, with the grouped matmuls' kernels between the two
 # --------------------------------------------------------------------------
+
+def _interpret_every_kernel(monkeypatch):
+    """From here on `moe_ffn` runs the rows' two kernels and the grouped
+    matmuls' three in Pallas' interpreter, the latter on tiles of 32 rows
+    too."""
+    monkeypatch.setattr(gm, "BLOCK_ROWS", 32)
+    monkeypatch.setattr(gm, "SUB_ROWS", 8)
+    monkeypatch.setattr(expert, "grouped_matmul", functools.partial(
+        gm.grouped_matmul, interpret=True))
+    for name in ("dispatch", "combine"):
+        monkeypatch.setattr(mr, name, functools.partial(
+            getattr(mr, name), interpret=True))
+
 
 def _layer(seed=0, E=8, F=32):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
@@ -250,13 +270,7 @@ def test_a_held_layer_on_kernels_alone_is_the_layer_on_jnp(
     w = {k: c[k] for k in ("w_up", "w_down", "w_gate")}
     both = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
     want = both(c["x"], c["router"], w)
-    monkeypatch.setattr(gm, "BLOCK_ROWS", 32)
-    monkeypatch.setattr(gm, "SUB_ROWS", 8)
-    monkeypatch.setattr(expert, "grouped_matmul", functools.partial(
-        gm.grouped_matmul, interpret=True))
-    for name in ("dispatch", "combine"):
-        monkeypatch.setattr(mr, name, functools.partial(
-            getattr(mr, name), interpret=True))
+    _interpret_every_kernel(monkeypatch)
     got = both(c["x"], c["router"], w)
     assert int(got[0][1]) == int(want[0][1])
     for side in (got, want):
@@ -265,3 +279,44 @@ def test_a_held_layer_on_kernels_alone_is_the_layer_on_jnp(
                     jax.tree_util.tree_leaves(want)):
         assert bool(jnp.all(jnp.isfinite(a)))
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_a_layer_that_holds_every_expert_on_kernels_alone_is_the_layer_on_jnp(
+        small_tiles, monkeypatch, gated):
+    """`moe_ffn(capacity_factor=None)` without `held` (OLMoE's layer: the
+    count is k * T, a constant) with every kernel in the interpreter: the
+    output and the gradients by x, the router and ALL the experts' matrices
+    (no row is dead, so `w_down`'s too) equal the jnp path's, and
+    `held=(0, E)` is the same layer but for its statistic. Each direction
+    moves the rows by one call of each kernel."""
+    c = _layer()
+    E = c["router"].shape[1]
+    w = {k: c[k] for k in ("w_up", "w_down") + (("w_gate",) if gated else ())}
+
+    def loss(held, x, router, w):
+        y, stats = expert.moe_ffn(
+            x, router, w["w_up"], w["w_down"], capacity_factor=None,
+            top_k=K, w_gate=w.get("w_gate"), renormalize=False, held=held)
+        assert ("held" in stats) == (held is not None)
+        assert stats["assignments"].shape == (E,)
+        return jnp.sum(c["g"] * y), stats["dropped"]
+
+    def both(held):
+        return jax.value_and_grad(
+            functools.partial(loss, held), argnums=(0, 1, 2), has_aux=True)(
+                c["x"], c["router"], w)
+
+    want = both(None)
+    assert _pallas_names(lambda: both(None)) == set()
+    _interpret_every_kernel(monkeypatch)
+    text = str(jax.make_jaxpr(lambda: both(None))())
+    for name in profile.MOE_ROWS_KERNELS:  # once forward, once backward
+        assert len(re.findall(r"name=%s\b" % name, text)) == 2, name
+    for held in (None, (0, E)):
+        got = both(held)
+        assert int(got[0][1]) == 0
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert bool(jnp.all(jnp.isfinite(a)))
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
