@@ -61,8 +61,9 @@ SIZES = dict(
     moe_batch=4, moe_len=1024, moe_steps=4,
     # (B, H, G, L, D, fused rotary): the L=1024 LM row's attention, the
     # long-context h6/gqa2/frope row's (its backward two kernels, dK/dV
-    # gridded) and that of the benchmark's 2 x 2048 LM cells (its backward
-    # one kernel, as at L=1024).
+    # gridded: with k's rotary tables neither resident form fits) and that
+    # of the benchmark's 2 x 2048 LM cells (its backward one kernel, as at
+    # L=1024).
     attn=[(8, 12, 12, 1024, 64, False), (2, 6, 2, 8192, 128, True),
           (2, 16, 16, 2048, 128, False)],
     # (B, H, L, D, D2) of latent attention's scores of two products at the
@@ -72,7 +73,7 @@ SIZES = dict(
     # (B, H, G, data length, D, block) of block-diffusion training's
     # attention at the benchmark's `sdar30b_1chip`: 32 heads on 4, a noisy
     # and a clean copy of 4096 tokens (8192 positions) under the block mask
-    # the kernels take by rule.
+    # the kernels take by rule; dK/dV resident and held by the q block.
     attn_block_diffusion=(1, 32, 4, 4096, 128, 4),
     # (n, T, C, K) of a hyper-connection at the benchmark's `xing29b_1chip`:
     # four streams of 4096 tokens, 3584 wide, onto phi's 24 columns.
@@ -436,15 +437,19 @@ def print_flash_plan(B, H, G, L, D, rotary, dtype, shared_dim=0, mask=None):
     """Which path each flash kernel of this shape takes (`hvd.profile`);
     `shared_dim`: the width of a second score product on one shared key;
     `mask`: a rule in place of the causal triangle, whose plans count the
-    score tiles each kernel visits, masks and skips."""
+    score tiles each kernel visits, masks and skips. dK/dV's line says
+    which side a grid step holds a block of (it has a resident form of
+    either kind)."""
     from horovod_tpu import profile
 
     for backward in (False, True):
         for name, plan in profile.flash_plan(
                 B, H, L, D, H // G, dtype, backward, rotary,
                 shared_dim=shared_dim, mask=mask).items():
+            path = plan.path + (" held by the %s block" % plan.held
+                                if name == profile.FLASH_DKV else "")
             print("  %s: %s, blocks %d x %d, grid %s = %d steps, VMEM %.1f "
-                  "MiB%s%s" % (name, plan.path, plan.block_q, plan.block_k,
+                  "MiB%s%s" % (name, path, plan.block_q, plan.block_k,
                                plan.grid, plan.grid_steps,
                                plan.vmem_bytes / 2 ** 20,
                                "" if plan.vmem_limit_bytes is None else
@@ -477,6 +482,46 @@ def attention_vs_reference(case, tol, kernels):
           "flash %s vs _blockwise_reference on the chip: out %.2e dq %.2e "
           "dk %.2e dv %.2e (max rel to max |ref|, tol %.0e)"
           % ((name,) + tuple(errs) + (tol,)))
+
+
+def dkv_forms_agree(B, H, G, L, D, dtype, seed, mask, tol):
+    """Where the plan holds dK/dV by the q block (`held` "q": k, v and the
+    results whole in VMEM, dK and dV summed there): dQ, dK and dV of that
+    form against the GRIDDED dK/dV kernel on the same inputs, forced by a
+    budget one byte short of what the form holds. Both add a k block's
+    tiles in ascending q order in f32 and round once."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import profile
+    from horovod_tpu.ops.flash_attention import (_pallas_backward,
+                                                 _pallas_forward_lse)
+
+    plan = profile.flash_plan(B, H, L, D, H // G, dtype, True,
+                              mask=mask)[profile.FLASH_DKV]
+    check((plan.path, plan.held) == ("resident", "q"),
+          "%s at this shape is resident and held by the q block"
+          % profile.FLASH_DKV)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q, k, v, w = (jax.random.normal(key, (B, heads, L, D),
+                                    jnp.float32).astype(dtype)
+                  for key, heads in zip(ks, (H, G, G, H)))
+
+    def grads(budget):
+        def f(q, k, v, w):
+            kw = dict(vmem_budget=budget, rule=mask)
+            out, lse = _pallas_forward_lse(q, k, v, D ** -0.5, mask is None,
+                                           False, **kw)
+            return _pallas_backward(q, k, v, out, lse, w, D ** -0.5,
+                                    mask is None, False, **kw)
+        return jax.jit(f)(q, k, v, w)
+
+    held, gridded = grads(plan.resident_bytes), grads(plan.resident_bytes - 1)
+    errs = [rel_err(a, b) for a, b in zip(held, gridded)]
+    check(max(errs) <= tol,
+          "%s held by the q block vs gridded on the chip: dq %.2e dk %.2e dv "
+          "%.2e (max rel to max |gridded|, tol %.0e)"
+          % ((profile.FLASH_DKV,) + tuple(errs) + (tol,)))
 
 
 def hc_stat_vs_jnp(n, T, C, K, dtype, seed):
@@ -759,6 +804,8 @@ def phase_kernels(args):
     attention_vs_reference(
         attention_case(*shape, args.seed + len(SIZES["attn"]), mask=rule),
         TOL["attn_bf16"], flash_kernels(*shape, mask=rule))
+    dkv_forms_agree(B, H, G, 2 * L, D, jnp.bfloat16, args.seed, rule,
+                    TOL["attn_bf16"])
 
     hc_stat_vs_jnp(*SIZES["hc"], jnp.bfloat16, args.seed)
     for shape in SIZES["moe_rows"]:

@@ -8,8 +8,11 @@ the gridded path (one pipeline step a tile) otherwise; where the whole
 backward is resident it is ONE kernel (`hvd_flash_bwd`), else dQ and
 dK/dV apart. This sweeps (block_q, block_k) on each path — `resident`
 (the one-kernel backward where its blocks tile), `split` (a budget one
-byte short of the one kernel's: the two resident backward kernels) and
-`gridded` — for the kernels alone: forward, dQ, dK/dV, and the whole
+byte short of the one kernel's: the two resident backward kernels, dK/dV
+held by the k block), `q-held` (one byte short of what THAT dK/dV holds:
+its second resident form, held by the q block with dK and dV summed in
+VMEM; grouped heads only, with one head a kv head the two hold the same)
+and `gridded` — for the kernels alone: forward, dQ, dK/dV, and the whole
 backward (one kernel, or the sum of the two). A backward kernel whose
 gradients are unused is dropped by XLA, so each is timed by itself, with
 the single-dispatch lax.scan recipe. The first row of a path is the
@@ -18,7 +21,14 @@ plan's own blocks. The block tables (`_resident_blocks`;
 off it.
 
 Usage: python examples/flash_block_sweep.py [--B 2 --L 2048 --H 16 --D 128]
-           [--path all|resident|split|gridded] [--kernels all|bwd]
+           [--path all|resident|split|q-held|gridded] [--kernels all|bwd]
+           [--mask-block N]
+`--mask-block N`: block-diffusion training's mask by rule in place of the
+causal triangle (L counts both copies of the sequence, blocks of N tokens);
+the forward and dQ take a rule resident only, so `gridded` then grids dK/dV
+alone. The block-diffusion cell's call (`sdar30b_1chip`):
+    --B 1 --H 32 --G 4 --L 8192 --mask-block 4 --path q-held,gridded \
+    --kernels bwd --bqp 64,128,256 --bk 512,1024
 GQA/MQA (--G < --H) sweeps the grouped-rows layout: the q-block
 candidates become bqp*group rows. The `_grouped_blocks` policy was
 tuned from this sweep at two points — B2 H6 G2 L8192 D128 (1536/512)
@@ -45,14 +55,26 @@ fa = importlib.import_module("horovod_tpu.ops.flash_attention")
 BWD = profile.FLASH_BWD
 
 
-def budgets(B, H, L, D, group, dtype, rotary):
-    """`vmem_budget` that forces a path: nothing fits 0, everything fits
-    2**40, and one byte less than the one-kernel backward holds keeps the
-    two resident backward kernels (each holds less)."""
-    fused = fa.flash_plan(B, H, L, D, group, dtype, True, rotary,
-                          vmem_budget=2 ** 40)
-    return {"gridded": 0, "split": fused[BWD].resident_bytes - 1,
-            "resident": 2 ** 40}
+def budgets(B, H, L, D, group, dtype, rotary, rule):
+    """`vmem_budget` that forces a path: everything fits 2**40, and one
+    byte less than a form holds gives the next that `flash_plan` tries:
+    the two resident backward kernels (`split`), dK/dV held by the q block
+    (`q-held`; with one head a kv head it holds what `split`'s does, and
+    there is no such budget), then gridded (under a rule dK/dV alone: one
+    byte short of its last resident form; else nothing fits 0)."""
+    def dkv(budget):
+        return fa.flash_plan(B, H, L, D, group, dtype, True, rotary,
+                             vmem_budget=budget, mask=rule)[profile.FLASH_DKV]
+
+    out = {"resident": 2 ** 40}
+    out["split"] = fa.flash_plan(
+        B, H, L, D, group, dtype, True, rotary, vmem_budget=2 ** 40,
+        mask=rule)[BWD].resident_bytes - 1
+    last = out["split"]
+    if group > 1:
+        last = out["q-held"] = dkv(last).resident_bytes - 1
+    out["gridded"] = 0 if rule is None else dkv(last).resident_bytes - 1
+    return out
 
 
 def timed(fn, args, iters=30):
@@ -94,7 +116,12 @@ def main():
     ap.add_argument("--rotary", action="store_true",
                     help="fused rotary (base 10000)")
     ap.add_argument("--path", default="all",
-                    choices=("all", "resident", "split", "gridded"))
+                    help="all, or of resident, split, q-held, gridded, "
+                         "with commas")
+    ap.add_argument("--mask-block", type=int, default=0,
+                    help="block-diffusion's mask by rule over 2 x L/2 "
+                         "positions in blocks of this many tokens, in "
+                         "place of the causal triangle")
     ap.add_argument("--kernels", default="all", choices=("all", "bwd"),
                     help="bwd: leave the forward kernel out")
     ap.add_argument("--bqp", default="128,256,512",
@@ -106,6 +133,9 @@ def main():
     G = args.G or H
     group = H // G
     base = 10000.0 if args.rotary else None
+    rule = (fa.BlockDiffusionMask(L // 2, args.mask_block)
+            if args.mask_block else None)
+    causal = rule is None
 
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(B, H, L, D), jnp.bfloat16)
@@ -115,16 +145,19 @@ def main():
     scale = D ** -0.5
     rows = L * group
     out, lse = jax.jit(lambda q, k, v: fa._pallas_forward_lse(
-        q, k, v, scale, True, False, rotary_base=base))(q, k, v)
+        q, k, v, scale, causal, False, rotary_base=base, rule=rule))(q, k, v)
 
-    print("shape B=%d L=%d H=%d G=%d D=%d%s (kernel layout, %d rows/slab)"
-          % (B, L, H, G, D, " rotary" if base else "", rows))
+    print("shape B=%d L=%d H=%d G=%d D=%d%s%s (kernel layout, %d rows/slab)"
+          % (B, L, H, G, D, " rotary" if base else "",
+             "" if rule is None else " %r" % (rule,), rows))
     for backward in (False, True):
         for name, plan in fa.flash_plan(B, H, L, D, group, q.dtype,
-                                        backward, base is not None).items():
+                                        backward, base is not None,
+                                        mask=rule).items():
             print("default plan %s: %s" % (name, plan._asdict()))
-    by_path = budgets(B, H, L, D, group, q.dtype, base is not None)
-    paths = tuple(by_path) if args.path == "all" else (args.path,)
+    by_path = budgets(B, H, L, D, group, q.dtype, base is not None, rule)
+    paths = tuple(by_path) if args.path == "all" else tuple(
+        args.path.split(","))
     print("%9s %6s %6s | %9s %9s %9s %9s" % (
         "path", "bq", "bk", "fwd ms", "dq ms", "dkv ms", "bwd ms"))
 
@@ -148,15 +181,20 @@ def main():
         for bq, bk in candidates:
             def fwd(q, bq=bq, bk=bk):
                 return fa._pallas_forward_lse(
-                    q, k, v, scale, True, False, bq, bk, base, budget)[0]
+                    q, k, v, scale, causal, False, bq, bk, base, budget,
+                    rule=rule)[0]
 
             def bwd(q, bq=bq, bk=bk):
                 return fa._pallas_backward(
-                    q, k, v, out, lse, g, scale, True, False, bq, bk,
-                    base, budget)
+                    q, k, v, out, lse, g, scale, causal, False, bq, bk,
+                    base, budget, rule=rule)
 
-            plan = fa.flash_plan(B, H, L, D, group, q.dtype, True,
-                                 base is not None, bq, bk, budget)
+            try:
+                plan = fa.flash_plan(B, H, L, D, group, q.dtype, True,
+                                     base is not None, bq, bk, budget,
+                                     mask=rule)
+            except ValueError:  # blocks the rule's length does not take
+                continue
             t_fwd = ms(fwd) if args.kernels == "all" else "-"
             if BWD in plan:  # one kernel: its three results at once
                 t_dq = t_dkv = "-"

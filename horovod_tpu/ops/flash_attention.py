@@ -15,6 +15,13 @@ argument, no environment variable:
   ds.k to dQ's rows as it goes, so s, p, dp and ds are formed once a
   tile (5 matmuls and one exp where the two kernels make 7 and two);
   its k-block axis carries dQ and runs in order.
+  dK/dV has a second resident form for the calls whose head group makes
+  q and dO too large (3 KiB a row of the grouped layout, a row a position
+  and query head): held by the q block, on dQ's grid (batch*kv_head,
+  q-block) and walk, with k, v and the two results whole in VMEM and dK
+  and dV summed over the q blocks in two f32 [L, D] accumulators in
+  scratch (3 KiB a position whatever the group), rounded and written at
+  the (batch, kv head)'s last q block; its q-block axis runs in order.
   The other sequence comes in as ONE block per
   batch*kv_head: its block index does not change across the inner grid
   axis, so Pallas fetches it once and double-buffers the next one behind
@@ -592,10 +599,12 @@ def _clamp_to_runs(i, runs):
     return jnp.where(nxt < big, nxt, last)
 
 
-def _rule_tiles(rule, kernel, positions, bqp, bk):
+def _rule_tiles(rule, held, positions, bqp, bk):
     """(visited, masked, skipped) score tiles of ONE (batch, kv head) of a
-    kernel under `rule`, from the runs the kernel walks."""
-    if kernel in _K_HELD:
+    kernel under `rule`, from the runs the kernel walks: the query tiles of
+    each k block where it holds a k block (`held` "k"), else the key tiles
+    of each q block."""
+    if held == "k":
         runs = rule.query_runs(np.arange(0, positions, bk), bk, bqp, np)
     else:
         runs = rule.key_runs(np.arange(0, positions, bqp), bqp, bk, np)
@@ -629,16 +638,23 @@ _DEFAULT_VMEM_LIMIT = 16 * 2 ** 20
 
 FlashKernelPlan = collections.namedtuple(
     "FlashKernelPlan",
-    "path block_q block_k grid grid_steps resident_bytes vmem_bytes "
+    "path held block_q block_k grid grid_steps resident_bytes vmem_bytes "
     "vmem_limit_bytes tiles_visited tiles_masked tiles_skipped",
     defaults=(None, None, None))
 FlashKernelPlan.__doc__ = """How one flash kernel of a call runs.
 
 path: "resident" (grid (B*G, blocks); the other sequence whole in VMEM,
 walked by a loop in the kernel) or "gridded" (grid (B*G, blocks, blocks),
-one pipeline step a tile). block_q counts ROWS of the grouped layout.
+one pipeline step a tile). held: the side a grid step holds ONE block of
+while it walks the other's: "q" (the forward, dQ; the grid's block axis
+counts q blocks) or "k" (the one-kernel backward; k blocks). dK/dV is "k"
+gridded and in its first resident form (q, dO, lse and delta whole in
+VMEM), and "q" in its second, taken where the first does not fit: k, v and
+the results whole in VMEM, dK and dV summed over the q blocks in two f32
+accumulators there. block_q counts ROWS of the grouped layout.
 grid_steps: pipeline steps the call issues. resident_bytes: the
-whole-sequence operands, double-buffered (0 when gridded). vmem_bytes:
+whole-sequence operands, double-buffered, and a resident kernel's f32
+accumulators, one buffer (0 when gridded). vmem_bytes:
 what the call's block specs and scratch take as padded in VMEM, both
 pipeline buffers counted. vmem_limit_bytes: what the call passes to
 Mosaic, from its own sum — the buffers, the kernel's values (s, p, dp,
@@ -669,6 +685,10 @@ _SHARED_OPERANDS = {profile.FLASH_FWD: (1, 1), profile.FLASH_DQ: (2, 1),
 # The kernels that hold a k block and walk the q blocks (the others hold a
 # q block and walk the k blocks).
 _K_HELD = (profile.FLASH_DKV, profile.FLASH_BWD)
+# dK/dV's resident forms by the side a grid step holds a block of, in the
+# order `flash_plan` tries them (tests narrow it to ("q",): with one head a
+# kv head the second fits only where the first does).
+_DKV_HELD = ("k", "q")
 
 
 def _resident_blocks(D, L, group, kernel):
@@ -708,15 +728,24 @@ def _resident_blocks(D, L, group, kernel):
 
 
 def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
-                 block_k, vmem_budget, D2=0, rule=None):
+                 block_k, vmem_budget, D2=0, rule=None, held=None):
+    """``held``: the side a grid step holds a block of, "k" for the kernels
+    of `_K_HELD` and "q" for the others unless given: dK/dV has a resident
+    form of either kind (`flash_plan` tries "k" first)."""
     backward = kernel != profile.FLASH_FWD
-    dkv = kernel in _K_HELD
+    k_held = (kernel in _K_HELD) if held is None else held == "k"
+    held = "k" if k_held else "q"
     n_q, n_k, n_stripes = _OPERANDS[kernel]
     n_q2, n_k2 = _SHARED_OPERANDS[kernel]
     # The whole backward in one kernel: dQ's f32 accumulator, one buffer
-    # (and the second product's dQ2's beside it).
+    # (and the second product's dQ2's beside it). dK/dV held by the q
+    # block: dK's and dV's, which the gridded form keeps a k block of.
     fused = kernel == profile.FLASH_BWD
-    dq_acc = _vmem(rows, D, 4) + _vmem(rows, D2, 4) if fused else 0
+    q_held_dkv = kernel == profile.FLASH_DKV and not k_held
+    if fused:
+        accumulators = _vmem(rows, D, 4) + _vmem(rows, D2, 4)
+    else:
+        accumulators = 2 * _vmem(L, D, 4) if q_held_dkv else 0
     tables = 2 * 4 if rotary else 0  # (C, S) f32, per side
 
     def q_side(n):  # one pipeline buffer of n rows of every q-side operand
@@ -738,15 +767,18 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
             rule.check(L, bq // group, bk)
         return bq, bk
 
-    whole = q_side(rows) if dkv else k_side(L)
-    resident = 2 * whole + dq_acc
+    whole = q_side(rows) if k_held else k_side(L)
+    resident = 2 * whole + accumulators
     if resident <= vmem_budget:
-        bq, bk = blocks(_resident_blocks(D, L, group, kernel))
+        # Held by the q block, dK/dV walks the k blocks as dQ does: on
+        # dQ's blocks.
+        bq, bk = blocks(_resident_blocks(
+            D, L, group, profile.FLASH_DQ if q_held_dkv else kernel))
         bqp = bq // group
         # The loop's peel is static only where one block tiles the other
         # (every pair the tables give; a caller's own blocks may not).
         if bqp % bk == 0 or bk % bqp == 0:
-            buffers = resident + 2 * (k_side(bk) if dkv else q_side(bq))
+            buffers = resident + 2 * (k_side(bk) if k_held else q_side(bq))
             # Beside the buffers the kernel's values live in VMEM too: s,
             # p, dp, ds, their low-precision copies, the carried state.
             # Mosaic's own need, by a compile for a described v5e, is
@@ -757,12 +789,13 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
             values = (6 * bq * bk * 4 + 4 * _vmem(max(bq, bk), D, 4)
                       + 2 * _vmem(max(bq, bk), D2, 4))
             limit = -(-(buffers + values) * 5 // 4 // 2 ** 20) * 2 ** 20
-            grid = (BG, L // bk if dkv else rows // bq)
+            grid = (BG, L // bk if k_held else rows // bq)
             return FlashKernelPlan(
-                "resident", bq, bk, grid, grid[0] * grid[1], resident,
+                "resident", held, bq, bk, grid, grid[0] * grid[1], resident,
                 buffers, max(_DEFAULT_VMEM_LIMIT, limit))
     if fused:
         return None  # no gridded form: a grid carries dQ or dK/dV, not both
+    dkv = kernel == profile.FLASH_DKV  # gridded, it holds a k block
     bq, bk = blocks(_grouped_blocks(D, L, group, backward))
     num_qb, num_kb = rows // bq, L // bk
     # acc / dq_acc (and m, l) per q block, or dk_acc + dv_acc per k block,
@@ -771,8 +804,9 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
                + (0 if backward else 2 * _vmem(bq, 128, 4))
                + (_vmem(bk if dkv else bq, D, isz) if rotary else 0))
     grid = (BG, num_kb, num_qb) if dkv else (BG, num_qb, num_kb)
-    return FlashKernelPlan("gridded", bq, bk, grid, BG * num_qb * num_kb,
-                           0, 2 * (q_side(bq) + k_side(bk)) + scratch, None)
+    return FlashKernelPlan("gridded", "k" if dkv else "q", bq, bk, grid,
+                           BG * num_qb * num_kb, 0,
+                           2 * (q_side(bq) + k_side(bk)) + scratch, None)
 
 
 def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
@@ -794,6 +828,18 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     one head a kv head the one-kernel backward holds 8 MiB at L=2048, 16
     at 4096 and 32 at 8192, where the budget keeps the two.
 
+    dK/dV has a SECOND resident form, tried where the first does not fit
+    (the order is `_DKV_HELD`'s): held by the q block (`held` "q", its
+    grid's block axis counts q blocks and runs in order), with k, v (and k's
+    tables) and the two results whole in VMEM, double-buffered, and dK and
+    dV summed over the q blocks in two f32 accumulators [L, D] there: 3
+    KiB a position at D <= 128 in bf16 whatever the head group, where the
+    first form's q + dO + lse + delta are 3 KiB a position and query head
+    of the group. So with one head a kv head the second fits exactly where
+    the first does and is never taken; with 8 (D=128, L=8192) the first
+    would hold 192 MiB and the second holds 24. Gridded where neither
+    fits. It has one score product: no ``shared_dim``.
+
     ``shared_dim`` = D2 > 0: the scores are of two products, q [.., D] on k
     and q2 [.., D2] on ONE key k2 a position for all H heads
     (`flash_attention`'s ``q_shared``, ``k_shared``); v is D wide. The sums
@@ -810,10 +856,11 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     The same choice of path and kernels, with blocks that divide the rule's
     length, and every plan says how many score tiles its kernel visits,
     masks and skips. The forward and dQ take a rule in their resident form
-    only and the one-kernel backward as ever; dK/dV alone also gridded (at
-    D=128 in bf16 with 8 heads a kv head and 8192 positions: the forward
-    and dQ resident on k + v, 8 MiB; dK/dV gridded, q + dO of a kv head
-    being 64 MiB). Where the forward or dQ would be gridded the result is
+    only and the one-kernel backward as ever; dK/dV in all three of its
+    forms (at D=128 in bf16 with 8 heads a kv head and 8192 positions: the
+    forward and dQ resident on k + v, 8 MiB; dK/dV resident too, held by
+    the q block: k, v, dk, dv and the two accumulators, 24 MiB, q + dO of a
+    kv head being 64). Where the forward or dQ would be gridded the result is
     ``{}`` and the call is the blockwise jnp form; fused rotary and a second
     score product are refused beside a rule.
 
@@ -827,15 +874,24 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
         raise ValueError("a mask by rule repeats positions and has one "
                          "score product: rotate outside the kernels")
 
-    def plan(kernel):
+    def plan(kernel, held=None):
         p = _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary,
-                         block_q, block_k, vmem_budget, shared_dim, mask)
+                         block_q, block_k, vmem_budget, shared_dim, mask,
+                         held)
         if mask is None or p is None:
             return p
         return p._replace(**dict(zip(
             ("tiles_visited", "tiles_masked", "tiles_skipped"),
-            (BG * n for n in _rule_tiles(mask, kernel, L,
+            (BG * n for n in _rule_tiles(mask, p.held, L,
                                          p.block_q // group, p.block_k)))))
+
+    def dkv_plan():
+        """dK/dV's first resident form that fits, else gridded."""
+        for held in ("k",) if shared_dim else _DKV_HELD:
+            p = plan(profile.FLASH_DKV, held)
+            if p.path == "resident":
+                break
+        return p
 
     def resident_or_none(plans):
         if any(p.path != "resident" for name, p in plans.items()
@@ -849,13 +905,14 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     fused = plan(profile.FLASH_BWD)
     if fused is not None:
         return {profile.FLASH_BWD: fused}
-    return resident_or_none({name: plan(name) for name in (
-        profile.FLASH_DQ, profile.FLASH_DKV)})
+    return resident_or_none({profile.FLASH_DQ: plan(profile.FLASH_DQ),
+                             profile.FLASH_DKV: dkv_plan()})
 
 
 def _compiler_params(plan, carries=False):
     """``carries``: the resident grid's block axis carries state in
-    scratch (the one-kernel backward's dQ), so its steps run in order."""
+    scratch (the one-kernel backward's dQ; dK and dV where dK/dV is held by
+    the q block), so its steps run in order."""
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary" if carries
                              else "parallel")
@@ -1174,6 +1231,85 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
                 return carry
 
             lax.fori_loop(0, q_ref.shape[0] // bq, store, 0)
+
+
+def _bwd_dkv_q_held_kernel(*refs, scale, causal, bk, bqp, group, rotary,
+                            rule=None):
+    """dK/dV held by the q block, with k and v whole in VMEM: what
+    `_bwd_dkv_kernel` computes, on `_bwd_dq_resident_kernel`'s grid and walk.
+    A step takes a q block (q, dO, lse, delta), walks the k blocks it sees
+    and adds each tile's p^T.dO and ds^T.q to the rows of that k block in
+    two f32 [L, D] accumulators, which live in VMEM scratch across the
+    grid's q-block axis: zeroed at the first q block of a (batch, kv head),
+    rounded once and written to the results (dK counter-rotated under fused
+    rotary), whole blocks too, at the last. A k block's sum over the q
+    blocks, the head group's rows among them, runs in ascending q order in
+    f32, as the gridded kernel's does."""
+    if rotary:
+        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
+         lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dk_acc, dv_acc) = refs
+    qi = pl.program_id(1)
+    num_kb = k_ref.shape[0] // bk
+
+    def k_block(j):
+        return pl.ds(pl.multiple_of(j * bk, bk), bk)
+
+    @pl.when(qi == 0)
+    def _init():
+        zeros = jnp.zeros((bk, dk_acc.shape[1]), jnp.float32)
+
+        def zero(j, carry):  # a k block at a time: bounded values
+            dk_acc[k_block(j), :] = zeros
+            dv_acc[k_block(j), :] = zeros
+            return carry
+
+        lax.fori_loop(0, num_kb, zero, 0)
+
+    q = q_ref[...]
+    if rotary:
+        q = _rot(q, qc_ref[...], qs_ref[...])
+    do = do_ref[...]
+    lse = lse_ref[:, :1]
+    delta = delta_ref[:, :1]
+
+    def visit(j, carry, masked):
+        at = k_block(j)
+        k = k_ref[at, :]
+        if rotary:
+            k = _rot(k, kc_ref[at, :], ks_ref[at, :])
+        s = _scores(q, k, scale)
+        if masked:
+            s = _mask_tile(s, rule, qi * bqp, j * bk, group)
+        p = jnp.exp(s - lse)  # masked entries: exp(-inf) = 0
+        dv_acc[at, :] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(
+            do, v_ref[at, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta) * scale).astype(q.dtype)
+        dk_acc[at, :] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return carry
+
+    _walk_k(visit, 0, qi, bqp, bk, num_kb, causal, rule)
+
+    @pl.when(qi == pl.num_programs(1) - 1)
+    def _finalize():
+        def store(j, carry):
+            at = k_block(j)
+            dk = dk_acc[at, :]
+            if rotary:
+                dk = _rot(dk, kc_ref[at, :], ks_ref[at, :], neg=True)
+            dk_ref[at, :] = dk.astype(dk_ref.dtype)
+            dv_ref[at, :] = dv_acc[at, :].astype(dv_ref.dtype)
+            return carry
+
+        lax.fori_loop(0, num_kb, store, 0)
 
 
 def _shared_operands(shared, B, G, group, plans):
@@ -1859,7 +1995,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     inputs' dtypes. Path and blocks per kernel from `flash_plan`. With
     ``shared`` = (q2 [B,H,L,D2], k2 [B,1,L,D2]) also (dq2, dk2) of those
     shapes, dk2 summed over the heads in f32. ``rule``: a mask by rule in
-    place of ``causal`` (dQ resident only, dK/dV in either form)."""
+    place of ``causal`` (dQ resident only, dK/dV in any of its forms)."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -1949,7 +2085,20 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     plan = plans[profile.FLASH_BWD if fused else profile.FLASH_DKV]
     bq, bk = plan.block_q, plan.block_k
     bqp = bq // group
-    if plan.path == "resident":
+    if plan.path == "resident" and plan.held == "q":
+        # dK/dV on dQ's grid: a q block a step; k, v and the results whole.
+        kernel = functools.partial(_bwd_dkv_q_held_kernel, scale=scale,
+                                   causal=causal, bk=bk, bqp=bqp,
+                                   group=group, rotary=rotary, **extra)
+        q_im, k_spec, tq_spec, tk_spec = _q_walk_specs(plan, L, D, group,
+                                                       causal)
+        q_spec = pl.BlockSpec((None, bq, D), q_im)
+        stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
+        # dK's and dV's accumulators across the q blocks of a (batch, kv
+        # head).
+        scratch = [pltpu.VMEM((L, D), jnp.float32),
+                   pltpu.VMEM((L, D), jnp.float32)]
+    elif plan.path == "resident":
         kernel = functools.partial(_bwd_dkv_resident_kernel, scale=scale,
                                    causal=causal, bq=bq, bqp=bqp,
                                    group=group, rotary=rotary,
@@ -1960,6 +2109,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         stripe_spec = pl.BlockSpec((None, rows, 8), lambda b, j: (b, 0, 0))
         tq_spec = pl.BlockSpec((rows, D), lambda b, j: (0, 0))
         tk_spec = pl.BlockSpec((bk, D), lambda b, j: (j, 0))
+        k_spec = pl.BlockSpec((None, bk, D), k_im)
         # dQ's accumulator across the k blocks of a (batch, kv head).
         scratch = [pltpu.VMEM((rows, D), jnp.float32)] if fused else []
         if fused and shared:
@@ -1978,11 +2128,12 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         tq_spec = pl.BlockSpec((bq, D), _q_index_map(bqp, bk, causal,
                                                      rank2=True))
         tk_spec = pl.BlockSpec((bk, D), lambda b, j, i: (j, 0))
+        k_spec = pl.BlockSpec((None, bk, D), k_im)
         scratch = [pltpu.VMEM((bk, D), jnp.float32),
                    pltpu.VMEM((bk, D), jnp.float32)] + (
             [pltpu.VMEM((bk, D), k.dtype)] if rotary else [])
-    k_spec = pl.BlockSpec((None, bk, D), k_im)
-    # dQ's block does not change across the k blocks: written back once.
+    # A result whose block does not change across the grid's block axis (the
+    # one kernel's dQ; dK and dV held by the q block) is written back once.
     results = _ruled_call(pl.pallas_call(
         kernel,
         name=profile.FLASH_BWD if fused else profile.FLASH_DKV,
@@ -2004,7 +2155,8 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
              if shared else []) + (
             [dq_shape] + ([dq2_shape] if shared else []) if fused else []),
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(plan, carries=fused),
+        compiler_params=_compiler_params(plan,
+                                         carries=fused or plan.held == "q"),
         interpret=interpret,
     ), profile.FLASH_BWD if fused else profile.FLASH_DKV, rule, plan, inputs,
         scale, interpret)(*inputs)

@@ -523,7 +523,10 @@ def flash_plan(*args, **kwargs):
     (`FLASH_FWD`; with ``backward=True`` `FLASH_BWD` alone where the whole
     backward is one resident kernel, else `FLASH_DQ` and `FLASH_DKV`): the
     path (`resident`: the other sequence whole in VMEM, one grid step per
-    block; `gridded`: one grid step per tile), the blocks, the grid and the
+    block; `gridded`: one grid step per tile), the side a grid step holds a
+    block of (`held`: "q" or "k"; dK/dV is resident by the k block where a
+    kv head's queries fit VMEM, else by the q block with dK and dV summed
+    in VMEM, else gridded), the blocks, the grid and the
     grid steps a call issues, and the VMEM bytes it asks for. With
     ``shared_dim=D2`` the call's scores are of two products (latent
     attention's rotary slice on one key shared by the heads): the same
@@ -535,8 +538,8 @@ def flash_plan(*args, **kwargs):
     positions) every plan also says how many score tiles of a call its
     kernel visits, masks and skips (`tiles_visited`, `tiles_masked`,
     `tiles_skipped`: the rule's own runs, which the kernel walks); the
-    forward and dQ take a rule resident only, dK/dV also gridded, ``{}``
-    otherwise. The kernels run what this returns, so like
+    forward and dQ take a rule resident only, dK/dV in any of its forms,
+    ``{}`` otherwise. The kernels run what this returns, so like
     `grad_collectives` it needs no chip."""
     # `ops.flash_attention` imports this module for its kernels' names.
     from horovod_tpu.ops.flash_attention import flash_plan as plan

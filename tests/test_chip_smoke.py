@@ -130,8 +130,8 @@ def test_kernels_phase_expects_what_the_plan_names(capsys):
 
 def test_kernels_phase_knows_the_block_diffusion_shape(capsys):
     """The smoke's mask-ruled attention: the kernels it requires at the
-    benchmark cell's shape are the plan's (forward and dQ resident, dK/dV
-    gridded), `print_flash_plan` prints the tiles each visits, and its case
+    benchmark cell's shape are the plan's (all three resident, dK/dV held by
+    the q block), `print_flash_plan` prints the tiles each visits, and its case
     (kernel against the dense masked softmax) agrees on the CPU at a small
     size, where `flash_attention` is the blockwise form."""
     import jax
@@ -150,7 +150,9 @@ def test_kernels_phase_knows_the_block_diffusion_shape(capsys):
     assert len(lines) == 3
     for line in lines:
         assert line.endswith("tiles visited 1280 (masked 384), skipped 2816")
-    assert lines[2].startswith("  hvd_flash_dkv: gridded, blocks 1024 x 512")
+    assert lines[2].startswith(
+        "  hvd_flash_dkv: resident held by the q block, blocks 1024 x 512, "
+        "grid (4, 64) = 256 steps, VMEM 27.0 MiB of a limit of 52")
     small = BlockDiffusionMask(128, 4)
     name, kernel, reference, qkvw = chip_smoke.attention_case(
         1, 4, 2, 256, 64, False, jnp.float32, 0, mask=small)

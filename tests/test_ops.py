@@ -247,6 +247,109 @@ def test_flash_resident_equals_gridded_in_bf16(other):
         assert np.max(np.abs(r - g)) <= 2 ** -8 * np.max(np.abs(g)), nm
 
 
+def _q_held_budget(monkeypatch, B, H, L, D, group, dtype, rotary,
+                   block_q=None, block_k=None):
+    """A `vmem_budget` under which `flash_plan` keeps dQ resident and takes
+    dK/dV's SECOND resident form, held by the q block: one byte short of
+    what the first holds (q, dO, lse and delta of the kv head's whole query
+    group). With one head a kv head the second holds as much as the first,
+    so no budget tells them apart: the order `flash_plan` tries them in is
+    narrowed to the second alone."""
+    import importlib
+    # the module: `ops` hands out the function under the same name
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    split = _split_budget(B, H, L, D, group, dtype, rotary, block_q, block_k)
+    if group == 1:
+        monkeypatch.setattr(fa, "_DKV_HELD", ("q",))
+        budget = split
+    else:
+        budget = fa.flash_plan(B, H, L, D, group, dtype, True, rotary,
+                               block_q, block_k, split)[
+                                   "hvd_flash_dkv"].resident_bytes - 1
+    plans = fa.flash_plan(B, H, L, D, group, dtype, True, rotary, block_q,
+                          block_k, budget)
+    assert {n: (p.path, p.held) for n, p in plans.items()} == {
+        "hvd_flash_dq": ("resident", "q"),
+        "hvd_flash_dkv": ("resident", "q")}, plans
+    dkv = plans["hvd_flash_dkv"]
+    assert dkv.grid == (B * H // group, L * group // dkv.block_q)
+    return budget
+
+
+@pytest.mark.parametrize("causal,H,G,rotary,bqp,bk", [
+    (True, 4, 4, None, 256, 512),     # group 1; a k block of two q blocks
+    (False, 4, 4, 10000.0, 512, 256),  # not causal: one loop, no peel
+    (True, 4, 2, None, 256, 512),     # group 2
+    (True, 4, 2, 10000.0, 512, 256),  # a q block of two k blocks
+    (False, 4, 2, None, 128, 128),
+    (True, 8, 1, None, 128, 512),     # group 8, the block-diffusion cell's
+    (True, 8, 1, 10000.0, 256, 128),
+    (False, 8, 1, 10000.0, 128, 256),
+])
+def test_flash_dkv_held_by_the_q_block_interpret(monkeypatch, causal, H, G,
+                                                 rotary, bqp, bk):
+    """dK/dV's second resident form (k, v and the results whole in VMEM, a
+    q block a grid step, dK and dV summed in two f32 accumulators in
+    scratch): against the gradient of `_blockwise_reference`, and against
+    the gridded kernel on the same inputs and blocks, which adds the same
+    tiles to a k block's sum in the same order. dQ beside it is the
+    resident kernel of old."""
+    from horovod_tpu.ops.flash_attention import (
+        _blockwise_reference, _pallas_backward, _pallas_forward_lse)
+    B, L, D = 1, 1024, 32
+    group = H // G
+    t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
+    q, k, v = (t(x) for x in _rand_gqa(B, L, H, G, D, seed=41))
+    w = jnp.asarray(np.random.RandomState(42).randn(B, H, L, D),
+                    jnp.float32)
+    budgets = {"gridded": 0, "q-held": _q_held_budget(
+        monkeypatch, B, H, L, D, group, q.dtype, rotary is not None,
+        bqp * group, bk)}
+    got = {}
+    for path, budget in budgets.items():
+        out, lse = _pallas_forward_lse(q, k, v, D ** -0.5, causal, True,
+                                       bqp * group, bk, rotary, budget)
+        got[path] = _pallas_backward(q, k, v, out, lse, w, D ** -0.5,
+                                     causal, True, bqp * group, bk, rotary,
+                                     budget)
+    _, vjp = jax.vjp(lambda q, k, v: _blockwise_reference(
+        q, k, v, D ** -0.5, causal, rotary), q, k, v)
+    for r, g, d, nm in zip(got["q-held"], got["gridded"], vjp(w),
+                           ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(r), np.asarray(d), rtol=2e-4,
+                                   atol=2e-4, err_msg=nm)
+        np.testing.assert_allclose(np.asarray(r), np.asarray(g), rtol=1e-6,
+                                   atol=1e-6, err_msg=nm)
+
+
+@pytest.mark.parametrize("H,G,rotary", [(4, 2, None), (8, 1, None),
+                                        (8, 1, 10000.0)])
+def test_flash_dkv_held_by_the_q_block_equals_gridded_in_bf16(monkeypatch, H,
+                                                              G, rotary):
+    """bf16 inputs, as the models feed them, on the plan's own blocks: dK
+    and dV of the q-held form and of the gridded `_bwd_dkv_kernel` agree to
+    bf16 rounding (both sum a k block's tiles in f32 and round once)."""
+    from horovod_tpu.ops.flash_attention import (
+        _pallas_backward, _pallas_forward_lse)
+    B, L, D = 1, 1024, 64
+    bf16 = jnp.bfloat16
+    q, k, v = (x.transpose(0, 2, 1, 3).astype(bf16)
+               for x in _rand_gqa(B, L, H, G, D, seed=43))
+    w = jnp.asarray(np.random.RandomState(44).randn(B, H, L, D), bf16)
+    got = []
+    for budget in (0, _q_held_budget(monkeypatch, B, H, L, D, H // G, bf16,
+                                     rotary is not None)):
+        out, lse = _pallas_forward_lse(q, k, v, D ** -0.5, True, True,
+                                       rotary_base=rotary,
+                                       vmem_budget=budget)
+        got.append(_pallas_backward(q, k, v, out, lse, w, D ** -0.5, True,
+                                    True, rotary_base=rotary,
+                                    vmem_budget=budget))
+    for g, r, nm in zip(got[0], got[1], ("dq", "dk", "dv")):
+        g, r = (np.asarray(x, np.float32) for x in (g, r))
+        assert np.max(np.abs(r - g)) <= 2 ** -8 * np.max(np.abs(g)), nm
+
+
 # B, H, L, D of the benchmark's cells: `lm1b4_1chip` and `lm1b4_dp4` (2
 # sequences of 2048 a chip) and `olmoe1b7_1chip` and `ouro2b6_1chip` (one
 # of 4096), 16 heads x 128, bf16, group 1, no fused rotary. Expected:

@@ -545,6 +545,137 @@ def test_flash_plan_under_hvd_profile_is_the_kernels_own(backward, B, L,
     assert all(p.path == "resident" for p in got.values())
 
 
+# A benchmark cell's flash call -> its plans as PR 44's tree gave them,
+# field for field but `held` (new in PR 45, with dK/dV's second resident
+# form): (path, block_q, block_k, grid, grid_steps, resident_bytes,
+# vmem_bytes, vmem_limit_bytes, tiles visited, masked, skipped). The six
+# cells whose backward is one kernel never reach the new branch; SDAR's
+# forward and dQ stay as they were; a fused-rotary grouped call at L=8192
+# and a plain one at 16384 fit neither resident form of dK/dV.
+_MiB = 2 ** 20
+_SDAR_TILES = (1280, 384, 2816)
+CELL_PLANS = {
+    "lm1b4_1chip": (dict(B=2, H=16, L=2048), {
+        "hvd_flash_fwd": ("resident", 512, 512, (32, 4), 128, 2 * _MiB,
+                          3 * _MiB, 16 * _MiB, None, None, None),
+        "hvd_flash_bwd": ("resident", 512, 1024, (32, 2), 64, 8 * _MiB,
+                          10 * _MiB, 30 * _MiB, None, None, None)}),
+    "olmoe1b7_1chip": (dict(B=1, H=16, L=4096), {
+        "hvd_flash_fwd": ("resident", 512, 512, (16, 8), 128, 4 * _MiB,
+                          5 * _MiB, 16 * _MiB, None, None, None),
+        "hvd_flash_bwd": ("resident", 512, 1024, (16, 4), 64, 16 * _MiB,
+                          18 * _MiB, 40 * _MiB, None, None, None)}),
+    "xing29b_1chip": (dict(B=1, H=32, L=4096, shared_dim=64), {
+        "hvd_flash_fwd": ("resident", 512, 512, (32, 8), 256, 6 * _MiB,
+                          7602176, 19 * _MiB, None, None, None),
+        "hvd_flash_bwd": ("resident", 512, 1024, (32, 4), 128, 22 * _MiB,
+                          25 * _MiB, 50 * _MiB, None, None, None)}),
+    "sdar30b_1chip": (dict(B=1, H=32, L=8192, group=8, mask=(4096, 4)), {
+        "hvd_flash_fwd": ("resident", 1024, 512, (4, 64), 256, 8 * _MiB,
+                          10 * _MiB, 30 * _MiB) + _SDAR_TILES,
+        "hvd_flash_dq": ("resident", 1024, 512, (4, 64), 256, 8 * _MiB,
+                         12058624, 32 * _MiB) + _SDAR_TILES}),
+    "grouped_fused_rotary_L8192": (
+        dict(B=2, H=6, L=8192, group=3, rotary=True), {
+            "hvd_flash_dq": ("resident", 1536, 512, (4, 16), 64, 24 * _MiB,
+                             33816576, 67 * _MiB, None, None, None),
+            "hvd_flash_dkv": ("gridded", 1536, 512, (4, 16, 16), 1024, 0,
+                              10616832, None, None, None, None)}),
+    "plain_L16384": (dict(B=1, H=16, L=16384), {
+        "hvd_flash_dq": ("resident", 512, 512, (16, 32), 512, 16 * _MiB,
+                         18612224, 31 * _MiB, None, None, None),
+        "hvd_flash_dkv": ("gridded", 512, 1024, (16, 16, 32), 8192, 0,
+                          4718592, None, None, None, None)}),
+}
+CELL_PLANS["lm1b4_dp4"] = CELL_PLANS["lm1b4_1chip"]
+CELL_PLANS["ouro2b6_1chip"] = CELL_PLANS["olmoe1b7_1chip"]
+
+
+def _cell_plans(B, H, L, mask=None, **kw):
+    from horovod_tpu.ops import BlockDiffusionMask
+
+    mask = mask and BlockDiffusionMask(*mask)
+    return {name: plan for backward in (False, True)
+            for name, plan in profile.flash_plan(
+                B, H, L, 128, backward=backward, mask=mask, **kw).items()}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PLANS))
+def test_flash_plans_of_the_cells_are_the_parents(cell):
+    call, expected = CELL_PLANS[cell]
+    plans = _cell_plans(**call)
+    assert set(expected) <= set(plans)
+    for name, want in expected.items():
+        got = plans[name]._asdict()
+        # A k block is held by the one-kernel backward and the gridded
+        # dK/dV, a q block by the forward and dQ.
+        assert got.pop("held") == (
+            "k" if name in ("hvd_flash_bwd", "hvd_flash_dkv") else "q")
+        assert tuple(got.values()) == want, name
+
+
+def test_flash_plan_holds_sdars_dkv_by_the_q_block():
+    """The block-diffusion cell's call: q, dO, lse and delta of a kv head's
+    8 query heads are 192 MiB double-buffered, so dK/dV is resident in its
+    SECOND form: a q block a grid step on dQ's grid and blocks, k, v, dk
+    and dv whole and two f32 accumulators in VMEM, 24 MiB, the tiles
+    counted by the key runs of each q block (`hvd_flash_dq` resident as
+    before). One byte less and it is the gridded kernel of old."""
+    from horovod_tpu.ops import BlockDiffusionMask, flash_attention as fa
+
+    fa = sys.modules[fa.__module__]  # the module, not the function
+    call = CELL_PLANS["sdar30b_1chip"][0]
+    plans = _cell_plans(**call)
+    assert sorted(plans) == ["hvd_flash_dkv", "hvd_flash_dq",
+                             "hvd_flash_fwd"]
+    dkv, dq = plans["hvd_flash_dkv"], plans["hvd_flash_dq"]
+    assert (dkv.path, dkv.held, dkv.block_q, dkv.block_k, dkv.grid,
+            dkv.grid_steps) == ("resident", "q", 1024, 512, (4, 64), 256)
+    assert (dkv.block_q, dkv.block_k, dkv.grid) == (
+        dq.block_q, dq.block_k, dq.grid)
+    assert (dkv.tiles_visited, dkv.tiles_masked,
+            dkv.tiles_skipped) == _SDAR_TILES
+    L, D = 8192, 128
+    # k, v, dk, dv in bf16, two buffers each; two f32 accumulators, one
+    assert dkv.resident_bytes == 2 * 4 * L * D * 2 + 2 * L * D * 4 \
+        == fa.RESIDENT_VMEM_BUDGET
+    assert dkv.resident_bytes < dkv.vmem_bytes < dkv.vmem_limit_bytes
+    gridded = fa.flash_plan(1, 32, L, D, 8, backward=True,
+                            vmem_budget=dkv.resident_bytes - 1,
+                            mask=BlockDiffusionMask(4096, 4))["hvd_flash_dkv"]
+    assert (gridded.path, gridded.held, gridded.grid, gridded.grid_steps,
+            gridded.tiles_visited) == ("gridded", "k", (4, 16, 64), 4096,
+                                       1280)
+
+
+# (B, H, L, group, fused rotary) of calls outside the cells -> dK/dV's form:
+# a head group's rows multiply what the k-held form holds (3 KiB a row at
+# D=128 in bf16) and not what the q-held form does (3 KiB a position, 5
+# with k's rotary tables), so grouped calls move to it up to L=8192 (4096
+# with fused rotary); with one head a kv head nothing does.
+@pytest.mark.parametrize("B,H,L,group,rotary,expected", [
+    (2, 6, 8192, 3, False, ("resident", "q")),
+    (1, 32, 2048, 8, False, ("resident", "q")),
+    (1, 32, 4096, 4, True, ("resident", "q")),
+    (1, 16, 4096, 2, False, ("resident", "k")),   # 24 MiB: the first form
+    (2, 6, 8192, 3, True, ("gridded", "k")),      # 40 MiB with k's tables
+    (1, 8, 16384, 2, False, ("gridded", "k")),
+    (1, 16, 8192, 1, True, ("gridded", "k")),     # both forms 40 MiB
+])
+def test_flash_plan_dkv_form_outside_the_cells(B, H, L, group, rotary,
+                                               expected):
+    plans = profile.flash_plan(B, H, L, 128, group, backward=True,
+                               rotary=rotary)
+    assert "hvd_flash_bwd" not in plans
+    dkv = plans["hvd_flash_dkv"]
+    assert (dkv.path, dkv.held) == expected
+    assert plans["hvd_flash_dq"].path == "resident"
+    # the grid's block axis counts blocks of the held side (the two can be
+    # as many: `held` says which)
+    assert dkv.grid[:2] == (B * H // group, L * group // dkv.block_q
+                            if dkv.held == "q" else L // dkv.block_k)
+
+
 # (B, L, D, V, chunk) -> (rows, iterations): the two LM cells' shapes cut
 # alike though one passes B=2 and the other B=1; `chunk=L` stays one shot; a
 # caller's B * chunk past the budget is kept; B * L = 3 * 1021 has no

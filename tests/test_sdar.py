@@ -224,16 +224,23 @@ def _kernel_case(length, H, G, D=64, seed=0):
 
 
 def _budget(path, H, G, S, D, bq, bk, rule):
-    """The VMEM budget that forces `path` of the backward under `rule`."""
+    """The VMEM budget that forces `path` of the backward under `rule`:
+    one byte short of what the form before it holds, in the order
+    `flash_plan` tries them (the one kernel; dQ beside dK/dV held by the k
+    block; by the q block; gridded)."""
     if path == "one kernel":
         return fa.RESIDENT_VMEM_BUDGET
     plan = lambda budget: fa.flash_plan(  # noqa: E731
         1, H, S, D, H // G, jnp.float32, True, False, bq, bk, budget,
         mask=rule)
-    fused = plan(fa.RESIDENT_VMEM_BUDGET)[profile.FLASH_BWD].resident_bytes
-    if path == "two resident":
-        return fused - 1
-    return plan(fused - 1)[profile.FLASH_DKV].resident_bytes - 1
+    budget = plan(fa.RESIDENT_VMEM_BUDGET)[
+        profile.FLASH_BWD].resident_bytes - 1
+    for _ in range(("two resident", "q-held dK/dV",
+                    "gridded dK/dV").index(path)):
+        dkv = plan(budget)[profile.FLASH_DKV]
+        if dkv.path == "resident":
+            budget = dkv.resident_bytes - 1
+    return budget
 
 
 # (block, heads, kv heads, rows of a q block, k block): at 2 x 256 positions
@@ -243,25 +250,34 @@ KERNEL_CASES = [(4, 4, 2, 64, 128), (16, 4, 1, 128, 128), (16, 2, 2, 64, 128)]
 
 
 @pytest.mark.parametrize("path", ["one kernel", "two resident",
-                                  "gridded dK/dV"])
+                                  "q-held dK/dV", "gridded dK/dV"])
 @pytest.mark.parametrize("block,H,G,bq,bk", KERNEL_CASES)
 def test_ruled_kernels_agree_with_a_dense_masked_softmax(block, H, G, bq, bk,
-                                                         path):
+                                                         path, monkeypatch):
     length, D = 256, 64
     rule = BlockDiffusionMask(length, block)
     q, k, v, w = _kernel_case(length, H, G, D)
     mask = jnp.asarray(_dense_mask(rule))
     want, vjp = jax.vjp(lambda *a: _dense_attention(*a, D ** -0.5, mask),
                         q, k, v)
-    budget = _budget(path, H, G, 2 * length, D, bq, bk, rule)
+    forced = path
+    if path == "q-held dK/dV" and H == G:
+        # One head a kv head: dK/dV held by the q block holds as much as
+        # held by the k block, so no budget reaches it; the order does.
+        monkeypatch.setattr(fa, "_DKV_HELD", ("q",))
+        forced = "two resident"
+    budget = _budget(forced, H, G, 2 * length, D, bq, bk, rule)
     plans = fa.flash_plan(1, H, 2 * length, D, H // G, q.dtype, True, False,
                           bq, bk, budget, mask=rule)
-    assert {n: p.path for n, p in plans.items()} == {
-        "one kernel": {profile.FLASH_BWD: "resident"},
-        "two resident": {profile.FLASH_DQ: "resident",
-                         profile.FLASH_DKV: "resident"},
-        "gridded dK/dV": {profile.FLASH_DQ: "resident",
-                          profile.FLASH_DKV: "gridded"}}[path]
+    resident = lambda held: {  # noqa: E731
+        profile.FLASH_DQ: ("resident", "q"),
+        profile.FLASH_DKV: ("resident", held)}
+    assert {n: (p.path, p.held) for n, p in plans.items()} == {
+        "one kernel": {profile.FLASH_BWD: ("resident", "k")},
+        "two resident": resident("k"),
+        "q-held dK/dV": resident("q"),
+        "gridded dK/dV": {profile.FLASH_DQ: ("resident", "q"),
+                          profile.FLASH_DKV: ("gridded", "k")}}[path]
     kw = dict(block_q=bq, block_k=bk, vmem_budget=budget, rule=rule)
     out, lse = fa._pallas_forward_lse(q, k, v, D ** -0.5, False, True, **kw)
     _close(out, want, 2e-6)
@@ -297,15 +313,23 @@ def test_flash_plan_counts_the_tiles_the_dense_mask_has(length, block, group,
 
 
 def test_the_cells_shape_runs_kernels_in_both_directions():
-    """1 x 32 heads on 4, 8192 positions, D 128: the forward and dQ resident
-    on k + v, dK/dV gridded, 5/16 of the tiles visited."""
+    """1 x 32 heads on 4, 8192 positions, D 128: all three kernels resident
+    on k + v and held by the q block (q + dO of a kv head's 8 query heads
+    do not fit; dK/dV holds dk, dv and their two accumulators too: 24 MiB),
+    5/16 of the tiles visited; gridded dK/dV would walk the same tiles."""
     rule = BlockDiffusionMask(4096, 4)
     plans = {n: p for b in (False, True) for n, p in fa.flash_plan(
         1, 32, 8192, 128, 8, jnp.bfloat16, b, mask=rule).items()}
-    assert {n: p.path for n, p in plans.items()} == {
-        profile.FLASH_FWD: "resident", profile.FLASH_DQ: "resident",
-        profile.FLASH_DKV: "gridded"}
-    for p in plans.values():
+    gridded = fa.flash_plan(1, 32, 8192, 128, 8, jnp.bfloat16, True,
+                            vmem_budget=plans[profile.FLASH_DKV]
+                            .resident_bytes - 1, mask=rule)[profile.FLASH_DKV]
+    assert (gridded.path, gridded.held, gridded.grid) == (
+        "gridded", "k", (4, 16, 64))
+    assert plans[profile.FLASH_DKV].resident_bytes == 24 * 2 ** 20
+    for p in list(plans.values()) + [gridded]:
+        if p is not gridded:
+            assert (p.path, p.held, p.grid, p.grid_steps) == (
+                "resident", "q", (4, 64), 256)
         assert (p.block_q, p.block_k) == (1024, 512)
         assert (p.tiles_visited, p.tiles_masked, p.tiles_skipped) == (
             1280, 384, 2816)
