@@ -59,13 +59,13 @@ SIZES = dict(
              moe_experts=16, moe_every=1, moe_top_k=4, moe_gated=True,
              moe_renormalize=False, moe_capacity_factor=None),
     moe_batch=4, moe_len=1024, moe_steps=4,
-    # (B, H, G, L, D, fused rotary): the L=1024 LM row's attention, the
-    # long-context h6/gqa2/frope row's (its backward two kernels, dK/dV
-    # gridded: with k's rotary tables neither resident form fits) and that
-    # of the benchmark's 2 x 2048 LM cells (its backward one kernel, as at
-    # L=1024).
-    attn=[(8, 12, 12, 1024, 64, False), (2, 6, 2, 8192, 128, True),
-          (2, 16, 16, 2048, 128, False)],
+    # (B, H, G, L, D): the L=1024 LM row's attention, a long grouped call's
+    # (its backward two kernels, dK/dV GRIDDED by its length alone: held by
+    # the q block it would have k, v, the results and two accumulators
+    # whole, 48 MiB of the 24) and that of the benchmark's 2 x 2048 LM cells
+    # (its backward one kernel, as at L=1024).
+    attn=[(8, 12, 12, 1024, 64), (1, 4, 2, 16384, 128),
+          (2, 16, 16, 2048, 128)],
     # (B, H, L, D, D2) of latent attention's scores of two products at the
     # benchmark's `xing29b_1chip` (printed only: that cell's own comparison
     # with its reference runs the kernels).
@@ -360,7 +360,7 @@ def compile_with_text(jitted, *call_args):
     return compiled, compiled.as_text(), time.perf_counter() - t0
 
 
-def attention_case(B, H, G, L, D, rotary, dtype, seed, mask=None):
+def attention_case(B, H, G, L, D, dtype, seed, mask=None):
     """flash_attention forward and backward alone at one shape, and
     _blockwise_reference doing the same: (name, kernel, reference,
     (q, k, v, cotangent)), both jitted and returning (out, dq, dk, dv).
@@ -379,12 +379,11 @@ def attention_case(B, H, G, L, D, rotary, dtype, seed, mask=None):
     k = jax.random.normal(kk, (B, L, G, D), jnp.float32).astype(dtype)
     v = jax.random.normal(kv, (B, L, G, D), jnp.float32).astype(dtype)
     w = jax.random.normal(kw, (B, L, H, D), jnp.float32)
-    base = 10000.0 if rotary else None
 
     def kernel(q, k, v):
         if mask is not None:
             return flash_attention(q, k, v, mask=mask)
-        return flash_attention(q, k, v, causal=True, rotary_base=base)
+        return flash_attention(q, k, v, causal=True)
 
     def reference(q, k, v):
         if mask is not None:
@@ -397,8 +396,7 @@ def attention_case(B, H, G, L, D, rotary, dtype, seed, mask=None):
                 q, k, v, mask.length, mask.block, 0))(
                     f32(q), f32(k), f32(v)).astype(q.dtype)
         t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
-        return t(_blockwise_reference(t(q), t(k), t(v), D ** -0.5, True,
-                                      base))
+        return t(_blockwise_reference(t(q), t(k), t(v), D ** -0.5, True))
 
     def both(fn):
         def f(q, k, v, w):
@@ -406,15 +404,15 @@ def attention_case(B, H, G, L, D, rotary, dtype, seed, mask=None):
             return (out,) + vjp(w.astype(out.dtype))
         return jax.jit(f)
 
-    name = "B%d H%d G%d L%d D%d%s%s %s" % (
-        B, H, G, L, D, " rotary" if rotary else "",
+    name = "B%d H%d G%d L%d D%d%s %s" % (
+        B, H, G, L, D,
         "" if mask is None else " %s(%d, %d)" % ((type(mask).__name__,)
                                                 + tuple(mask)),
         jnp.dtype(dtype).name)
     return name, both(kernel), both(reference), (q, k, v, w)
 
 
-def flash_kernels(B, H, G, L, D, rotary, dtype, mask=None):
+def flash_kernels(B, H, G, L, D, dtype, mask=None):
     """The names of the kernels a forward and backward of this shape run
     (`hvd.profile.flash_plan`): the forward's, then the backward's one
     (`hvd_flash_bwd`) or two."""
@@ -422,7 +420,7 @@ def flash_kernels(B, H, G, L, D, rotary, dtype, mask=None):
 
     return [name for backward in (False, True)
             for name in profile.flash_plan(B, H, L, D, H // G, dtype,
-                                           backward, rotary, mask=mask)]
+                                           backward, mask=mask)]
 
 
 def model_flash_kernels(model, batch, length, dtype):
@@ -430,10 +428,10 @@ def model_flash_kernels(model, batch, length, dtype):
     outside the kernels)."""
     heads = model["num_heads"]
     return flash_kernels(batch, heads, heads, length,
-                         model["embed_dim"] // heads, False, dtype)
+                         model["embed_dim"] // heads, dtype)
 
 
-def print_flash_plan(B, H, G, L, D, rotary, dtype, shared_dim=0, mask=None):
+def print_flash_plan(B, H, G, L, D, dtype, shared_dim=0, mask=None):
     """Which path each flash kernel of this shape takes (`hvd.profile`);
     `shared_dim`: the width of a second score product on one shared key;
     `mask`: a rule in place of the causal triangle, whose plans count the
@@ -444,8 +442,8 @@ def print_flash_plan(B, H, G, L, D, rotary, dtype, shared_dim=0, mask=None):
 
     for backward in (False, True):
         for name, plan in profile.flash_plan(
-                B, H, L, D, H // G, dtype, backward, rotary,
-                shared_dim=shared_dim, mask=mask).items():
+                B, H, L, D, H // G, dtype, backward, shared_dim=shared_dim,
+                mask=mask).items():
             path = plan.path + (" held by the %s block" % plan.held
                                 if name == profile.FLASH_DKV else "")
             print("  %s: %s, blocks %d x %d, grid %s = %d steps, VMEM %.1f "
@@ -792,12 +790,12 @@ def phase_kernels(args):
     B, H, L, D, D2 = SIZES["attn_two_products"]
     print("  scores of two products, %d + %d wide on %d heads at L=%d:"
           % (D, D2, H, L), flush=True)
-    print_flash_plan(B, H, H, L, D, False, jnp.bfloat16, shared_dim=D2)
+    print_flash_plan(B, H, H, L, D, jnp.bfloat16, shared_dim=D2)
     from horovod_tpu.ops import BlockDiffusionMask
 
     B, H, G, L, D, block = SIZES["attn_block_diffusion"]
     rule = BlockDiffusionMask(L, block)
-    shape = (B, H, G, 2 * L, D, False, jnp.bfloat16)
+    shape = (B, H, G, 2 * L, D, jnp.bfloat16)
     print("  block diffusion, a noisy and a clean copy of %d tokens in "
           "blocks of %d:" % (L, block), flush=True)
     print_flash_plan(*shape, mask=rule)

@@ -55,7 +55,7 @@ fa = importlib.import_module("horovod_tpu.ops.flash_attention")
 BWD = profile.FLASH_BWD
 
 
-def budgets(B, H, L, D, group, dtype, rotary, rule):
+def budgets(B, H, L, D, group, dtype, rule):
     """`vmem_budget` that forces a path: everything fits 2**40, and one
     byte less than a form holds gives the next that `flash_plan` tries:
     the two resident backward kernels (`split`), dK/dV held by the q block
@@ -63,12 +63,12 @@ def budgets(B, H, L, D, group, dtype, rotary, rule):
     there is no such budget), then gridded (under a rule dK/dV alone: one
     byte short of its last resident form; else nothing fits 0)."""
     def dkv(budget):
-        return fa.flash_plan(B, H, L, D, group, dtype, True, rotary,
+        return fa.flash_plan(B, H, L, D, group, dtype, True,
                              vmem_budget=budget, mask=rule)[profile.FLASH_DKV]
 
     out = {"resident": 2 ** 40}
     out["split"] = fa.flash_plan(
-        B, H, L, D, group, dtype, True, rotary, vmem_budget=2 ** 40,
+        B, H, L, D, group, dtype, True, vmem_budget=2 ** 40,
         mask=rule)[BWD].resident_bytes - 1
     last = out["split"]
     if group > 1:
@@ -113,8 +113,6 @@ def main():
                          "q-block candidates become bqp*group rows in "
                          "the grouped layout")
     ap.add_argument("--D", type=int, default=128)
-    ap.add_argument("--rotary", action="store_true",
-                    help="fused rotary (base 10000)")
     ap.add_argument("--path", default="all",
                     help="all, or of resident, split, q-held, gridded, "
                          "with commas")
@@ -132,7 +130,6 @@ def main():
     B, L, H, D = args.B, args.L, args.H, args.D
     G = args.G or H
     group = H // G
-    base = 10000.0 if args.rotary else None
     rule = (fa.BlockDiffusionMask(L // 2, args.mask_block)
             if args.mask_block else None)
     causal = rule is None
@@ -145,17 +142,15 @@ def main():
     scale = D ** -0.5
     rows = L * group
     out, lse = jax.jit(lambda q, k, v: fa._pallas_forward_lse(
-        q, k, v, scale, causal, False, rotary_base=base, rule=rule))(q, k, v)
+        q, k, v, scale, causal, False, rule=rule))(q, k, v)
 
-    print("shape B=%d L=%d H=%d G=%d D=%d%s%s (kernel layout, %d rows/slab)"
-          % (B, L, H, G, D, " rotary" if base else "",
-             "" if rule is None else " %r" % (rule,), rows))
+    print("shape B=%d L=%d H=%d G=%d D=%d%s (kernel layout, %d rows/slab)"
+          % (B, L, H, G, D, "" if rule is None else " %r" % (rule,), rows))
     for backward in (False, True):
         for name, plan in fa.flash_plan(B, H, L, D, group, q.dtype,
-                                        backward, base is not None,
-                                        mask=rule).items():
+                                        backward, mask=rule).items():
             print("default plan %s: %s" % (name, plan._asdict()))
-    by_path = budgets(B, H, L, D, group, q.dtype, base is not None, rule)
+    by_path = budgets(B, H, L, D, group, q.dtype, rule)
     paths = tuple(by_path) if args.path == "all" else tuple(
         args.path.split(","))
     print("%9s %6s %6s | %9s %9s %9s %9s" % (
@@ -181,18 +176,17 @@ def main():
         for bq, bk in candidates:
             def fwd(q, bq=bq, bk=bk):
                 return fa._pallas_forward_lse(
-                    q, k, v, scale, causal, False, bq, bk, base, budget,
+                    q, k, v, scale, causal, False, bq, bk, budget,
                     rule=rule)[0]
 
             def bwd(q, bq=bq, bk=bk):
                 return fa._pallas_backward(
                     q, k, v, out, lse, g, scale, causal, False, bq, bk,
-                    base, budget, rule=rule)
+                    budget, rule=rule)
 
             try:
-                plan = fa.flash_plan(B, H, L, D, group, q.dtype, True,
-                                     base is not None, bq, bk, budget,
-                                     mask=rule)
+                plan = fa.flash_plan(B, H, L, D, group, q.dtype, True, bq,
+                                     bk, budget, mask=rule)
             except ValueError:  # blocks the rule's length does not take
                 continue
             t_fwd = ms(fwd) if args.kernels == "all" else "-"

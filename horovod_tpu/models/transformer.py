@@ -56,12 +56,6 @@ class TransformerConfig:
     # kernels run the grouped-rows layout (one kv fetch per head
     # group, in-kernel dK/dV group reduction).
     num_kv_heads: Optional[int] = None
-    # Fuse rotary embedding into the flash/ring/ulysses kernels' q/k
-    # load path (positions derived in-kernel from global offsets —
-    # the explicit `positions` input is then unused by attention, so
-    # it only works for the standard layouts those offsets describe).
-    # The dense path always rotates outside.
-    rope_fused: bool = False
     rope_base: float = 10000.0
     sp_axis: Optional[str] = None  # mesh axis holding the sequence shards
     # Ring schedule: "zigzag" is the causal load-balanced layout
@@ -155,9 +149,9 @@ class TransformerConfig:
     # one of `q_lora_rank` (required beside it); a head's q.k is
     # `qk_nope_dim` wide without position and `qk_rope_dim` wide with
     # rotary, the rotary key ONE a token for all heads; values are
-    # `v_head_dim` wide. `head_dim`, `num_kv_heads`, `qk_norm` and
-    # `rope_fused` do not apply. `rope_yarn` rescales the rotary slice's
-    # frequencies and the softmax scale.
+    # `v_head_dim` wide. `head_dim`, `num_kv_heads` and `qk_norm` do not
+    # apply. `rope_yarn` rescales the rotary slice's frequencies and the
+    # softmax scale.
     kv_lora_rank: Optional[int] = None
     q_lora_rank: Optional[int] = None
     qk_nope_dim: int = 128
@@ -252,13 +246,6 @@ class TransformerConfig:
                 raise ValueError("%s cannot be combined with %s (built for "
                                  "one stack of blocks on one device a "
                                  "replica)" % (field, ", ".join(new)))
-        if self.rope_fused and (self.qk_norm == "head"
-                                or self.attention_mask is not None):
-            # The kernels' own rotary knows positions 0..L-1 only.
-            raise ValueError("rope_fused cannot be combined with %s (built "
-                             "for positions as given)" % ", ".join(
-                                 n for n in new if n in (
-                                     "qk_norm='head'", "attention_mask")))
         if self.moe_held is not None and self.ep_axis is not None:
             raise ValueError("moe_held cannot be combined with ep_axis (a "
                              "device that is told which experts it holds "
@@ -590,24 +577,19 @@ class Attention(nn.Module):
                 flat = t.reshape(t.shape[:-2] + (-1,))
                 return _rms_norm(cfg, name)(flat).reshape(t.shape)
             q, k = whole(q, "q_norm"), whole(k, "k_norm")
-        fused = (cfg.rope_fused and
-                 cfg.attention in ("flash", "ring", "ulysses"))
-        if not fused:
-            q = _rotary(q, positions, cfg.rope_base)
-            k = _rotary(k, positions, cfg.rope_base)
-        rb = cfg.rope_base if fused else None
+        q = _rotary(q, positions, cfg.rope_base)
+        k = _rotary(k, positions, cfg.rope_base)
         if cfg.attention == "ring":
             o = ring_attention(q, k, v, cfg.sp_axis, causal=True,
-                               schedule=cfg.sp_schedule, rotary_base=rb)
+                               schedule=cfg.sp_schedule)
         elif cfg.attention == "ulysses":
-            o = ulysses_attention(q, k, v, cfg.sp_axis, causal=True,
-                                  rotary_base=rb)
+            o = ulysses_attention(q, k, v, cfg.sp_axis, causal=True)
         elif cfg.attention == "flash":
             from horovod_tpu.ops import flash_attention
             if cfg.attention_mask is not None:
                 o = flash_attention(q, k, v, mask=cfg.attention_mask)
             else:
-                o = flash_attention(q, k, v, causal=True, rotary_base=rb)
+                o = flash_attention(q, k, v, causal=True)
         else:
             if G != cfg.num_heads:
                 k = jnp.repeat(k, cfg.num_heads // G, axis=2)
@@ -640,11 +622,6 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        # Under rope_fused=True with a kernel attention (flash/ring/
-        # ulysses), `positions` is IGNORED: the kernels apply rotary
-        # in-kernel from global row offsets, which assumes the standard
-        # contiguous 0..L-1 layout. Custom position ids (packing, shifted
-        # windows) require rope_fused=False.
         # `out`: the sandwich norm on a branch's output, or nothing.
         out = (lambda name, h: _rms_norm(cfg, name)(h)) \
             if cfg.sandwich_norm else (lambda name, h: h)

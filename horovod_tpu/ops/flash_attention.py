@@ -2,8 +2,8 @@
 
 The three plain kernels (forward, dQ, dK/dV) are one algorithm in two
 forms, and `flash_plan` chooses between them per kernel from the call's
-shapes alone (L, D, the head group, the dtype, fused rotary) — no
-argument, no environment variable:
+shapes alone (L, D, the head group, the dtype) — no argument, no
+environment variable:
 
 - resident, when the whole-sequence operands fit a VMEM budget (forward
   and dQ: k and v; dK/dV: q, dO, lse and delta), which at D=128 and one
@@ -59,22 +59,8 @@ of a [B,H,L,D] gradient plus a post-hoc sum. The only kernel change is
 that row positions are `row // group` — masks, frontier clamps and
 block-skip predicates all run in position units.
 
-Rotary embedding can be fused into the kernels (`rotary_base`), which
-removes the HBM round trip of writing rotated q/k outside the kernel.
-The cos/sin terms are NOT computed in-kernel: transcendentals plus the
-half-pair shuffle on every block visit serialize the VPU ahead of each
-MXU step and measured ~2x whole-kernel cost at L=8192. Instead the
-caller builds full-width (C, S) tables once per call (f32, sign folded
-into S; XLA CSEs them across layers) and the kernels stream table
-blocks through the same index maps as q/k — per-visit work drops to
-one lane-roll + 2 mul + 1 add (`_rot`), and q is rotated once for the
-whole k sweep (gridded: cached in VMEM scratch; resident: k's tables
-are whole in VMEM beside k). Rotation is linear-orthogonal
-per row, so the backward kernels rotate q/k the same way to recompute
-scores and counter-rotate finished dQ/dK blocks (the S sign flips —
-see `_rot(neg=True)`) at finalize. The ring-step kernels instead
-accumulate gradients in rotated space across ring steps; the caller
-counter-rotates once after the last step (`apply_rotary(neg=True)`).
+Rotary embedding is the caller's (`models.transformer._rotary`, from the
+caller's positions): the kernels take q and k as they are scored.
 
 Scores of two products (`q_shared`, `k_shared`: latent attention's
 rotary slice): s = scale * (q.k^T + q2.k2^T) with k2 ONE key a position for
@@ -118,59 +104,6 @@ from horovod_tpu import profile
 
 BLOCK_Q = 128
 BLOCK_K = 128
-
-
-def apply_rotary(x, positions, base=10000.0, neg=False):
-    """Rotary embedding outside the kernels (jnp fallbacks, ring
-    gradient counter-rotation). ``positions`` must be broadcastable to
-    ``x.shape[:-1]``; pairs are (d, d + D/2) — the same convention as
-    the in-kernel table path and `models.transformer._rotary`.
-    ``neg=True`` applies the transpose rotation R(-pos) (the gradient
-    counter-rotation)."""
-    D = x.shape[-1]
-    half = D // 2
-    inv = base ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / D)
-    ang = positions[..., None].astype(jnp.float32) * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    if neg:
-        sin = -sin
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).astype(x.dtype)
-
-
-def _rope_tables(positions, D, base):
-    """Full-width rotary tables for the kernels: (C, S) [R, D] f32 with
-    C[r, j] = cos(pos_r * inv_freq[j mod D/2]) and the application sign
-    baked into S (= [-sin | +sin]), so the in-kernel work is
-    x * C + roll(x, D/2) * S — no transcendentals, no half-pair
-    slicing. Built once per call; XLA CSEs identical tables across
-    layers."""
-    half = D // 2
-    inv = base ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / D)
-    ang = positions[:, None].astype(jnp.float32) * inv  # [R, half]
-    c = jnp.cos(ang)
-    s = jnp.sin(ang)
-    return (jnp.concatenate([c, c], axis=-1),
-            jnp.concatenate([-s, s], axis=-1))
-
-
-def _rot(x, cos, sin, neg=False):
-    """Rotate a [R, D] block by its table rows: each row's pair
-    partner sits half a lane-width away, fetched with one lane-roll.
-    ``neg=True`` is the transpose rotation (gradient counter-rotation;
-    for the baked-sign tables that is exactly an S sign flip)."""
-    xf = x.astype(jnp.float32)
-    partner = pltpu.roll(xf, x.shape[-1] // 2, 1)
-    ps = partner * sin
-    out = xf * cos + (-ps if neg else ps)
-    return out.astype(x.dtype)
-
-
-def _rot_apply(x, cos_ref, sin_ref, neg=False):
-    """`_rot` by streamed table blocks."""
-    return _rot(x, cos_ref[...], sin_ref[...], neg)
 
 
 def _to_rows(x, group):
@@ -256,17 +189,11 @@ def _online_softmax_update(s, v_ref, acc_ref, m_ref, l_ref, guard_empty):
         preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, scale, causal, num_kb, bqp, group):
     # q_ref: [BQ, D]; k_ref/v_ref: [BK, D]; o_ref: [BQ, D];
-    # scratch: acc [BQ, D] f32, m/l [BQ, 128] f32 (state across k steps)
-    # + qrot [BQ, D] under fused rotary (q rotated ONCE per q block at
-    # kj==0). bqp = BQ // group: positions per q block (grouped GQA).
-    if rotary:
-        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, o_ref,
-         lse_ref, acc_ref, m_ref, l_ref, qrot_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-         l_ref) = refs
+    # scratch: acc [BQ, D] f32, m/l [BQ, 128] f32 (state across k steps).
+    # bqp = BQ // group: positions per q block (grouped GQA).
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     block_k = k_ref.shape[0]
@@ -276,8 +203,6 @@ def _fwd_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
-        if rotary:
-            qrot_ref[...] = _rot_apply(q_ref[...], qc_ref, qs_ref)
 
     # Causal: skip the compute (the fetch is pipelined regardless) of
     # k-blocks entirely above the diagonal. Position units.
@@ -287,13 +212,7 @@ def _fwd_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
     def _compute():
         # Matmuls take the inputs' native (bf16) dtype — the MXU's fast
         # path — and accumulate in f32; only softmax runs in f32.
-        if rotary:
-            q = qrot_ref[...]
-            k = _rot_apply(k_ref[...], kc_ref, ks_ref)
-        else:
-            q = q_ref[...]
-            k = k_ref[...]
-        s = _masked_scores(q, k, scale, causal,
+        s = _masked_scores(q_ref[...], k_ref[...], scale, causal,
                            q_off=qi * bqp, kv_off=kj * block_k,
                            fill=-jnp.inf, group=group)
         _online_softmax_update(s, v_ref, acc_ref, m_ref, l_ref,
@@ -382,46 +301,33 @@ def _default_blocks(D, L=None, backward=False):
     return (256, 512)
 
 
-def _kv_index_map(bqp, bk, causal, rank2=False):
+def _kv_index_map(bqp, bk, causal):
     """k/v BlockSpec index map for grids with k innermost (position
     units: bqp = positions per q block). Causal runs clamp the k-block
     index to the diagonal frontier: steps above the diagonal revisit
     the frontier block, and Pallas skips the DMA for a revisited index
     — halving k/v HBM traffic at long L (the compute is separately
-    gated by `pl.when(visible)`). ``rank2`` drops the batch coordinate
-    (the rotary tables have no batch dim)."""
+    gated by `pl.when(visible)`)."""
     if not causal:
-        if rank2:
-            return lambda b, i, j: (j, 0)
         return lambda b, i, j: (b, j, 0)
-    if rank2:
-        return lambda b, i, j: (jnp.minimum(j, ((i + 1) * bqp - 1) // bk), 0)
     return lambda b, i, j: (b, jnp.minimum(j, ((i + 1) * bqp - 1) // bk), 0)
 
 
-def _q_index_map(bqp, bk, causal, rank2=False):
+def _q_index_map(bqp, bk, causal):
     """q-side BlockSpec index map for the dk/dv grid (q innermost).
     Causal runs clamp the q-block index UP to the first block at or
     below the diagonal (qi_min = (kj*bk)//bqp, position units): the
     leading invisible steps revisit that block, skipping their DMA."""
     if not causal:
-        if rank2:
-            return lambda b, j, i: (i, 0)
         return lambda b, j, i: (b, i, 0)
-    if rank2:
-        return lambda b, j, i: (jnp.maximum(i, (j * bk) // bqp), 0)
     return lambda b, j, i: (b, jnp.maximum(i, (j * bk) // bqp), 0)
 
 
-def _rule_q_index_map(rule, bqp, bk, rank2=False):
+def _rule_q_index_map(rule, bqp, bk):
     """`_q_index_map` for a rule: the q-block index clamped into the runs
     of the step's k block (`_clamp_to_runs`)."""
-    def at(j, i):
-        return _clamp_to_runs(i, rule.query_runs(j * bk, bk, bqp))
-
-    if rank2:
-        return lambda b, j, i: (at(j, i), 0)
-    return lambda b, j, i: (b, at(j, i), 0)
+    return lambda b, j, i: (
+        b, _clamp_to_runs(i, rule.query_runs(j * bk, bk, bqp)), 0)
 
 
 def _require_rows_block(L, preferred, group, what):
@@ -444,12 +350,6 @@ def _check_blocks(rows, L, bq, bk, group):
             f"invalid flash blocks: block_q={bq} must divide "
             f"rows={rows} and be a multiple of group={group}; "
             f"block_k={bk} must divide the kv length {L}")
-
-
-def _row_positions(L, group):
-    """Positions of the grouped-rows layout's rows for a full sequence
-    starting at 0: row r = pos*group + u -> position r//group."""
-    return jnp.repeat(jnp.arange(L, dtype=jnp.int32), group)
 
 
 # --- a mask by rule --------------------------------------------------------
@@ -629,7 +529,7 @@ def _rule_tiles(rule, held, positions, bqp, bk):
 # VMEM, both pipeline buffers counted. The v5e has 128 MiB of VMEM; the
 # budget is set by what was swept, not by what would fit: the resident
 # form beat the gridded one by 32-50% a kernel at every shape tried (D=128
-# and 64, group 1 and 3, fused rotary, L=1024 to 8192; PERF.md, PR 28),
+# and 64, group 1 and 3, L=1024 to 8192; PERF.md, PR 28),
 # the largest of them L=8192's dK/dV at D=128, 24 MiB.
 RESIDENT_VMEM_BUDGET = 24 * 2 ** 20
 # Mosaic's default scoped-VMEM limit on the v5e: a resident kernel asks
@@ -697,8 +597,8 @@ def _resident_blocks(D, L, group, kernel):
     step, is gone here; what is left is a loop turn's own overhead and
     the size of s [BQ, BK] in VMEM. v5e sweep (PR 28,
     examples/flash_block_sweep.py --path resident; ms a kernel and
-    layer). Group 1, the same answer at D=128 (L=2048, 4096, 8192, and
-    2048 with fused rotary) and D=64 (L=1024, 2048): forward and dQ
+    layer). Group 1, the same answer at D=128 (L=2048, 4096, 8192) and
+    D=64 (L=1024, 2048): forward and dQ
     (512, 512), dK/dV (512, 1024) — at 2 x 16 x 2048 x 128: 0.442 /
     0.532 / 0.714 against 0.466 / 0.572 / 0.857 for the gridded table's
     (256, 512) on this path, and 0.998 / 0.977 / 1.283 gridded. Group 3
@@ -715,8 +615,7 @@ def _resident_blocks(D, L, group, kernel):
     bk 256: 1.02-1.45. 1 x 16 x 4096 x 128: 1.961 as two; (512, 1024)
     1.387, (1024, 512) 1.378, (1024, 1024) 1.384, (256, 1024) 1.449,
     (512, 512) 1.456. D=64 at 2 x 16 x 2048: 1.246; (512, 1024) 0.883,
-    (512, 512) 0.867. Fused rotary at D=128, L=2048: 1.338; (512, 1024)
-    0.951, (512, 512) 0.935. Group 3 (2 x 6 heads on 2, L=2048, D=128):
+    (512, 512) 0.867. Group 3 (2 x 6 heads on 2, L=2048, D=128):
     0.605; (1536, 512) 0.358, (768, 512) 0.371, (1536, 1024) 0.408. The
     same pair read twice differs by up to 0.05, so nothing here beats the
     dK/dV kernel's table by more than the reading's own spread."""
@@ -727,8 +626,8 @@ def _resident_blocks(D, L, group, kernel):
     return _grouped_blocks(D, L, group, kernel != profile.FLASH_FWD)
 
 
-def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
-                 block_k, vmem_budget, D2=0, rule=None, held=None):
+def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
+                 vmem_budget, D2=0, rule=None, held=None):
     """``held``: the side a grid step holds a block of, "k" for the kernels
     of `_K_HELD` and "q" for the others unless given: dK/dV has a resident
     form of either kind (`flash_plan` tries "k" first)."""
@@ -746,14 +645,13 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
         accumulators = _vmem(rows, D, 4) + _vmem(rows, D2, 4)
     else:
         accumulators = 2 * _vmem(L, D, 4) if q_held_dkv else 0
-    tables = 2 * 4 if rotary else 0  # (C, S) f32, per side
 
     def q_side(n):  # one pipeline buffer of n rows of every q-side operand
-        return (_vmem(n, D, n_q * isz + tables) + n_stripes * _vmem(n, 8, 4)
+        return (_vmem(n, D, n_q * isz) + n_stripes * _vmem(n, 8, 4)
                 + _vmem(n, D2, n_q2 * isz))
 
     def k_side(n):
-        return _vmem(n, D, n_k * isz + tables) + _vmem(n, D2, n_k2 * isz)
+        return _vmem(n, D, n_k * isz) + _vmem(n, D2, n_k2 * isz)
 
     # Under a rule the blocks divide ITS length, so that no tile straddles
     # the halves of the sequence.
@@ -798,11 +696,9 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
     dkv = kernel == profile.FLASH_DKV  # gridded, it holds a k block
     bq, bk = blocks(_grouped_blocks(D, L, group, backward))
     num_qb, num_kb = rows // bq, L // bk
-    # acc / dq_acc (and m, l) per q block, or dk_acc + dv_acc per k block,
-    # and the block rotated once.
+    # acc / dq_acc (and m, l) per q block, or dk_acc + dv_acc per k block.
     scratch = ((2 * _vmem(bk, D, 4) if dkv else _vmem(bq, D, 4))
-               + (0 if backward else 2 * _vmem(bq, 128, 4))
-               + (_vmem(bk if dkv else bq, D, isz) if rotary else 0))
+               + (0 if backward else 2 * _vmem(bq, 128, 4)))
     grid = (BG, num_kb, num_qb) if dkv else (BG, num_qb, num_kb)
     return FlashKernelPlan("gridded", "k" if dkv else "q", bq, bk, grid,
                            BG * num_qb * num_kb, 0,
@@ -810,8 +706,8 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary, block_q,
 
 
 def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
-               rotary=False, block_q=None, block_k=None,
-               vmem_budget=RESIDENT_VMEM_BUDGET, shared_dim=0, mask=None):
+               block_q=None, block_k=None, vmem_budget=RESIDENT_VMEM_BUDGET,
+               shared_dim=0, mask=None):
     """How `flash_attention` runs q [B, H, L, D] against H // group kv
     heads: {kernel name: FlashKernelPlan} for the forward kernel
     (`hvd_flash_fwd`) or, with ``backward``, the backward: ONE kernel
@@ -820,20 +716,20 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     `hvd_flash_dkv`), which form them twice. THE place where the path is
     chosen, from what a call can see and nothing else: a kernel is
     resident when its whole-sequence operands, double-buffered, fit
-    ``vmem_budget`` (forward and dQ hold k + v, and k's rotary tables;
-    dK/dV holds q + dO, q's tables, and lse + delta, whose 8-wide f32
-    rows pad to 128 lanes; the one-kernel backward holds dK/dV's and
-    dQ's output block, and one f32 accumulator of dQ's shape) and one of
-    its blocks tiles the other; gridded otherwise. At D=128 in bf16 with
-    one head a kv head the one-kernel backward holds 8 MiB at L=2048, 16
-    at 4096 and 32 at 8192, where the budget keeps the two.
+    ``vmem_budget`` (forward and dQ hold k + v; dK/dV holds q + dO and
+    lse + delta, whose 8-wide f32 rows pad to 128 lanes; the one-kernel
+    backward holds dK/dV's and dQ's output block, and one f32 accumulator
+    of dQ's shape) and one of its blocks tiles the other; gridded
+    otherwise. At D=128 in bf16 with one head a kv head the one-kernel
+    backward holds 8 MiB at L=2048, 16 at 4096 and 32 at 8192, where the
+    budget keeps the two.
 
     dK/dV has a SECOND resident form, tried where the first does not fit
     (the order is `_DKV_HELD`'s): held by the q block (`held` "q", its
-    grid's block axis counts q blocks and runs in order), with k, v (and k's
-    tables) and the two results whole in VMEM, double-buffered, and dK and
-    dV summed over the q blocks in two f32 accumulators [L, D] there: 3
-    KiB a position at D <= 128 in bf16 whatever the head group, where the
+    grid's block axis counts q blocks and runs in order), with k, v and the
+    two results whole in VMEM, double-buffered, and dK and dV summed over
+    the q blocks in two f32 accumulators [L, D] there: 3 KiB a position at
+    D <= 128 in bf16 whatever the head group, where the
     first form's q + dO + lse + delta are 3 KiB a position and query head
     of the group. So with one head a kv head the second fits exactly where
     the first does and is never taken; with 8 (D=128, L=8192) the first
@@ -861,8 +757,8 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     forward and dQ resident on k + v, 8 MiB; dK/dV resident too, held by
     the q block: k, v, dk, dv and the two accumulators, 24 MiB, q + dO of a
     kv head being 64). Where the forward or dQ would be gridded the result is
-    ``{}`` and the call is the blockwise jnp form; fused rotary and a second
-    score product are refused beside a rule.
+    ``{}`` and the call is the blockwise jnp form; a second score product
+    is refused beside a rule.
 
     `_pallas_forward_lse` and `_pallas_backward` run what this returns,
     so it is also the counter that says which path a program took
@@ -870,14 +766,12 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     BG, rows = B * H // group, L * group
     isz = jnp.dtype(dtype).itemsize
 
-    if mask is not None and (rotary or shared_dim):
-        raise ValueError("a mask by rule repeats positions and has one "
-                         "score product: rotate outside the kernels")
+    if mask is not None and shared_dim:
+        raise ValueError("a mask by rule has one score product")
 
     def plan(kernel, held=None):
-        p = _kernel_plan(BG, rows, L, D, group, isz, kernel, rotary,
-                         block_q, block_k, vmem_budget, shared_dim, mask,
-                         held)
+        p = _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q,
+                         block_k, vmem_budget, shared_dim, mask, held)
         if mask is None or p is None:
             return p
         return p._replace(**dict(zip(
@@ -923,22 +817,17 @@ def _compiler_params(plan, carries=False):
 
 def _q_walk_specs(plan, L, D, group, causal):
     """Block specs of a kernel that holds a q block and walks the k
-    blocks (forward, dQ) under `plan`: (q-side index map, k/v spec, q's
-    table spec, k's table spec). Resident: k/v and k's tables whole,
-    their block index constant across q blocks; gridded: per tile, the
-    index clamped to the causal frontier (`_kv_index_map`)."""
+    blocks (forward, dQ) under `plan`: (q-side index map, k/v spec).
+    Resident: k/v whole, their block index constant across q blocks;
+    gridded: per tile, the index clamped to the causal frontier
+    (`_kv_index_map`)."""
     bq, bk = plan.block_q, plan.block_k
     if plan.path == "resident":
         return (lambda b, i: (b, i, 0),
-                pl.BlockSpec((None, L, D), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((bq, D), lambda b, i: (i, 0)),
-                pl.BlockSpec((L, D), lambda b, i: (0, 0)))
-    bqp = bq // group
+                pl.BlockSpec((None, L, D), lambda b, i: (b, 0, 0)))
     return (lambda b, i, j: (b, i, 0),
-            pl.BlockSpec((None, bk, D), _kv_index_map(bqp, bk, causal)),
-            pl.BlockSpec((bq, D), lambda b, i, j: (i, 0)),
-            pl.BlockSpec((bk, D), _kv_index_map(bqp, bk, causal,
-                                                rank2=True)))
+            pl.BlockSpec((None, bk, D),
+                         _kv_index_map(bq // group, bk, causal)))
 
 
 # --- the resident kernels --------------------------------------------------
@@ -998,12 +887,11 @@ def _mask_tile(s, rule, q_off, kv_off, group):
     return _rule_mask(s, rule, q_off, kv_off, -jnp.inf, group)
 
 
-def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
+def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group,
                          shared=False, rule=None):
     # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [L, D], fetched once per b (the
     # block index does not change across q blocks); lse_ref [BQ, 8]. The
-    # online-softmax state (acc, m, l) is carried by the loop. Under
-    # fused rotary kc/ks are whole [L, D] tables, q is rotated once.
+    # online-softmax state (acc, m, l) is carried by the loop.
     # `shared`: q2_ref [BQ, D2] and k2_ref [L, D2] follow v (`_scores2`).
     # `rule`: a mask by rule in place of the causal triangle; the loop
     # walks the rule's runs of k blocks (`_walk_runs`).
@@ -1011,23 +899,15 @@ def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
         q2_ref, k2_ref = refs[3:5]
         refs = refs[:3] + refs[5:]
         q2 = q2_ref[...]
-    if rotary:
-        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, o_ref,
-         lse_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, o_ref, lse_ref = refs
+    q_ref, k_ref, v_ref, o_ref, lse_ref = refs
     qi = pl.program_id(1)
     bq, D = q_ref.shape
     q = q_ref[...]
-    if rotary:
-        q = _rot(q, qc_ref[...], qs_ref[...])
 
     def visit(j, carry, masked):
         acc, m_prev, l_prev = carry
         at = pl.ds(pl.multiple_of(j * bk, bk), bk)
         k = k_ref[at, :]
-        if rotary:
-            k = _rot(k, kc_ref[at, :], ks_ref[at, :])
         s = _scores2(q, k, q2, k2_ref[at, :], scale) if shared \
             else _scores(q, k, scale)
         if masked:
@@ -1059,7 +939,7 @@ def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
     lse_ref[...] = jnp.broadcast_to(m + jnp.log(l), lse_ref.shape)
 
 
-def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
+def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group,
                             shared=False, rule=None):
     """dQ with k and v whole in VMEM: `_bwd_dq_kernel`'s arithmetic,
     the dq accumulator carried by the loop. `shared`: q2_ref [BQ, D2] and
@@ -1070,15 +950,9 @@ def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
         q2_ref, k2_ref = refs[3:5]
         refs = refs[:3] + refs[5:]
         q2 = q2_ref[...]
-    if rotary:
-        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
-         lse_ref, delta_ref, dq_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
     qi = pl.program_id(1)
     q = q_ref[...]
-    if rotary:
-        q = _rot(q, qc_ref[...], qs_ref[...])
     do = do_ref[...]
     lse = lse_ref[:, :1]
     delta = delta_ref[:, :1]
@@ -1088,8 +962,6 @@ def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
             dq, dq2 = dq
         at = pl.ds(pl.multiple_of(j * bk, bk), bk)
         k = k_ref[at, :]
-        if rotary:
-            k = _rot(k, kc_ref[at, :], ks_ref[at, :])
         s = _scores2(q, k, q2, k2_ref[at, :], scale) if shared \
             else _scores(q, k, scale)
         if masked:
@@ -1115,23 +987,20 @@ def _bwd_dq_resident_kernel(*refs, scale, causal, bk, bqp, group, rotary,
     if shared:
         dq, dq2 = dq
         dq2_ref[...] = dq2.astype(dq2_ref.dtype)
-    if rotary:
-        dq = _rot(dq, qc_ref[...], qs_ref[...], neg=True)
     dq_ref[...] = dq.astype(dq_ref.dtype)
 
 
-def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
+def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group,
                              with_dq, shared=False, rule=None):
     """dK/dV with q, dO, lse and delta whole in VMEM: `_bwd_dkv_kernel`'s
-    arithmetic, the dk and dv accumulators carried by the loop; k is
-    rotated once, q per visit. ``with_dq`` (`hvd_flash_bwd`): the whole
-    backward in this one kernel. s, p, dp and ds of a tile are formed
-    once and serve dQ too: every visit adds ds.k to the rows of its q
-    block in an f32 [rows, D] accumulator that lives in VMEM scratch
-    across the grid's k-block axis, zeroed at the first k block of a
-    (batch, kv head) and written to the dQ output, counter-rotated and
-    cast once, at the last. dQ's sum over k blocks runs in ascending k
-    order in f32, as `_bwd_dq_resident_kernel`'s loop runs it.
+    arithmetic, the dk and dv accumulators carried by the loop.
+    ``with_dq`` (`hvd_flash_bwd`): the whole backward in this one kernel.
+    s, p, dp and ds of a tile are formed once and serve dQ too: every
+    visit adds ds.k to the rows of its q block in an f32 [rows, D]
+    accumulator that lives in VMEM scratch across the grid's k-block axis,
+    zeroed at the first k block of a (batch, kv head) and written to the
+    dQ output, cast once, at the last. dQ's sum over k blocks runs in
+    ascending k order in f32, as `_bwd_dq_resident_kernel`'s loop runs it.
 
     ``shared``: the scores are of two products (`_scores2`). q2_ref [rows,
     D2] whole and k2_ref [BK, D2] follow v; dk2_ref [BK, D2], THIS head's
@@ -1146,17 +1015,10 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
         q2_ref, k2_ref = refs[3:5]
         refs = refs[:3] + refs[5:]
         k2 = k2_ref[...]
-    if rotary:
-        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
-         lse_ref, delta_ref, dk_ref, dv_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-         dv_ref) = refs
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref = refs
     kj = pl.program_id(1)
     bk = k_ref.shape[0]
     k = k_ref[...]
-    if rotary:
-        k = _rot(k, kc_ref[...], ks_ref[...])
     v = v_ref[...]
 
     if with_dq:
@@ -1170,8 +1032,6 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
         dk, dv, *dk2 = carry
         at = pl.ds(pl.multiple_of(i * bq, bq), bq)
         q = q_ref[at, :]
-        if rotary:
-            q = _rot(q, qc_ref[at, :], qs_ref[at, :])
         do = do_ref[at, :]
         if shared:
             q2 = q2_ref[at, :]
@@ -1212,8 +1072,6 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
         kj, bqp, bk, q_ref.shape[0] // bq, causal, rule)
     if shared:
         dk2_ref[...] = dk2[0].astype(dk2_ref.dtype)
-    if rotary:
-        dk = _rot(dk, kc_ref[...], ks_ref[...], neg=True)
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
@@ -1222,10 +1080,7 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
         def _finalize():
             def store(i, carry):  # a q block at a time: bounded values
                 at = pl.ds(pl.multiple_of(i * bq, bq), bq)
-                dq = dq_acc[at, :]
-                if rotary:
-                    dq = _rot(dq, qc_ref[at, :], qs_ref[at, :], neg=True)
-                dq_ref[at, :] = dq.astype(dq_ref.dtype)
+                dq_ref[at, :] = dq_acc[at, :].astype(dq_ref.dtype)
                 if shared:
                     dq2_ref[at, :] = dq2_acc[at, :].astype(dq2_ref.dtype)
                 return carry
@@ -1233,24 +1088,18 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group, rotary,
             lax.fori_loop(0, q_ref.shape[0] // bq, store, 0)
 
 
-def _bwd_dkv_q_held_kernel(*refs, scale, causal, bk, bqp, group, rotary,
-                            rule=None):
+def _bwd_dkv_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
+                           bk, bqp, group, rule=None):
     """dK/dV held by the q block, with k and v whole in VMEM: what
     `_bwd_dkv_kernel` computes, on `_bwd_dq_resident_kernel`'s grid and walk.
     A step takes a q block (q, dO, lse, delta), walks the k blocks it sees
     and adds each tile's p^T.dO and ds^T.q to the rows of that k block in
     two f32 [L, D] accumulators, which live in VMEM scratch across the
     grid's q-block axis: zeroed at the first q block of a (batch, kv head),
-    rounded once and written to the results (dK counter-rotated under fused
-    rotary), whole blocks too, at the last. A k block's sum over the q
-    blocks, the head group's rows among them, runs in ascending q order in
-    f32, as the gridded kernel's does."""
-    if rotary:
-        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
-         lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-         dk_acc, dv_acc) = refs
+    rounded once and written to the results, whole blocks too, at the last.
+    A k block's sum over the q blocks, the head group's rows among them,
+    runs in ascending q order in f32, as the gridded kernel's does."""
     qi = pl.program_id(1)
     num_kb = k_ref.shape[0] // bk
 
@@ -1269,8 +1118,6 @@ def _bwd_dkv_q_held_kernel(*refs, scale, causal, bk, bqp, group, rotary,
         lax.fori_loop(0, num_kb, zero, 0)
 
     q = q_ref[...]
-    if rotary:
-        q = _rot(q, qc_ref[...], qs_ref[...])
     do = do_ref[...]
     lse = lse_ref[:, :1]
     delta = delta_ref[:, :1]
@@ -1278,8 +1125,6 @@ def _bwd_dkv_q_held_kernel(*refs, scale, causal, bk, bqp, group, rotary,
     def visit(j, carry, masked):
         at = k_block(j)
         k = k_ref[at, :]
-        if rotary:
-            k = _rot(k, kc_ref[at, :], ks_ref[at, :])
         s = _scores(q, k, scale)
         if masked:
             s = _mask_tile(s, rule, qi * bqp, j * bk, group)
@@ -1302,10 +1147,7 @@ def _bwd_dkv_q_held_kernel(*refs, scale, causal, bk, bqp, group, rotary,
     def _finalize():
         def store(j, carry):
             at = k_block(j)
-            dk = dk_acc[at, :]
-            if rotary:
-                dk = _rot(dk, kc_ref[at, :], ks_ref[at, :], neg=True)
-            dk_ref[at, :] = dk.astype(dk_ref.dtype)
+            dk_ref[at, :] = dk_acc[at, :].astype(dk_ref.dtype)
             dv_ref[at, :] = dv_acc[at, :].astype(dv_ref.dtype)
             return carry
 
@@ -1352,7 +1194,7 @@ def _ruled_call(call, name, rule, plan, inputs, *static):
 
 
 def _pallas_forward_lse(q, k, v, scale, causal, interpret,
-                        block_q=None, block_k=None, rotary_base=None,
+                        block_q=None, block_k=None,
                         vmem_budget=RESIDENT_VMEM_BUDGET, shared=None,
                         rule=None):
     """q [B, H, L, D], k/v [B, G, L, D] with G | H. Returns
@@ -1371,10 +1213,9 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     qf = _to_rows(q, group)
     kf = k.reshape(B * G, L, D)
     vf = v.reshape(B * G, L, D)
-    rotary = rotary_base is not None
     D2 = shared[0].shape[-1] if shared else 0
-    plans = flash_plan(B, H, L, D, group, q.dtype, False, rotary, block_q,
-                       block_k, vmem_budget, D2, rule)
+    plans = flash_plan(B, H, L, D, group, q.dtype, False, block_q, block_k,
+                       vmem_budget, D2, rule)
     if shared:
         q2f, k2f, of_batch = _shared_operands(shared, B, G, group, plans)
     if rule is not None and not plans:
@@ -1386,30 +1227,23 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     rows = L * group
     bqp = bq // group
     inputs = [qf, kf, vf] + ([q2f, k2f] if shared else [])
-    if rotary:
-        qc, qs = _rope_tables(_row_positions(L, group), D, rotary_base)
-        kc, ks = _rope_tables(jnp.arange(L, dtype=jnp.int32), D,
-                              rotary_base)
-        inputs += [qc, qs, kc, ks]
-    q_im, kv_spec, tq_spec, tk_spec = _q_walk_specs(plan, L, D, group,
-                                                    causal)
+    q_im, kv_spec = _q_walk_specs(plan, L, D, group, causal)
     if plan.path == "resident":
         kernel = functools.partial(_fwd_resident_kernel, scale=scale,
                                    causal=causal, bk=bk, bqp=bqp,
-                                   group=group, rotary=rotary,
+                                   group=group,
                                    **({"shared": True} if shared else {}),
                                    **({} if rule is None
                                       else {"rule": rule}))
         scratch = []
     else:
         kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                                   num_kb=L // bk, bqp=bqp, group=group,
-                                   rotary=rotary)
+                                   num_kb=L // bk, bqp=bqp, group=group)
         scratch = [
             pltpu.VMEM((bq, D), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
-        ] + ([pltpu.VMEM((bq, D), q.dtype)] if rotary else [])
+        ]
     q_spec = pl.BlockSpec((None, bq, D), q_im)
     # The shared key whole, by its batch: the block index is the same for
     # every head of a batch, so it is fetched once a batch.
@@ -1421,8 +1255,7 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
         kernel,
         name=profile.FLASH_FWD,
         grid=plan.grid,
-        in_specs=[q_spec, kv_spec, kv_spec] + shared_specs + (
-            [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []),
+        in_specs=[q_spec, kv_spec, kv_spec] + shared_specs,
         out_specs=[q_spec, pl.BlockSpec((None, bq, 8), q_im)],
         out_shape=[
             jax.ShapeDtypeStruct((B * G, rows, D), q.dtype),
@@ -1436,12 +1269,14 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
 
 
 def _pallas_forward(q, k, v, scale, causal, interpret,
-                    block_q=None, block_k=None, rotary_base=None):
+                    block_q=None, block_k=None):
     return _pallas_forward_lse(q, k, v, scale, causal, interpret,
-                               block_q, block_k, rotary_base)[0]
+                               block_q, block_k)[0]
 
 
-def _ring_step_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
+def _ring_step_kernel(q_offs_ref, kv_offs_ref, q_ref, k_ref, v_ref, oi_ref,
+                      mi_ref, li_ref, oo_ref, mo_ref, lo_ref, acc_ref, m_ref,
+                      l_ref, *, scale, causal, num_kb, bqp, group):
     """One ring-attention step as a flash kernel with carried state.
 
     Same online-softmax update as `_fwd_kernel`, but the (acc, m, l)
@@ -1452,16 +1287,8 @@ def _ring_step_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
     position units) rather than one scalar per shard, so a shard may
     hold discontiguous sequence chunks (the zigzag causal schedule) as
     long as chunk boundaries align with block boundaries. Block skipping
-    is dynamic for the same reason. Fused rotary streams shard-global
-    (C, S) tables built by the caller from the same offsets.
+    is dynamic for the same reason.
     """
-    if rotary:
-        (q_offs_ref, kv_offs_ref, q_ref, k_ref, v_ref, qc_ref, qs_ref,
-         kc_ref, ks_ref, oi_ref, mi_ref, li_ref, oo_ref, mo_ref, lo_ref,
-         acc_ref, m_ref, l_ref, qrot_ref) = refs
-    else:
-        (q_offs_ref, kv_offs_ref, q_ref, k_ref, v_ref, oi_ref, mi_ref,
-         li_ref, oo_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref) = refs
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     q_off = q_offs_ref[qi]
@@ -1472,22 +1299,15 @@ def _ring_step_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
         acc_ref[...] = oi_ref[...]
         m_ref[...] = jnp.broadcast_to(mi_ref[:, :1], m_ref.shape)
         l_ref[...] = jnp.broadcast_to(li_ref[:, :1], l_ref.shape)
-        if rotary:
-            qrot_ref[...] = _rot_apply(q_ref[...], qc_ref, qs_ref)
 
     # A k/v block entirely in this q block's future contributes nothing.
     visible = (kv_off <= q_off + bqp - 1) if causal else kj >= 0
 
     @pl.when(visible)
     def _compute():
-        if rotary:
-            q = qrot_ref[...]
-            k = _rot_apply(k_ref[...], kc_ref, ks_ref)
-        else:
-            q = q_ref[...]
-            k = k_ref[...]
-        s = _masked_scores(q, k, scale, causal, q_off=q_off,
-                           kv_off=kv_off, fill=-jnp.inf, group=group)
+        s = _masked_scores(q_ref[...], k_ref[...], scale, causal,
+                           q_off=q_off, kv_off=kv_off, fill=-jnp.inf,
+                           group=group)
         _online_softmax_update(s, v_ref, acc_ref, m_ref, l_ref,
                                guard_empty=True)
 
@@ -1532,33 +1352,9 @@ def _block_offsets(offset, L, blk):
     return off[pos // Lc] + pos % Lc
 
 
-def shard_positions(offset, L):
-    """Global positions [L] of a shard described by a scalar offset or
-    a 1-D array of per-chunk offsets (the `_block_offsets` convention);
-    used for the ring path's rotary tables and post-loop
-    counter-rotation."""
-    off = jnp.asarray(offset, jnp.int32)
-    if off.ndim == 0:
-        return off + jnp.arange(L, dtype=jnp.int32)
-    Lc = L // off.shape[0]
-    return (off[:, None] +
-            jnp.arange(Lc, dtype=jnp.int32)[None]).reshape(-1)
-
-
-def _ring_tables(q_offset, kv_offset, Lq, Lk, D, group, rotary_base):
-    """(qc, qs, kc, ks) rotary tables for one ring step, from the
-    shard/chunk offsets (shard-global positions; q in grouped-rows
-    order)."""
-    qpos = jnp.repeat(shard_positions(q_offset, Lq), group)
-    kpos = shard_positions(kv_offset, Lk)
-    qc, qs = _rope_tables(qpos, D, rotary_base)
-    kc, ks = _rope_tables(kpos, D, rotary_base)
-    return qc, qs, kc, ks
-
-
 def flash_ring_step(q, k, v, o, m, l, q_offset, kv_offset, causal=True,
                     scale=None, interpret=False, block_q=None,
-                    block_k=None, group=1, rotary_base=None):
+                    block_k=None, group=1):
     """One ring-attention local step over kernel-layout shards.
 
     Args: q [BG, Lq*group, D] grouped-rows layout (bf16/f32; group=1 is
@@ -1584,10 +1380,9 @@ def flash_ring_step(q, k, v, o, m, l, q_offset, kv_offset, causal=True,
     num_kb = Lk // bk
     q_offs = _block_offsets(q_offset, Lq, bqp)
     kv_offs = _block_offsets(kv_offset, Lk, bk)
-    rotary = rotary_base is not None
     kernel = functools.partial(_ring_step_kernel, scale=scale,
                                causal=causal, num_kb=num_kb, bqp=bqp,
-                               group=group, rotary=rotary)
+                               group=group)
     grid = (BG, rows // bq, num_kb)
     q_spec = pl.BlockSpec((None, bq, D), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0))
@@ -1601,14 +1396,6 @@ def flash_ring_step(q, k, v, o, m, l, q_offset, kv_offset, causal=True,
         pl.BlockSpec(memory_space=pltpu.SMEM),  # per-kv-block offs
         q_spec, kv_spec, kv_spec,
     ]
-    inputs = [q_offs, kv_offs, q, k, v]
-    if rotary:
-        qc, qs, kc, ks = _ring_tables(q_offset, kv_offset, Lq, Lk, D,
-                                      group, rotary_base)
-        tq = pl.BlockSpec((bq, D), lambda b, i, j: (i, 0))
-        tk = pl.BlockSpec((bk, D), lambda b, i, j: (j, 0))
-        in_specs += [tq, tq, tk, tk]
-        inputs += [qc, qs, kc, ks]
     return pl.pallas_call(
         kernel,
         name=profile.RING_ATTN,
@@ -1624,29 +1411,21 @@ def flash_ring_step(q, k, v, o, m, l, q_offset, kv_offset, causal=True,
             pltpu.VMEM((bq, D), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
-        ] + ([pltpu.VMEM((bq, D), q.dtype)] if rotary else []),
+        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(*(inputs + [o, m, l]))
+    )(q_offs, kv_offs, q, k, v, o, m, l)
 
 
-def _ring_bwd_dq_kernel(*refs, scale, causal, num_kb, bqp, group,
-                        rotary):
+def _ring_bwd_dq_kernel(q_offs_ref, kv_offs_ref, q_ref, k_ref, v_ref, do_ref,
+                        lse_ref, delta_ref, dqi_ref, dqo_ref, dq_acc, *,
+                        scale, causal, num_kb, bqp, group):
     """dQ contribution of one backward ring step (FlashAttention-2
     math, global offsets like `_ring_step_kernel`). The dq accumulator
     is carried *across ring steps* (dqi -> dqo, f32): each arriving k/v
     shard adds its `sum_k dS.K` term; no forward recompute — p comes
-    from the saved per-row lse. With fused rotary the accumulator stays
-    in ROTATED space across steps; the caller counter-rotates once
-    after the last ring step."""
-    if rotary:
-        (q_offs_ref, kv_offs_ref, q_ref, k_ref, v_ref, qc_ref, qs_ref,
-         kc_ref, ks_ref, do_ref, lse_ref, delta_ref, dqi_ref, dqo_ref,
-         dq_acc, qrot_ref) = refs
-    else:
-        (q_offs_ref, kv_offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-         delta_ref, dqi_ref, dqo_ref, dq_acc) = refs
+    from the saved per-row lse."""
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     q_off = q_offs_ref[qi]
@@ -1655,19 +1434,13 @@ def _ring_bwd_dq_kernel(*refs, scale, causal, num_kb, bqp, group,
     @pl.when(kj == 0)
     def _load():
         dq_acc[...] = dqi_ref[...]
-        if rotary:
-            qrot_ref[...] = _rot_apply(q_ref[...], qc_ref, qs_ref)
 
     visible = (kv_off <= q_off + bqp - 1) if causal else kj >= 0
 
     @pl.when(visible)
     def _compute():
-        if rotary:
-            q = qrot_ref[...]
-            k = _rot_apply(k_ref[...], kc_ref, ks_ref)
-        else:
-            q = q_ref[...]
-            k = k_ref[...]
+        q = q_ref[...]
+        k = k_ref[...]
         s = _masked_scores(q, k, scale, causal, q_off=q_off,
                            kv_off=kv_off, fill=-jnp.inf, group=group)
         p = jnp.exp(s - lse_ref[:, :1])  # masked entries: exp(-inf) = 0
@@ -1684,22 +1457,15 @@ def _ring_bwd_dq_kernel(*refs, scale, causal, num_kb, bqp, group,
         dqo_ref[...] = dq_acc[...]
 
 
-def _ring_bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group,
-                         rotary):
+def _ring_bwd_dkv_kernel(q_offs_ref, kv_offs_ref, q_ref, k_ref, v_ref,
+                         do_ref, lse_ref, delta_ref, dki_ref, dvi_ref,
+                         dko_ref, dvo_ref, dk_acc, dv_acc, *, scale, causal,
+                         num_qb, bqp, group):
     """dK/dV contribution of one backward ring step. The dk/dv
     accumulators travel around the ring with their k/v shard (the
     caller ppermutes them together), so after n steps each shard
-    arrives home with its full gradient (dk in rotated space under
-    fused rotary — counter-rotated at home after the loop). Grid
-    (bg, k-block, q-block), q innermost sequential."""
-    if rotary:
-        (q_offs_ref, kv_offs_ref, q_ref, k_ref, v_ref, qc_ref, qs_ref,
-         kc_ref, ks_ref, do_ref, lse_ref, delta_ref, dki_ref, dvi_ref,
-         dko_ref, dvo_ref, dk_acc, dv_acc, krot_ref) = refs
-    else:
-        (q_offs_ref, kv_offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-         delta_ref, dki_ref, dvi_ref, dko_ref, dvo_ref, dk_acc,
-         dv_acc) = refs
+    arrives home with its full gradient. Grid (bg, k-block, q-block), q
+    innermost sequential."""
     kj = pl.program_id(1)
     qi = pl.program_id(2)
     q_off = q_offs_ref[qi]
@@ -1709,19 +1475,13 @@ def _ring_bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group,
     def _load():
         dk_acc[...] = dki_ref[...]
         dv_acc[...] = dvi_ref[...]
-        if rotary:
-            krot_ref[...] = _rot_apply(k_ref[...], kc_ref, ks_ref)
 
     visible = (q_off + bqp - 1 >= kv_off) if causal else qi >= 0
 
     @pl.when(visible)
     def _compute():
-        if rotary:
-            q = _rot_apply(q_ref[...], qc_ref, qs_ref)
-            k = krot_ref[...]
-        else:
-            q = q_ref[...]
-            k = k_ref[...]
+        q = q_ref[...]
+        k = k_ref[...]
         s = _masked_scores(q, k, scale, causal, q_off=q_off,
                            kv_off=kv_off, fill=-jnp.inf, group=group)
         p = jnp.exp(s - lse_ref[:, :1])  # masked entries: exp(-inf) = 0
@@ -1745,7 +1505,7 @@ def _ring_bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group,
 def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
                         kv_offset, causal=True, scale=None,
                         interpret=False, block_q=None, block_k=None,
-                        group=1, rotary_base=None):
+                        group=1):
     """One backward ring step over kernel-layout shards.
 
     Args: q/do [BG, Lq*group, D] grouped-rows layout, k/v [BG, Lk, D],
@@ -1753,9 +1513,7 @@ def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
     forward; delta = rowsum(dO*O)), dq [BG, Lq*group, D] f32 (local
     accumulator), dk/dv [BG, Lk, D] f32 (accumulators traveling with
     the k/v shard), q_offset/kv_offset global token position offsets.
-    Returns updated (dq, dk, dv). Under fused rotary, dq and dk stay
-    in rotated space — counter-rotate after the last ring step with
-    `apply_rotary(..., neg=True)`."""
+    Returns updated (dq, dk, dv)."""
     BG, rows, D = q.shape
     Lq = rows // group
     Lk = k.shape[1]
@@ -1771,24 +1529,13 @@ def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
     num_kb, num_qb = Lk // bk, rows // bq
     q_offs = _block_offsets(q_offset, Lq, bqp)
     kv_offs = _block_offsets(kv_offset, Lk, bk)
-    rotary = rotary_base is not None
-    if rotary:
-        tables = list(_ring_tables(q_offset, kv_offset, Lq, Lk, D,
-                                   group, rotary_base))
-    else:
-        tables = []
 
     q_spec = lambda b, i, j: (b, i, 0)      # noqa: E731
     stripe_spec = lambda b, i, j: (b, i, 0)  # noqa: E731
-    table_specs_ki = ([pl.BlockSpec((bq, D), lambda b, i, j: (i, 0))] * 2
-                      + [pl.BlockSpec((bk, D),
-                                      lambda b, i, j: (j, 0))] * 2
-                      if rotary else [])
 
     dq = pl.pallas_call(
         functools.partial(_ring_bwd_dq_kernel, scale=scale, causal=causal,
-                          num_kb=num_kb, bqp=bqp, group=group,
-                          rotary=rotary),
+                          num_kb=num_kb, bqp=bqp, group=group),
         name=profile.RING_ATTN_DQ,
         grid=(BG, num_qb, num_kb),
         in_specs=[
@@ -1797,7 +1544,6 @@ def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
             pl.BlockSpec((None, bq, D), q_spec),
             pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((None, bk, D), lambda b, i, j: (b, j, 0)),
-        ] + table_specs_ki + [
             pl.BlockSpec((None, bq, D), q_spec),
             pl.BlockSpec((None, bq, 8), stripe_spec),
             pl.BlockSpec((None, bq, 8), stripe_spec),
@@ -1805,22 +1551,17 @@ def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
         ],
         out_specs=pl.BlockSpec((None, bq, D), q_spec),
         out_shape=jax.ShapeDtypeStruct((BG, rows, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)] + (
-            [pltpu.VMEM((bq, D), q.dtype)] if rotary else []),
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q_offs, kv_offs, q, k, v, *tables, do, lse, delta, dq)
+    )(q_offs, kv_offs, q, k, v, do, lse, delta, dq)
 
     k_spec = lambda b, j, i: (b, j, 0)  # noqa: E731
-    table_specs_qi = ([pl.BlockSpec((bq, D), lambda b, j, i: (i, 0))] * 2
-                      + [pl.BlockSpec((bk, D),
-                                      lambda b, j, i: (j, 0))] * 2
-                      if rotary else [])
     dk, dv = pl.pallas_call(
         functools.partial(_ring_bwd_dkv_kernel, scale=scale,
                           causal=causal, num_qb=num_qb, bqp=bqp,
-                          group=group, rotary=rotary),
+                          group=group),
         name=profile.RING_ATTN_DKV,
         grid=(BG, num_kb, num_qb),
         in_specs=[
@@ -1829,7 +1570,6 @@ def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
             pl.BlockSpec((None, bq, D), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((None, bk, D), k_spec),
             pl.BlockSpec((None, bk, D), k_spec),
-        ] + table_specs_qi + [
             pl.BlockSpec((None, bq, D), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((None, bq, 8), lambda b, j, i: (b, i, 0)),
             pl.BlockSpec((None, bq, 8), lambda b, j, i: (b, i, 0)),
@@ -1845,29 +1585,20 @@ def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
             jax.ShapeDtypeStruct((BG, Lk, D), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)] + (
-            [pltpu.VMEM((bk, D), k.dtype)] if rotary else []),
+                        pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q_offs, kv_offs, q, k, v, *tables, do, lse, delta, dk, dv)
+    )(q_offs, kv_offs, q, k, v, do, lse, delta, dk, dv)
     return dq, dk, dv
 
 
-def _bwd_dq_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   dq_acc, *, scale, causal, num_kb, bqp, group):
     """dQ: grid (bg, q-block, k-block), k innermost sequential.
     Recomputes p = exp(s - lse) per block; dS = p * (dO.V^T - delta);
     dQ = sum_k dS.K * scale accumulated in VMEM scratch. lse and
-    delta = rowsum(dO*O) are precomputed per row and streamed in.
-    Fused rotary: q rotated once per q block into scratch (kj==0);
-    accumulate in rotated space, counter-rotate the finished block at
-    finalize."""
-    if rotary:
-        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
-         lse_ref, delta_ref, dq_ref, dq_acc, qrot_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-         dq_acc) = refs
+    delta = rowsum(dO*O) are precomputed per row and streamed in."""
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     block_k = k_ref.shape[0]
@@ -1875,19 +1606,13 @@ def _bwd_dq_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
     @pl.when(kj == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
-        if rotary:
-            qrot_ref[...] = _rot_apply(q_ref[...], qc_ref, qs_ref)
 
     visible = (kj * block_k < (qi + 1) * bqp) if causal else kj >= 0
 
     @pl.when(visible)
     def _compute():
-        if rotary:
-            q = qrot_ref[...]
-            k = _rot_apply(k_ref[...], kc_ref, ks_ref)
-        else:
-            q = q_ref[...]
-            k = k_ref[...]
+        q = q_ref[...]
+        k = k_ref[...]
         s = _masked_scores(q, k, scale, causal,
                            q_off=qi * bqp, kv_off=kj * block_k,
                            fill=-jnp.inf, group=group)
@@ -1902,29 +1627,16 @@ def _bwd_dq_kernel(*refs, scale, causal, num_kb, bqp, group, rotary):
 
     @pl.when(kj == num_kb - 1)
     def _finalize():
-        dq = dq_acc[...]
-        if rotary:
-            dq = _rot_apply(dq, qc_ref, qs_ref, neg=True)
-        dq_ref[...] = dq.astype(dq_ref.dtype)
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary,
-                    rule=None):
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                    dv_ref, dk_acc, dv_acc, *, scale, causal, num_qb, bqp,
+                    group, rule=None):
     """dK/dV: grid (bg, k-block, q-block), q innermost sequential.
     dV = sum_q P^T.dO; dK = sum_q dS^T.Q * scale. In the grouped GQA
     layout the q rows interleave the whole head group, so the group
-    reduction of dK/dV happens in these same accumulators. Fused
-    rotary: k rotated once per OUTER k block into scratch (qi==0, the
-    block is fixed across the inner q sweep); q rotated per visit (a
-    fresh DMA each step anyway); dK counter-rotated at finalize (dV is
-    rotation-free)."""
-    if rotary:
-        (q_ref, k_ref, v_ref, qc_ref, qs_ref, kc_ref, ks_ref, do_ref,
-         lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-         krot_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-         dv_ref, dk_acc, dv_acc) = refs
+    reduction of dK/dV happens in these same accumulators."""
     kj = pl.program_id(1)
     qi = pl.program_id(2)
     block_k = k_ref.shape[0]
@@ -1933,8 +1645,6 @@ def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary,
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
-        if rotary:
-            krot_ref[...] = _rot_apply(k_ref[...], kc_ref, ks_ref)
 
     # Causal: q blocks entirely above this k block see none of it. A rule:
     # the q blocks of its runs for this k block (`_rule_q_index_map`
@@ -1948,12 +1658,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary,
 
     @pl.when(visible)
     def _compute():
-        if rotary:
-            q = _rot_apply(q_ref[...], qc_ref, qs_ref)
-            k = krot_ref[...]
-        else:
-            q = q_ref[...]
-            k = k_ref[...]
+        q = q_ref[...]
+        k = k_ref[...]
         if rule is not None:
             s = jax.lax.cond(
                 _in_runs(qi, runs, masked_only=True),
@@ -1979,15 +1685,12 @@ def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rotary,
 
     @pl.when(qi == num_qb - 1)
     def _finalize():
-        dk = dk_acc[...]
-        if rotary:
-            dk = _rot_apply(dk, kc_ref, ks_ref, neg=True)
-        dk_ref[...] = dk.astype(dk_ref.dtype)
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
-                     block_q=None, block_k=None, rotary_base=None,
+                     block_q=None, block_k=None,
                      vmem_budget=RESIDENT_VMEM_BUDGET, shared=None,
                      rule=None):
     """Pallas backward: q/out/g [B,H,L,D], k/v [B,G,L,D], lse in the
@@ -2009,13 +1712,12 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         jnp.sum(gf.astype(jnp.float32) * outf.astype(jnp.float32),
                 axis=-1, keepdims=True), lse.shape)
     rows = L * group
-    rotary = rotary_base is not None
     # Backward blocks are independent of the forward's (lse/delta
     # stripes are block-agnostic); see _resident_blocks and
     # _default_blocks for the swept preferences.
     D2 = shared[0].shape[-1] if shared else 0
-    plans = flash_plan(B, H, L, D, group, q.dtype, True, rotary, block_q,
-                       block_k, vmem_budget, D2, rule)
+    plans = flash_plan(B, H, L, D, group, q.dtype, True, block_q, block_k,
+                       vmem_budget, D2, rule)
     if shared:
         q2f, k2f, of_batch = _shared_operands(shared, B, G, group, plans)
         extra = {"shared": True}
@@ -2029,14 +1731,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
                 "`flash_plan(..., backward=True, mask=%r)` says this "
                 "call's is not" % (rule,))
         extra = {"rule": rule}
-    if rotary:
-        qc, qs = _rope_tables(_row_positions(L, group), D, rotary_base)
-        kc, ks = _rope_tables(jnp.arange(L, dtype=jnp.int32), D,
-                              rotary_base)
-        tables = [qc, qs, kc, ks]
-    else:
-        tables = []
-    inputs = [qf, kf, vf] + ([q2f, k2f] if shared else []) + tables + [
+    inputs = [qf, kf, vf] + ([q2f, k2f] if shared else []) + [
         gf, lse, delta]
     dq_shape = jax.ShapeDtypeStruct((B * G, rows, D), q.dtype)
 
@@ -2047,19 +1742,17 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         plan = plans[profile.FLASH_DQ]
         bq, bk = plan.block_q, plan.block_k
         bqp = bq // group
-        q_im, kv_spec, tq_spec, tk_spec = _q_walk_specs(plan, L, D, group,
-                                                        causal)
+        q_im, kv_spec = _q_walk_specs(plan, L, D, group, causal)
         if plan.path == "resident":
             kernel = functools.partial(
                 _bwd_dq_resident_kernel, scale=scale, causal=causal, bk=bk,
-                bqp=bqp, group=group, rotary=rotary, **extra)
+                bqp=bqp, group=group, **extra)
             scratch = []
         else:
             kernel = functools.partial(
                 _bwd_dq_kernel, scale=scale, causal=causal, num_kb=L // bk,
-                bqp=bqp, group=group, rotary=rotary)
-            scratch = [pltpu.VMEM((bq, D), jnp.float32)] + (
-                [pltpu.VMEM((bq, D), q.dtype)] if rotary else [])
+                bqp=bqp, group=group)
+            scratch = [pltpu.VMEM((bq, D), jnp.float32)]
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
         q2_spec = pl.BlockSpec((None, bq, D2), q_im)
@@ -2070,9 +1763,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
             in_specs=[q_spec, kv_spec, kv_spec] + ([
                 q2_spec, pl.BlockSpec((None, L, D2),
                                       lambda b, i: (of_batch(b), 0, 0))]
-                if shared else []) + (
-                [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
-                q_spec, stripe_spec, stripe_spec],
+                if shared else []) + [q_spec, stripe_spec, stripe_spec],
             out_specs=[q_spec, q2_spec] if shared else q_spec,
             out_shape=[dq_shape, dq2_shape] if shared else dq_shape,
             scratch_shapes=scratch,
@@ -2089,9 +1780,8 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         # dK/dV on dQ's grid: a q block a step; k, v and the results whole.
         kernel = functools.partial(_bwd_dkv_q_held_kernel, scale=scale,
                                    causal=causal, bk=bk, bqp=bqp,
-                                   group=group, rotary=rotary, **extra)
-        q_im, k_spec, tq_spec, tk_spec = _q_walk_specs(plan, L, D, group,
-                                                       causal)
+                                   group=group, **extra)
+        q_im, k_spec = _q_walk_specs(plan, L, D, group, causal)
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
         # dK's and dV's accumulators across the q blocks of a (batch, kv
@@ -2101,14 +1791,11 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     elif plan.path == "resident":
         kernel = functools.partial(_bwd_dkv_resident_kernel, scale=scale,
                                    causal=causal, bq=bq, bqp=bqp,
-                                   group=group, rotary=rotary,
-                                   with_dq=fused, **extra)
+                                   group=group, with_dq=fused, **extra)
         k_im = lambda b, j: (b, j, 0)                       # noqa: E731
         q_spec = pl.BlockSpec((None, rows, D), lambda b, j: (b, 0, 0))
         q2_spec = pl.BlockSpec((None, rows, D2), lambda b, j: (b, 0, 0))
         stripe_spec = pl.BlockSpec((None, rows, 8), lambda b, j: (b, 0, 0))
-        tq_spec = pl.BlockSpec((rows, D), lambda b, j: (0, 0))
-        tk_spec = pl.BlockSpec((bk, D), lambda b, j: (j, 0))
         k_spec = pl.BlockSpec((None, bk, D), k_im)
         # dQ's accumulator across the k blocks of a (batch, kv head).
         scratch = [pltpu.VMEM((rows, D), jnp.float32)] if fused else []
@@ -2117,7 +1804,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     else:
         kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
                                    causal=causal, num_qb=rows // bq,
-                                   bqp=bqp, group=group, rotary=rotary,
+                                   bqp=bqp, group=group,
                                    **({} if rule is None
                                       else {"rule": rule}))
         k_im = lambda b, j, i: (b, j, 0)                    # noqa: E731
@@ -2125,13 +1812,9 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
             else _rule_q_index_map(rule, bqp, bk)
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
-        tq_spec = pl.BlockSpec((bq, D), _q_index_map(bqp, bk, causal,
-                                                     rank2=True))
-        tk_spec = pl.BlockSpec((bk, D), lambda b, j, i: (j, 0))
         k_spec = pl.BlockSpec((None, bk, D), k_im)
         scratch = [pltpu.VMEM((bk, D), jnp.float32),
-                   pltpu.VMEM((bk, D), jnp.float32)] + (
-            [pltpu.VMEM((bk, D), k.dtype)] if rotary else [])
+                   pltpu.VMEM((bk, D), jnp.float32)]
     # A result whose block does not change across the grid's block axis (the
     # one kernel's dQ; dK and dV held by the q block) is written back once.
     results = _ruled_call(pl.pallas_call(
@@ -2141,9 +1824,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         in_specs=[q_spec, k_spec, k_spec] + ([
             q2_spec, pl.BlockSpec((None, bk, D2),
                                   lambda b, j: (of_batch(b), j, 0))]
-            if shared else []) + (
-            [tq_spec, tq_spec, tk_spec, tk_spec] if rotary else []) + [
-            q_spec, stripe_spec, stripe_spec],
+            if shared else []) + [q_spec, stripe_spec, stripe_spec],
         # Results in the kernel's order: dk, dv, [dk2], [dq, [dq2]].
         out_specs=[k_spec, k_spec] + (
             [pl.BlockSpec((None, bk, D2), k_im)] if shared else []) + (
@@ -2178,8 +1859,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
             dv.reshape(B, G, L, D))
 
 
-def _blockwise_reference(q, k, v, scale, causal, rotary_base=None,
-                         rule=None):
+def _blockwise_reference(q, k, v, scale, causal, rule=None):
     """Blockwise JAX attention, O(BLOCK_Q * L) live memory; used for the
     backward recompute and as the non-TPU fallback. q [B,H,L,D], k/v
     [B,G,L,D] — GQA repeats kv across each head group here (the kernel
@@ -2188,10 +1868,6 @@ def _blockwise_reference(q, k, v, scale, causal, rotary_base=None,
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
-    if rotary_base is not None:
-        pos = jnp.arange(L, dtype=jnp.int32)
-        q = apply_rotary(q, pos, rotary_base)
-        k = apply_rotary(k, pos, rotary_base)
     if group > 1:
         k = jnp.repeat(k, group, axis=1)
         v = jnp.repeat(v, group, axis=1)
@@ -2220,36 +1896,34 @@ def _blockwise_reference(q, k, v, scale, causal, rotary_base=None,
     return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=2)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, interpret, rotary_base=None, rule=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, scale, causal, interpret, rule=None):
     if interpret is None:
-        return _blockwise_reference(q, k, v, scale, causal, rotary_base,
-                                    rule)
+        return _blockwise_reference(q, k, v, scale, causal, rule)
     return _pallas_forward_lse(q, k, v, scale, causal, interpret,
-                               rotary_base=rotary_base, rule=rule)[0]
+                               rule=rule)[0]
 
 
-def _flash_fwd(q, k, v, scale, causal, interpret, rotary_base=None,
-               rule=None):
+def _flash_fwd(q, k, v, scale, causal, interpret, rule=None):
     if interpret is None:
-        return _blockwise_reference(q, k, v, scale, causal, rotary_base,
-                                    rule), (q, k, v, None, None)
+        return (_blockwise_reference(q, k, v, scale, causal, rule),
+                (q, k, v, None, None))
     out, lse = _pallas_forward_lse(q, k, v, scale, causal, interpret,
-                                   rotary_base=rotary_base, rule=rule)
+                                   rule=rule)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, interpret, rotary_base, rule, res, g):
+def _flash_bwd(scale, causal, interpret, rule, res, g):
     q, k, v, out, lse = res
     if interpret is None:
         # Non-kernel path: recompute-blockwise VJP in plain JAX.
         _, vjp = jax.vjp(
             lambda q, k, v: _blockwise_reference(q, k, v, scale, causal,
-                                                 rotary_base, rule),
+                                                 rule),
             q, k, v)
         return vjp(g)
     return _pallas_backward(q, k, v, out, lse, g, scale, causal,
-                            interpret, rotary_base=rotary_base, rule=rule)
+                            interpret, rule=rule)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -2319,31 +1993,28 @@ def analytic_attention_flops(B, H, L, D, causal=True, training=False):
     return (9.0 if training else 2.0) * per_matmul
 
 
-def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
-                    q_shared=None, k_shared=None, mask=None):
+def flash_attention(q, k, v, causal=True, scale=None, q_shared=None,
+                    k_shared=None, mask=None):
     """Flash attention over [B, L, H, D] inputs (same layout as
     `parallel.ring.ring_attention`); returns [B, L, H, D] in q.dtype.
 
     GQA/MQA: pass k/v with fewer heads, [B, L, G, D] with G dividing H
     — query head h attends through kv head h // (H // G) (consecutive
-    query heads share a kv head, the llama convention). ``rotary_base``
-    fuses rotary position embedding (positions 0..L-1) into the
-    kernels' q/k load path — do not also rotate outside.
+    query heads share a kv head, the llama convention). Rotary embedding is
+    the caller's: q and k arrive rotated.
 
     Scores of two products (latent attention): ``q_shared`` [B, L, H, D2]
     and ``k_shared`` [B, L, 1, D2], ONE key a position for every head; the
     scores are ``scale * (q.k + q_shared.k_shared)``, ``scale`` by default
     (D + D2) ** -0.5, and v is as wide as k. The kernels read the shared
-    key by its batch and never repeat it over the heads in memory; rotate
-    it outside (`rotary_base` is refused beside it).
+    key by its batch and never repeat it over the heads in memory.
 
     ``mask``: a rule over (query position, key position) in place of
     ``causal`` (`BlockDiffusionMask(length, block)`, with L = 2 x length:
     a noisy and a clean copy of a sequence under block-diffusion training's
     mask). The kernels compute the tiles the rule leaves non-empty and mask
-    only those it cuts (`flash_plan(..., mask=)` counts them); positions
-    repeat, so rotate outside (``rotary_base`` and the second score product
-    are refused beside it).
+    only those it cuts (`flash_plan(..., mask=)` counts them); the second
+    score product is refused beside it.
 
     L must be a multiple of 128 to hit the Pallas kernel; other shapes
     (and non-TPU backends without interpret mode) use the blockwise JAX
@@ -2359,9 +2030,6 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
         raise ValueError("q_shared and k_shared come together")
     D2 = 0 if q_shared is None else q_shared.shape[-1]
     if D2:
-        if rotary_base is not None:
-            raise ValueError("rotary_base cannot be combined with q_shared "
-                             "/ k_shared: rotate the shared slice outside")
         if (k_shared.shape != (B, L, 1, D2) or q_shared.shape[:3] != (B, L, H)
                 or v.shape[-1] != D):
             raise ValueError(
@@ -2369,9 +2037,9 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
                 "[B, L, 1, D2] and v as wide as k"
                 % (q_shared.shape, k_shared.shape, v.shape))
     if mask is not None:
-        if rotary_base is not None or D2:
-            raise ValueError("mask=%r cannot be combined with rotary_base or "
-                             "q_shared / k_shared" % (mask,))
+        if D2:
+            raise ValueError("mask=%r cannot be combined with q_shared / "
+                             "k_shared" % (mask,))
         mask.check(L, 1, 1)
     if scale is None:
         scale = (D + D2) ** -0.5
@@ -2387,7 +2055,7 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
             flash_plan(B, H, L, D, group, q.dtype, backward, mask=mask)
             for backward in (False, True))
         out = _flash(qt, kt, vt, scale, False, False if kernel_ok else None,
-                     None, mask)
+                     mask)
         return out.transpose(0, 2, 1, 3)
     kernel_ok = (
         on_tpu and L % BLOCK_Q == 0 and
@@ -2403,6 +2071,5 @@ def flash_attention(q, k, v, causal=True, scale=None, rotary_base=None,
                             k_shared.transpose(0, 2, 1, 3), scale, causal,
                             False if kernel_ok else None)
         return out.transpose(0, 2, 1, 3)
-    out = _flash(qt, kt, vt, scale, causal, False if kernel_ok else None,
-                 rotary_base)
+    out = _flash(qt, kt, vt, scale, causal, False if kernel_ok else None)
     return out.transpose(0, 2, 1, 3)

@@ -21,8 +21,8 @@ first-class here because long context shapes the core design on TPU.
   back. Better when heads >= devices and the per-device sequence is short.
 
 Both support GQA/MQA (k/v with fewer heads than q: [B, L, G, D] with
-G | H) and fused rotary (``rotary_base`` — positions are the *global*
-token positions implied by the schedule, so sequence shards agree).
+G | H). Rotary embedding is the caller's, from the GLOBAL positions of its
+shard's tokens (`models.transformer` does it), before either is called.
 
 Both are meant to run inside ``shard_map`` over a mesh axis (see
 `horovod_tpu.parallel.mesh.hybrid_mesh`).
@@ -33,8 +33,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-
-from horovod_tpu.ops.flash_attention import apply_rotary, shard_positions
 
 
 def _block_attention(q, k, v, o, m, l, q_offset, kv_offset, causal, scale):
@@ -118,25 +116,15 @@ def _causal_skip_step(causal, src, idx, Lq, Lk, step, a, b, c,
                     a, b, c, k_blk, v_blk)
 
 
-def _ring_jnp(q, k, v, axis_name, causal, scale, rotary_base=None):
+def _ring_jnp(q, k, v, axis_name, causal, scale):
     """Blockwise jnp ring (non-TPU / unaligned-shape fallback).
     q [B,Lq,H,D]; k/v [B,Lk,G,D] — GQA repeats kv across each head
-    group (the kernel path never materializes that). Rotary is applied
-    up front: q with this shard's global positions, k with the HOME
-    shard's positions before it starts traveling (each k row's rotation
-    is fixed by its own global position, not by who computes with it).
-    """
+    group (the kernel path never materializes that)."""
     n = lax.psum(1, axis_name)
     idx = lax.axis_index(axis_name)
     B, Lq, H, D = q.shape
     Lk, G = k.shape[1], k.shape[2]
     perm = [(j, (j + 1) % n) for j in range(n)]
-
-    if rotary_base is not None:
-        qpos = idx * Lq + jnp.arange(Lq, dtype=jnp.int32)
-        kpos = idx * Lk + jnp.arange(Lk, dtype=jnp.int32)
-        q = apply_rotary(q, qpos[None, :, None], rotary_base)
-        k = apply_rotary(k, kpos[None, :, None], rotary_base)
 
     step = functools.partial(_block_attention, causal=causal, scale=scale)
 
@@ -199,7 +187,7 @@ def _schedule_offsets(schedule, rank, n, L):
 
 
 def _ring_flash_impl(q, k, v, axis_name, causal, scale,
-                     schedule="contiguous", rotary_base=None):
+                     schedule="contiguous"):
     """Pallas ring forward. q [B,Lq,H,D], k/v [B,Lk,G,D]. Returns
     (out [B,Lq,H,D], out_k, lse) where out_k is the normalized output
     in the grouped-rows kernel layout and lse [B*G, Lq*group, 8] is the
@@ -234,8 +222,7 @@ def _ring_flash_impl(q, k, v, axis_name, causal, scale,
                 q_offset=q_off,
                 kv_offset=_schedule_offsets(schedule, src, n, Lk),
                 causal=causal, scale=scale,
-                interpret=_interpret_mode(), group=group,
-                rotary_base=rotary_base)
+                interpret=_interpret_mode(), group=group)
 
         if schedule == "zigzag":
             # Every step has at-or-below-diagonal work by construction
@@ -257,27 +244,23 @@ def _ring_flash_impl(q, k, v, axis_name, causal, scale,
     return _from_rows_bl(out_k, B, group), out_k, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _ring_flash(q, k, v, axis_name, causal, scale,
-                schedule="contiguous", rotary_base=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _ring_flash(q, k, v, axis_name, causal, scale, schedule="contiguous"):
     """Pallas ring attention, wrapped in a custom VJP because Pallas
     kernels are not auto-differentiable. The backward is a second ring
     pass (FlashAttention-2 style) over the saved per-row log-sum-exp —
     no forward recompute: dq accumulates locally while dk/dv travel
     around the ring with their k/v shard."""
-    return _ring_flash_impl(q, k, v, axis_name, causal, scale,
-                            schedule, rotary_base)[0]
+    return _ring_flash_impl(q, k, v, axis_name, causal, scale, schedule)[0]
 
 
-def _ring_flash_fwd(q, k, v, axis_name, causal, scale, schedule,
-                    rotary_base):
+def _ring_flash_fwd(q, k, v, axis_name, causal, scale, schedule):
     out, out_k, lse = _ring_flash_impl(q, k, v, axis_name, causal,
-                                       scale, schedule, rotary_base)
+                                       scale, schedule)
     return out, (q, k, v, out_k, lse)
 
 
-def _ring_flash_bwd(axis_name, causal, scale, schedule, rotary_base,
-                    res, g):
+def _ring_flash_bwd(axis_name, causal, scale, schedule, res, g):
     from horovod_tpu.ops.flash_attention import flash_ring_bwd_step
 
     q, k, v, out_k, lse = res
@@ -315,8 +298,7 @@ def _ring_flash_bwd(axis_name, causal, scale, schedule, rotary_base,
                 q_offset=q_off,
                 kv_offset=_schedule_offsets(schedule, src, n, Lk),
                 causal=causal, scale=scale,
-                interpret=_interpret_mode(), group=group,
-                rotary_base=rotary_base)
+                interpret=_interpret_mode(), group=group)
 
         if schedule == "zigzag":
             dq, dk, dv = compute(dq, dk, dv, k_blk, v_blk)
@@ -333,17 +315,6 @@ def _ring_flash_bwd(axis_name, causal, scale, schedule, rotary_base,
         return dq, k_nxt, v_nxt, dk_nxt, dv_nxt
 
     dq, _, _, dk, dv = lax.fori_loop(0, n, body, (dq0, kk, vk, dk0, dv0))
-    if rotary_base is not None:
-        # The ring kernels accumulate dq/dk in ROTATED space (the
-        # accumulators persist across ring steps, so per-step counter-
-        # rotation would corrupt later additions). One counter-rotation
-        # at the end: dq by this shard's q-row positions, dk by its
-        # HOME kv positions (it traveled the full ring and is home).
-        qpos_rows = jnp.repeat(shard_positions(q_off, Lq), group)
-        dq = apply_rotary(dq, qpos_rows[None, :], rotary_base, neg=True)
-        kpos = shard_positions(
-            _schedule_offsets(schedule, idx, n, Lk), Lk)
-        dk = apply_rotary(dk, kpos[None, :], rotary_base, neg=True)
     return (_from_rows_bl(dq, B, group).astype(q.dtype),
             _from_rows_bl(dk, B, 1).astype(k.dtype),
             _from_rows_bl(dv, B, 1).astype(v.dtype))
@@ -353,15 +324,14 @@ _ring_flash.defvjp(_ring_flash_fwd, _ring_flash_bwd)
 
 
 def ring_attention(q, k, v, axis_name, causal=True, scale=None,
-                   schedule="contiguous", rotary_base=None):
+                   schedule="contiguous"):
     """Exact multi-head attention over a sequence sharded on `axis_name`.
 
     Args: q of shape [B, L_local, H, D], k/v [B, L_local, G, D] with
     G | H (GQA/MQA: query head h reads kv head h // (H//G); G == H is
     plain MHA) — per-device shards, equal L_local on every device,
     inside shard_map over `axis_name`. Returns [B, L_local, H, D] in
-    q.dtype. ``rotary_base`` fuses rotary embedding into the kernels
-    using the schedule's global positions — do not also rotate outside.
+    q.dtype.
 
     schedule:
       * "contiguous" (default): rank r holds tokens [r*L_local,
@@ -412,12 +382,10 @@ def ring_attention(q, k, v, axis_name, causal=True, scale=None,
                 "schedule='zigzag' runs on the Pallas kernel ring "
                 "only (TPU backend, or HVD_TPU_PALLAS_INTERPRET=1, "
                 "static scale)")
-        return _ring_flash(q, k, v, axis_name, causal, scale, "zigzag",
-                           rotary_base)
+        return _ring_flash(q, k, v, axis_name, causal, scale, "zigzag")
     if _use_flash_ring(Lq, Lk, scale):
-        return _ring_flash(q, k, v, axis_name, causal, scale,
-                           "contiguous", rotary_base)
-    return _ring_jnp(q, k, v, axis_name, causal, scale, rotary_base)
+        return _ring_flash(q, k, v, axis_name, causal, scale, "contiguous")
+    return _ring_jnp(q, k, v, axis_name, causal, scale)
 
 
 def zigzag_shard(x, n, axis=1):
@@ -650,8 +618,7 @@ def ring_allgather(x, axis_name, compression="none"):
     return chunks.reshape(-1)
 
 
-def ulysses_attention(q, k, v, axis_name, causal=True, scale=None,
-                      rotary_base=None):
+def ulysses_attention(q, k, v, axis_name, causal=True, scale=None):
     """All-to-all sequence parallelism (DeepSpeed-Ulysses style).
 
     Input q [B, L_local, H, D] / k, v [B, L_local, G, D] sequence-
@@ -660,9 +627,7 @@ def ulysses_attention(q, k, v, axis_name, causal=True, scale=None,
     the full sequence, and a second all_to_all restores sequence
     sharding. Both H and G must be divisible by the axis size (GQA
     keeps its head grouping because consecutive query heads share a kv
-    head and the split is contiguous). ``rotary_base`` fuses rotary in
-    the local kernel — positions are global (the gathered sequence
-    starts at 0), so shards agree.
+    head and the split is contiguous).
     """
     n = lax.psum(1, axis_name)
     B, Ll, H, D = q.shape
@@ -693,6 +658,5 @@ def ulysses_attention(q, k, v, axis_name, causal=True, scale=None,
     # falls back to the numerically-identical blockwise implementation
     # on other backends/unaligned shapes.
     from horovod_tpu.ops import flash_attention
-    og = flash_attention(qg, kg, vg, causal=causal, scale=scale,
-                         rotary_base=rotary_base)
+    og = flash_attention(qg, kg, vg, causal=causal, scale=scale)
     return heads_to_seq(og)

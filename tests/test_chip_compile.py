@@ -72,26 +72,24 @@ def _compile(one_chip, fn, *shapes):
         return jax.jit(fn).lower(*args).compile().as_text()
 
 
-# (B, H, G, L, D, fused rotary): the attention of chip_smoke.py's L=1024
-# LM and of its long-context h6 / gqa2 / fused-rope shape (the
-# one shape here whose dK/dV `flash_plan` sends down the gridded path:
-# three heads' rows and q's rotary tables do not fit, nor do k, v, the
-# results, k's tables and dK/dV's two accumulators: 40 MiB), and of the
+# (B, H, G, L, D): the attention of chip_smoke.py's L=1024 LM and of its
+# long-context h6 / gqa2 shape (the one shape here that `flash_plan` sends
+# down the gridded path, by its length alone and in all three kernels: k
+# and v whole are 32 MiB, and dK/dV held by the q block 96), and of the
 # benchmark's LM configurations on a chip (`neox1b4_w2048`: 2 x 2048;
 # `olmoe1b7_w2048` and `ouro2b6_w2048`: 1 x 4096), whose resident
 # backward, one kernel, asks for more than the default VMEM limit, and of
-# a grouped, fused-rotary shape short enough that the one kernel holds it
-# (three heads' rows, q's tables and dQ's accumulator: 17 MiB).
-_LM_SHAPES = [(8, 12, 12, 1024, 64, None), (2, 6, 2, 8192, 128, 10000.0),
-              (2, 16, 16, 2048, 128, None), (1, 16, 16, 4096, 128, None),
-              (2, 6, 2, 1024, 128, 10000.0)]
+# a grouped shape short enough that the one kernel holds it (three heads'
+# rows and dQ's accumulator: 12 MiB).
+_GRIDDED = (1, 6, 2, 32768, 128)
+_LM_SHAPES = [(8, 12, 12, 1024, 64), _GRIDDED, (2, 16, 16, 2048, 128),
+              (1, 16, 16, 4096, 128), (2, 6, 2, 1024, 128)]
 
 
-@pytest.mark.parametrize("B,H,G,L,D,rotary", _LM_SHAPES)
-def test_flash_forward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
+@pytest.mark.parametrize("B,H,G,L,D", _LM_SHAPES)
+def test_flash_forward_compiles_for_v5e(one_chip, B, H, G, L, D):
     fwd = functools.partial(_pallas_forward_lse, scale=D ** -0.5,
-                            causal=True, interpret=False,
-                            rotary_base=rotary)
+                            causal=True, interpret=False)
     bf16 = jnp.bfloat16
     text = _compile(one_chip, fwd, ((B, H, L, D), bf16),
                     ((B, G, L, D), bf16), ((B, G, L, D), bf16))
@@ -99,15 +97,15 @@ def test_flash_forward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
     assert _named(text, profile.FLASH_FWD)
 
 
-@pytest.mark.parametrize("B,H,G,L,D,rotary", _LM_SHAPES)
-def test_flash_backward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
+@pytest.mark.parametrize("B,H,G,L,D", _LM_SHAPES)
+def test_flash_backward_compiles_for_v5e(one_chip, B, H, G, L, D):
     def bwd(q, k, v, g):
         # interpret=False names the kernel path itself: the dispatcher in
         # flash_attention() asks jax.default_backend(), which is the CPU
         # here.
         _, vjp = jax.vjp(
-            lambda q, k, v: _flash(q, k, v, D ** -0.5, True, False,
-                                   rotary), q, k, v)
+            lambda q, k, v: _flash(q, k, v, D ** -0.5, True, False),
+            q, k, v)
         return vjp(g)
 
     bf16 = jnp.bfloat16
@@ -119,10 +117,10 @@ def test_flash_backward_compiles_for_v5e(one_chip, B, H, G, L, D, rotary):
     # dK/dV apart where not, three.
     paths = {name: p.path for backward in (False, True)
              for name, p in flash_plan(B, H, L, D, H // G, jnp.bfloat16,
-                                       backward, rotary is not None).items()}
+                                       backward).items()}
     assert paths == ({
-        profile.FLASH_FWD: "resident", profile.FLASH_DQ: "resident",
-        profile.FLASH_DKV: "gridded"} if L == 8192 else {
+        profile.FLASH_FWD: "gridded", profile.FLASH_DQ: "gridded",
+        profile.FLASH_DKV: "gridded"} if (B, H, G, L, D) == _GRIDDED else {
         profile.FLASH_FWD: "resident", profile.FLASH_BWD: "resident"})
     assert _kernels(text) == len(paths), text[:2000]
     for name in paths:
@@ -186,8 +184,8 @@ def test_ruled_flash_compiles_for_v5e(one_chip, H, G, length, expected):
 
     def fwd_bwd(q, k, v, g):
         out, vjp = jax.vjp(
-            lambda q, k, v: _flash(q, k, v, D ** -0.5, False, False, None,
-                                   rule), q, k, v)
+            lambda q, k, v: _flash(q, k, v, D ** -0.5, False, False, rule),
+            q, k, v)
         return (out,) + vjp(g)
 
     bf16 = jnp.bfloat16

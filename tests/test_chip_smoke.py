@@ -108,8 +108,8 @@ def test_kernels_phase_expects_what_the_plan_names(capsys):
     """The smoke's attention shapes: the kernels it requires in the
     compiled program are `hvd.profile.flash_plan`'s, so the backward as
     one kernel at the L=1024 LM's shape and at a benchmark cell's, as two
-    at the long grouped fused-rotary shape; `print_flash_plan` prints
-    the one-entry backward plan."""
+    at the long grouped shape, whose dK/dV is the gridded kernel;
+    `print_flash_plan` prints the one-entry backward plan."""
     import jax.numpy as jnp
 
     import chip_smoke
@@ -119,8 +119,13 @@ def test_kernels_phase_expects_what_the_plan_names(capsys):
     one, two = (["hvd_flash_fwd", "hvd_flash_bwd"],
                 ["hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"])
     assert got == [one, two, one]
-    assert (2, 16, 16, 2048, 128, False) in chip_smoke.SIZES["attn"]
-    chip_smoke.print_flash_plan(2, 16, 16, 2048, 128, False, jnp.bfloat16)
+    assert (2, 16, 16, 2048, 128) in chip_smoke.SIZES["attn"]
+    chip_smoke.print_flash_plan(*chip_smoke.SIZES["attn"][1], jnp.bfloat16)
+    lines = capsys.readouterr().out.splitlines()
+    assert len(lines) == 3 and lines[2].startswith(
+        "  hvd_flash_dkv: gridded held by the k block, blocks 1024 x 512, "
+        "grid (2, 32, 32) = 2048 steps")
+    chip_smoke.print_flash_plan(2, 16, 16, 2048, 128, jnp.bfloat16)
     lines = capsys.readouterr().out.splitlines()
     assert len(lines) == 2
     assert lines[1].startswith(
@@ -142,7 +147,7 @@ def test_kernels_phase_knows_the_block_diffusion_shape(capsys):
 
     B, H, G, L, D, block = chip_smoke.SIZES["attn_block_diffusion"]
     rule = BlockDiffusionMask(L, block)
-    shape = (B, H, G, 2 * L, D, False, jnp.bfloat16)
+    shape = (B, H, G, 2 * L, D, jnp.bfloat16)
     assert chip_smoke.flash_kernels(*shape, mask=rule) == [
         "hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"]
     chip_smoke.print_flash_plan(*shape, mask=rule)
@@ -155,7 +160,7 @@ def test_kernels_phase_knows_the_block_diffusion_shape(capsys):
         "grid (4, 64) = 256 steps, VMEM 27.0 MiB of a limit of 52")
     small = BlockDiffusionMask(128, 4)
     name, kernel, reference, qkvw = chip_smoke.attention_case(
-        1, 4, 2, 256, 64, False, jnp.float32, 0, mask=small)
+        1, 4, 2, 256, 64, jnp.float32, 0, mask=small)
     assert "BlockDiffusionMask(128, 4)" in name
     with jax.default_matmul_precision("highest"):
         for got, want in zip(kernel(*qkvw), reference(*qkvw)):

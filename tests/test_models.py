@@ -1,6 +1,7 @@
 """Model-zoo shape/correctness tests (CPU, f32 to keep them cheap)."""
 
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
@@ -122,6 +123,51 @@ def test_transformer_ring_matches_dense():
         shard_fn, mesh=mesh, in_specs=(P(None, "sp"), P(None, "sp")),
         out_specs=P(None, "sp"), check_vma=False))
     out = f(tokens, positions)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
+                               rtol=2e-4, atol=2e-4)
+
+
+_POSITIONS = {
+    # two documents packed into one row: the second starts again at 0
+    "packed": lambda L: jnp.concatenate(
+        [jnp.arange(L // 2 + 3), jnp.arange(L - L // 2 - 3)]),
+    # every third position (a uniform SHIFT would prove nothing: rotary
+    # scores read differences of positions)
+    "stretched": lambda L: 3 * jnp.arange(L),
+}
+
+
+@pytest.mark.parametrize("positions", sorted(_POSITIONS))
+@pytest.mark.parametrize("attention", ["flash", "ring", "ulysses"])
+def test_transformer_reads_the_positions_it_is_given(attention, positions):
+    """Positions that are NOT 0..L-1: every attention rotates by them, as
+    the dense one does (rotary has one implementation, outside the kernels
+    and the ring), and the answer is not the one at 0..L-1."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    from horovod_tpu.models import Transformer, TransformerConfig
+
+    n, L = 4, 32
+    base = dict(vocab_size=64, num_layers=2, num_heads=4, embed_dim=32,
+                mlp_dim=64, dtype=jnp.float32)
+    dense_model = Transformer(TransformerConfig(**base))
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, L), 0, 64)
+    pos = jnp.broadcast_to(
+        _POSITIONS[positions](L).astype(jnp.int32)[None], tokens.shape)
+    variables = dense_model.init(jax.random.PRNGKey(0), tokens)
+    expected = dense_model.apply(variables, tokens, pos)
+    assert not np.allclose(np.asarray(expected),
+                           np.asarray(dense_model.apply(variables, tokens)),
+                           rtol=1e-2, atol=1e-2)
+
+    sp = {} if attention == "flash" else {"sp_axis": "sp"}
+    model = Transformer(TransformerConfig(attention=attention, **sp, **base))
+    apply = lambda t, p: model.apply(variables, t, p)  # noqa: E731
+    if sp:
+        mesh = Mesh(np.array(jax.devices("cpu")[:n]), ("sp",))
+        apply = jax.shard_map(
+            apply, mesh=mesh, in_specs=(P(None, "sp"), P(None, "sp")),
+            out_specs=P(None, "sp"), check_vma=False)
+    out = jax.jit(apply)(tokens, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-4, atol=2e-4)
 

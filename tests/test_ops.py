@@ -136,33 +136,32 @@ def test_flash_pallas_backward_interpret(causal, bq, bk):
             rtol=2e-4, atol=2e-4)
 
 
-def _split_budget(B, H, L, D, group, dtype, rotary, block_q=None,
-                  block_k=None):
+def _split_budget(B, H, L, D, group, dtype, block_q=None, block_k=None):
     """A `vmem_budget` one byte short of what the one-kernel backward
     holds: `flash_plan` then keeps the backward's two kernels, resident
     (each holds less), where everything fits it would choose the one."""
     from horovod_tpu.ops.flash_attention import flash_plan
-    fused = flash_plan(B, H, L, D, group, dtype, True, rotary, block_q,
-                       block_k, 2 ** 40)
+    fused = flash_plan(B, H, L, D, group, dtype, True, block_q, block_k,
+                       2 ** 40)
     assert list(fused) == ["hvd_flash_bwd"], fused
     return fused["hvd_flash_bwd"].resident_bytes - 1
 
 
-@pytest.mark.parametrize("causal,H,G,rotary,bqp,bk", [
-    (True, 4, 4, None, 256, 512),   # group 1; peel: 1 block fwd/dQ, 2 dK/dV
-    (True, 4, 4, None, 512, 256),   # peel: 2 blocks fwd/dQ, 1 dK/dV
-    (False, 4, 4, None, 256, 512),  # not causal: one loop, no peel
-    (False, 4, 2, 10000.0, 512, 256),
-    (True, 4, 2, None, 256, 512),   # group 2
-    (True, 4, 2, 10000.0, 512, 256),
-    (True, 4, 1, None, 512, 256),   # group 4 (MQA)
-    (True, 4, 1, 10000.0, 256, 512),
-    (True, 4, 4, 10000.0, 128, 128),  # equal blocks: the longest loops
-    (True, 3, 1, None, 256, 512),   # group 3, bqp < bk
-    (True, 3, 1, 10000.0, 512, 256),  # group 3, bqp > bk, fused rotary
-    (False, 6, 2, 10000.0, 256, 512),  # group 3 of two kv heads, not causal
+@pytest.mark.parametrize("causal,H,G,bqp,bk", [
+    (True, 4, 4, 256, 512),   # group 1; peel: 1 block fwd/dQ, 2 dK/dV
+    (True, 4, 4, 512, 256),   # peel: 2 blocks fwd/dQ, 1 dK/dV
+    (False, 4, 4, 256, 512),  # not causal: one loop, no peel
+    (False, 4, 2, 512, 256),
+    (True, 4, 2, 256, 512),   # group 2
+    (True, 4, 2, 512, 256),
+    (True, 4, 1, 512, 256),   # group 4 (MQA)
+    (True, 4, 1, 256, 512),
+    (True, 4, 4, 128, 128),   # equal blocks: the longest loops
+    (True, 3, 1, 256, 512),   # group 3, bqp < bk
+    (True, 3, 1, 512, 256),   # group 3, bqp > bk
+    (False, 6, 2, 256, 512),  # group 3 of two kv heads, not causal
 ])
-def test_flash_resident_path_interpret(causal, H, G, rotary, bqp, bk):
+def test_flash_resident_path_interpret(causal, H, G, bqp, bk):
     """The resident kernels (k/v, or q/dO/lse/delta, whole in VMEM and
     walked by a loop inside the kernel): out, dQ, dK and dV against dense
     attention, and against the gridded kernels on the same blocks, which
@@ -179,7 +178,7 @@ def test_flash_resident_path_interpret(causal, H, G, rotary, bqp, bk):
                     jnp.float32)
     t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     budgets = dict(_PATHS, split=_split_budget(
-        B, H, L, D, group, q.dtype, rotary is not None, bqp * group, bk))
+        B, H, L, D, group, q.dtype, bqp * group, bk))
     names = {"gridded": ["hvd_flash_dq", "hvd_flash_dkv"],
              "split": ["hvd_flash_dq", "hvd_flash_dkv"],
              "resident": ["hvd_flash_bwd"]}
@@ -187,18 +186,18 @@ def test_flash_resident_path_interpret(causal, H, G, rotary, bqp, bk):
     for path, budget in budgets.items():
         for backward in (False, True):
             plans = flash_plan(B, H, L, D, group, q.dtype, backward,
-                               rotary is not None, bqp * group, bk, budget)
+                               bqp * group, bk, budget)
             assert {p.path for p in plans.values()} == {
                 "gridded" if path == "gridded" else "resident"}
             if backward:
                 assert list(plans) == names[path]
         out, lse = _pallas_forward_lse(
             t(q), t(k), t(v), D ** -0.5, causal, True, bqp * group, bk,
-            rotary, budget)
+            budget)
         got[path] = (out,) + _pallas_backward(
             t(q), t(k), t(v), out, lse, t(w), D ** -0.5, causal, True,
-            bqp * group, bk, rotary, budget)
-    dense = lambda q, k, v: _dense_gqa(q, k, v, causal, rotary)  # noqa: E731
+            bqp * group, bk, budget)
+    dense = lambda q, k, v: _dense_gqa(q, k, v, causal)  # noqa: E731
     want = (dense(q, k, v),) + jax.grad(
         lambda q, k, v: jnp.sum(dense(q, k, v) * w),
         argnums=(0, 1, 2))(q, k, v)
@@ -228,7 +227,7 @@ def test_flash_resident_equals_gridded_in_bf16(other):
                for x in _rand_qkv(B, L, H, D, seed=31))
     w = jnp.asarray(np.random.RandomState(32).randn(B, H, L, D), bf16)
     budget = (2 ** 18 if other == "gridded"
-              else _split_budget(B, H, L, D, 1, bf16, False))
+              else _split_budget(B, H, L, D, 1, bf16))
     got = []
     for budget, path, kernels in (
             (None, "resident", 1),
@@ -247,8 +246,8 @@ def test_flash_resident_equals_gridded_in_bf16(other):
         assert np.max(np.abs(r - g)) <= 2 ** -8 * np.max(np.abs(g)), nm
 
 
-def _q_held_budget(monkeypatch, B, H, L, D, group, dtype, rotary,
-                   block_q=None, block_k=None):
+def _q_held_budget(monkeypatch, B, H, L, D, group, dtype, block_q=None,
+                   block_k=None):
     """A `vmem_budget` under which `flash_plan` keeps dQ resident and takes
     dK/dV's SECOND resident form, held by the q block: one byte short of
     what the first holds (q, dO, lse and delta of the kv head's whole query
@@ -258,16 +257,16 @@ def _q_held_budget(monkeypatch, B, H, L, D, group, dtype, rotary,
     import importlib
     # the module: `ops` hands out the function under the same name
     fa = importlib.import_module("horovod_tpu.ops.flash_attention")
-    split = _split_budget(B, H, L, D, group, dtype, rotary, block_q, block_k)
+    split = _split_budget(B, H, L, D, group, dtype, block_q, block_k)
     if group == 1:
         monkeypatch.setattr(fa, "_DKV_HELD", ("q",))
         budget = split
     else:
-        budget = fa.flash_plan(B, H, L, D, group, dtype, True, rotary,
-                               block_q, block_k, split)[
+        budget = fa.flash_plan(B, H, L, D, group, dtype, True, block_q,
+                               block_k, split)[
                                    "hvd_flash_dkv"].resident_bytes - 1
-    plans = fa.flash_plan(B, H, L, D, group, dtype, True, rotary, block_q,
-                          block_k, budget)
+    plans = fa.flash_plan(B, H, L, D, group, dtype, True, block_q, block_k,
+                          budget)
     assert {n: (p.path, p.held) for n, p in plans.items()} == {
         "hvd_flash_dq": ("resident", "q"),
         "hvd_flash_dkv": ("resident", "q")}, plans
@@ -276,18 +275,18 @@ def _q_held_budget(monkeypatch, B, H, L, D, group, dtype, rotary,
     return budget
 
 
-@pytest.mark.parametrize("causal,H,G,rotary,bqp,bk", [
-    (True, 4, 4, None, 256, 512),     # group 1; a k block of two q blocks
-    (False, 4, 4, 10000.0, 512, 256),  # not causal: one loop, no peel
-    (True, 4, 2, None, 256, 512),     # group 2
-    (True, 4, 2, 10000.0, 512, 256),  # a q block of two k blocks
-    (False, 4, 2, None, 128, 128),
-    (True, 8, 1, None, 128, 512),     # group 8, the block-diffusion cell's
-    (True, 8, 1, 10000.0, 256, 128),
-    (False, 8, 1, 10000.0, 128, 256),
+@pytest.mark.parametrize("causal,H,G,bqp,bk", [
+    (True, 4, 4, 256, 512),   # group 1; a k block of two q blocks
+    (False, 4, 4, 512, 256),  # not causal: one loop, no peel
+    (True, 4, 2, 256, 512),   # group 2
+    (True, 4, 2, 512, 256),   # a q block of two k blocks
+    (False, 4, 2, 128, 128),
+    (True, 8, 1, 128, 512),   # group 8, the block-diffusion cell's
+    (True, 8, 1, 256, 128),
+    (False, 8, 1, 128, 256),
 ])
 def test_flash_dkv_held_by_the_q_block_interpret(monkeypatch, causal, H, G,
-                                                 rotary, bqp, bk):
+                                                 bqp, bk):
     """dK/dV's second resident form (k, v and the results whole in VMEM, a
     q block a grid step, dK and dV summed in two f32 accumulators in
     scratch): against the gradient of `_blockwise_reference`, and against
@@ -303,17 +302,15 @@ def test_flash_dkv_held_by_the_q_block_interpret(monkeypatch, causal, H, G,
     w = jnp.asarray(np.random.RandomState(42).randn(B, H, L, D),
                     jnp.float32)
     budgets = {"gridded": 0, "q-held": _q_held_budget(
-        monkeypatch, B, H, L, D, group, q.dtype, rotary is not None,
-        bqp * group, bk)}
+        monkeypatch, B, H, L, D, group, q.dtype, bqp * group, bk)}
     got = {}
     for path, budget in budgets.items():
         out, lse = _pallas_forward_lse(q, k, v, D ** -0.5, causal, True,
-                                       bqp * group, bk, rotary, budget)
+                                       bqp * group, bk, budget)
         got[path] = _pallas_backward(q, k, v, out, lse, w, D ** -0.5,
-                                     causal, True, bqp * group, bk, rotary,
-                                     budget)
+                                     causal, True, bqp * group, bk, budget)
     _, vjp = jax.vjp(lambda q, k, v: _blockwise_reference(
-        q, k, v, D ** -0.5, causal, rotary), q, k, v)
+        q, k, v, D ** -0.5, causal), q, k, v)
     for r, g, d, nm in zip(got["q-held"], got["gridded"], vjp(w),
                            ("dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(r), np.asarray(d), rtol=2e-4,
@@ -322,10 +319,9 @@ def test_flash_dkv_held_by_the_q_block_interpret(monkeypatch, causal, H, G,
                                    atol=1e-6, err_msg=nm)
 
 
-@pytest.mark.parametrize("H,G,rotary", [(4, 2, None), (8, 1, None),
-                                        (8, 1, 10000.0)])
+@pytest.mark.parametrize("H,G", [(4, 2), (8, 1), (6, 2)])
 def test_flash_dkv_held_by_the_q_block_equals_gridded_in_bf16(monkeypatch, H,
-                                                              G, rotary):
+                                                              G):
     """bf16 inputs, as the models feed them, on the plan's own blocks: dK
     and dV of the q-held form and of the gridded `_bwd_dkv_kernel` agree to
     bf16 rounding (both sum a k block's tiles in f32 and round once)."""
@@ -337,14 +333,11 @@ def test_flash_dkv_held_by_the_q_block_equals_gridded_in_bf16(monkeypatch, H,
                for x in _rand_gqa(B, L, H, G, D, seed=43))
     w = jnp.asarray(np.random.RandomState(44).randn(B, H, L, D), bf16)
     got = []
-    for budget in (0, _q_held_budget(monkeypatch, B, H, L, D, H // G, bf16,
-                                     rotary is not None)):
+    for budget in (0, _q_held_budget(monkeypatch, B, H, L, D, H // G, bf16)):
         out, lse = _pallas_forward_lse(q, k, v, D ** -0.5, True, True,
-                                       rotary_base=rotary,
                                        vmem_budget=budget)
         got.append(_pallas_backward(q, k, v, out, lse, w, D ** -0.5, True,
-                                    True, rotary_base=rotary,
-                                    vmem_budget=budget))
+                                    True, vmem_budget=budget))
     for g, r, nm in zip(got[0], got[1], ("dq", "dk", "dv")):
         g, r = (np.asarray(x, np.float32) for x in (g, r))
         assert np.max(np.abs(r - g)) <= 2 ** -8 * np.max(np.abs(g)), nm
@@ -352,8 +345,7 @@ def test_flash_dkv_held_by_the_q_block_equals_gridded_in_bf16(monkeypatch, H,
 
 # B, H, L, D of the benchmark's cells: `lm1b4_1chip` and `lm1b4_dp4` (2
 # sequences of 2048 a chip) and `olmoe1b7_1chip` and `ouro2b6_1chip` (one
-# of 4096), 16 heads x 128, bf16, group 1, no fused rotary. Expected:
-# blocks, grid, path.
+# of 4096), 16 heads x 128, bf16, group 1. Expected: blocks, grid, path.
 @pytest.mark.parametrize("B,L,expected", [
     (2, 2048, {"hvd_flash_fwd": (512, 512, (32, 4)),
                "hvd_flash_bwd": (512, 1024, (32, 2))}),
@@ -440,12 +432,11 @@ def test_flash_plan_past_the_budget_is_gridded():
         for plan in flash_plan(1, 16, 32768, 128, 1, bf16,
                                backward).values():
             assert plan.path == "gridded" and len(plan.grid) == 3
-    # Fused rotary adds two f32 tables to what a kernel holds, and a head
-    # group multiplies dK/dV's rows.
-    rot = flash_plan(2, 6, 8192, 128, 3, bf16, backward=True, rotary=True)
-    assert rot["hvd_flash_dkv"].path == "gridded"
-    assert flash_plan(1, 16, 16384, 128, 1, bf16, rotary=True)[
-        "hvd_flash_fwd"].path == "gridded"
+    # A head group does not save dK/dV at this length: held by the q block
+    # it has k, v, the results and two accumulators whole, 48 MiB.
+    grouped = flash_plan(1, 8, 16384, 128, 2, bf16, backward=True)
+    assert {n: p.path for n, p in grouped.items()} == {
+        "hvd_flash_dq": "resident", "hvd_flash_dkv": "gridded"}
     odd = flash_plan(1, 2, 768, 128, 1, bf16, block_q=384, block_k=256)
     assert odd["hvd_flash_fwd"].path == "gridded"
     # The same for the backward: no static peel, so not the one kernel.
@@ -562,26 +553,13 @@ def test_transformer_flash_matches_dense():
 
 
 # ---------------------------------------------------------------------------
-# GQA/MQA (grouped kv heads) + fused rotary
+# GQA/MQA (grouped kv heads)
 
 
-def _ref_rotary(x, base=10000.0):
-    """Independent outside-the-kernel rotary reference: the production
-    model path (`models.transformer._rotary`), positions 0..L-1, over
-    [B, L, H, D]. The kernels' in-block rotation must agree with it."""
-    from horovod_tpu.models.transformer import _rotary
-    B, L = x.shape[:2]
-    pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (B, L))
-    return _rotary(x, pos, base)
-
-
-def _dense_gqa(q, k, v, causal, rotary_base=None):
-    """Dense reference for q [B,L,H,D], k/v [B,L,G,D]: rotate outside,
-    repeat kv across each query-head group."""
+def _dense_gqa(q, k, v, causal):
+    """Dense reference for q [B,L,H,D], k/v [B,L,G,D]: repeat kv across
+    each query-head group."""
     H, G = q.shape[2], k.shape[2]
-    if rotary_base is not None:
-        q = _ref_rotary(q, rotary_base)
-        k = _ref_rotary(k, rotary_base)
     if H != G:
         k = jnp.repeat(k, H // G, axis=2)
         v = jnp.repeat(v, H // G, axis=2)
@@ -611,34 +589,31 @@ def test_flash_gqa_interpret_matches_dense(G, causal):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("G,rotary", [(2, None), (4, 10000.0),
-                                      (2, 10000.0), (1, 10000.0)])
-def test_flash_gqa_rotary_backward_interpret(G, rotary):
+@pytest.mark.parametrize("G,D", [(2, 32), (4, 32), (1, 32), (2, 64)])
+def test_flash_gqa_backward_interpret(G, D):
     """Values AND all three gradients of the Pallas path (custom VJP,
-    interpret mode) for grouped kv heads and fused rotary, against
-    dense attention that rotates outside and repeats kv. Pins: the
-    in-kernel dK/dV group reduction, the rotated-space dQ/dK
-    accumulation with finalize counter-rotation, and the grouped
-    causal masks."""
+    interpret mode) for grouped kv heads (G = H: none), against dense
+    attention that repeats kv. Pins: the in-kernel dK/dV group reduction
+    and the grouped causal masks."""
     from horovod_tpu.ops.flash_attention import _flash
-    B, L, H, D = 1, 512, 4, 32
+    B, L, H = 1, 512, 4
     q, k, v = _rand_gqa(B, L, H, G, D, seed=9)
     w = jnp.asarray(np.random.RandomState(10).randn(B, L, H, D),
                     jnp.float32)
 
     def loss_flash(q, k, v):
         out = _flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-                     v.transpose(0, 2, 1, 3), D ** -0.5, True, True,
-                     rotary).transpose(0, 2, 1, 3)
+                     v.transpose(0, 2, 1, 3), D ** -0.5, True,
+                     True).transpose(0, 2, 1, 3)
         return jnp.sum(out * w), out
 
     def loss_dense(q, k, v):
-        return jnp.sum(_dense_gqa(q, k, v, True, rotary) * w)
+        return jnp.sum(_dense_gqa(q, k, v, True) * w)
 
     (_, out), g_flash = jax.value_and_grad(
         loss_flash, argnums=(0, 1, 2), has_aux=True)(q, k, v)
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(_dense_gqa(q, k, v, True, rotary)),
+        np.asarray(out), np.asarray(_dense_gqa(q, k, v, True)),
         rtol=2e-5, atol=2e-5)
     g_dense = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for gf, gd, nm in zip(g_flash, g_dense, ("dq", "dk", "dv")):
@@ -647,22 +622,22 @@ def test_flash_gqa_rotary_backward_interpret(G, rotary):
 
 
 def test_flash_attention_gqa_fallback_and_validation():
-    """Public API on CPU (blockwise fallback): GQA + fused rotary
-    values/grads match dense; mismatched head counts raise."""
+    """Public API on CPU (blockwise fallback): GQA values/grads match
+    dense; mismatched head counts raise."""
     from horovod_tpu.ops import flash_attention
     B, L, H, G, D = 1, 48, 4, 2, 16  # L not 128-aligned -> fallback
     q, k, v = _rand_gqa(B, L, H, G, D, seed=13)
 
-    out = flash_attention(q, k, v, causal=True, rotary_base=10000.0)
-    expected = _dense_gqa(q, k, v, True, 10000.0)
+    out = flash_attention(q, k, v, causal=True)
+    expected = _dense_gqa(q, k, v, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
 
     g_flash = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-        q, k, v, causal=True, rotary_base=10000.0) ** 2),
+        q, k, v, causal=True) ** 2),
         argnums=(0, 1, 2))(q, k, v)
     g_dense = jax.grad(lambda q, k, v: jnp.sum(
-        _dense_gqa(q, k, v, True, 10000.0) ** 2),
+        _dense_gqa(q, k, v, True) ** 2),
         argnums=(0, 1, 2))(q, k, v)
     for gf, gd in zip(g_flash, g_dense):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
@@ -689,17 +664,16 @@ def test_pick_rows_block_policy():
 
 def test_transformer_gqa_flash_matches_dense():
     """Transformer with grouped kv heads: the flash path (fallback on
-    CPU) must match the dense path on the same params, with rope_fused
-    exercising the kernel-side rotary against the model-side one; the
-    kv projections must actually shrink to G heads."""
+    CPU) must match the dense path on the same params (both rotate by
+    the model's `_rotary`, outside the attention); the kv projections must
+    actually shrink to G heads."""
     from horovod_tpu.models import Transformer, TransformerConfig
     base = dict(vocab_size=64, num_layers=2, num_heads=4,
                 num_kv_heads=2, embed_dim=32, mlp_dim=64,
                 dtype=jnp.float32)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 64)
     dense_model = Transformer(TransformerConfig(**base))
-    flash_model = Transformer(TransformerConfig(
-        attention="flash", rope_fused=True, **base))
+    flash_model = Transformer(TransformerConfig(attention="flash", **base))
     variables = dense_model.init(jax.random.PRNGKey(0), tokens)
     key_kernel = variables["params"]["block_0"]["attn"]["key"]["kernel"]
     assert key_kernel.shape == (32, 2, 8)  # (embed, G, head_dim)

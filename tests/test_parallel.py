@@ -347,53 +347,59 @@ def test_train_step_accum_composes_with_zero1():
                                rtol=1e-5, atol=1e-6)
 
 
-def _dense_gqa_reference(q, k, v, causal=True, rotary_base=None):
-    """Dense reference for q [B,L,H,D], k/v [B,L,G,D]: rotate outside
-    (the production model path), repeat kv across head groups."""
+def _positions(B, L):
+    return jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None], (B, L))
+
+
+def _dense_gqa_reference(q, k, v, causal=True, rotary=False):
+    """Dense reference for q [B,L,H,D], k/v [B,L,G,D] on the whole
+    sequence: with ``rotary``, q and k rotated by the model's `_rotary` at
+    positions 0..L-1; kv repeated across head groups."""
     H, G = q.shape[2], k.shape[2]
-    if rotary_base is not None:
+    if rotary:
         from horovod_tpu.models.transformer import _rotary
-        B, L = q.shape[:2]
-        pos = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32)[None],
-                               (B, L))
-        q = _rotary(q, pos, rotary_base)
-        k = _rotary(k, pos, rotary_base)
+        pos = _positions(*q.shape[:2])
+        q, k = _rotary(q, pos), _rotary(k, pos)
     if H != G:
         k = jnp.repeat(k, H // G, axis=2)
         v = jnp.repeat(v, H // G, axis=2)
     return _dense_reference(q, k, v, causal)
 
 
-def test_ring_gqa_rotary_jnp_path_matches_dense():
-    """The jnp ring fallback with grouped kv heads and rotary: the
-    small G-head shards travel the ring and are repeated per step."""
+def _rotated_ring(q, k, v, positions, **kw):
+    """What the model does on a sequence shard: rotary outside the ring, by
+    the shard's GLOBAL positions (an input, sharded as the tokens are)."""
+    from horovod_tpu.models.transformer import _rotary
     from horovod_tpu.parallel import ring_attention
+    return ring_attention(_rotary(q, positions), _rotary(k, positions), v,
+                          "sp", causal=True, **kw)
+
+
+def test_ring_gqa_jnp_path_matches_dense():
+    """The jnp ring fallback with grouped kv heads, rotary outside it by
+    global positions: the small G-head shards travel the ring and are
+    repeated per step."""
     n = 4
     B, L, H, G, D = 2, 32, 4, 2, 16
     rng = np.random.RandomState(21)
     q = jnp.asarray(rng.randn(B, L, H, D), jnp.float32)
     k = jnp.asarray(rng.randn(B, L, G, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, L, G, D), jnp.float32)
-    expected = _dense_gqa_reference(q, k, v, True, 10000.0)
+    expected = _dense_gqa_reference(q, k, v, rotary=True)
 
     mesh = _mesh(n, "sp")
     f = jax.jit(jax.shard_map(
-        lambda q, k, v: ring_attention(q, k, v, "sp", causal=True,
-                                       rotary_base=10000.0),
-        mesh=mesh, in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp"),
-        check_vma=False))
-    out = f(q, k, v)
+        _rotated_ring, mesh=mesh, in_specs=(P(None, "sp"),) * 4,
+        out_specs=P(None, "sp"), check_vma=False))
+    out = f(q, k, v, _positions(B, L))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
 
 
-def test_ring_flash_gqa_rotary_values_and_grads(monkeypatch):
-    """Kernel ring path (interpret mode) with grouped kv heads + fused
-    rotary: values AND gradients vs dense. Pins the grouped-rows ring
-    layout, in-kernel rotation from SMEM offsets, and the post-loop
-    counter-rotation of dq (shard q positions) and dk (home kv
-    positions after the full ring trip)."""
-    from horovod_tpu.parallel import ring_attention
+def test_ring_flash_gqa_values_and_grads(monkeypatch):
+    """Kernel ring path (interpret mode) with grouped kv heads, rotary
+    outside it by global positions: values AND gradients (through the
+    rotation) vs dense. Pins the grouped-rows ring layout."""
     monkeypatch.setenv("HVD_TPU_PALLAS_INTERPRET", "1")
     n = 2
     B, L, H, G, D = 1, 256, 4, 2, 16
@@ -402,28 +408,27 @@ def test_ring_flash_gqa_rotary_values_and_grads(monkeypatch):
     k = jnp.asarray(rng.randn(B, L, G, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, L, G, D), jnp.float32)
     w = jnp.asarray(rng.randn(B, L, H, D), jnp.float32)
-    expected = _dense_gqa_reference(q, k, v, True, 10000.0)
+    expected = _dense_gqa_reference(q, k, v, rotary=True)
 
     mesh = _mesh(n, "sp")
 
-    def fwd_and_grads(q, k, v, w):
+    def fwd_and_grads(q, k, v, w, positions):
         def loss(q, k, v):
-            out = ring_attention(q, k, v, "sp", causal=True,
-                                 rotary_base=10000.0)
+            out = _rotated_ring(q, k, v, positions)
             return jnp.sum(out.astype(jnp.float32) * w), out
         (_, out), grads = jax.value_and_grad(
             loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
         return (out,) + grads
 
     f = jax.jit(jax.shard_map(
-        fwd_and_grads, mesh=mesh, in_specs=(P(None, "sp"),) * 4,
+        fwd_and_grads, mesh=mesh, in_specs=(P(None, "sp"),) * 5,
         out_specs=(P(None, "sp"),) * 4, check_vma=False))
-    out, gq, gk, gv = f(q, k, v, w)
+    out, gq, gk, gv = f(q, k, v, w, _positions(B, L))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expected),
                                rtol=2e-5, atol=2e-5)
 
     def dense_loss(q, k, v):
-        return jnp.sum(_dense_gqa_reference(q, k, v, True, 10000.0) * w)
+        return jnp.sum(_dense_gqa_reference(q, k, v, rotary=True) * w)
 
     dq, dk, dv = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
     for got, exp, nm in ((gq, dq, "dq"), (gk, dk, "dk"), (gv, dv, "dv")):
@@ -431,12 +436,11 @@ def test_ring_flash_gqa_rotary_values_and_grads(monkeypatch):
                                    rtol=2e-4, atol=2e-4, err_msg=nm)
 
 
-def test_zigzag_mqa_rotary_matches_dense(monkeypatch):
-    """zigzag schedule + MQA (G=1) + fused rotary: the in-kernel
-    rotation must use the discontiguous per-chunk global positions and
-    the post-loop counter-rotation the chunked shard_positions."""
-    from horovod_tpu.parallel import (ring_attention, zigzag_shard,
-                                      zigzag_unshard)
+def test_zigzag_mqa_matches_dense(monkeypatch):
+    """zigzag schedule + MQA (G=1), rotary outside the ring: the positions
+    are zigzag-sharded with the tokens, so each shard rotates by its two
+    discontiguous chunks' global positions."""
+    from horovod_tpu.parallel import zigzag_shard, zigzag_unshard
     monkeypatch.setenv("HVD_TPU_PALLAS_INTERPRET", "1")
     n = 4
     B, L, H, G, D = 1, 4096, 2, 1, 16
@@ -445,30 +449,30 @@ def test_zigzag_mqa_rotary_matches_dense(monkeypatch):
     k = jnp.asarray(rng.randn(B, L, G, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, L, G, D), jnp.float32)
     w = jnp.asarray(rng.randn(B, L, H, D), jnp.float32)
-    expected = _dense_gqa_reference(q, k, v, True, 10000.0)
+    expected = _dense_gqa_reference(q, k, v, rotary=True)
 
-    qz, kz, vz, wz = (zigzag_shard(x, n) for x in (q, k, v, w))
+    qz, kz, vz, wz, pz = (zigzag_shard(x, n)
+                          for x in (q, k, v, w, _positions(B, L)))
     mesh = _mesh(n, "sp")
 
-    def fwd_and_grads(q, k, v, w):
+    def fwd_and_grads(q, k, v, w, positions):
         def loss(q, k, v):
-            out = ring_attention(q, k, v, "sp", causal=True,
-                                 schedule="zigzag", rotary_base=10000.0)
+            out = _rotated_ring(q, k, v, positions, schedule="zigzag")
             return jnp.sum(out.astype(jnp.float32) * w), out
         (_, out), grads = jax.value_and_grad(
             loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
         return (out,) + grads
 
     f = jax.jit(jax.shard_map(
-        fwd_and_grads, mesh=mesh, in_specs=(P(None, "sp"),) * 4,
+        fwd_and_grads, mesh=mesh, in_specs=(P(None, "sp"),) * 5,
         out_specs=(P(None, "sp"),) * 4, check_vma=False))
-    out, gq, gk, gv = f(qz, kz, vz, wz)
+    out, gq, gk, gv = f(qz, kz, vz, wz, pz)
     np.testing.assert_allclose(
         np.asarray(zigzag_unshard(out, n)), np.asarray(expected),
         rtol=2e-5, atol=2e-5)
 
     def dense_loss(q, k, v):
-        return jnp.sum(_dense_gqa_reference(q, k, v, True, 10000.0) * w)
+        return jnp.sum(_dense_gqa_reference(q, k, v, rotary=True) * w)
 
     dq, dk, dv = jax.grad(dense_loss, argnums=(0, 1, 2))(q, k, v)
     for got, exp, nm in ((gq, dq, "dq"), (gk, dk, "dk"), (gv, dv, "dv")):

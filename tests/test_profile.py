@@ -517,7 +517,7 @@ def test_flash_kernels_carry_their_names(budget, expected):
     assert _kernel_names(str(jax.make_jaxpr(grads)(q, q, q))) == expected
 
     def loss(q, k, v):
-        return _flash(q, k, v, 0.125, True, True, None).sum()
+        return _flash(q, k, v, 0.125, True, True).sum()
 
     # What a model's call runs: the default budget, which this shape fits.
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
@@ -550,8 +550,10 @@ def test_flash_plan_under_hvd_profile_is_the_kernels_own(backward, B, L,
 # form): (path, block_q, block_k, grid, grid_steps, resident_bytes,
 # vmem_bytes, vmem_limit_bytes, tiles visited, masked, skipped). The six
 # cells whose backward is one kernel never reach the new branch; SDAR's
-# forward and dQ stay as they were; a fused-rotary grouped call at L=8192
-# and a plain one at 16384 fit neither resident form of dK/dV.
+# forward and dQ stay as they were. Beside them a grouped call at L=8192,
+# whose dK/dV is held by the q block (k, v, the results and the two
+# accumulators: 24 MiB), and a plain one at 16384, which fits neither
+# resident form of dK/dV.
 _MiB = 2 ** 20
 _SDAR_TILES = (1280, 384, 2816)
 CELL_PLANS = {
@@ -575,12 +577,11 @@ CELL_PLANS = {
                           10 * _MiB, 30 * _MiB) + _SDAR_TILES,
         "hvd_flash_dq": ("resident", 1024, 512, (4, 64), 256, 8 * _MiB,
                          12058624, 32 * _MiB) + _SDAR_TILES}),
-    "grouped_fused_rotary_L8192": (
-        dict(B=2, H=6, L=8192, group=3, rotary=True), {
-            "hvd_flash_dq": ("resident", 1536, 512, (4, 16), 64, 24 * _MiB,
-                             33816576, 67 * _MiB, None, None, None),
-            "hvd_flash_dkv": ("gridded", 1536, 512, (4, 16, 16), 1024, 0,
-                              10616832, None, None, None, None)}),
+    "grouped_L8192": (dict(B=2, H=6, L=8192, group=3), {
+        "hvd_flash_dq": ("resident", 1536, 512, (4, 16), 64, 8 * _MiB,
+                         13893632, 43 * _MiB, None, None, None),
+        "hvd_flash_dkv": ("resident", 1536, 512, (4, 16), 64, 24 * _MiB,
+                          29884416, 62 * _MiB, None, None, None)}),
     "plain_L16384": (dict(B=1, H=16, L=16384), {
         "hvd_flash_dq": ("resident", 512, 512, (16, 32), 512, 16 * _MiB,
                          18612224, 31 * _MiB, None, None, None),
@@ -608,9 +609,11 @@ def test_flash_plans_of_the_cells_are_the_parents(cell):
     for name, want in expected.items():
         got = plans[name]._asdict()
         # A k block is held by the one-kernel backward and the gridded
-        # dK/dV, a q block by the forward and dQ.
+        # dK/dV, a q block by the forward, dQ and the one resident dK/dV
+        # of this table (the grouped call's; the next two tests say when).
         assert got.pop("held") == (
-            "k" if name in ("hvd_flash_bwd", "hvd_flash_dkv") else "q")
+            "k" if name == "hvd_flash_bwd" or (
+                name == "hvd_flash_dkv" and want[0] == "gridded") else "q")
         assert tuple(got.values()) == want, name
 
 
@@ -648,27 +651,27 @@ def test_flash_plan_holds_sdars_dkv_by_the_q_block():
                                        1280)
 
 
-# (B, H, L, group, fused rotary) of calls outside the cells -> dK/dV's form:
-# a head group's rows multiply what the k-held form holds (3 KiB a row at
-# D=128 in bf16) and not what the q-held form does (3 KiB a position, 5
-# with k's rotary tables), so grouped calls move to it up to L=8192 (4096
-# with fused rotary); with one head a kv head nothing does.
-@pytest.mark.parametrize("B,H,L,group,rotary,expected", [
-    (2, 6, 8192, 3, False, ("resident", "q")),
-    (1, 32, 2048, 8, False, ("resident", "q")),
-    (1, 32, 4096, 4, True, ("resident", "q")),
-    (1, 16, 4096, 2, False, ("resident", "k")),   # 24 MiB: the first form
-    (2, 6, 8192, 3, True, ("gridded", "k")),      # 40 MiB with k's tables
-    (1, 8, 16384, 2, False, ("gridded", "k")),
-    (1, 16, 8192, 1, True, ("gridded", "k")),     # both forms 40 MiB
+# (B, H, L, group) of calls outside the cells -> dK/dV's form: a head
+# group's rows multiply what the k-held form holds (3 KiB a row at D=128 in
+# bf16) and not what the q-held form does (3 KiB a position), so grouped
+# calls move to it up to L=8192; with one head a kv head nothing does.
+@pytest.mark.parametrize("B,H,L,group,expected", [
+    (2, 6, 8192, 3, ("resident", "q")),
+    (1, 32, 2048, 8, ("resident", "q")),
+    (1, 32, 4096, 4, ("resident", "q")),
+    (1, 16, 4096, 2, ("resident", "k")),   # 24 MiB: the first form
+    (1, 12, 8192, 6, ("resident", "q")),   # 24 MiB whatever the group
+    (1, 8, 16384, 2, ("gridded", "k")),    # 48 MiB in the second form
+    (1, 16, 8192, 1, ("resident", "k")),   # both forms 24 MiB: the first
 ])
-def test_flash_plan_dkv_form_outside_the_cells(B, H, L, group, rotary,
-                                               expected):
-    plans = profile.flash_plan(B, H, L, 128, group, backward=True,
-                               rotary=rotary)
+def test_flash_plan_dkv_form_outside_the_cells(B, H, L, group, expected):
+    plans = profile.flash_plan(B, H, L, 128, group, backward=True)
     assert "hvd_flash_bwd" not in plans
     dkv = plans["hvd_flash_dkv"]
     assert (dkv.path, dkv.held) == expected
+    if dkv.held == "q":
+        # k, v, dk, dv in bf16, two buffers each; two f32 accumulators, one
+        assert dkv.resident_bytes == 2 * 4 * L * 128 * 2 + 2 * L * 128 * 4
     assert plans["hvd_flash_dq"].path == "resident"
     # the grid's block axis counts blocks of the held side (the two can be
     # as many: `held` says which)

@@ -231,8 +231,7 @@ def _budget(path, H, G, S, D, bq, bk, rule):
     if path == "one kernel":
         return fa.RESIDENT_VMEM_BUDGET
     plan = lambda budget: fa.flash_plan(  # noqa: E731
-        1, H, S, D, H // G, jnp.float32, True, False, bq, bk, budget,
-        mask=rule)
+        1, H, S, D, H // G, jnp.float32, True, bq, bk, budget, mask=rule)
     budget = plan(fa.RESIDENT_VMEM_BUDGET)[
         profile.FLASH_BWD].resident_bytes - 1
     for _ in range(("two resident", "q-held dK/dV",
@@ -267,8 +266,8 @@ def test_ruled_kernels_agree_with_a_dense_masked_softmax(block, H, G, bq, bk,
         monkeypatch.setattr(fa, "_DKV_HELD", ("q",))
         forced = "two resident"
     budget = _budget(forced, H, G, 2 * length, D, bq, bk, rule)
-    plans = fa.flash_plan(1, H, 2 * length, D, H // G, q.dtype, True, False,
-                          bq, bk, budget, mask=rule)
+    plans = fa.flash_plan(1, H, 2 * length, D, H // G, q.dtype, True, bq, bk,
+                          budget, mask=rule)
     resident = lambda held: {  # noqa: E731
         profile.FLASH_DQ: ("resident", "q"),
         profile.FLASH_DKV: ("resident", held)}
@@ -344,7 +343,7 @@ def test_ruled_custom_vjp(interpret):
     want, vjp = jax.vjp(lambda *a: _dense_attention(*a, D ** -0.5, mask),
                         q, k, v)
     out, got = jax.vjp(lambda *a: fa._flash(*a, D ** -0.5, False, interpret,
-                                            None, rule), q, k, v)
+                                            rule), q, k, v)
     _close(out, want, 2e-6)
     for g, r in zip(got(w), vjp(w)):
         _close(g, r, 2e-6)
@@ -361,7 +360,7 @@ def test_ruled_calls_of_one_shape_share_one_lowering():
 
     def three(q, k, v):
         for _ in range(3):
-            q = fa._flash(q, k, v, D ** -0.5, False, True, None, rule)
+            q = fa._flash(q, k, v, D ** -0.5, False, True, rule)
         return jnp.sum(q.astype(jnp.float32))
 
     text = jax.jit(jax.grad(three, argnums=(0, 1, 2))).lower(
@@ -378,9 +377,8 @@ def test_flash_attention_takes_the_rule_and_refuses_what_it_cannot():
     got = fa.flash_attention(to_blhd(q), to_blhd(k), to_blhd(v), mask=rule)
     _close(to_blhd(got), _dense_attention(
         q, k, v, 64 ** -0.5, jnp.asarray(_dense_mask(rule))), 2e-6)
-    with pytest.raises(ValueError, match="rotary_base"):
-        fa.flash_attention(to_blhd(q), to_blhd(k), to_blhd(v), mask=rule,
-                           rotary_base=1e4)
+    with pytest.raises(ValueError, match="one score product"):
+        fa.flash_plan(1, 4, 256, 64, 2, shared_dim=64, mask=rule)
     with pytest.raises(ValueError, match="2 x length"):
         fa.flash_attention(to_blhd(q), to_blhd(k), to_blhd(v),
                            mask=BlockDiffusionMask(64, 4))
@@ -489,15 +487,9 @@ REFUSED = {
     "attention_mask beside sp_axis": dict(
         attention_mask=BlockDiffusionMask(64, 4), attention="ring",
         sp_axis="sp"),
-    "attention_mask beside rope_fused": dict(
-        attention_mask=BlockDiffusionMask(64, 4), attention="flash",
-        rope_fused=True),
     "qk_norm='head' beside tp_axis": dict(qk_norm="head", tp_axis="tp"),
     "qk_norm='head' beside sp_axis": dict(qk_norm="head", attention="ring",
                                           sp_axis="sp"),
-    "qk_norm='head' beside rope_fused": dict(qk_norm="head",
-                                             attention="flash",
-                                             rope_fused=True),
     "attention_mask beside latent attention": dict(
         attention_mask=BlockDiffusionMask(64, 4), kv_lora_rank=16,
         q_lora_rank=16),

@@ -282,7 +282,8 @@ def test_flash_plan_answers_for_two_score_widths():
 
 
 REFUSED_FLASH = {
-    "rotary_base": (dict(rotary_base=10000.0), ValueError, "rotary_base"),
+    "a_mask_by_rule": (dict(mask=fa.BlockDiffusionMask(64, 4)), ValueError,
+                       "cannot be combined with q_shared"),
     "one_without_the_other": (dict(k_shared=None), ValueError, "together"),
     "a_wider_v": (dict(v_wide=True), ValueError, "as wide as k")}
 
