@@ -53,7 +53,7 @@ def build(config, traffic, mesh, seed, abstract=False):
 
     from benchmark import flops, flops_moe
     from benchmark.references import olmoe as reference
-    from horovod_tpu import models
+    from horovod_tpu import models, profile
     from horovod_tpu.ops.losses import chunked_softmax_cross_entropy
     from horovod_tpu.parallel import (make_train_step, router_aux_losses,
                                       routing_stats)
@@ -241,14 +241,20 @@ def build(config, traffic, mesh, seed, abstract=False):
         ]
 
     rows = top_k * length  # rows of a chip's grouped matmuls
+    # The flash kernels a layer's call runs, as the program's own plan
+    # names them for the shapes (the backward is one kernel or two).
+    kernels = [k for b in (False, True) for k in profile.flash_plan(
+        1, heads, length, head_dim, heads // kv_heads, cfg.dtype, b)]
     counts = {
         "model_flops_per_item": flops_moe.olmoe_model_flops_per_token(
             hidden, width, experts, top_k, vocab, layers, heads, head_dim,
             length),
+        # per step and per device, by the kernels `flash_plan` names
+        "flash_kernels": kernels,
         "flash_executed_flops": layers * flops.flash_executed_flops(
-            1, heads, length, head_dim),
+            kernels, 1, heads, length, head_dim),
         "flash_min_bytes": layers * flops.flash_min_bytes(
-            1, heads, kv_heads, length, head_dim),
+            kernels, 1, heads, kv_heads, length, head_dim),
         "moe_gmm_executed_flops": layers * flops_moe.gated_experts_flops(
             rows, hidden, width),
         "moe_gmm_min_bytes": layers * flops_moe.gated_experts_min_bytes(

@@ -60,7 +60,7 @@ def build(config, traffic, mesh, seed, abstract=False):
 
     from benchmark import flops_ouro
     from benchmark.references import ouro as reference
-    from horovod_tpu import models
+    from horovod_tpu import models, profile
     from horovod_tpu.ops.losses import (chunked_softmax_cross_entropy,
                                         exit_distribution,
                                         expected_exit_loss)
@@ -275,14 +275,21 @@ def build(config, traffic, mesh, seed, abstract=False):
                              once["loss"], TOL_LOSS)),
         ]
 
+    # The flash kernels a layer pass's call runs, as the program's own plan
+    # names them for the shapes (the backward is one kernel or two).
+    kernels = [k for b in (False, True) for k in profile.flash_plan(
+        per_chip, heads, length, head_dim, heads // kv_heads, cfg.dtype, b)]
     counts = {
         "model_flops_per_item": flops_ouro.model_flops_per_token(
             hidden, width, vocab, layers, passes, heads, head_dim, length),
-        # per step and per device: every layer PASS's three kernels
+        # per step and per device, by the kernels `flash_plan` names: once
+        # a layer PASS
+        "flash_kernels": kernels,
         "flash_executed_flops": flops_ouro.flash_executed_flops(
-            layers, passes, per_chip, heads, length, head_dim),
+            kernels, layers, passes, per_chip, heads, length, head_dim),
         "flash_min_bytes": flops_ouro.flash_min_bytes(
-            layers, passes, per_chip, heads, kv_heads, length, head_dim),
+            kernels, layers, passes, per_chip, heads, kv_heads, length,
+            head_dim),
         "params": flops_ouro.params(hidden, width, vocab, layers),
         "layer_passes": passes * layers,
     }

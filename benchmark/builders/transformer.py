@@ -28,7 +28,7 @@ def build(config, traffic, mesh, seed, abstract=False):
 
     from benchmark import flops
     from benchmark.references import transformer as reference
-    from horovod_tpu import models
+    from horovod_tpu import models, profile
     from horovod_tpu.ops.losses import chunked_softmax_cross_entropy
     from horovod_tpu.parallel import make_train_step
 
@@ -132,14 +132,19 @@ def build(config, traffic, mesh, seed, abstract=False):
              % (first_loss, ref_loss, err_step, TOL_LOSS)),
         ]
 
+    # The flash kernels a layer's call runs, as the program's own plan
+    # names them for the shapes (the backward is one kernel or two).
+    kernels = [k for b in (False, True) for k in profile.flash_plan(
+        per_chip, heads, length, head_dim, 1, cfg.dtype, b)]
     counts = {
         "model_flops_per_item": flops.transformer_model_flops_per_token(
             hidden, mlp, vocab, layers, heads, head_dim, length),
-        # per step and per device: every attention layer's three kernels
+        # per step and per device, by the kernels `flash_plan` names
+        "flash_kernels": kernels,
         "flash_executed_flops": layers * flops.flash_executed_flops(
-            per_chip, heads, length, head_dim),
+            kernels, per_chip, heads, length, head_dim),
         "flash_min_bytes": layers * flops.flash_min_bytes(
-            per_chip, heads, heads, length, head_dim),
+            kernels, per_chip, heads, heads, length, head_dim),
         "params": flops.transformer_params(hidden, mlp, vocab, layers),
     }
     return {"step": step, "state": state,

@@ -48,33 +48,45 @@ def transformer_model_flops_per_token(hidden, mlp, vocab, layers, heads,
     return dense + attn / length
 
 
-# Matmul-equivalents the three flash kernels execute per block pair:
-# forward QK^T and PV (2); dQ kernel recomputes the scores, forms dP and dQ
-# (3); dK/dV kernel recomputes the scores, forms dP, dV and dK (4).
-FLASH_EXECUTED_MATMULS = {"forward": 2, "dq": 3, "dkdv": 4}
+# Matmul-equivalents a flash kernel executes per block pair, by the name the
+# program gives the kernel (`hvd.profile.flash_plan` names the ones a call
+# runs: the builder asks it and hands the names here, so the count follows
+# the path the program takes and not a form it once had): the forward forms
+# QK^T and PV (2); the one-kernel backward recomputes the scores and forms
+# dP, dV, dK and dQ (5); as two kernels dQ recomputes the scores and forms
+# dP and dQ (3), dK/dV recomputes them and forms dP, dV and dK (4).
+FLASH_EXECUTED_MATMULS = {"hvd_flash_fwd": 2, "hvd_flash_bwd": 5,
+                          "hvd_flash_dq": 3, "hvd_flash_dkv": 4}
 
 
-def flash_executed_flops(batch, heads, length, head_dim, causal=True):
-    """Operations the flash forward, dQ and dK/dV kernels execute for one
-    attention layer in a train step (9 matmul-equivalents)."""
-    return (sum(FLASH_EXECUTED_MATMULS.values())
+def flash_executed_flops(kernels, batch, heads, length, head_dim,
+                         causal=True):
+    """Operations the flash kernels named `kernels` (the keys of
+    `flash_plan`'s forward and backward answers) execute for one attention
+    layer in a train step: 7 matmul-equivalents with the one-kernel
+    backward, 9 with dQ and dK/dV apart."""
+    return (sum(FLASH_EXECUTED_MATMULS[k] for k in kernels)
             * attention_matmul_flops(batch, heads, length, head_dim, causal))
 
 
-def flash_min_bytes(batch, heads, kv_heads, length, head_dim, itemsize=2):
-    """Least bytes the three kernels move to and from device memory for
-    one layer in a train step, each tensor once per kernel that needs it:
-    forward reads q, k, v and writes o and the row logsumexp; dQ reads q,
-    k, v, dO and the two f32 row statistics (logsumexp, delta) and writes
-    dq; dK/dV reads the same and writes dk, dv. A row statistic is counted
-    at 4 bytes a row, whatever padding the kernel keeps it in."""
+def flash_min_bytes(kernels, batch, heads, kv_heads, length, head_dim,
+                    itemsize=2):
+    """Least bytes those kernels move to and from device memory for one
+    layer in a train step, each tensor once per kernel that needs it:
+    forward reads q, k, v and writes o and the row logsumexp; the
+    one-kernel backward reads q, k, v, dO and the two f32 row statistics
+    (logsumexp, delta) and writes dq, dk, dv; as two kernels dQ reads the
+    same and writes dq, dK/dV reads the same and writes dk, dv. A row
+    statistic is counted at 4 bytes a row, whatever padding the kernel
+    keeps it in."""
     q_like = batch * heads * length * head_dim * itemsize
     kv_like = batch * kv_heads * length * head_dim * itemsize
     rows = batch * heads * length * 4
-    forward = 2 * q_like + 2 * kv_like + rows
-    dq = 3 * q_like + 2 * kv_like + 2 * rows
-    dkdv = 2 * q_like + 4 * kv_like + 2 * rows
-    return forward + dq + dkdv
+    cost = {"hvd_flash_fwd": 2 * q_like + 2 * kv_like + rows,
+            "hvd_flash_bwd": 3 * q_like + 4 * kv_like + 2 * rows,
+            "hvd_flash_dq": 3 * q_like + 2 * kv_like + 2 * rows,
+            "hvd_flash_dkv": 2 * q_like + 4 * kv_like + 2 * rows}
+    return sum(cost[k] for k in kernels)
 
 
 # --------------------------------------------------------------------------

@@ -43,15 +43,16 @@ def model_flops_per_token(hidden, width, vocab, layers, passes, heads,
     return dense + attn / length
 
 
-def flash_executed_flops(layers, passes, batch, heads, length, head_dim):
-    """Operations the flash kernels execute in a train step: forward, dQ
-    and dK/dV once a layer pass."""
+def flash_executed_flops(kernels, layers, passes, batch, heads, length,
+                         head_dim):
+    """Operations the flash kernels named `kernels` (`flash_plan`'s, for
+    one layer) execute in a train step: once a layer pass."""
     return passes * layers * flops.flash_executed_flops(
-        batch, heads, length, head_dim)
+        kernels, batch, heads, length, head_dim)
 
 
-def flash_min_bytes(layers, passes, batch, heads, kv_heads, length,
+def flash_min_bytes(kernels, layers, passes, batch, heads, kv_heads, length,
                     head_dim):
     """Least bytes those kernels move, once a layer pass."""
     return passes * layers * flops.flash_min_bytes(
-        batch, heads, kv_heads, length, head_dim)
+        kernels, batch, heads, kv_heads, length, head_dim)

@@ -10,11 +10,13 @@ what `flash_roofline.sdar` reads: what the kernels run, by the tiles
 computed whole).
 """
 
-# Matrix products a flash kernel forms on a tile it visits: the forward s
-# and p.v; dQ s, dp, dq; dK/dV s, dp, dv, dk; the one-kernel backward s, dp,
-# dv, dk, dq.
-FLASH_MATMULS = {"hvd_flash_fwd": 2, "hvd_flash_dq": 3, "hvd_flash_dkv": 4,
-                 "hvd_flash_bwd": 5}
+from benchmark import flops
+
+# Matrix products a flash kernel forms on a tile it visits, and the least
+# bytes a call of it moves (positions = all 2 x length rows): `flops.py`'s
+# tables by kernel name, one for plain and ruled calls alike.
+FLASH_MATMULS = flops.FLASH_EXECUTED_MATMULS
+flash_min_bytes = flops.flash_min_bytes
 
 
 def visible_pairs(length, block):
@@ -72,18 +74,3 @@ def flash_executed_flops(plans, head_dim):
     block_q rows x block_k keys, whole."""
     return sum(FLASH_MATMULS[name] * 2.0 * p.tiles_visited * p.block_q
                * p.block_k * head_dim for name, p in plans.items())
-
-
-def flash_min_bytes(kernels, batch, heads, kv_heads, positions, head_dim,
-                    itemsize=2):
-    """Least bytes those kernels move in one call each, every tensor once
-    per kernel that needs it: q, o, dO and dq at `heads`, k, v, dk and dv at
-    `kv_heads`, a row statistic at 4 bytes a row."""
-    q = batch * heads * positions * head_dim * itemsize
-    kv = batch * kv_heads * positions * head_dim * itemsize
-    stat = batch * heads * positions * 4
-    cost = {"hvd_flash_fwd": 2 * q + 2 * kv + stat,           # q k v; o lse
-            "hvd_flash_dq": 3 * q + 2 * kv + 2 * stat,        # + dO; dq
-            "hvd_flash_dkv": 2 * q + 4 * kv + 2 * stat,       # ; dk dv
-            "hvd_flash_bwd": 3 * q + 4 * kv + 2 * stat}
-    return sum(cost[k] for k in kernels)

@@ -1,5 +1,12 @@
-"""`flash_dkv_ms` for the SDAR cell (by the kernel's own name, under the
-block-diffusion mask; see `flash_dkv_ms.py`). With the two others it adds
-up to `flash_ms.sdar`."""
+"""Device milliseconds per step in the Pallas kernel `hvd_flash_dkv` (flash
+attention backward, the dK/dV kernel of every layer, under the
+block-diffusion mask), mean over devices. With `flash_fwd_ms.sdar` and
+`flash_dq_ms.sdar` it adds up to `flash_ms.sdar`. Nothing to read where the
+backward is one kernel. Source: device trace, by the kernel's own name
+(`scope_reduce.py`)."""
 
-from benchmark.layer_metrics.flash_dkv_ms import read  # noqa: F401
+from benchmark import scope_reduce as sr
+
+
+def read(trace, context):
+    return sr.kernel_ms(trace, context, sr.names.FLASH_DKV)

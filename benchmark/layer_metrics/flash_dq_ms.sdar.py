@@ -1,5 +1,12 @@
-"""`flash_dq_ms` for the SDAR cell (by the kernel's own name, under the
-block-diffusion mask; see `flash_dq_ms.py`). With the two others it adds
-up to `flash_ms.sdar`."""
+"""Device milliseconds per step in the Pallas kernel `hvd_flash_dq` (flash
+attention backward, the dQ kernel of every layer, under the block-diffusion
+mask), mean over devices. With `flash_fwd_ms.sdar` and `flash_dkv_ms.sdar`
+it adds up to `flash_ms.sdar`. Nothing to read where the backward is one
+kernel. Source: device trace, by the kernel's own name
+(`scope_reduce.py`)."""
 
-from benchmark.layer_metrics.flash_dq_ms import read  # noqa: F401
+from benchmark import scope_reduce as sr
+
+
+def read(trace, context):
+    return sr.kernel_ms(trace, context, sr.names.FLASH_DQ)

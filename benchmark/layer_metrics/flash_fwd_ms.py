@@ -1,7 +1,7 @@
 """Device milliseconds per step in the Pallas kernel `hvd_flash_fwd` (flash
-attention forward, every layer), mean over devices. With `flash_dq_ms` and
-`flash_dkv_ms` it adds up to `flash_ms`. Source: device trace, by the
-kernel's own name (`scope_reduce.py`)."""
+attention forward, every layer), mean over devices. With `flash_bwd_ms` it
+adds up to `flash_ms`. Source: device trace, by the kernel's own name
+(`scope_reduce.py`)."""
 
 from benchmark import scope_reduce as sr
 

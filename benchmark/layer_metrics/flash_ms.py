@@ -1,8 +1,9 @@
-"""Device milliseconds per step in the Pallas flash-attention kernels
-(forward, dQ, dK/dV of every layer), mean over devices. Source: device
-trace: the self time of the events whose instruction is a custom call
-with target `tpu_custom_call` — in these configurations the flash kernels
-are the program's only Pallas kernels (24 a step at depth 8)."""
+"""Device milliseconds per step in the Pallas flash-attention kernels (the
+forward and the backward, one kernel or two, of every layer), mean over
+devices. Source: device trace: the self time of the events whose
+instruction is a custom call with target `tpu_custom_call`; in these
+configurations the flash kernels are the program's only Pallas kernels (16
+a step at depth 8 with the one-kernel backward)."""
 
 from benchmark import trace_reduce as tr
 

@@ -51,7 +51,8 @@ def main(names):
         compiled = built["step"].lower(*built["state"]).compile()
         secs = time.perf_counter() - t0
         text = compiled.as_text()
-        needles = program_needles(config, int(cell["chips"]))
+        needles = program_needles(config, int(cell["chips"]),
+                                  built["counts"])
         print(json.dumps({
             "cell": name, "chips": cell["chips"], "compile_s": round(secs, 1),
             "per_device_gib": memory_gib(compiled.memory_analysis()),

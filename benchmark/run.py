@@ -14,6 +14,10 @@ so this file holds no cell, configuration, traffic or per-layer metric name.
 It does hold the four end-to-end metrics (`measured`, in `main`): they are
 what this loop measures, and only a `benchmark` PR, which may edit this
 file, adds one.
+The measured window accounts for itself (`window_account`: its median
+step, the time it lost to anything but its steps' usual pace, its longest
+gap), and the result line of a traced run carries that account; the window
+is taken once, and `throughput` and `step_ms_p95` are over all of it.
 The last line of stdout is the result; the lines before it that start with
 `INFO ` are for people.
 
@@ -105,11 +109,17 @@ def peaks_for(kind):
     return table["devices"][kind]
 
 
-def program_needles(config, chips):
+def program_needles(config, chips, counts=None):
     """Strings the compiled step's text must hold: the configuration's own
-    (its kernels), and an all-reduce wherever the step spans chips."""
-    return list(config.get("program_must_contain", [])) + (
-        ["all-reduce"] if chips > 1 else [])
+    (the mechanisms it has to run), the flash kernels the program's own
+    plan names for the cell's shapes (the builder's
+    `counts["flash_kernels"]`: whichever backward the plan chose has to be
+    there, so the check follows the plan and a backward that left Pallas
+    fails it), and an all-reduce wherever the step spans chips."""
+    needles = list(config.get("program_must_contain", []))
+    needles += [k for k in (counts or {}).get("flash_kernels", [])
+                if k not in needles]
+    return needles + (["all-reduce"] if chips > 1 else [])
 
 
 def percentile(values, q):
@@ -299,13 +309,30 @@ def allocator_peaks(devices):
                + m.get("peak_bytes_reserved", 0))
 
 
-def long_gaps(gaps_ms):
-    """(median gap, gaps over 1.5 times the median, their summed excess
-    over the median in ms): whether a window that reads low lost its time
-    in a few long waits or in every step."""
+def window_account(gaps_ms, wait_s=None, dispatch_s=None):
+    """What a window's step gaps say of it: the median gap; `lost_ms`, the
+    window's length less its steps at the median gap, which is what its
+    `throughput` lost to anything but its own usual pace (a short gap after
+    a late one gives the time back); the gaps over 1.5 medians with their
+    summed excess (a few long waits, or every step slower?); the longest
+    gap, where it fell and, given the loop's `wait_s` and `dispatch_s`, how
+    much of it was the wait for the loss and how much the `step(...)`
+    calls."""
     mid = median(gaps_ms)
     over = [g for g in gaps_ms if g > 1.5 * mid]
-    return mid, len(over), sum(g - mid for g in over)
+    longest = gaps_ms.index(max(gaps_ms))
+    window_ms = sum(gaps_ms)
+    account = {"steps": len(gaps_ms), "window_ms": window_ms,
+               "step_ms_median": mid,
+               "lost_ms": window_ms - len(gaps_ms) * mid,
+               "long_gaps": len(over),
+               "long_gaps_excess_ms": sum(g - mid for g in over),
+               "max_ms": gaps_ms[longest], "max_at": longest}
+    if wait_s is not None:
+        account["max_wait_ms"] = 1e3 * wait_s[longest]
+        account["max_dispatch_ms"] = 1e3 * sum(
+            dispatch_s[0 if longest == 0 else longest + 1:longest + 2])
+    return account
 
 
 def layer_metrics(manifest, cell, trace, context):
@@ -380,7 +407,7 @@ def main():
     mem_gib = memory_gib(compiled.memory_analysis())
     checks = []  # (what, ok, detail)
     text = compiled.as_text()
-    for needle in program_needles(config, chips):
+    for needle in program_needles(config, chips, built["counts"]):
         n = text.count(needle)
         checks.append(("program text holds %r" % needle, n > 0 or not on_chip,
                        "%d times%s" % (n, "" if on_chip else
@@ -401,17 +428,20 @@ def main():
     # per-layer metrics that need no trace), then a profiler window.
     # `--trace 0` and `--trace 2` share every statement down to the
     # allocator's peaks: only after those does `--trace 2` touch the
-    # profiler, so the two kinds of run read alike.
+    # profiler, so the two kinds of run read alike. The window is taken
+    # once: `throughput` and `step_ms_p95` are over all its steps and all
+    # its time, and `window_account` says what it lost to a late host.
     compiles_before = len(compiles)
     seconds = max(2.0, args.seconds / 4.0) if args.trace == 1 \
         else args.seconds
     win = loop.run(seconds=seconds)
     compiles_in_window = len(compiles) - compiles_before
+    marks = [win["t0"]] + win["stamps"]
+    gaps_ms = [1e3 * (b - a) for a, b in zip(marks, marks[1:])]
+    account = window_account(gaps_ms, win["wait_s"], win["dispatch_s"])
     window_s = win["t1"] - win["t0"]
     throughput = (built["items_per_step"] * win["completed"]
                   / window_s / chips)
-    marks = [win["t0"]] + win["stamps"]
-    gaps_ms = [1e3 * (b - a) for a, b in zip(marks, marks[1:])]
     step_ms_p95 = percentile(gaps_ms, 0.95)
     trace, counted = None, None  # `counted`: the losses the checks count
     trace_dir = os.path.join(ROOT, ".bench_trace", cell["name"])
@@ -455,16 +485,15 @@ def main():
     for what, ok, detail in checks:
         info(check=what, ok=bool(ok), detail=detail)
 
-    late = gaps_ms.index(max(gaps_ms))
-    gap_median, gaps_long, gaps_long_excess_ms = long_gaps(gaps_ms)
     info(cell=cell["name"], seed=args.seed, steps_in_window=win["completed"],
-         window_s=window_s, step_ms_median=gap_median,
-         step_ms_long_gaps=gaps_long,
-         step_ms_long_gaps_excess_ms=gaps_long_excess_ms,
-         step_ms_samples=len(gaps_ms), step_ms_max=gaps_ms[late],
-         step_ms_max_at=late, step_ms_max_wait_ms=1e3 * win["wait_s"][late],
-         step_ms_max_dispatch_ms=1e3 * sum(win["dispatch_s"][
-             0 if late == 0 else late + 1:late + 2]),
+         window_s=window_s, step_ms_median=account["step_ms_median"],
+         window_lost_ms=account["lost_ms"],
+         step_ms_long_gaps=account["long_gaps"],
+         step_ms_long_gaps_excess_ms=account["long_gaps_excess_ms"],
+         step_ms_samples=len(gaps_ms), step_ms_max=account["max_ms"],
+         step_ms_max_at=account["max_at"],
+         step_ms_max_wait_ms=account["max_wait_ms"],
+         step_ms_max_dispatch_ms=account["max_dispatch_ms"],
          dispatch_ms_median=1e3 * median(win["dispatch_s"]),
          compiles_in_window=compiles_in_window, compile_cache=cache_dir,
          cache_hits=hits, cache_misses=misses, first_run_compiled=misses > 0,
@@ -500,14 +529,15 @@ def main():
              "is printed", traced_devices=sorted(trace.devices) if trace
              else None)
     elif trace is not None:
-        # `throughput`, `dispatch_s` and `gaps_ms` are the untraced
-        # window's: a quarter of `--seconds` under `--trace 1`, all of it
-        # under `--trace 2`.
+        # `throughput`, `dispatch_s`, `gaps_ms` and `window` are the
+        # untraced window's: a quarter of `--seconds` under
+        # `--trace 1`, all of it under `--trace 2`.
         context = {"cell": cell, "config": config, "traffic": traffic,
                    "chips": chips, "peaks": peaks, "counts": built["counts"],
                    "throughput": throughput,
                    "steps_traced": trace.modules[min(trace.modules)],
                    "dispatch_s": win["dispatch_s"], "gaps_ms": gaps_ms,
+                   "window": account,
                    "memory_stats_peak_bytes": stats_peak_bytes}
         t0 = time.perf_counter()
         per_layer, traced_device, result["breakdown"] = \

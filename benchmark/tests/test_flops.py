@@ -46,20 +46,91 @@ def test_configuration_file_gives_the_same_counts():
         c["num_hidden_layers"]) == 608_733_184
 
 
+ONE = ["hvd_flash_fwd", "hvd_flash_bwd"]
+TWO = ["hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"]
+
+
 def test_flash_kernel_counts_by_hand():
     # one product, B=2 H=16 L=2048 D=128, causal: 2*2*16*2048^2*128/2
     assert flops.attention_matmul_flops(2, 16, 2048, 128) == 17_179_869_184
     assert flops.attention_matmul_flops(2, 16, 2048, 128, causal=False) == \
         34_359_738_368
-    # forward 2 + dQ 3 + dK/dV 4 products
-    assert flops.flash_executed_flops(2, 16, 2048, 128) == 9 * 17_179_869_184
+    # forward 2 + the one-kernel backward 5 products; as two kernels dQ 3 +
+    # dK/dV 4: the scores and dP are formed twice
+    assert flops.flash_executed_flops(ONE, 2, 16, 2048, 128) == \
+        7 * 17_179_869_184
+    assert flops.flash_executed_flops(TWO, 2, 16, 2048, 128) == \
+        9 * 17_179_869_184
+    assert flops.flash_executed_flops(["hvd_flash_fwd"], 2, 16, 2048,
+                                      128) == 2 * 17_179_869_184
     # a q-like tensor: 2*16*2048*128*2 bytes = 16,777,216; a row statistic
     # 2*16*2048*4 = 262,144. forward 4 tensors + 1 row, dQ 5 + 2, dK/dV 6 + 2
-    assert flops.flash_min_bytes(2, 16, 16, 2048, 128) == \
+    assert flops.flash_min_bytes(TWO, 2, 16, 16, 2048, 128) == \
         15 * 16_777_216 + 5 * 262_144
+    # the one-kernel backward reads q, k, v, dO once for all three results:
+    # 7 tensors + 2 rows where the two kernels move 11 + 4
+    assert flops.flash_min_bytes(ONE, 2, 16, 16, 2048, 128) == \
+        11 * 16_777_216 + 3 * 262_144
     # grouped-query: k and v (8 of the 15) shrink with the kv heads
-    assert flops.flash_min_bytes(2, 16, 4, 2048, 128) == \
+    assert flops.flash_min_bytes(TWO, 2, 16, 4, 2048, 128) == \
         7 * 16_777_216 + 8 * 4_194_304 + 5 * 262_144
+
+
+@pytest.mark.parametrize("cell,shape,products", [
+    ("lm1b4_1chip / lm1b4_dp4", (2, 16, 2048, 128, 1), 7),
+    ("olmoe1b7_1chip", (1, 16, 4096, 128, 1), 7),
+    ("ouro2b6_1chip", (1, 16, 4096, 128, 1), 7),
+    ("past the one-kernel backward's budget", (1, 16, 8192, 128, 1), 9),
+    ("a kv head's eight queries at 8192", (1, 32, 8192, 128, 8), 9)])
+def test_the_count_follows_the_kernels_the_plan_names(cell, shape, products):
+    import jax.numpy as jnp
+
+    from horovod_tpu import profile
+
+    batch, heads, length, head_dim, group = shape
+    forward, backward = (list(profile.flash_plan(
+        batch, heads, length, head_dim, group, jnp.bfloat16, b))
+        for b in (False, True))
+    assert forward == [profile.FLASH_FWD]
+    assert backward == ([profile.FLASH_BWD] if products == 7
+                        else [profile.FLASH_DQ, profile.FLASH_DKV])
+    one = flops.attention_matmul_flops(batch, heads, length, head_dim)
+    assert flops.flash_executed_flops(forward + backward, batch, heads,
+                                      length, head_dim) == products * one
+    assert set(forward + backward) <= set(flops.FLASH_EXECUTED_MATMULS)
+
+
+@pytest.mark.parametrize("cell,passes", [
+    ("lm1b4_1chip", 1), ("lm1b4_dp4", 1), ("olmoe1b7_1chip", 1),
+    ("ouro2b6_1chip", 4)])
+def test_a_causal_cells_builder_counts_the_one_kernel_backward(cell, passes):
+    """The builders hand `flops` the kernels the program's plan names for
+    the cell's own shapes: since PR 33 one backward kernel, so 7 products a
+    layer (pass), where the count once stood at 9."""
+    import jax
+
+    from benchmark.run import BENCH_DIR, ROOT, find_cell, load_json, \
+        load_plugin
+    from horovod_tpu import parallel
+
+    found, entry = find_cell(load_json(os.path.join(ROOT, "BENCHMARK.json")),
+                             cell)
+    c = load_json(os.path.join(ROOT, entry["file"]))
+    traffic = load_json(os.path.join(BENCH_DIR, "traffic",
+                                     found["traffic"] + ".json"))
+    c.pop("rehearse", None), traffic.pop("rehearse", None)
+    # one chip's share of the step: the counts are per device
+    traffic["batch"] //= found["chips"]
+    mesh = parallel.data_parallel_mesh(devices=jax.devices("cpu")[:1])
+    counts = load_plugin("builders", c["builder"]).build(
+        c, traffic, mesh, 0, abstract=True)["counts"]
+    assert counts["flash_kernels"] == ["hvd_flash_fwd", "hvd_flash_bwd"]
+    heads = c["num_attention_heads"]
+    one = flops.attention_matmul_flops(
+        traffic["batch"], heads, traffic["seq_len"],
+        c["hidden_size"] // heads)
+    assert counts["flash_executed_flops"] == \
+        7 * passes * c["num_hidden_layers"] * one
 
 
 def test_resnet50_by_hand():

@@ -40,11 +40,16 @@ def test_published_size_by_hand():
     assert got == dense + attn == 8_581_545_984 + 1_006_632_960
 
 
+@pytest.mark.parametrize("kernels,products", [
+    (["hvd_flash_fwd", "hvd_flash_bwd"], 7),
+    (["hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"], 9)])
 @pytest.mark.parametrize("layers,passes", [(5, 4), (48, 4), (3, 1)])
-def test_flash_counts_are_a_layers_times_the_layer_passes(layers, passes):
-    one = flops.flash_executed_flops(1, 16, 4096, 128)
-    assert flops_ouro.flash_executed_flops(layers, passes, 1, 16, 4096,
-                                           128) == layers * passes * one
-    assert flops_ouro.flash_min_bytes(layers, passes, 1, 16, 16, 4096,
-                                      128) == \
-        layers * passes * flops.flash_min_bytes(1, 16, 16, 4096, 128)
+def test_flash_counts_are_a_layers_times_the_layer_passes(layers, passes,
+                                                          kernels, products):
+    one = flops.flash_executed_flops(kernels, 1, 16, 4096, 128)
+    assert one == products * flops.attention_matmul_flops(1, 16, 4096, 128)
+    assert flops_ouro.flash_executed_flops(kernels, layers, passes, 1, 16,
+                                           4096, 128) == layers * passes * one
+    assert flops_ouro.flash_min_bytes(kernels, layers, passes, 1, 16, 16,
+                                      4096, 128) == \
+        layers * passes * flops.flash_min_bytes(kernels, 1, 16, 16, 4096, 128)

@@ -119,12 +119,69 @@ def test_every_cell_resolves_to_files(manifest):
     assert used == set(configs)
     files = [c["file"] for c in manifest["configs"]]
     assert len(files) == len(set(files))
-    for m in manifest["per_layer"]:
-        assert os.path.isfile(os.path.join(
-            BENCH, "layer_metrics", m["name"] + ".py")), m["name"]
+    # every per-layer metric has its reader, and every reader its metric
+    readers = {fn[:-3] for fn in os.listdir(
+        os.path.join(BENCH, "layer_metrics")) if fn.endswith(".py")}
+    assert {m["name"] for m in manifest["per_layer"]} == readers
     for dirpath, _, filenames in os.walk(BENCH):
         if "__pycache__" in dirpath:
             continue
         for fn in filenames:
             rel = os.path.relpath(os.path.join(dirpath, fn), ROOT)
             assert PATH.match(rel), rel
+
+
+def test_the_flash_readers_name_kernels_the_program_runs(manifest):
+    """Since PR 33 every causal cell's backward is one kernel,
+    `hvd_flash_bwd`: the readers by the two older names are gone from the
+    cells that never run them (`sdar30b_1chip` runs the two kernels and
+    keeps its own), and the window's own account is reported in every
+    cell."""
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
+    for gone in ("flash_dq_ms", "flash_dkv_ms", "flash_dq_ms.olmoe",
+                 "flash_dkv_ms.olmoe"):
+        assert gone not in per_layer
+        assert not os.path.exists(os.path.join(
+            BENCH, "layer_metrics", gone + ".py"))
+    assert per_layer["flash_bwd_ms"]["workloads"] == \
+        per_layer["flash_fwd_ms"]["workloads"] == \
+        per_layer["flash_ms"]["workloads"]
+    assert per_layer["flash_bwd_ms.olmoe"]["workloads"] == \
+        per_layer["flash_fwd_ms.olmoe"]["workloads"]
+    assert {"flash_dq_ms.sdar", "flash_dkv_ms.sdar"} <= set(per_layer)
+    for name in ("step_ms_median", "window_lost_ms", "step_ms_max"):
+        m = per_layer[name]
+        assert "workloads" not in m and m["moves"] == "throughput"
+        assert (m["source"], m["layer"]) == ("host_clock", "entry points")
+
+
+def test_needles_name_a_mechanism_and_follow_the_plan(manifest):
+    """A configuration's `program_must_contain` holds the forward's kernel
+    (every plan's forward is its own kernel) and never a backward's: one
+    kernel or two is `flash_plan`'s choice from the shapes. The backward
+    is still held: `run.program_needles` adds the kernels the plan names
+    (the builder's `counts["flash_kernels"]`), so the program's text has
+    to hold whichever backward the plan chose."""
+    import sys
+
+    sys.path.insert(0, ROOT)
+    from benchmark.run import program_needles
+
+    for c in manifest["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            config = json.load(f)
+        needles = config.get("program_must_contain", [])
+        for backward in ("hvd_flash_bwd", "hvd_flash_dq", "hvd_flash_dkv"):
+            assert backward not in needles, (c["name"], backward)
+        one = program_needles(config, 1, {"flash_kernels": [
+            "hvd_flash_fwd", "hvd_flash_bwd"]})
+        two = program_needles(config, 4, {"flash_kernels": [
+            "hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"]})
+        assert one[:len(needles)] == two[:len(needles)] == needles
+        assert "hvd_flash_bwd" in one and "hvd_flash_dq" not in one
+        assert {"hvd_flash_dq", "hvd_flash_dkv"} <= set(two)
+        assert "hvd_flash_bwd" not in two and two[-1] == "all-reduce"
+        assert one.count("hvd_flash_fwd") == two.count("hvd_flash_fwd") == 1
+        # a builder that counts no flash kernel (ResNet) adds none
+        assert program_needles(config, 1, {}) == needles == \
+            program_needles(config, 1)

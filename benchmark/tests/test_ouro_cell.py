@@ -35,13 +35,16 @@ def manifest():
         return json.load(f)
 
 
-def test_the_entries_are_there_and_at_the_end(manifest):
-    assert manifest["configs"][-1]["name"] == CONFIG
-    assert manifest["configs"][-1]["reduced"] == ["num_hidden_layers"]
-    cell = manifest["workloads"][-1]
+def test_the_entries_are_there_and_in_order(manifest):
+    # (at the end when their PR added them; later cells came after)
+    entry = next(c for c in manifest["configs"] if c["name"] == CONFIG)
+    assert entry["reduced"] == ["num_hidden_layers"]
+    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
         == (CELL, CONFIG, "tokens_b1x4096", 1)
-    tail = manifest["per_layer"][-len(METRICS):]
+    names = [m["name"] for m in manifest["per_layer"]]
+    first = names.index(METRICS[0])
+    tail = manifest["per_layer"][first:first + len(METRICS)]
     assert tuple(m["name"] for m in tail) == METRICS
     for m in tail:
         assert m["workloads"] == [CELL] and m["moves"] == "throughput"

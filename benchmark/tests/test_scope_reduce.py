@@ -61,9 +61,15 @@ import pytest
 
 from benchmark import scope_reduce as sr
 from benchmark import trace_reduce as tr
-from benchmark.layer_metrics import (flash_dkv_ms, flash_dq_ms, flash_fwd_ms,
-                                     flash_ms, fwd_bwd_ms, grad_sync_ms,
-                                     loss_ms, optimizer_ms, unscoped_ms)
+from benchmark.layer_metrics import (flash_bwd_ms, flash_fwd_ms, flash_ms,
+                                     fwd_bwd_ms, grad_sync_ms, loss_ms,
+                                     optimizer_ms, unscoped_ms)
+from benchmark.run import load_plugin
+
+# The readers of the two-kernel backward (the synthetic trace's program runs
+# it): `sdar30b_1chip`'s, whose file names are no module names.
+flash_dq_ms = load_plugin("layer_metrics", "flash_dq_ms.sdar")
+flash_dkv_ms = load_plugin("layer_metrics", "flash_dkv_ms.sdar")
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 SCOPED = os.path.join(DATA, "synthetic_scoped.xplane.pb")
@@ -71,7 +77,7 @@ RECORDED = os.path.join(DATA, "recorded_v5e_scoped_slice.xplane.pb")
 UNNAMED = os.path.join(DATA, "synthetic.xplane.pb")
 MS = 1e-3  # of a microsecond
 NEW_READERS = (fwd_bwd_ms, grad_sync_ms, optimizer_ms, loss_ms, unscoped_ms,
-               flash_fwd_ms, flash_dq_ms, flash_dkv_ms)
+               flash_fwd_ms, flash_bwd_ms, flash_dq_ms, flash_dkv_ms)
 
 
 def reading(monkeypatch, path):
@@ -128,6 +134,58 @@ def test_phases_and_kernels_against_the_hand_worked_sums(monkeypatch):
     assert flash_fwd_ms.read(trace, context) == pytest.approx(105 * MS)
     assert flash_dkv_ms.read(trace, context) == pytest.approx(35 * MS)
     assert flash_dq_ms.read(trace, context) == pytest.approx(20 * MS)
+    # this program's backward is two kernels: the one-kernel reader is silent
+    assert flash_bwd_ms.read(trace, context) is None
+
+
+ONE, TWO = ["hvd_flash_fwd", "hvd_flash_bwd"], \
+    ["hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"]
+
+
+@pytest.mark.parametrize("kernels,plan,flash_ms_a_step", [
+    ({"hvd_flash_fwd": 1.25, "hvd_flash_bwd": 2.5, "hvd_moe_gmm": 24.0},
+     ONE, 3.75),
+    ({"hvd_flash_fwd": 1.25, "hvd_flash_dq": 1.5, "hvd_flash_dkv": 2.25},
+     TWO, 5.0),
+    # the trace holds other kernels than the plan the counts follow: the
+    # share would be off by 7/9 or 9/7, so none is given
+    ({"hvd_flash_fwd": 1.25, "hvd_flash_bwd": 2.5}, TWO, None),
+    ({"hvd_flash_fwd": 1.25, "hvd_flash_dq": 1.5, "hvd_flash_dkv": 2.25},
+     ONE, None),
+    ({"hvd_flash_fwd": 1.25}, ONE, None),
+    ({"hvd_moe_gmm": 24.0}, ONE, None)])
+def test_readers_follow_whichever_backward_kernels_ran(
+        monkeypatch, capsys, kernels, plan, flash_ms_a_step):
+    """`flash_bwd_ms` reads the one-kernel backward by its name;
+    `flash_roofline.olmoe` is over the forward and whichever backward
+    kernels ran, never over the grouped matmuls, silent where no flash
+    kernel ran, and silent (with an INFO line that names both sides) where
+    the kernels that ran are not the ones the plan names. The readers over
+    `flash_ms` (`flash_roofline`, `.ouro`) hold the same rule."""
+    monkeypatch.setattr(sr, "reduce",
+                        lambda trace, context: {"kernels": kernels})
+    context = {"counts": {"flash_executed_flops": 197e12 * 2e-3,
+                          "flash_min_bytes": 819e9 * 1e-3,
+                          "flash_kernels": plan},
+               "peaks": {"bf16_flops_per_s": 197e12,
+                         "hbm_bytes_per_s": 819e9}}
+    assert flash_bwd_ms.read(None, context) == kernels.get("hvd_flash_bwd")
+    assert flash_dq_ms.read(None, context) == kernels.get("hvd_flash_dq")
+    roofline = load_plugin("layer_metrics", "flash_roofline.olmoe")
+    got = roofline.read(None, context)
+    whole = load_plugin("layer_metrics", "flash_roofline")
+    monkeypatch.setattr(whole.flash_ms, "read", lambda trace, context: sum(
+        v for k, v in kernels.items() if k.startswith("hvd_flash")) or None)
+    got_whole = whole.read(None, context)
+    said = capsys.readouterr().out
+    if flash_ms_a_step is None:
+        assert got is None and got_whole is None
+        assert ("_not_read" in said) == any(
+            k.startswith("hvd_flash") for k in kernels)
+    else:  # the operations bind: 2 ms at peak over the kernels' time
+        assert got == got_whole == pytest.approx(
+            100.0 * 2.0 / flash_ms_a_step)
+        assert "_binds" in said and "_not_read" not in said
 
 
 def test_one_device_by_hand():

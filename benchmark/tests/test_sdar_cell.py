@@ -89,9 +89,11 @@ def test_the_configuration_is_the_published_one_but_for_its_cut(config):
     for what in ("Block length 4", "U(1e-3, 1)", "No shift", "mask id",
                  "0.001", "AdamW", "memory rule"):
         assert what in said, what
-    for needle in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv",
-                   "hvd_moe_gmm", "hvd_moe_rows", "hvd_bd"):
+    for needle in ("hvd_flash_fwd", "hvd_moe_gmm", "hvd_moe_rows", "hvd_bd"):
         assert needle in config["program_must_contain"]
+    # one backward kernel or two is the plan's choice, not a needle
+    for backward in ("hvd_flash_dq", "hvd_flash_dkv", "hvd_flash_bwd"):
+        assert backward not in config["program_must_contain"]
     assert config["builder"] == "sdar"
     # the timed step runs the model's own top-8: no routing switch, and
     # the seeded state that spreads its choice says why
