@@ -181,6 +181,39 @@ class TransformerConfig:
     # hands back its normed state beside the stack's, for the loss on the
     # token after next through the same head.
     mtp_depth: int = 0
+    # A layer pattern (Nemotron-H's `hybrid_override_pattern`): one kind a
+    # layer, `num_layers` of them, each layer ONE mixer behind one norm,
+    # `x + mixer(rms(x))`: "ssm" a Mamba-2 mixer (`Mamba2`, the `ssm_*`
+    # sizes), "attn" attention alone, "moe" the routed feed-forward alone
+    # (the `moe_*` fields), "mlp" the dense one. None: every layer is the
+    # two-branch block, routed where `moe_every` / `first_k_dense` say.
+    layer_types: Optional[Tuple[str, ...]] = None
+    # False: attention reads no position (no rotary on q and k; a causal
+    # stack behind a recurrence needs none).
+    rotary: bool = True
+    # A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060): `ssm_heads` heads of
+    # `ssm_head_dim` channels (their product the mixer's inner width),
+    # B and C shared by the heads of each of `ssm_groups` groups, a state
+    # of `ssm_state` a channel, a causal depthwise convolution of `ssm_conv`
+    # taps, the recurrence in chunks of `ssm_chunk` tokens (`ops/ssd.py`).
+    # `ssm_dt_init`: (least, largest, floor) of the log-uniform time steps
+    # the step bias starts at.
+    ssm_heads: Optional[int] = None
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    ssm_dt_init: Tuple[float, float, float] = (0.001, 0.1, 1e-4)
+    # The routed experts run in a latent of this width between two shared
+    # projections (LatentMoE; `parallel.expert.MoeMlp`); the router and the
+    # shared expert read the state itself.
+    moe_latent_dim: Optional[int] = None
+    # The routed and the shared experts' activation ("silu" | "relu2"),
+    # and whether the shared expert is gated like DeepSeek's or
+    # `down(act(up x))`.
+    moe_act: str = "silu"
+    moe_shared_gated: bool = True
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
@@ -238,7 +271,12 @@ class TransformerConfig:
             ("block_remat", self.block_remat > 0),
             ("mtp_depth", self.mtp_depth > 0),
             ("qk_norm='head'", self.qk_norm == "head"),
-            ("attention_mask", self.attention_mask is not None)) if on]
+            ("attention_mask", self.attention_mask is not None),
+            ("layer_types", self.layer_types is not None),
+            ("rotary=False", not self.rotary),
+            ("moe_latent_dim", self.moe_latent_dim is not None),
+            ("moe_act", self.moe_act != "silu"),
+            ("moe_shared_gated=False", not self.moe_shared_gated)) if on]
         for field in ("tp_axis", "sp_axis", "num_passes"):
             on = (self.num_passes > 1 if field == "num_passes"
                   else getattr(self, field) is not None)
@@ -279,6 +317,50 @@ class TransformerConfig:
             raise ValueError("mtp_depth=%d: one multi-token prediction "
                              "module is built, not a chain of them"
                              % self.mtp_depth)
+        if not self.rotary and self.kv_lora_rank is not None:
+            raise ValueError("rotary=False cannot be combined with "
+                             "kv_lora_rank (latent attention's rotary slice "
+                             "is its only position)")
+        if self.moe_act not in ("silu", "relu2"):
+            raise ValueError("moe_act=%r: 'silu' or 'relu2'"
+                             % (self.moe_act,))
+        if self.layer_types is not None:
+            self._check_layer_types()
+
+    def _check_layer_types(self):
+        """What a layer pattern cannot be placed beside, by name."""
+        kinds = ("ssm", "attn", "moe", "mlp")
+        types = self.layer_types
+        if len(types) != self.num_layers or any(t not in kinds
+                                                for t in types):
+            raise ValueError("layer_types=%r: num_layers=%d kinds, each of "
+                             "%s" % (types, self.num_layers,
+                                     ", ".join(kinds)))
+        # Built as a plain stack of one-mixer layers on one device a
+        # replica: the streams' and the module's blocks are two-branch
+        # blocks, the exchange of an expert-parallel layer and the sandwich
+        # norms know no layer of another kind.
+        for field, on in (("hc_mult", self.hc_mult > 1),
+                          ("mtp_depth", self.mtp_depth > 0),
+                          ("ep_axis", self.ep_axis is not None),
+                          ("sandwich_norm", self.sandwich_norm),
+                          ("first_k_dense", self.first_k_dense > 0)):
+            if on:
+                raise ValueError("layer_types cannot be combined with %s "
+                                 "(the pattern says what each layer is; it "
+                                 "is built for a plain stack of one-mixer "
+                                 "layers)" % field)
+        if "moe" in types and self.moe_experts is None:
+            raise ValueError("layer_types names a 'moe' layer: give "
+                             "moe_experts (and the moe_* sizes)")
+        if "ssm" in types:
+            if self.ssm_heads is None:
+                raise ValueError("layer_types names an 'ssm' layer: give "
+                                 "ssm_heads (and the ssm_* sizes)")
+            if self.ssm_heads % self.ssm_groups:
+                raise ValueError("ssm_groups=%d must divide ssm_heads=%d "
+                                 "(a group's B and C serve whole heads)"
+                                 % (self.ssm_groups, self.ssm_heads))
 
     def local(self, tp_size):
         """The per-shard config for `tp_size`-way tensor parallelism."""
@@ -577,8 +659,9 @@ class Attention(nn.Module):
                 flat = t.reshape(t.shape[:-2] + (-1,))
                 return _rms_norm(cfg, name)(flat).reshape(t.shape)
             q, k = whole(q, "q_norm"), whole(k, "k_norm")
-        q = _rotary(q, positions, cfg.rope_base)
-        k = _rotary(k, positions, cfg.rope_base)
+        if cfg.rotary:
+            q = _rotary(q, positions, cfg.rope_base)
+            k = _rotary(k, positions, cfg.rope_base)
         if cfg.attention == "ring":
             o = ring_attention(q, k, v, cfg.sp_axis, causal=True,
                                schedule=cfg.sp_schedule)
@@ -615,13 +698,115 @@ class Attention(nn.Module):
         return out
 
 
+class Mamba2(nn.Module):
+    """A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060; Nemotron-H's layer `M`)
+    on the normed state u [B, L, D], H heads of P channels in G groups, a
+    state of N a channel:
+
+        [z | xBC | dt] = in_proj u        widths HP | HP + 2GN | H
+        xBC = silu(conv_causal_depthwise(xBC) + conv_bias)
+        [x | B | C] = xBC                 as [H, P] | [G, N] | [G, N]
+        dt = softplus(dt + dt_bias);  a = -exp(A_log)          per head
+        S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t;  y_t = S_t C_t + D x_t
+        y = rms_G(y * silu(z)) * norm     the mean square over a group's
+                                          HP / G channels, gate first
+        out = out_proj y
+
+    The recurrence is `ops.ssd.ssd_scan` (chunked); everything after the
+    in-projection and before the out-projection is f32. No projection has
+    a bias, the convolution has one. Sows ``ssd_state_max`` under
+    ``intermediates`` (the largest |S| at a chunk border)."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u):
+        from horovod_tpu.ops.ssd import ssd_scan
+        cfg = self.cfg
+        H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state)
+        inner, taps = H * P, cfg.ssm_conv
+        conv_dim = inner + 2 * G * N
+        B, L, _ = u.shape
+        f32 = jnp.float32
+
+        def dense(n, name):
+            return nn.Dense(n, dtype=cfg.dtype, param_dtype=f32,
+                            use_bias=False, name=name)
+
+        def around_zero(key, shape, dtype):  # torch's Conv1d: +-fan_in^-1/2
+            bound = taps ** -0.5
+            return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+        def dt_bias_init(key, shape, dtype):
+            lo, hi, floor = cfg.ssm_dt_init
+            dt = jnp.maximum(jnp.exp(jax.random.uniform(
+                key, shape, dtype, math.log(lo), math.log(hi))), floor)
+            return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
+
+        def a_log_init(key, shape, dtype):
+            return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+        zxbcdt = dense(inner + conv_dim + H, "in_proj")(u)
+        z = zxbcdt[..., :inner]
+        xbc = zxbcdt[..., inner:inner + conv_dim]
+        dt = zxbcdt[..., inner + conv_dim:]
+        with jax.named_scope(profile.SSM_CONV):
+            w = self.param("conv_kernel", around_zero, (taps, conv_dim), f32)
+            bias = self.param("conv_bias", around_zero, (conv_dim,), f32)
+            # Tap j reads the token taps - 1 - j behind: zeros before the
+            # sequence. Padded as it is and widened a tap at a time, so
+            # that no f32 copy of the projection is made.
+            padded = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+            xbc = nn.silu(bias + sum(w[j] * padded[:, j:j + L].astype(f32)
+                                     for j in range(taps))).astype(cfg.dtype)
+        x = xbc[..., :inner].reshape(B, L, H, P)
+        b = xbc[..., inner:inner + G * N].reshape(B, L, G, N)
+        c = xbc[..., inner + G * N:].reshape(B, L, G, N)
+        dt = jax.nn.softplus(dt.astype(f32) + self.param(
+            "dt_bias", dt_bias_init, (H,), f32))
+        a = -jnp.exp(self.param("A_log", a_log_init, (H,), f32))
+        y, state_max = ssd_scan(x, dt, a, b, c, cfg.ssm_chunk)
+        self.sow("intermediates", "ssd_state_max", state_max)
+        skip = self.param("D", nn.initializers.ones, (H,), f32)
+        y = y + skip[:, None] * x.astype(f32)
+        y = (y.reshape(B, L, inner) * nn.silu(z.astype(f32))).reshape(
+            B, L, G, inner // G)
+        y = y * lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+        y = y.reshape(B, L, inner) * self.param(
+            "norm", nn.initializers.ones, (inner,), f32)
+        return dense(cfg.embed_dim, "out_proj")(y.astype(cfg.dtype))
+
+
+def ssd_stats(intermediates):
+    """The largest |state| any Mamba-2 layer's scan carried over a chunk
+    border (f32 scalar), from the ``ssd_state_max`` the mixers sowed under
+    ``intermediates``."""
+    from horovod_tpu.parallel.expert import _sown
+    found = _sown(intermediates, "ssd_state_max")
+    if not found:
+        raise ValueError("no Mamba2 mixer sowed into these intermediates")
+    return jnp.max(jnp.stack(found))
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     moe: bool = False
+    # The layer's one mixer under `layer_types` ("ssm", "attn", "moe",
+    # "mlp"); None: the two-branch block.
+    kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
+        if self.kind is not None:
+            h = _rms_norm(cfg, "norm")(x)
+            if self.kind == "ssm":
+                with jax.named_scope(profile.SSM):
+                    return x + Mamba2(cfg, name="ssm")(h)
+            if self.kind == "attn":
+                return x + Attention(cfg, name="attn")(h, positions)
+            return x + _feed_forward(cfg, self.kind == "moe", h)
         # `out`: the sandwich norm on a branch's output, or nothing.
         out = (lambda name, h: _rms_norm(cfg, name)(h)) \
             if cfg.sandwich_norm else (lambda name, h: h)
@@ -655,6 +840,12 @@ def _feed_forward(cfg, moe, h):
             new["held"] = cfg.moe_held
         if cfg.moe_shared_dim is not None:
             new["shared_dim"] = cfg.moe_shared_dim
+        if cfg.moe_latent_dim is not None:
+            new["latent_dim"] = cfg.moe_latent_dim
+        if cfg.moe_act != "silu":
+            new["act"] = cfg.moe_act
+        if not cfg.moe_shared_gated:
+            new["shared_gated"] = False
         with jax.named_scope("mlp"):
             return MoeMlp(num_experts=cfg.moe_experts,
                           mlp_dim=cfg.moe_dim or cfg.mlp_dim,
@@ -754,7 +945,9 @@ class Transformer(nn.Module):
                    i % cfg.moe_every == cfg.moe_every - 1)
             block = nn.remat(Block, policy=_keep_hc_stat()) \
                 if i < cfg.block_remat else Block
-            blocks.append(block(cfg, moe=moe, name="block_%d" % i))
+            kind = None if cfg.layer_types is None else cfg.layer_types[i]
+            blocks.append(block(cfg, moe=moe, kind=kind,
+                                name="block_%d" % i))
         norm_f = _rms_norm(cfg, "norm_f")
         if cfg.hc_mult > 1 or cfg.mtp_depth:
             return _streams(cfg, positions, return_hidden, x, blocks, norm_f)

@@ -19,6 +19,12 @@ played by Pallas TPU kernels:
   holds a part of the experts: the rows of its k*T-row buffer moved by
   the count of those that are live, each op's transpose the other
   kernel.
+
+Beside them, in jnp (XLA's fusions own it until a trace says otherwise):
+
+* :mod:`.ssd` — the selective state-space recurrence of a Mamba-2 layer
+  in its chunked form: four matrix products a chunk, the chunk states
+  carried by a scan, f32 decays and carry under bf16 operands.
 """
 
 from .flash_attention import BlockDiffusionMask, flash_attention  # noqa: F401

@@ -52,6 +52,7 @@ w_out`` or gated (``w_gate``, ``w_up``, ``w_down``: SwiGLU with SiLU).
 """
 
 import math
+import re
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -142,6 +143,15 @@ def sort_assignments(experts, num_experts):
     return flat, order, inv, group_sizes
 
 
+def relu2(h):
+    """relu(h) squared (Nemotron-H's `relu2`)."""
+    return jnp.square(nn.relu(h))
+
+
+# An expert's activation by the name a configuration gives it.
+ACTIVATIONS = {"silu": nn.silu, "relu2": relu2}
+
+
 def _experts(xs, w_in, w_out, w_gate, act, matmul):
     """The experts' feed-forward on rows `xs`; `matmul(rows, weights)` is
     grouped (dropless) or batched (capacity). `xs` may be a tuple: the
@@ -165,7 +175,7 @@ def moe_capacity(tokens, num_experts, capacity_factor):
 def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
             ep_axis=None, act=nn.silu, top_k=1, w_gate=None,
             renormalize=True, scoring="softmax", bias=None, scale=1.0,
-            held=None):
+            held=None, rows=None):
     """Routed feed-forward over flattened tokens.
 
     x: [T, D], whose dtype is the compute dtype (the experts' weights may
@@ -179,6 +189,11 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
     with a factor each assignment consumes one of its expert's capacity
     slots — size it accordingly (>= top_k for comparable drop rates).
     `scoring`, `bias` and `scale` are `route`'s.
+
+    ``rows`` [T, R]: what the experts take where it is not `x` itself
+    (experts in a latent: the router reads `x`, the experts a shared
+    projection of it); w_in, w_gate are then [E_local, R, F], w_out
+    [E_local, F, R] and y is [T, R].
 
     ``held=(first, count)`` (dropless and local only; without it all E
     are held): this device holds
@@ -195,7 +210,6 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
     (int32 scalar: assignments past their expert's capacity; 0 by
     construction when dropless).
     """
-    T, D = x.shape
     E = router_w.shape[1]
     ep = 1 if ep_axis is None else lax.axis_size(ep_axis)
     if held is not None:
@@ -220,6 +234,9 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
         logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
         weights, experts, probs = route(logits, top_k, renormalize,
                                         scoring, bias, scale)
+    if rows is not None:
+        x = rows
+    T, D = x.shape
     with jax.named_scope(profile.MOE_DISPATCH):
         flat, order, inv, group_sizes = sort_assignments(experts, E)
     with jax.named_scope(profile.MOE_ROUTE):
@@ -236,7 +253,7 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
             # they are (`ops/moe_rows`: kernels that touch the live rows
             # alone where a TPU runs them). With every expert held the
             # run is the whole order and the count k*T, a constant.
-            sizes, n_live = group_sizes, jnp.int32(kT)
+            sizes, n_live, bound = group_sizes, jnp.int32(kT), kT
             if held is not None:
                 # From `start`: turned to the front of the k*T-row buffer,
                 # which so holds the run whatever the router does. The
@@ -248,12 +265,22 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
                 order = order[(at + start) % kT]
                 inv = (inv - start) % kT
                 stats["held"] = n_live
+                # A token picks an expert once, so `count` experts are
+                # sent count * T rows at most: where that is under k * T
+                # (many choices, few held) the experts run on the front of
+                # the buffer alone, a static cut, and what they return is
+                # filled up again behind.
+                bound = min(kT, count * T)
             xs = moe_rows.dispatch(x, order, inv, n_live, top_k,
                                    1 if w_gate is None else 2)
+            if bound < kT:
+                xs = tuple(rows[:bound] for rows in xs)
         with jax.named_scope(profile.MOE_EXPERTS):
             ys = _experts(xs, w_in, w_out, w_gate, act,
                           lambda rows, w: grouped_matmul(rows, w, sizes))
         with jax.named_scope(profile.MOE_COMBINE):
+            if bound < kT:
+                ys = jnp.pad(ys, ((0, kT - bound), (0, 0)))
             y = moe_rows.combine(ys, weights, order, inv, n_live)
         stats["dropped"] = jnp.zeros((), jnp.int32)
         return y, stats
@@ -328,6 +355,9 @@ class MoeMlp(nn.Module):
     route_scale: float = 1.0
     held: Optional[Tuple[int, int]] = None
     shared_dim: Optional[int] = None
+    act: str = "silu"
+    shared_gated: bool = True
+    latent_dim: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
@@ -349,31 +379,47 @@ class MoeMlp(nn.Module):
             return self.param(name, nn.initializers.normal(0.02),
                               (e_local, rows, cols), jnp.float32)
 
+        def dense(n, name):
+            return nn.Dense(n, dtype=self.dtype, param_dtype=jnp.float32,
+                            use_bias=False, name=name)
+
+        R = D if self.latent_dim is None else self.latent_dim
         if self.gated:
-            w_gate = expert("w_gate", D, self.mlp_dim)
-            w_in = expert("w_up", D, self.mlp_dim)
-            w_out = expert("w_down", self.mlp_dim, D)
+            w_gate = expert("w_gate", R, self.mlp_dim)
+            w_in = expert("w_up", R, self.mlp_dim)
+            w_out = expert("w_down", self.mlp_dim, R)
         else:
             w_gate = None
-            w_in = expert("w_in", D, self.mlp_dim)
-            w_out = expert("w_out", self.mlp_dim, D)
+            w_in = expert("w_in", R, self.mlp_dim)
+            w_out = expert("w_out", self.mlp_dim, R)
+        new = {}  # only what a layer sets: the others' calls stay as they were
+        if self.act != "silu":
+            new["act"] = ACTIVATIONS[self.act]
         with jax.named_scope(profile.MOE):
+            if self.latent_dim is not None:
+                with jax.named_scope(profile.MOE_LATENT):
+                    new["rows"] = dense(R, "latent_in")(
+                        x.reshape(-1, D)).astype(self.dtype)
             y, stats = moe_ffn(x.reshape(-1, D).astype(self.dtype), router_w,
                                w_in, w_out,
                                capacity_factor=self.capacity_factor,
                                ep_axis=self.ep_axis, top_k=self.top_k,
                                w_gate=w_gate, renormalize=self.renormalize,
                                scoring=self.scoring, bias=bias,
-                               scale=self.route_scale, held=self.held)
+                               scale=self.route_scale, held=self.held, **new)
+            if self.latent_dim is not None:
+                with jax.named_scope(profile.MOE_LATENT):
+                    y = dense(D, "latent_out")(y)
             if self.shared_dim is not None:
                 with jax.named_scope(profile.MOE_SHARED):
-                    dense = lambda n, name: nn.Dense(  # noqa: E731
-                        n, dtype=self.dtype, param_dtype=jnp.float32,
-                        use_bias=False, name=name)
+                    act = ACTIVATIONS[self.act]
                     xs = x.reshape(-1, D)
-                    y = y + dense(D, "shared_down")(
-                        nn.silu(dense(self.shared_dim, "shared_gate")(xs))
-                        * dense(self.shared_dim, "shared_up")(xs))
+                    if self.shared_gated:
+                        h = act(dense(self.shared_dim, "shared_gate")(xs)) \
+                            * dense(self.shared_dim, "shared_up")(xs)
+                    else:
+                        h = act(dense(self.shared_dim, "shared_up")(xs))
+                    y = y + dense(D, "shared_down")(h)
         for name, key in (("moe_aux_loss", "load_balance_loss"),
                           ("moe_z_loss", "router_z_loss"),
                           ("moe_assignments", "assignments"),
@@ -386,13 +432,19 @@ class MoeMlp(nn.Module):
 
 
 def _sown(intermediates, name):
-    """Every value sown as `name` anywhere in the tree, in the tree's
-    order (one per MoE layer)."""
+    """Every value sown as `name` anywhere in the tree (one per MoE layer),
+    in the order of the stack: the tree's order with the numbers in a
+    module's name read as numbers (`block_3` before `block_10`)."""
+    def depth(path):
+        return [[(0, int(t)) if t.isdigit() else (1, t)
+                 for t in re.split(r"(\d+)", str(getattr(k, "key", k)))]
+                for k in path]
+
     found = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
         if any(getattr(k, "key", None) == name for k in path):
-            found.append(leaf)
-    return found
+            found.append((depth(path), leaf))
+    return [leaf for _, leaf in sorted(found, key=lambda f: f[0])]
 
 
 def router_aux_losses(intermediates):

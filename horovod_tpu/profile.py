@@ -74,8 +74,23 @@ MOE_DISPATCH = "hvd_moe_dispatch"  # sort, group sizes, gather of the rows
 MOE_EXPERTS = "hvd_moe_experts"    # the grouped matmuls and the gate
 MOE_COMBINE = "hvd_moe_combine"    # gather back, weights, sum over choices
 MOE_SHARED = "hvd_moe_shared"      # the always-on expert beside the routed
+# The two shared projections of a layer whose experts run in a latent
+# (`MoeMlp` with ``latent_dim``): state -> latent before the dispatch,
+# latent -> state after the combine.
+MOE_LATENT = "hvd_moe_latent"
 MOE_SCOPES = (MOE, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
-              MOE_SHARED)
+              MOE_SHARED, MOE_LATENT)
+
+# A Mamba-2 mixer (`models/transformer.py::Mamba2`, a layer of kind "ssm"
+# in `layer_types`), inside `BLOCK`: `SSM` around all of it (the in- and
+# out-projection, the gate, the grouped norm), `SSM_CONV` around the causal
+# depthwise convolution and `SSD` around the chunked scan (`ops/ssd.py`:
+# the products inside a chunk, the chunk states, the carry) inside. Not in
+# MODEL_SCOPES: the layer reads as `hvd_block` in the by-scope table.
+SSM = "hvd_ssm"
+SSM_CONV = "hvd_ssm_conv"
+SSD = "hvd_ssd"
+SSM_SCOPES = (SSM, SSM_CONV, SSD)
 
 # The hyper-connection around each of a block's two branches
 # (`models/transformer.py`, `hc_mult` > 1), inside `BLOCK` and beside the

@@ -325,6 +325,89 @@ def test_moe_rows_compile_for_v5e(one_chip, monkeypatch):
     assert plan["path"] == "kernel" and plan["tile_rows"] == 1024
 
 
+# One rank's share of Nemotron-3-Super's layers on one chip (`benchmark`'s
+# cell `nemo3s120b_1chip`): attention's 16 query heads on ONE kv head at 4096
+# positions, a head group the one backward kernel cannot hold (q + dO of 16
+# heads beside k): forward and dQ resident by the q block, dK/dV held by
+# the q block too.
+def test_flash_at_a_head_group_of_16_compiles_for_v5e(one_chip):
+    B, H, G, L, D = 1, 16, 1, 4096, 128
+
+    def bwd(q, k, v, g):
+        _, vjp = jax.vjp(
+            lambda q, k, v: _flash(q, k, v, D ** -0.5, True, False),
+            q, k, v)
+        return vjp(g)
+
+    bf16 = jnp.bfloat16
+    text = _compile(one_chip, bwd, ((B, H, L, D), bf16),
+                    ((B, G, L, D), bf16), ((B, G, L, D), bf16),
+                    ((B, H, L, D), bf16))
+    plans = {name: (p.path, p.held) for backward in (False, True)
+             for name, p in flash_plan(B, H, L, D, H // G, bf16,
+                                       backward).items()}
+    assert plans == {profile.FLASH_FWD: ("resident", "q"),
+                     profile.FLASH_DQ: ("resident", "q"),
+                     profile.FLASH_DKV: ("resident", "q")}
+    assert _kernels(text) == 3, text[:2000]
+    for name in plans:
+        assert _named(text, name), name
+
+
+# Its routed layer: top-22 of 512 over 4096 tokens, 8 experts held, in a
+# 1024-wide latent, relu2 experts of width 2688 without a gate: the rows'
+# kernels on a 90112-row buffer, the grouped matmuls on its first 32768
+# rows (a token picks an expert once).
+def test_latent_routed_layer_compiles_for_v5e(one_chip, monkeypatch):
+    from horovod_tpu.parallel import expert
+
+    T, D, R, F, E, k, held = 4096, 4096, 1024, 2688, 512, 22, (0, 8)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def fwd_bwd(x, rows, router, bias, w_in, w_out, g):
+        out, vjp = jax.vjp(
+            lambda x, rows, router, w_in, w_out: expert.moe_ffn(
+                x, router, w_in, w_out, capacity_factor=None,
+                act=expert.relu2, top_k=k, scoring="sigmoid", bias=bias,
+                scale=5.0, held=held, rows=rows)[0],
+            x, rows, router, w_in, w_out)
+        return out, vjp(g)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    text = _compile(one_chip, fwd_bwd, ((T, D), bf16), ((T, R), bf16),
+                    ((D, E), f32), ((E,), f32), ((held[1], R, F), f32),
+                    ((held[1], F, R), f32), ((T, R), bf16))
+    # 2 + 4 grouped matmuls, and each rows' kernel forward and backward
+    assert _kernels(text) == 10, text[:2000]
+    for name in profile.MOE_GMM_KERNELS + profile.MOE_ROWS_KERNELS:
+        assert _named(text, name), name
+    # the experts' intermediate is cut to count x T rows, never k x T
+    assert "[32768,2688]" in text and "[90112,2688]" not in text
+    assert profile.moe_rows_plan(T, k, R, bf16)["path"] == "kernel"
+
+
+# Its Mamba-2 layer's scan: 64 heads of 64 in 4 groups, a state of 128, 32
+# chunks of 128 (jnp: no kernel; the carry is the one loop).
+def test_chunked_scan_compiles_for_v5e(one_chip):
+    from horovod_tpu.ops.ssd import ssd_scan
+
+    L, H, P, G, N = 4096, 64, 64, 4, 128
+
+    def fwd_bwd(x, dt, a, b, c, g):
+        out, vjp = jax.vjp(
+            lambda x, dt, a, b, c: ssd_scan(x, dt, a, b, c, 128)[0],
+            x, dt, a, b, c)
+        return out, vjp(g)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    text = _compile(one_chip, fwd_bwd, ((1, L, H, P), bf16),
+                    ((1, L, H), f32), ((H,), f32), ((1, L, G, N), bf16),
+                    ((1, L, G, N), bf16), ((1, L, H, P), f32))
+    assert _kernels(text) == 0 and profile.SSD in text
+    # never an [L, L] array a head
+    assert "4096,4096" not in text
+
+
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------
 
 def _lm_step(topo, chips, monkeypatch, **more):
