@@ -435,9 +435,9 @@ def print_flash_plan(B, H, G, L, D, dtype, shared_dim=0, mask=None):
     """Which path each flash kernel of this shape takes (`hvd.profile`);
     `shared_dim`: the width of a second score product on one shared key;
     `mask`: a rule in place of the causal triangle, whose plans count the
-    score tiles each kernel visits, masks and skips. dK/dV's line says
-    which side a grid step holds a block of (it has a resident form of
-    either kind)."""
+    score tiles each kernel visits, masks and skips. A backward kernel's
+    line says which side a grid step holds a block of (the one kernel has a
+    resident form of either kind)."""
     from horovod_tpu import profile
 
     for backward in (False, True):
@@ -445,7 +445,8 @@ def print_flash_plan(B, H, G, L, D, dtype, shared_dim=0, mask=None):
                 B, H, L, D, H // G, dtype, backward, shared_dim=shared_dim,
                 mask=mask).items():
             path = plan.path + (" held by the %s block" % plan.held
-                                if name == profile.FLASH_DKV else "")
+                                if name in (profile.FLASH_DKV,
+                                            profile.FLASH_BWD) else "")
             print("  %s: %s, blocks %d x %d, grid %s = %d steps, VMEM %.1f "
                   "MiB%s%s" % (name, path, plan.block_q, plan.block_k,
                                plan.grid, plan.grid_steps,
@@ -482,12 +483,13 @@ def attention_vs_reference(case, tol, kernels):
           % ((name,) + tuple(errs) + (tol,)))
 
 
-def dkv_forms_agree(B, H, G, L, D, dtype, seed, mask, tol):
-    """Where the plan holds dK/dV by the q block (`held` "q": k, v and the
-    results whole in VMEM, dK and dV summed there): dQ, dK and dV of that
-    form against the GRIDDED dK/dV kernel on the same inputs, forced by a
-    budget one byte short of what the form holds. Both add a k block's
-    tiles in ascending q order in f32 and round once."""
+def backward_forms_agree(B, H, G, L, D, dtype, seed, mask, tol):
+    """Where the plan holds the one-kernel backward by the q block (`held`
+    "q": k, v, dk and dv whole in VMEM, dQ carried by the loop, dK and dV
+    summed there): its dQ, dK and dV against the two kernels with dK/dV
+    GRIDDED on the same inputs, forced by a budget one byte short of what
+    the form holds. Both add a block's tiles in ascending order in f32 and
+    round once."""
     import jax
     import jax.numpy as jnp
 
@@ -495,11 +497,19 @@ def dkv_forms_agree(B, H, G, L, D, dtype, seed, mask, tol):
     from horovod_tpu.ops.flash_attention import (_pallas_backward,
                                                  _pallas_forward_lse)
 
-    plan = profile.flash_plan(B, H, L, D, H // G, dtype, True,
-                              mask=mask)[profile.FLASH_DKV]
-    check((plan.path, plan.held) == ("resident", "q"),
-          "%s at this shape is resident and held by the q block"
-          % profile.FLASH_DKV)
+    def plans(**budget):
+        return profile.flash_plan(B, H, L, D, H // G, dtype, True, mask=mask,
+                                  **budget)
+
+    plan = plans()[profile.FLASH_BWD]
+    check((plan.path, plan.held) == ("resident", "q") and {
+        name: (p.path, p.held) for name, p in plans(
+            vmem_budget=plan.resident_bytes - 1).items()} == {
+                profile.FLASH_DQ: ("resident", "q"),
+                profile.FLASH_DKV: ("gridded", "k")},
+          "%s at this shape is resident and held by the q block, and one "
+          "byte under what it holds %s beside the gridded %s"
+          % (profile.FLASH_BWD, profile.FLASH_DQ, profile.FLASH_DKV))
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q, k, v, w = (jax.random.normal(key, (B, heads, L, D),
                                     jnp.float32).astype(dtype)
@@ -517,9 +527,10 @@ def dkv_forms_agree(B, H, G, L, D, dtype, seed, mask, tol):
     held, gridded = grads(plan.resident_bytes), grads(plan.resident_bytes - 1)
     errs = [rel_err(a, b) for a, b in zip(held, gridded)]
     check(max(errs) <= tol,
-          "%s held by the q block vs gridded on the chip: dq %.2e dk %.2e dv "
-          "%.2e (max rel to max |gridded|, tol %.0e)"
-          % ((profile.FLASH_DKV,) + tuple(errs) + (tol,)))
+          "%s held by the q block vs %s + gridded %s on the chip: dq %.2e dk "
+          "%.2e dv %.2e (max rel to max |gridded|, tol %.0e)"
+          % ((profile.FLASH_BWD, profile.FLASH_DQ, profile.FLASH_DKV)
+             + tuple(errs) + (tol,)))
 
 
 def hc_stat_vs_jnp(n, T, C, K, dtype, seed):
@@ -802,8 +813,8 @@ def phase_kernels(args):
     attention_vs_reference(
         attention_case(*shape, args.seed + len(SIZES["attn"]), mask=rule),
         TOL["attn_bf16"], flash_kernels(*shape, mask=rule))
-    dkv_forms_agree(B, H, G, 2 * L, D, jnp.bfloat16, args.seed, rule,
-                    TOL["attn_bf16"])
+    backward_forms_agree(B, H, G, 2 * L, D, jnp.bfloat16, args.seed, rule,
+                         TOL["attn_bf16"])
 
     hc_stat_vs_jnp(*SIZES["hc"], jnp.bfloat16, args.seed)
     for shape in SIZES["moe_rows"]:

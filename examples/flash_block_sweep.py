@@ -5,20 +5,19 @@ path of the plain kernels.
 path (the other sequence whole in VMEM, walked by a loop inside the
 kernel) when its whole-sequence operands fit the VMEM budget, and down
 the gridded path (one pipeline step a tile) otherwise; where the whole
-backward is resident it is ONE kernel (`hvd_flash_bwd`), else dQ and
-dK/dV apart. This sweeps (block_q, block_k) on each path — `resident`
-(the one-kernel backward where its blocks tile), `split` (a budget one
-byte short of the one kernel's: the two resident backward kernels, dK/dV
-held by the k block), `q-held` (one byte short of what THAT dK/dV holds:
-its second resident form, held by the q block with dK and dV summed in
-VMEM; grouped heads only, with one head a kv head the two hold the same)
-and `gridded` — for the kernels alone: forward, dQ, dK/dV, and the whole
-backward (one kernel, or the sum of the two). A backward kernel whose
-gradients are unused is dropped by XLA, so each is timed by itself, with
-the single-dispatch lax.scan recipe. The first row of a path is the
-plan's own blocks. The block tables (`_resident_blocks`;
-`_default_blocks` and `_grouped_blocks` for the gridded path) were read
-off it.
+backward is resident it is ONE kernel (`hvd_flash_bwd`), held by the k
+block or, where that does not fit, by the q block; else dQ and dK/dV
+apart. This sweeps (block_q, block_k) on each path: `resident` (the one
+kernel held by the k block, whatever it holds), `q-held` (the one kernel
+held by the q block: k, v, dk, dv whole and dK, dV summed in VMEM),
+`split` (the two backward kernels as `flash_plan` chooses them where it
+may not take the one: dK/dV resident where it fits) and `gridded`, for
+the kernels alone: forward, dQ, dK/dV, and the whole backward (one kernel,
+or the sum of the two). A backward kernel whose gradients are unused is
+dropped by XLA, so each is timed by itself, with the single-dispatch
+lax.scan recipe. The first row of a path is the plan's own blocks. The
+block tables (`_resident_blocks`; `_default_blocks` and `_grouped_blocks`
+for the gridded path) were read off it.
 
 Usage: python examples/flash_block_sweep.py [--B 2 --L 2048 --H 16 --D 128]
            [--path all|resident|split|q-held|gridded] [--kernels all|bwd]
@@ -27,8 +26,8 @@ Usage: python examples/flash_block_sweep.py [--B 2 --L 2048 --H 16 --D 128]
 causal triangle (L counts both copies of the sequence, blocks of N tokens);
 the forward and dQ take a rule resident only, so `gridded` then grids dK/dV
 alone. The block-diffusion cell's call (`sdar30b_1chip`):
-    --B 1 --H 32 --G 4 --L 8192 --mask-block 4 --path q-held,gridded \
-    --kernels bwd --bqp 64,128,256 --bk 512,1024
+    --B 1 --H 32 --G 4 --L 8192 --mask-block 4 --path q-held,split \
+    --kernels bwd --bqp 64,128 --bk 512,1024
 GQA/MQA (--G < --H) sweeps the grouped-rows layout: the q-block
 candidates become bqp*group rows. The `_grouped_blocks` policy was
 tuned from this sweep at two points — B2 H6 G2 L8192 D128 (1536/512)
@@ -55,26 +54,19 @@ fa = importlib.import_module("horovod_tpu.ops.flash_attention")
 BWD = profile.FLASH_BWD
 
 
-def budgets(B, H, L, D, group, dtype, rule):
-    """`vmem_budget` that forces a path: everything fits 2**40, and one
-    byte less than a form holds gives the next that `flash_plan` tries:
-    the two resident backward kernels (`split`), dK/dV held by the q block
-    (`q-held`; with one head a kv head it holds what `split`'s does, and
-    there is no such budget), then gridded (under a rule dK/dV alone: one
-    byte short of its last resident form; else nothing fits 0)."""
-    def dkv(budget):
-        return fa.flash_plan(B, H, L, D, group, dtype, True,
-                             vmem_budget=budget, mask=rule)[profile.FLASH_DKV]
-
-    out = {"resident": 2 ** 40}
-    out["split"] = fa.flash_plan(
-        B, H, L, D, group, dtype, True, vmem_budget=2 ** 40,
-        mask=rule)[BWD].resident_bytes - 1
-    last = out["split"]
-    if group > 1:
-        last = out["q-held"] = dkv(last).resident_bytes - 1
-    out["gridded"] = 0 if rule is None else dkv(last).resident_bytes - 1
-    return out
+def forms(B, H, L, D, group, dtype, rule):
+    """{path: (`vmem_budget`, the one kernel's held sides `flash_plan` may
+    try)} that forces a path: everything fits 2**40, so the order alone
+    gives the one kernel by either side; with no side to try, the plan's
+    own budget gives the two kernels (`split`), each resident where it
+    fits; then gridded (under a rule dK/dV alone: what the forward holds,
+    k + v, which dQ holds too and no form of dK/dV fits; else nothing
+    fits 0)."""
+    fwd = fa.flash_plan(B, H, L, D, group, dtype, vmem_budget=2 ** 40,
+                        mask=rule)[profile.FLASH_FWD]
+    return {"resident": (2 ** 40, ("k",)), "q-held": (2 ** 40, ("q",)),
+            "split": (fa.RESIDENT_VMEM_BUDGET, ()),
+            "gridded": (0 if rule is None else fwd.resident_bytes, ())}
 
 
 def timed(fn, args, iters=30):
@@ -150,7 +142,7 @@ def main():
         for name, plan in fa.flash_plan(B, H, L, D, group, q.dtype,
                                         backward, mask=rule).items():
             print("default plan %s: %s" % (name, plan._asdict()))
-    by_path = budgets(B, H, L, D, group, q.dtype, rule)
+    by_path = forms(B, H, L, D, group, q.dtype, rule)
     paths = tuple(by_path) if args.path == "all" else tuple(
         args.path.split(","))
     print("%9s %6s %6s | %9s %9s %9s %9s" % (
@@ -166,7 +158,7 @@ def main():
         return "%9s" % x if isinstance(x, str) else "%9.3f" % x
 
     for path in paths:
-        budget = by_path[path]
+        budget, fa._BWD_HELD = by_path[path]
         # The plan's own blocks first, then the candidates.
         candidates = [(None, None)] + [
             (bqp * group, bk)

@@ -15,13 +15,14 @@ environment variable:
   ds.k to dQ's rows as it goes, so s, p, dp and ds are formed once a
   tile (5 matmuls and one exp where the two kernels make 7 and two);
   its k-block axis carries dQ and runs in order.
-  dK/dV has a second resident form for the calls whose head group makes
-  q and dO too large (3 KiB a row of the grouped layout, a row a position
-  and query head): held by the q block, on dQ's grid (batch*kv_head,
-  q-block) and walk, with k, v and the two results whole in VMEM and dK
-  and dV summed over the q blocks in two f32 [L, D] accumulators in
-  scratch (3 KiB a position whatever the group), rounded and written at
-  the (batch, kv head)'s last q block; its q-block axis runs in order.
+  The one kernel has a second held side for the calls whose head group
+  makes q and dO too large (3 KiB a row of the grouped layout, a row a
+  position and query head): held by the q block, on dQ's grid
+  (batch*kv_head, q-block) and walk, with k, v, dk and dv whole in VMEM,
+  dQ of the block carried by the loop and dK and dV summed over the q
+  blocks in two f32 [L, D] accumulators in scratch (3 KiB a position
+  whatever the group), rounded and written at the (batch, kv head)'s
+  last q block; its q-block axis runs in order.
   The other sequence comes in as ONE block per
   batch*kv_head: its block index does not change across the inner grid
   axis, so Pallas fetches it once and double-buffers the next one behind
@@ -547,11 +548,11 @@ path: "resident" (grid (B*G, blocks); the other sequence whole in VMEM,
 walked by a loop in the kernel) or "gridded" (grid (B*G, blocks, blocks),
 one pipeline step a tile). held: the side a grid step holds ONE block of
 while it walks the other's: "q" (the forward, dQ; the grid's block axis
-counts q blocks) or "k" (the one-kernel backward; k blocks). dK/dV is "k"
-gridded and in its first resident form (q, dO, lse and delta whole in
-VMEM), and "q" in its second, taken where the first does not fit: k, v and
-the results whole in VMEM, dK and dV summed over the q blocks in two f32
-accumulators there. block_q counts ROWS of the grouped layout.
+counts q blocks) or "k" (dK/dV; k blocks). The one-kernel backward has
+two held sides: "k" (q, dO, lse, delta and dQ whole in VMEM with an f32
+accumulator of dQ), and "q" where that does not fit: k, v, dk and dv whole
+in VMEM, dK and dV summed over the q blocks in two f32 accumulators there,
+dQ a block a step. block_q counts ROWS of the grouped layout.
 grid_steps: pipeline steps the call issues. resident_bytes: the
 whole-sequence operands, double-buffered, and a resident kernel's f32
 accumulators, one buffer (0 when gridded). vmem_bytes:
@@ -585,10 +586,10 @@ _SHARED_OPERANDS = {profile.FLASH_FWD: (1, 1), profile.FLASH_DQ: (2, 1),
 # The kernels that hold a k block and walk the q blocks (the others hold a
 # q block and walk the k blocks).
 _K_HELD = (profile.FLASH_DKV, profile.FLASH_BWD)
-# dK/dV's resident forms by the side a grid step holds a block of, in the
-# order `flash_plan` tries them (tests narrow it to ("q",): with one head a
-# kv head the second fits only where the first does).
-_DKV_HELD = ("k", "q")
+# The one-kernel backward's forms by the side a grid step holds a block of,
+# in the order `flash_plan` tries them (tests and the block sweep narrow it
+# to reach the second, or the two kernels, where the first fits).
+_BWD_HELD = ("k", "q")
 
 
 def _resident_blocks(D, L, group, kernel):
@@ -618,7 +619,12 @@ def _resident_blocks(D, L, group, kernel):
     (512, 512) 0.867. Group 3 (2 x 6 heads on 2, L=2048, D=128):
     0.605; (1536, 512) 0.358, (768, 512) 0.371, (1536, 1024) 0.408. The
     same pair read twice differs by up to 0.05, so nothing here beats the
-    dK/dV kernel's table by more than the reading's own spread."""
+    dK/dV kernel's table by more than the reading's own spread. Held by
+    the q block it keeps that table too (PR 49; ms a call): group 8 under
+    the block-diffusion rule (1 x 32 on 4 x 8192 x 128) (1024, 512) 6.34,
+    (2048, 512) 6.47, (512, 512) 6.93, (1024, 1024) 7.18, as two 9.44;
+    group 1 (1 x 16 x 8192 x 128) (512, 1024) 4.64, (512, 512) 5.12, as
+    two 6.86."""
     if group == 1:
         return (512, 1024) if kernel in _K_HELD else (512, 512)
     if D > 64:
@@ -629,22 +635,22 @@ def _resident_blocks(D, L, group, kernel):
 def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
                  vmem_budget, D2=0, rule=None, held=None):
     """``held``: the side a grid step holds a block of, "k" for the kernels
-    of `_K_HELD` and "q" for the others unless given: dK/dV has a resident
-    form of either kind (`flash_plan` tries "k" first)."""
+    of `_K_HELD` and "q" for the others unless given: the one-kernel
+    backward has a form of either kind (`flash_plan` tries `_BWD_HELD`)."""
     backward = kernel != profile.FLASH_FWD
     k_held = (kernel in _K_HELD) if held is None else held == "k"
     held = "k" if k_held else "q"
     n_q, n_k, n_stripes = _OPERANDS[kernel]
     n_q2, n_k2 = _SHARED_OPERANDS[kernel]
-    # The whole backward in one kernel: dQ's f32 accumulator, one buffer
-    # (and the second product's dQ2's beside it). dK/dV held by the q
-    # block: dK's and dV's, which the gridded form keeps a k block of.
+    # The whole backward in one kernel, one buffer each: held by the k
+    # block dQ's f32 accumulator (and the second product's dQ2's beside
+    # it); held by the q block dK's and dV's, which the gridded dK/dV keeps
+    # a k block of (dQ's block is then the loop's carry).
     fused = kernel == profile.FLASH_BWD
-    q_held_dkv = kernel == profile.FLASH_DKV and not k_held
-    if fused:
+    if fused and k_held:
         accumulators = _vmem(rows, D, 4) + _vmem(rows, D2, 4)
     else:
-        accumulators = 2 * _vmem(L, D, 4) if q_held_dkv else 0
+        accumulators = 2 * _vmem(L, D, 4) if fused else 0
 
     def q_side(n):  # one pipeline buffer of n rows of every q-side operand
         return (_vmem(n, D, n_q * isz) + n_stripes * _vmem(n, 8, 4)
@@ -668,10 +674,7 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
     whole = q_side(rows) if k_held else k_side(L)
     resident = 2 * whole + accumulators
     if resident <= vmem_budget:
-        # Held by the q block, dK/dV walks the k blocks as dQ does: on
-        # dQ's blocks.
-        bq, bk = blocks(_resident_blocks(
-            D, L, group, profile.FLASH_DQ if q_held_dkv else kernel))
+        bq, bk = blocks(_resident_blocks(D, L, group, kernel))
         bqp = bq // group
         # The loop's peel is static only where one block tiles the other
         # (every pair the tables give; a caller's own blocks may not).
@@ -721,20 +724,18 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     backward holds dK/dV's and dQ's output block, and one f32 accumulator
     of dQ's shape) and one of its blocks tiles the other; gridded
     otherwise. At D=128 in bf16 with one head a kv head the one-kernel
-    backward holds 8 MiB at L=2048, 16 at 4096 and 32 at 8192, where the
-    budget keeps the two.
+    backward holds 8 MiB at L=2048, 16 at 4096 and 32 at 8192.
 
-    dK/dV has a SECOND resident form, tried where the first does not fit
-    (the order is `_DKV_HELD`'s): held by the q block (`held` "q", its
-    grid's block axis counts q blocks and runs in order), with k, v and the
-    two results whole in VMEM, double-buffered, and dK and dV summed over
-    the q blocks in two f32 accumulators [L, D] there: 3 KiB a position at
-    D <= 128 in bf16 whatever the head group, where the
-    first form's q + dO + lse + delta are 3 KiB a position and query head
-    of the group. So with one head a kv head the second fits exactly where
-    the first does and is never taken; with 8 (D=128, L=8192) the first
-    would hold 192 MiB and the second holds 24. Gridded where neither
-    fits. It has one score product: no ``shared_dim``.
+    The one kernel has a SECOND held side, tried where the first does not
+    fit (the order is `_BWD_HELD`'s): held by the q block (`held` "q", its
+    grid's block axis counts q blocks and runs in order), with k, v, dk
+    and dv whole in VMEM, double-buffered, and dK and dV summed over the q
+    blocks in two f32 accumulators [L, D] there: 3 KiB a position at D <=
+    128 in bf16 whatever the head group (24 MiB at L=8192), where the first
+    holds 4 KiB a position and query head of the group (256 MiB with 8 at
+    L=8192). Where neither fits the backward is two kernels, dK/dV held by
+    the k block where THAT fits and gridded where not. The second form has
+    one score product: no ``shared_dim``.
 
     ``shared_dim`` = D2 > 0: the scores are of two products, q [.., D] on k
     and q2 [.., D2] on ONE key k2 a position for all H heads
@@ -752,11 +753,11 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     The same choice of path and kernels, with blocks that divide the rule's
     length, and every plan says how many score tiles its kernel visits,
     masks and skips. The forward and dQ take a rule in their resident form
-    only and the one-kernel backward as ever; dK/dV in all three of its
-    forms (at D=128 in bf16 with 8 heads a kv head and 8192 positions: the
-    forward and dQ resident on k + v, 8 MiB; dK/dV resident too, held by
-    the q block: k, v, dk, dv and the two accumulators, 24 MiB, q + dO of a
-    kv head being 64). Where the forward or dQ would be gridded the result is
+    only, the one-kernel backward in both of its, dK/dV resident or gridded
+    (at D=128 in bf16 with 8 heads a kv head and 8192 positions: the
+    forward resident on k + v, 8 MiB; the backward one kernel held by the q
+    block: k, v, dk, dv and the two accumulators, 24 MiB, q + dO of a kv
+    head being 64). Where the forward or dQ would be gridded the result is
     ``{}`` and the call is the blockwise jnp form; a second score product
     is refused beside a rule.
 
@@ -779,14 +780,6 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
             (BG * n for n in _rule_tiles(mask, p.held, L,
                                          p.block_q // group, p.block_k)))))
 
-    def dkv_plan():
-        """dK/dV's first resident form that fits, else gridded."""
-        for held in ("k",) if shared_dim else _DKV_HELD:
-            p = plan(profile.FLASH_DKV, held)
-            if p.path == "resident":
-                break
-        return p
-
     def resident_or_none(plans):
         if any(p.path != "resident" for name, p in plans.items()
                if shared_dim or (mask is not None
@@ -796,17 +789,18 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
 
     if not backward:
         return resident_or_none({profile.FLASH_FWD: plan(profile.FLASH_FWD)})
-    fused = plan(profile.FLASH_BWD)
-    if fused is not None:
-        return {profile.FLASH_BWD: fused}
+    for held in ("k",) if shared_dim else _BWD_HELD:
+        fused = plan(profile.FLASH_BWD, held)
+        if fused is not None:
+            return {profile.FLASH_BWD: fused}
     return resident_or_none({profile.FLASH_DQ: plan(profile.FLASH_DQ),
-                             profile.FLASH_DKV: dkv_plan()})
+                             profile.FLASH_DKV: plan(profile.FLASH_DKV)})
 
 
 def _compiler_params(plan, carries=False):
     """``carries``: the resident grid's block axis carries state in
-    scratch (the one-kernel backward's dQ; dK and dV where dK/dV is held by
-    the q block), so its steps run in order."""
+    scratch (the one-kernel backward's dQ, or its dK and dV where it is held
+    by the q block), so its steps run in order."""
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary" if carries
                              else "parallel")
@@ -1088,18 +1082,20 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group,
             lax.fori_loop(0, q_ref.shape[0] // bq, store, 0)
 
 
-def _bwd_dkv_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                           bk, bqp, group, rule=None):
-    """dK/dV held by the q block, with k and v whole in VMEM: what
-    `_bwd_dkv_kernel` computes, on `_bwd_dq_resident_kernel`'s grid and walk.
-    A step takes a q block (q, dO, lse, delta), walks the k blocks it sees
-    and adds each tile's p^T.dO and ds^T.q to the rows of that k block in
-    two f32 [L, D] accumulators, which live in VMEM scratch across the
-    grid's q-block axis: zeroed at the first q block of a (batch, kv head),
-    rounded once and written to the results, whole blocks too, at the last.
-    A k block's sum over the q blocks, the head group's rows among them,
-    runs in ascending q order in f32, as the gridded kernel's does."""
+def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, *, scale,
+                       causal, bk, bqp, group, rule=None):
+    """The whole backward (`hvd_flash_bwd`) held by the q block, with k and
+    v whole in VMEM: on `_bwd_dq_resident_kernel`'s grid and walk, s, p, dp
+    and ds of a tile formed once for dQ, dK and dV. A step takes a q block
+    (q, dO, lse, delta) and walks the k blocks it sees. dQ of the block is
+    the loop's carry, as that kernel's: summed in ascending k order in f32
+    and complete within the step. Each tile's p^T.dO and ds^T.q are added
+    to the rows of its k block in two f32 [L, D] accumulators in VMEM
+    scratch across the grid's q-block axis: zeroed at the first q block of
+    a (batch, kv head), rounded once and written, whole blocks too, at the
+    last. A k block's sum over the q blocks, the head group's rows among
+    them, runs in ascending q order, as the gridded `_bwd_dkv_kernel`'s."""
     qi = pl.program_id(1)
     num_kb = k_ref.shape[0] // bk
 
@@ -1122,7 +1118,7 @@ def _bwd_dkv_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     lse = lse_ref[:, :1]
     delta = delta_ref[:, :1]
 
-    def visit(j, carry, masked):
+    def visit(j, dq, masked):
         at = k_block(j)
         k = k_ref[at, :]
         s = _scores(q, k, scale)
@@ -1139,9 +1135,13 @@ def _bwd_dkv_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[at, :] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return carry
+        return dq + jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    _walk_k(visit, 0, qi, bqp, bk, num_kb, causal, rule)
+    dq = _walk_k(visit, jnp.zeros(q.shape, jnp.float32), qi, bqp, bk,
+                 num_kb, causal, rule)
+    dq_ref[...] = dq.astype(dq_ref.dtype)
 
     @pl.when(qi == pl.num_programs(1) - 1)
     def _finalize():
@@ -1698,7 +1698,8 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     inputs' dtypes. Path and blocks per kernel from `flash_plan`. With
     ``shared`` = (q2 [B,H,L,D2], k2 [B,1,L,D2]) also (dq2, dk2) of those
     shapes, dk2 summed over the heads in f32. ``rule``: a mask by rule in
-    place of ``causal`` (dQ resident only, dK/dV in any of its forms)."""
+    place of ``causal`` (dQ resident only, the one kernel in either of its
+    forms, dK/dV resident or gridded)."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -1776,9 +1777,9 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     plan = plans[profile.FLASH_BWD if fused else profile.FLASH_DKV]
     bq, bk = plan.block_q, plan.block_k
     bqp = bq // group
-    if plan.path == "resident" and plan.held == "q":
-        # dK/dV on dQ's grid: a q block a step; k, v and the results whole.
-        kernel = functools.partial(_bwd_dkv_q_held_kernel, scale=scale,
+    if plan.held == "q":
+        # The one kernel on dQ's grid: a q block a step; k, v, dk, dv whole.
+        kernel = functools.partial(_bwd_q_held_kernel, scale=scale,
                                    causal=causal, bk=bk, bqp=bqp,
                                    group=group, **extra)
         q_im, k_spec = _q_walk_specs(plan, L, D, group, causal)
@@ -1816,7 +1817,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         scratch = [pltpu.VMEM((bk, D), jnp.float32),
                    pltpu.VMEM((bk, D), jnp.float32)]
     # A result whose block does not change across the grid's block axis (the
-    # one kernel's dQ; dK and dV held by the q block) is written back once.
+    # one kernel's dQ, or held by the q block dK and dV) is written back once.
     results = _ruled_call(pl.pallas_call(
         kernel,
         name=profile.FLASH_BWD if fused else profile.FLASH_DKV,
@@ -1836,8 +1837,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
              if shared else []) + (
             [dq_shape] + ([dq2_shape] if shared else []) if fused else []),
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(plan,
-                                         carries=fused or plan.held == "q"),
+        compiler_params=_compiler_params(plan, carries=fused),
         interpret=interpret,
     ), profile.FLASH_BWD if fused else profile.FLASH_DKV, rule, plan, inputs,
         scale, interpret)(*inputs)

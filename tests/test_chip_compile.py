@@ -75,7 +75,7 @@ def _compile(one_chip, fn, *shapes):
 # (B, H, G, L, D): the attention of chip_smoke.py's L=1024 LM and of its
 # long-context h6 / gqa2 shape (the one shape here that `flash_plan` sends
 # down the gridded path, by its length alone and in all three kernels: k
-# and v whole are 32 MiB, and dK/dV held by the q block 96), and of the
+# and v whole are 32 MiB, and the backward held by the q block 96), and of the
 # benchmark's LM configurations on a chip (`neox1b4_w2048`: 2 x 2048;
 # `olmoe1b7_w2048` and `ouro2b6_w2048`: 1 x 4096), whose resident
 # backward, one kernel, asks for more than the default VMEM limit, and of
@@ -130,18 +130,19 @@ def test_flash_backward_compiles_for_v5e(one_chip, B, H, G, L, D):
 # The programs the plain kernels compiled to before the kernels learnt to
 # take a mask by rule (PR 38), as `benchmark/rehearse_text.py` hashes a
 # program: forward + backward of `_flash` with every source location taken
-# out. The one-kernel backward (1 x 16 x 4096 x 128) and the two-kernel one
-# under grouped heads (2 x 6 on 2 x 8192 x 128), whose dK/dV was gridded
-# until PR 45 and is since held by the q block (k, v, the results and two
-# accumulators resident, 24 MiB: that PR moved the second pair of hashes, as
-# it meant to, and not the first). A change that is MEANT to move these
+# out. The one-kernel backward held by the k block (1 x 16 x 4096 x 128) and
+# the backward under grouped heads (2 x 6 on 2 x 8192 x 128): two kernels
+# with dK/dV gridded until PR 45, then held by the q block, and since PR 49
+# ONE kernel held by the q block (k, v, dk, dv and two accumulators
+# resident, 24 MiB: each of those PRs moved the second pair of hashes, as it
+# meant to, and not the first). A change that is MEANT to move these
 # kernels replaces the hashes; one that adds a rule, a product or a path
 # beside them does not.
 _PLAIN_PROGRAMS = {
     (1, 16, 16, 4096, 128, True): "3e42888fafb106fc",
     (1, 16, 16, 4096, 128, False): "0a992eb5acbb888b",
-    (2, 6, 2, 8192, 128, True): "71049d75f486607d",
-    (2, 6, 2, 8192, 128, False): "603a3cb5cf64b26e"}
+    (2, 6, 2, 8192, 128, True): "59a28172375be0c1",
+    (2, 6, 2, 8192, 128, False): "e7e5dc1f3a70e2dd"}
 
 
 @pytest.mark.parametrize("B,H,G,L,D,causal", list(_PLAIN_PROGRAMS))
@@ -167,16 +168,12 @@ def test_causal_and_full_calls_lower_to_the_text_they_had(one_chip, B, H, G,
 
 # The attention of the benchmark's block-diffusion cell (`sdar30b_1chip`):
 # 32 heads on 4, a noisy and a clean copy of 4096 tokens, blocks of 4 (the
-# backward two kernels under their two names, which the cell's configuration
-# requires in the program's text: dK/dV resident, held by the q block); and
-# a shape short enough that the whole backward is one kernel.
-@pytest.mark.parametrize("H,G,length,expected", [
-    (32, 4, 4096, {profile.FLASH_FWD: "resident",
-                   profile.FLASH_DQ: "resident",
-                   profile.FLASH_DKV: "resident"}),
-    (16, 16, 1024, {profile.FLASH_FWD: "resident",
-                    profile.FLASH_BWD: "resident"})])
-def test_ruled_flash_compiles_for_v5e(one_chip, H, G, length, expected):
+# backward ONE kernel held by the q block since PR 49: `hvd_flash_bwd` in the
+# program's text and neither `hvd_flash_dq` nor `hvd_flash_dkv`); and a shape
+# short enough that the one kernel is held by the k block.
+@pytest.mark.parametrize("H,G,length,held", [(32, 4, 4096, "q"),
+                                             (16, 16, 1024, "k")])
+def test_ruled_flash_compiles_for_v5e(one_chip, H, G, length, held):
     from horovod_tpu.ops import BlockDiffusionMask
 
     D, S = 128, 2 * length
@@ -192,10 +189,11 @@ def test_ruled_flash_compiles_for_v5e(one_chip, H, G, length, expected):
     text = _compile(one_chip, fwd_bwd, ((1, H, S, D), bf16),
                     ((1, G, S, D), bf16), ((1, G, S, D), bf16),
                     ((1, H, S, D), bf16))
-    paths = {name: p.path for backward in (False, True)
+    paths = {name: (p.path, p.held) for backward in (False, True)
              for name, p in flash_plan(1, H, S, D, H // G, bf16, backward,
                                        mask=rule).items()}
-    assert paths == expected
+    assert paths == {profile.FLASH_FWD: ("resident", "q"),
+                     profile.FLASH_BWD: ("resident", held)}
     assert _kernels(text) == len(paths), text[:2000]
     for name in (profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV,
                  profile.FLASH_BWD):
@@ -327,9 +325,9 @@ def test_moe_rows_compile_for_v5e(one_chip, monkeypatch):
 
 # One rank's share of Nemotron-3-Super's layers on one chip (`benchmark`'s
 # cell `nemo3s120b_1chip`): attention's 16 query heads on ONE kv head at 4096
-# positions, a head group the one backward kernel cannot hold (q + dO of 16
-# heads beside k): forward and dQ resident by the q block, dK/dV held by
-# the q block too.
+# positions, a head group the one backward kernel cannot hold by the k block
+# (q + dO of 16 heads): the forward and, since PR 49, the ONE backward kernel
+# resident and held by the q block.
 def test_flash_at_a_head_group_of_16_compiles_for_v5e(one_chip):
     B, H, G, L, D = 1, 16, 1, 4096, 128
 
@@ -347,11 +345,12 @@ def test_flash_at_a_head_group_of_16_compiles_for_v5e(one_chip):
              for name, p in flash_plan(B, H, L, D, H // G, bf16,
                                        backward).items()}
     assert plans == {profile.FLASH_FWD: ("resident", "q"),
-                     profile.FLASH_DQ: ("resident", "q"),
-                     profile.FLASH_DKV: ("resident", "q")}
-    assert _kernels(text) == 3, text[:2000]
-    for name in plans:
-        assert _named(text, name), name
+                     profile.FLASH_BWD: ("resident", "q")}
+    # the forward (for the residuals) and the backward
+    assert _kernels(text) == 2, text[:2000]
+    for name in (profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV,
+                 profile.FLASH_BWD):
+        assert _named(text, name) == (name in plans), name
 
 
 # Its routed layer: top-22 of 512 over 4096 tokens, 8 experts held, in a

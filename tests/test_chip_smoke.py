@@ -129,14 +129,15 @@ def test_kernels_phase_expects_what_the_plan_names(capsys):
     lines = capsys.readouterr().out.splitlines()
     assert len(lines) == 2
     assert lines[1].startswith(
-        "  hvd_flash_bwd: resident, blocks 512 x 1024, grid (32, 2) = 64 "
-        "steps, VMEM 10.0 MiB of a limit of")
+        "  hvd_flash_bwd: resident held by the k block, blocks 512 x 1024, "
+        "grid (32, 2) = 64 steps, VMEM 10.0 MiB of a limit of")
 
 
 def test_kernels_phase_knows_the_block_diffusion_shape(capsys):
     """The smoke's mask-ruled attention: the kernels it requires at the
-    benchmark cell's shape are the plan's (all three resident, dK/dV held by
-    the q block), `print_flash_plan` prints the tiles each visits, and its case
+    benchmark cell's shape are the plan's (the forward and the ONE backward
+    kernel, resident and held by the q block), `print_flash_plan` prints the
+    tiles each visits, and its case
     (kernel against the dense masked softmax) agrees on the CPU at a small
     size, where `flash_attention` is the blockwise form."""
     import jax
@@ -149,15 +150,15 @@ def test_kernels_phase_knows_the_block_diffusion_shape(capsys):
     rule = BlockDiffusionMask(L, block)
     shape = (B, H, G, 2 * L, D, jnp.bfloat16)
     assert chip_smoke.flash_kernels(*shape, mask=rule) == [
-        "hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"]
+        "hvd_flash_fwd", "hvd_flash_bwd"]
     chip_smoke.print_flash_plan(*shape, mask=rule)
     lines = capsys.readouterr().out.splitlines()
-    assert len(lines) == 3
+    assert len(lines) == 2
     for line in lines:
         assert line.endswith("tiles visited 1280 (masked 384), skipped 2816")
-    assert lines[2].startswith(
-        "  hvd_flash_dkv: resident held by the q block, blocks 1024 x 512, "
-        "grid (4, 64) = 256 steps, VMEM 27.0 MiB of a limit of 52")
+    assert lines[1].startswith(
+        "  hvd_flash_bwd: resident held by the q block, blocks 1024 x 512, "
+        "grid (4, 64) = 256 steps, VMEM 27.5 MiB of a limit of 52")
     small = BlockDiffusionMask(128, 4)
     name, kernel, reference, qkvw = chip_smoke.attention_case(
         1, 4, 2, 256, 64, jnp.float32, 0, mask=small)
@@ -165,3 +166,31 @@ def test_kernels_phase_knows_the_block_diffusion_shape(capsys):
     with jax.default_matmul_precision("highest"):
         for got, want in zip(kernel(*qkvw), reference(*qkvw)):
             assert chip_smoke.rel_err(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("block", [0, 4])
+def test_backward_forms_agree_on_the_interpreter(monkeypatch, capsys, block):
+    """`backward_forms_agree` at a shape small enough for the interpreter
+    whose plan is the smoke's (8 query heads on one kv head: the one kernel
+    held by the q block, and one byte under it dQ beside the gridded dK/dV),
+    causal and under the block-diffusion rule: the two agree in f32 to
+    1e-6, and a tolerance no kernel meets fails the phase."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    import chip_smoke
+    from horovod_tpu.ops import BlockDiffusionMask
+
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    for name, at in (("_pallas_forward_lse", 5), ("_pallas_backward", 8)):
+        monkeypatch.setattr(fa, name, lambda *a, _f=getattr(fa, name),
+                            _at=at, **kw: _f(*a[:_at], True, *a[_at + 1:],
+                                             **kw))
+    rule = BlockDiffusionMask(512, block) if block else None
+    call = (1, 8, 1, 1024, 128, jnp.float32, 0, rule)
+    chip_smoke.backward_forms_agree(*call, 1e-6)
+    out = capsys.readouterr().out
+    assert out.count("  ok  ") == 2 and "hvd_flash_bwd held by the q" in out
+    with pytest.raises(chip_smoke.PhaseFailed):
+        chip_smoke.backward_forms_agree(*call, -1.0)

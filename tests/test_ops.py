@@ -136,15 +136,26 @@ def test_flash_pallas_backward_interpret(causal, bq, bk):
             rtol=2e-4, atol=2e-4)
 
 
-def _split_budget(B, H, L, D, group, dtype, block_q=None, block_k=None):
-    """A `vmem_budget` one byte short of what the one-kernel backward
-    holds: `flash_plan` then keeps the backward's two kernels, resident
-    (each holds less), where everything fits it would choose the one."""
+def _k_held_bytes(B, H, L, D, group, dtype, block_q=None, block_k=None):
+    """What the one-kernel backward holds in its first form, held by the k
+    block: what `flash_plan` chooses where everything fits."""
     from horovod_tpu.ops.flash_attention import flash_plan
     fused = flash_plan(B, H, L, D, group, dtype, True, block_q, block_k,
                        2 ** 40)
-    assert list(fused) == ["hvd_flash_bwd"], fused
-    return fused["hvd_flash_bwd"].resident_bytes - 1
+    assert {n: p.held for n, p in fused.items()} == {"hvd_flash_bwd": "k"}
+    return fused["hvd_flash_bwd"].resident_bytes
+
+
+def _split_budget(monkeypatch, *call):
+    """A `vmem_budget` one byte short of what the one-kernel backward
+    holds by the k block, with its second form (held by the q block, which
+    holds less at these shapes) out of the order `flash_plan` tries:
+    the backward's two kernels, resident (each holds less)."""
+    import importlib
+    # the module: `ops` hands out the function under the same name
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_BWD_HELD", ("k",))
+    return _k_held_bytes(*call) - 1
 
 
 @pytest.mark.parametrize("causal,H,G,bqp,bk", [
@@ -161,7 +172,7 @@ def _split_budget(B, H, L, D, group, dtype, block_q=None, block_k=None):
     (True, 3, 1, 512, 256),   # group 3, bqp > bk
     (False, 6, 2, 256, 512),  # group 3 of two kv heads, not causal
 ])
-def test_flash_resident_path_interpret(causal, H, G, bqp, bk):
+def test_flash_resident_path_interpret(monkeypatch, causal, H, G, bqp, bk):
     """The resident kernels (k/v, or q/dO/lse/delta, whole in VMEM and
     walked by a loop inside the kernel): out, dQ, dK and dV against dense
     attention, and against the gridded kernels on the same blocks, which
@@ -178,7 +189,7 @@ def test_flash_resident_path_interpret(causal, H, G, bqp, bk):
                     jnp.float32)
     t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     budgets = dict(_PATHS, split=_split_budget(
-        B, H, L, D, group, q.dtype, bqp * group, bk))
+        monkeypatch, B, H, L, D, group, q.dtype, bqp * group, bk))
     names = {"gridded": ["hvd_flash_dq", "hvd_flash_dkv"],
              "split": ["hvd_flash_dq", "hvd_flash_dkv"],
              "resident": ["hvd_flash_bwd"]}
@@ -212,7 +223,7 @@ def test_flash_resident_path_interpret(causal, H, G, bqp, bk):
 
 
 @pytest.mark.parametrize("other", ["gridded", "split"])
-def test_flash_resident_equals_gridded_in_bf16(other):
+def test_flash_resident_equals_gridded_in_bf16(monkeypatch, other):
     """bf16 inputs, as the models feed them: under the default budget the
     shape goes down the resident path, its backward one kernel; under a
     budget it does not fit, down the gridded one; under a budget the
@@ -227,7 +238,7 @@ def test_flash_resident_equals_gridded_in_bf16(other):
                for x in _rand_qkv(B, L, H, D, seed=31))
     w = jnp.asarray(np.random.RandomState(32).randn(B, H, L, D), bf16)
     budget = (2 ** 18 if other == "gridded"
-              else _split_budget(B, H, L, D, 1, bf16))
+              else _split_budget(monkeypatch, B, H, L, D, 1, bf16))
     got = []
     for budget, path, kernels in (
             (None, "resident", 1),
@@ -246,32 +257,20 @@ def test_flash_resident_equals_gridded_in_bf16(other):
         assert np.max(np.abs(r - g)) <= 2 ** -8 * np.max(np.abs(g)), nm
 
 
-def _q_held_budget(monkeypatch, B, H, L, D, group, dtype, block_q=None,
-                   block_k=None):
-    """A `vmem_budget` under which `flash_plan` keeps dQ resident and takes
-    dK/dV's SECOND resident form, held by the q block: one byte short of
-    what the first holds (q, dO, lse and delta of the kv head's whole query
-    group). With one head a kv head the second holds as much as the first,
-    so no budget tells them apart: the order `flash_plan` tries them in is
-    narrowed to the second alone."""
-    import importlib
-    # the module: `ops` hands out the function under the same name
-    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
-    split = _split_budget(B, H, L, D, group, dtype, block_q, block_k)
-    if group == 1:
-        monkeypatch.setattr(fa, "_DKV_HELD", ("q",))
-        budget = split
-    else:
-        budget = fa.flash_plan(B, H, L, D, group, dtype, True, block_q,
-                               block_k, split)[
-                                   "hvd_flash_dkv"].resident_bytes - 1
-    plans = fa.flash_plan(B, H, L, D, group, dtype, True, block_q, block_k,
-                          budget)
+def _q_held_budget(B, H, L, D, group, dtype, block_q=None, block_k=None):
+    """A `vmem_budget` under which `flash_plan` takes the one-kernel
+    backward's SECOND form, held by the q block: one byte short of what the
+    first holds (q, dO, dQ, lse, delta and dQ's f32 accumulator, of the kv
+    head's whole query group), which is more than the second's k, v, dk, dv
+    and two accumulators at every shape here."""
+    from horovod_tpu.ops.flash_attention import flash_plan
+    budget = _k_held_bytes(B, H, L, D, group, dtype, block_q, block_k) - 1
+    plans = flash_plan(B, H, L, D, group, dtype, True, block_q, block_k,
+                       budget)
     assert {n: (p.path, p.held) for n, p in plans.items()} == {
-        "hvd_flash_dq": ("resident", "q"),
-        "hvd_flash_dkv": ("resident", "q")}, plans
-    dkv = plans["hvd_flash_dkv"]
-    assert dkv.grid == (B * H // group, L * group // dkv.block_q)
+        "hvd_flash_bwd": ("resident", "q")}, plans
+    bwd = plans["hvd_flash_bwd"]
+    assert bwd.grid == (B * H // group, L * group // bwd.block_q)
     return budget
 
 
@@ -285,14 +284,13 @@ def _q_held_budget(monkeypatch, B, H, L, D, group, dtype, block_q=None,
     (True, 8, 1, 256, 128),
     (False, 8, 1, 128, 256),
 ])
-def test_flash_dkv_held_by_the_q_block_interpret(monkeypatch, causal, H, G,
-                                                 bqp, bk):
-    """dK/dV's second resident form (k, v and the results whole in VMEM, a
-    q block a grid step, dK and dV summed in two f32 accumulators in
-    scratch): against the gradient of `_blockwise_reference`, and against
-    the gridded kernel on the same inputs and blocks, which adds the same
-    tiles to a k block's sum in the same order. dQ beside it is the
-    resident kernel of old."""
+def test_flash_bwd_held_by_the_q_block_interpret(causal, H, G, bqp, bk):
+    """The one-kernel backward's second form (k, v, dk and dv whole in VMEM,
+    a q block a grid step, dQ carried by the loop, dK and dV summed in two
+    f32 accumulators in scratch): dq, dk and dv against the gradient of
+    `_blockwise_reference`, and against the gridded two kernels on the same
+    inputs and blocks, which add the same tiles to a q block's and a k
+    block's sums in the same order."""
     from horovod_tpu.ops.flash_attention import (
         _blockwise_reference, _pallas_backward, _pallas_forward_lse)
     B, L, D = 1, 1024, 32
@@ -302,7 +300,7 @@ def test_flash_dkv_held_by_the_q_block_interpret(monkeypatch, causal, H, G,
     w = jnp.asarray(np.random.RandomState(42).randn(B, H, L, D),
                     jnp.float32)
     budgets = {"gridded": 0, "q-held": _q_held_budget(
-        monkeypatch, B, H, L, D, group, q.dtype, bqp * group, bk)}
+        B, H, L, D, group, q.dtype, bqp * group, bk)}
     got = {}
     for path, budget in budgets.items():
         out, lse = _pallas_forward_lse(q, k, v, D ** -0.5, causal, True,
@@ -320,11 +318,10 @@ def test_flash_dkv_held_by_the_q_block_interpret(monkeypatch, causal, H, G,
 
 
 @pytest.mark.parametrize("H,G", [(4, 2), (8, 1), (6, 2)])
-def test_flash_dkv_held_by_the_q_block_equals_gridded_in_bf16(monkeypatch, H,
-                                                              G):
-    """bf16 inputs, as the models feed them, on the plan's own blocks: dK
-    and dV of the q-held form and of the gridded `_bwd_dkv_kernel` agree to
-    bf16 rounding (both sum a k block's tiles in f32 and round once)."""
+def test_flash_bwd_held_by_the_q_block_equals_gridded_in_bf16(H, G):
+    """bf16 inputs, as the models feed them, on the plan's own blocks: dQ,
+    dK and dV of the q-held one kernel and of the gridded two agree to bf16
+    rounding (both sum a block's tiles in f32 and round once)."""
     from horovod_tpu.ops.flash_attention import (
         _pallas_backward, _pallas_forward_lse)
     B, L, D = 1, 1024, 64
@@ -333,7 +330,7 @@ def test_flash_dkv_held_by_the_q_block_equals_gridded_in_bf16(monkeypatch, H,
                for x in _rand_gqa(B, L, H, G, D, seed=43))
     w = jnp.asarray(np.random.RandomState(44).randn(B, H, L, D), bf16)
     got = []
-    for budget in (0, _q_held_budget(monkeypatch, B, H, L, D, H // G, bf16)):
+    for budget in (0, _q_held_budget(B, H, L, D, H // G, bf16)):
         out, lse = _pallas_forward_lse(q, k, v, D ** -0.5, True, True,
                                        vmem_budget=budget)
         got.append(_pallas_backward(q, k, v, out, lse, w, D ** -0.5, True,
@@ -379,8 +376,9 @@ def test_flash_plan_benchmark_shapes_are_resident(B, L, expected):
 
 
 # (B, L) a chip of the four LM cells, then lengths past what the
-# one-kernel backward holds in 24 MiB (D=128, bf16: 8 MiB at 2048, 16 at
-# 4096, 32 at 8192), then a budget nothing fits. Expected: {kernel: path}.
+# one-kernel backward holds in 24 MiB by the k block (D=128, bf16: 8 MiB at
+# 2048, 16 at 4096, 32 at 8192, where it holds 24 by the q block) and by
+# either, then a budget nothing fits. Expected: {kernel: path}.
 @pytest.mark.parametrize("B,L,budget,expected", [
     pytest.param(2, 2048, None, {"hvd_flash_bwd": "resident"},
                  id="lm1b4_1chip"),
@@ -390,8 +388,7 @@ def test_flash_plan_benchmark_shapes_are_resident(B, L, expected):
                  id="olmoe1b7_1chip"),
     pytest.param(1, 4096, None, {"hvd_flash_bwd": "resident"},
                  id="ouro2b6_1chip"),
-    pytest.param(1, 8192, None, {"hvd_flash_dq": "resident",
-                                 "hvd_flash_dkv": "resident"}, id="L8192"),
+    pytest.param(1, 8192, None, {"hvd_flash_bwd": "resident"}, id="L8192"),
     pytest.param(1, 16384, None, {"hvd_flash_dq": "resident",
                                   "hvd_flash_dkv": "gridded"}, id="L16384"),
     pytest.param(2, 2048, 0, {"hvd_flash_dq": "gridded",
@@ -399,17 +396,24 @@ def test_flash_plan_benchmark_shapes_are_resident(B, L, expected):
 ])
 def test_flash_plan_backward_kernels(B, L, budget, expected):
     """The backward is ONE kernel exactly where its whole-sequence
-    operands with dQ's accumulator fit the budget — every benchmark cell —
-    and the two kernels of old, each resident or gridded as before,
-    where they do not."""
+    operands with its accumulators fit the budget, held by the k block
+    (every causal benchmark cell) or by the q block, and the two kernels of
+    old, each resident or gridded as before, where they do not."""
     from horovod_tpu.ops.flash_attention import (RESIDENT_VMEM_BUDGET,
                                                  flash_plan)
     kw = {} if budget is None else {"vmem_budget": budget}
     plans = flash_plan(B, 16, L, 128, 1, jnp.bfloat16, backward=True, **kw)
     assert {n: p.path for n, p in plans.items()} == expected
-    whole = 2 * (3 * L * 128 * 2 + 2 * L * 128 * 4) + L * 128 * 4
-    assert (whole <= (RESIDENT_VMEM_BUDGET if budget is None else budget)
-            ) == ("hvd_flash_bwd" in plans)
+    # by the k block: q, dO, dQ and the two stripes, two buffers, and dQ's
+    # accumulator; by the q block: k, v, dk, dv and two accumulators
+    forms = {"k": 2 * (3 * L * 128 * 2 + 2 * L * 128 * 4) + L * 128 * 4,
+             "q": 2 * 4 * L * 128 * 2 + 2 * L * 128 * 4}
+    budget = RESIDENT_VMEM_BUDGET if budget is None else budget
+    fits = [held for held, whole in forms.items() if whole <= budget]
+    assert [p.held for n, p in plans.items() if n == "hvd_flash_bwd"] \
+        == fits[:1]
+    if fits:
+        assert plans["hvd_flash_bwd"].resident_bytes == forms[fits[0]]
 
 
 def test_flash_plan_past_the_budget_is_gridded():

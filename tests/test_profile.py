@@ -524,11 +524,13 @@ def test_flash_kernels_carry_their_names(budget, expected):
     assert _kernel_names(text) == {profile.FLASH_FWD, profile.FLASH_BWD}
 
 
-# A benchmark cell's shape, whose backward is one kernel, and L=8192,
-# where the budget keeps the two.
+# A benchmark cell's shape, whose backward is one kernel held by the k
+# block, L=8192, where it is held by the q block, and L=16384, where the
+# budget keeps the two (dK/dV gridded).
 @pytest.mark.parametrize("B,L,bwd_names", [
     (2, 2048, {profile.FLASH_BWD}),
-    (1, 8192, {profile.FLASH_DQ, profile.FLASH_DKV}),
+    (1, 8192, {profile.FLASH_BWD}),
+    (1, 16384, {profile.FLASH_DQ, profile.FLASH_DKV}),
 ])
 @pytest.mark.parametrize("backward", [False, True])
 def test_flash_plan_under_hvd_profile_is_the_kernels_own(backward, B, L,
@@ -542,18 +544,19 @@ def test_flash_plan_under_hvd_profile_is_the_kernels_own(backward, B, L,
     got = profile.flash_plan(B, 16, L, 128, backward=backward)
     assert got == fa.flash_plan(B, 16, L, 128, backward=backward)
     assert set(got) == (bwd_names if backward else {profile.FLASH_FWD})
-    assert all(p.path == "resident" for p in got.values())
+    assert all(p.path == ("gridded" if name == profile.FLASH_DKV
+                          else "resident") for name, p in got.items())
 
 
-# A benchmark cell's flash call -> its plans as PR 44's tree gave them,
-# field for field but `held` (new in PR 45, with dK/dV's second resident
-# form): (path, block_q, block_k, grid, grid_steps, resident_bytes,
-# vmem_bytes, vmem_limit_bytes, tiles visited, masked, skipped). The six
-# cells whose backward is one kernel never reach the new branch; SDAR's
-# forward and dQ stay as they were. Beside them a grouped call at L=8192,
-# whose dK/dV is held by the q block (k, v, the results and the two
-# accumulators: 24 MiB), and a plain one at 16384, which fits neither
-# resident form of dK/dV.
+# A benchmark cell's flash call -> its plans, field for field but `held`:
+# (path, block_q, block_k, grid, grid_steps, resident_bytes, vmem_bytes,
+# vmem_limit_bytes, tiles visited, masked, skipped). The five cells whose
+# backward is one kernel held by the k block stand as PR 44's tree gave
+# them; SDAR's forward too. Since PR 49 SDAR's backward and Nemotron's one
+# attention call (16 query heads on one kv head) are the one kernel held by
+# the q block (k, v, dk, dv and the two accumulators: 24 and 12 MiB), as is
+# a grouped call at L=8192; a plain one at 16384 fits no resident form of
+# the backward but dQ's.
 _MiB = 2 ** 20
 _SDAR_TILES = (1280, 384, 2816)
 CELL_PLANS = {
@@ -575,13 +578,16 @@ CELL_PLANS = {
     "sdar30b_1chip": (dict(B=1, H=32, L=8192, group=8, mask=(4096, 4)), {
         "hvd_flash_fwd": ("resident", 1024, 512, (4, 64), 256, 8 * _MiB,
                           10 * _MiB, 30 * _MiB) + _SDAR_TILES,
-        "hvd_flash_dq": ("resident", 1024, 512, (4, 64), 256, 8 * _MiB,
-                         12058624, 32 * _MiB) + _SDAR_TILES}),
+        "hvd_flash_bwd": ("resident", 1024, 512, (4, 64), 256, 24 * _MiB,
+                          28835840, 52 * _MiB) + _SDAR_TILES}),
+    "nemo3s120b_1chip": (dict(B=1, H=16, L=4096, group=16), {
+        "hvd_flash_fwd": ("resident", 1024, 512, (1, 64), 64, 4 * _MiB,
+                          6 * _MiB, 25 * _MiB, None, None, None),
+        "hvd_flash_bwd": ("resident", 1024, 512, (1, 64), 64, 12 * _MiB,
+                          16252928, 37 * _MiB, None, None, None)}),
     "grouped_L8192": (dict(B=2, H=6, L=8192, group=3), {
-        "hvd_flash_dq": ("resident", 1536, 512, (4, 16), 64, 8 * _MiB,
-                         13893632, 43 * _MiB, None, None, None),
-        "hvd_flash_dkv": ("resident", 1536, 512, (4, 16), 64, 24 * _MiB,
-                          29884416, 62 * _MiB, None, None, None)}),
+        "hvd_flash_bwd": ("resident", 1536, 512, (4, 16), 64, 24 * _MiB,
+                          30670848, 63 * _MiB, None, None, None)}),
     "plain_L16384": (dict(B=1, H=16, L=16384), {
         "hvd_flash_dq": ("resident", 512, 512, (16, 32), 512, 16 * _MiB,
                          18612224, 31 * _MiB, None, None, None),
@@ -590,6 +596,8 @@ CELL_PLANS = {
 }
 CELL_PLANS["lm1b4_dp4"] = CELL_PLANS["lm1b4_1chip"]
 CELL_PLANS["ouro2b6_1chip"] = CELL_PLANS["olmoe1b7_1chip"]
+# The calls whose one-kernel backward is held by the q block.
+_Q_HELD_BWD = ("sdar30b_1chip", "nemo3s120b_1chip", "grouped_L8192")
 
 
 def _cell_plans(B, H, L, mask=None, **kw):
@@ -606,77 +614,86 @@ def test_flash_plans_of_the_cells_are_the_parents(cell):
     call, expected = CELL_PLANS[cell]
     plans = _cell_plans(**call)
     assert set(expected) <= set(plans)
+    assert ("hvd_flash_bwd" in plans) != ("hvd_flash_dq" in plans)
     for name, want in expected.items():
         got = plans[name]._asdict()
-        # A k block is held by the one-kernel backward and the gridded
-        # dK/dV, a q block by the forward, dQ and the one resident dK/dV
-        # of this table (the grouped call's; the next two tests say when).
+        # A k block is held by dK/dV and by the one-kernel backward in its
+        # first form, a q block by the forward, dQ and the one kernel in its
+        # second (the next two tests say when).
         assert got.pop("held") == (
-            "k" if name == "hvd_flash_bwd" or (
-                name == "hvd_flash_dkv" and want[0] == "gridded") else "q")
+            "k" if name == "hvd_flash_dkv" or (
+                name == "hvd_flash_bwd" and cell not in _Q_HELD_BWD) else "q")
         assert tuple(got.values()) == want, name
 
 
-def test_flash_plan_holds_sdars_dkv_by_the_q_block():
-    """The block-diffusion cell's call: q, dO, lse and delta of a kv head's
-    8 query heads are 192 MiB double-buffered, so dK/dV is resident in its
-    SECOND form: a q block a grid step on dQ's grid and blocks, k, v, dk
-    and dv whole and two f32 accumulators in VMEM, 24 MiB, the tiles
-    counted by the key runs of each q block (`hvd_flash_dq` resident as
-    before). One byte less and it is the gridded kernel of old."""
+def test_flash_plan_holds_sdars_backward_by_the_q_block():
+    """The block-diffusion cell's call: q, dO, dQ, lse and delta of a kv
+    head's 8 query heads are 256 MiB with dQ's accumulator, so the backward
+    is ONE kernel in its SECOND form: a q block a grid step on the forward's
+    grid and blocks, k, v, dk and dv whole and two f32 accumulators in
+    VMEM, 24 MiB, the tiles counted by the key runs of each q block. One
+    byte less and it is dQ of old beside the gridded dK/dV."""
     from horovod_tpu.ops import BlockDiffusionMask, flash_attention as fa
 
     fa = sys.modules[fa.__module__]  # the module, not the function
     call = CELL_PLANS["sdar30b_1chip"][0]
     plans = _cell_plans(**call)
-    assert sorted(plans) == ["hvd_flash_dkv", "hvd_flash_dq",
-                             "hvd_flash_fwd"]
-    dkv, dq = plans["hvd_flash_dkv"], plans["hvd_flash_dq"]
-    assert (dkv.path, dkv.held, dkv.block_q, dkv.block_k, dkv.grid,
-            dkv.grid_steps) == ("resident", "q", 1024, 512, (4, 64), 256)
-    assert (dkv.block_q, dkv.block_k, dkv.grid) == (
-        dq.block_q, dq.block_k, dq.grid)
-    assert (dkv.tiles_visited, dkv.tiles_masked,
-            dkv.tiles_skipped) == _SDAR_TILES
+    assert sorted(plans) == ["hvd_flash_bwd", "hvd_flash_fwd"]
+    bwd, fwd = plans["hvd_flash_bwd"], plans["hvd_flash_fwd"]
+    assert (bwd.path, bwd.held, bwd.block_q, bwd.block_k, bwd.grid,
+            bwd.grid_steps) == ("resident", "q", 1024, 512, (4, 64), 256)
+    assert (bwd.block_q, bwd.block_k, bwd.grid) == (
+        fwd.block_q, fwd.block_k, fwd.grid)
+    assert (bwd.tiles_visited, bwd.tiles_masked,
+            bwd.tiles_skipped) == _SDAR_TILES
     L, D = 8192, 128
     # k, v, dk, dv in bf16, two buffers each; two f32 accumulators, one
-    assert dkv.resident_bytes == 2 * 4 * L * D * 2 + 2 * L * D * 4 \
+    assert bwd.resident_bytes == 2 * 4 * L * D * 2 + 2 * L * D * 4 \
         == fa.RESIDENT_VMEM_BUDGET
-    assert dkv.resident_bytes < dkv.vmem_bytes < dkv.vmem_limit_bytes
-    gridded = fa.flash_plan(1, 32, L, D, 8, backward=True,
-                            vmem_budget=dkv.resident_bytes - 1,
-                            mask=BlockDiffusionMask(4096, 4))["hvd_flash_dkv"]
+    # beside them a block of q, dO and dQ and of lse and delta, two buffers
+    assert bwd.vmem_bytes == bwd.resident_bytes + 2 * (
+        3 * 1024 * D * 2 + 2 * 1024 * 128 * 4) < bwd.vmem_limit_bytes
+    two = fa.flash_plan(1, 32, L, D, 8, backward=True,
+                        vmem_budget=bwd.resident_bytes - 1,
+                        mask=BlockDiffusionMask(4096, 4))
+    assert sorted(two) == ["hvd_flash_dkv", "hvd_flash_dq"]
+    gridded = two["hvd_flash_dkv"]
     assert (gridded.path, gridded.held, gridded.grid, gridded.grid_steps,
             gridded.tiles_visited) == ("gridded", "k", (4, 16, 64), 4096,
                                        1280)
 
 
-# (B, H, L, group) of calls outside the cells -> dK/dV's form: a head
-# group's rows multiply what the k-held form holds (3 KiB a row at D=128 in
-# bf16) and not what the q-held form does (3 KiB a position), so grouped
-# calls move to it up to L=8192; with one head a kv head nothing does.
+# (B, H, L, group) of calls outside the cells -> the backward's form where
+# the one kernel held by the k block does not fit: a head group's rows
+# multiply what a k-held form holds (3 KiB a row at D=128 in bf16 for dK/dV,
+# more for the one kernel) and not what the one kernel held by the q block
+# does (3 KiB a position), so it takes every call up to L=8192, and past it
+# the backward is two kernels, dK/dV gridded.
 @pytest.mark.parametrize("B,H,L,group,expected", [
-    (2, 6, 8192, 3, ("resident", "q")),
-    (1, 32, 2048, 8, ("resident", "q")),
-    (1, 32, 4096, 4, ("resident", "q")),
-    (1, 16, 4096, 2, ("resident", "k")),   # 24 MiB: the first form
-    (1, 12, 8192, 6, ("resident", "q")),   # 24 MiB whatever the group
-    (1, 8, 16384, 2, ("gridded", "k")),    # 48 MiB in the second form
-    (1, 16, 8192, 1, ("resident", "k")),   # both forms 24 MiB: the first
+    (2, 6, 8192, 3, ("hvd_flash_bwd", "resident", "q")),
+    (1, 32, 2048, 8, ("hvd_flash_bwd", "resident", "q")),
+    (1, 32, 4096, 4, ("hvd_flash_bwd", "resident", "q")),
+    (1, 16, 4096, 2, ("hvd_flash_bwd", "resident", "q")),  # 12 MiB
+    (1, 12, 8192, 6, ("hvd_flash_bwd", "resident", "q")),  # 24 MiB
+    (1, 8, 16384, 2, ("hvd_flash_dkv", "gridded", "k")),   # 48 MiB q-held
+    (1, 16, 8192, 1, ("hvd_flash_bwd", "resident", "q")),  # G1_L8192
 ])
-def test_flash_plan_dkv_form_outside_the_cells(B, H, L, group, expected):
+def test_flash_plan_backward_form_outside_the_cells(B, H, L, group, expected):
     plans = profile.flash_plan(B, H, L, 128, group, backward=True)
-    assert "hvd_flash_bwd" not in plans
-    dkv = plans["hvd_flash_dkv"]
-    assert (dkv.path, dkv.held) == expected
-    if dkv.held == "q":
+    name, path, held = expected
+    assert sorted(plans) == ([name] if name == "hvd_flash_bwd"
+                             else ["hvd_flash_dkv", "hvd_flash_dq"])
+    p = plans[name]
+    assert (p.path, p.held) == (path, held)
+    if name == "hvd_flash_bwd":
         # k, v, dk, dv in bf16, two buffers each; two f32 accumulators, one
-        assert dkv.resident_bytes == 2 * 4 * L * 128 * 2 + 2 * L * 128 * 4
-    assert plans["hvd_flash_dq"].path == "resident"
+        assert p.resident_bytes == 2 * 4 * L * 128 * 2 + 2 * L * 128 * 4
+    else:
+        assert plans["hvd_flash_dq"].path == "resident"
     # the grid's block axis counts blocks of the held side (the two can be
     # as many: `held` says which)
-    assert dkv.grid[:2] == (B * H // group, L * group // dkv.block_q
-                            if dkv.held == "q" else L // dkv.block_k)
+    assert p.grid[:2] == (B * H // group, L * group // p.block_q
+                          if p.held == "q" else L // p.block_k)
 
 
 # (B, L, D, V, chunk) -> (rows, iterations): the two LM cells' shapes cut

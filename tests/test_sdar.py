@@ -223,22 +223,24 @@ def _kernel_case(length, H, G, D=64, seed=0):
             jax.random.normal(ks[3], shape(H)))
 
 
-def _budget(path, H, G, S, D, bq, bk, rule):
-    """The VMEM budget that forces `path` of the backward under `rule`:
-    one byte short of what the form before it holds, in the order
-    `flash_plan` tries them (the one kernel; dQ beside dK/dV held by the k
-    block; by the q block; gridded)."""
-    if path == "one kernel":
-        return fa.RESIDENT_VMEM_BUDGET
+def _budget(path, H, G, S, D, bq, bk, rule, monkeypatch):
+    """The VMEM budget that forces `path` of the backward under `rule`: one
+    byte short of what the form before it holds, in the order `flash_plan`
+    tries them (the one kernel held by the k block, then by the q block;
+    dQ beside dK/dV held by the k block; gridded). The two kernels hold
+    less than the one held by the q block only with one head a kv head, so
+    they are reached with that form out of the order."""
     plan = lambda budget: fa.flash_plan(  # noqa: E731
         1, H, S, D, H // G, jnp.float32, True, bq, bk, budget, mask=rule)
-    budget = plan(fa.RESIDENT_VMEM_BUDGET)[
-        profile.FLASH_BWD].resident_bytes - 1
-    for _ in range(("two resident", "q-held dK/dV",
-                    "gridded dK/dV").index(path)):
-        dkv = plan(budget)[profile.FLASH_DKV]
-        if dkv.path == "resident":
-            budget = dkv.resident_bytes - 1
+    budget = fa.RESIDENT_VMEM_BUDGET
+    if path == "one kernel":
+        return budget
+    budget = plan(budget)[profile.FLASH_BWD].resident_bytes - 1
+    if path == "q-held":
+        return budget
+    monkeypatch.setattr(fa, "_BWD_HELD", ("k",))
+    if path == "gridded dK/dV":
+        budget = plan(budget)[profile.FLASH_DKV].resident_bytes - 1
     return budget
 
 
@@ -249,7 +251,7 @@ KERNEL_CASES = [(4, 4, 2, 64, 128), (16, 4, 1, 128, 128), (16, 2, 2, 64, 128)]
 
 
 @pytest.mark.parametrize("path", ["one kernel", "two resident",
-                                  "q-held dK/dV", "gridded dK/dV"])
+                                  "q-held", "gridded dK/dV"])
 @pytest.mark.parametrize("block,H,G,bq,bk", KERNEL_CASES)
 def test_ruled_kernels_agree_with_a_dense_masked_softmax(block, H, G, bq, bk,
                                                          path, monkeypatch):
@@ -259,22 +261,14 @@ def test_ruled_kernels_agree_with_a_dense_masked_softmax(block, H, G, bq, bk,
     mask = jnp.asarray(_dense_mask(rule))
     want, vjp = jax.vjp(lambda *a: _dense_attention(*a, D ** -0.5, mask),
                         q, k, v)
-    forced = path
-    if path == "q-held dK/dV" and H == G:
-        # One head a kv head: dK/dV held by the q block holds as much as
-        # held by the k block, so no budget reaches it; the order does.
-        monkeypatch.setattr(fa, "_DKV_HELD", ("q",))
-        forced = "two resident"
-    budget = _budget(forced, H, G, 2 * length, D, bq, bk, rule)
+    budget = _budget(path, H, G, 2 * length, D, bq, bk, rule, monkeypatch)
     plans = fa.flash_plan(1, H, 2 * length, D, H // G, q.dtype, True, bq, bk,
                           budget, mask=rule)
-    resident = lambda held: {  # noqa: E731
-        profile.FLASH_DQ: ("resident", "q"),
-        profile.FLASH_DKV: ("resident", held)}
     assert {n: (p.path, p.held) for n, p in plans.items()} == {
         "one kernel": {profile.FLASH_BWD: ("resident", "k")},
-        "two resident": resident("k"),
-        "q-held dK/dV": resident("q"),
+        "two resident": {profile.FLASH_DQ: ("resident", "q"),
+                         profile.FLASH_DKV: ("resident", "k")},
+        "q-held": {profile.FLASH_BWD: ("resident", "q")},
         "gridded dK/dV": {profile.FLASH_DQ: ("resident", "q"),
                           profile.FLASH_DKV: ("gridded", "k")}}[path]
     kw = dict(block_q=bq, block_k=bk, vmem_budget=budget, rule=rule)
@@ -312,20 +306,23 @@ def test_flash_plan_counts_the_tiles_the_dense_mask_has(length, block, group,
 
 
 def test_the_cells_shape_runs_kernels_in_both_directions():
-    """1 x 32 heads on 4, 8192 positions, D 128: all three kernels resident
-    on k + v and held by the q block (q + dO of a kv head's 8 query heads
-    do not fit; dK/dV holds dk, dv and their two accumulators too: 24 MiB),
-    5/16 of the tiles visited; gridded dK/dV would walk the same tiles."""
+    """1 x 32 heads on 4, 8192 positions, D 128: the forward resident on k +
+    v and the backward ONE kernel, both held by the q block (q + dO of a kv
+    head's 8 query heads do not fit; the backward holds k, v, dk, dv and two
+    accumulators: 24 MiB), 5/16 of the tiles visited; the two kernels with
+    dK/dV gridded, one byte under that, would walk the same tiles."""
     rule = BlockDiffusionMask(4096, 4)
     plans = {n: p for b in (False, True) for n, p in fa.flash_plan(
         1, 32, 8192, 128, 8, jnp.bfloat16, b, mask=rule).items()}
-    gridded = fa.flash_plan(1, 32, 8192, 128, 8, jnp.bfloat16, True,
-                            vmem_budget=plans[profile.FLASH_DKV]
-                            .resident_bytes - 1, mask=rule)[profile.FLASH_DKV]
+    assert sorted(plans) == [profile.FLASH_BWD, profile.FLASH_FWD]
+    assert plans[profile.FLASH_BWD].resident_bytes == 24 * 2 ** 20
+    two = fa.flash_plan(1, 32, 8192, 128, 8, jnp.bfloat16, True,
+                        vmem_budget=24 * 2 ** 20 - 1, mask=rule)
+    assert sorted(two) == [profile.FLASH_DKV, profile.FLASH_DQ]
+    gridded = two[profile.FLASH_DKV]
     assert (gridded.path, gridded.held, gridded.grid) == (
         "gridded", "k", (4, 16, 64))
-    assert plans[profile.FLASH_DKV].resident_bytes == 24 * 2 ** 20
-    for p in list(plans.values()) + [gridded]:
+    for p in list(plans.values()) + list(two.values()):
         if p is not gridded:
             assert (p.path, p.held, p.grid, p.grid_steps) == (
                 "resident", "q", (4, 64), 256)
