@@ -83,6 +83,13 @@ SIZES = dict(
     # `olmoe1b7_1chip`'s: 8 x 4096 over 64 experts, all held, every row of
     # [32768, 2048] live.
     moe_rows=[(4096, 4, 3584, 64, 8), (4096, 8, 2048, 64, 64)],
+    # (rows, F, activation, gated, live rows) of the buffers between the
+    # grouped matmuls of the three cells whose layers hold a part of their
+    # experts: `sdar30b_1chip`, `nemo3s120b_1chip`, `xing29b_1chip`, a live
+    # share like each cell's.
+    moe_act=[(65536, 768, "silu", True, 9000),
+             (32768, 2688, "relu2", False, 1700),
+             (16384, 1024, "silu", True, 2000)],
     # (M, C) of the largest and the smallest BatchNorm of ResNet-50 at
     # batch 256.
     bn=[(256 * 112 * 112, 64), (256 * 7 * 7, 2048)],
@@ -664,6 +671,71 @@ def moe_rows_vs_jnp(T, k, D, experts, held, dtype, seed):
                          + tuple(errs) + (TOL["attn_bf16"],)))
 
 
+def moe_act_vs_jnp(rows, F, act, gated, live, dtype, seed):
+    """How the activation between a held routed layer's grouped matmuls
+    runs at this shape (`hvd.profile.moe_act_plan`), that the two kernels
+    it names are in the program, and that on the chip the result, the
+    gradients and the backward's second `a` agree with jnp in f32 on the
+    live rows, are zeros from the count to its tile's end, and are finite
+    there though the operands' dead rows hold NaN."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import profile
+    from horovod_tpu.ops import moe_act
+    from horovod_tpu.parallel import expert
+
+    plan = profile.moe_act_plan(rows, F, dtype, gated=gated)
+    print("  %s, %s [%d, %d] %s%s: %s, tiles of %d of the buffer's rows x "
+          "%d columns, %d grid steps a call, VMEM %.1f MiB"
+          % (profile.MOE_ACT, profile.MOE_ACT_BWD, rows, F, act,
+             " gated" if gated else "", plan["path"], plan["tile_rows"],
+             plan["block_cols"], plan["grid_steps"],
+             plan["vmem_bytes"] / 2 ** 20), flush=True)
+    fn = expert.ACTIVATIONS[act]
+    dead = (jnp.arange(rows) >= live)[:, None]
+    g, h, da = (jnp.where(dead, jnp.nan, jax.random.normal(key, (rows, F))
+                          ).astype(dtype)
+                for key in jax.random.split(jax.random.PRNGKey(seed), 3))
+    tiles = (plan["tile_rows"], plan["block_cols"])
+
+    @jax.jit
+    def kernels(g, h, da):  # as `activated_matmul`'s rule calls them
+        args = (fn, g if gated else None, h, jnp.int32(live))
+        return (moe_act._pallas_act(*args, None, tiles, False)
+                + moe_act._pallas_act(*args, da, tiles, False))
+
+    @jax.jit
+    def plain(g, h, da):  # in f32, the dead rows selected away
+        g, h, da = (jnp.where(dead, 0, x).astype(jnp.float32)
+                    for x in (g, h, da))
+        a, vjp = jax.vjp(lambda g, h: fn(g) * h if gated else fn(h), g, h)
+        return tuple(x.astype(dtype)
+                     for x in (a,) + vjp(da)[0 if gated else 1:] + (a,))
+
+    names = profile.MOE_ACT_KERNELS
+    compiled, text, secs = compile_with_text(kernels, g, h, da)
+    check(plan["path"] == "kernel" and kernel_calls(text) == 2
+          and all(kernel_named(text, name) for name in names),
+          "%s: %d tpu_custom_call in the program (%s; compiled in %.1f s)"
+          % (profile.MOE_ACT, kernel_calls(text), ", ".join(names), secs))
+    got, want = compiled(g, h, da), plain(g, h, da)
+    written = -(-live // tiles[0]) * tiles[0]
+    errs = [rel_err(a[:written], b[:written]) for a, b in zip(got, want)]
+    zeros = max(float(jnp.max(jnp.abs(a[live:written].astype(jnp.float32)),
+                              initial=0.0))  # a count on a tile's edge
+                for a in got)
+    check(max(errs) <= TOL["attn_bf16"] and zeros == 0.0,
+          "%s vs jnp in f32 on the chip, %d of %d rows live: %s (max rel to "
+          "max |ref|, tol %.0e); from the count to the tile's end |.| <= "
+          "%.1e (NaN operands there)"
+          % (profile.MOE_ACT, live, rows, " ".join(
+              "%s %.2e" % pair for pair in zip(
+                  ("a", "dg", "dh", "a again") if gated
+                  else ("a", "dh", "a again"), errs)),
+             TOL["attn_bf16"], zeros))
+
+
 def bn_case(M, C, seed):
     """fused_batch_norm_train (Pallas statistics and gradient-statistics
     kernels) and flax.linen.BatchNorm at one ResNet-50 shape: (fused,
@@ -819,6 +891,8 @@ def phase_kernels(args):
     hc_stat_vs_jnp(*SIZES["hc"], jnp.bfloat16, args.seed)
     for shape in SIZES["moe_rows"]:
         moe_rows_vs_jnp(*shape, jnp.bfloat16, args.seed)
+    for shape in SIZES["moe_act"]:
+        moe_act_vs_jnp(*shape, jnp.bfloat16, args.seed)
 
     step, state = resnet_step(models.ResNet50PBN, mesh,
                               SIZES["resnet_batch"], args.seed)

@@ -16,8 +16,18 @@ held: an eighth live) and OLMoE's ([32768, 2048], all 64 held: every row
 live, where there is nothing to skip and the question is one row move at a
 time against XLA's gather).
 
+With `--act`, the activation between the grouped matmuls instead
+(`ops.moe_act`: ``act(g) * h`` or ``act(h)`` over the live tiles of [rows, F]
+buffers, `TILE_ROWS` rows and `BLOCK_BYTES` of an operand a grid step)
+against XLA's fusion over all rows, forward and backward, at the
+three cells whose layers hold a part of their experts: SDAR's [65536, 768]
+silu-gated, Nemotron's [32768, 2688] relu2 without a gate, Xing's
+[16384, 1024] silu-gated, at a live share like each cell's.
+
 Usage: python examples/moe_rows_sweep.py [--rows 256 512 1024 2048]
            [--resident-mib 12 24 48] [--unroll 8] [--iters 20]
+       python examples/moe_rows_sweep.py --act [--rows 256 512 1024 2048]
+           [--block-kib 512 1024 2048 4096] [--iters 20]
 """
 
 import argparse
@@ -33,12 +43,23 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from horovod_tpu.ops import moe_act as ma  # noqa: E402
 from horovod_tpu.ops import moe_rows as mr  # noqa: E402
 from horovod_tpu.parallel import expert  # noqa: E402
 
 # (T, k, D, experts, held): the benchmark's two routed cells.
 CASES = {"xing_eighth_live": (4096, 4, 3584, 64, 8),
          "olmoe_all_live": (4096, 8, 2048, 64, 64)}
+
+
+# (rows, F, the activation, gated, live rows): the buffers between the
+# grouped matmuls of the three cells whose layers hold a part of their experts.
+ACT_CASES = {"sdar_silu_gated": (65536, 768, "silu", True, 9000),
+             "nemo3_relu2": (32768, 2688, "relu2", False, 1700),
+             "xing_silu_gated": (16384, 1024, "silu", True, 2048)}
+
+
+CHAIN = 8  # --act: calls a timed program makes, one after the other
 
 
 def ops(x, ys, weights, order, inv, n_live, k):
@@ -59,8 +80,89 @@ def timed(step, args, iters):
     return min(times)
 
 
+def sweep_act(args):
+    """The activation: XLA's fusion over all rows, then the kernels at each
+    candidate tile. A call is a tenth of a millisecond, under the host's
+    cost of issuing one: each timed program runs `CHAIN` of them, every one
+    on the results of the one before, behind a barrier XLA fuses nothing
+    across."""
+    bf16 = jnp.bfloat16
+    for case, (rows, F, name, gated, live) in ACT_CASES.items():
+        act = expert.ACTIVATIONS[name]
+        g, h, da = (jax.random.normal(key, (rows, F), bf16)
+                    for key in jax.random.split(jax.random.PRNGKey(0), 3))
+        n_live = jnp.int32(live)
+
+        def xla(g, h, da):
+            """(a,), or with `da` (dg, dh, a) / (dh, a): autodiff of the
+            plain expression."""
+            fn = (lambda g, h: act(g) * h) if gated else (lambda g, h: act(h))
+            if da is None:
+                return (fn(g, h),)
+            a, vjp = jax.vjp(fn, g, h)
+            return vjp(da)[0 if gated else 1:] + (a,)
+
+        def kernels(g, h, da):
+            return tuple(ma._pallas_act(
+                act, g if gated else None, h, n_live, da,
+                ma._tiles(rows, F, bf16), False))
+
+        def chained(form, backward):
+            def f(g, h, da):
+                for _ in range(CHAIN):
+                    got = form(g, h, da if backward else None)
+                    # the results are the next call's operands, no buffer
+                    # twice among them
+                    g, h, da = jax.lax.optimization_barrier(
+                        (got[0], got[-1], g) if backward
+                        else (h, got[0], da))
+                return g, h, da
+            return jax.jit(f)
+
+        want = jax.jit(xla)(g, h, da)
+
+        def report(form_name, form, **more):
+            got = jax.jit(form)(g, h, da)
+            off = [float(jnp.max(jnp.abs(
+                a[:live].astype(jnp.float32) - b[:live].astype(jnp.float32))))
+                for a, b in zip(got, want)]
+            ms = [timed(chained(form, backward), (g, h, da), args.iters)
+                  / CHAIN for backward in (False, True)]
+            print(json.dumps({
+                "case": case, "form": form_name, "live": live,
+                "buffer_rows": rows, "width": F, **more,
+                "live_rows_off_xla": [round(e, 5) for e in off],
+                "forward_ms": round(ms[0], 4),
+                "backward_ms": round(ms[1], 4)}), flush=True)
+
+        report("xla", xla)
+        seen = set()
+        for ma.TILE_ROWS in args.rows:
+            for kib in args.block_kib:
+                ma.BLOCK_BYTES = kib << 10
+                tiles = ma._tiles(rows, F, bf16)
+                plan = ma.act_plan(rows, F, bf16, gated)
+                if tiles is None or tiles in seen \
+                        or plan["vmem_bytes"] > ma._VMEM_LIMIT_BYTES:
+                    continue
+                seen.add(tiles)
+                jax.clear_caches()  # the kernels' calls are jitted
+                try:
+                    report("kernel", kernels, tile_rows=tiles[0],
+                           block_cols=tiles[1])
+                except jax.errors.JaxRuntimeError as e:  # VMEM, mostly
+                    print(json.dumps({"case": case, "tile_rows": tiles[0],
+                                      "block_cols": tiles[1],
+                                      "refused": str(e)[:160]}), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--act", action="store_true",
+                    help="sweep the activation's kernels, not the rows'")
+    ap.add_argument("--block-kib", nargs="+", type=int,
+                    default=[512, 1024, 2048, 4096],
+                    help="--act: KiB of one operand's block a grid step")
     ap.add_argument("--rows", nargs="+", type=int,
                     default=[256, 512, 1024, 2048])
     ap.add_argument("--resident-mib", nargs="+", type=int,
@@ -74,6 +176,8 @@ def main():
                  "a TPU every row would be the jnp form (backend: %s)"
                  % jax.default_backend())
     print("device:", jax.devices()[0].device_kind)
+    if args.act:
+        return sweep_act(args)
     for case, (T, k, D, E, held) in CASES.items():
         keys = jax.random.split(jax.random.PRNGKey(0), 6)
         bf16 = jnp.bfloat16

@@ -14,6 +14,7 @@ shards agree.
 
 import contextlib
 import dataclasses
+import functools
 import math
 from typing import Any, NamedTuple, Optional, Tuple, Union
 
@@ -553,13 +554,18 @@ def hc_maps(X, phi, bias, alpha, iters, eps, clamp, norm_eps):
             sinkhorn(jnp.exp(jnp.clip(res, clamp[0], clamp[1])), iters, eps))
 
 
+@functools.cache
 def _keep_hc_stat():
     """The policy of this module's recomputations (`hc_remat`,
     `block_remat`): they run everything again but what `hc_maps` derives
     from a full pass over the streams, each token's sum of squares and
     its projection (16 KB + 384 KB a connection at 4096 tokens and four
     streams), which they keep by name. A model without hyper-connections
-    names nothing, and its recomputation keeps nothing, as ever."""
+    names nothing, and its recomputation keeps nothing, as ever. ONE object
+    for every layer: JAX caches a jitted call's kept and recomputed halves
+    by (the call's jaxpr, the policy), so a closure a layer lowers every
+    jitted kernel of every recomputed layer anew (a third of the SDAR
+    cell's `tpu_custom_call`s, `docs/TRACING.md`)."""
     return jax.checkpoint_policies.save_only_these_names(profile.HC_STAT)
 
 

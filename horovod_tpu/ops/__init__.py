@@ -19,6 +19,9 @@ played by Pallas TPU kernels:
   holds a part of the experts: the rows of its k*T-row buffer moved by
   the count of those that are live, each op's transpose the other
   kernel.
+* :mod:`.moe_act` — the experts' activation between the grouped matmuls
+  of such a layer (``act(g) * h``, or ``act(h)``): the live tiles of its
+  buffers alone, zeros to the end of the last one, custom VJP.
 
 Beside them, in jnp (XLA's fusions own it until a trace says otherwise):
 

@@ -78,14 +78,24 @@ def visits(group_sizes, rows, block, visit_empty=False):
     V = rows / block + G. With `visit_empty` an empty group gets one visit
     (of a tile in which it has no row), so that a kernel can zero what it
     owns."""
-    G = group_sizes.shape[0]
+    return _visits(_groups_tiles(group_sizes, rows, block), visit_empty)
+
+
+def _groups_tiles(group_sizes, rows, block):
+    """What both forms of `visits` share: (sizes, ends, each group's first
+    and last tile, the tiles)."""
     tiles = rows // block
-    V = tiles + G
     sizes = group_sizes.astype(jnp.int32)
     ends = jnp.cumsum(sizes)
-    begins = ends - sizes
-    first = jnp.minimum(begins // block, tiles - 1)
+    first = jnp.minimum((ends - sizes) // block, tiles - 1)
     last = jnp.minimum((ends - 1) // block, tiles - 1)
+    return sizes, ends, first, last, tiles
+
+
+def _visits(groups, visit_empty):
+    sizes, ends, first, last, tiles = groups
+    G = sizes.shape[0]
+    V = tiles + G
     count = jnp.where(sizes > 0, last - first + 1, int(visit_empty))
     upto = jnp.cumsum(count)
     total = upto[-1:]
@@ -259,36 +269,81 @@ def _drhs(lhs, g, meta, block, sub, interpret):
     )(*meta, lhs, g)
 
 
-def _visits(group_sizes, rows, visit_empty=False):
-    return visits(group_sizes, _whole_tiles(rows, BLOCK_ROWS), BLOCK_ROWS,
-                  visit_empty)
+def layer_visits(group_sizes, rows, interpret=None):
+    """What the kernels behind `grouped_matmul` are told of `rows` rows in
+    groups of `group_sizes`: (`visits` as the products and the rows'
+    gradients take them, `visits` with the empty groups visited as the
+    matrices' gradients do), in whole tiles of `BLOCK_ROWS`; None where the
+    call is `lax.ragged_dot`'s. A routed layer has three products over the
+    same rows and of each two gradients: it forms this ONCE and hands it to
+    each (`meta=`), where every call formed its own anew, twenty small
+    instructions traced 84 times for the SDAR cell's step. They stay out of
+    the jitted calls (the comment above `_gmm`)."""
+    if interpret is None and jax.default_backend() != "tpu":
+        return None
+    groups = _groups_tiles(group_sizes, _whole_tiles(rows, BLOCK_ROWS),
+                           BLOCK_ROWS)
+    return _visits(groups, False), _visits(groups, True)
+
+
+def product(lhs, rhs, meta, interpret):
+    """lhs [M, K] x rhs [G, K, N] -> [M, N], meta `layer_visits` of the M
+    rows: the forward kernel alone (`hvd_moe_gmm`), for a caller that
+    brings its own rule (`ops/moe_act.py`)."""
+    return _gmm(lhs, rhs, meta[0], False, BLOCK_ROWS, SUB_ROWS, interpret)
+
+
+def rows_gradient(g, rhs, meta, interpret):
+    """g [M, N], rhs [G, K, N], meta `layer_visits` of the M rows ->
+    [M, K]: the gradient by the rows of `grouped_matmul(lhs, rhs, ...)`
+    from its result's cotangent (`hvd_moe_gmm_dlhs`)."""
+    return _gmm(g, rhs, meta[0], True, BLOCK_ROWS, SUB_ROWS, interpret)
+
+
+def matrices_gradient(lhs, g, meta, dtype, interpret):
+    """lhs [M, K], g [M, N] -> [G, K, N] in `dtype`: the gradient by the
+    matrices (`hvd_moe_gmm_drhs`). A row of `lhs` that belongs to no group
+    is multiplied by zero where a part (`SUB_ROWS_DRHS`) also holds a row
+    of the last group: it has to be finite there."""
+    return _drhs(lhs, g, meta[1], BLOCK_ROWS, SUB_ROWS_DRHS,
+                 interpret).astype(dtype)
+
+
+def tile_sizes():
+    """(`BLOCK_ROWS`, `SUB_ROWS`, `SUB_ROWS_DRHS`) as they stand: what a
+    jitted function that calls the three above depends on beside its
+    operands, for its key (the tests shrink them)."""
+    return BLOCK_ROWS, SUB_ROWS, SUB_ROWS_DRHS
+
+
+# A rule's backward is ONE jitted function, as its kernels' calls are: the
+# layers of a model trace it once, and each layer binds one call where it
+# bound one a kernel. `sizes` is `tile_sizes()`, read by the caller.
+@functools.partial(jax.jit, static_argnames=("sizes", "interpret"))
+def _gradients(lhs, rhs, meta, g, sizes, interpret):
+    del sizes
+    return (rows_gradient(g, rhs, meta, interpret),
+            matrices_gradient(lhs, g, meta, rhs.dtype, interpret))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _grouped(lhs, rhs, group_sizes, interpret):
-    return _gmm(lhs, rhs, _visits(group_sizes, lhs.shape[0]), False,
-                BLOCK_ROWS, SUB_ROWS, interpret)
+def _grouped(lhs, rhs, meta, interpret):
+    return product(lhs, rhs, meta, interpret)
 
 
-def _grouped_fwd(lhs, rhs, group_sizes, interpret):
-    return (_grouped(lhs, rhs, group_sizes, interpret),
-            (lhs, rhs, group_sizes))
+def _grouped_fwd(lhs, rhs, meta, interpret):
+    return product(lhs, rhs, meta, interpret), (lhs, rhs, meta)
 
 
 def _grouped_bwd(interpret, res, g):
-    lhs, rhs, group_sizes = res
-    rows = lhs.shape[0]
-    d_lhs = _gmm(g, rhs, _visits(group_sizes, rows), True, BLOCK_ROWS,
-                 SUB_ROWS, interpret)
-    d_rhs = _drhs(lhs, g, _visits(group_sizes, rows, visit_empty=True),
-                  BLOCK_ROWS, SUB_ROWS_DRHS, interpret)
-    return d_lhs, d_rhs.astype(rhs.dtype), None
+    lhs, rhs, meta = res
+    return _gradients(lhs, rhs, meta, g, tile_sizes(), interpret) + (None,)
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
+def grouped_matmul(lhs, rhs, group_sizes, interpret=None, meta=None):
     """lhs [M, K] whose rows lie in contiguous groups of `group_sizes` [G]
     int32 (summing to M at most) times rhs [G, K, N], each group with its
     own matrix: [M, N] in lhs.dtype. The matrices may be kept in another
@@ -297,11 +352,14 @@ def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
     back in rhs.dtype.
 
     `interpret`: None takes the kernels on a TPU and `lax.ragged_dot`
-    elsewhere; True runs the kernels in Pallas' interpreter."""
+    elsewhere; True runs the kernels in Pallas' interpreter. `meta`:
+    `layer_visits(group_sizes, M, interpret)` where the caller has formed
+    it for several calls over the same rows; formed here otherwise."""
     if interpret is None and jax.default_backend() != "tpu":
         return lax.ragged_dot(lhs, rhs.astype(lhs.dtype),
                               group_sizes.astype(jnp.int32),
                               preferred_element_type=jnp.float32
                               ).astype(lhs.dtype)
-    return _grouped(lhs, rhs, group_sizes.astype(jnp.int32),
-                    bool(interpret))
+    if meta is None:
+        meta = layer_visits(group_sizes, lhs.shape[0], interpret)
+    return _grouped(lhs, rhs, meta, bool(interpret))

@@ -346,24 +346,27 @@ def _pallas_sum(srcs, tok, n_live, scale, T, tiles, interpret):
 
 
 def rows_sum(srcs, order, inv, n_live, k, weights=None, interpret=None):
-    """srcs: arrays [k*T, D] in sorted order whose SUM's rows are meant (one,
-    or the cotangents of the copies `dispatch` handed out), order [k*T]
+    """srcs: arrays [S, D] in sorted order whose SUM's rows are meant (one,
+    or the cotangents of the copies `dispatch` handed out), order [S]
     (`order[s]` = j*T + t: the assignment at sorted position s), inv [k*T]
     (its inverse), n_live (int32 scalar), weights [k, T] f32 or None (ones)
     -> [T, D] in the sources' dtype:
     ``y[t] = sum_j weights[j, t] * src[inv[j*T + t]]`` over the choices j
     whose row is live (``inv[j*T + t] < n_live``), summed in f32 and rounded
     once (the kernel adds the sources, then a token's rows in sorted order;
-    jnp rounds the sources' sum and adds in the order of j). Rows from
+    jnp rounds the sources' sum and adds in the order of j). S is k*T, or a
+    whole number of T under it where no more rows can be live (`moe_ffn`'s
+    cut): the token count is `inv`'s, never derived from the rows. Rows from
     `n_live` on are selected away. `interpret`: as `rows_out`'s."""
     S, D = srcs[0].shape
-    T = S // k
-    tiles = _kernel_tiles(T, k, D, srcs[0].dtype, interpret)
+    T = inv.shape[0] // k
+    tiles = _kernel_tiles(T, S // T, D, srcs[0].dtype, interpret)
     if tiles is None:
         src = srcs[0] if len(srcs) == 1 else sum(
             a.astype(jnp.float32) for a in srcs).astype(srcs[0].dtype)
         pos = inv.reshape(k, T)
         live = pos < n_live
+        # A position past a cut buffer is dead: the gather clamps it.
         rows = jnp.where(live[..., None], src[pos], 0)
         w = live.astype(jnp.float32) if weights is None \
             else jnp.where(live, weights, 0.0)
@@ -386,8 +389,9 @@ def _dispatched(x, order, n_live, copies, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def dispatch(x, order, inv, n_live, k, copies=1, interpret=None):
-    """x [T, D] -> `copies` times the same [k*T, D]: row s is the token of
-    the assignment at sorted position s (`order[s]` = j*T + t) for
+    """x [T, D] -> `copies` times the same [S, D], S the length of `order`
+    (k*T, or the front of the sorted order that can be live): row s is the
+    token of the assignment at sorted position s (`order[s]` = j*T + t) for
     ``s < n_live``; behind them as `rows_out` leaves it. `inv` [k*T]: the
     sorted position of assignment a. One copy for each use of the rows (a
     gated expert's two first matmuls): the transpose is ONE `rows_sum` of
@@ -418,12 +422,12 @@ def _combined(ys, weights, order, inv, n_live, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def combine(ys, weights, order, inv, n_live, interpret=None):
-    """ys [k*T, D] in sorted order, weights [k, T] f32 -> y [T, D] in
-    ys.dtype: each token's weighted sum over its choices whose row is live
-    (`rows_sum`). Transposed as one `rows_out`: the cotangent's row of each
-    live position times its weight, and its product with that position's
-    row of `ys`, the weights' gradient. The rows in assignment order are
-    made in neither direction."""
+    """ys [S, D] in sorted order (S and `order` as `dispatch`'s), weights
+    [k, T] f32 -> y [T, D] in ys.dtype: each token's weighted sum over its
+    choices whose row is live (`rows_sum`). Transposed as one `rows_out`:
+    the cotangent's row of each live position times its weight, and its
+    product with that position's row of `ys`, the weights' gradient. The
+    rows in assignment order are made in neither direction."""
     return _combined(ys, weights, order, inv, n_live, interpret)
 
 

@@ -33,7 +33,11 @@ this is the TPU-first ``ep`` member of the parallelism family
   belong to a held expert are live; the dispatch and the combine are told
   their count (k*T where every expert is held) and touch no other. Each
   one's transpose is the other's kernel: no scatter-add of rows in either
-  direction.
+  direction. Told which experts it holds, the layer's buffer is the front
+  of the sorted order that can be live (count*T rows where that is under
+  k*T: a token picks an expert once) from the dispatch to the combine, and
+  the activation between the grouped matmuls touches its live tiles alone
+  too (`ops/moe_act.activated_matmul`).
 * **capacity** (a ``capacity_factor``; required with ``ep_axis``) — each
   expert has ``C = ceil(T/E * capacity_factor)`` slots, filled from the same
   sorted order (first choices of all tokens before second choices, GShard's
@@ -61,8 +65,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu import profile
-from horovod_tpu.ops import moe_rows
-from horovod_tpu.ops.grouped_matmul import grouped_matmul
+from horovod_tpu.ops import moe_act, moe_rows
+from horovod_tpu.ops.grouped_matmul import grouped_matmul, layer_visits
 
 # Final key of every expert-sharded leaf: the contract between `MoeMlp`,
 # `ep_param_specs` and `ep_grad_sync`.
@@ -152,19 +156,20 @@ def relu2(h):
 ACTIVATIONS = {"silu": nn.silu, "relu2": relu2}
 
 
-def _experts(xs, w_in, w_out, w_gate, act, matmul):
+def _experts(xs, w_in, w_out, w_gate, act, matmul, last=None):
     """The experts' feed-forward on rows `xs`; `matmul(rows, weights)` is
     grouped (dropless) or batched (capacity). `xs` may be a tuple: the
     rows once for each of a gated expert's two first matmuls, from a
-    dispatch that sums their gradients itself (`ops/moe_rows.dispatch`)."""
+    dispatch that sums their gradients itself (`ops/moe_rows.dispatch`).
+    `last(h, gate)`, where given, is the activation and the last matmul
+    (`moe_ffn` where it holds a part of the experts)."""
     if not isinstance(xs, tuple):
         xs = (xs,)
     h = matmul(xs[0], w_in)
-    if w_gate is not None:
-        h = act(matmul(xs[-1], w_gate)) * h
-    else:
-        h = act(h)
-    return matmul(h, w_out)
+    gate = None if w_gate is None else matmul(xs[-1], w_gate)
+    if last is not None:
+        return last(h, gate)
+    return matmul(act(h) if gate is None else act(gate) * h, w_out)
 
 
 def moe_capacity(tokens, num_experts, capacity_factor):
@@ -253,34 +258,42 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
             # they are (`ops/moe_rows`: kernels that touch the live rows
             # alone where a TPU runs them). With every expert held the
             # run is the whole order and the count k*T, a constant.
-            sizes, n_live, bound = group_sizes, jnp.int32(kT), kT
+            sizes, n_live = group_sizes, jnp.int32(kT)
             if held is not None:
-                # From `start`: turned to the front of the k*T-row buffer,
-                # which so holds the run whatever the router does. The
-                # rows behind the `n_live` belong to no group.
+                # A token picks an expert once, so `count` experts are
+                # sent count * T rows at most: where that is under k * T
+                # (many choices, few held) the buffer is that long, a
+                # static cut, from the dispatch to the combine.
+                bound = min(kT, count * T)
+                # From `start`: turned to the front of the buffer, which
+                # so holds the run whatever the router does. The rows
+                # behind the `n_live` belong to no group.
                 start = jnp.sum(group_sizes[:first])
                 sizes = group_sizes[first:first + count]
                 n_live = jnp.sum(sizes)
-                at = jnp.arange(kT, dtype=jnp.int32)
+                at = jnp.arange(bound, dtype=jnp.int32)
                 order = order[(at + start) % kT]
                 inv = (inv - start) % kT
                 stats["held"] = n_live
-                # A token picks an expert once, so `count` experts are
-                # sent count * T rows at most: where that is under k * T
-                # (many choices, few held) the experts run on the front of
-                # the buffer alone, a static cut, and what they return is
-                # filled up again behind.
-                bound = min(kT, count * T)
             xs = moe_rows.dispatch(x, order, inv, n_live, top_k,
                                    1 if w_gate is None else 2)
-            if bound < kT:
-                xs = tuple(rows[:bound] for rows in xs)
         with jax.named_scope(profile.MOE_EXPERTS):
+            # The three products run over the same rows in the same
+            # groups: what their kernels are told of them is formed once.
+            meta = layer_visits(sizes, xs[0].shape[0])
+            # Told which experts it holds, the layer's activation touches
+            # the live tiles of its buffers alone, as the kernels on both
+            # sides of it do (`ops/moe_act`, where a TPU runs it and the
+            # shapes fit); with every expert held nothing is dead, and
+            # XLA's fusion stays.
+            last = None if held is None else (
+                lambda h, gate: moe_act.activated_matmul(
+                    act, h, n_live, w_out, sizes, gate, meta=meta))
             ys = _experts(xs, w_in, w_out, w_gate, act,
-                          lambda rows, w: grouped_matmul(rows, w, sizes))
+                          lambda rows, w: grouped_matmul(rows, w, sizes,
+                                                         meta=meta),
+                          last)
         with jax.named_scope(profile.MOE_COMBINE):
-            if bound < kT:
-                ys = jnp.pad(ys, ((0, kT - bound), (0, 0)))
             y = moe_rows.combine(ys, weights, order, inv, n_live)
         stats["dropped"] = jnp.zeros((), jnp.int32)
         return y, stats

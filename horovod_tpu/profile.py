@@ -140,10 +140,15 @@ HC_STAT_DPHI = "hvd_hc_stat_dphi"  # backward: the gradient of phi
 MOE_ROWS = "hvd_moe_rows"  # out[s] = scale[s] * src[idx[s]], s live
 MOE_SUM = "hvd_moe_sum"    # y[t] = the sum of the live rows s of token t
 MOE_ROWS_KERNELS = (MOE_ROWS, MOE_SUM)
+# The activation between the grouped matmuls of such a layer, over the live
+# tiles alone (`ops/moe_act.py`, under `MOE_EXPERTS`).
+MOE_ACT = "hvd_moe_act"          # a = act(g) * h, or act(h), s live
+MOE_ACT_BWD = "hvd_moe_act_bwd"  # (dg, dh), or dh, from (g, h, da)
+MOE_ACT_KERNELS = (MOE_ACT, MOE_ACT_BWD)
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
            RING_ATTN_DQ, RING_ATTN_DKV, BN_STATS,
            BN_GRAD_STATS) + MOE_GMM_KERNELS + (HC_STAT, HC_STAT_DPHI) \
-    + MOE_ROWS_KERNELS
+    + MOE_ROWS_KERNELS + MOE_ACT_KERNELS
 
 # Host spans a traced window shows: the program's only per-call Python
 # (`span`), and `step.place`, which is a `phase` (below) and so shows there
@@ -614,5 +619,22 @@ def moe_rows_plan(*args, **kwargs):
     this returns."""
     # `ops.moe_rows` imports this module for its kernels' names.
     from horovod_tpu.ops.moe_rows import rows_plan as plan
+
+    return plan(*args, **kwargs)
+
+
+def moe_act_plan(*args, **kwargs):
+    """How the activation between the grouped matmuls of such a layer runs:
+    `ops.moe_act.act_plan(rows, F, dtype, gated=, held=)` (its arguments and
+    result). The path (`kernel`: `MOE_ACT` and `MOE_ACT_BWD`, which touch
+    the live tiles of the [rows, F] buffers alone, where the layer holds a
+    part of the experts, the shapes fit and a TPU runs it; `xla`: the plain
+    expression over all rows), a tile's rows, a block's columns, the
+    buffer's rows, the grid steps a call issues, the VMEM bytes the
+    backward's blocks take and the kernel calls a layer makes in each
+    direction. How often it engages is the live tiles' share of the
+    buffer's (`parallel.routing_stats`' `held_share`). The op runs what
+    this returns."""
+    from horovod_tpu.ops.moe_act import act_plan as plan
 
     return plan(*args, **kwargs)

@@ -323,6 +323,46 @@ def test_moe_rows_compile_for_v5e(one_chip, monkeypatch):
     assert plan["path"] == "kernel" and plan["tile_rows"] == 1024
 
 
+# The activation between the grouped matmuls of the three cells whose layers
+# hold a part of their experts, with the last matmul, as `moe_ffn` calls them:
+# (rows, F, the activation, gated, experts held, the matmul's width).
+MOE_ACT_SHAPES = {
+    "sdar30b_1chip": (65536, 768, "silu", True, 16, 2048),
+    "nemo3s120b_1chip": (32768, 2688, "relu2", False, 8, 1024),
+    "xing29b_1chip": (16384, 1024, "silu", True, 8, 3584),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(MOE_ACT_SHAPES))
+def test_moe_act_compiles_for_v5e(one_chip, monkeypatch, cell):
+    from horovod_tpu.ops import moe_act
+    from horovod_tpu.parallel.expert import ACTIVATIONS
+
+    rows, F, act, gated, held, width = MOE_ACT_SHAPES[cell]
+    bf16 = jnp.bfloat16
+
+    def fwd_bwd(g, h, n_live, w_out, sizes, dy):
+        out, vjp = jax.vjp(lambda g, h, w_out: moe_act.activated_matmul(
+            ACTIVATIONS[act], h, n_live, w_out, sizes, g if gated else None,
+            False), g, h, w_out)
+        return out, vjp(dy)
+
+    text = _compile(one_chip, fwd_bwd, ((rows, F), bf16), ((rows, F), bf16),
+                    ((), jnp.int32), ((held, F, width), jnp.float32),
+                    ((held,), jnp.int32), ((rows, width), bf16))
+    # the activation forward and backward between the grouped matmul's three
+    assert _kernels(text) == 5, text[:2000]
+    for name in profile.MOE_ACT_KERNELS + profile.MOE_GMM_KERNELS:
+        assert _named(text, name), name
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = profile.moe_act_plan(rows, F, bf16, gated=gated)
+    assert plan["path"] == "kernel" and plan["buffer_rows"] == rows
+    assert rows % plan["tile_rows"] == 0 and F % plan["block_cols"] == 0
+    assert plan["vmem_bytes"] <= moe_act._VMEM_LIMIT_BYTES
+    assert profile.moe_act_plan(rows, F, bf16, gated=gated,
+                                held=False)["path"] == "xla"
+
+
 # One rank's share of Nemotron-3-Super's layers on one chip (`benchmark`'s
 # cell `nemo3s120b_1chip`): attention's 16 query heads on ONE kv head at 4096
 # positions, a head group the one backward kernel cannot hold by the k block
@@ -355,8 +395,8 @@ def test_flash_at_a_head_group_of_16_compiles_for_v5e(one_chip):
 
 # Its routed layer: top-22 of 512 over 4096 tokens, 8 experts held, in a
 # 1024-wide latent, relu2 experts of width 2688 without a gate: the rows'
-# kernels on a 90112-row buffer, the grouped matmuls on its first 32768
-# rows (a token picks an expert once).
+# kernels, the grouped matmuls and the activation's between them on a buffer
+# of 32768 rows (a token picks an expert once), not of 90112.
 def test_latent_routed_layer_compiles_for_v5e(one_chip, monkeypatch):
     from horovod_tpu.parallel import expert
 
@@ -376,12 +416,16 @@ def test_latent_routed_layer_compiles_for_v5e(one_chip, monkeypatch):
     text = _compile(one_chip, fwd_bwd, ((T, D), bf16), ((T, R), bf16),
                     ((D, E), f32), ((E,), f32), ((held[1], R, F), f32),
                     ((held[1], F, R), f32), ((T, R), bf16))
-    # 2 + 4 grouped matmuls, and each rows' kernel forward and backward
-    assert _kernels(text) == 10, text[:2000]
-    for name in profile.MOE_GMM_KERNELS + profile.MOE_ROWS_KERNELS:
+    # 2 + 4 grouped matmuls, each rows' kernel forward and backward, and
+    # the activation's two between the matmuls
+    assert _kernels(text) == 12, text[:2000]
+    for name in profile.MOE_GMM_KERNELS + profile.MOE_ROWS_KERNELS \
+            + profile.MOE_ACT_KERNELS:
         assert _named(text, name), name
-    # the experts' intermediate is cut to count x T rows, never k x T
-    assert "[32768,2688]" in text and "[90112,2688]" not in text
+    # the buffer is count x T rows from the dispatch to the combine, never
+    # k x T: no [90112, .] array of rows, no slice of one and no fill-up
+    assert "[32768,2688]" in text and "[32768,1024]" in text
+    assert "[90112,2688]" not in text and "[90112,1024]" not in text
     assert profile.moe_rows_plan(T, k, R, bf16)["path"] == "kernel"
 
 

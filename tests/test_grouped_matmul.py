@@ -111,3 +111,25 @@ def test_non_tpu_backend_takes_ragged_dot():
     np.testing.assert_allclose(out[2:], 8.0)
     assert "ragged_dot" in str(jax.make_jaxpr(
         lambda l, r: gm.grouped_matmul(l, r, sizes))(lhs, rhs))
+
+
+def test_the_visits_a_caller_formed_are_the_calls_own(small_tiles):
+    """`meta=layer_visits(...)`: the result and both gradients are those of
+    the call that forms its visits itself, and off a TPU, with no interpreter
+    asked for, there is nothing to form."""
+    rng = np.random.RandomState(3)
+    sizes = jnp.asarray([5, 0, 17, 9], jnp.int32)
+    lhs = jnp.asarray(rng.randn(40, 128), jnp.float32)
+    rhs = jnp.asarray(rng.randn(4, 128, 128), jnp.float32)
+    g = jnp.asarray(rng.randn(40, 128), jnp.float32)
+    meta = gm.layer_visits(sizes, 40, True)
+    assert gm.layer_visits(sizes, 40) is None
+
+    def both(meta):
+        out, vjp = jax.vjp(lambda a, b: gm.grouped_matmul(
+            a, b, sizes, True, meta), lhs, rhs)
+        live = (jnp.arange(40) < 31)[:, None]
+        return (jnp.where(live, out, 0.0),) + vjp(jnp.where(live, g, 0.0))
+
+    for mine, its in zip(both(meta), both(None)):
+        np.testing.assert_array_equal(mine, its)

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from horovod_tpu import profile
 from horovod_tpu.ops import grouped_matmul as gm
+from horovod_tpu.ops import moe_act as ma
 from horovod_tpu.ops import moe_rows as mr
 from horovod_tpu.parallel import expert
 
@@ -223,26 +224,32 @@ def test_rows_plan_says_which_path_a_call_takes(monkeypatch, case):
 # --------------------------------------------------------------------------
 
 def _interpret_every_kernel(monkeypatch):
-    """From here on `moe_ffn` runs the rows' two kernels and the grouped
-    matmuls' three in Pallas' interpreter, the latter on tiles of 32 rows
-    too."""
+    """From here on `moe_ffn` runs the rows' two kernels, the grouped
+    matmuls' three and the activation's two in Pallas' interpreter, the
+    latter five on tiles of 32 rows too."""
     monkeypatch.setattr(gm, "BLOCK_ROWS", 32)
     monkeypatch.setattr(gm, "SUB_ROWS", 8)
+    monkeypatch.setattr(ma, "TILE_ROWS", TILE)
+    monkeypatch.setattr(ma, "activated_matmul", functools.partial(
+        ma.activated_matmul, interpret=True))
     monkeypatch.setattr(expert, "grouped_matmul", functools.partial(
         gm.grouped_matmul, interpret=True))
+    monkeypatch.setattr(expert, "layer_visits", functools.partial(
+        gm.layer_visits, interpret=True))
     for name in ("dispatch", "combine"):
         monkeypatch.setattr(mr, name, functools.partial(
             getattr(mr, name), interpret=True))
 
 
-def _layer(seed=0, E=8, F=32):
+def _layer(seed=0, E=8, F=128):
+    """A gated layer of a width the activation's kernels take too."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     return dict(
         x=jax.random.normal(ks[0], (T, 128)),
         router=jax.random.normal(ks[1], (128, E)),
         w_gate=0.3 * jax.random.normal(ks[2], (E, 128, F)),
         w_up=0.3 * jax.random.normal(ks[3], (E, 128, F)),
-        w_down=0.3 * jax.random.normal(ks[4], (E, F, 128)),
+        w_down=0.15 * jax.random.normal(ks[4], (E, F, 128)),
         g=jax.random.normal(ks[5], (T, 128)))
 
 
@@ -250,13 +257,13 @@ def _layer(seed=0, E=8, F=32):
 def test_a_held_layer_on_kernels_alone_is_the_layer_on_jnp(
         small_tiles, monkeypatch, held):
     """`moe_ffn(held=)` with every kernel of the routed feed-forward in the
-    interpreter (the rows', and the grouped matmuls', whose matrices'
-    gradient multiplies the dead rows of the last live part by zero):
-    values and the gradients by x, the router and the experts' first
-    matrices equal the jnp path's. Not `w_down`'s: its rows are the
-    grouped matmuls' own output, whose dead rows they leave as they find
-    them (the interpreter fills them with NaN, with either path of the
-    rows; PERF.md §7)."""
+    interpreter (the rows', the activation's, and the grouped matmuls',
+    whose matrices' gradient multiplies the dead rows of the last live part
+    by zero): values and the gradients by x, the router and ALL the experts'
+    matrices equal the jnp path's. `w_down`'s too: its left operand is the
+    activation formed again by the backward's kernel, zeros from the count
+    to the tile's end where the grouped matmuls before it leave what they
+    find (NaN in the interpreter)."""
     c = _layer()
     sl = slice(held[0], held[0] + held[1])
 
@@ -273,12 +280,78 @@ def test_a_held_layer_on_kernels_alone_is_the_layer_on_jnp(
     _interpret_every_kernel(monkeypatch)
     got = both(c["x"], c["router"], w)
     assert int(got[0][1]) == int(want[0][1])
-    for side in (got, want):
-        side[1][2].pop("w_down")
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         assert bool(jnp.all(jnp.isfinite(a)))
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+def _dense_held(x, rows, router, w_in, w_out, bias, held, k):
+    """A latent layer that holds `held` of its relu2 experts, the plain
+    way: every held expert on every token, masked by the router's choice
+    (`route` itself, so the choice is the layer's)."""
+    logits = x.astype(jnp.float32) @ router
+    weights, experts, _ = expert.route(logits, k, True, "sigmoid", bias, 5.0)
+    y = jnp.zeros_like(rows)
+    for e in range(held[0], held[0] + held[1]):
+        w = jnp.sum(jnp.where(experts == e, weights, 0.0), axis=1)
+        out = expert.relu2(rows @ w_in[e - held[0]]) @ w_out[e - held[0]]
+        y = y + w[:, None] * out
+    return y
+
+
+@pytest.mark.parametrize("path", ["kernels", "jnp"])
+@pytest.mark.parametrize("held", [(0, 2), (5, 3), (14, 2)])
+def test_few_held_of_many_choices_never_fills_the_cut_buffer_up(
+        small_tiles, monkeypatch, held, path):
+    """Nemotron's shape: more choices a token (k = 6 of 16) than experts
+    held, relu2 experts without a gate in a latent. The buffer is count x T
+    rows from the dispatch to the combine (a token picks an expert once),
+    never k x T: the output and the gradients by the tokens, the latent
+    rows, the router and both matrices equal the dense masked
+    computation's, on the kernels alone and on jnp."""
+    k, E, R, F, D = 6, 16, 128, 128, 32
+    ks = jax.random.split(jax.random.PRNGKey(held[0]), 7)
+    args = dict(
+        x=jax.random.normal(ks[0], (T, D)),
+        rows=jax.random.normal(ks[1], (T, R)),
+        router=jax.random.normal(ks[2], (D, E)),
+        w_in=0.3 * jax.random.normal(ks[3], (held[1], R, F)),
+        w_out=0.3 * jax.random.normal(ks[4], (held[1], F, R)))
+    bias = 0.1 * jax.random.normal(ks[5], (E,))
+    g = jax.random.normal(ks[6], (T, R))
+
+    def layer(a):
+        y, stats = expert.moe_ffn(
+            a["x"], a["router"], a["w_in"], a["w_out"], capacity_factor=None,
+            act=expert.relu2, top_k=k, scoring="sigmoid", bias=bias,
+            scale=5.0, held=held, rows=a["rows"])
+        return jnp.sum(g * y), stats["held"]
+
+    def dense(a):
+        return jnp.sum(g * _dense_held(a["x"], a["rows"], a["router"],
+                                       a["w_in"], a["w_out"], bias, held, k))
+
+    if path == "kernels":
+        _interpret_every_kernel(monkeypatch)
+    jaxpr = str(jax.make_jaxpr(jax.grad(lambda a: layer(a)[0]))(args))
+    cut, whole = held[1] * T, k * T
+    assert cut < whole
+    assert re.search(r"\[%d,%d\]" % (cut, R), jaxpr)
+    assert not re.search(r"\[%d,(%d|%d)\]" % (whole, R, F), jaxpr), \
+        "a k x T-row array between the dispatch and the combine"
+    for name in profile.MOE_ROWS_KERNELS + profile.MOE_ACT_KERNELS:
+        assert (("name=%s" % name) in jaxpr) == (path == "kernels"), name
+    (loss, n_live), grads = jax.value_and_grad(layer, has_aux=True)(args)
+    want, want_grads = jax.value_and_grad(dense)(args)
+    assert 0 < int(n_live) <= cut
+    np.testing.assert_allclose(loss, want, rtol=1e-4)
+    for key in sorted(args):
+        assert bool(jnp.all(jnp.isfinite(grads[key]))), key
+        np.testing.assert_allclose(
+            grads[key], want_grads[key], rtol=1e-4,
+            atol=1e-4 * float(jnp.max(jnp.abs(want_grads[key]))),
+            err_msg=key)
 
 
 @pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
@@ -320,3 +393,39 @@ def test_a_layer_that_holds_every_expert_on_kernels_alone_is_the_layer_on_jnp(
                         jax.tree_util.tree_leaves(want)):
             assert bool(jnp.all(jnp.isfinite(a)))
             np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("held", [(3, 3), None], ids=["held", "all"])
+def test_a_layer_forms_its_groups_visits_once_for_all_its_matmuls(
+        small_tiles, monkeypatch, held):
+    """A gated layer has three grouped products over the same rows and of
+    each two gradients: what their kernels are told of the groups
+    (`grouped_matmul.layer_visits`: both forms of `visits`, and what the
+    two share once) is formed ONCE a layer, forward and backward together,
+    where every call formed its own (nine traces of twenty small
+    instructions a layer)."""
+    c = _layer()
+    sl = slice(None) if held is None else slice(held[0], held[0] + held[1])
+    calls = {"groups": 0, "forms": []}
+    groups, forms = gm._groups_tiles, gm._visits
+
+    def counted_groups(*args):
+        calls["groups"] += 1
+        return groups(*args)
+
+    def counted_forms(of, visit_empty):
+        calls["forms"].append(visit_empty)
+        return forms(of, visit_empty)
+
+    def loss(x, w):
+        y, _ = expert.moe_ffn(
+            x, c["router"], w["w_up"][sl], w["w_down"][sl],
+            capacity_factor=None, top_k=K, w_gate=w["w_gate"][sl], held=held)
+        return jnp.sum(c["g"] * y)
+
+    _interpret_every_kernel(monkeypatch)
+    monkeypatch.setattr(gm, "_groups_tiles", counted_groups)
+    monkeypatch.setattr(gm, "_visits", counted_forms)
+    w = {k: c[k] for k in ("w_up", "w_down", "w_gate")}
+    jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(c["x"], w)
+    assert calls == {"groups": 1, "forms": [False, True]}
