@@ -75,6 +75,10 @@ SIZES = dict(
     # and a clean copy of 4096 tokens (8192 positions) under the block mask
     # the kernels take by rule; dK/dV resident and held by the q block.
     attn_block_diffusion=(1, 32, 4, 4096, 128, 4),
+    # (B, H, G, L, D, window) of a window layer's attention at the
+    # benchmark's `mellum12b_1chip`: the same call under the causal band
+    # the kernels take by rule, a query on itself and the 1023 keys before.
+    attn_band=(1, 32, 4, 8192, 128, 1024),
     # (n, T, C, K) of a hyper-connection at the benchmark's `xing29b_1chip`:
     # four streams of 4096 tokens, 3584 wide, onto phi's 24 columns.
     hc=(4, 4096, 3584, 24),
@@ -372,9 +376,11 @@ def attention_case(B, H, G, L, D, dtype, seed, mask=None):
     _blockwise_reference doing the same: (name, kernel, reference,
     (q, k, v, cotangent)), both jitted and returning (out, dq, dk, dv).
     `mask`: a rule in place of the causal triangle (L counts all its
-    positions); the reference is then `benchmark/references/sdar.py`'s
-    dense masked softmax, its mask made from the rule's three clauses, a
-    block of query rows at a time."""
+    positions); the reference is then the dense masked softmax of the
+    benchmark's plain reference of the rule's model (`references/sdar.py`:
+    the mask from the block-diffusion rule's three clauses;
+    `references/mellum.py`: the band's two comparisons), a block of query
+    rows at a time."""
     import jax
     import jax.numpy as jnp
 
@@ -397,11 +403,13 @@ def attention_case(B, H, G, L, D, dtype, seed, mask=None):
             # Not the program's `rule.visible`: the dense mask written
             # from the rule's three clauses, as the benchmark's reference
             # has it, in float32.
-            from benchmark.references import sdar
+            from benchmark.references import mellum, sdar
             f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
-            return jax.vmap(lambda q, k, v: sdar.attention(
-                q, k, v, mask.length, mask.block, 0))(
-                    f32(q), f32(k), f32(v)).astype(q.dtype)
+            dense = (lambda q, k, v: mellum.attention(  # noqa: E731
+                q, k, v, mask.window)) if hasattr(mask, "window") else (
+                    lambda q, k, v: sdar.attention(
+                        q, k, v, mask.length, mask.block, 0))
+            return jax.vmap(dense)(f32(q), f32(k), f32(v)).astype(q.dtype)
         t = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
         return t(_blockwise_reference(t(q), t(k), t(v), D ** -0.5, True))
 
@@ -413,8 +421,8 @@ def attention_case(B, H, G, L, D, dtype, seed, mask=None):
 
     name = "B%d H%d G%d L%d D%d%s %s" % (
         B, H, G, L, D,
-        "" if mask is None else " %s(%d, %d)" % ((type(mask).__name__,)
-                                                + tuple(mask)),
+        "" if mask is None else " %s%r" % (type(mask).__name__,
+                                            tuple(mask)),
         jnp.dtype(dtype).name)
     return name, both(kernel), both(reference), (q, k, v, w)
 
@@ -886,6 +894,20 @@ def phase_kernels(args):
         attention_case(*shape, args.seed + len(SIZES["attn"]), mask=rule),
         TOL["attn_bf16"], flash_kernels(*shape, mask=rule))
     backward_forms_agree(B, H, G, 2 * L, D, jnp.bfloat16, args.seed, rule,
+                         TOL["attn_bf16"])
+    from horovod_tpu.ops import BandMask
+
+    B, H, G, L, D, window = SIZES["attn_band"]
+    rule = BandMask(window)
+    shape = (B, H, G, L, D, jnp.bfloat16)
+    print("  a causal band, each of %d queries on itself and the %d keys "
+          "before it:" % (L, window - 1), flush=True)
+    print_flash_plan(*shape, mask=rule)
+    attention_vs_reference(
+        attention_case(*shape, args.seed + len(SIZES["attn"]) + 1,
+                       mask=rule),
+        TOL["attn_bf16"], flash_kernels(*shape, mask=rule))
+    backward_forms_agree(B, H, G, L, D, jnp.bfloat16, args.seed + 1, rule,
                          TOL["attn_bf16"])
 
     hc_stat_vs_jnp(*SIZES["hc"], jnp.bfloat16, args.seed)

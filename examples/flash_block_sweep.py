@@ -21,13 +21,18 @@ for the gridded path) were read off it.
 
 Usage: python examples/flash_block_sweep.py [--B 2 --L 2048 --H 16 --D 128]
            [--path all|resident|split|q-held|gridded] [--kernels all|bwd]
-           [--mask-block N]
+           [--mask-block N | --window W]
 `--mask-block N`: block-diffusion training's mask by rule in place of the
 causal triangle (L counts both copies of the sequence, blocks of N tokens);
 the forward and dQ take a rule resident only, so `gridded` then grids dK/dV
 alone. The block-diffusion cell's call (`sdar30b_1chip`):
     --B 1 --H 32 --G 4 --L 8192 --mask-block 4 --path q-held,split \
     --kernels bwd --bqp 64,128 --bk 512,1024
+`--window W`: the causal band of sliding-window attention (`ops.BandMask`:
+a query sees itself and the W - 1 keys before it), a rule likewise. The
+window layers' call of `mellum12b_1chip`:
+    --B 1 --H 32 --G 4 --L 8192 --window 1024 --path q-held \
+    --bqp 64,128,256 --bk 128,256,512,1024
 GQA/MQA (--G < --H) sweeps the grouped-rows layout: the q-block
 candidates become bqp*group rows. The `_grouped_blocks` policy was
 tuned from this sweep at two points — B2 H6 G2 L8192 D128 (1536/512)
@@ -112,6 +117,10 @@ def main():
                     help="block-diffusion's mask by rule over 2 x L/2 "
                          "positions in blocks of this many tokens, in "
                          "place of the causal triangle")
+    ap.add_argument("--window", type=int, default=0,
+                    help="the causal band by rule: a query sees itself and "
+                         "this many keys less one before it, in place of "
+                         "the causal triangle")
     ap.add_argument("--kernels", default="all", choices=("all", "bwd"),
                     help="bwd: leave the forward kernel out")
     ap.add_argument("--bqp", default="128,256,512",
@@ -122,8 +131,11 @@ def main():
     B, L, H, D = args.B, args.L, args.H, args.D
     G = args.G or H
     group = H // G
+    if args.mask_block and args.window:
+        ap.error("--mask-block and --window are one rule each: give one")
     rule = (fa.BlockDiffusionMask(L // 2, args.mask_block)
-            if args.mask_block else None)
+            if args.mask_block else
+            fa.BandMask(args.window) if args.window else None)
     causal = rule is None
 
     rng = np.random.RandomState(0)

@@ -111,6 +111,17 @@ class TransformerConfig:
     # are the ROWS of the sequence handed in; `positions` (rotary) are the
     # caller's and may repeat. attention="dense" or "flash".
     attention_mask: Optional[Any] = None
+    # An attention kind a layer of the two-branch block, `num_layers` of
+    # "full" | "window" (Gemma's and Qwen's `layer_types` of
+    # `full_attention` / `sliding_attention`): a "window" layer's query sees
+    # itself and the `attention_window` - 1 keys before it
+    # (`ops.BandMask`), a "full" layer's every key before it. Both rotate
+    # by `rope_base`; `rope_yarn` rescales the FULL layers' frequencies
+    # alone (a window never reaches past the trained context). None: every
+    # layer is causal, or under `attention_mask`. attention="dense" or
+    # "flash".
+    attention_types: Optional[Tuple[str, ...]] = None
+    attention_window: Optional[int] = None
     norm_eps: float = 1e-6        # every RMSNorm's epsilon
     # Passes over the ONE stack of blocks, on the same weights (a looped
     # or universal transformer; Ouro's `total_ut_steps`): `norm_f` closes
@@ -152,7 +163,8 @@ class TransformerConfig:
     # rotary, the rotary key ONE a token for all heads; values are
     # `v_head_dim` wide. `head_dim`, `num_kv_heads` and `qk_norm` do not
     # apply. `rope_yarn` rescales the rotary slice's frequencies and the
-    # softmax scale.
+    # softmax scale (beside `attention_types`: the full layers' whole-head
+    # rotation of plain attention, cos and sin times its factor).
     kv_lora_rank: Optional[int] = None
     q_lora_rank: Optional[int] = None
     qk_nope_dim: int = 128
@@ -273,6 +285,7 @@ class TransformerConfig:
             ("mtp_depth", self.mtp_depth > 0),
             ("qk_norm='head'", self.qk_norm == "head"),
             ("attention_mask", self.attention_mask is not None),
+            ("attention_types", self.attention_types is not None),
             ("layer_types", self.layer_types is not None),
             ("rotary=False", not self.rotary),
             ("moe_latent_dim", self.moe_latent_dim is not None),
@@ -308,9 +321,13 @@ class TransformerConfig:
                              "'flash' and plain attention, not %r%s"
                              % (self.attention, " with kv_lora_rank"
                                 if self.kv_lora_rank is not None else ""))
-        if self.rope_yarn is not None and self.kv_lora_rank is None:
+        if (self.rope_yarn is not None and self.kv_lora_rank is None
+                and self.attention_types is None):
             raise ValueError("rope_yarn rescales latent attention's rotary "
-                             "slice: give kv_lora_rank")
+                             "slice (give kv_lora_rank) or the full layers' "
+                             "rotation (give attention_types)")
+        if self.attention_types is not None:
+            self._check_attention_types()
         if self.hc_mult < 1:
             raise ValueError("hc_mult=%d: the residual path has at least "
                              "one stream" % self.hc_mult)
@@ -327,6 +344,40 @@ class TransformerConfig:
                              % (self.moe_act,))
         if self.layer_types is not None:
             self._check_layer_types()
+
+    def _check_attention_types(self):
+        """What an attention kind a layer cannot be placed beside, by
+        name."""
+        kinds = ("full", "window")
+        types = self.attention_types
+        if len(types) != self.num_layers or any(t not in kinds
+                                                for t in types):
+            raise ValueError("attention_types=%r: num_layers=%d kinds, each "
+                             "of %s" % (types, self.num_layers,
+                                        ", ".join(kinds)))
+        if "window" in types and (self.attention_window or 0) < 1:
+            raise ValueError("attention_types names a 'window' layer: give "
+                             "attention_window (the keys a query sees, "
+                             "itself among them)")
+        # The band is a rule of the flash kernels and of the dense form;
+        # the sequence-parallel paths know `causal` alone, one mask serves
+        # a stack under `attention_mask`, latent attention has its own
+        # rotation, a layer pattern's "attn" layers have no kind, nor do
+        # the streams' blocks and the prediction module's.
+        for field, on in (
+                ("attention=%r" % self.attention,
+                 self.attention in ("ring", "ulysses")),
+                ("attention_mask", self.attention_mask is not None),
+                ("kv_lora_rank", self.kv_lora_rank is not None),
+                ("layer_types", self.layer_types is not None),
+                ("hc_mult", self.hc_mult > 1),
+                ("mtp_depth", self.mtp_depth > 0)):
+            if on:
+                raise ValueError("attention_types cannot be combined with "
+                                 "%s (window and full layers are built for "
+                                 "the two-branch block's plain attention "
+                                 "under attention='dense' or 'flash')"
+                                 % field)
 
     def _check_layer_types(self):
         """What a layer pattern cannot be placed beside, by name."""
@@ -640,10 +691,17 @@ class HyperConnection(nn.Module):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    # The layer's kind under `attention_types` ("full" | "window"); None:
+    # the stack's one kind (causal, or `attention_mask`).
+    kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
+        mask = cfg.attention_mask
+        if self.kind == "window":
+            from horovod_tpu.ops import BandMask
+            mask = BandMask(cfg.attention_window)
         head_dim = cfg.head_dim or cfg.embed_dim // cfg.num_heads
         G = cfg.num_kv_heads or cfg.num_heads
         if cfg.num_heads % G:
@@ -665,7 +723,16 @@ class Attention(nn.Module):
                 flat = t.reshape(t.shape[:-2] + (-1,))
                 return _rms_norm(cfg, name)(flat).reshape(t.shape)
             q, k = whole(q, "q_norm"), whole(k, "k_norm")
-        if cfg.rotary:
+        if cfg.rotary and self.kind == "full" and cfg.rope_yarn is not None:
+            # YaRN on the whole head: cos and sin both times its factor, so
+            # a rotated q.k carries the factor's square.
+            yarn = cfg.rope_yarn
+            inv_freq = yarn_inv_freq(head_dim, cfg.rope_base, yarn)
+            m = (yarn_mscale(yarn.factor, yarn.mscale)
+                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+            q = _rotary_freq(q, positions, inv_freq, m)
+            k = _rotary_freq(k, positions, inv_freq, m)
+        elif cfg.rotary:
             q = _rotary(q, positions, cfg.rope_base)
             k = _rotary(k, positions, cfg.rope_base)
         if cfg.attention == "ring":
@@ -675,8 +742,8 @@ class Attention(nn.Module):
             o = ulysses_attention(q, k, v, cfg.sp_axis, causal=True)
         elif cfg.attention == "flash":
             from horovod_tpu.ops import flash_attention
-            if cfg.attention_mask is not None:
-                o = flash_attention(q, k, v, mask=cfg.attention_mask)
+            if mask is not None:
+                o = flash_attention(q, k, v, mask=mask)
             else:
                 o = flash_attention(q, k, v, causal=True)
         else:
@@ -689,9 +756,9 @@ class Attention(nn.Module):
             L = s.shape[-1]
             rows = lax.broadcasted_iota(jnp.int32, (L, L), 0)
             cols = lax.broadcasted_iota(jnp.int32, (L, L), 1)
-            mask = rows >= cols if cfg.attention_mask is None \
-                else cfg.attention_mask.visible(rows, cols)
-            s = jnp.where(mask[None, None], s, -jnp.inf)
+            seen = rows >= cols if mask is None \
+                else mask.visible(rows, cols)
+            s = jnp.where(seen[None, None], s, -jnp.inf)
             p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
         out = nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), dtype=cfg.dtype,
@@ -801,6 +868,9 @@ class Block(nn.Module):
     # The layer's one mixer under `layer_types` ("ssm", "attn", "moe",
     # "mlp"); None: the two-branch block.
     kind: Optional[str] = None
+    # The two-branch block's attention kind under `attention_types` ("full"
+    # | "window"); None: the stack's one kind.
+    attention_kind: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, positions):
@@ -821,8 +891,15 @@ class Block(nn.Module):
         if cfg.hc_mult > 1:
             return _hyper_connected(cfg, self.moe, x, positions, attention,
                                     out)
-        x = x + out("norm1_out", attention(cfg, name="attn")(
-            _rms_norm(cfg, "norm1")(x), positions))
+        # A layer with a kind has its attention half under the kind's scope:
+        # the two kinds share a kernel name and a shape, and the trace tells
+        # them apart.
+        kind = self.attention_kind
+        with jax.named_scope(profile.ATTN_KINDS[kind]) if kind \
+                else contextlib.nullcontext():
+            x = x + out("norm1_out", attention(
+                cfg, name="attn", **({"kind": kind} if kind else {}))(
+                    _rms_norm(cfg, "norm1")(x), positions))
         h = _rms_norm(cfg, "norm2")(x)
         # `mlp` beside flax's `attn`: the profiler's scope for this half
         # of the block (hvd.profile), dense or routed; no module and no
@@ -952,8 +1029,10 @@ class Transformer(nn.Module):
             block = nn.remat(Block, policy=_keep_hc_stat()) \
                 if i < cfg.block_remat else Block
             kind = None if cfg.layer_types is None else cfg.layer_types[i]
+            new = {} if cfg.attention_types is None \
+                else {"attention_kind": cfg.attention_types[i]}
             blocks.append(block(cfg, moe=moe, kind=kind,
-                                name="block_%d" % i))
+                                name="block_%d" % i, **new))
         norm_f = _rms_norm(cfg, "norm_f")
         if cfg.hc_mult > 1 or cfg.mtp_depth:
             return _streams(cfg, positions, return_hidden, x, blocks, norm_f)

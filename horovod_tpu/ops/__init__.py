@@ -30,4 +30,5 @@ Beside them, in jnp (XLA's fusions own it until a trace says otherwise):
   carried by a scan, f32 decays and carry under bf16 operands.
 """
 
-from .flash_attention import BlockDiffusionMask, flash_attention  # noqa: F401
+from .flash_attention import (  # noqa: F401
+    BandMask, BlockDiffusionMask, flash_attention)

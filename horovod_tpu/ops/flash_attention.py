@@ -73,8 +73,9 @@ shared key's gradient leaves the kernel a head at a time and is summed over
 the heads outside. The gridded kernels have no such form: a call whose plan
 is not resident takes the blockwise jnp path.
 
-A mask by RULE over (query position, key position) (`mask=`; the first
-rule is `BlockDiffusionMask`): the rule says, for a tile of queries, which
+A mask by RULE over (query position, key position) (`mask=`; the rules
+are `BlockDiffusionMask` and `BandMask`, the causal band of sliding-window
+attention): the rule says, for a tile of queries, which
 runs of key tiles hold a visible pair and which of those need the mask
 pass, as closed forms in the tile's position (`key_runs`, `query_runs`). The
 resident kernels loop over those runs alone (a tile the rule empties is
@@ -324,11 +325,12 @@ def _q_index_map(bqp, bk, causal):
     return lambda b, j, i: (b, jnp.maximum(i, (j * bk) // bqp), 0)
 
 
-def _rule_q_index_map(rule, bqp, bk):
+def _rule_q_index_map(rule, bqp, bk, positions):
     """`_q_index_map` for a rule: the q-block index clamped into the runs
     of the step's k block (`_clamp_to_runs`)."""
     return lambda b, j, i: (
-        b, _clamp_to_runs(i, rule.query_runs(j * bk, bk, bqp)), 0)
+        b, _clamp_to_runs(i, rule.query_runs(j * bk, bk, bqp, positions)),
+        0)
 
 
 def _require_rows_block(L, preferred, group, what):
@@ -364,7 +366,14 @@ def _check_blocks(rows, L, bq, bk, group):
 # that hold a visible pair: (first tile, one past the last, whether the run
 # needs the mask pass), ascending, a fixed number of them, empty where
 # first >= end. The runs are evaluated with `xp=jnp` on the kernel's program
-# ids and with `xp=np` on all tiles at once by `flash_plan`.
+# ids and with `xp=np` on all tiles at once by `flash_plan`. `query_runs` is
+# told the call's positions too: where the last viewer of a key lies is the
+# sequence's end, which a rule need not carry.
+#
+# What a rule owes the PLAN, said once for all of them: `tiled(positions)`,
+# the length a kernel's blocks must divide (the call's positions, or a part
+# of them no tile may straddle), and `check(positions, bqp, bk)`, which
+# refuses a call or a pair of blocks the rule does not describe.
 
 def _cdiv(a, n):
     return (a + n - 1) // n
@@ -422,11 +431,12 @@ class BlockDiffusionMask(collections.namedtuple("BlockDiffusionMask",
                 (L // bk, (L + all_hi) // bk, False),
                 ((L + all_hi) // bk, _cdiv(L + any_hi, bk), True))
 
-    def query_runs(self, k_lo, n, bqp, xp=jnp):
+    def query_runs(self, k_lo, n, bqp, positions, xp=jnp):
         """The query tiles (of `bqp` positions) that see the keys [k_lo,
         k_lo + n), as five runs: of noisy keys, the noisy tiles of their
         own blocks (masked); of clean keys, the noisy tiles that see some of
-        them (masked) and all of them, then the clean tiles likewise."""
+        them (masked) and all of them, then the clean tiles likewise.
+        `positions` is 2 x length (`check`) and says nothing new."""
         L, b = self
         noisy = k_lo < L
         c = xp.where(noisy, k_lo, k_lo - L)
@@ -446,6 +456,10 @@ class BlockDiffusionMask(collections.namedtuple("BlockDiffusionMask",
                  True),
                 (clean(_cdiv(L + last, bqp)), end, False))
 
+    def tiled(self, positions):
+        """A half: no tile may straddle the noisy and the clean copy."""
+        return self.length
+
     def check(self, positions, bqp, bk):
         """Refuses a call this rule does not describe."""
         L, b = self
@@ -458,6 +472,62 @@ class BlockDiffusionMask(collections.namedtuple("BlockDiffusionMask",
                 "flash blocks of %d query and %d key positions must divide "
                 "%r's length: no tile may straddle the two halves"
                 % (bqp, bk, self))
+
+
+class BandMask(collections.namedtuple("BandMask", "window")):
+    """The causal band of sliding-window attention: query i sees key j iff
+
+        j <= i  and  i - j < window
+
+    itself and the `window` - 1 keys before it. A window of the sequence's
+    length or more is the causal triangle. A tile of queries sees a run of
+    key tiles cut at the band's lower edge, whole tiles between, and a run
+    cut on the diagonal; any blocks that tile the sequence will do."""
+    __slots__ = ()
+
+    def visible(self, rows, cols, xp=jnp):
+        """Elementwise, as `BlockDiffusionMask.visible`: two comparisons
+        against thin vectors and one `and`."""
+        return (cols <= rows) & (cols > rows - self.window)
+
+    @staticmethod
+    def _runs(lo, whole_lo, whole_hi, hi, xp):
+        """[lo, hi) as a cut run, the whole tiles [whole_lo, whole_hi)
+        where there are any, and a cut run."""
+        whole_lo = xp.minimum(xp.maximum(whole_lo, lo), hi)
+        whole_hi = xp.minimum(xp.maximum(whole_hi, whole_lo), hi)
+        return ((lo, whole_lo, True), (whole_lo, whole_hi, False),
+                (whole_hi, hi, True))
+
+    def key_runs(self, q_lo, n, bk, xp=jnp):
+        """The key tiles (of `bk` positions) that the queries [q_lo, q_lo +
+        n) see: from the first query's oldest key to the last query's own.
+        Every one of them sees a tile whole that begins after the last
+        query's oldest key and ends at or before the first query."""
+        w = self.window
+        return self._runs(
+            xp.maximum(q_lo - w + 1, 0) // bk,
+            _cdiv(xp.maximum(q_lo + n - w, 0), bk), (q_lo + 1) // bk,
+            (q_lo + n - 1) // bk + 1, xp)
+
+    def query_runs(self, k_lo, n, bqp, positions, xp=jnp):
+        """The query tiles (of `bqp` positions) that see the keys [k_lo,
+        k_lo + n): from the first key's own to the last key's latest
+        viewer, or the sequence's end. A tile sees every one of them whole
+        that begins at or after the last key and ends within the first
+        key's window."""
+        w = self.window
+        return self._runs(
+            k_lo // bqp, _cdiv(k_lo + n - 1, bqp), (k_lo + w) // bqp,
+            xp.minimum(_cdiv(k_lo + n + w - 1, bqp), positions // bqp), xp)
+
+    def tiled(self, positions):
+        """The whole call: the band has no seam."""
+        return positions
+
+    def check(self, positions, bqp, bk):
+        if self.window < 1:
+            raise ValueError("%r: a query sees itself at least" % (self,))
 
 
 def _rule_mask(s, rule, q_off, kv_off, fill, group=1):
@@ -506,7 +576,8 @@ def _rule_tiles(rule, held, positions, bqp, bk):
     each k block where it holds a k block (`held` "k"), else the key tiles
     of each q block."""
     if held == "k":
-        runs = rule.query_runs(np.arange(0, positions, bk), bk, bqp, np)
+        runs = rule.query_runs(np.arange(0, positions, bk), bk, bqp,
+                               positions, np)
     else:
         runs = rule.key_runs(np.arange(0, positions, bqp), bqp, bk, np)
     def tiles(masked_only):
@@ -624,7 +695,19 @@ def _resident_blocks(D, L, group, kernel):
     the block-diffusion rule (1 x 32 on 4 x 8192 x 128) (1024, 512) 6.34,
     (2048, 512) 6.47, (512, 512) 6.93, (1024, 1024) 7.18, as two 9.44;
     group 1 (1 x 16 x 8192 x 128) (512, 1024) 4.64, (512, 512) 5.12, as
-    two 6.86."""
+    two 6.86.
+
+    Under the causal band the forward and the q-held backward keep the
+    same table (PR 52, `--window 1024 --path q-held`; group 8, 1 x 32 on 4
+    x 8192 x 128; ms a call, forward / backward): the plan's (1024, 512)
+    3.25 / 4.16; (512, 512) 3.13 / 4.41, (2048, 512) 3.31 / 4.30, (512, 256)
+    3.62 / 4.57, (1024, 256) 3.42 / 4.15, (2048, 256) 3.34 / 4.02, (512,
+    128) 4.74 / 6.87, (1024, 128) 4.18 / 6.05, (2048, 128) 4.29 / 6.22. A
+    narrower k block wastes less of the two cut runs (1151 visible keys a
+    query tile are 3-4 tiles of 512, 5-6 of 256, 10 of 128) and loses all
+    the same from 128 down: a loop turn's own cost on a [1024, 128] x [128,
+    bk] product; 256 and 512 tie to the reading's spread, and the table
+    stays one."""
     if group == 1:
         return (512, 1024) if kernel in _K_HELD else (512, 512)
     if D > 64:
@@ -659,9 +742,8 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
     def k_side(n):
         return _vmem(n, D, n_k * isz) + _vmem(n, D2, n_k2 * isz)
 
-    # Under a rule the blocks divide ITS length, so that no tile straddles
-    # the halves of the sequence.
-    tiled = L if rule is None else rule.length
+    # Under a rule the blocks divide what IT says they must (`tiled`).
+    tiled = L if rule is None else rule.tiled(L)
 
     def blocks(preferred):
         bq = block_q or _pick_rows_block(tiled, preferred[0], group)
@@ -748,10 +830,12 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     the result is ``{}``, no kernel at all, and the call is the blockwise
     jnp form.
 
-    ``mask``: a rule (`BlockDiffusionMask`) in place of the causal
-    triangle; L counts ALL positions of the call (2 x the rule's length).
-    The same choice of path and kernels, with blocks that divide the rule's
-    length, and every plan says how many score tiles its kernel visits,
+    ``mask``: a rule in place of the causal triangle; L counts ALL
+    positions of the call (`BlockDiffusionMask`: 2 x the rule's length;
+    `BandMask`: the sequence). The same choice of path and kernels, with
+    blocks that divide what the rule says they must (`tiled`: a half of the
+    block-diffusion pair, the whole of a band's sequence), and every plan
+    says how many score tiles its kernel visits,
     masks and skips. The forward and dQ take a rule in their resident form
     only, the one-kernel backward in both of its, dK/dV resident or gridded
     (at D=128 in bf16 with 8 heads a kv head and 8192 positions: the
@@ -871,7 +955,8 @@ def _walk_q(visit, carry, kj, bqp, bk, num_qb, causal, rule):
     """The q blocks k block `kj` is seen by: likewise."""
     if rule is None:
         return _walk_q_blocks(visit, carry, kj, bqp, bk, num_qb, causal)
-    return _walk_runs(visit, carry, rule.query_runs(kj * bk, bk, bqp))
+    return _walk_runs(visit, carry,
+                      rule.query_runs(kj * bk, bk, bqp, num_qb * bqp))
 
 
 def _mask_tile(s, rule, q_off, kv_off, group):
@@ -1650,7 +1735,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     # the q blocks of its runs for this k block (`_rule_q_index_map`
     # fetches no others), the mask pass on the runs that ask for it.
     if rule is not None:
-        runs = rule.query_runs(kj * block_k, block_k, bqp)
+        runs = rule.query_runs(kj * block_k, block_k, bqp, num_qb * bqp)
         visible = _in_runs(qi, runs)
     else:
         visible = (qi * bqp + (bqp - 1) >= kj * block_k) if causal \
@@ -1810,7 +1895,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
                                       else {"rule": rule}))
         k_im = lambda b, j, i: (b, j, 0)                    # noqa: E731
         q_im = _q_index_map(bqp, bk, causal) if rule is None \
-            else _rule_q_index_map(rule, bqp, bk)
+            else _rule_q_index_map(rule, bqp, bk, L)
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
         k_spec = pl.BlockSpec((None, bk, D), k_im)
@@ -2012,8 +2097,9 @@ def flash_attention(q, k, v, causal=True, scale=None, q_shared=None,
     ``mask``: a rule over (query position, key position) in place of
     ``causal`` (`BlockDiffusionMask(length, block)`, with L = 2 x length:
     a noisy and a clean copy of a sequence under block-diffusion training's
-    mask). The kernels compute the tiles the rule leaves non-empty and mask
-    only those it cuts (`flash_plan(..., mask=)` counts them); the second
+    mask; `BandMask(window)`: a query on itself and the window - 1 keys
+    before it). The kernels compute the tiles the rule leaves non-empty and
+    mask only those it cuts (`flash_plan(..., mask=)` counts them); the second
     score product is refused beside it.
 
     L must be a multiple of 128 to hit the Pallas kernel; other shapes
@@ -2050,8 +2136,8 @@ def flash_attention(q, k, v, causal=True, scale=None, q_shared=None,
 
     on_tpu = jax.default_backend() == "tpu"
     if mask is not None:
-        # The rule's kernels tile ITS length; no plan, no kernel.
-        kernel_ok = on_tpu and mask.length % BLOCK_Q == 0 and all(
+        # The rule's kernels tile what it says; no plan, no kernel.
+        kernel_ok = on_tpu and mask.tiled(L) % BLOCK_Q == 0 and all(
             flash_plan(B, H, L, D, group, q.dtype, backward, mask=mask)
             for backward in (False, True))
         out = _flash(qt, kt, vt, scale, False, False if kernel_ok else None,

@@ -107,6 +107,17 @@ HC_MIX = "hvd_hc_mix"
 HC_SCOPES = (HC, HC_MAP, HC_MIX)
 MTP = "hvd_mtp"
 
+# The attention half of a two-branch block whose layer has a kind
+# (`models/transformer.py`, `attention_types`), inside `BLOCK` and around
+# flax's `attn`: the norm before it, the projections, the per-head norms,
+# the kind's rotation, its flash kernels (under the band, or causal), the
+# output projection and the residual add. The two kinds run kernels of one
+# name and one shape; the scope tells them apart. Not in MODEL_SCOPES: the
+# half still reads as `hvd_block/attn`.
+ATTN_WINDOW = "hvd_attn_window"
+ATTN_FULL = "hvd_attn_full"
+ATTN_KINDS = {"window": ATTN_WINDOW, "full": ATTN_FULL}
+
 # Block-diffusion training (`models/block_diffusion.py`), inside FWD_BWD and
 # outside the model: the noise draw, the doubled ids, positions and row
 # weights (`block_diffusion_batch`), and the slice of the noisy half before

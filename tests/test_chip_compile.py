@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from chip_smoke import kernel_calls as _kernels, kernel_named as _named
-from horovod_tpu.ops import batch_norm
+from horovod_tpu.ops import BandMask, BlockDiffusionMask, batch_norm
 from horovod_tpu import profile
 from horovod_tpu.ops.flash_attention import (_flash, _pallas_forward_lse,
                                              flash_plan,
@@ -170,14 +170,16 @@ def test_causal_and_full_calls_lower_to_the_text_they_had(one_chip, B, H, G,
 # 32 heads on 4, a noisy and a clean copy of 4096 tokens, blocks of 4 (the
 # backward ONE kernel held by the q block since PR 49: `hvd_flash_bwd` in the
 # program's text and neither `hvd_flash_dq` nor `hvd_flash_dkv`); and a shape
-# short enough that the one kernel is held by the k block.
-@pytest.mark.parametrize("H,G,length,held", [(32, 4, 4096, "q"),
-                                             (16, 16, 1024, "k")])
-def test_ruled_flash_compiles_for_v5e(one_chip, H, G, length, held):
-    from horovod_tpu.ops import BlockDiffusionMask
-
-    D, S = 128, 2 * length
-    rule = BlockDiffusionMask(length, 4)
+# short enough that the one kernel is held by the k block. Beside each, the
+# same call under the causal band (`mellum12b_1chip`'s window layers: a
+# query on itself and the 1023 keys before it; and a window that is no
+# multiple of a tile).
+@pytest.mark.parametrize("H,G,S,rule,held", [
+    (32, 4, 8192, BlockDiffusionMask(4096, 4), "q"),
+    (16, 16, 2048, BlockDiffusionMask(1024, 4), "k"),
+    (32, 4, 8192, BandMask(1024), "q"), (16, 16, 2048, BandMask(300), "k")])
+def test_ruled_flash_compiles_for_v5e(one_chip, H, G, S, rule, held):
+    D = 128
 
     def fwd_bwd(q, k, v, g):
         out, vjp = jax.vjp(
