@@ -450,9 +450,12 @@ def print_flash_plan(B, H, G, L, D, dtype, shared_dim=0, mask=None):
     """Which path each flash kernel of this shape takes (`hvd.profile`);
     `shared_dim`: the width of a second score product on one shared key;
     `mask`: a rule in place of the causal triangle, whose plans count the
-    score tiles each kernel visits, masks and skips. A backward kernel's
-    line says which side a grid step holds a block of (the one kernel has a
-    resident form of either kind)."""
+    score tiles each kernel visits, masks and skips, and the key step at
+    which it takes a cut k block with one sub-tile in sight, with the
+    sub-tiles it visits and masks (`cut_k` under the k block, fewer
+    sub-tiles than four a tile: the one-kernel backward's walk engaged). A
+    backward kernel's line says which side a grid step holds a block of (the
+    one kernel has a resident form of either kind)."""
     from horovod_tpu import profile
 
     for backward in (False, True):
@@ -470,9 +473,13 @@ def print_flash_plan(B, H, G, L, D, dtype, shared_dim=0, mask=None):
                                " of a limit of %.0f" % (
                                    plan.vmem_limit_bytes / 2 ** 20),
                                "" if mask is None else
-                               "; tiles visited %d (masked %d), skipped %d"
+                               "; tiles visited %d (masked %d), skipped %d; "
+                               "a lone sub-tile is of %d keys: sub-tiles "
+                               "visited %d (masked %d)"
                                % (plan.tiles_visited, plan.tiles_masked,
-                                  plan.tiles_skipped)),
+                                  plan.tiles_skipped, plan.cut_k,
+                                  plan.subtiles_visited,
+                                  plan.subtiles_masked)),
                   flush=True)
 
 
@@ -503,8 +510,12 @@ def backward_forms_agree(B, H, G, L, D, dtype, seed, mask, tol):
     "q": k, v, dk and dv whole in VMEM, dQ carried by the loop, dK and dV
     summed there): its dQ, dK and dV against the two kernels with dK/dV
     GRIDDED on the same inputs, forced by a budget one byte short of what
-    the form holds. Both add a block's tiles in ascending order in f32 and
-    round once."""
+    the form holds. Both add in f32 and round once; dK and dV add a k
+    block's q tiles in ascending order in both forms. dQ's keys are summed
+    in another order in each since PR 53 (the one kernel by the rule's runs
+    in ascending order, a cut k block's lone sub-tile alone; dQ's own
+    kernel the whole k blocks first, then the cut ones): a difference in
+    dQ's last bits, where PR 52 read 0.0, is that order and no fault."""
     import jax
     import jax.numpy as jnp
 
@@ -542,8 +553,9 @@ def backward_forms_agree(B, H, G, L, D, dtype, seed, mask, tol):
     held, gridded = grads(plan.resident_bytes), grads(plan.resident_bytes - 1)
     errs = [rel_err(a, b) for a, b in zip(held, gridded)]
     check(max(errs) <= tol,
-          "%s held by the q block vs %s + gridded %s on the chip: dq %.2e dk "
-          "%.2e dv %.2e (max rel to max |gridded|, tol %.0e)"
+          "%s held by the q block vs %s + gridded %s on the chip: dq %.2e "
+          "(its keys summed in another order in each) dk %.2e dv %.2e (max "
+          "rel to max |gridded|, tol %.0e)"
           % ((profile.FLASH_BWD, profile.FLASH_DQ, profile.FLASH_DKV)
              + tuple(errs) + (tol,)))
 

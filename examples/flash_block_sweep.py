@@ -21,7 +21,7 @@ for the gridded path) were read off it.
 
 Usage: python examples/flash_block_sweep.py [--B 2 --L 2048 --H 16 --D 128]
            [--path all|resident|split|q-held|gridded] [--kernels all|bwd]
-           [--mask-block N | --window W]
+           [--mask-block N | --window W] [--cut-k 128,256,512]
 `--mask-block N`: block-diffusion training's mask by rule in place of the
 causal triangle (L counts both copies of the sequence, blocks of N tokens);
 the forward and dQ take a rule resident only, so `gridded` then grids dK/dV
@@ -33,6 +33,16 @@ a query sees itself and the W - 1 keys before it), a rule likewise. The
 window layers' call of `mellum12b_1chip`:
     --B 1 --H 32 --G 4 --L 8192 --window 1024 --path q-held \
     --bqp 64,128,256 --bk 128,256,512,1024
+`--cut-k`: under a rule, the widths of the sub-tile that the one-kernel
+backward held by the q block takes alone where a cut k block has one in sight
+(`flash_attention._CUT_K`; at the k block's own width it walks k blocks
+alone), each candidate pair of blocks at each; the forward's time does not
+follow it. The table in `_resident_blocks`' docstring is the two cells' calls
+at the plan's blocks:
+    --B 1 --H 32 --G 4 --L 8192 --mask-block 4 --path q-held \
+    --bqp "" --bk "" --cut-k 128,256,512
+    --B 1 --H 32 --G 4 --L 8192 --window 1024 --path q-held \
+    --bqp "" --bk "" --cut-k 128,256,512
 GQA/MQA (--G < --H) sweeps the grouped-rows layout: the q-block
 candidates become bqp*group rows. The `_grouped_blocks` policy was
 tuned from this sweep at two points — B2 H6 G2 L8192 D128 (1536/512)
@@ -121,6 +131,10 @@ def main():
                     help="the causal band by rule: a query sees itself and "
                          "this many keys less one before it, in place of "
                          "the causal triangle")
+    ap.add_argument("--cut-k", default="",
+                    help="under a rule: widths of the sub-tile the q-held "
+                         "backward takes alone, to try in turn, with commas "
+                         "(none: the program's own)")
     ap.add_argument("--kernels", default="all", choices=("all", "bwd"),
                     help="bwd: leave the forward kernel out")
     ap.add_argument("--bqp", default="128,256,512",
@@ -157,8 +171,11 @@ def main():
     by_path = forms(B, H, L, D, group, q.dtype, rule)
     paths = tuple(by_path) if args.path == "all" else tuple(
         args.path.split(","))
-    print("%9s %6s %6s | %9s %9s %9s %9s" % (
-        "path", "bq", "bk", "fwd ms", "dq ms", "dkv ms", "bwd ms"))
+    cuts = [int(x) for x in args.cut_k.split(",") if x] or [fa._CUT_K]
+    if rule is None and args.cut_k:
+        ap.error("--cut-k is of the walk of a rule's cut runs: give a rule")
+    print("%9s %6s %6s %6s | %9s %9s %9s %9s" % (
+        "path", "bq", "bk", "cut_k", "fwd ms", "dq ms", "dkv ms", "bwd ms"))
 
     def ms(probe):
         try:
@@ -177,7 +194,8 @@ def main():
             for bqp in (int(x) for x in args.bqp.split(",") if x)
             for bk in (int(x) for x in args.bk.split(",") if x)
             if not (rows % (bqp * group) or L % bk or L % bqp)]
-        for bq, bk in candidates:
+        for bq, bk, fa._CUT_K in [c + (cut,) for c in candidates
+                                  for cut in cuts]:
             def fwd(q, bq=bq, bk=bk):
                 return fa._pallas_forward_lse(
                     q, k, v, scale, causal, False, bq, bk, budget,
@@ -204,8 +222,9 @@ def main():
                 t_dkv = ms(lambda q: total(*bwd(q)[1:]))
                 t_bwd = (t_dq + t_dkv if isinstance(t_dq, float)
                          and isinstance(t_dkv, float) else "-")
-            print("%9s %6s %6s | %s" % (
+            print("%9s %6s %6s %6s | %s" % (
                 path, bq or "plan", bk or "plan",
+                plan[BWD].cut_k if BWD in plan and rule is not None else "-",
                 " ".join(cell(x) for x in (t_fwd, t_dq, t_dkv, t_bwd))),
                 flush=True)
 

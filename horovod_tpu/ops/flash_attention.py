@@ -82,10 +82,17 @@ resident kernels loop over those runs alone (a tile the rule empties is
 never computed, a tile it fills runs with no mask pass, a tile it cuts is
 masked by `visible`); the gridded dK/dV kernel gates the compute by the same
 runs and clamps its q-side block index into them, so an empty tile is not
-fetched either. `flash_plan(..., mask=)` counts the tiles from the same
-runs. The forward and dQ take a rule in their resident form only (k and v
-whole in VMEM: at D=128 up to 24576 positions); where that does not fit the
-call is the blockwise jnp form.
+fetched either. The resident kernels held by the q block walk a rule's
+three runs, mostly a tile or two long, so as to enter few loops: the forward
+and dQ, whose loops carry their state, take the runs of a kind in ONE loop
+(`_walk_runs_merged`); the one-kernel backward sums dQ of its block in VMEM
+scratch beside dK and dV, its loops carry nothing, and it asks the rule for
+its runs at a FINER key step too (`cut_k`, `_cut_k`): a cut k block of which
+the rule leaves one sub-tile in sight is a turn of that sub-tile
+(`_walk_cut_runs`). `flash_plan(..., mask=)` counts the tiles, and the
+sub-tiles, from the same runs. The forward and dQ take a rule in their
+resident form only (k and v whole in VMEM: at D=128 up to 24576 positions);
+where that does not fit the call is the blockwise jnp form.
 
 Backward: custom VJP over saved per-row log-sum-exp (FlashAttention-2
 style). On non-TPU backends the same kernels run in Pallas interpret
@@ -548,6 +555,89 @@ def _walk_runs(visit, carry, runs):
     return carry
 
 
+def _runs_by_kind(runs):
+    """`runs` as at most two: (masked, how many tiles, the tile at turn t),
+    the whole runs' tiles laid end to end, ascending, then the masked runs'
+    (the tile at a turn: a chain of selects over the runs of the kind)."""
+    for kind in (False, True):
+        group = [(lo, hi) for lo, hi, masked in runs if masked == kind]
+        if not group:
+            continue
+        counts = [jnp.maximum(hi - lo, 0) for lo, hi in group]
+        starts = [sum(counts[:r]) for r in range(len(group))]
+
+        def tile(t, group=group, counts=counts, starts=starts):
+            j = group[-1][0] + t - starts[-1]
+            for r in reversed(range(len(group) - 1)):
+                j = jnp.where(t < starts[r] + counts[r],
+                              group[r][0] + t - starts[r], j)
+            return j
+
+        yield kind, sum(counts), tile
+
+
+def _walk_runs_merged(visit, carry, runs):
+    """`_walk_runs` with ONE loop over the tiles of all whole runs, then one
+    over those of all masked runs (`_runs_by_kind`). For a kernel whose
+    loops CARRY its state (the forward's accumulator, running max and sum:
+    1.5 MiB of values that live in VMEM; dQ's): on the v5e every loop such a
+    kernel enters costs it about 0.85 us whatever it runs, as much as half a
+    tile, and a rule's three runs are mostly a tile or two long
+    (`_resident_blocks`). A row's keys are then summed whole tiles first;
+    every masked turn keeps the guard for a row it leaves empty."""
+    for masked, count, tile in _runs_by_kind(runs):
+        carry = lax.fori_loop(
+            0, count, lambda t, c, masked=masked, tile=tile: visit(
+                tile(t), c, masked), carry)
+    return carry
+
+
+def _seen_of_tile(j, fine, ratio, xp=jnp):
+    """Of k block `j`, a whole number `ratio` of sub-tiles: how many of its
+    sub-tiles the runs `fine` (in sub-tile units) hold, and the first of
+    them."""
+    count, first = 0, 2 ** 30
+    for lo, hi, _ in fine:
+        a = xp.maximum(lo, j * ratio)
+        b = xp.minimum(hi, (j + 1) * ratio)
+        count = count + xp.maximum(b - a, 0)
+        first = xp.where(b > a, xp.minimum(first, a), first)
+    return count, first
+
+
+def _walk_cut_runs(visit, runs, fine, bk, cut_k):
+    """`_walk_runs_merged` for a kernel that can take a key tile at any
+    sub-tile of the k block (`cut_k` keys, a whole number of them a k block;
+    k and v whole in VMEM) and whose loops carry NOTHING (its sums are in
+    VMEM scratch): ``visit(j, masked, width)`` at SUB-tile `j`, of `width`
+    keys, a k block's or a sub-tile's. `runs` are the rule's in k blocks,
+    `fine` the same rule's in sub-tiles. A whole k block is a turn. So is a
+    cut one, but where the rule leaves ONE of its sub-tiles in sight (`fine`
+    holds no other of it): that turn is of the sub-tile alone, its share of
+    every product and pass. A k block with more in sight stays one turn: on
+    the v5e a turn of 128 keys costs three quarters of one of 512 (a turn's
+    own cost: `_resident_blocks`). Three traced bodies: whole, cut, lone."""
+    ratio = bk // cut_k
+
+    def loop(lo, hi, body):
+        lax.fori_loop(lo, hi, lambda j, c: body(j) or c, 0)
+
+    for masked, count, tile in _runs_by_kind(runs):
+        if not masked:
+            loop(0, count, lambda t, tile=tile: visit(tile(t) * ratio, False,
+                                                      bk))
+            continue
+
+        def turn(t, tile=tile):
+            j = tile(t)
+            seen, first = _seen_of_tile(j, fine, ratio)
+            alone = (seen == 1).astype(jnp.int32)
+            loop(0, alone, lambda _: visit(first, True, cut_k))
+            loop(alone, 1, lambda _: visit(j * ratio, True, bk))
+
+        loop(0, count, turn)
+
+
 def _in_runs(i, runs, masked_only=False):
     """Whether tile `i` lies in one of `runs` (in a masked one)."""
     hit = i < 0
@@ -590,6 +680,28 @@ def _rule_tiles(rule, held, positions, bqp, bk):
             (positions // bqp) * (positions // bk) - visited)
 
 
+def _rule_subtiles(rule, positions, bqp, bk, cut_k):
+    """(visited, masked) score SUB-tiles ([bqp, cut_k]) of ONE (batch, kv
+    head) of a kernel that walks `rule` by `_walk_cut_runs`, from the runs it
+    walks: a k block's worth a turn, but one for a cut k block with one
+    sub-tile in sight."""
+    ratio = bk // cut_k
+    q_lo = np.arange(0, positions, bqp)  # every q tile at once
+    fine = rule.key_runs(q_lo, bqp, cut_k, np)
+    visited = masked = 0
+    for lo, hi, cut in rule.key_runs(q_lo, bqp, bk, np):
+        lo, hi = (np.broadcast_to(x, q_lo.shape) for x in (lo, hi))
+        if not cut:
+            visited += int(np.sum(np.maximum(hi - lo, 0))) * ratio
+            continue
+        for j in (lo + d for d in range(int(np.max(hi - lo, initial=0)))):
+            lone = _seen_of_tile(j, fine, ratio, np)[0] == 1
+            turns = int(np.sum(np.where(j < hi, np.where(lone, 1, ratio), 0)))
+            visited += turns
+            masked += turns
+    return visited, masked
+
+
 # --- the plan: resident or gridded, per kernel ----------------------------
 #
 # One algorithm with one parameter that follows from the call's shapes: is
@@ -611,8 +723,9 @@ _DEFAULT_VMEM_LIMIT = 16 * 2 ** 20
 FlashKernelPlan = collections.namedtuple(
     "FlashKernelPlan",
     "path held block_q block_k grid grid_steps resident_bytes vmem_bytes "
-    "vmem_limit_bytes tiles_visited tiles_masked tiles_skipped",
-    defaults=(None, None, None))
+    "vmem_limit_bytes tiles_visited tiles_masked tiles_skipped "
+    "cut_k subtiles_visited subtiles_masked",
+    defaults=(None,) * 6)
 FlashKernelPlan.__doc__ = """How one flash kernel of a call runs.
 
 path: "resident" (grid (B*G, blocks); the other sequence whole in VMEM,
@@ -635,7 +748,17 @@ default (None: the default itself, which every gridded block table
 fits). tiles_visited / tiles_masked / tiles_skipped (with `mask=` only, else
 None): the [block_q // group, block_k] score tiles of one call, over all
 batch x kv heads, that the kernel computes, that it computes with the
-rule's mask pass, and that it neither computes nor fetches."""
+rule's mask pass, and that it neither computes nor fetches: a k block the
+kernel computes any part of counts as visited, one it masks any part of as
+masked. cut_k / subtiles_visited / subtiles_masked (with `mask=` only): the
+key step at which the kernel takes a cut k block with one sub-tile in sight
+(`_cut_k`: under block_k in the one-kernel backward held by the q block; else
+block_k itself: the kernel walks k blocks alone), and the [block_q // group,
+cut_k] SUB-tiles it computes and masks, from the runs it walks: a k block's
+worth a turn, one for such a turn. The area under the mask pass is
+tiles_masked x block_k keys a query tile by k blocks alone and
+subtiles_masked x cut_k as walked; cut_k ==
+block_k reads subtiles_* == tiles_*."""
 
 
 def _vmem(rows, cols, itemsize):
@@ -661,6 +784,11 @@ _K_HELD = (profile.FLASH_DKV, profile.FLASH_BWD)
 # in the order `flash_plan` tries them (tests and the block sweep narrow it
 # to reach the second, or the two kernels, where the first fits).
 _BWD_HELD = ("k", "q")
+# The key step at which the one-kernel backward held by the q block walks a
+# rule's cut runs, where it divides the k block (`_cut_k`; tests and the block
+# sweep set it to reach a finer or a coarser walk: at the k block's own width
+# the walk is by k blocks alone). Swept on the v5e: `_resident_blocks`.
+_CUT_K = 256
 
 
 def _resident_blocks(D, L, group, kernel):
@@ -707,12 +835,54 @@ def _resident_blocks(D, L, group, kernel):
     query tile are 3-4 tiles of 512, 5-6 of 256, 10 of 128) and loses all
     the same from 128 down: a loop turn's own cost on a [1024, 128] x [128,
     bk] product; 256 and 512 tie to the reading's spread, and the table
-    stays one."""
+    stays one.
+
+    How the kernels held by the q block WALK a rule's runs at those blocks
+    (PR 53; `--mask-block 4` / `--window 1024 --path q-held --cut-k`; the two
+    cells' calls, 1 x 32 on 4 x 8192 x 128; ms a call, forward / backward,
+    block diffusion then band). By k blocks, a loop a run, as PR 38-52:
+    4.60 / 6.33 and 3.25 / 4.16. What was asked for first, every cut run a
+    128-key sub-tile at a time and a whole run's remainder likewise: 4.98 /
+    6.65 and 4.05 / 5.14 (256: 4.78 / 6.21 and 3.64 / 4.18): SLOWER with a
+    quarter of the masked area and 15-25% less visited, because a turn's
+    cost hardly follows its width. With parts of that walk left out (timing
+    only): a 128-key turn is 1.75-1.9 us in a loop of several and 2.6-3.0 in
+    a loop of its own where a whole 512-key turn is 2.2 (the state, 1.5 MiB
+    of f32 with the running max and sum one lane wide, is read and written a
+    turn whatever the tile); the mask pass on it 0.07-0.37 us; and EVERY LOOP
+    ENTERED costs about 0.85 us in the forward (less in the backward, whose
+    carry is dQ alone), which is most of what a cut tile seemed to cost over
+    a whole one (4.0-5.3 us against 2.2: three loops a q tile, a tile or two
+    each). So: fewer loops, and narrow turns only where they replace a wide
+    one. The forward's state in VMEM scratch (the loops carry nothing): 5.95
+    / - and 3.63 / - with 128-lane max and sum, 5.54 and 3.36 one lane wide:
+    worse, it stays the carry, and the runs of a kind go in ONE loop
+    (`_walk_runs_merged`): 4.41 and 3.00 (-4%, -8%). The backward's dQ in
+    scratch: - / 6.11 and - / 4.02; and then, its loops free, a cut k block
+    with one sub-tile in sight as that sub-tile (`_walk_cut_runs`): 128 keys
+    - / 5.82 and - / 3.86, **256 keys - / 5.77 and - / 3.70** (-9%, -11%;
+    512, no cut: 6.21 / 4.06); with dQ carried the same walk read 6.24 /
+    4.31, and the merged loops 6.23 / 4.07. `_CUT_K` is 256."""
     if group == 1:
         return (512, 1024) if kernel in _K_HELD else (512, 512)
     if D > 64:
         return (1536, 512)
     return _grouped_blocks(D, L, group, kernel != profile.FLASH_FWD)
+
+
+def _cut_k(plan, kernel):
+    """The key step of a kernel's walk under a rule, from its plan: `_CUT_K`
+    for the one-kernel backward held by the q block (k and v whole in VMEM: a
+    key tile is a slice at any multiple of it; its sums in scratch: a loop
+    costs nothing to enter) where the k block is a whole number of them, else
+    the k block itself: the forward and dQ carry their state through the
+    loops (`_walk_runs_merged`), held by the k block the cut would be in q
+    rows (`_walk_q`), gridded a tile is a pipeline step. It divides what the
+    rule's `tiled` and `check` asked of the k block, since the k block does."""
+    bk = plan.block_k
+    fine = (kernel == profile.FLASH_BWD and plan.path == "resident"
+            and plan.held == "q" and _CUT_K < bk and bk % _CUT_K == 0)
+    return _CUT_K if fine else bk
 
 
 def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
@@ -859,10 +1029,14 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
                          block_k, vmem_budget, shared_dim, mask, held)
         if mask is None or p is None:
             return p
-        return p._replace(**dict(zip(
-            ("tiles_visited", "tiles_masked", "tiles_skipped"),
-            (BG * n for n in _rule_tiles(mask, p.held, L,
-                                         p.block_q // group, p.block_k)))))
+        bqp, cut_k = p.block_q // group, _cut_k(p, kernel)
+        tiles = _rule_tiles(mask, p.held, L, bqp, p.block_k)
+        subtiles = tiles[:2] if cut_k == p.block_k else _rule_subtiles(
+            mask, L, bqp, p.block_k, cut_k)
+        return p._replace(cut_k=cut_k, **dict(zip(
+            ("tiles_visited", "tiles_masked", "tiles_skipped",
+             "subtiles_visited", "subtiles_masked"),
+            (BG * n for n in tiles + subtiles))))
 
     def resident_or_none(plans):
         if any(p.path != "resident" for name, p in plans.items()
@@ -945,14 +1119,20 @@ def _walk_q_blocks(visit, carry, kj, bqp, bk, num_qb, causal):
 
 
 def _walk_k(visit, carry, qi, bqp, bk, num_kb, causal, rule):
-    """The k blocks q block `qi` sees: a rule's runs, or the causal walk."""
+    """The k blocks q block `qi` sees, for a kernel whose loops carry its
+    state: the causal walk, or a rule's runs, those of a kind in one loop
+    (`_walk_runs_merged`)."""
     if rule is None:
         return _walk_k_blocks(visit, carry, qi, bqp, bk, num_kb, causal)
-    return _walk_runs(visit, carry, rule.key_runs(qi * bqp, bqp, bk))
+    return _walk_runs_merged(visit, carry, rule.key_runs(qi * bqp, bqp, bk))
 
 
 def _walk_q(visit, carry, kj, bqp, bk, num_qb, causal, rule):
-    """The q blocks k block `kj` is seen by: likewise."""
+    """The q blocks k block `kj` is seen by: likewise, a rule's runs at the
+    q block's own step. A cut here would be in q ROWS (sub-tiles of the q
+    block, `query_runs` at a finer step); no benchmark cell runs a kernel
+    held by the k block under a rule (their backward is held by the q block),
+    so this walk stays as it is."""
     if rule is None:
         return _walk_q_blocks(visit, carry, kj, bqp, bk, num_qb, causal)
     return _walk_runs(visit, carry,
@@ -1168,8 +1348,8 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group,
 
 
 def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, *, scale,
-                       causal, bk, bqp, group, rule=None):
+                       dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, *dq_acc, scale,
+                       causal, bk, bqp, group, rule=None, cut_k=None):
     """The whole backward (`hvd_flash_bwd`) held by the q block, with k and
     v whole in VMEM: on `_bwd_dq_resident_kernel`'s grid and walk, s, p, dp
     and ds of a tile formed once for dQ, dK and dV. A step takes a q block
@@ -1180,7 +1360,14 @@ def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     scratch across the grid's q-block axis: zeroed at the first q block of
     a (batch, kv head), rounded once and written, whole blocks too, at the
     last. A k block's sum over the q blocks, the head group's rows among
-    them, runs in ascending q order, as the gridded `_bwd_dkv_kernel`'s."""
+    them, runs in ascending q order, as the gridded `_bwd_dkv_kernel`'s.
+
+    `rule`: dQ of the block is summed in a third accumulator in scratch
+    (`dq_acc` [BQ, D] f32) in place of the carry, so that the walk's loops
+    carry nothing and cost nothing to enter, and the walk is
+    `_walk_cut_runs` at the key step `cut_k`: a cut k block with one
+    sub-tile in sight is a turn of that sub-tile, its p^T.dO and ds^T.q
+    added at the sub-tile's rows."""
     qi = pl.program_id(1)
     num_kb = k_ref.shape[0] // bk
 
@@ -1203,12 +1390,14 @@ def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     lse = lse_ref[:, :1]
     delta = delta_ref[:, :1]
 
-    def visit(j, dq, masked):
-        at = k_block(j)
+    def tile(j, step, width, masked):
+        """ds.k of the `width` keys from tile `j` of `step` keys on, their
+        p^T.dO and ds^T.q added to dV's and dK's rows."""
+        at = pl.ds(pl.multiple_of(j * step, step), width)
         k = k_ref[at, :]
         s = _scores(q, k, scale)
         if masked:
-            s = _mask_tile(s, rule, qi * bqp, j * bk, group)
+            s = _mask_tile(s, rule, qi * bqp, j * step, group)
         p = jnp.exp(s - lse)  # masked entries: exp(-inf) = 0
         dv_acc[at, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -1220,12 +1409,25 @@ def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[at, :] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return dq + jax.lax.dot_general(
+        return jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    dq = _walk_k(visit, jnp.zeros(q.shape, jnp.float32), qi, bqp, bk,
-                 num_kb, causal, rule)
+    if rule is None:
+        dq = _walk_k(
+            lambda j, dq, masked: dq + tile(j, bk, bk, masked),
+            jnp.zeros(q.shape, jnp.float32), qi, bqp, bk, num_kb, causal,
+            rule)
+    else:
+        dq_acc, = dq_acc
+        dq_acc[...] = jnp.zeros(q.shape, jnp.float32)
+
+        def visit(j, masked, width):
+            dq_acc[...] += tile(j, cut_k, width, masked)
+
+        _walk_cut_runs(visit, rule.key_runs(qi * bqp, bqp, bk),
+                       rule.key_runs(qi * bqp, bqp, cut_k), bk, cut_k)
+        dq = dq_acc[...]
     dq_ref[...] = dq.astype(dq_ref.dtype)
 
     @pl.when(qi == pl.num_programs(1) - 1)
@@ -1866,14 +2068,19 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         # The one kernel on dQ's grid: a q block a step; k, v, dk, dv whole.
         kernel = functools.partial(_bwd_q_held_kernel, scale=scale,
                                    causal=causal, bk=bk, bqp=bqp,
-                                   group=group, **extra)
+                                   group=group, **extra,
+                                   **({} if rule is None
+                                      else {"cut_k": plan.cut_k}))
         q_im, k_spec = _q_walk_specs(plan, L, D, group, causal)
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
         # dK's and dV's accumulators across the q blocks of a (batch, kv
-        # head).
+        # head; under a rule dQ's of the block too (the walk's loops carry
+        # nothing).
         scratch = [pltpu.VMEM((L, D), jnp.float32),
-                   pltpu.VMEM((L, D), jnp.float32)]
+                   pltpu.VMEM((L, D), jnp.float32)] + (
+                       [] if rule is None
+                       else [pltpu.VMEM((bq, D), jnp.float32)])
     elif plan.path == "resident":
         kernel = functools.partial(_bwd_dkv_resident_kernel, scale=scale,
                                    causal=causal, bq=bq, bqp=bqp,

@@ -568,7 +568,14 @@ def flash_plan(*args, **kwargs):
     rule (`ops.BlockDiffusionMask(length, block)`; L counts all 2 x length
     positions) every plan also says how many score tiles of a call its
     kernel visits, masks and skips (`tiles_visited`, `tiles_masked`,
-    `tiles_skipped`: the rule's own runs, which the kernel walks); the
+    `tiles_skipped`: the rule's own runs, which the kernel walks), the
+    width of the sub-tile it takes alone where a cut k block has one in
+    sight (`cut_k`: under `block_k` in the one-kernel backward held by the q
+    block; else `block_k`: the kernel walks k blocks alone) and the
+    sub-tiles of that width it visits and masks (`subtiles_visited`,
+    `subtiles_masked`): the area under the mask pass is `subtiles_masked` x
+    `cut_k` keys a query tile, against `tiles_masked` x `block_k` by k
+    blocks alone. The
     forward and dQ take a rule resident only, dK/dV in any of its forms,
     ``{}`` otherwise. The kernels run what this returns, so like
     `grad_collectives` it needs no chip."""

@@ -173,7 +173,10 @@ def test_causal_and_full_calls_lower_to_the_text_they_had(one_chip, B, H, G,
 # short enough that the one kernel is held by the k block. Beside each, the
 # same call under the causal band (`mellum12b_1chip`'s window layers: a
 # query on itself and the 1023 keys before it; and a window that is no
-# multiple of a tile).
+# multiple of a tile). All four rows compile the forward's merged walk
+# (`_walk_runs_merged`), the two "q" rows the backward's walk with lone
+# sub-tiles (`_walk_cut_runs`); the "k" rows' backward walks the q blocks
+# (`_walk_q`).
 @pytest.mark.parametrize("H,G,S,rule,held", [
     (32, 4, 8192, BlockDiffusionMask(4096, 4), "q"),
     (16, 16, 2048, BlockDiffusionMask(1024, 4), "k"),
@@ -191,11 +194,19 @@ def test_ruled_flash_compiles_for_v5e(one_chip, H, G, S, rule, held):
     text = _compile(one_chip, fwd_bwd, ((1, H, S, D), bf16),
                     ((1, G, S, D), bf16), ((1, G, S, D), bf16),
                     ((1, H, S, D), bf16))
-    paths = {name: (p.path, p.held) for backward in (False, True)
+    plans = {name: p for backward in (False, True)
              for name, p in flash_plan(1, H, S, D, H // G, bf16, backward,
                                        mask=rule).items()}
+    paths = {name: (p.path, p.held) for name, p in plans.items()}
     assert paths == {profile.FLASH_FWD: ("resident", "q"),
                      profile.FLASH_BWD: ("resident", held)}
+    # what compiled: the forward's runs of a kind in one loop; the backward
+    # held by the q block with a cut k block's lone sub-tile of 256 keys a
+    # turn (PR 53), the one held by the k block by k blocks
+    assert {name: p.cut_k for name, p in plans.items()} == {
+        profile.FLASH_FWD: plans[profile.FLASH_FWD].block_k,
+        profile.FLASH_BWD: 256 if held == "q" else plans[
+            profile.FLASH_BWD].block_k}
     assert _kernels(text) == len(paths), text[:2000]
     for name in (profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV,
                  profile.FLASH_BWD):

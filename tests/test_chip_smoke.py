@@ -154,8 +154,15 @@ def test_kernels_phase_knows_the_block_diffusion_shape(capsys):
     chip_smoke.print_flash_plan(*shape, mask=rule)
     lines = capsys.readouterr().out.splitlines()
     assert len(lines) == 2
-    for line in lines:
-        assert line.endswith("tiles visited 1280 (masked 384), skipped 2816")
+    # the tiles by k blocks; then how each kernel walks them (PR 53): the
+    # forward by k blocks alone, the backward a cut k block's lone 256-key
+    # sub-tile alone
+    for line, walk in zip(lines, (
+            "512 keys: sub-tiles visited 1280 (masked 384)",
+            "256 keys: sub-tiles visited 2304 (masked 512)")):
+        assert line.endswith(
+            "tiles visited 1280 (masked 384), skipped 2816; a lone sub-tile "
+            "is of " + walk)
     assert lines[1].startswith(
         "  hvd_flash_bwd: resident held by the q block, blocks 1024 x 512, "
         "grid (4, 64) = 256 steps, VMEM 27.5 MiB of a limit of 52")

@@ -164,15 +164,12 @@ BAND_CASES = [(8, 4, 2, 64, 128), (128, 2, 2, 128, 128), (200, 8, 1, 256, 128),
               (256, 4, 2, 128, 64)]
 
 
-@pytest.mark.parametrize("path", ["one kernel", "two resident", "q-held",
-                                  "gridded dK/dV"])
-@pytest.mark.parametrize("window,H,G,bq,bk", BAND_CASES)
-def test_banded_kernels_agree_with_a_dense_masked_softmax(window, H, G, bq,
-                                                          bk, path,
-                                                          monkeypatch):
-    S, D = 256, 64
-    rule = BandMask(window)
-    q, k, v, w = _kernel_case(S, H, G, D)
+def _kernels_against_the_dense_mask(rule, S, H, G, bq, bk, path, monkeypatch,
+                                    seed=0):
+    """The forward and the backward in the form `path` names, at the given
+    blocks, against the dense masked softmax; the backward's plans."""
+    D = 64
+    q, k, v, w = _kernel_case(S, H, G, D, seed)
     mask = jnp.asarray(_dense_mask(rule, S))
     want, vjp = jax.vjp(lambda *a: _dense_attention(*a, D ** -0.5, mask),
                         q, k, v)
@@ -193,6 +190,53 @@ def test_banded_kernels_agree_with_a_dense_masked_softmax(window, H, G, bq,
                               **kw)
     for g, r in zip(got, vjp(w)):
         _close(g, r, 2e-6)
+    return plans
+
+
+@pytest.mark.parametrize("path", ["one kernel", "two resident", "q-held",
+                                  "gridded dK/dV"])
+@pytest.mark.parametrize("window,H,G,bq,bk", BAND_CASES)
+def test_banded_kernels_agree_with_a_dense_masked_softmax(window, H, G, bq,
+                                                          bk, path,
+                                                          monkeypatch):
+    _kernels_against_the_dense_mask(BandMask(window), 256, H, G, bq, bk, path,
+                                    monkeypatch)
+
+
+# (positions, window, heads, kv heads, rows of a q block, k block, cut_k,
+# cut k blocks a kv head with one sub-tile in sight) with `_CUT_K` set to
+# cut_k: a window smaller than a sub-tile (a q tile at a k block's start
+# sees one sub-tile of it and one of the block before); a window of several
+# sub-tiles; a head group of 8 on q tiles narrower than a sub-tile; the
+# triangle with q tiles as wide as the k block (every sub-tile of a cut k
+# block in sight: the walk by k blocks); the cells' steps, (128, 512, 128).
+CUT_CASES = [(512, 8, 4, 2, 64, 128, 32, 7), (512, 200, 2, 2, 32, 128, 32, 6),
+             (512, 300, 8, 1, 256, 256, 64, 4),
+             (512, 512, 2, 1, 128, 128, 32, 0),
+             (1024, 300, 1, 1, 128, 512, 128, 3)]
+
+
+@pytest.mark.parametrize("path", ["two resident", "q-held"])
+@pytest.mark.parametrize("S,window,H,G,bq,bk,cut_k,lone", CUT_CASES)
+def test_banded_kernels_take_a_lone_sub_tile_alone(S, window, H, G, bq, bk,
+                                                   cut_k, lone, path,
+                                                   monkeypatch):
+    """The kernels held by the q block against the dense masked softmax: the
+    forward and dQ by its own kernel under `_walk_runs_merged`, the
+    one-kernel backward under `_walk_cut_runs`, where a cut k block with one
+    sub-tile in sight (`lone`: how many the call has a kv head) is computed
+    as that sub-tile."""
+    monkeypatch.setattr(fa, "_CUT_K", cut_k)
+    plans = _kernels_against_the_dense_mask(BandMask(window), S, H, G, bq, bk,
+                                            path, monkeypatch, seed=7)
+    if path == "two resident":  # dQ carries its sum: k blocks alone
+        assert plans[profile.FLASH_DQ].cut_k == bk
+    else:
+        cut, ratio = plans[profile.FLASH_BWD], bk // cut_k
+        assert (cut.held, cut.cut_k) == ("q", cut_k)
+        assert G * lone * (ratio - 1) == (
+            ratio * cut.tiles_visited - cut.subtiles_visited) == (
+            ratio * cut.tiles_masked - cut.subtiles_masked)
 
 
 @pytest.mark.parametrize("backward", [False, True])

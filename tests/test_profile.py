@@ -623,7 +623,15 @@ def test_flash_plans_of_the_cells_are_the_parents(cell):
         assert got.pop("held") == (
             "k" if name == "hvd_flash_dkv" or (
                 name == "hvd_flash_bwd" and cell not in _Q_HELD_BWD) else "q")
-        assert tuple(got.values()) == want, name
+        # The parent's fields, then PR 53's (`cut_k`, `subtiles_visited`,
+        # `subtiles_masked`): with no rule None; under the cell's rule the
+        # forward by k blocks alone, the backward's lone sub-tiles alone.
+        values = tuple(got.values())
+        assert values[:len(want)] == want, name
+        assert values[len(want):] == (
+            (None,) * 3 if "mask" not in call else
+            (512,) + _SDAR_TILES[:2] if name == "hvd_flash_fwd" else
+            (256, 2304, 512)), name
 
 
 def test_flash_plan_holds_sdars_backward_by_the_q_block():

@@ -250,14 +250,12 @@ def _budget(path, H, G, S, D, bq, bk, rule, monkeypatch):
 KERNEL_CASES = [(4, 4, 2, 64, 128), (16, 4, 1, 128, 128), (16, 2, 2, 64, 128)]
 
 
-@pytest.mark.parametrize("path", ["one kernel", "two resident",
-                                  "q-held", "gridded dK/dV"])
-@pytest.mark.parametrize("block,H,G,bq,bk", KERNEL_CASES)
-def test_ruled_kernels_agree_with_a_dense_masked_softmax(block, H, G, bq, bk,
-                                                         path, monkeypatch):
-    length, D = 256, 64
-    rule = BlockDiffusionMask(length, block)
-    q, k, v, w = _kernel_case(length, H, G, D)
+def _kernels_against_the_dense_mask(rule, H, G, bq, bk, path, monkeypatch,
+                                    seed=0):
+    """The forward and the backward in the form `path` names, at the given
+    blocks, against the dense masked softmax; the backward's plans."""
+    length, D = rule.length, 64
+    q, k, v, w = _kernel_case(length, H, G, D, seed)
     mask = jnp.asarray(_dense_mask(rule))
     want, vjp = jax.vjp(lambda *a: _dense_attention(*a, D ** -0.5, mask),
                         q, k, v)
@@ -278,6 +276,52 @@ def test_ruled_kernels_agree_with_a_dense_masked_softmax(block, H, G, bq, bk,
                               **kw)
     for g, r in zip(got, vjp(w)):
         _close(g, r, 2e-6)
+    return plans
+
+
+@pytest.mark.parametrize("path", ["one kernel", "two resident",
+                                  "q-held", "gridded dK/dV"])
+@pytest.mark.parametrize("block,H,G,bq,bk", KERNEL_CASES)
+def test_ruled_kernels_agree_with_a_dense_masked_softmax(block, H, G, bq, bk,
+                                                         path, monkeypatch):
+    _kernels_against_the_dense_mask(BlockDiffusionMask(256, block), H, G, bq,
+                                    bk, path, monkeypatch)
+
+
+# (length, block, heads, kv heads, rows of a q block, k block, cut_k, cut k
+# blocks a kv head with one sub-tile in sight) with `_CUT_K` set to cut_k: a
+# noisy q tile's own blocks lie in one sub-tile, and the clean k block that
+# holds its own block's copy begins with it a time in four; a head group of
+# 4; diffusion blocks as wide as a sub-tile; the cell's steps, (128, 512,
+# 128).
+CUT_CASES = [(256, 4, 4, 2, 64, 128, 32, 12),
+             (256, 16, 4, 1, 128, 128, 32, 12),
+             (256, 64, 2, 2, 32, 256, 64, 12),
+             (512, 4, 1, 1, 128, 512, 128, 6)]
+
+
+@pytest.mark.parametrize("path", ["two resident", "q-held"])
+@pytest.mark.parametrize("length,block,H,G,bq,bk,cut_k,lone", CUT_CASES)
+def test_ruled_kernels_take_a_lone_sub_tile_alone(length, block, H, G, bq,
+                                                  bk, cut_k, lone, path,
+                                                  monkeypatch):
+    """The kernels held by the q block against the dense masked softmax: the
+    forward and dQ by its own kernel under `_walk_runs_merged`, the
+    one-kernel backward under `_walk_cut_runs`, where a cut k block with one
+    sub-tile in sight (`lone`: how many the call has a kv head) is computed
+    as that sub-tile."""
+    monkeypatch.setattr(fa, "_CUT_K", cut_k)
+    plans = _kernels_against_the_dense_mask(
+        BlockDiffusionMask(length, block), H, G, bq, bk, path, monkeypatch,
+        seed=7)
+    if path == "two resident":  # dQ carries its sum: k blocks alone
+        assert plans[profile.FLASH_DQ].cut_k == bk
+    else:
+        cut, ratio = plans[profile.FLASH_BWD], bk // cut_k
+        assert (cut.held, cut.cut_k) == ("q", cut_k)
+        assert G * lone * (ratio - 1) == (
+            ratio * cut.tiles_visited - cut.subtiles_visited) == (
+            ratio * cut.tiles_masked - cut.subtiles_masked)
 
 
 @pytest.mark.parametrize("backward", [False, True])
