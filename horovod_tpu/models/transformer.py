@@ -520,14 +520,25 @@ class LatentAttention(nn.Module):
         heads = lambda d, name: nn.DenseGeneral(  # noqa: E731
             (H, d), dtype=cfg.dtype, param_dtype=jnp.float32,
             use_bias=False, name=name)
-        q = heads(nope + rope, "q_b")(_rms_norm(cfg, "q_norm")(
-            dense(cfg.q_lora_rank, "q_a")(x)))
-        kv = dense(cfg.kv_lora_rank + rope, "kv_a")(x)
-        k_rope = kv[..., None, cfg.kv_lora_rank:]        # [B, L, 1, rope]
-        kv = heads(nope + vd, "kv_b")(_rms_norm(cfg, "kv_norm")(
-            kv[..., :cfg.kv_lora_rank]))
-        q_nope, q_rope = q[..., :nope], q[..., nope:]
-        k_nope, v = kv[..., :nope], kv[..., nope:]
+        # The work outside the kernels under the profiler's three names
+        # (`profile.ATTN_PARTS`): no module and no parameter name.
+        with jax.named_scope(profile.ATTN_PROJ):
+            c_q = dense(cfg.q_lora_rank, "q_a")(x)
+        with jax.named_scope(profile.ATTN_NORM):
+            c_q = _rms_norm(cfg, "q_norm")(c_q)
+        with jax.named_scope(profile.ATTN_PROJ):
+            q = heads(nope + rope, "q_b")(c_q)
+            kv = dense(cfg.kv_lora_rank + rope, "kv_a")(x)
+        with jax.named_scope(profile.ATTN_ROPE):
+            k_rope = kv[..., None, cfg.kv_lora_rank:]    # [B, L, 1, rope]
+            c_kv = kv[..., :cfg.kv_lora_rank]
+        with jax.named_scope(profile.ATTN_NORM):
+            c_kv = _rms_norm(cfg, "kv_norm")(c_kv)
+        with jax.named_scope(profile.ATTN_PROJ):
+            kv = heads(nope + vd, "kv_b")(c_kv)
+        with jax.named_scope(profile.ATTN_ROPE):
+            q_nope, q_rope = q[..., :nope], q[..., nope:]
+            k_nope, v = kv[..., :nope], kv[..., nope:]
         scale = (nope + rope) ** -0.5
         yarn = cfg.rope_yarn
         if yarn is None:
@@ -539,8 +550,9 @@ class LatentAttention(nn.Module):
             m = (yarn_mscale(yarn.factor, yarn.mscale)
                  / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
             scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
-        q_rope = _rotary_freq(q_rope, positions, inv_freq, m)
-        k_rope = _rotary_freq(k_rope, positions, inv_freq, m)
+        with jax.named_scope(profile.ATTN_ROPE):
+            q_rope = _rotary_freq(q_rope, positions, inv_freq, m)
+            k_rope = _rotary_freq(k_rope, positions, inv_freq, m)
         if cfg.attention == "flash":
             from horovod_tpu.ops import flash_attention
             o = flash_attention(q_nope, k_nope, v, causal=True, scale=scale,
@@ -556,9 +568,10 @@ class LatentAttention(nn.Module):
             s = jnp.where(mask[None, None], s, -jnp.inf)
             p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
-        return nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), dtype=cfg.dtype,
-                               param_dtype=jnp.float32, use_bias=False,
-                               name="out")(o)
+        with jax.named_scope(profile.ATTN_PROJ):
+            return nn.DenseGeneral(
+                cfg.embed_dim, axis=(-2, -1), dtype=cfg.dtype,
+                param_dtype=jnp.float32, use_bias=False, name="out")(o)
 
 
 def sinkhorn(m, iters, eps):
@@ -711,30 +724,37 @@ class Attention(nn.Module):
         heads = lambda n, name: nn.DenseGeneral(  # noqa: E731
             (n, head_dim), dtype=cfg.dtype,
             param_dtype=jnp.float32, use_bias=False, name=name)
-        q = heads(cfg.num_heads, "query")(x)
-        k = heads(G, "key")(x)
-        v = heads(G, "value")(x)
-        if cfg.qk_norm == "head":
-            # Over each head's own width: the norm acts on the last axis,
-            # its one scale [head_dim] shared by the heads.
-            q, k = _rms_norm(cfg, "q_norm")(q), _rms_norm(cfg, "k_norm")(k)
-        elif cfg.qk_norm:
-            def whole(t, name):
-                flat = t.reshape(t.shape[:-2] + (-1,))
-                return _rms_norm(cfg, name)(flat).reshape(t.shape)
-            q, k = whole(q, "q_norm"), whole(k, "k_norm")
-        if cfg.rotary and self.kind == "full" and cfg.rope_yarn is not None:
-            # YaRN on the whole head: cos and sin both times its factor, so
-            # a rotated q.k carries the factor's square.
-            yarn = cfg.rope_yarn
-            inv_freq = yarn_inv_freq(head_dim, cfg.rope_base, yarn)
-            m = (yarn_mscale(yarn.factor, yarn.mscale)
-                 / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
-            q = _rotary_freq(q, positions, inv_freq, m)
-            k = _rotary_freq(k, positions, inv_freq, m)
-        elif cfg.rotary:
-            q = _rotary(q, positions, cfg.rope_base)
-            k = _rotary(k, positions, cfg.rope_base)
+        # The work outside the kernels under the profiler's three names
+        # (`profile.ATTN_PARTS`): no module and no parameter name.
+        with jax.named_scope(profile.ATTN_PROJ):
+            q = heads(cfg.num_heads, "query")(x)
+            k = heads(G, "key")(x)
+            v = heads(G, "value")(x)
+        with jax.named_scope(profile.ATTN_NORM):
+            if cfg.qk_norm == "head":
+                # Over each head's own width: the norm acts on the last
+                # axis, its one scale [head_dim] shared by the heads.
+                q = _rms_norm(cfg, "q_norm")(q)
+                k = _rms_norm(cfg, "k_norm")(k)
+            elif cfg.qk_norm:
+                def whole(t, name):
+                    flat = t.reshape(t.shape[:-2] + (-1,))
+                    return _rms_norm(cfg, name)(flat).reshape(t.shape)
+                q, k = whole(q, "q_norm"), whole(k, "k_norm")
+        with jax.named_scope(profile.ATTN_ROPE):
+            if cfg.rotary and self.kind == "full" \
+                    and cfg.rope_yarn is not None:
+                # YaRN on the whole head: cos and sin both times its
+                # factor, so a rotated q.k carries the factor's square.
+                yarn = cfg.rope_yarn
+                inv_freq = yarn_inv_freq(head_dim, cfg.rope_base, yarn)
+                m = (yarn_mscale(yarn.factor, yarn.mscale)
+                     / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+                q = _rotary_freq(q, positions, inv_freq, m)
+                k = _rotary_freq(k, positions, inv_freq, m)
+            elif cfg.rotary:
+                q = _rotary(q, positions, cfg.rope_base)
+                k = _rotary(k, positions, cfg.rope_base)
         if cfg.attention == "ring":
             o = ring_attention(q, k, v, cfg.sp_axis, causal=True,
                                schedule=cfg.sp_schedule)
@@ -761,9 +781,10 @@ class Attention(nn.Module):
             s = jnp.where(seen[None, None], s, -jnp.inf)
             p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
-        out = nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), dtype=cfg.dtype,
-                              param_dtype=jnp.float32, use_bias=False,
-                              name="out")(o)
+        with jax.named_scope(profile.ATTN_PROJ):
+            out = nn.DenseGeneral(
+                cfg.embed_dim, axis=(-2, -1), dtype=cfg.dtype,
+                param_dtype=jnp.float32, use_bias=False, name="out")(o)
         if cfg.tp_axis is not None:
             # Each tp shard projected only its local heads: the row-
             # parallel output is a partial sum (Megatron-style).
@@ -819,7 +840,10 @@ class Mamba2(nn.Module):
         def a_log_init(key, shape, dtype):
             return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
-        zxbcdt = dense(inner + conv_dim + H, "in_proj")(u)
+        # Outside the convolution and the scan the profiler knows two parts
+        # (`profile.SSM_PROJ`, `SSM_GATE`): no module, no parameter name.
+        with jax.named_scope(profile.SSM_PROJ):
+            zxbcdt = dense(inner + conv_dim + H, "in_proj")(u)
         z = zxbcdt[..., :inner]
         xbc = zxbcdt[..., inner:inner + conv_dim]
         dt = zxbcdt[..., inner + conv_dim:]
@@ -835,20 +859,25 @@ class Mamba2(nn.Module):
         x = xbc[..., :inner].reshape(B, L, H, P)
         b = xbc[..., inner:inner + G * N].reshape(B, L, G, N)
         c = xbc[..., inner + G * N:].reshape(B, L, G, N)
-        dt = jax.nn.softplus(dt.astype(f32) + self.param(
-            "dt_bias", dt_bias_init, (H,), f32))
-        a = -jnp.exp(self.param("A_log", a_log_init, (H,), f32))
+        with jax.named_scope(profile.SSM_GATE):
+            dt = jax.nn.softplus(dt.astype(f32) + self.param(
+                "dt_bias", dt_bias_init, (H,), f32))
+            a = -jnp.exp(self.param("A_log", a_log_init, (H,), f32))
         y, state_max = ssd_scan(x, dt, a, b, c, cfg.ssm_chunk)
         self.sow("intermediates", "ssd_state_max", state_max)
-        skip = self.param("D", nn.initializers.ones, (H,), f32)
-        y = y + skip[:, None] * x.astype(f32)
-        y = (y.reshape(B, L, inner) * nn.silu(z.astype(f32))).reshape(
-            B, L, G, inner // G)
-        y = y * lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True)
-                          + cfg.norm_eps)
-        y = y.reshape(B, L, inner) * self.param(
-            "norm", nn.initializers.ones, (inner,), f32)
-        return dense(cfg.embed_dim, "out_proj")(y.astype(cfg.dtype))
+        with jax.named_scope(profile.SSM_GATE):
+            skip = self.param("D", nn.initializers.ones, (H,), f32)
+            y = y + skip[:, None] * x.astype(f32)
+            y = (y.reshape(B, L, inner) * nn.silu(z.astype(f32))).reshape(
+                B, L, G, inner // G)
+            y = y * lax.rsqrt(
+                jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                + cfg.norm_eps)
+            y = (y.reshape(B, L, inner) * self.param(
+                "norm", nn.initializers.ones, (inner,), f32)).astype(
+                    cfg.dtype)
+        with jax.named_scope(profile.SSM_PROJ):
+            return dense(cfg.embed_dim, "out_proj")(y)
 
 
 def ssd_stats(intermediates):

@@ -82,15 +82,24 @@ MOE_SCOPES = (MOE, MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE,
               MOE_SHARED, MOE_LATENT)
 
 # A Mamba-2 mixer (`models/transformer.py::Mamba2`, a layer of kind "ssm"
-# in `layer_types`), inside `BLOCK`: `SSM` around all of it (the in- and
-# out-projection, the gate, the grouped norm), `SSM_CONV` around the causal
-# depthwise convolution and `SSD` around the chunked scan (`ops/ssd.py`:
-# the products inside a chunk, the chunk states, the carry) inside. Not in
-# MODEL_SCOPES: the layer reads as `hvd_block` in the by-scope table.
+# in `layer_types`), inside `BLOCK`: `SSM` around all of it, `SSM_CONV`
+# around the causal depthwise convolution and `SSD` around the chunked scan
+# (`ops/ssd.py`: the products inside a chunk, the chunk states, the carry)
+# inside. Not in MODEL_SCOPES: the layer reads as `hvd_block` in the
+# by-scope table.
 SSM = "hvd_ssm"
 SSM_CONV = "hvd_ssm_conv"
 SSD = "hvd_ssd"
-SSM_SCOPES = (SSM, SSM_CONV, SSD)
+# What the mixer does outside its convolution and its scan, inside `SSM` and
+# opened in `Mamba2.__call__` itself, beside the two above and never around
+# them: `SSM_PROJ` around `in_proj` and `out_proj`; `SSM_GATE` around the
+# elementwise part in f32 (dt's bias and softplus, `a`, the skip, `y *
+# silu(z)`, the grouped mean square and `rsqrt`, the `norm` scale, the cast
+# before `out_proj`). What is left under `SSM` alone is the slices of the
+# in-projection's output and the reshapes.
+SSM_PROJ = "hvd_ssm_proj"
+SSM_GATE = "hvd_ssm_gate"
+SSM_SCOPES = (SSM, SSM_CONV, SSD, SSM_PROJ, SSM_GATE)
 
 # The hyper-connection around each of a block's two branches
 # (`models/transformer.py`, `hc_mult` > 1), inside `BLOCK` and beside the
@@ -117,6 +126,25 @@ MTP = "hvd_mtp"
 ATTN_WINDOW = "hvd_attn_window"
 ATTN_FULL = "hvd_attn_full"
 ATTN_KINDS = {"window": ATTN_WINDOW, "full": ATTN_FULL}
+
+# What an attention module does outside its kernels, inside flax's `attn`
+# and opened in `Attention.__call__` / `LatentAttention.__call__` themselves
+# (so a block under a pass, under `MTP`, inside a hyper-connection or under a
+# kind has them): `ATTN_PROJ` around every projection into heads and out of
+# them (`query`, `key`, `value`, `out`; latent attention's `q_a`, `q_b`,
+# `kv_a`, `kv_b`, `out`), `ATTN_NORM` around the norms (`q_norm`, `k_norm`
+# per head or whole; latent attention's `q_norm`, `kv_norm`), `ATTN_ROPE`
+# around the rotations of q and k (latent attention: the slices of q and kv
+# into their no-position and rotary parts and the rotation of the two rotary
+# parts). What is left under `attn` alone is the reshapes, transposes and
+# copies that feed the kernels (and the dense, ring and ulysses paths). Not
+# in MODEL_SCOPES: the half still reads as `hvd_block/attn`, and under a
+# kind as `ATTN_WINDOW` / `ATTN_FULL`. A fusion has ONE `op_name`, so a
+# part's time is by fusion: `fused_scopes` says which fusions mix them.
+ATTN_PROJ = "hvd_attn_proj"
+ATTN_NORM = "hvd_attn_norm"
+ATTN_ROPE = "hvd_attn_rope"
+ATTN_PARTS = (ATTN_PROJ, ATTN_NORM, ATTN_ROPE)
 
 # Block-diffusion training (`models/block_diffusion.py`), inside FWD_BWD and
 # outside the model: the noise draw, the doubled ids, positions and row
@@ -542,6 +570,93 @@ def grad_collectives(text):
                           for o in _HLO_OPERAND.findall(operands))
         out[kind]["count"] += 1
         out[kind]["bytes"] += nbytes
+    return out
+
+
+# --- how far a scope's device time can be trusted ---------------------------
+#
+# A fusion is one device instruction with ONE `op_name` (the root's, as a
+# rule), and a trace counts its whole time for that name. The instructions
+# INSIDE its fused computation keep their own `op_name`, so the text of a
+# compiled step says which fusions hold work of more than one scope: a
+# norm's scaling inside a rotation, a gate inside a projection's matmul.
+_HLO_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_SCOPE_SPLIT = re.compile(r"[/()]")
+
+
+def fused_scopes(text, scopes):
+    """Which fusions of a compiled step (`compiled.as_text()`) hold work
+    under `scopes` (names of this module, e.g. `ATTN_PARTS`), and how pure
+    each is. Per fusion instruction of the entry computation (and of a loop's
+    or a branch's body) whose own `op_name`, or that of any instruction
+    inside its fused computation, holds one of `scopes`:
+
+        {fusion: {"scope": s, "inner": {scope or None: n}, "mixed": bool}}
+
+    `scope` is the one of `scopes` the fusion's own `op_name` holds (the
+    innermost on its path, so `scopes` may hold `BLOCK` beside the parts
+    inside it; None: none of them), which is where a trace counts its time;
+    `inner` counts the fused computation's instructions but its parameters
+    and constants by the scope on their own `op_name` (None: none of
+    `scopes`, or no `op_name`), a fusion inside it by ITS computation's
+    instructions (libtpu fuses the producers of a matmul's operands into the
+    matmul's fusion as fusions of their own); `mixed` says that `inner`
+    holds more than one of `scopes`. Like `grad_collectives` it reads text
+    and needs no chip."""
+    scopes = tuple(scopes)
+
+    def scope_of(op_name):
+        return next((t for t in reversed(_SCOPE_SPLIT.split(op_name))
+                     if t in scopes), None)
+
+    # computation -> [(scope or None, the computation it calls if a fusion)]
+    held = {}
+    fusions = []  # (fusion, the computation it is in, the one it calls, scope)
+    comp = None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            held[comp] = []
+            continue
+        m = _HLO_LINE.match(line)
+        if not m or comp is None:
+            continue
+        name, rest = m.groups()
+        end = _type_end(rest)
+        op = _HLO_OPCODE.match(rest, end)
+        if not op or op.group(1) in ("parameter", "constant"):
+            continue
+        found = _HLO_OP_NAME.search(rest, end)
+        scope = scope_of(found.group(1)) if found else None
+        called = _HLO_CALLS.search(rest, end) \
+            if op.group(1) == "fusion" else None
+        held[comp].append((scope, called and called.group(1)))
+        if called:
+            fusions.append((name, comp, called.group(1), scope))
+
+    counted = {}  # computation -> {scope or None: instructions}, all depths
+
+    def count(comp):
+        if comp not in counted:
+            counts = counted[comp] = {}
+            for scope, called in held.get(comp, ()):
+                for s, n in (count(called).items() if called
+                             else ((scope, 1),)):
+                    counts[s] = counts.get(s, 0) + n
+        return counted[comp]
+
+    fused = {called for _, _, called, _ in fusions}
+    out = {}
+    for name, comp, called, scope in fusions:
+        if comp in fused:
+            continue  # a fusion inside a fusion: counted with its caller
+        inner = count(called)
+        named = [s for s in inner if s is not None]
+        if scope is None and not named:
+            continue
+        out[name] = {"scope": scope, "inner": dict(inner),
+                     "mixed": len(named) > 1}
     return out
 
 

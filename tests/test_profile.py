@@ -256,6 +256,255 @@ def test_host_spans_of_the_zero1_wrapper_land_on_the_host_plane(tmp_path):
 
 
 # --------------------------------------------------------------------------
+# What attention does outside its kernels, and a Mamba-2 mixer outside its
+# scan: five scopes where the work happens, and how far a fusion keeps them
+# --------------------------------------------------------------------------
+
+_INNER_BASE = dict(vocab_size=VOCAB, num_layers=2, num_heads=2, embed_dim=16,
+                   mlp_dim=32, max_seq_len=LENGTH, attention="dense",
+                   dtype=jnp.float32)
+# case: (what the configuration adds, the parts its attention holds each with
+# a module or an operation that must lie under it, the attention's or the
+# mixer's own parameters in block_0)
+INNER_CASES = {
+    "plain": (
+        {},
+        {profile.ATTN_PROJ: ("query", "key", "value", "out"),
+         profile.ATTN_ROPE: ("cos", "sin")},
+        ["key", "out", "query", "value"]),
+    "head_norm": (
+        dict(num_kv_heads=1, head_dim=8, qk_norm="head"),
+        {profile.ATTN_PROJ: ("query", "out"),
+         profile.ATTN_NORM: ("q_norm", "k_norm"),
+         profile.ATTN_ROPE: ("cos",)},
+        ["k_norm", "key", "out", "q_norm", "query", "value"]),
+    "whole_norm": (
+        dict(qk_norm=True),
+        {profile.ATTN_PROJ: ("key",),
+         profile.ATTN_NORM: ("q_norm", "k_norm"),
+         profile.ATTN_ROPE: ("sin",)},
+        ["k_norm", "key", "out", "q_norm", "query", "value"]),
+    "yarn_kinds": (
+        dict(num_kv_heads=1, head_dim=8, qk_norm="head",
+             attention_types=("window", "full"), attention_window=4,
+             rope_base=500000.0, rope_yarn=models.Yarn(16.0, 32.0, 1.0, 8)),
+        {profile.ATTN_PROJ: ("value", "out"),
+         profile.ATTN_NORM: ("q_norm",),
+         profile.ATTN_ROPE: ("cos",)},
+        ["k_norm", "key", "out", "q_norm", "query", "value"]),
+    "latent": (
+        dict(kv_lora_rank=8, q_lora_rank=12, qk_nope_dim=8, qk_rope_dim=4,
+             v_head_dim=8, rope_yarn=models.Yarn(64.0, 32, 1, 4096, 1.0, 1.0)),
+        {profile.ATTN_PROJ: ("q_a", "q_b", "kv_a", "kv_b", "out"),
+         profile.ATTN_NORM: ("q_norm", "kv_norm"),
+         profile.ATTN_ROPE: ("cos", "slice")},
+        ["kv_a", "kv_b", "kv_norm", "out", "q_a", "q_b", "q_norm"]),
+    "ssm": (
+        dict(num_kv_heads=1, head_dim=8, rotary=False,
+             layer_types=("ssm", "attn"), ssm_heads=4, ssm_head_dim=8,
+             ssm_groups=2, ssm_state=8, ssm_conv=4, ssm_chunk=8),
+        {profile.ATTN_PROJ: ("query", "out")},
+        ["A_log", "D", "conv_bias", "conv_kernel", "dt_bias", "in_proj",
+         "norm", "out_proj"]),
+}
+
+
+def _inner_model(case):
+    model = models.Transformer(
+        models.TransformerConfig(**dict(_INNER_BASE, **INNER_CASES[case][0])))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, LENGTH), 0, VOCAB)
+    params = model.init(jax.random.PRNGKey(0), tokens[:1])["params"]
+    return model, params, tokens
+
+
+def _inner_grad(case):
+    model, params, tokens = _inner_model(case)
+    return jax.jit(jax.grad(lambda p: jnp.sum(jnp.square(
+        model.apply({"params": p}, tokens))))), params
+
+
+def _inner_paths(case):
+    fun, params = _inner_grad(case)
+    text = fun.lower(params).as_text(debug_info=True)
+    return set(re.findall(r'loc\("([^"]+)"', text))
+
+
+def _tokens(path):
+    return [t for t in re.split(r"[/()]", path) if t]
+
+
+@pytest.mark.parametrize("case", list(INNER_CASES))
+def test_attention_names_what_it_does_outside_its_kernels(case):
+    paths = [_tokens(p) for p in _inner_paths(case)]
+    under_attn = [t for t in paths if profile.BLOCK in t and "attn" in t]
+    wanted = INNER_CASES[case][1]
+    for part in profile.ATTN_PARTS:
+        held = [t for t in under_attn if part in t]
+        if part not in wanted:
+            assert not held, (part, held[:1])  # no such work, no such name
+            continue
+        # the part lies INSIDE flax's `attn`, in both directions, and holds
+        # the modules and operations it is named for
+        assert all(t.index("attn") < t.index(part) for t in held)
+        assert any("transpose" in t for t in held), part
+        assert any("transpose" not in t for t in held), part
+        for what in wanted[part]:
+            assert any(x.startswith(what) for t in held
+                       for x in t[t.index(part) + 1:]), (part, what)
+    for t in paths:
+        # the parts stand beside each other, and nowhere but under `attn`
+        parts = [x for x in t if x in profile.ATTN_PARTS]
+        assert len(set(parts)) <= 1, t
+        assert not parts or "attn" in t, t
+    # what is left directly under `attn` (the scores and the softmax of the
+    # dense path here; reshapes and copies around the kernels on the chip)
+    # is under none of them: it is the reducer's `rest`
+    rest = [t for t in under_attn if not set(t) & set(profile.ATTN_PARTS)]
+    assert any(x.startswith(("exp", "reduce_max")) for t in rest for x in t)
+    # not model scopes: the half still reads `hvd_block/attn`
+    assert not set(profile.ATTN_PARTS) & set(profile.MODEL_SCOPES)
+
+
+def test_a_mixer_names_what_it_does_outside_its_scan():
+    paths = [_tokens(p) for p in _inner_paths("ssm")]
+    under = [t for t in paths if profile.SSM in t]
+    assert profile.SSM_PROJ in profile.SSM_SCOPES
+    assert profile.SSM_GATE in profile.SSM_SCOPES
+    for part, names in ((profile.SSM_PROJ, ("in_proj", "out_proj")),
+                        (profile.SSM_GATE, ("softplus", "silu", "rsqrt",
+                                            "exp", "square"))):
+        held = [t for t in under if part in t]
+        assert all(t.index(profile.SSM) < t.index(part) for t in held)
+        assert any("transpose" in t for t in held), part
+        assert any("transpose" not in t for t in held), part
+        for what in names:
+            assert any(x.startswith(what) for t in held
+                       for x in t[t.index(part) + 1:]), (part, what)
+    # the convolution and the scan stay outside both, and nothing of the two
+    # lies outside the mixer
+    new = {profile.SSM_PROJ, profile.SSM_GATE}
+    for t in paths:
+        assert not new & set(t) or profile.SSM in t, t
+        if profile.SSD in t or profile.SSM_CONV in t:
+            assert not new & set(t), t
+    assert any(profile.SSD in t for t in under)
+    assert any(profile.SSM_CONV in t for t in under)
+
+
+@pytest.mark.parametrize("case", list(INNER_CASES))
+def test_the_inner_scopes_change_no_parameter(case, monkeypatch):
+    _, named, _ = _inner_model(case)
+    mixer = "ssm" if case == "ssm" else "attn"
+    assert sorted(named["block_0"][mixer]) == INNER_CASES[case][2]
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    _, bare, _ = _inner_model(case)
+    assert jax.tree_util.tree_structure(named) \
+        == jax.tree_util.tree_structure(bare)
+    for a, b in zip(jax.tree_util.tree_leaves(named),
+                    jax.tree_util.tree_leaves(bare)):
+        np.testing.assert_array_equal(a, b)
+
+
+# One clean fusion (a projection's convert beside its dot), one mixed (a
+# norm's scaling fused into a rotation, part of it as a fusion of its own
+# inside, the way libtpu fuses a producer), one with none of the scopes, and
+# an instruction that is no fusion: the text a compiled step has, cut down.
+_FUSED_TEXT = """HloModule jit_shard_step, entry_computation_layout={()->f32[]}
+
+%fused_computation.1 (param_0.1: bf16[8,16], param_1.2: bf16[16,4]) -> bf16[8,4] {
+  %param_0.1 = bf16[8,16]{1,0} parameter(0)
+  %param_1.2 = bf16[16,4]{1,0} parameter(1)
+  %convert.3 = f32[8,16]{1,0} convert(%param_0.1), metadata={op_name="jit(shard_step)/hvd_fwd_bwd/jvp(Transformer)/hvd_block/block_0/attn/hvd_attn_proj/query/convert_element_type"}
+  ROOT %dot.4 = bf16[8,4]{1,0} dot(%convert.3, %param_1.2), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(shard_step)/hvd_fwd_bwd/jvp(Transformer)/hvd_block/block_0/attn/hvd_attn_proj/query/dot_general" source_file="t.py" source_line=7}
+}
+
+%fused_computation.9.clone (param_0.9: f32[8,4]) -> f32[8,4] {
+  %param_0.9 = f32[8,4]{1,0} parameter(0)
+  %rsqrt.9 = f32[8,4]{1,0} rsqrt(%param_0.9), metadata={op_name="jit(shard_step)/hvd_fwd_bwd/jvp(Transformer)/hvd_block/block_0/attn/hvd_attn_norm/q_norm/rsqrt"}
+  ROOT %multiply.9 = f32[8,4]{1,0} multiply(%rsqrt.9, %param_0.9), metadata={op_name="jit(shard_step)/hvd_fwd_bwd/jvp(Transformer)/hvd_block/block_0/attn/hvd_attn_norm/q_norm/mul"}
+}
+
+%fused_computation.2 (param_0.5: bf16[8,4], param_1.6: f32[4]) -> (bf16[8,4], f32[8]) {
+  %param_0.5 = bf16[8,4]{1,0} parameter(0)
+  %param_1.6 = f32[4]{0} parameter(1)
+  %constant.7 = f32[] constant(2)
+  %multiply.8 = f32[8,4]{1,0} multiply(%param_0.5, %param_0.5), metadata={op_name="jit(shard_step)/hvd_fwd_bwd/jvp(Transformer)/hvd_block/block_0/attn/hvd_attn_norm/q_norm/mul"}
+  %reduce.9 = f32[8]{0} reduce(%multiply.8, %constant.7), dimensions={1}, to_apply=%add, metadata={op_name="jit(shard_step)/hvd_fwd_bwd/jvp(Transformer)/hvd_block/block_0/attn/hvd_attn_norm/q_norm/reduce_sum"}
+  %fusion.9.clone = f32[8,4]{1,0} fusion(%multiply.8), kind=kLoop, calls=%fused_computation.9.clone
+  %cosine.10 = f32[8,4]{1,0} cosine(%fusion.9.clone), metadata={op_name="jit(shard_step)/hvd_fwd_bwd/transpose(jvp(Transformer))/hvd_block/block_0/attn/hvd_attn_rope/cos"}
+  %bitcast.11 = f32[8,4]{1,0} bitcast(%cosine.10)
+  ROOT %tuple.12 = (bf16[8,4]{1,0}, f32[8]{0}) tuple(%bitcast.11, %reduce.9)
+}
+
+%fused_computation.3 (param_0.13: f32[8]) -> f32[8] {
+  %param_0.13 = f32[8]{0} parameter(0)
+  ROOT %negate.14 = f32[8]{0} negate(%param_0.13), metadata={op_name="jit(shard_step)/hvd_optimizer/neg"}
+}
+
+ENTRY %main.20 (Arg_0.1: bf16[8,16], Arg_1.2: bf16[16,4], Arg_2.3: f32[4]) -> f32[8] {
+  %Arg_0.1 = bf16[8,16]{1,0} parameter(0)
+  %Arg_1.2 = bf16[16,4]{1,0} parameter(1)
+  %Arg_2.3 = f32[4]{0} parameter(2)
+  %fusion.1 = bf16[8,4]{1,0} fusion(%Arg_0.1, %Arg_1.2), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(shard_step)/hvd_fwd_bwd/jvp(Transformer)/hvd_block/block_0/attn/hvd_attn_proj/query/dot_general"}
+  %fusion.2 = (bf16[8,4]{1,0}, f32[8]{0}) fusion(%fusion.1, %Arg_2.3), kind=kLoop, calls=%fused_computation.2, metadata={op_name="jit(shard_step)/hvd_fwd_bwd/transpose(jvp(Transformer))/hvd_block/block_0/attn/hvd_attn_rope/cos"}
+  %get-tuple-element.15 = f32[8]{0} get-tuple-element(%fusion.2), index=1
+  %exponential.16 = f32[8]{0} exponential(%get-tuple-element.15), metadata={op_name="jit(shard_step)/hvd_fwd_bwd/jvp(Transformer)/hvd_block/block_0/attn/hvd_attn_norm/exp"}
+  ROOT %fusion.3 = f32[8]{0} fusion(%exponential.16), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(shard_step)/hvd_optimizer/neg"}
+}
+"""
+
+
+def test_fused_scopes_tells_a_clean_fusion_from_a_mixed_one():
+    assert profile.fused_scopes(_FUSED_TEXT, profile.ATTN_PARTS) == {
+        "fusion.1": {"scope": profile.ATTN_PROJ,
+                     "inner": {profile.ATTN_PROJ: 2}, "mixed": False},
+        # counted where its own name says (the rotation), though four of
+        # its seven instructions are the norm's (two of them inside a fusion
+        # of its own, which is counted by what IT holds) and two carry no
+        # name
+        "fusion.2": {"scope": profile.ATTN_ROPE,
+                     "inner": {profile.ATTN_NORM: 4, profile.ATTN_ROPE: 1,
+                               None: 2}, "mixed": True}}
+    # of the scopes asked for alone; with none of them, nothing
+    assert profile.fused_scopes(_FUSED_TEXT, (profile.ATTN_NORM,)) == {
+        "fusion.2": {"scope": None, "inner": {profile.ATTN_NORM: 4, None: 3},
+                     "mixed": False}}
+    # the innermost of the scopes on a path is the one that counts, so the
+    # block's scope may be asked for beside the parts inside it
+    wide = profile.fused_scopes(_FUSED_TEXT,
+                                profile.ATTN_PARTS + (profile.BLOCK,))
+    assert wide["fusion.1"]["inner"] == {profile.ATTN_PROJ: 2}
+    assert wide["fusion.2"]["scope"] == profile.ATTN_ROPE
+    assert profile.fused_scopes(_FUSED_TEXT, (profile.BLOCK,))["fusion.1"] \
+        == {"scope": profile.BLOCK, "inner": {profile.BLOCK: 2},
+            "mixed": False}
+    assert profile.fused_scopes(_FUSED_TEXT, (profile.SSM_GATE,)) == {}
+    assert profile.fused_scopes("", profile.ATTN_PARTS) == {}
+
+
+@pytest.mark.parametrize("case", ["head_norm", "ssm"])
+def test_fused_scopes_reads_a_compiled_step(case):
+    fun, params = _inner_grad(case)
+    text = fun.lower(params).compile().as_text()
+    scopes = profile.ATTN_PARTS + profile.SSM_SCOPES[1:]
+    found = profile.fused_scopes(text, scopes)
+    assert found
+    for name, fusion in found.items():
+        assert re.search(r"%?" + re.escape(name) + r" = .* fusion\(", text)
+        held = [s for s in fusion["inner"] if s is not None]
+        assert set(held) <= set(scopes) and fusion["scope"] in scopes + (None,)
+        assert fusion["mixed"] == (len(held) > 1)
+        assert held or fusion["scope"] is not None
+        assert all(n > 0 for n in fusion["inner"].values())
+    # XLA's CPU compiler fuses the elementwise work as the chip's does: the
+    # rotation's, or the gate's, is there under its own name
+    wanted = profile.SSM_GATE if case == "ssm" else profile.ATTN_ROPE
+    assert any(f["scope"] == wanted and f["inner"].get(wanted)
+               for f in found.values())
+
+
+# --------------------------------------------------------------------------
 # Getting going: the spans a process makes a bounded number of times
 # --------------------------------------------------------------------------
 
