@@ -629,14 +629,16 @@ def moe_rows_vs_jnp(T, k, D, experts, held, dtype, seed):
     from horovod_tpu.ops import moe_rows
     from horovod_tpu.parallel import expert
 
-    plan = profile.moe_rows_plan(T, k, D, dtype)
+    plan = profile.moe_rows_plan(T, k, D, dtype, experts=experts,
+                                 held=(0, held))
     print("  %s, %s [%d, %d] x %d choices: %s, tiles of %d of the buffer's "
           "%d rows, %d columns of the tokens resident, VMEM %.1f MiB, %d + "
-          "%d kernel calls a layer"
+          "%d kernel calls a layer; the order by %s (%d bins)"
           % (profile.MOE_ROWS, profile.MOE_SUM, T, D, k, plan["path"],
              plan["tile_rows"], plan["buffer_rows"], plan["block_cols"],
              plan["vmem_bytes"] / 2 ** 20, plan["calls_a_layer"]["forward"],
-             plan["calls_a_layer"]["backward"]), flush=True)
+             plan["calls_a_layer"]["backward"], plan["order"],
+             plan["bins"]), flush=True)
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
     x = jax.random.normal(keys[0], (T, D), dtype)
     ys, g_xs = (jax.random.normal(key, (k * T, D), dtype)
@@ -645,7 +647,7 @@ def moe_rows_vs_jnp(T, k, D, experts, held, dtype, seed):
     weights = jax.random.uniform(keys[4], (k, T), jnp.float32)
     chosen = jnp.argsort(jax.random.uniform(keys[5], (T, experts)),
                          axis=1)[:, :k].astype(jnp.int32)
-    _, order, inv, sizes = expert.sort_assignments(chosen, experts)
+    flat, order, inv, sizes = expert.sort_assignments(chosen, experts)
 
     def both(fn):
         def f(x, ys, weights, n_live, g_xs, g_y):
@@ -689,6 +691,35 @@ def moe_rows_vs_jnp(T, k, D, experts, held, dtype, seed):
               "%.2e dx %.2e dys %.2e dw %.2e (max rel to max |ref|, tol "
               "%.0e)" % ((profile.MOE_ROWS, int(n_live), k * T, what)
                          + tuple(errs) + (TOL["attn_bf16"],)))
+    if plan["order"] != "count":
+        return
+    # The order `moe_ffn` forms where it holds a part (`expert.held_order`:
+    # counted positions, one sort that carries the weights, the weights'
+    # gradient sorted back): the same live front, the same results.
+    n_live = jnp.sum(sizes[:held])
+
+    def counted(x, ys, w, n_live):
+        every, at, scale = expert.held_order(flat, w, 0, sizes[:held])
+        return (moe_rows.dispatch(x, every, at, n_live, k)[0],
+                moe_rows.combine(ys, w, every, at, n_live,
+                                 carried=(scale, every)))
+
+    every, at, _ = jax.jit(expert.held_order, static_argnums=2)(
+        flat, weights, 0, sizes[:held])
+    live = inv < n_live
+    check(bool(jnp.all(every[:int(n_live)] == order[:int(n_live)]))
+          and bool(jnp.all(jnp.where(live, at == inv, at >= n_live))),
+          "the counted order's live front of %d rows is the sorted order's"
+          % int(n_live))
+    args = args[:3] + (n_live,) + args[4:]
+    got, want = both(counted)(*args), compiled(*args)
+    live = (jnp.arange(k * T) < n_live)[:, None]
+    errs = [rel_err(*(jnp.where(live, a, 0) if a.shape[0] == k * T else a
+                      for a in pair)) for pair in zip(got, want)]
+    check(max(errs) == 0.0,
+          "%s on the counted order vs on the argsorts, %d rows live: xs "
+          "%.2e y %.2e dx %.2e dys %.2e dw %.2e (the same rows in the same "
+          "order: 0)" % ((profile.MOE_ROWS, int(n_live)) + tuple(errs)))
 
 
 def moe_act_vs_jnp(rows, F, act, gated, live, dtype, seed):

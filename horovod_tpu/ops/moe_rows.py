@@ -40,11 +40,17 @@ tiled operand in HBM ("Slice shape along dimension 0 must be aligned to
 tiling (8)"), and a row of a bf16 array is half of each word of its tile.
 
 `dispatch` and `combine` are the two differentiable ops `moe_ffn` calls;
-each one's transpose is the other kernel. `rows_plan` says which path a call
-takes; the kernels run what it returns. Where the width is no multiple of
-128 or no tile divides the shapes, and on a backend that is no TPU, the same
-results come from jnp (over all k*T rows, as before), unless
-`interpret=True` asks for the kernels in Pallas' interpreter (the tests do).
+each one's transpose is the other kernel. Where the layer's sort carried the
+weights with the order (`combine`'s `carried`: a layer that holds a part of
+the experts, `order_plan`), no [k*T] vector is gathered on either side of
+the kernels: the weights arrive sorted, and their gradient goes back into
+the assignments' order by a sort on the permutation (a gather of k*T scalars
+costs the v5e 0.45 ms, a sort of them a tenth: `examples/moe_order_sweep.py`).
+`rows_plan` says which path a call takes; the kernels run what it returns.
+Where the width is no multiple of 128 or no tile divides the shapes, and on
+a backend that is no TPU, the same results come from jnp (over all k*T rows,
+as before), unless `interpret=True` asks for the kernels in Pallas'
+interpreter (the tests do).
 """
 
 import functools
@@ -99,7 +105,20 @@ def _vmem_bytes(T, rows, cols, itemsize):
             + 2 * rows * _LANES * 4 * 2)
 
 
-def rows_plan(T, k, D, dtype=jnp.bfloat16):
+def order_plan(experts, held):
+    """(how `moe_ffn` forms the sorted order of its rows, the bins it counts
+    over): ("count", count) where the layer is told it holds `count` of its
+    `experts` (``held=(first, count)``) and they are not all of them, so that
+    a part of the k * T assignments is dead: `parallel/expert.held_order`,
+    ONE sort that carries the weights, the dead assignments behind the
+    `count` held bins. Else ("argsort", 0): `sort_assignments`' two argsorts
+    over all `experts` bins (every expert held, the capacity path)."""
+    if held is not None and experts is not None and held[1] < experts:
+        return "count", held[1]
+    return "argsort", 0
+
+
+def rows_plan(T, k, D, dtype=jnp.bfloat16, experts=None, held=None):
     """How the dispatch and the combine of a dropless local routed layer
     move their rows, x [T, D] in `dtype` with k choices a token
     (`hvd.profile.moe_rows_plan`; the ops run what this returns, where a
@@ -110,21 +129,28 @@ def rows_plan(T, k, D, dtype=jnp.bfloat16):
          "block_cols": columns of the token side resident at a time,
          "buffer_rows": k * T, whatever is live,
          "vmem_bytes": what a kernel's blocks take of VMEM at most,
-         "calls_a_layer": {"forward": 2, "backward": 2} kernel calls}
+         "calls_a_layer": {"forward": 2, "backward": 2} kernel calls,
+         "order": "count" or "argsort",
+         "bins": the bins counted over (0 under "argsort")}
 
     The path is "kernel" where the shapes fit (`_tiles`) and the backend is
     a TPU, whatever the layer holds of the experts (the count it tells the
     kernels is k * T where it holds them all); else "jnp": gathers, selects
-    and sums over all k * T rows."""
+    and sums over all k * T rows. The order (`order_plan`; whatever the
+    backend) is "count" where the layer routes over `experts` and is told
+    it holds ``held=(first, count)`` of them with count < experts, else
+    (and with neither given) "argsort"."""
     tiles = _kernel_tiles(T, k, D, dtype, None)
     rows, cols = tiles or (0, 0)
     calls = 2 if tiles else 0
+    order, bins = order_plan(experts, held)
     return {"path": "kernel" if tiles else "jnp", "tile_rows": rows,
             "block_cols": cols, "buffer_rows": k * T,
             "vmem_bytes": _vmem_bytes(T, rows, cols,
                                       jnp.dtype(dtype).itemsize)
             if tiles else 0,
-            "calls_a_layer": {"forward": calls, "backward": calls}}
+            "calls_a_layer": {"forward": calls, "backward": calls},
+            "order": order, "bins": bins}
 
 
 def _kernel_tiles(T, k, D, dtype, interpret):
@@ -345,7 +371,8 @@ def _pallas_sum(srcs, tok, n_live, scale, T, tiles, interpret):
     )(tok.astype(jnp.int32), n_live.astype(jnp.int32).reshape(1), *args)
 
 
-def rows_sum(srcs, order, inv, n_live, k, weights=None, interpret=None):
+def rows_sum(srcs, order, inv, n_live, k, weights=None, interpret=None,
+             scale=None):
     """srcs: arrays [S, D] in sorted order whose SUM's rows are meant (one,
     or the cotangents of the copies `dispatch` handed out), order [S]
     (`order[s]` = j*T + t: the assignment at sorted position s), inv [k*T]
@@ -357,7 +384,9 @@ def rows_sum(srcs, order, inv, n_live, k, weights=None, interpret=None):
     jnp rounds the sources' sum and adds in the order of j). S is k*T, or a
     whole number of T under it where no more rows can be live (`moe_ffn`'s
     cut): the token count is `inv`'s, never derived from the rows. Rows from
-    `n_live` on are selected away. `interpret`: as `rows_out`'s."""
+    `n_live` on are selected away. `scale` [S]: the weights in sorted order
+    where the caller's sort carried them (else gathered here by `order`).
+    `interpret`: as `rows_out`'s."""
     S, D = srcs[0].shape
     T = inv.shape[0] // k
     tiles = _kernel_tiles(T, S // T, D, srcs[0].dtype, interpret)
@@ -373,7 +402,8 @@ def rows_sum(srcs, order, inv, n_live, k, weights=None, interpret=None):
         return jnp.einsum("ktd,kt->td", rows, w,
                           preferred_element_type=jnp.float32
                           ).astype(src.dtype)
-    scale = None if weights is None else weights.reshape(-1)[order]
+    if scale is None and weights is not None:
+        scale = weights.reshape(-1)[order]
     return _pallas_sum(tuple(srcs), order % T, n_live, scale, T, tiles,
                        bool(interpret))
 
@@ -415,35 +445,56 @@ def _dispatch_bwd(k, copies, interpret, res, g):
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _combined(ys, weights, order, inv, n_live, interpret):
+def _combined(ys, weights, order, inv, n_live, interpret, carried):
     return rows_sum((ys,), order, inv, n_live, weights.shape[0], weights,
-                    interpret=interpret)
+                    interpret=interpret,
+                    scale=None if carried is None else carried[0])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def combine(ys, weights, order, inv, n_live, interpret=None):
+def combine(ys, weights, order, inv, n_live, interpret=None, carried=None):
     """ys [S, D] in sorted order (S and `order` as `dispatch`'s), weights
     [k, T] f32 -> y [T, D] in ys.dtype: each token's weighted sum over its
     choices whose row is live (`rows_sum`). Transposed as one `rows_out`:
     the cotangent's row of each live position times its weight, and its
     product with that position's row of `ys`, the weights' gradient. The
-    rows in assignment order are made in neither direction."""
-    return _combined(ys, weights, order, inv, n_live, interpret)
+    rows in assignment order are made in neither direction.
+
+    `carried`: what the caller's sort carried with the order, where it
+    carried anything (`parallel/expert.held_order`): (the weights in sorted
+    order [S], the assignment at each of ALL k*T sorted positions, a
+    permutation [k*T] whose front is `order`). The weights are then gathered
+    in neither direction: forward they are given, and their gradient is put
+    back into the assignments' order by a sort on that permutation. No
+    gradient flows through `carried`."""
+    return _combined(ys, weights, order, inv, n_live, interpret, carried)
 
 
-def _combine_fwd(ys, weights, order, inv, n_live, interpret):
-    return (_combined(ys, weights, order, inv, n_live, interpret),
-            (ys, weights, order, inv, n_live))
+def _combine_fwd(ys, weights, order, inv, n_live, interpret, carried):
+    return (_combined(ys, weights, order, inv, n_live, interpret, carried),
+            (ys, weights, order, inv, n_live, carried))
 
 
 def _combine_bwd(interpret, res, dy):
-    ys, weights, order, inv, n_live = res
+    ys, weights, order, inv, n_live, carried = res
     T = weights.shape[1]
-    d_ys, dots = rows_out(dy, order % T, n_live,
-                          scale=weights.reshape(-1)[order], other=ys,
+    tok = order % T
+    scale = weights.reshape(-1)[order] if carried is None else carried[0]
+    d_ys, dots = rows_out(dy, tok, n_live, scale=scale, other=ys,
                           interpret=interpret)
-    d_w = jnp.where(inv < n_live, dots[inv], 0.0).reshape(weights.shape)
-    return d_ys, d_w.astype(weights.dtype), None, None, None
+    if carried is None:
+        d_w = jnp.where(inv < n_live, dots[inv], 0.0)
+    else:
+        # Sorted by the assignment each position holds, `dots` is in the
+        # assignments' order (a cut buffer's are the front of them: nothing
+        # behind the cut is live). A dead slot's gradient is zero by its
+        # counted position, as above, whatever the kernel left.
+        every = carried[1]
+        dots = jnp.pad(dots, (0, every.shape[0] - dots.shape[0]))
+        d_w = lax.sort((every, dots), num_keys=1, is_stable=False)[1]
+        d_w = jnp.where(inv < n_live, d_w, 0.0)
+    return (d_ys, d_w.reshape(weights.shape).astype(weights.dtype),
+            None, None, None, None)
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)

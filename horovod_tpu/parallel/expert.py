@@ -23,7 +23,12 @@ this is the TPU-first ``ep`` member of the parallelism family
   with ``shared_dim``).
 * **sort** — the k*T assignments are sorted by expert (`sort_assignments`):
   a permutation, its inverse and the group sizes, which always sum to k*T.
-  Nothing here builds a [T, E, C] tensor.
+  Nothing here builds a [T, E, C] tensor. A layer told that it holds a PART
+  of the experts forms the held experts' run by itself (`held_order`): an
+  assignment's position by counting over the bins held, and one sort that
+  puts the dead assignments behind them and carries the weights, so that
+  neither all k*T are sorted twice nor any [k*T] vector gathered
+  (`ops/moe_rows.order_plan` says which; the same rows in the same order).
 * **dropless** (``capacity_factor=None``, the local path) — the tokens' rows
   are put into the sorted order (`ops/moe_rows.dispatch`), the experts run
   as a grouped matmul over the contiguous ragged groups (`grouped_matmul`),
@@ -139,12 +144,53 @@ def sort_assignments(experts, num_experts):
     flat = experts.T.reshape(-1)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     inv = jnp.argsort(order).astype(jnp.int32)
-    # A count by comparison, not a scatter-add (0.29 ms for 32768
-    # assignments on the v5e against microseconds).
-    group_sizes = jnp.sum(
+    return flat, order, inv, _group_sizes(flat, num_experts)
+
+
+def _group_sizes(flat, num_experts):
+    """How many of the assignments `flat` [kT] each expert takes, [E] int32:
+    a count by comparison, not a scatter-add (0.29 ms for 32768 assignments
+    on the v5e against microseconds)."""
+    return jnp.sum(
         flat[:, None] == jnp.arange(num_experts, dtype=flat.dtype)[None, :],
         axis=0, dtype=jnp.int32)
-    return flat, order, inv, group_sizes
+
+
+def held_order(flat, weights, first, sizes):
+    """The sorted order of the rows of the experts [first, first + count),
+    count = len(sizes), out of the k*T assignments `flat` (`sort_assignments`'
+    first result), where the others' rows are never read: by counting over
+    the `count` bins held and ONE sort, not by two argsorts over all E.
+
+    `weights` [k, T] f32: each assignment's weight; `sizes` [count] int32:
+    the held experts' group sizes, n_live their sum. Returns
+
+    - order [kT] int32: the assignment at sorted position s. Its front of
+      n_live positions is the held experts' run of `sort_assignments`' order
+      (an expert's first choices before its second), turned to the front;
+      behind it the dead assignments, ascending: a permutation.
+    - inv [kT] int32: the sorted position of assignment a, counted: its
+      bin's start + how many of its bin came before it (the exclusive
+      running count down the bin's row of the [count, kT] comparison); kT,
+      behind every row, where no held expert takes it.
+    - scale [kT] f32: the weights in sorted order, carried by the sort that
+      forms `order` (a gather of k*T scalars costs the v5e 0.45 ms, the sort
+      of three operands 0.05: `examples/moe_order_sweep.py`). No gradient
+      flows through it (`ops/moe_rows.combine`'s `carried`)."""
+    count, kT = sizes.shape[0], flat.shape[0]
+    hit = flat[None, :] == (first + jnp.arange(count, dtype=flat.dtype)
+                            )[:, None]
+    live = jnp.any(hit, axis=0)
+    starts = jnp.cumsum(sizes) - sizes
+    before = jnp.cumsum(hit, axis=1, dtype=jnp.int32) - 1
+    inv = jnp.where(live, jnp.sum(
+        jnp.where(hit, starts[:, None] + before, 0), axis=0), kT)
+    _, order, scale = lax.sort(
+        (jnp.where(live, flat - first, count),
+         jnp.arange(kT, dtype=jnp.int32),
+         lax.stop_gradient(weights).reshape(-1)),
+        num_keys=1, is_stable=True)
+    return order, inv, scale
 
 
 def relu2(h):
@@ -242,8 +288,15 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
     if rows is not None:
         x = rows
     T, D = x.shape
+    # How the sorted order is formed, by what the layer is told it holds.
+    counted = capacity_factor is None \
+        and moe_rows.order_plan(E, held)[0] == "count"
     with jax.named_scope(profile.MOE_DISPATCH):
-        flat, order, inv, group_sizes = sort_assignments(experts, E)
+        if counted:  # the held experts' run is formed by itself, below
+            flat = experts.T.reshape(-1)
+            group_sizes = _group_sizes(flat, E)
+        else:
+            flat, order, inv, group_sizes = sort_assignments(experts, E)
     with jax.named_scope(profile.MOE_ROUTE):
         balance, z = router_losses(logits, probs, group_sizes)
     stats = {"load_balance_loss": balance, "router_z_loss": z,
@@ -251,30 +304,38 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
     weights = weights.T  # [k, T], the assignments' order
 
     if capacity_factor is None:
-        kT = order.shape[0]
+        kT = flat.shape[0]
         with jax.named_scope(profile.MOE_DISPATCH):
             # The rows of the experts held here are one run of the sorted
             # order, and the dispatch and the combine are told how many
             # they are (`ops/moe_rows`: kernels that touch the live rows
             # alone where a TPU runs them). With every expert held the
             # run is the whole order and the count k*T, a constant.
-            sizes, n_live = group_sizes, jnp.int32(kT)
+            sizes, n_live, carried = group_sizes, jnp.int32(kT), None
             if held is not None:
                 # A token picks an expert once, so `count` experts are
                 # sent count * T rows at most: where that is under k * T
                 # (many choices, few held) the buffer is that long, a
                 # static cut, from the dispatch to the combine.
                 bound = min(kT, count * T)
-                # From `start`: turned to the front of the buffer, which
-                # so holds the run whatever the router does. The rows
-                # behind the `n_live` belong to no group.
-                start = jnp.sum(group_sizes[:first])
                 sizes = group_sizes[first:first + count]
                 n_live = jnp.sum(sizes)
-                at = jnp.arange(bound, dtype=jnp.int32)
-                order = order[(at + start) % kT]
-                inv = (inv - start) % kT
                 stats["held"] = n_live
+                if counted:
+                    # A part of the assignments is dead: no sort of them
+                    # all, no turn to the front, no gather of the weights.
+                    every, inv, scale = held_order(flat, weights, first,
+                                                   sizes)
+                    order = every[:bound]
+                    carried = (scale[:bound], every)
+                else:
+                    # From `start`: turned to the front of the buffer,
+                    # which so holds the run whatever the router does. The
+                    # rows behind the `n_live` belong to no group.
+                    start = jnp.sum(group_sizes[:first])
+                    at = jnp.arange(bound, dtype=jnp.int32)
+                    order = order[(at + start) % kT]
+                    inv = (inv - start) % kT
             xs = moe_rows.dispatch(x, order, inv, n_live, top_k,
                                    1 if w_gate is None else 2)
         with jax.named_scope(profile.MOE_EXPERTS):
@@ -294,7 +355,8 @@ def moe_ffn(x, router_w, w_in, w_out, capacity_factor=1.25,
                                                          meta=meta),
                           last)
         with jax.named_scope(profile.MOE_COMBINE):
-            y = moe_rows.combine(ys, weights, order, inv, n_live)
+            y = moe_rows.combine(ys, weights, order, inv, n_live,
+                                 carried=carried)
         stats["dropped"] = jnp.zeros((), jnp.int32)
         return y, stats
     C = moe_capacity(T, E, capacity_factor)

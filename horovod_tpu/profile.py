@@ -740,16 +740,24 @@ def hc_plan(*args, **kwargs):
 def moe_rows_plan(*args, **kwargs):
     """How the dispatch and the combine of `parallel.moe_ffn`'s dropless
     local path move the rows of a call: `ops.moe_rows.rows_plan(T, k, D,
-    dtype)` (its arguments and result), here beside the other program-side
-    counters. The path (`kernel`: `MOE_ROWS` and `MOE_SUM`, which touch the
-    live rows alone, where the shapes fit and a TPU runs it; `jnp`: gathers
-    and sums over all k * T rows of the buffer), a tile's rows, the columns
-    of the token side resident at a time, the buffer's rows, the VMEM bytes
-    a kernel's blocks take and the kernel calls a layer makes in each
-    direction. How many of the buffer's rows are live: all where the layer
-    holds every expert, else the router's to decide each step
-    (`parallel.routing_stats`' `held_share` counts it). The ops run what
-    this returns."""
+    dtype, experts=, held=)` (its arguments and result), here beside the
+    other program-side counters. The path (`kernel`: `MOE_ROWS` and
+    `MOE_SUM`, which touch the live rows alone, where the shapes fit and a
+    TPU runs it; `jnp`: gathers and sums over all k * T rows of the buffer),
+    a tile's rows, the columns of the token side resident at a time, the
+    buffer's rows, the VMEM bytes a kernel's blocks take and the kernel
+    calls a layer makes in each direction. How many of the buffer's rows are
+    live: all where the layer holds every expert, else the router's to
+    decide each step (`parallel.routing_stats`' `held_share` counts it).
+    And how the sorted order of the rows is formed (`order`, `bins`; PR 55):
+    `count` where the layer routes over `experts` and is told it holds
+    ``held=(first, count)`` of them, count < experts (the held experts' run
+    alone, by counting over its `bins` = count bins and ONE sort that
+    carries the weights, the weights' gradient sorted back:
+    `parallel/expert.held_order`); `argsort` (0 bins) where it holds them
+    all, is not told, or has a capacity (`sort_assignments`: two argsorts
+    of all k * T assignments, the weights gathered). The ops run what this
+    returns."""
     # `ops.moe_rows` imports this module for its kernels' names.
     from horovod_tpu.ops.moe_rows import rows_plan as plan
 

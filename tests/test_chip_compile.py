@@ -336,6 +336,41 @@ def test_moe_rows_compile_for_v5e(one_chip, monkeypatch):
     assert plan["path"] == "kernel" and plan["tile_rows"] == 1024
 
 
+# The same two ops on the order a layer forms where it holds a part of the
+# experts (`expert.held_order`, PR 55; `sdar30b_1chip` / `mellum12b_1chip`:
+# 8 x 8192 assignments, 16 bins; `nemo3s120b_1chip`: 22 x 4096, 8 bins, the
+# buffer cut to 8 x 4096 rows): one sort forward and one backward, and no
+# gather of a [k*T] vector on either side of the kernels.
+@pytest.mark.parametrize("T,k,D,count", [(8192, 8, 2048, 16),
+                                         (4096, 22, 1024, 8)])
+def test_moe_rows_on_the_counted_order_compile_for_v5e(one_chip, T, k, D,
+                                                       count):
+    from horovod_tpu.ops import moe_rows
+    from horovod_tpu.parallel import expert
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    bound = min(k * T, count * T)
+
+    def fwd_bwd(x, ys, w, flat, sizes, g_xs, g_y):
+        n_live = jnp.sum(sizes)
+
+        def ops(x, ys, w):
+            every, inv, scale = expert.held_order(flat, w, 3, sizes)
+            return (moe_rows.dispatch(x, every[:bound], inv, n_live, k, 1,
+                                      False)[0],
+                    moe_rows.combine(ys, w, every[:bound], inv, n_live,
+                                     False, (scale[:bound], every)))
+
+        out, vjp = jax.vjp(ops, x, ys, w)
+        return out, vjp((g_xs, g_y))
+
+    text = _compile(one_chip, fwd_bwd, ((T, D), bf16), ((bound, D), bf16),
+                    ((k, T), jnp.float32), ((k * T,), i32), ((count,), i32),
+                    ((bound, D), bf16), ((T, D), bf16))
+    assert _kernels(text) == 4, text[:2000]
+    assert text.count(" sort(") == 2 and " gather(" not in text
+
+
 # The activation between the grouped matmuls of the three cells whose layers
 # hold a part of their experts, with the last matmul, as `moe_ffn` calls them:
 # (rows, F, the activation, gated, experts held, the matmul's width).
