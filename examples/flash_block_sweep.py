@@ -43,6 +43,12 @@ at the plan's blocks:
     --bqp "" --bk "" --cut-k 128,256,512
     --B 1 --H 32 --G 4 --L 8192 --window 1024 --path q-held \
     --bqp "" --bk "" --cut-k 128,256,512
+`--D2 N`: scores of TWO products (latent attention: q2 [.., N] on one key a
+position for all heads beside q on k; no rule beside it). Every path has the
+form since PR 56; held by the q block the whole-sequence operands have one
+buffer each. The Kanana cell's call (`kanana30b_1chip`):
+    --B 1 --H 32 --L 8192 --D2 64 --path q-held,split --bqp 256,512,1024 \
+    --bk 512,1024
 GQA/MQA (--G < --H) sweeps the grouped-rows layout: the q-block
 candidates become bqp*group rows. The `_grouped_blocks` policy was
 tuned from this sweep at two points — B2 H6 G2 L8192 D128 (1536/512)
@@ -69,7 +75,7 @@ fa = importlib.import_module("horovod_tpu.ops.flash_attention")
 BWD = profile.FLASH_BWD
 
 
-def forms(B, H, L, D, group, dtype, rule):
+def forms(B, H, L, D, group, dtype, rule, D2=0):
     """{path: (`vmem_budget`, the one kernel's held sides `flash_plan` may
     try)} that forces a path: everything fits 2**40, so the order alone
     gives the one kernel by either side; with no side to try, the plan's
@@ -78,7 +84,7 @@ def forms(B, H, L, D, group, dtype, rule):
     k + v, which dQ holds too and no form of dK/dV fits; else nothing
     fits 0)."""
     fwd = fa.flash_plan(B, H, L, D, group, dtype, vmem_budget=2 ** 40,
-                        mask=rule)[profile.FLASH_FWD]
+                        shared_dim=D2, mask=rule)[profile.FLASH_FWD]
     return {"resident": (2 ** 40, ("k",)), "q-held": (2 ** 40, ("q",)),
             "split": (fa.RESIDENT_VMEM_BUDGET, ()),
             "gridded": (0 if rule is None else fwd.resident_bytes, ())}
@@ -120,6 +126,10 @@ def main():
                          "q-block candidates become bqp*group rows in "
                          "the grouped layout")
     ap.add_argument("--D", type=int, default=128)
+    ap.add_argument("--D2", type=int, default=0,
+                    help="width of a second score product on one key a "
+                         "position shared by the heads (latent attention's "
+                         "rotary slice); 0: one product")
     ap.add_argument("--path", default="all",
                     help="all, or of resident, split, q-held, gridded, "
                          "with commas")
@@ -147,6 +157,9 @@ def main():
     group = H // G
     if args.mask_block and args.window:
         ap.error("--mask-block and --window are one rule each: give one")
+    D2 = args.D2
+    if D2 and (args.mask_block or args.window):
+        ap.error("a mask by rule has one score product: --D2 or a rule")
     rule = (fa.BlockDiffusionMask(L // 2, args.mask_block)
             if args.mask_block else
             fa.BandMask(args.window) if args.window else None)
@@ -157,18 +170,23 @@ def main():
     k = jnp.asarray(rng.randn(B, G, L, D), jnp.bfloat16)
     v = jnp.asarray(rng.randn(B, G, L, D), jnp.bfloat16)
     g = jnp.asarray(rng.randn(B, H, L, D), jnp.bfloat16)
-    scale = D ** -0.5
+    shared = (jnp.asarray(rng.randn(B, H, L, D2), jnp.bfloat16),
+              jnp.asarray(rng.randn(B, 1, L, D2), jnp.bfloat16)) if D2 \
+        else None
+    scale = (D + D2) ** -0.5
     rows = L * group
     out, lse = jax.jit(lambda q, k, v: fa._pallas_forward_lse(
-        q, k, v, scale, causal, False, rule=rule))(q, k, v)
+        q, k, v, scale, causal, False, shared=shared, rule=rule))(q, k, v)
 
-    print("shape B=%d L=%d H=%d G=%d D=%d%s (kernel layout, %d rows/slab)"
-          % (B, L, H, G, D, "" if rule is None else " %r" % (rule,), rows))
+    print("shape B=%d L=%d H=%d G=%d D=%d%s%s (kernel layout, %d rows/slab)"
+          % (B, L, H, G, D, " D2=%d" % D2 if D2 else "",
+             "" if rule is None else " %r" % (rule,), rows))
     for backward in (False, True):
         for name, plan in fa.flash_plan(B, H, L, D, group, q.dtype,
-                                        backward, mask=rule).items():
+                                        backward, shared_dim=D2,
+                                        mask=rule).items():
             print("default plan %s: %s" % (name, plan._asdict()))
-    by_path = forms(B, H, L, D, group, q.dtype, rule)
+    by_path = forms(B, H, L, D, group, q.dtype, rule, D2)
     paths = tuple(by_path) if args.path == "all" else tuple(
         args.path.split(","))
     cuts = [int(x) for x in args.cut_k.split(",") if x] or [fa._CUT_K]
@@ -199,16 +217,16 @@ def main():
             def fwd(q, bq=bq, bk=bk):
                 return fa._pallas_forward_lse(
                     q, k, v, scale, causal, False, bq, bk, budget,
-                    rule=rule)[0]
+                    shared=shared, rule=rule)[0]
 
             def bwd(q, bq=bq, bk=bk):
                 return fa._pallas_backward(
                     q, k, v, out, lse, g, scale, causal, False, bq, bk,
-                    budget, rule=rule)
+                    budget, shared=shared, rule=rule)
 
             try:
                 plan = fa.flash_plan(B, H, L, D, group, q.dtype, True, bq,
-                                     bk, budget, mask=rule)
+                                     bk, budget, D2, rule)
             except ValueError:  # blocks the rule's length does not take
                 continue
             t_fwd = ms(fwd) if args.kernels == "all" else "-"
@@ -218,8 +236,10 @@ def main():
             else:
                 if path == "resident" and bq is not None:
                     continue  # blocks that do not tile: the split row's
-                t_dq = ms(lambda q: total(bwd(q)[0]))
-                t_dkv = ms(lambda q: total(*bwd(q)[1:]))
+                # (dq, dk, dv) and, under a second product, (dq2, dk2)
+                t_dq = ms(lambda q: total(*bwd(q)[0::3]))
+                t_dkv = ms(lambda q: total(*[
+                    x for i, x in enumerate(bwd(q)) if i % 3]))
                 t_bwd = (t_dq + t_dkv if isinstance(t_dq, float)
                          and isinstance(t_dkv, float) else "-")
             print("%9s %6s %6s %6s | %s" % (

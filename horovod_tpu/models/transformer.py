@@ -158,7 +158,8 @@ class TransformerConfig:
     moe_held: Optional[Tuple[int, int]] = None
     # Latent attention (DeepSeek-V2's MLA) where `kv_lora_rank` is set: keys
     # and values from one normed projection of that width, queries through
-    # one of `q_lora_rank` (required beside it); a head's q.k is
+    # a normed one of `q_lora_rank`, or where that is None (DeepSeek-V2-Lite,
+    # Kanana-2) straight from the state; a head's q.k is
     # `qk_nope_dim` wide without position and `qk_rope_dim` wide with
     # rotary, the rotary key ONE a token for all heads; values are
     # `v_head_dim` wide. `head_dim`, `num_kv_heads` and `qk_norm` do not
@@ -305,10 +306,6 @@ class TransformerConfig:
         if self.moe_held is not None and self.moe_capacity_factor is not None:
             raise ValueError("moe_held is the dropless path: give "
                              "moe_capacity_factor=None")
-        if self.kv_lora_rank is not None and self.q_lora_rank is None:
-            raise ValueError("kv_lora_rank (latent attention) needs "
-                             "q_lora_rank too: the queries' low-rank "
-                             "projection is the one form built")
         if self.kv_lora_rank is not None and self.attention not in (
                 "dense", "flash"):
             raise ValueError("kv_lora_rank (latent attention) runs with "
@@ -498,6 +495,7 @@ class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
 
         c_q = rms(W_qa h);  [q_nope | q_rope] = W_qb c_q   per head
+        (`q_lora_rank` None: [q_nope | q_rope] = W_q h, no W_qa, no norm)
         [c_kv | k_rope] = W_kva h;  c_kv = rms(c_kv)
         [k_nope | v] = W_kvb c_kv                           per head
         s = scale * (q_nope.k_nope + rot(q_rope).rot(k_rope)),  causal
@@ -522,12 +520,14 @@ class LatentAttention(nn.Module):
             use_bias=False, name=name)
         # The work outside the kernels under the profiler's three names
         # (`profile.ATTN_PARTS`): no module and no parameter name.
+        if cfg.q_lora_rank is not None:
+            with jax.named_scope(profile.ATTN_PROJ):
+                c_q = dense(cfg.q_lora_rank, "q_a")(x)
+            with jax.named_scope(profile.ATTN_NORM):
+                c_q = _rms_norm(cfg, "q_norm")(c_q)
         with jax.named_scope(profile.ATTN_PROJ):
-            c_q = dense(cfg.q_lora_rank, "q_a")(x)
-        with jax.named_scope(profile.ATTN_NORM):
-            c_q = _rms_norm(cfg, "q_norm")(c_q)
-        with jax.named_scope(profile.ATTN_PROJ):
-            q = heads(nope + rope, "q_b")(c_q)
+            q = heads(nope + rope, "q_b")(c_q) \
+                if cfg.q_lora_rank is not None else heads(nope + rope, "q")(x)
             kv = dense(cfg.kv_lora_rank + rope, "kv_a")(x)
         with jax.named_scope(profile.ATTN_ROPE):
             k_rope = kv[..., None, cfg.kv_lora_rank:]    # [B, L, 1, rope]

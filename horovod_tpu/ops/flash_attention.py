@@ -66,12 +66,17 @@ caller's positions): the kernels take q and k as they are scored.
 Scores of two products (`q_shared`, `k_shared`: latent attention's
 rotary slice): s = scale * (q.k^T + q2.k2^T) with k2 ONE key a position for
 every head, never broadcast in memory: its block's index is the batch's, so
-consecutive heads of a batch reuse the fetched block. The resident kernels
-take the second pair beside the first (the forward and the one-kernel
-backward at D=128, D2=64, L=4096: `flash_plan(..., shared_dim=64)`); the
-shared key's gradient leaves the kernel a head at a time and is summed over
-the heads outside. The gridded kernels have no such form: a call whose plan
-is not resident takes the blockwise jnp path.
+consecutive heads of a batch reuse the fetched block. Every kernel takes the
+second pair beside the first, in whichever form `flash_plan(...,
+shared_dim=64)` gives it: at D=128, D2=64 the forward resident up to
+L=16384 and the backward ONE kernel, held by the k block at L=4096 and by
+the q block at L=8192. Held by the q block the whole-sequence operands (k,
+v, k2, dk, dv, dk2) have ONE pipeline buffer each (a head's k and v wait
+for their copy once, 4 MiB against half a millisecond of work), and the
+shared key's gradient is summed over the heads where it is formed, in a
+third f32 accumulator that lives across all the heads of a batch: no
+head's part of it reaches HBM. From the kernels held by the k block and the
+gridded ones it leaves a head at a time and is summed outside.
 
 A mask by RULE over (query position, key position) (`mask=`; the rules
 are `BlockDiffusionMask` and `BandMask`, the causal band of sliding-window
@@ -160,12 +165,15 @@ def _causal_mask(s, q_off, kv_off, fill, group=1):
     return jnp.where(rows >= cols, s, fill)
 
 
-def _masked_scores(q, k, scale, causal, q_off, kv_off, fill, group=1):
+def _masked_scores(q, k, scale, causal, q_off, kv_off, fill, group=1,
+                   shared=None):
     """Scores of one grid step with causal masking. Only blocks
     straddling the diagonal pay the elementwise mask pass (the kernels
     are VPU-bound, every pass counts); `fill` is -inf for scores, 0 for
-    probabilities."""
-    s = _scores(q, k, scale)  # [BQ, BK]
+    probabilities. ``shared``: (q2, k2), the step's blocks of a second
+    score product (`_scores2`)."""
+    s = _scores(q, k, scale) if shared is None \
+        else _scores2(q, k, *shared, scale)  # [BQ, BK]
     if not causal:
         return s
     # q_off is the POSITION of the block's first row.
@@ -198,11 +206,16 @@ def _online_softmax_update(s, v_ref, acc_ref, m_ref, l_ref, guard_empty):
         preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, causal, num_kb, bqp, group):
+def _fwd_kernel(*refs, scale, causal, num_kb, bqp, group, shared=False):
     # q_ref: [BQ, D]; k_ref/v_ref: [BK, D]; o_ref: [BQ, D];
     # scratch: acc [BQ, D] f32, m/l [BQ, 128] f32 (state across k steps).
     # bqp = BQ // group: positions per q block (grouped GQA).
+    # `shared`: q2_ref [BQ, D2] and k2_ref [BK, D2] follow v (`_scores2`).
+    second = None
+    if shared:
+        second = refs[3:5]
+        refs = refs[:3] + refs[5:]
+    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     block_k = k_ref.shape[0]
@@ -223,7 +236,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # path — and accumulate in f32; only softmax runs in f32.
         s = _masked_scores(q_ref[...], k_ref[...], scale, causal,
                            q_off=qi * bqp, kv_off=kj * block_k,
-                           fill=-jnp.inf, group=group)
+                           fill=-jnp.inf, group=group,
+                           **({"shared": (second[0][...], second[1][...])}
+                              if shared else {}))
         _online_softmax_update(s, v_ref, acc_ref, m_ref, l_ref,
                                guard_empty=False)
 
@@ -903,7 +918,10 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
     if fused and k_held:
         accumulators = _vmem(rows, D, 4) + _vmem(rows, D2, 4)
     else:
-        accumulators = 2 * _vmem(L, D, 4) if fused else 0
+        accumulators = 2 * _vmem(L, D, 4) + _vmem(L, D2, 4) if fused else 0
+    # Held by the q block under a second product the whole-sequence operands
+    # have ONE pipeline buffer each (`_pallas_backward`).
+    copies = 1 if fused and not k_held and D2 else 2
 
     def q_side(n):  # one pipeline buffer of n rows of every q-side operand
         return (_vmem(n, D, n_q * isz) + n_stripes * _vmem(n, 8, 4)
@@ -924,7 +942,7 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
         return bq, bk
 
     whole = q_side(rows) if k_held else k_side(L)
-    resident = 2 * whole + accumulators
+    resident = copies * whole + accumulators
     if resident <= vmem_budget:
         bq, bk = blocks(_resident_blocks(D, L, group, kernel))
         bqp = bq // group
@@ -952,7 +970,8 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
     bq, bk = blocks(_grouped_blocks(D, L, group, backward))
     num_qb, num_kb = rows // bq, L // bk
     # acc / dq_acc (and m, l) per q block, or dk_acc + dv_acc per k block.
-    scratch = ((2 * _vmem(bk, D, 4) if dkv else _vmem(bq, D, 4))
+    scratch = ((2 * _vmem(bk, D, 4) + _vmem(bk, D2, 4) if dkv
+                else _vmem(bq, D, 4) + (_vmem(bq, D2, 4) if backward else 0))
                + (0 if backward else 2 * _vmem(bq, 128, 4)))
     grid = (BG, num_kb, num_qb) if dkv else (BG, num_qb, num_kb)
     return FlashKernelPlan("gridded", "k" if dkv else "q", bq, bk, grid,
@@ -986,19 +1005,26 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     128 in bf16 whatever the head group (24 MiB at L=8192), where the first
     holds 4 KiB a position and query head of the group (256 MiB with 8 at
     L=8192). Where neither fits the backward is two kernels, dK/dV held by
-    the k block where THAT fits and gridded where not. The second form has
-    one score product: no ``shared_dim``.
+    the k block where THAT fits and gridded where not.
 
     ``shared_dim`` = D2 > 0: the scores are of two products, q [.., D] on k
     and q2 [.., D2] on ONE key k2 a position for all H heads
     (`flash_attention`'s ``q_shared``, ``k_shared``); v is D wide. The sums
-    above then hold q2, k2 and their gradients too (D2 pads to 128 lanes):
-    at D=128, D2=64 in bf16 with one head a kv head the one-kernel backward
-    holds 22 MiB at L=4096 (16 without the second product) and stays one
-    kernel; at L=8192 dK/dV's 28 MiB is past the budget. Only the resident
-    kernels have this form: where one of a call's kernels would be gridded
-    the result is ``{}``, no kernel at all, and the call is the blockwise
-    jnp form.
+    above then hold q2, k2 and their gradients too (D2 pads to 128 lanes),
+    and the same order of forms is tried: at D=128, D2=64 in bf16 with one
+    head a kv head the one-kernel backward held by the k block holds 22 MiB
+    at L=4096 (16 without the second product) and 44 at 8192; held by the q
+    block it holds k, v, k2, dk, dv, dk2 in ONE pipeline buffer each (a
+    whole-sequence operand's block changes once a head, where a copy that
+    is waited for costs microseconds; the shared key's does not change
+    within a batch) and three f32 accumulators, of dK, dV and of dK2 (summed
+    over a batch's heads there: the grid's first axis runs in order): 3 KiB
+    a position, 24 MiB at L=8192, every call up to there. Past it the
+    backward is two kernels, dQ resident on k + v + k2 (double-buffered: 24
+    MiB at 16384) and dK/dV held by the k block, resident or gridded; the
+    forward is resident up to 16384 and gridded beyond. Every form is a
+    Pallas kernel: a call with a second product never takes the blockwise
+    jnp path on a TPU.
 
     ``mask``: a rule in place of the causal triangle; L counts ALL
     positions of the call (`BlockDiffusionMask`: 2 x the rule's length;
@@ -1012,8 +1038,8 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     forward resident on k + v, 8 MiB; the backward one kernel held by the q
     block: k, v, dk, dv and the two accumulators, 24 MiB, q + dO of a kv
     head being 64). Where the forward or dQ would be gridded the result is
-    ``{}`` and the call is the blockwise jnp form; a second score product
-    is refused beside a rule.
+    ``{}``, no kernel at all (the one case left), and the call is the
+    blockwise jnp form; a second score product is refused beside a rule.
 
     `_pallas_forward_lse` and `_pallas_backward` run what this returns,
     so it is also the counter that says which path a program took
@@ -1039,15 +1065,15 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
             (BG * n for n in tiles + subtiles))))
 
     def resident_or_none(plans):
-        if any(p.path != "resident" for name, p in plans.items()
-               if shared_dim or (mask is not None
-                                 and name != profile.FLASH_DKV)):
+        if mask is not None and any(
+                p.path != "resident" for name, p in plans.items()
+                if name != profile.FLASH_DKV):
             return {}
         return plans
 
     if not backward:
         return resident_or_none({profile.FLASH_FWD: plan(profile.FLASH_FWD)})
-    for held in ("k",) if shared_dim else _BWD_HELD:
+    for held in _BWD_HELD:
         fused = plan(profile.FLASH_BWD, held)
         if fused is not None:
             return {profile.FLASH_BWD: fused}
@@ -1055,13 +1081,15 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
                              profile.FLASH_DKV: plan(profile.FLASH_DKV)})
 
 
-def _compiler_params(plan, carries=False):
+def _compiler_params(plan, carries=False, heads_in_order=False):
     """``carries``: the resident grid's block axis carries state in
     scratch (the one-kernel backward's dQ, or its dK and dV where it is held
-    by the q block), so its steps run in order."""
+    by the q block), so its steps run in order. ``heads_in_order``: so does
+    its first axis (the shared key's gradient, summed over a batch's heads
+    in scratch)."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "arbitrary" if carries
-                             else "parallel")
+        dimension_semantics=("arbitrary" if heads_in_order else "parallel",
+                             "arbitrary" if carries else "parallel")
         if plan.path == "resident"
         else ("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=plan.vmem_limit_bytes)
@@ -1347,9 +1375,8 @@ def _bwd_dkv_resident_kernel(*refs, scale, causal, bq, bqp, group,
             lax.fori_loop(0, q_ref.shape[0] // bq, store, 0)
 
 
-def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, *dq_acc, scale,
-                       causal, bk, bqp, group, rule=None, cut_k=None):
+def _bwd_q_held_kernel(*refs, scale, causal, bk, bqp, group, rule=None,
+                       cut_k=None, shared=False, heads=1):
     """The whole backward (`hvd_flash_bwd`) held by the q block, with k and
     v whole in VMEM: on `_bwd_dq_resident_kernel`'s grid and walk, s, p, dp
     and ds of a tile formed once for dQ, dK and dV. A step takes a q block
@@ -1367,7 +1394,25 @@ def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     carry nothing and cost nothing to enter, and the walk is
     `_walk_cut_runs` at the key step `cut_k`: a cut k block with one
     sub-tile in sight is a turn of that sub-tile, its p^T.dO and ds^T.q
-    added at the sub-tile's rows."""
+    added at the sub-tile's rows.
+
+    `shared`: the scores are of two products (`_scores2`). q2_ref [BQ, D2]
+    and k2_ref [L, D2], the ONE key of the batch's `heads` kv heads, follow
+    v; dk2_ref [L, D2] follows dv, dq2_ref [BQ, D2] follows dq, and a third
+    f32 accumulator dk2_acc [L, D2] the other two. dQ2 of the block is the
+    loop's carry beside dQ. The shared key's gradient is summed where it is
+    formed: dk2_acc lives across ALL the heads of a batch (zeroed at its
+    first head's first q block, rounded once and written at its last head's
+    last), so that no head's part of it ever reaches HBM; the grid's first
+    axis then runs in order too."""
+    if shared:
+        (q_ref, k_ref, v_ref, q2_ref, k2_ref, do_ref, lse_ref, delta_ref,
+         dk_ref, dv_ref, dk2_ref, dq_ref, dq2_ref, dk_acc, dv_acc,
+         dk2_acc) = refs
+        q2 = q2_ref[...]
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dq_ref, dk_acc, dv_acc, *dq_acc) = refs
     qi = pl.program_id(1)
     num_kb = k_ref.shape[0] // bk
 
@@ -1385,17 +1430,32 @@ def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         lax.fori_loop(0, num_kb, zero, 0)
 
+    if shared:
+        head = pl.program_id(0) % heads
+
+        @pl.when((qi == 0) & (head == 0))
+        def _init_shared():
+            zeros = jnp.zeros((bk, dk2_acc.shape[1]), jnp.float32)
+
+            def zero(j, carry):
+                dk2_acc[k_block(j), :] = zeros
+                return carry
+
+            lax.fori_loop(0, num_kb, zero, 0)
+
     q = q_ref[...]
     do = do_ref[...]
     lse = lse_ref[:, :1]
     delta = delta_ref[:, :1]
 
     def tile(j, step, width, masked):
-        """ds.k of the `width` keys from tile `j` of `step` keys on, their
-        p^T.dO and ds^T.q added to dV's and dK's rows."""
+        """ds.k of the `width` keys from tile `j` of `step` keys on (and
+        ds.k2 beside it under `shared`), their p^T.dO and ds^T.q (and
+        ds^T.q2) added to dV's and dK's (and dK2's) rows."""
         at = pl.ds(pl.multiple_of(j * step, step), width)
         k = k_ref[at, :]
-        s = _scores(q, k, scale)
+        s = _scores2(q, k, q2, k2_ref[at, :], scale) if shared \
+            else _scores(q, k, scale)
         if masked:
             s = _mask_tile(s, rule, qi * bqp, j * step, group)
         p = jnp.exp(s - lse)  # masked entries: exp(-inf) = 0
@@ -1409,11 +1469,29 @@ def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[at, :] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return jax.lax.dot_general(
+        dq = jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if not shared:
+            return dq
+        dk2_acc[at, :] += jax.lax.dot_general(
+            ds, q2, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dq, jax.lax.dot_general(
+            ds, k2_ref[at, :], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    if rule is None:
+    if shared:
+        def visit2(j, carry, masked):
+            dq, dq2 = tile(j, bk, bk, masked)
+            return carry[0] + dq, carry[1] + dq2
+
+        dq, dq2 = _walk_k(
+            visit2, (jnp.zeros(q.shape, jnp.float32),
+                     jnp.zeros(q2.shape, jnp.float32)),
+            qi, bqp, bk, num_kb, causal, rule)
+        dq2_ref[...] = dq2.astype(dq2_ref.dtype)
+    elif rule is None:
         dq = _walk_k(
             lambda j, dq, masked: dq + tile(j, bk, bk, masked),
             jnp.zeros(q.shape, jnp.float32), qi, bqp, bk, num_kb, causal,
@@ -1440,19 +1518,41 @@ def _bwd_q_held_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         lax.fori_loop(0, num_kb, store, 0)
 
+    if shared:
+        @pl.when((qi == pl.num_programs(1) - 1) & (head == heads - 1))
+        def _finalize_shared():
+            def store(j, carry):
+                at = k_block(j)
+                dk2_ref[at, :] = dk2_acc[at, :].astype(dk2_ref.dtype)
+                return carry
 
-def _shared_operands(shared, B, G, group, plans):
+            lax.fori_loop(0, num_kb, store, 0)
+
+
+def _shared_operands(shared, B, G, group):
     """(q2 in the grouped-rows layout, k2 [B, L, D2], the index of k2's
     batch from a kernel's first grid index) of a call with a second score
-    product; refuses a plan that is not resident."""
+    product."""
     q2, k2 = shared
-    if not plans or any(p.path != "resident" for p in plans.values()):
-        raise NotImplementedError(
-            "scores of two products (q_shared, k_shared) exist in the "
-            "resident flash kernels only; `flash_plan(..., shared_dim=%d)` "
-            "says this call's are not" % q2.shape[-1])
     return (_to_rows(q2, group), k2.reshape(B, k2.shape[2], k2.shape[3]),
             lambda b: b // G)
+
+
+def _q_walk_shared_specs(plan, L, D2, group, causal, of_batch):
+    """Beside `_q_walk_specs`, the second product's (q2 spec, k2 spec): q2
+    by the q block; the shared key by its BATCH, whole where the kernel is
+    resident (the block index is the same for every head of a batch, so it
+    is fetched once a batch), else the k block the step's `_kv_index_map`
+    names."""
+    bq, bk = plan.block_q, plan.block_k
+    if plan.path == "resident":
+        return (pl.BlockSpec((None, bq, D2), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((None, L, D2),
+                             lambda b, i: (of_batch(b), 0, 0)))
+    kv_im = _kv_index_map(bq // group, bk, causal)
+    return (pl.BlockSpec((None, bq, D2), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, bk, D2), lambda b, i, j: (
+                of_batch(b),) + kv_im(b, i, j)[1:]))
 
 
 _RULED_CALLS = {}  # {what decides a ruled kernel's call: its jitted call}
@@ -1504,7 +1604,7 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     plans = flash_plan(B, H, L, D, group, q.dtype, False, block_q, block_k,
                        vmem_budget, D2, rule)
     if shared:
-        q2f, k2f, of_batch = _shared_operands(shared, B, G, group, plans)
+        q2f, k2f, of_batch = _shared_operands(shared, B, G, group)
     if rule is not None and not plans:
         raise NotImplementedError(
             "a mask by rule exists in the resident forward kernel only; "
@@ -1525,19 +1625,16 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
         scratch = []
     else:
         kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                                   num_kb=L // bk, bqp=bqp, group=group)
+                                   num_kb=L // bk, bqp=bqp, group=group,
+                                   **({"shared": True} if shared else {}))
         scratch = [
             pltpu.VMEM((bq, D), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ]
     q_spec = pl.BlockSpec((None, bq, D), q_im)
-    # The shared key whole, by its batch: the block index is the same for
-    # every head of a batch, so it is fetched once a batch.
-    shared_specs = [pl.BlockSpec((None, bq, D2), q_im),
-                    pl.BlockSpec((None, L, D2),
-                                 lambda b, i: (of_batch(b), 0, 0))] \
-        if shared else []
+    shared_specs = list(_q_walk_shared_specs(
+        plan, L, D2, group, causal, of_batch)) if shared else []
     out, lse = _ruled_call(pl.pallas_call(
         kernel,
         name=profile.FLASH_FWD,
@@ -1880,12 +1977,19 @@ def flash_ring_bwd_step(q, k, v, do, lse, delta, dq, dk, dv, q_offset,
     return dq, dk, dv
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, scale, causal, num_kb, bqp, group):
+def _bwd_dq_kernel(*refs, scale, causal, num_kb, bqp, group, shared=False):
     """dQ: grid (bg, q-block, k-block), k innermost sequential.
     Recomputes p = exp(s - lse) per block; dS = p * (dO.V^T - delta);
     dQ = sum_k dS.K * scale accumulated in VMEM scratch. lse and
-    delta = rowsum(dO*O) are precomputed per row and streamed in."""
+    delta = rowsum(dO*O) are precomputed per row and streamed in.
+    ``shared``: q2_ref [BQ, D2] and k2_ref [BK, D2] follow v, dq2_ref [BQ,
+    D2] follows dq and its f32 accumulator dq's."""
+    if shared:
+        (q_ref, k_ref, v_ref, q2_ref, k2_ref, do_ref, lse_ref, delta_ref,
+         dq_ref, dq2_ref, dq_acc, dq2_acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+         dq_acc) = refs
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     block_k = k_ref.shape[0]
@@ -1893,6 +1997,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     @pl.when(kj == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if shared:
+            dq2_acc[...] = jnp.zeros_like(dq2_acc)
 
     visible = (kj * block_k < (qi + 1) * bqp) if causal else kj >= 0
 
@@ -1902,7 +2008,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[...]
         s = _masked_scores(q, k, scale, causal,
                            q_off=qi * bqp, kv_off=kj * block_k,
-                           fill=-jnp.inf, group=group)
+                           fill=-jnp.inf, group=group,
+                           **({"shared": (q2_ref[...], k2_ref[...])}
+                              if shared else {}))
         p = jnp.exp(s - lse_ref[:, :1])  # masked entries: exp(-inf) = 0
         dp = jax.lax.dot_general(
             do_ref[...], v_ref[...], (((1,), (1,)), ((), ())),
@@ -1911,19 +2019,33 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_acc[...] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if shared:
+            dq2_acc[...] += jax.lax.dot_general(
+                ds.astype(k.dtype), k2_ref[...], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(kj == num_kb - 1)
     def _finalize():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+        if shared:
+            dq2_ref[...] = dq2_acc[...].astype(dq2_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                    dv_ref, dk_acc, dv_acc, *, scale, causal, num_qb, bqp,
-                    group, rule=None):
+def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rule=None,
+                    shared=False):
     """dK/dV: grid (bg, k-block, q-block), q innermost sequential.
     dV = sum_q P^T.dO; dK = sum_q dS^T.Q * scale. In the grouped GQA
     layout the q rows interleave the whole head group, so the group
-    reduction of dK/dV happens in these same accumulators."""
+    reduction of dK/dV happens in these same accumulators. ``shared``:
+    q2_ref [BQ, D2] and k2_ref [BK, D2] follow v, dk2_ref [BK, D2] (THIS
+    head's part of the shared key's gradient) follows dv and its f32
+    accumulator dv's."""
+    if shared:
+        (q_ref, k_ref, v_ref, q2_ref, k2_ref, do_ref, lse_ref, delta_ref,
+         dk_ref, dv_ref, dk2_ref, dk_acc, dv_acc, dk2_acc) = refs
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dk_acc, dv_acc) = refs
     kj = pl.program_id(1)
     qi = pl.program_id(2)
     block_k = k_ref.shape[0]
@@ -1932,6 +2054,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+        if shared:
+            dk2_acc[...] = jnp.zeros_like(dk2_acc)
 
     # Causal: q blocks entirely above this k block see none of it. A rule:
     # the q blocks of its runs for this k block (`_rule_q_index_map`
@@ -1956,7 +2080,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         else:
             s = _masked_scores(q, k, scale, causal,
                                q_off=qi * bqp, kv_off=kj * block_k,
-                               fill=-jnp.inf, group=group)
+                               fill=-jnp.inf, group=group,
+                               **({"shared": (q2_ref[...], k2_ref[...])}
+                                  if shared else {}))
         p = jnp.exp(s - lse_ref[:, :1])  # masked entries: exp(-inf) = 0
         p_lo = p.astype(do_ref.dtype)
         dv_acc[...] += jax.lax.dot_general(
@@ -1969,11 +2095,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if shared:
+            dk2_acc[...] += jax.lax.dot_general(
+                ds, q2_ref[...], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(qi == num_qb - 1)
     def _finalize():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        if shared:
+            dk2_ref[...] = dk2_acc[...].astype(dk2_ref.dtype)
 
 
 def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
@@ -2007,7 +2139,7 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     plans = flash_plan(B, H, L, D, group, q.dtype, True, block_q, block_k,
                        vmem_budget, D2, rule)
     if shared:
-        q2f, k2f, of_batch = _shared_operands(shared, B, G, group, plans)
+        q2f, k2f, of_batch = _shared_operands(shared, B, G, group)
         extra = {"shared": True}
         dq2_shape = jax.ShapeDtypeStruct((B * G, rows, D2), q2f.dtype)
     else:
@@ -2039,19 +2171,21 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         else:
             kernel = functools.partial(
                 _bwd_dq_kernel, scale=scale, causal=causal, num_kb=L // bk,
-                bqp=bqp, group=group)
-            scratch = [pltpu.VMEM((bq, D), jnp.float32)]
+                bqp=bqp, group=group, **({"shared": True} if shared else {}))
+            scratch = [pltpu.VMEM((bq, D), jnp.float32)] + (
+                [pltpu.VMEM((bq, D2), jnp.float32)] if shared else [])
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
-        q2_spec = pl.BlockSpec((None, bq, D2), q_im)
+        if shared:
+            q2_spec, k2_spec = _q_walk_shared_specs(plan, L, D2, group,
+                                                    causal, of_batch)
         dq = _ruled_call(pl.pallas_call(
             kernel,
             name=profile.FLASH_DQ,
             grid=plan.grid,
-            in_specs=[q_spec, kv_spec, kv_spec] + ([
-                q2_spec, pl.BlockSpec((None, L, D2),
-                                      lambda b, i: (of_batch(b), 0, 0))]
-                if shared else []) + [q_spec, stripe_spec, stripe_spec],
+            in_specs=[q_spec, kv_spec, kv_spec] + (
+                [q2_spec, k2_spec] if shared else []) + [
+                q_spec, stripe_spec, stripe_spec],
             out_specs=[q_spec, q2_spec] if shared else q_spec,
             out_shape=[dq_shape, dq2_shape] if shared else dq_shape,
             scratch_shapes=scratch,
@@ -2081,6 +2215,22 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
                    pltpu.VMEM((L, D), jnp.float32)] + (
                        [] if rule is None
                        else [pltpu.VMEM((bq, D), jnp.float32)])
+        if shared:
+            # The whole-sequence operands in ONE buffer each (`_kernel_plan`
+            # counts them so): a head's k and v wait for their copy, 4 MiB
+            # in a kernel of half a millisecond a head. The shared key and
+            # its gradient by their BATCH: fetched, and written, once for
+            # all its heads, summed over them in the third accumulator.
+            kernel = functools.partial(kernel, heads=G)
+            once = pl.Buffered(1)
+            k_spec = pl.BlockSpec((None, L, D), lambda b, i: (b, 0, 0),
+                                  pipeline_mode=once)
+            q2_spec = pl.BlockSpec((None, bq, D2), q_im)
+            k2_spec = dk2_spec = pl.BlockSpec(
+                (None, L, D2), lambda b, i: (of_batch(b), 0, 0),
+                pipeline_mode=once)
+            dk2_heads = B
+            scratch.append(pltpu.VMEM((L, D2), jnp.float32))
     elif plan.path == "resident":
         kernel = functools.partial(_bwd_dkv_resident_kernel, scale=scale,
                                    causal=causal, bq=bq, bqp=bqp,
@@ -2090,6 +2240,11 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         q2_spec = pl.BlockSpec((None, rows, D2), lambda b, j: (b, 0, 0))
         stripe_spec = pl.BlockSpec((None, rows, 8), lambda b, j: (b, 0, 0))
         k_spec = pl.BlockSpec((None, bk, D), k_im)
+        # This head's block of the shared key, and its part of the gradient.
+        k2_spec = pl.BlockSpec((None, bk, D2),
+                               lambda b, j: (of_batch(b), j, 0))
+        dk2_spec = pl.BlockSpec((None, bk, D2), k_im)
+        dk2_heads = B * G
         # dQ's accumulator across the k blocks of a (batch, kv head).
         scratch = [pltpu.VMEM((rows, D), jnp.float32)] if fused else []
         if fused and shared:
@@ -2097,39 +2252,43 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     else:
         kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
                                    causal=causal, num_qb=rows // bq,
-                                   bqp=bqp, group=group,
-                                   **({} if rule is None
-                                      else {"rule": rule}))
+                                   bqp=bqp, group=group, **extra)
         k_im = lambda b, j, i: (b, j, 0)                    # noqa: E731
         q_im = _q_index_map(bqp, bk, causal) if rule is None \
             else _rule_q_index_map(rule, bqp, bk, L)
         q_spec = pl.BlockSpec((None, bq, D), q_im)
         stripe_spec = pl.BlockSpec((None, bq, 8), q_im)
         k_spec = pl.BlockSpec((None, bk, D), k_im)
+        q2_spec = pl.BlockSpec((None, bq, D2), q_im)
+        k2_spec = pl.BlockSpec((None, bk, D2),
+                               lambda b, j, i: (of_batch(b), j, 0))
+        dk2_spec = pl.BlockSpec((None, bk, D2), k_im)
+        dk2_heads = B * G
         scratch = [pltpu.VMEM((bk, D), jnp.float32),
-                   pltpu.VMEM((bk, D), jnp.float32)]
+                   pltpu.VMEM((bk, D), jnp.float32)] + (
+                       [pltpu.VMEM((bk, D2), jnp.float32)] if shared else [])
     # A result whose block does not change across the grid's block axis (the
     # one kernel's dQ, or held by the q block dK and dV) is written back once.
     results = _ruled_call(pl.pallas_call(
         kernel,
         name=profile.FLASH_BWD if fused else profile.FLASH_DKV,
         grid=plan.grid,
-        in_specs=[q_spec, k_spec, k_spec] + ([
-            q2_spec, pl.BlockSpec((None, bk, D2),
-                                  lambda b, j: (of_batch(b), j, 0))]
-            if shared else []) + [q_spec, stripe_spec, stripe_spec],
+        in_specs=[q_spec, k_spec, k_spec] + (
+            [q2_spec, k2_spec] if shared else []) + [
+            q_spec, stripe_spec, stripe_spec],
         # Results in the kernel's order: dk, dv, [dk2], [dq, [dq2]].
-        out_specs=[k_spec, k_spec] + (
-            [pl.BlockSpec((None, bk, D2), k_im)] if shared else []) + (
+        out_specs=[k_spec, k_spec] + ([dk2_spec] if shared else []) + (
             [q_spec] + ([q2_spec] if shared else []) if fused else []),
         out_shape=[
             jax.ShapeDtypeStruct((B * G, L, D), k.dtype),
             jax.ShapeDtypeStruct((B * G, L, D), v.dtype),
-        ] + ([jax.ShapeDtypeStruct((B * G, L, D2), k2f.dtype)]
+        ] + ([jax.ShapeDtypeStruct((dk2_heads, L, D2), k2f.dtype)]
              if shared else []) + (
             [dq_shape] + ([dq2_shape] if shared else []) if fused else []),
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(plan, carries=fused),
+        compiler_params=_compiler_params(
+            plan, carries=fused,
+            heads_in_order=bool(shared) and plan.held == "q"),
         interpret=interpret,
     ), profile.FLASH_BWD if fused else profile.FLASH_DKV, rule, plan, inputs,
         scale, interpret)(*inputs)
@@ -2137,9 +2296,11 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
         dk, dv, dk2, *rest = results
         if fused:
             dq, dq2 = rest
-        # A head's part of the shared key's gradient each: summed in f32.
-        dk2 = jnp.sum(dk2.reshape(B, G, L, D2), axis=1, keepdims=True,
-                      dtype=jnp.float32).astype(dk2.dtype)
+        if dk2_heads == B:  # summed over the heads where it was formed
+            dk2 = dk2.reshape(B, 1, L, D2)
+        else:  # a head's part each: summed in f32
+            dk2 = jnp.sum(dk2.reshape(B, G, L, D2), axis=1, keepdims=True,
+                          dtype=jnp.float32).astype(dk2.dtype)
         return (_from_rows(dq, B, group), dk.reshape(B, G, L, D),
                 dv.reshape(B, G, L, D), _from_rows(dq2, B, group), dk2)
     if fused:
@@ -2357,9 +2518,6 @@ def flash_attention(q, k, v, causal=True, scale=None, q_shared=None,
             L, _grouped_blocks(D, L, group, backward=True)[0], group)
         is not None)
     if D2:
-        kernel_ok = kernel_ok and all(
-            flash_plan(B, H, L, D, group, q.dtype, backward,
-                       shared_dim=D2) for backward in (False, True))
         out = _flash_shared(qt, kt, vt, q_shared.transpose(0, 2, 1, 3),
                             k_shared.transpose(0, 2, 1, 3), scale, causal,
                             False if kernel_ok else None)

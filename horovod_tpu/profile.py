@@ -677,9 +677,11 @@ def flash_plan(*args, **kwargs):
     ``shared_dim=D2`` the call's scores are of two products (latent
     attention's rotary slice on one key shared by the heads): the same
     kernels hold the second pair of operands and the sums count them
-    (D=128, D2=64, L=4096: the one-kernel backward at 22 MiB of the 24);
-    ``{}`` says no kernel has that form at the shape (a gridded one would
-    be needed) and the call is the blockwise jnp path. With ``mask=`` a
+    (D=128, D2=64: the one-kernel backward held by the k block at L=4096,
+    22 MiB of the 24; at L=8192 held by the q block, the whole-sequence
+    operands in one buffer each and the shared key's gradient summed over
+    the heads in VMEM, 24 MiB; past it dQ and dK/dV apart, gridded where
+    they must be): every form is a kernel, none is ``{}``. With ``mask=`` a
     rule (`ops.BlockDiffusionMask(length, block)`; L counts all 2 x length
     positions) every plan also says how many score tiles of a call its
     kernel visits, masks and skips (`tiles_visited`, `tiles_masked`,

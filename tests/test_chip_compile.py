@@ -24,7 +24,8 @@ from jax.sharding import SingleDeviceSharding
 from chip_smoke import kernel_calls as _kernels, kernel_named as _named
 from horovod_tpu.ops import BandMask, BlockDiffusionMask, batch_norm
 from horovod_tpu import profile
-from horovod_tpu.ops.flash_attention import (_flash, _pallas_forward_lse,
+from horovod_tpu.ops.flash_attention import (_flash, _flash_shared,
+                                             _pallas_forward_lse,
                                              flash_plan,
                                              flash_ring_bwd_step,
                                              flash_ring_step)
@@ -436,6 +437,49 @@ def test_flash_at_a_head_group_of_16_compiles_for_v5e(one_chip):
                      profile.FLASH_BWD: ("resident", "q")}
     # the forward (for the residuals) and the backward
     assert _kernels(text) == 2, text[:2000]
+    for name in (profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV,
+                 profile.FLASH_BWD):
+        assert _named(text, name) == (name in plans), name
+
+
+# Latent attention's call (1 x 32 heads, D=128, a second score product 64
+# wide on one key a position): every form of it is a kernel (PR 56). At
+# 4096 the one-kernel backward held by the k block (`xing29b_1chip`); at
+# 8192 held by the q block, the whole-sequence operands in one pipeline
+# buffer each and the shared key's gradient summed over the heads in VMEM
+# (`kanana30b_1chip`); at 16384 dQ beside a gridded dK/dV; at 32768 all
+# three gridded.
+_TWO_PRODUCT_FORMS = {
+    4096: {profile.FLASH_FWD: ("resident", "q"),
+           profile.FLASH_BWD: ("resident", "k")},
+    8192: {profile.FLASH_FWD: ("resident", "q"),
+           profile.FLASH_BWD: ("resident", "q")},
+    16384: {profile.FLASH_FWD: ("resident", "q"),
+            profile.FLASH_DQ: ("resident", "q"),
+            profile.FLASH_DKV: ("gridded", "k")},
+    32768: {profile.FLASH_FWD: ("gridded", "q"),
+            profile.FLASH_DQ: ("gridded", "q"),
+            profile.FLASH_DKV: ("gridded", "k")}}
+
+
+@pytest.mark.parametrize("L", sorted(_TWO_PRODUCT_FORMS))
+def test_flash_of_two_products_compiles_for_v5e(one_chip, L):
+    B, H, D, D2 = 1, 32, 128, 64
+
+    def bwd(q, k, v, q2, k2, g):
+        _, vjp = jax.vjp(lambda *a: _flash_shared(
+            *a, (D + D2) ** -0.5, True, False), q, k, v, q2, k2)
+        return vjp(g)
+
+    bf16 = jnp.bfloat16
+    text = _compile(one_chip, bwd, *(((B, H, L, D), bf16),) * 3,
+                    ((B, H, L, D2), bf16), ((B, 1, L, D2), bf16),
+                    ((B, H, L, D), bf16))
+    plans = {name: (p.path, p.held) for backward in (False, True)
+             for name, p in flash_plan(B, H, L, D, 1, bf16, backward,
+                                       shared_dim=D2).items()}
+    assert plans == _TWO_PRODUCT_FORMS[L]
+    assert _kernels(text) == len(plans), text[:2000]
     for name in (profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV,
                  profile.FLASH_BWD):
         assert _named(text, name) == (name in plans), name

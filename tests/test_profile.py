@@ -805,7 +805,9 @@ def test_flash_plan_under_hvd_profile_is_the_kernels_own(backward, B, L,
 # attention call (16 query heads on one kv head) are the one kernel held by
 # the q block (k, v, dk, dv and the two accumulators: 24 and 12 MiB), as is
 # a grouped call at L=8192; a plain one at 16384 fits no resident form of
-# the backward but dQ's.
+# the backward but dQ's. Since PR 56 so is Kanana's call, scores of two
+# products at 8192 positions (one buffer a whole-sequence operand, the shared
+# key's gradient summed over the heads in a third accumulator: 24 MiB).
 _MiB = 2 ** 20
 _SDAR_TILES = (1280, 384, 2816)
 CELL_PLANS = {
@@ -824,6 +826,11 @@ CELL_PLANS = {
                           7602176, 19 * _MiB, None, None, None),
         "hvd_flash_bwd": ("resident", 512, 1024, (32, 4), 128, 22 * _MiB,
                           25 * _MiB, 50 * _MiB, None, None, None)}),
+    "kanana30b_1chip": (dict(B=1, H=32, L=8192, shared_dim=64), {
+        "hvd_flash_fwd": ("resident", 512, 512, (32, 16), 512, 12 * _MiB,
+                          13893632, 26 * _MiB, None, None, None),
+        "hvd_flash_bwd": ("resident", 512, 1024, (32, 16), 512, 24 * _MiB,
+                          27525120, 52 * _MiB, None, None, None)}),
     "sdar30b_1chip": (dict(B=1, H=32, L=8192, group=8, mask=(4096, 4)), {
         "hvd_flash_fwd": ("resident", 1024, 512, (4, 64), 256, 8 * _MiB,
                           10 * _MiB, 30 * _MiB) + _SDAR_TILES,
@@ -846,7 +853,8 @@ CELL_PLANS = {
 CELL_PLANS["lm1b4_dp4"] = CELL_PLANS["lm1b4_1chip"]
 CELL_PLANS["ouro2b6_1chip"] = CELL_PLANS["olmoe1b7_1chip"]
 # The calls whose one-kernel backward is held by the q block.
-_Q_HELD_BWD = ("sdar30b_1chip", "nemo3s120b_1chip", "grouped_L8192")
+_Q_HELD_BWD = ("sdar30b_1chip", "nemo3s120b_1chip", "grouped_L8192",
+               "kanana30b_1chip")
 
 
 def _cell_plans(B, H, L, mask=None, **kw):

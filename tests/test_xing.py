@@ -222,7 +222,7 @@ TWO_PRODUCT_CASES = {
 
 
 @pytest.mark.parametrize("case", list(TWO_PRODUCT_CASES))
-def test_flash_two_products_forward_and_every_gradient(case):
+def test_flash_two_products_forward_and_every_gradient(case, monkeypatch):
     how = TWO_PRODUCT_CASES[case]
     q, k, v, q2, k2, g = _two_product_case(G=how.get("G"))
     scale = 0.11
@@ -236,11 +236,14 @@ def test_flash_two_products_forward_and_every_gradient(case):
     assert list(plans) == [profile.FLASH_BWD]
     budget = fa.RESIDENT_VMEM_BUDGET
     if how.get("split"):
-        budget = plans[profile.FLASH_BWD].resident_bytes - 1
-        assert sorted(fa.flash_plan(
+        # Since PR 56 a budget under the one kernel's sum gives the one
+        # kernel held by the q block (`tests/test_kanana.py`); the two
+        # resident kernels are what is left with neither form allowed.
+        monkeypatch.setattr(fa, "_BWD_HELD", ())
+        assert {n: p.path for n, p in fa.flash_plan(
             B, H, L, D, 1, q.dtype, True, block_q=bq, block_k=bk,
-            vmem_budget=budget, shared_dim=64)) == [profile.FLASH_DKV,
-                                                    profile.FLASH_DQ]
+            shared_dim=64).items()} == {profile.FLASH_DQ: "resident",
+                                        profile.FLASH_DKV: "resident"}
     out, lse = fa._pallas_forward_lse(q, k, v, scale, True, True, bq, bk,
                                       shared=(q2, k2))
     _close(out, want, 5e-6)
@@ -273,9 +276,11 @@ def test_flash_plan_answers_for_two_score_widths():
     assert bwd[profile.FLASH_BWD].resident_bytes == 22 * 2 ** 20
     plain = profile.flash_plan(1, 32, 4096, 128, backward=True)
     assert plain[profile.FLASH_BWD].resident_bytes == 16 * 2 ** 20
-    # past the budget there is no kernel form: the call is blockwise jnp
-    assert profile.flash_plan(1, 32, 8192, 128, backward=True,
-                              shared_dim=64) == {}
+    # past that budget the one kernel is held by the q block (PR 56;
+    # `tests/test_kanana.py` holds the plans by length): no plan is empty
+    far = profile.flash_plan(1, 32, 8192, 128, backward=True, shared_dim=64)
+    assert [(n, p.held) for n, p in far.items()] == [
+        (profile.FLASH_BWD, "q")]
     # and without a second product the plan is what it was
     assert profile.flash_plan(2, 16, 2048, 128, backward=True) == \
         profile.flash_plan(2, 16, 2048, 128, backward=True, shared_dim=0)
@@ -301,11 +306,21 @@ def test_flash_two_products_refuses_by_name(case):
         fa.flash_attention(q, k, v, **kw)
 
 
-def test_gridded_kernels_refuse_a_second_product():
-    q, k, v, q2, k2, _ = _two_product_case(L=128)
-    with pytest.raises(NotImplementedError, match="resident"):
-        fa._pallas_forward_lse(q, k, v, 0.1, True, True, vmem_budget=0,
-                               shared=(q2, k2))
+def test_gridded_kernels_take_a_second_product():
+    """Until PR 56 a refusal ("resident kernels only"); now the gridded
+    forward, dQ and dK/dV hold the second pair of operands too."""
+    q, k, v, q2, k2, g = _two_product_case(L=128)
+    want, vjp = jax.vjp(lambda *a: _dense_two_products(*a, 0.1),
+                        q, k, v, q2, k2)
+    assert fa.flash_plan(*q.shape, 1, q.dtype, vmem_budget=0, shared_dim=64)[
+        profile.FLASH_FWD].path == "gridded"
+    out, lse = fa._pallas_forward_lse(q, k, v, 0.1, True, True,
+                                      vmem_budget=0, shared=(q2, k2))
+    _close(out, want, 5e-6)
+    grads = fa._pallas_backward(q, k, v, out, lse, g, 0.1, True, True,
+                                vmem_budget=0, shared=(q2, k2))
+    for got, want_g in zip(grads, vjp(g)):
+        _close(got, want_g, 2e-5)
 
 
 # --------------------------------------------------------------------------
@@ -609,8 +624,6 @@ REFUSED = {
     "ring attention with latent attention": (dict(attention="ring"),
                                              "kv_lora_rank"),
     "yarn without latent attention": (dict(kv_lora_rank=None), "rope_yarn"),
-    "latent keys without latent queries": (dict(q_lora_rank=None),
-                                           "needs q_lora_rank"),
     "two prediction modules": (dict(mtp_depth=2), "mtp_depth=2"),
     "no stream": (dict(hc_mult=0), "hc_mult=0"),
 }
@@ -621,6 +634,17 @@ def test_combinations_not_built_are_refused_by_name(case):
     over, match = REFUSED[case]
     with pytest.raises(ValueError, match=match):
         _cfg(**over)
+
+
+def test_latent_keys_without_latent_queries_are_built():
+    """Until PR 56 a refusal ("needs q_lora_rank"); now the queries come
+    straight from the state (`tests/test_kanana.py` holds the layer to its
+    reference)."""
+    cfg = _cfg(q_lora_rank=None)
+    _, params, _ = _seeded(cfg)
+    attn = params["block_0"]["attn"]
+    assert "q" in attn and not {"q_a", "q_norm", "q_b"} & set(attn)
+    assert attn["q"]["kernel"].shape == (HIDDEN, HEADS, 32 + 16)
 
 
 @pytest.mark.parametrize("field,over", [
