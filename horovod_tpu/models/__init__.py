@@ -27,7 +27,7 @@ from .resnet import (ResNet, ResNet18, ResNet34, ResNet50, ResNet50GN,  # noqa: 
 from .mnist import MnistCNN  # noqa: F401
 from .word2vec import SkipGram  # noqa: F401
 from .transformer import (Transformer, TransformerConfig, Yarn,  # noqa: F401
-                          hc_stats, ssd_stats)
+                          hc_stats, kda_stats, ssd_stats)
 from .block_diffusion import (block_diffusion_batch,  # noqa: F401
                               block_diffusion_noisy_half,
                               block_diffusion_stats)

@@ -122,6 +122,18 @@ class TransformerConfig:
     # "flash".
     attention_types: Optional[Tuple[str, ...]] = None
     attention_window: Optional[int] = None
+    # A "kda" layer of `attention_types` is a Kimi Delta Attention mixer
+    # (`KimiDeltaAttention`; Kimi Linear, arXiv:2510.26692) in attention's
+    # place: `num_heads` heads of `kda_head_dim` channels (the two low ranks
+    # are as wide), a causal depthwise convolution of `kda_conv` taps on q, k
+    # and v, the recurrence in chunks of `kda_chunk` tokens (`ops/kda.py`);
+    # the decay's starting values as `ssm_dt_init` says. It reads no
+    # position, and beside it a "full" layer may be latent attention
+    # (`kv_lora_rank`) with `rotary=False`: the KDA layers' decay is the
+    # stack's position.
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    kda_chunk: int = 64
     norm_eps: float = 1e-6        # every RMSNorm's epsilon
     # Passes over the ONE stack of blocks, on the same weights (a looped
     # or universal transformer; Ouro's `total_ut_steps`): `norm_f` closes
@@ -332,10 +344,12 @@ class TransformerConfig:
             raise ValueError("mtp_depth=%d: one multi-token prediction "
                              "module is built, not a chain of them"
                              % self.mtp_depth)
-        if not self.rotary and self.kv_lora_rank is not None:
+        if (not self.rotary and self.kv_lora_rank is not None
+                and "kda" not in (self.attention_types or ())):
             raise ValueError("rotary=False cannot be combined with "
                              "kv_lora_rank (latent attention's rotary slice "
-                             "is its only position)")
+                             "is its only position) but beside 'kda' layers "
+                             "in attention_types, whose decay is one")
         if self.moe_act not in ("silu", "relu2"):
             raise ValueError("moe_act=%r: 'silu' or 'relu2'"
                              % (self.moe_act,))
@@ -345,7 +359,7 @@ class TransformerConfig:
     def _check_attention_types(self):
         """What an attention kind a layer cannot be placed beside, by
         name."""
-        kinds = ("full", "window")
+        kinds = ("full", "window", "kda")
         types = self.attention_types
         if len(types) != self.num_layers or any(t not in kinds
                                                 for t in types):
@@ -359,13 +373,15 @@ class TransformerConfig:
         # The band is a rule of the flash kernels and of the dense form;
         # the sequence-parallel paths know `causal` alone, one mask serves
         # a stack under `attention_mask`, latent attention has its own
-        # rotation, a layer pattern's "attn" layers have no kind, nor do
+        # rotation and no band (its "full" layers stand beside "kda" ones
+        # alone), a layer pattern's "attn" layers have no kind, nor do
         # the streams' blocks and the prediction module's.
         for field, on in (
                 ("attention=%r" % self.attention,
                  self.attention in ("ring", "ulysses")),
                 ("attention_mask", self.attention_mask is not None),
-                ("kv_lora_rank", self.kv_lora_rank is not None),
+                ("kv_lora_rank", self.kv_lora_rank is not None
+                 and ("kda" not in types or "window" in types)),
                 ("layer_types", self.layer_types is not None),
                 ("hc_mult", self.hc_mult > 1),
                 ("mtp_depth", self.mtp_depth > 0)):
@@ -550,9 +566,10 @@ class LatentAttention(nn.Module):
             m = (yarn_mscale(yarn.factor, yarn.mscale)
                  / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
             scale *= yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
-        with jax.named_scope(profile.ATTN_ROPE):
-            q_rope = _rotary_freq(q_rope, positions, inv_freq, m)
-            k_rope = _rotary_freq(k_rope, positions, inv_freq, m)
+        if cfg.rotary:  # else no position: beside "kda" layers alone
+            with jax.named_scope(profile.ATTN_ROPE):
+                q_rope = _rotary_freq(q_rope, positions, inv_freq, m)
+                k_rope = _rotary_freq(k_rope, positions, inv_freq, m)
         if cfg.attention == "flash":
             from horovod_tpu.ops import flash_attention
             o = flash_attention(q_nope, k_nope, v, causal=True, scale=scale,
@@ -792,6 +809,30 @@ class Attention(nn.Module):
         return out
 
 
+def _conv_init(taps):
+    """A depthwise convolution's taps as torch's Conv1d draws them:
+    uniform in +-fan_in^-1/2."""
+    def init(key, shape, dtype):
+        bound = taps ** -0.5
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+    return init
+
+
+def _dt_bias_init(dt_init):
+    """The step bias whose softplus is a log-uniform time step: `dt_init` =
+    (least, largest, floor)."""
+    def init(key, shape, dtype):
+        lo, hi, floor = dt_init
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, dtype, math.log(lo), math.log(hi))), floor)
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
+    return init
+
+
+def _a_log_init(key, shape, dtype):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
 class Mamba2(nn.Module):
     """A Mamba-2 mixer (Dao & Gu, arXiv:2405.21060; Nemotron-H's layer `M`)
     on the normed state u [B, L, D], H heads of P channels in G groups, a
@@ -827,18 +868,9 @@ class Mamba2(nn.Module):
             return nn.Dense(n, dtype=cfg.dtype, param_dtype=f32,
                             use_bias=False, name=name)
 
-        def around_zero(key, shape, dtype):  # torch's Conv1d: +-fan_in^-1/2
-            bound = taps ** -0.5
-            return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-        def dt_bias_init(key, shape, dtype):
-            lo, hi, floor = cfg.ssm_dt_init
-            dt = jnp.maximum(jnp.exp(jax.random.uniform(
-                key, shape, dtype, math.log(lo), math.log(hi))), floor)
-            return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
-
-        def a_log_init(key, shape, dtype):
-            return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+        around_zero = _conv_init(taps)
+        dt_bias_init = _dt_bias_init(cfg.ssm_dt_init)
+        a_log_init = _a_log_init
 
         # Outside the convolution and the scan the profiler knows two parts
         # (`profile.SSM_PROJ`, `SSM_GATE`): no module, no parameter name.
@@ -891,6 +923,102 @@ def ssd_stats(intermediates):
     return jnp.max(jnp.stack(found))
 
 
+class KimiDeltaAttention(nn.Module):
+    """A Kimi Delta Attention mixer (Kimi Linear, arXiv:2510.26692) on the
+    normed state x [B, L, C], H heads of D channels, low ranks of D:
+
+        [q | k | v | f | z | b] = in_proj x   widths HD | HD | HD | D | D | H
+        q, k, v = silu(conv_causal_depthwise(.))         no bias
+        q = l2(q) D^-1/2;  k = l2(k)                     per head
+        g = -exp(A_log) softplus(f_up f + dt_bias)       [H, D] a token, f32
+        beta = sigmoid(b)                                per head
+        S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+        out = out_proj(rms_D(o) norm * sigmoid(g_up z))  norm [D], the heads'
+
+    The recurrence is `ops.kda.kda_chunked`; the convolution, the norms, the
+    decay and the gate are f32. No projection and no convolution has a
+    bias; `dt_bias` and `A_log` start as `Mamba2`'s. With |k| = 1 and beta
+    in (0, 1) the transition is a contraction: nothing is clamped. Sows
+    ``kda_state_max`` under ``intermediates`` (the largest |S| a chunk ends
+    in)."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from horovod_tpu.ops.kda import kda_chunked
+        cfg = self.cfg
+        H, D, taps = cfg.num_heads, cfg.kda_head_dim, cfg.kda_conv
+        inner = H * D
+        B, L, _ = x.shape
+        f32 = jnp.float32
+
+        def dense(n, name):
+            return nn.Dense(n, dtype=cfg.dtype, param_dtype=f32,
+                            use_bias=False, name=name)
+
+        around_zero = _conv_init(taps)
+        dt_bias_init = _dt_bias_init(cfg.ssm_dt_init)
+        a_log_init = _a_log_init
+
+        def heads(t):
+            return t.reshape(B, L, H, D)
+
+        def l2(t):
+            return t * lax.rsqrt(jnp.sum(jnp.square(t), axis=-1,
+                                         keepdims=True) + 1e-6)
+
+        with jax.named_scope(profile.KDA_PROJ):
+            proj = dense(3 * inner + 2 * D + H, "in_proj")(x)
+        qkv = proj[..., :3 * inner]
+        f = proj[..., 3 * inner:3 * inner + D]
+        z = proj[..., 3 * inner + D:3 * inner + 2 * D]
+        b = proj[..., 3 * inner + 2 * D:]
+        with jax.named_scope(profile.KDA_CONV):
+            w = self.param("conv_kernel", around_zero, (taps, 3 * inner), f32)
+            # Tap j reads the token taps - 1 - j behind, zeros before the
+            # sequence; widened a tap at a time, as `Mamba2`'s.
+            padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
+            qkv = nn.silu(sum(w[j] * padded[:, j:j + L].astype(f32)
+                              for j in range(taps)))
+        q, k, v = (heads(qkv[..., i * inner:(i + 1) * inner])
+                   for i in range(3))
+        with jax.named_scope(profile.KDA_PROJ):
+            f = dense(inner, "f_up")(f)
+            z = dense(inner, "g_up")(z)
+        with jax.named_scope(profile.KDA_GATE):
+            q = (l2(q) * D ** -0.5).astype(cfg.dtype)
+            k = l2(k).astype(cfg.dtype)
+            v = v.astype(cfg.dtype)
+            g = -jnp.exp(self.param("A_log", a_log_init, (H,), f32))[
+                :, None] * jax.nn.softplus(heads(
+                    f.astype(f32) + self.param("dt_bias", dt_bias_init,
+                                               (inner,), f32)))
+            beta = jax.nn.sigmoid(b.astype(f32))
+        o, _, state_max = kda_chunked(q, k, v, g, beta, cfg.kda_chunk)
+        self.sow("intermediates", "kda_state_max", state_max)
+        with jax.named_scope(profile.KDA_GATE):
+            o = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                              + cfg.norm_eps) * self.param(
+                                  "norm", nn.initializers.ones, (D,), f32)
+            o = (o * jax.nn.sigmoid(heads(z.astype(f32)))).reshape(
+                B, L, inner).astype(cfg.dtype)
+        with jax.named_scope(profile.KDA_PROJ):
+            return dense(cfg.embed_dim, "out_proj")(o)
+
+
+def kda_stats(intermediates):
+    """The largest |state| any KDA layer's scan ended a chunk in (f32
+    scalar), from the ``kda_state_max`` the mixers sowed under
+    ``intermediates``."""
+    from horovod_tpu.parallel.expert import _sown
+    found = _sown(intermediates, "kda_state_max")
+    if not found:
+        raise ValueError("no KimiDeltaAttention mixer sowed into these "
+                         "intermediates")
+    return jnp.max(jnp.stack(found))
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     moe: bool = False
@@ -898,7 +1026,7 @@ class Block(nn.Module):
     # "mlp"); None: the two-branch block.
     kind: Optional[str] = None
     # The two-branch block's attention kind under `attention_types` ("full"
-    # | "window"); None: the stack's one kind.
+    # | "window" | "kda"); None: the stack's one kind.
     attention_kind: Optional[str] = None
 
     @nn.compact
@@ -924,11 +1052,17 @@ class Block(nn.Module):
         # the two kinds share a kernel name and a shape, and the trace tells
         # them apart.
         kind = self.attention_kind
-        with jax.named_scope(profile.ATTN_KINDS[kind]) if kind \
-                else contextlib.nullcontext():
-            x = x + out("norm1_out", attention(
-                cfg, name="attn", **({"kind": kind} if kind else {}))(
-                    _rms_norm(cfg, "norm1")(x), positions))
+        if kind == "kda":
+            scope = jax.named_scope(profile.KDA)
+            mixer = KimiDeltaAttention(cfg, name="attn")
+        else:
+            scope = jax.named_scope(profile.ATTN_KINDS[kind]) if kind \
+                else contextlib.nullcontext()
+            module = attention(cfg, name="attn", **(
+                {"kind": kind} if kind and attention is Attention else {}))
+            mixer = lambda h: module(h, positions)  # noqa: E731
+        with scope:
+            x = x + out("norm1_out", mixer(_rms_norm(cfg, "norm1")(x)))
         h = _rms_norm(cfg, "norm2")(x)
         # `mlp` beside flax's `attn`: the profiler's scope for this half
         # of the block (hvd.profile), dense or routed; no module and no

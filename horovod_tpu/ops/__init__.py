@@ -28,6 +28,10 @@ Beside them, in jnp (XLA's fusions own it until a trace says otherwise):
 * :mod:`.ssd` — the selective state-space recurrence of a Mamba-2 layer
   in its chunked form: four matrix products a chunk, the chunk states
   carried by a scan, f32 decays and carry under bf16 operands.
+* :mod:`.kda` — Kimi Delta Attention's gated delta rule (a decay a channel)
+  in its chunked form: the decayed scores by sub-blocks, one
+  unit-lower-triangular solve a chunk (the WY form), a scan over the chunks
+  whose body multiplies WITH the carried state.
 """
 
 from .flash_attention import (  # noqa: F401

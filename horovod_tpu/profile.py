@@ -101,6 +101,26 @@ SSM_PROJ = "hvd_ssm_proj"
 SSM_GATE = "hvd_ssm_gate"
 SSM_SCOPES = (SSM, SSM_CONV, SSD, SSM_PROJ, SSM_GATE)
 
+# A Kimi Delta Attention mixer (`models/transformer.py::KimiDeltaAttention`,
+# a layer of kind "kda" in `attention_types`), inside `BLOCK` and around the
+# attention half of the two-branch block as `ATTN_FULL` is around a "full"
+# layer's: `KDA` around all of it (the norm before it and the residual add
+# too), and opened in the mixer itself `KDA_PROJ` around its projections (the
+# in-projection of q, k, v, the two low ranks' down sides and beta; the two
+# up sides; the output projection), `KDA_CONV` around the causal depthwise
+# convolutions, `KDA_GATE` around the elementwise part in f32 (the l2 norms,
+# the decay's softplus, beta's sigmoid, the gated head norm), and in
+# `ops/kda.py` `KDA_CHUNK` around what is made for all chunks at once (the
+# cumulative decays, the decayed scores, the solve, W and U) and `KDA_CARRY`
+# around the scan over the chunks. Not in MODEL_SCOPES.
+KDA = "hvd_kda"
+KDA_PROJ = "hvd_kda_proj"
+KDA_CONV = "hvd_kda_conv"
+KDA_GATE = "hvd_kda_gate"
+KDA_CHUNK = "hvd_kda_chunk"
+KDA_CARRY = "hvd_kda_carry"
+KDA_SCOPES = (KDA, KDA_PROJ, KDA_CONV, KDA_GATE, KDA_CHUNK, KDA_CARRY)
+
 # The hyper-connection around each of a block's two branches
 # (`models/transformer.py`, `hc_mult` > 1), inside `BLOCK` and beside the
 # branches' own `attn` / `mlp`: `HC` around all of it, `HC_MAP` (the norm
@@ -184,10 +204,16 @@ MOE_ROWS_KERNELS = (MOE_ROWS, MOE_SUM)
 MOE_ACT = "hvd_moe_act"          # a = act(g) * h, or act(h), s live
 MOE_ACT_BWD = "hvd_moe_act_bwd"  # (dg, dh), or dh, from (g, h, da)
 MOE_ACT_KERNELS = (MOE_ACT, MOE_ACT_BWD)
+# A sub-block's own decayed scores of the chunked KDA recurrence, term by
+# term (`ops/kda.py`, under `KDA_CHUNK`): q and k against k inside each
+# sub-block of 16 tokens, exp(G_t - G_s) a channel.
+KDA_SCORES = "hvd_kda_scores"          # (pq, pk) [sub, sub] a sub-block
+KDA_SCORES_BWD = "hvd_kda_scores_bwd"  # (dq, dk, dG) from their cotangents
+KDA_KERNELS = (KDA_SCORES, KDA_SCORES_BWD)
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
            RING_ATTN_DQ, RING_ATTN_DKV, BN_STATS,
            BN_GRAD_STATS) + MOE_GMM_KERNELS + (HC_STAT, HC_STAT_DPHI) \
-    + MOE_ROWS_KERNELS + MOE_ACT_KERNELS
+    + MOE_ROWS_KERNELS + MOE_ACT_KERNELS + KDA_KERNELS
 
 # Host spans a traced window shows: the program's only per-call Python
 # (`span`), and `step.place`, which is a `phase` (below) and so shows there

@@ -552,6 +552,33 @@ def test_chunked_scan_compiles_for_v5e(one_chip):
     assert "4096,4096" not in text
 
 
+# The Kimi-Linear cell's KDA layer: 32 heads of 128 at 8192 tokens in chunks
+# of 64 (PR 58). The two kernels of a sub-block's own decayed scores (3-D
+# blocks [64, 16, 128], reductions over the lanes and over the sublanes) and
+# the jnp around them: one scan over the 128 chunks, the solve, no [L, L].
+def test_chunked_kda_compiles_for_v5e(one_chip, monkeypatch):
+    from horovod_tpu.ops import kda
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    L, H, D = 8192, 32, 128
+    assert kda.own_plan(H * L // 16, 16, D) == kda.BLOCK_SUBS
+
+    def fwd_bwd(q, k, v, g, beta, cot):
+        out, vjp = jax.vjp(
+            lambda *a: kda.kda_chunked(*a, chunk=64)[0], q, k, v, g, beta)
+        return out, vjp(cot)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    text = _compile(one_chip, fwd_bwd, *[((1, L, H, D), bf16)] * 3,
+                    ((1, L, H, D), f32), ((1, L, H), f32),
+                    ((1, L, H, D), f32))
+    assert _named(text, profile.KDA_SCORES)
+    assert _named(text, profile.KDA_SCORES_BWD)
+    assert profile.KDA_CHUNK in text and profile.KDA_CARRY in text
+    # never an [L, L] array a head
+    assert "8192,8192" not in text
+
+
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------
 
 def _lm_step(topo, chips, monkeypatch, **more):

@@ -1,0 +1,497 @@
+"""Kimi-Linear-48B-A3B's stack at toy sizes on the CPU: the chunked Kimi
+Delta Attention recurrence (`ops/kda.py`) against the token-by-token one,
+the mixer and the model (KDA three layers in four, latent attention without
+position in the fourth, sigmoid experts beside a shared one) against
+`benchmark/references/kimi.py`, a routed layer's 32 shares, a train step,
+and what is refused by name.
+"""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+import optax
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.references import kimi as reference  # noqa: E402
+from horovod_tpu import models, parallel, profile  # noqa: E402
+from horovod_tpu.models import transformer  # noqa: E402
+from horovod_tpu.ops import kda  # noqa: E402
+from horovod_tpu.ops.losses import chunked_softmax_cross_entropy  # noqa: E402
+from horovod_tpu.parallel import expert  # noqa: E402
+
+jax.config.update("jax_default_matmul_precision", "highest")
+
+VOCAB, HIDDEN, HEADS, LENGTH = 256, 64, 2, 64
+EXPERTS, HELD, TOP_K, SCALE = 16, (4, 4), 4, 2.446
+KINDS = ("kda", "kda", "kda", "full", "kda", "full")
+
+
+def _cfg(**over):
+    base = dict(
+        vocab_size=VOCAB, num_layers=len(KINDS), num_heads=HEADS,
+        embed_dim=HIDDEN, mlp_dim=96, mlp_gated=True, max_seq_len=LENGTH,
+        attention="dense", norm_eps=1e-5, rotary=False,
+        attention_types=KINDS, kda_head_dim=32, kda_chunk=16,
+        kv_lora_rank=16, q_lora_rank=None, qk_nope_dim=32, qk_rope_dim=16,
+        v_head_dim=32, moe_experts=EXPERTS, moe_every=1, first_k_dense=1,
+        moe_dim=32, moe_top_k=TOP_K, moe_capacity_factor=None,
+        moe_gated=True, moe_renormalize=True, moe_scoring="sigmoid",
+        moe_route_scale=SCALE, moe_shared_dim=32, moe_held=HELD,
+        dtype=jnp.float32)
+    base.update(over)
+    return models.TransformerConfig(**base)
+
+
+def _arch(cfg, held=HELD):
+    return {"kinds": cfg.attention_types, "first_k_dense": cfg.first_k_dense,
+            "eps": cfg.norm_eps, "kda_heads": cfg.num_heads,
+            "kda_head_dim": cfg.kda_head_dim, "kda_chunk": cfg.kda_chunk,
+            "nope": cfg.qk_nope_dim,
+            "rope": cfg.qk_rope_dim, "top_k": cfg.moe_top_k,
+            "norm_topk_prob": cfg.moe_renormalize,
+            "route_scale": cfg.moe_route_scale, "held": held}
+
+
+def _seeded(cfg, seed=0):
+    """(model, parameters with every vector moved off its initial value:
+    norm scales, the selection bias, A_log and dt_bias; tokens [1,
+    LENGTH])."""
+    model = models.Transformer(cfg)
+    k_p, k_t, k_n = jax.random.split(jax.random.PRNGKey(seed), 3)
+    tokens = jax.random.randint(k_t, (1, LENGTH), 0, VOCAB, jnp.int32)
+    params = model.init(k_p, tokens)["params"]
+    flat, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(k_n, len(flat))
+    return model, jax.tree_util.tree_unflatten(tree, [
+        x + 0.3 * jax.random.normal(k, x.shape) if x.ndim == 1 else x
+        for k, x in zip(keys, flat)]), tokens
+
+
+def _close(a, b, tol):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    assert a.shape == b.shape
+    assert np.max(np.abs(a - b)) <= tol * max(1.0, np.max(np.abs(b))), \
+        np.max(np.abs(a - b))
+
+
+def _leaves_close(got, want, tol):
+    flat = jax.tree_util.tree_flatten_with_path(got)[0]
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(want)):
+        scale = max(float(jnp.max(jnp.abs(b))), 1e-3)
+        assert float(jnp.max(jnp.abs(a - b))) <= tol * scale, \
+            (jax.tree_util.keystr(path), float(jnp.max(jnp.abs(a - b))),
+             scale)
+
+
+# --------------------------------------------------------------------------
+# (a) The chunked recurrence against the token-by-token one
+# --------------------------------------------------------------------------
+
+def _recurrence_case(L, H=2, D=16, Dv=16, least=1e-3, seed=0):
+    """q, k of norm 1, v, g = log(alpha) with alpha drawn down to `least` a
+    channel and token (64 tokens at 1e-3 are e^-442: the product of two
+    exponentials would overflow), beta in (0, 1)."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa
+    return (unit(jax.random.normal(ks[0], (1, L, H, D))),
+            unit(jax.random.normal(ks[1], (1, L, H, D))),
+            jax.random.normal(ks[2], (1, L, H, Dv)),
+            jnp.log(least) * jax.random.uniform(ks[3], (1, L, H, D)),
+            jax.nn.sigmoid(jax.random.normal(ks[4], (1, L, H))))
+
+
+NAMES = ("q", "k", "v", "g", "beta")
+
+
+@pytest.mark.parametrize("chunk,sub", [(32, 8), (64, 16)])
+@pytest.mark.parametrize("what", ["output", "state", "state_max"]
+                         + ["d" + n for n in NAMES])
+def test_chunked_kda_agrees_with_the_recurrence(what, chunk, sub):
+    """Several chunks, decays down to 1e-3 a token: outputs, the final
+    state, the counter, and the gradient of each of the five inputs."""
+    args = _recurrence_case(L=4 * chunk, seed=chunk)
+    cot_o = jax.random.normal(jax.random.PRNGKey(7), args[2].shape)
+    cot_s = jax.random.normal(jax.random.PRNGKey(8), (1, 2, 16, 16))
+
+    def chunked(*a):
+        return kda.kda_chunked(*a, chunk=chunk, sub=sub)
+
+    def sequential(*a):
+        o, s, top = reference.kda_recurrence(*(t[0] for t in a), block=chunk)
+        return o[None], s[None], top
+
+    if not what.startswith("d"):
+        i = ("output", "state", "state_max").index(what)
+        got, want = chunked(*args)[i], sequential(*args)[i]
+        assert np.all(np.isfinite(np.asarray(got)))
+        _close(got, want, 2e-5)
+        return
+    i = NAMES.index(what[1:])
+
+    def scalar(f):
+        return lambda *a: (lambda r: jnp.sum(r[0] * cot_o)
+                           + jnp.sum(r[1] * cot_s))(f(*a))
+
+    got = jax.grad(scalar(chunked), argnums=i)(*args)
+    want = jax.grad(scalar(sequential), argnums=i)(*args)
+    assert np.all(np.isfinite(np.asarray(got)))
+    _close(got, want, 5e-5)
+
+
+def test_chunked_kda_takes_bf16_operands_and_refuses_a_ragged_length():
+    args = _recurrence_case(L=128)
+    q, k, v = (t.astype(jnp.bfloat16) for t in args[:3])
+    o, s, top = kda.kda_chunked(q, k, v, *args[3:], chunk=64)
+    assert o.dtype == s.dtype == top.dtype == jnp.float32
+    want = reference.kda_recurrence(*(t[0].astype(jnp.float32)
+                                      for t in (q, k, v)),
+                                    *(t[0] for t in args[3:]))
+    _close(o[0], want[0], 3e-2)
+    _close(s[0], want[1], 3e-2)
+    with pytest.raises(ValueError, match="multiple of the chunk"):
+        kda.kda_chunked(*_recurrence_case(L=96), chunk=64)
+    with pytest.raises(ValueError, match="sub-block"):
+        kda.kda_chunked(*args, chunk=64, sub=24)
+
+
+@pytest.mark.parametrize("what", ["scores", "dq", "dk", "dG"])
+@pytest.mark.parametrize("block,group", [(4, 4), (8, 2), (3, 1)])
+def test_the_own_block_kernels_agree_with_jnp(what, block, group,
+                                              monkeypatch):
+    """`hvd_kda_scores` / `hvd_kda_scores_bwd` in Pallas' interpreter
+    against the jnp form they stand for, at three ways of cutting the
+    sub-blocks into grid steps and register groups."""
+    monkeypatch.setattr(kda, "BLOCK_SUBS", block)
+    monkeypatch.setattr(kda, "GROUP", group)
+    N, sub, D = 24, 16, 128
+    ks = jax.random.split(jax.random.PRNGKey(block), 5)
+    q, k = (jax.random.normal(kk, (N, sub, D)) for kk in ks[:2])
+    G = jnp.cumsum(jnp.log(1e-3) * jax.random.uniform(ks[2], (N, sub, D)),
+                   axis=1)
+    cot = [jax.random.normal(kk, (N, sub, sub)) for kk in ks[3:]]
+    assert kda.own_plan(N, sub, D, interpret=True) == block
+    jax.clear_caches()
+
+    def kernels(*a):
+        return kda.own_block_scores(*a, interpret=True)
+
+    if what == "scores":
+        for got, want in zip(kernels(q, k, G), kda._own_jnp(q, k, G)):
+            assert float(jnp.max(jnp.abs(jnp.triu(got, 1)))) == 0.0
+            _close(got, want, 1e-5)
+        return
+    i = ("dq", "dk", "dG").index(what)
+
+    def scalar(f):
+        return lambda *a: sum(jnp.sum(r * c) for r, c in zip(f(*a), cot))
+
+    _close(jax.grad(scalar(kernels), argnums=i)(q, k, G),
+           jax.grad(scalar(kda._own_jnp), argnums=i)(q, k, G), 1e-5)
+
+
+def test_own_plan_says_which_calls_take_the_kernels():
+    assert kda.own_plan(16384, 16, 128, interpret=True) == kda.BLOCK_SUBS
+    assert kda.own_plan(8, 16, 128, interpret=True) == 8
+    # no TPU and no interpreter asked for; a narrow head; a short sub-block;
+    # sub-blocks no block divides
+    assert kda.own_plan(16384, 16, 128) is None
+    assert kda.own_plan(16384, 16, 64, interpret=True) is None
+    assert kda.own_plan(16384, 8, 128, interpret=True) is None
+    assert kda.own_plan(kda.BLOCK_SUBS + 1, 16, 128, interpret=True) is None
+
+
+def test_chunked_kda_through_the_kernels_agrees_with_the_recurrence(
+        monkeypatch):
+    monkeypatch.setattr(kda, "BLOCK_SUBS", 8)
+    jax.clear_caches()
+    args = _recurrence_case(L=128, H=2, D=128, Dv=128, seed=3)
+    cot = jax.random.normal(jax.random.PRNGKey(5), args[2].shape)
+
+    def chunked(*a):
+        return kda.kda_chunked(*a, chunk=64, interpret=True)[0]
+
+    def sequential(*a):
+        return reference.kda_recurrence(*(t[0] for t in a))[0][None]
+
+    _close(chunked(*args), sequential(*args), 2e-5)
+    got = jax.grad(lambda *a: jnp.sum(chunked(*a) * cot),
+                   argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(lambda *a: jnp.sum(sequential(*a) * cot),
+                    argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(got, want):
+        _close(a, b, 5e-5)
+
+
+def test_decayed_scores_never_form_an_l_by_l_array_or_a_token_loop():
+    """The program of the chunked form: one scan over the L / C chunks, no
+    array with two axes of the sequence's length."""
+    L, chunk = 256, 32
+    args = _recurrence_case(L=L)
+    jaxpr = jax.make_jaxpr(lambda *a: kda.kda_chunked(*a, chunk=chunk))(*args)
+    text = str(jaxpr)
+    assert text.count("scan[") == 1 and "length=%d" % (L // chunk) in text
+    assert "while[" not in text
+
+    def shapes(jp):
+        for eqn in jp.eqns:
+            for v in eqn.outvars:
+                yield getattr(v.aval, "shape", ())
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from shapes(sub)
+
+    assert not any(sum(1 for d in s if d >= L) > 1 for s in shapes(
+        jaxpr.jaxpr))
+
+
+# --------------------------------------------------------------------------
+# (b) The mixer and latent attention without position
+# --------------------------------------------------------------------------
+
+def test_the_kda_mixer_agrees_with_the_reference():
+    cfg = _cfg()
+    module = transformer.KimiDeltaAttention(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, LENGTH, HIDDEN))
+    p = module.init(jax.random.PRNGKey(1), x)["params"]
+    inner = HEADS * 32
+    assert {k: (v["kernel"] if isinstance(v, dict) else v).shape
+            for k, v in p.items()} == {
+        "in_proj": (HIDDEN, 3 * inner + 2 * 32 + HEADS),
+        "conv_kernel": (4, 3 * inner), "f_up": (32, inner),
+        "g_up": (32, inner), "A_log": (HEADS,), "dt_bias": (inner,),
+        "norm": (32,), "out_proj": (inner, HIDDEN)}
+    p = dict(p, norm=p["norm"] + 0.3 * jax.random.normal(
+        jax.random.PRNGKey(2), (32,)))
+    arch = _arch(cfg)
+    g = jax.random.normal(jax.random.PRNGKey(3), (LENGTH, HIDDEN))
+
+    def system(p, x):
+        y, state = module.apply({"params": p}, x, mutable=["intermediates"])
+        return y[0], state["intermediates"]["kda_state_max"][0]
+
+    want, top = reference.kda_mixer(x[0], p, arch)
+    _close(system(p, x)[0], want, 2e-5)
+    assert float(system(p, x)[1]) == pytest.approx(float(top), rel=1e-4)
+    got = jax.grad(lambda *a: jnp.sum(system(*a)[0] * g), argnums=(0, 1))(
+        p, x)
+    want = jax.grad(lambda p, x: jnp.sum(reference.kda_mixer(
+        x[0], p, arch)[0] * g), argnums=(0, 1))(p, x)
+    _leaves_close(got, want, 5e-4)
+
+
+@pytest.mark.parametrize("attention", ["dense", "flash"])
+def test_latent_attention_without_position(attention):
+    """`rotary=False` beside "kda" layers is the rotated layer turned by
+    nothing (every token at position 0: all angles are zero), and the
+    reference's."""
+    cfg = _cfg(attention=attention)
+    turned = _cfg(attention=attention, rotary=True, attention_types=None)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, LENGTH, HIDDEN))
+    pos = jnp.arange(LENGTH)[None]
+    p = transformer.LatentAttention(cfg).init(
+        jax.random.PRNGKey(1), x, pos)["params"]
+    got = transformer.LatentAttention(cfg).apply({"params": p}, x, pos)
+    _close(got, transformer.LatentAttention(turned).apply(
+        {"params": p}, x, jnp.zeros_like(pos)), 1e-6)
+    rotated = transformer.LatentAttention(turned).apply({"params": p}, x, pos)
+    assert float(jnp.max(jnp.abs(got - rotated))) > 1e-3
+    _close(got[0], reference.latent_attention(x[0], p, _arch(cfg)), 2e-5)
+    # and a position moves nothing
+    _close(got, transformer.LatentAttention(cfg).apply(
+        {"params": p}, x, pos + 5), 0.0)
+
+
+# --------------------------------------------------------------------------
+# (c) A routed layer's 32 shares
+# --------------------------------------------------------------------------
+
+def test_32_shares_of_a_routed_layer_add_up_to_the_uncut_one():
+    """8 of 256 experts each, sigmoid scores, top-8 on score + bias, the
+    weights renormalised x 2.446, the shared expert counted ONCE: the sum of
+    what 32 ranks compute is the reference's uncut layer."""
+    experts, top_k, dim, width = 256, 8, 32, 16
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, LENGTH, dim))
+    ks = jax.random.split(jax.random.PRNGKey(1), 8)
+    whole = {
+        "router": jax.random.normal(ks[0], (dim, experts)),
+        "select_bias": 0.3 * jax.random.normal(ks[1], (experts,)),
+        "w_gate": 0.3 * jax.random.normal(ks[2], (experts, dim, width)),
+        "w_up": 0.3 * jax.random.normal(ks[3], (experts, dim, width)),
+        "w_down": 0.3 * jax.random.normal(ks[4], (experts, width, dim)),
+        "shared_gate": {"kernel": 0.3 * jax.random.normal(ks[5], (dim, 16))},
+        "shared_up": {"kernel": 0.3 * jax.random.normal(ks[6], (dim, 16))},
+        "shared_down": {"kernel": 0.3 * jax.random.normal(ks[7], (16, dim))}}
+    arch = {"top_k": top_k, "norm_topk_prob": True, "route_scale": SCALE,
+            "held": (0, experts)}
+    want, chosen, _ = reference.routed_ffn(x[0], whole, arch)
+    shared_alone = reference.gated(
+        x[0], *(whole[n]["kernel"] for n in ("shared_gate", "shared_up",
+                                             "shared_down")))
+    total, rows = jnp.zeros_like(want), 0
+    for rank in range(32):
+        held = (8 * rank, 8)
+        module = expert.MoeMlp(
+            num_experts=experts, mlp_dim=width, capacity_factor=None,
+            top_k=top_k, gated=True, dtype=jnp.float32, scoring="sigmoid",
+            route_scale=SCALE, held=held, shared_dim=16)
+        p = dict(whole, **{n: whole[n][held[0]:held[0] + 8]
+                           for n in ("w_gate", "w_up", "w_down")})
+        y, state = module.apply({"params": p}, x, mutable=["intermediates"])
+        total = total + y[0] - shared_alone
+        stats = parallel.routing_stats(state["intermediates"])
+        rows += float(stats["held_share"][0])
+        if rank in (0, 31):  # the share is the reference's layer as HELD
+            _close(y[0], reference.routed_ffn(
+                x[0], p, dict(arch, held=held))[0], 5e-6)
+    _close(total + shared_alone, want, 2e-5)
+    assert rows == pytest.approx(1.0)
+    assert int(jnp.sum(chosen)) == top_k * LENGTH
+
+
+# --------------------------------------------------------------------------
+# (d) The whole model and one train step
+# --------------------------------------------------------------------------
+
+def _loss(model, params, tokens, chunk=16):
+    hid = model.apply({"params": params}, tokens, return_hidden=True)
+    return chunked_softmax_cross_entropy(
+        hid, params["lm_head"]["kernel"], jnp.roll(tokens, -1, 1),
+        chunk=chunk)
+
+
+@pytest.mark.parametrize("case", ["dense", "flash", "block_remat"])
+def test_loss_states_and_gradients_agree_with_the_reference(case):
+    cfg = _cfg(**{"dense": {}, "flash": {"attention": "flash"},
+                  "block_remat": {"block_remat": 5}}[case])
+    model, params, tokens = _seeded(cfg)
+    arch = _arch(cfg)
+    ref = reference.forward(params, tokens[0], arch)
+    hid, state = model.apply(
+        {"params": params}, tokens, return_hidden=True,
+        mutable=["intermediates"],
+        capture_intermediates=lambda m, n: isinstance(
+            m, transformer.Block) and n == "__call__")
+    inter = state["intermediates"]
+    for i in range(cfg.num_layers):
+        _close(inter["block_%d" % i]["__call__"][0][0], ref["states"][i],
+               5e-5)
+    assert float(models.kda_stats(inter)) == pytest.approx(
+        float(ref["kda_state_max"]), rel=1e-4)
+    stats = parallel.routing_stats(inter)
+    chosen = jnp.any(jax.nn.one_hot(stats["chosen"], EXPERTS,
+                                    dtype=jnp.bool_), axis=-2)
+    assert bool(jnp.all(chosen == ref["chosen"]))
+    assert [int(v) for v in stats["assignments"][
+        :, HELD[0]:HELD[0] + HELD[1]].sum(1)] \
+        == [int(v) for v in ref["held_rows"]]
+    loss, grads = jax.value_and_grad(
+        lambda p: _loss(model, p, tokens))(params)
+    assert float(loss) == pytest.approx(float(ref["loss"]), rel=2e-6)
+    want = reference.gradient(params, tokens[0], arch)
+    # a layer of each kind: KDA, latent, dense, routed
+    for name in ("block_0", "block_3", "block_4", "block_5", "embed"):
+        _leaves_close(grads[name], want[name], 1e-3)
+
+
+@pytest.mark.parametrize("other", ["shared", "decay"])
+def test_the_reference_of_another_model_is_far(other):
+    """Without the shared expert, and with alpha = 1 (the plain delta rule):
+    the comparison a chip run makes must see both."""
+    cfg = _cfg()
+    _, params, tokens = _seeded(cfg)
+    arch = _arch(cfg)
+    ref = reference.forward(params, tokens[0], arch)["states"]
+    off = reference.forward(params, tokens[0], arch,
+                            **{other: 0.0})["states"]
+    err = jnp.max(jnp.abs(ref - off), axis=(1, 2)) \
+        / jnp.max(jnp.abs(ref), axis=(1, 2))
+    assert float(jnp.max(err)) > 0.05
+
+
+def test_one_train_step_agrees_with_the_reference():
+    cfg = _cfg()
+    model, params, tokens = _seeded(cfg)
+    opt = optax.adamw(1e-3, b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1)
+    mesh = parallel.data_parallel_mesh(devices=jax.devices()[:1])
+    step = parallel.make_train_step(
+        lambda p, b: _loss(model, p, b["x"]), opt, mesh)
+    arch = _arch(cfg)
+    want_loss = float(reference.forward(params, tokens[0], arch)["loss"])
+    grads = reference.gradient(params, tokens[0], arch)
+    updates, _ = opt.update(grads, opt.init(params), params)
+    want = optax.apply_updates(params, updates)
+    state = step.place(jax.tree_util.tree_map(jnp.copy, params),
+                       opt.init(params), {"x": tokens})
+    new, _, loss = step(*state)
+    assert float(loss) == pytest.approx(want_loss, rel=2e-6)
+    # Adam's first step is lr * sign(g) wherever |g| >> eps: where the two
+    # gradients agree in sign the parameters agree to rounding.
+    moved = jax.tree_util.tree_map(
+        lambda a, b, g: jnp.mean((jnp.abs(a - b) <= 1e-6)
+                                 | (jnp.abs(g) < 1e-6)), new, want, grads)
+    assert min(float(v) for v in jax.tree_util.tree_leaves(moved)) > 0.97
+
+
+def test_the_program_names_the_mixers_parts():
+    cfg = _cfg()
+    model, params, tokens = _seeded(cfg)
+    text = jax.jit(jax.grad(lambda p: _loss(model, p, tokens))).lower(
+        params).as_text(debug_info=True)
+    assert profile.KDA_SCOPES == (
+        "hvd_kda", "hvd_kda_proj", "hvd_kda_conv", "hvd_kda_gate",
+        "hvd_kda_chunk", "hvd_kda_carry")
+    for i, kind in enumerate(KINDS):
+        here = "block_%d/%s" % (i, profile.KDA if kind == "kda"
+                                else profile.ATTN_FULL)
+        there = "block_%d/%s" % (i, profile.ATTN_FULL if kind == "kda"
+                                 else profile.KDA)
+        assert here in text and there not in text
+    for part in profile.KDA_SCOPES[1:]:
+        assert "%s/attn/%s" % (profile.KDA, part) in text
+    for turn in ("cos", "sin"):  # nothing is rotated
+        assert "%s/%s" % (profile.ATTN_ROPE, turn) not in text
+
+
+# --------------------------------------------------------------------------
+# (e) What is not built is refused by name
+# --------------------------------------------------------------------------
+
+REFUSED = {
+    "hc_mult": dict(hc_mult=2),
+    "mtp_depth": dict(mtp_depth=1),
+    "attention_mask": dict(attention_mask=object()),
+    "tp_axis": dict(tp_axis="tp", moe_experts=None, moe_held=None,
+                    moe_scoring="softmax", moe_shared_dim=None,
+                    first_k_dense=0, moe_dim=None),
+    "sp_axis": dict(attention="ring", sp_axis="sp"),
+    "layer_types": dict(layer_types=("attn",) * len(KINDS)),
+    # latent attention has no band, and its no-position form stands beside
+    # "kda" layers alone
+    "kv_lora_rank": dict(attention_types=("kda", "window") * 3,
+                         attention_window=8),
+    "rotary=False": dict(attention_types=None),
+    "each of full, window, kda": dict(
+        attention_types=("kda", "linear") * 3)}
+
+
+@pytest.mark.parametrize("case", list(REFUSED))
+def test_combinations_not_built_are_refused_by_name(case):
+    with pytest.raises(ValueError) as err:
+        _cfg(**REFUSED[case])
+    assert case in str(err.value)
+
+
+def test_kda_layers_stand_beside_plain_attention_too():
+    """Without `kv_lora_rank` the "full" layers are plain attention, rotated
+    or not."""
+    for rotary in (True, False):
+        cfg = _cfg(kv_lora_rank=None, rotary=rotary, head_dim=32)
+        model, params, tokens = _seeded(cfg)
+        assert "query" in params["block_3"]["attn"]
+        assert "in_proj" in params["block_0"]["attn"]
+        assert np.isfinite(float(_loss(model, params, tokens)))
