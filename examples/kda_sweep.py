@@ -5,12 +5,17 @@ bf16 operands, f32 decays; `benchmark/configs/kimilinear48b_a3b_w2304.json`):
 the whole call forward and forward + backward; the two Pallas kernels of a
 sub-block's own decayed scores (`own_block_scores`) at each `--blocks` x
 `--groups` (sub-blocks a grid step, sub-blocks its loop holds at a time)
-against the jnp form where that fits; the unit-lower-triangular solve alone.
-Each kernel form is held to the jnp one on a few sub-blocks before it is
-timed.
+against the jnp form where that fits; the unit-lower-triangular solve alone;
+and the chunk stage's kernels alone (the own blocks' two in the model's
+layout; `hvd_kda_wy` as the primal call and as the rule's forward, which also
+saves; `hvd_kda_wy_bwd`) at each `--chunks` (chunks a grid step,
+`kda.BLOCK_CHUNKS`) and `--side` (chunks a loop iteration, `kda.SIDE`). Each
+kernel form is held to the jnp one before it is timed.
 
 Usage: python examples/kda_sweep.py [--blocks 32 64 128] [--groups 1 2 4 8]
-       [--iters 10] [--cpu]   (--cpu: tiny shapes, the interpreter, no times)
+       [--chunks 4 8 16] [--side 2] [--iters 10] [--cpu]
+       (--cpu: tiny shapes, the interpreter, no times; an option with no
+       value skips its part)
 """
 
 import argparse
@@ -40,13 +45,96 @@ def timed(fn, args, iters):
     return 1e3 * (time.perf_counter() - t0) / iters
 
 
+def chunk_stage(args, inputs, interpret):
+    """The chunk stage's kernels alone by chunks a grid step, each held to
+    the jnp form (every result; the five gradients of a scalar of them)."""
+    q, k, v, g, beta = inputs
+    B, L, H, D = q.shape
+    C, sub = 64, 16
+    nc = L // C
+    *flat, pq, pk = kda._chunk_stage_operands(q, k, v, g, beta, C, sub,
+                                              bool(interpret))
+    own, own_of = (pq, pk), (flat[0], flat[1], flat[3])
+    own_block = kda.own_plan(L // sub, sub, D, interpret)
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+
+    def in_jnp(*a):  # the jnp form's results laid as the scan reads them
+        return tuple(jnp.moveaxis(t, 2, 0)
+                     for t in kda._chunk_stage_jnp(*a, C, sub, interpret))
+
+    want = jax.jit(in_jnp)(*inputs)
+    cot = tuple(jax.random.normal(kk, t.shape).astype(t.dtype)
+                for kk, t in zip(ks, want))
+
+    def scalar(results):
+        return sum(jnp.sum(r.astype(jnp.float32) * c.astype(jnp.float32))
+                   for r, c in zip(results, cot))
+
+    want_grad = jax.jit(jax.grad(lambda *a: scalar(in_jnp(*a)),
+                                 argnums=(0, 1, 2, 3, 4)))(*inputs)
+    worst = lambda got, ref: max(  # noqa: E731
+        float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                              - b.astype(jnp.float32)))
+              / jnp.maximum(jnp.max(jnp.abs(b.astype(jnp.float32))), 1e-30))
+        for a, b in zip(got, ref))
+    rows = []
+    if not args.cpu and args.chunks:
+        rows.append({"own_blocks_ms": timed(
+            lambda *a: kda._pallas_own(*a, None, own_block, False, H, sub),
+            own_of, args.iters),
+            "own_blocks_bwd_ms": timed(
+                lambda *a: kda._pallas_own(*a[:3], a[3], own_block, False,
+                                           H, sub),
+                own_of + (own,), args.iters)})
+        print(json.dumps(rows[-1]), flush=True)
+    for block in args.chunks:
+        if nc % block or block % kda.SIDE or (block % 8 and block != nc):
+            continue
+
+        def whole(*a):
+            return kda._chunk_stage_kernels(*a, C, sub, block,
+                                            bool(interpret))
+
+        def primal(*a):
+            return kda._pallas_wy(*a, own, None, None, C, sub, block, False,
+                                  bool(interpret))
+
+        def saving(*a):
+            return kda._pallas_wy(*a, own, None, None, C, sub, block, True,
+                                  bool(interpret))
+
+        def backward(*a):
+            return kda._pallas_wy(*a[:5], None, a[5], a[6], C, sub, block,
+                                  False, bool(interpret))
+
+        got = whole(*inputs)
+        got_grad = jax.grad(lambda *a: scalar(whole(*a)),
+                            argnums=(0, 1, 2, 3, 4))(*inputs)
+        row = {"chunks": block, "side": kda.SIDE,
+               "fwd_rel_err": worst(got, want),
+               "bwd_rel_err": worst(got_grad, want_grad)}
+        if not args.cpu:
+            saved = saving(*flat)[5:]
+            kcot = cot[:4] + (jnp.moveaxis(cot[4][..., 0], 0, 2),)
+            row["wy_ms"] = timed(primal, flat, args.iters)
+            row["wy_saving_ms"] = timed(saving, flat, args.iters)
+            row["wy_bwd_ms"] = timed(backward, flat + [kcot, tuple(saved)],
+                                     args.iters)
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--blocks", type=int, nargs="*", default=[32, 64, 128])
     ap.add_argument("--groups", type=int, nargs="*", default=[1, 2, 4, 8])
+    ap.add_argument("--chunks", type=int, nargs="*", default=[4, 8, 16])
+    ap.add_argument("--side", type=int, default=kda.SIDE)
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--cpu", action="store_true")
     args = ap.parse_args()
+    kda.SIDE = args.side
     if not args.cpu and jax.default_backend() != "tpu":
         raise SystemExit("kda_sweep: needs a TPU (or --cpu for the forms "
                          "alone)")
@@ -63,18 +151,20 @@ def main():
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, L, H)))
     out = {"shape": [B, L, H, D]}
 
-    def whole(*a):
-        return kda.kda_chunked(*a, interpret=interpret)[0]
+    def whole(*a):  # q, k, v, g as the mixer has them: [B, L, H D]
+        return kda.kda_chunked(*(t.reshape(B, L, H, D) for t in a[:4]),
+                               a[4], interpret=interpret)[0]
 
     def whole_grad(*a):
         return jax.grad(lambda *b: jnp.sum(jnp.square(whole(*b))),
                         argnums=(0, 1, 2, 3, 4))(*a)
 
     if not args.cpu:
-        out["kda_chunked_fwd_ms"] = timed(jax.jit(whole),
-                                          (q, k, v, g, beta), args.iters)
-        out["kda_chunked_fwd_bwd_ms"] = timed(jax.jit(whole_grad),
-                                              (q, k, v, g, beta), args.iters)
+        flat = tuple(t.reshape(B, L, H * D) for t in (q, k, v, g)) + (beta,)
+        out["kda_chunked_fwd_ms"] = timed(jax.jit(whole), flat, args.iters)
+        out["kda_chunked_fwd_bwd_ms"] = timed(jax.jit(whole_grad), flat,
+                                              args.iters)
+        print(json.dumps(out), flush=True)
     # the own-block kernels alone, on the layer's sub-blocks
     sub, N = 16, B * H * L // 16
     flat = lambda t: t.transpose(0, 2, 1, 3).reshape(N, sub, D)  # noqa: E731
@@ -117,6 +207,7 @@ def main():
             rows.append(row)
             print(json.dumps(row), flush=True)
     out["own_block_scores"] = rows
+    out["chunk_stage"] = chunk_stage(args, (q, k, v, g, beta), interpret)
     if not args.cpu:
         C = 64
         a = jnp.tril(jax.random.normal(ks[5], (B, H, L // C, C, C)), -1) \

@@ -111,8 +111,10 @@ SSM_SCOPES = (SSM, SSM_CONV, SSD, SSM_PROJ, SSM_GATE)
 # convolutions, `KDA_GATE` around the elementwise part in f32 (the l2 norms,
 # the decay's softplus, beta's sigmoid, the gated head norm), and in
 # `ops/kda.py` `KDA_CHUNK` around what is made for all chunks at once (the
-# cumulative decays, the decayed scores, the solve, W and U) and `KDA_CARRY`
-# around the scan over the chunks. Not in MODEL_SCOPES.
+# cumulative decays, the decayed scores, the solve, W and U: the kernels
+# `KDA_WY` / `KDA_WY_BWD` below, or jnp around `KDA_SCORES` /
+# `KDA_SCORES_BWD`) and `KDA_CARRY` around the scan over the chunks. Not in
+# MODEL_SCOPES.
 KDA = "hvd_kda"
 KDA_PROJ = "hvd_kda_proj"
 KDA_CONV = "hvd_kda_conv"
@@ -204,12 +206,24 @@ MOE_ROWS_KERNELS = (MOE_ROWS, MOE_SUM)
 MOE_ACT = "hvd_moe_act"          # a = act(g) * h, or act(h), s live
 MOE_ACT_BWD = "hvd_moe_act_bwd"  # (dg, dh), or dh, from (g, h, da)
 MOE_ACT_KERNELS = (MOE_ACT, MOE_ACT_BWD)
-# A sub-block's own decayed scores of the chunked KDA recurrence, term by
-# term (`ops/kda.py`, under `KDA_CHUNK`): q and k against k inside each
-# sub-block of 16 tokens, exp(G_t - G_s) a channel.
+# The chunked KDA recurrence's kernels (`ops/kda.py`), all under `KDA_CHUNK`.
+# A sub-block's own decayed scores term by term (q and k against k inside
+# each sub-block of 16 tokens, exp(G_t - G_s) a channel): `KDA_SCORES` /
+# `KDA_SCORES_BWD`, the public op `own_block_scores` and, in the model's
+# layout, a part of every call `chunk_plan` takes. The rest of the chunk
+# stage, one call a direction (PR 59): `KDA_WY` reads q, k, v, the cumulative
+# decays and beta as the mixer lays them and the own blocks' squares, and
+# makes, a chunk at a time in VMEM, the decayed scores, the inverse of the
+# unit triangle, W, U and the scan's other operands, chunks leading;
+# `KDA_WY_BWD` turns their cotangents into dq, dk, dv, dG, dbeta and the
+# squares' cotangents from the five inputs, the saved inverse and the saved
+# k-k scores. `kda_kernel_ms` (the sum over `KDA_KERNELS`) is thereby the
+# whole chunk stage but XLA's two products for the cumulative decays.
 KDA_SCORES = "hvd_kda_scores"          # (pq, pk) [sub, sub] a sub-block
 KDA_SCORES_BWD = "hvd_kda_scores_bwd"  # (dq, dk, dG) from their cotangents
-KDA_KERNELS = (KDA_SCORES, KDA_SCORES_BWD)
+KDA_WY = "hvd_kda_wy"          # (W | Q e^G, U, qk, K e^(G_last - G), e^G_last)
+KDA_WY_BWD = "hvd_kda_wy_bwd"  # (dq, dk, dv, dG, dbeta, the squares')
+KDA_KERNELS = (KDA_SCORES, KDA_SCORES_BWD, KDA_WY, KDA_WY_BWD)
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
            RING_ATTN_DQ, RING_ATTN_DKV, BN_STATS,
            BN_GRAD_STATS) + MOE_GMM_KERNELS + (HC_STAT, HC_STAT_DPHI) \

@@ -553,30 +553,60 @@ def test_chunked_scan_compiles_for_v5e(one_chip):
 
 
 # The Kimi-Linear cell's KDA layer: 32 heads of 128 at 8192 tokens in chunks
-# of 64 (PR 58). The two kernels of a sub-block's own decayed scores (3-D
-# blocks [64, 16, 128], reductions over the lanes and over the sublanes) and
-# the jnp around them: one scan over the 128 chunks, the solve, no [L, L].
+# of 64 (PR 58; since PR 59 the chunk stage is kernels alone). The operands
+# come as the mixer has them, [B, L, H D] (a head's slab is a block of
+# columns; the TPU tiles a [.., 32, 128] array by heads, so the 4-D form as a
+# PARAMETER would be re-laid): `hvd_kda_wy` and `hvd_kda_wy_bwd` once each (8
+# chunks a grid step, four side by side in the inverse's [64, 256] x [256,
+# 256] f32 products at full precision, the transposed products of the
+# backward, squares placed and taken by slices along the lanes), the own
+# blocks' `hvd_kda_scores` and `hvd_kda_scores_bwd` once each in the same
+# layout (a [1024, 128] slab of the same [1, 8192, 4096] operand), one scan
+# over the 128 chunks, no [L, L], libtpu's solve gone, and no copy of an
+# activation between the operands and the kernels or between the kernels and
+# the scan.
 def test_chunked_kda_compiles_for_v5e(one_chip, monkeypatch):
+    import re
+
     from horovod_tpu.ops import kda
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     L, H, D = 8192, 32, 128
-    assert kda.own_plan(H * L // 16, 16, D) == kda.BLOCK_SUBS
+    assert kda.chunk_plan(1, L, H, D, D, 64, 16) == kda.BLOCK_CHUNKS
 
     def fwd_bwd(q, k, v, g, beta, cot):
+        heads = lambda t: t.reshape(1, L, H, D)  # noqa: E731
         out, vjp = jax.vjp(
-            lambda *a: kda.kda_chunked(*a, chunk=64)[0], q, k, v, g, beta)
+            lambda q, k, v, g, beta: kda.kda_chunked(
+                heads(q), heads(k), heads(v), heads(g), beta,
+                chunk=64)[0].reshape(1, L, H * D), q, k, v, g, beta)
         return out, vjp(cot)
 
     bf16, f32 = jnp.bfloat16, jnp.float32
-    text = _compile(one_chip, fwd_bwd, *[((1, L, H, D), bf16)] * 3,
-                    ((1, L, H, D), f32), ((1, L, H), f32),
-                    ((1, L, H, D), f32))
-    assert _named(text, profile.KDA_SCORES)
-    assert _named(text, profile.KDA_SCORES_BWD)
+    text = _compile(one_chip, fwd_bwd, *[((1, L, H * D), bf16)] * 3,
+                    ((1, L, H * D), f32), ((1, L, H), f32),
+                    ((1, L, H * D), f32))
+    for name in profile.KDA_KERNELS:
+        assert len(re.findall(r"\b%s/pallas_call" % name, text)) == 1
+    assert _kernels(text) == 4
     assert profile.KDA_CHUNK in text and profile.KDA_CARRY in text
-    # never an [L, L] array a head
+    # never an [L, L] array a head; libtpu's 64-step solve is gone
     assert "8192,8192" not in text
+    assert "riangular" not in text and "1,32,128,1,64,64" not in text
+
+    def passes(scope):
+        """The `copy` / `transpose` instructions under `scope` that move an
+        activation ([8192, 32, 128] elements or more)."""
+        found = []
+        for line in text.splitlines():
+            m = re.search(r"= \w+\[([0-9,]+)\]\S* (copy|transpose)\(", line)
+            if m and scope in line and functools.reduce(
+                    lambda a, b: a * int(b), m.group(1).split(","),
+                    1) >= L * H * D:
+                found.append(line.strip()[:160])
+        return found
+
+    assert passes(profile.KDA_CHUNK) == []
 
 
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------

@@ -7,6 +7,7 @@ and what is refused by name.
 """
 
 import os
+import re
 import sys
 
 import numpy as np
@@ -14,6 +15,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 import optax
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -206,6 +208,162 @@ def test_own_plan_says_which_calls_take_the_kernels():
     assert kda.own_plan(kda.BLOCK_SUBS + 1, 16, 128, interpret=True) is None
 
 
+def _stage_case(name):
+    """The chunk stage's two test shapes: [1, 256, 2, 128] in f32 with
+    decays down to 1e-3 a token, and one head of the benchmark's layer cut
+    to 4 chunks (bf16 operands, q carrying its scale, the mixer's spread of
+    decays)."""
+    if name == "f32":
+        return _recurrence_case(L=256, H=2, D=128, Dv=128, seed=11), 1e-5
+    q, k, v, g, beta = _recurrence_case(L=256, H=1, D=128, Dv=128, seed=12)
+    bf16 = jnp.bfloat16
+    g = -jnp.exp(jax.random.uniform(jax.random.PRNGKey(13), g.shape,
+                                    minval=-7.0, maxval=0.5))
+    return ((q * 128 ** -0.5).astype(bf16), k.astype(bf16), v.astype(bf16),
+            g, beta), 1e-2
+
+
+def _stage_jnp(*a):
+    """The jnp form's five results laid as the scan reads them."""
+    return tuple(jnp.moveaxis(t, 2, 0)
+                 for t in kda._chunk_stage_jnp(*a, 64, 16, None))
+
+
+def _stage_kernels(q, k, v, g, beta):
+    B, L, H, _ = q.shape
+    block = kda.chunk_plan(B, L, H, 128, 128, 64, 16, interpret=True)
+    return kda._chunk_stage_kernels(q, k, v, g, beta, 64, 16, block, True)
+
+
+def _saved_by_the_forward_kernel(q, k, v, g, beta):
+    """(the inverse of the unit triangle, the k-k scores) [B, H, nc, 64,
+    64] as `hvd_kda_wy` saves them for the backward rule."""
+    B, L, H, D = q.shape
+    *five, pq, pk = kda._chunk_stage_operands(q, k, v, g, beta, 64, 16, True)
+    return kda._pallas_wy(
+        *five, (pq, pk), None, None, 64, 16,
+        kda.chunk_plan(B, L, H, D, D, 64, 16, True), True, True)[5:]
+
+
+STAGE = ("w_and_qe", "u", "qk", "k_out", "keep")
+
+
+@pytest.mark.parametrize("case", ["f32", "one_head_bf16"])
+@pytest.mark.parametrize("what", STAGE)
+def test_the_chunk_stage_forward_kernel_agrees_with_jnp(what, case):
+    """`hvd_kda_wy` in Pallas' interpreter against the jnp form, every
+    result: f32 operands to f32 rounding, bf16 ones to a bf16 step (both
+    round at the same places)."""
+    args, tol = _stage_case(case)
+    i = STAGE.index(what)
+    got, want = _stage_kernels(*args)[i], _stage_jnp(*args)[i]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    _close(got.astype(jnp.float32), want.astype(jnp.float32), tol)
+    if what == "qk":
+        assert float(jnp.max(jnp.abs(jnp.triu(
+            got.astype(jnp.float32), 1)))) == 0.0
+
+
+@pytest.mark.parametrize("case", ["f32", "one_head_bf16"])
+@pytest.mark.parametrize("what", ["d" + n for n in NAMES])
+def test_the_chunk_stage_backward_kernel_agrees_with_jnp(what, case,
+                                                         monkeypatch):
+    """`jax.grad` of a scalar of `kda_chunked`'s output and final state
+    through `hvd_kda_wy_bwd` against the same through the jnp form."""
+    args, tol = _stage_case(case)
+    i = NAMES.index(what[1:])
+    cot_o = jax.random.normal(jax.random.PRNGKey(7), args[2].shape)
+    cot_s = jax.random.normal(jax.random.PRNGKey(8),
+                              (1, args[0].shape[2], 128, 128))
+
+    def scalar(interpret):
+        def f(*a):
+            o, s, _ = kda.kda_chunked(*a, chunk=64, interpret=interpret)
+            return jnp.sum(o * cot_o) + jnp.sum(s * cot_s)
+        return f
+
+    got = jax.grad(scalar(True), argnums=i)(*args)
+    monkeypatch.setattr(kda, "chunk_plan", lambda *a, **k: None)
+    want = jax.grad(scalar(None), argnums=i)(*args)
+    assert got.dtype == want.dtype
+    assert np.all(np.isfinite(np.asarray(got, np.float32)))
+    _close(got.astype(jnp.float32), want.astype(jnp.float32),
+           5e-5 if case == "f32" else 2e-2)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("alpha", [1e-3, 1.0])
+def test_the_chunk_stage_kernels_at_the_decays_ends(alpha, direction):
+    """alpha = 1e-3 on every channel over whole chunks (e^-442 a chunk: the
+    product of two exponentials would overflow) and alpha exactly 1 (no
+    decay: G = 0): no inf, no nan, and the jnp form's numbers."""
+    q, k, v, g, beta = _recurrence_case(L=256, H=1, D=128, Dv=128, seed=5)
+    g = jnp.full_like(g, np.log(alpha))
+    args = (q, k, v, g, beta)
+    if direction == "forward":
+        for got, want in zip(_stage_kernels(*args), _stage_jnp(*args)):
+            assert np.all(np.isfinite(np.asarray(got)))
+            _close(got, want, 1e-5)
+        return
+    cot = jax.random.normal(jax.random.PRNGKey(6), v.shape)
+
+    def scalar(f):
+        return lambda *a: sum(jnp.sum(r) for r in f(*a)[1:]) + jnp.sum(
+            f(*a)[0][:, 0, :, :64, :] * cot[0, :64, 0])
+
+    got = jax.grad(scalar(_stage_kernels), argnums=(0, 1, 2, 3, 4))(*args)
+    want = jax.grad(scalar(_stage_jnp), argnums=(0, 1, 2, 3, 4))(*args)
+    for a, b in zip(got, want):
+        assert np.all(np.isfinite(np.asarray(a)))
+        _close(a, b, 5e-5)
+
+
+@pytest.mark.parametrize("how", ["product", "side_by_side", "kernel"])
+def test_the_unit_triangle_inverse_agrees_with_the_triangular_solve(how):
+    """The finite product that stands for the solve, alone, for two
+    triangles side by side and as the kernel saves it, against
+    `lax.linalg.triangular_solve` at f32."""
+    args, _ = _stage_case("f32")
+    if how == "product":
+        a = jnp.tril(jax.random.normal(jax.random.PRNGKey(2), (64, 64)), -1)
+        got, unit = kda.unit_lower_inverse(a, 16), (a + jnp.eye(64))[None]
+    elif how == "side_by_side":
+        a = jnp.tril(jax.random.normal(jax.random.PRNGKey(3), (2, 64, 64)),
+                     -1)
+        got = kda.unit_lower_inverse(jnp.concatenate(list(a), axis=1), 16)
+        got, unit = jnp.stack([got[:, :64], got[:, 64:]]), a + jnp.eye(64)
+    else:
+        got, kk = _saved_by_the_forward_kernel(*args)
+        beta = args[4].transpose(0, 2, 1).reshape(1, 2, 4, 64, 1)
+        unit = jnp.tril(beta * kk, -1) + jnp.eye(64)
+    want = lax.linalg.triangular_solve(
+        unit, jnp.broadcast_to(jnp.eye(64), unit.shape), left_side=True,
+        lower=True, unit_diagonal=True)
+    _close(got, want.reshape(got.shape), 1e-5)
+    assert float(jnp.max(jnp.abs(jnp.triu(got, 1)))) == 0.0
+
+
+@pytest.mark.parametrize("shape,interpret,takes", [
+    ((1, 8192, 32, 128, 128, 64, 16), True, True),   # the benchmark's layer
+    ((1, 256, 2, 128, 128, 64, 16), True, True),     # 4 chunks: one block
+    ((2, 1024, 4, 256, 128, 64, 16), True, True),
+    ((1, 8192, 32, 128, 128, 64, 16), None, False),  # no TPU, no interpreter
+    ((1, 8192, 32, 64, 128, 64, 16), True, False),   # a narrow head
+    ((1, 8192, 32, 128, 64, 64, 16), True, False),   # a narrow value
+    ((1, 8192, 32, 128, 128, 64, 8), True, False),   # a short sub-block
+    ((1, 8192, 32, 128, 128, 32, 16), True, False),  # another chunk
+    ((1, 768, 2, 128, 128, 64, 16), True, False),    # 12 chunks: no block
+])
+def test_chunk_plan_says_which_calls_take_the_kernels(shape, interpret,
+                                                      takes):
+    block = kda.chunk_plan(*shape, interpret=interpret)
+    if not takes:
+        assert block is None
+        return
+    chunks = shape[1] // 64
+    assert block == min(kda.BLOCK_CHUNKS, chunks) and chunks % block == 0
+
+
 def test_chunked_kda_through_the_kernels_agrees_with_the_recurrence(
         monkeypatch):
     monkeypatch.setattr(kda, "BLOCK_SUBS", 8)
@@ -228,15 +386,33 @@ def test_chunked_kda_through_the_kernels_agrees_with_the_recurrence(
         _close(a, b, 5e-5)
 
 
-def test_decayed_scores_never_form_an_l_by_l_array_or_a_token_loop():
-    """The program of the chunked form: one scan over the L / C chunks, no
-    array with two axes of the sequence's length."""
-    L, chunk = 256, 32
-    args = _recurrence_case(L=L)
-    jaxpr = jax.make_jaxpr(lambda *a: kda.kda_chunked(*a, chunk=chunk))(*args)
+@pytest.mark.parametrize("kernels", [False, True])
+def test_decayed_scores_never_form_an_l_by_l_array_or_a_token_loop(kernels):
+    """The program of the chunked form, in jnp and through the chunk stage's
+    kernels (forward and backward): one scan over the L / C chunks (and,
+    inside a kernel, one loop over a grid step's chunks), no array with two
+    axes of the sequence's length."""
+    L, chunk = (2048, 64) if kernels else (256, 32)
+    args = _recurrence_case(L=L, D=128 if kernels else 16,
+                            Dv=128 if kernels else 16)
+
+    def program(*a):
+        return jax.grad(lambda *b: jnp.sum(kda.kda_chunked(
+            *b, chunk=chunk, interpret=kernels or None)[0]))(*a)
+
+    jaxpr = jax.make_jaxpr(program)(*args)
     text = str(jaxpr)
-    assert text.count("scan[") == 1 and "length=%d" % (L // chunk) in text
+    block = kda.chunk_plan(1, L, 2, 128, 128, chunk, 16, kernels or None)
+    assert (block == kda.BLOCK_CHUNKS) is kernels
+    # the chunks' scan and its transpose; a kernel's loop over its chunks
+    lengths = [int(n) for n in re.findall(r"length=(\d+)", text)]
+    assert len(lengths) == text.count("scan[")
+    assert lengths.count(L // chunk) == 2
+    assert set(lengths) <= {L // chunk} | (
+        {block // kda.SIDE, kda.BLOCK_SUBS // kda.GROUP} if kernels
+        else set())
     assert "while[" not in text
+    assert ("pallas_call" in text) is kernels
 
     def shapes(jp):
         for eqn in jp.eqns:
