@@ -755,11 +755,11 @@ def sync_batch_norm_stats(stat_sum, stat_sumsq, count, group=None,
     mapped axis is in scope — and returns ``(mean, var, global_count)``.
 
     The standalone jax-wrapper surface for CUSTOM norm layers bringing
-    their own one-pass statistics. The shipped modules
-    (``ops.batch_norm.LeanBatchNorm(sync_group=...)`` /
-    ``PallasBatchNorm(axis_name=...)``) do this same reduction inside
-    their custom VJPs (``_lean_sync`` — the backward needs its own
-    group-scoped pass, which a forward-only helper cannot provide).
+    their own one-pass statistics. The shipped module
+    (``ops.batch_norm.LeanBatchNorm(sync_group=...)`` or
+    ``(axis_name=...)``) does this same reduction inside its custom VJP
+    (``_lean_sync`` — the backward needs its own group-scoped pass, which
+    a forward-only helper cannot provide).
     ``count`` is the PER-REPLICA element count behind the partial sums
     (a static int)."""
     from horovod_tpu import groups as _grp

@@ -22,12 +22,12 @@ float32 parameters, static shapes, no data-dependent Python control flow.
 """
 
 from .resnet import (ResNet, ResNet18, ResNet34, ResNet50, ResNet50GN,  # noqa: F401
-                     ResNet50Lean, ResNet50NF, ResNet50PBN, ResNet101,
-                     ResNet101NF, ResNet152)
+                     ResNet50Lean, ResNet50NF, ResNet101, ResNet101NF,
+                     ResNet152)
 from .mnist import MnistCNN  # noqa: F401
 from .word2vec import SkipGram  # noqa: F401
-from .transformer import (Transformer, TransformerConfig, Yarn,  # noqa: F401
-                          hc_stats, kda_stats, ssd_stats)
+from .transformer import (Layer, Transformer, TransformerConfig,  # noqa: F401
+                          Yarn, hc_stats, kda_stats, ssd_stats)
 from .block_diffusion import (block_diffusion_batch,  # noqa: F401
                               block_diffusion_noisy_half,
                               block_diffusion_stats)

@@ -40,16 +40,12 @@ class VGG16(nn.Module):
 
 
 class _ConvBN(nn.Module):
-    """Conv + BatchNorm + ReLU, the Inception building block.
-    `norm="pallas"` swaps in the fused-stats PallasBatchNorm
-    (ops/batch_norm.py) — Inception is the zoo's most BN-bound model,
-    so it is the second measurement target for that kernel."""
+    """Conv + BatchNorm + ReLU, the Inception building block."""
     filters: int
     kernel: tuple
     strides: tuple = (1, 1)
     padding: Any = "SAME"
     dtype: Any = jnp.bfloat16
-    norm: str = "batch"
     bn_axis_name: Optional[str] = None  # sync BN: psum stats over this mesh axis
 
     @nn.compact
@@ -57,15 +53,10 @@ class _ConvBN(nn.Module):
         x = nn.Conv(self.filters, self.kernel, self.strides,
                     padding=self.padding, use_bias=False,
                     dtype=self.dtype, param_dtype=jnp.float32)(x)
-        if self.norm == "pallas":
-            from horovod_tpu.ops.batch_norm import PallasBatchNorm
-            bn_cls = PallasBatchNorm
-        else:
-            bn_cls = nn.BatchNorm
-        x = bn_cls(use_running_average=not train, momentum=0.9,
-                   epsilon=1e-3, dtype=self.dtype,
-                   param_dtype=jnp.float32,
-                   axis_name=self.bn_axis_name)(x)
+        x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
+                         epsilon=1e-3, dtype=self.dtype,
+                         param_dtype=jnp.float32,
+                         axis_name=self.bn_axis_name)(x)
         return nn.relu(x)
 
 
@@ -76,14 +67,13 @@ def _avgpool3(x):
 class InceptionV3(nn.Module):
     """Inception V3 (Szegedy et al. 2015), aux head omitted (the
     reference synthetic benchmarks train the main head only)."""
-    norm: str = "batch"
     num_classes: int = 1000
     dtype: Any = jnp.bfloat16
     bn_axis_name: Optional[str] = None  # sync BN over this mesh axis
 
     @nn.compact
     def __call__(self, x, train: bool = True):
-        cbn = partial(_ConvBN, dtype=self.dtype, norm=self.norm,
+        cbn = partial(_ConvBN, dtype=self.dtype,
                       bn_axis_name=self.bn_axis_name)
         x = x.astype(self.dtype)
         # Stem: 299x299x3 -> 35x35x192

@@ -110,16 +110,16 @@ class ResNet(nn.Module):
     dtype: Any = jnp.bfloat16
     norm: str = "batch"
     # Cross-replica (sync) BN: psum batch statistics over this mesh
-    # axis (the flax, Pallas, and lean norm paths all support it). The
+    # axis (the flax and the lean norm paths both support it). The
     # standard choice at small per-chip batch, where per-device BN
     # statistics get noisy.
     bn_axis_name: Optional[str] = None
-    # Host-plane sync-BN scope (norm="lean"/"pallas" via the lean path):
+    # Host-plane sync-BN scope (norm="lean"):
     # a hvd.ProcessGroup (e.g. hvd.batch_group() under a 2-D mesh) or
     # the string "world" — statistics ride the host collectives
     # group-scoped (docs/GROUPS.md).
     bn_sync_group: Any = None
-    # Ghost BN (norm="lean"/"pallas"): virtual batch each normalization
+    # Ghost BN (norm="lean"): virtual batch each normalization
     # group sees; None = the whole per-replica batch.
     bn_virtual_batch_size: Optional[int] = None
     # BN-scoped remat (norm="lean"): recompute the normalize-pass
@@ -142,16 +142,6 @@ class ResNet(nn.Module):
         elif self.norm == "group":
             norm = partial(nn.GroupNorm, num_groups=32, epsilon=1e-5,
                            dtype=self.dtype, param_dtype=jnp.float32)
-        elif self.norm == "pallas":
-            # Fused Pallas BN statistics (ops/batch_norm.py): one
-            # bf16-read f32-accumulate kernel per stats pass, attacking
-            # the convert_reduce_fusion HBM share in PERF.md.
-            from horovod_tpu.ops.batch_norm import PallasBatchNorm
-            norm = partial(PallasBatchNorm, use_running_average=not train,
-                           momentum=0.9, epsilon=1e-5, dtype=self.dtype,
-                           param_dtype=jnp.float32,
-                           axis_name=self.bn_axis_name,
-                           virtual_batch_size=self.bn_virtual_batch_size)
         elif self.norm == "lean":
             # Traffic-lean graph-level BN (round 10, ops/batch_norm.py).
             from horovod_tpu.ops.batch_norm import LeanBatchNorm
@@ -165,11 +155,14 @@ class ResNet(nn.Module):
             # the pre-activation sign); block-final norms and the
             # post-residual-add ReLUs stay separate.
             norm_act = partial(norm, fuse_relu=True)
-        else:
+        elif self.norm == "batch":
             norm = partial(nn.BatchNorm, use_running_average=not train,
                            momentum=0.9, epsilon=1e-5, dtype=self.dtype,
                            param_dtype=jnp.float32,
                            axis_name=self.bn_axis_name)
+        else:
+            raise ValueError("norm=%r: 'batch', 'none', 'group' or 'lean'"
+                             % (self.norm,))
         act = nn.relu
 
         block_cls = self.block_cls
@@ -212,8 +205,6 @@ ResNet152 = partial(ResNet, stage_sizes=[3, 8, 36, 3],
                     block_cls=BottleneckBlock)
 ResNet50GN = partial(ResNet, stage_sizes=[3, 4, 6, 3],
                      block_cls=BottleneckBlock, norm="group")
-ResNet50PBN = partial(ResNet, stage_sizes=[3, 4, 6, 3],
-                      block_cls=BottleneckBlock, norm="pallas")
 ResNet50NF = partial(ResNet, stage_sizes=[3, 4, 6, 3],
                      block_cls=BottleneckBlock, norm="none")
 ResNet50Lean = partial(ResNet, stage_sizes=[3, 4, 6, 3],

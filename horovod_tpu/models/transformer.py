@@ -42,6 +42,33 @@ class Yarn(NamedTuple):
     mscale_all_dim: float = 0.0
 
 
+class Layer(NamedTuple):
+    """What ONE block of the stack is (`TransformerConfig.layers` makes
+    them, `Block` runs them): its branches in order, each
+    `x + out_norm(mixer(norm(x)))` (under `hc_mult` > 1 the same mixer
+    inside a hyper-connection). The default is the plain two-branch block:
+    causal attention, then the dense feed-forward. The sizes are the
+    configuration's; a new kind of layer is a new value here and an entry
+    in `_mixer`."""
+    # The mixers: "attn" (`Attention`) | "latent" (`LatentAttention`) |
+    # "kda" (`KimiDeltaAttention`) | "ssm" (`Mamba2`) | "mlp" (the dense
+    # feed-forward) | "moe" (the routed one).
+    branches: Tuple[str, ...] = ("attn", "mlp")
+    # The parameter name of each branch's norm, and of the sandwich norm
+    # on its output (None: no such norm).
+    norms: Tuple[str, ...] = ("norm1", "norm2")
+    out_norms: Tuple[Optional[str], ...] = (None, None)
+    # The attention branch's kind under `attention_types`: "window" (an
+    # "attn" branch under `ops.BandMask`), "full" (an "attn" branch under
+    # YaRN's rotation, or a "latent" one); None: the stack's one mask
+    # (causal, or `attention_mask`). A kind is a name in the trace too
+    # (`profile.ATTN_KINDS`).
+    kind: Optional[str] = None
+    # Whether the block keeps only its input for the backward pass and
+    # runs its forward again there.
+    remat: bool = False
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -426,6 +453,40 @@ class TransformerConfig:
                 raise ValueError("ssm_groups=%d must divide ssm_heads=%d "
                                  "(a group's B and C serve whole heads)"
                                  % (self.ssm_groups, self.ssm_heads))
+
+    def layers(self):
+        """What each block of the model is, a `Layer` a block: `num_layers`
+        of them, `block_<i>`'s at i, and behind them the prediction
+        module's `mtp_block` where `mtp_depth` is set. The one reader of
+        the fields that spell a layer (`layer_types`; `attention_types`;
+        `moe_every` and `first_k_dense`; `kv_lora_rank` as a choice of
+        attention; `sandwich_norm` as names; `block_remat`)."""
+        attention = "latent" if self.kv_lora_rank is not None else "attn"
+
+        def two_branch(mixer, routed, kind=None, remat=False):
+            norms = ("norm1", "norm2")
+            return Layer((mixer, "moe" if routed else "mlp"), norms,
+                         tuple(name + "_out" if self.sandwich_norm else None
+                               for name in norms), kind, remat)
+
+        table = []
+        for i in range(self.num_layers):
+            remat = i < self.block_remat
+            if self.layer_types is not None:  # one mixer behind one norm
+                table.append(Layer((self.layer_types[i],), ("norm",),
+                                   (None,), None, remat))
+                continue
+            kind = None if self.attention_types is None \
+                else self.attention_types[i]
+            routed = (self.moe_experts is not None
+                      and i >= self.first_k_dense
+                      and i % self.moe_every == self.moe_every - 1)
+            mixer, kind = ("kda", None) if kind == "kda" \
+                else (attention, kind)
+            table.append(two_branch(mixer, routed, kind, remat))
+        if self.mtp_depth:  # routed wherever the model is, never recomputed
+            table.append(two_branch(attention, self.moe_experts is not None))
+        return tuple(table)
 
     def local(self, tp_size):
         """The per-shard config for `tp_size`-way tensor parallelism."""
@@ -1020,78 +1081,83 @@ def kda_stats(intermediates):
 
 
 class Block(nn.Module):
+    """One block of the stack on x [B, L, C] (the streams [n, B, L, C]
+    under `cfg.hc_mult` = n > 1): for each branch of `layer`,
+    `x + out_norm(mixer(norm(x)))` or, on streams, the same branch inside
+    its hyper-connection, X' = H_res X + H_post^T F(H_pre X), whose work
+    lies under `hvd_hc` (`hvd_hc_map`, `hvd_hc_mix` inside)."""
     cfg: TransformerConfig
-    moe: bool = False
-    # The layer's one mixer under `layer_types` ("ssm", "attn", "moe",
-    # "mlp"); None: the two-branch block.
-    kind: Optional[str] = None
-    # The two-branch block's attention kind under `attention_types` ("full"
-    # | "window" | "kda"); None: the stack's one kind.
-    attention_kind: Optional[str] = None
+    layer: Layer = Layer()
 
     @nn.compact
     def __call__(self, x, positions):
-        cfg = self.cfg
-        if self.kind is not None:
-            h = _rms_norm(cfg, "norm")(x)
-            if self.kind == "ssm":
-                with jax.named_scope(profile.SSM):
-                    return x + Mamba2(cfg, name="ssm")(h)
-            if self.kind == "attn":
-                return x + Attention(cfg, name="attn")(h, positions)
-            return x + _feed_forward(cfg, self.kind == "moe", h)
-        # `out`: the sandwich norm on a branch's output, or nothing.
-        out = (lambda name, h: _rms_norm(cfg, name)(h)) \
-            if cfg.sandwich_norm else (lambda name, h: h)
-        attention = LatentAttention if cfg.kv_lora_rank is not None \
-            else Attention
-        if cfg.hc_mult > 1:
-            return _hyper_connected(cfg, self.moe, x, positions, attention,
-                                    out)
-        # A layer with a kind has its attention half under the kind's scope:
-        # the two kinds share a kernel name and a shape, and the trace tells
-        # them apart.
-        kind = self.attention_kind
-        if kind == "kda":
-            scope = jax.named_scope(profile.KDA)
-            mixer = KimiDeltaAttention(cfg, name="attn")
-        else:
-            scope = jax.named_scope(profile.ATTN_KINDS[kind]) if kind \
-                else contextlib.nullcontext()
-            module = attention(cfg, name="attn", **(
-                {"kind": kind} if kind and attention is Attention else {}))
-            mixer = lambda h: module(h, positions)  # noqa: E731
-        with scope:
-            x = x + out("norm1_out", mixer(_rms_norm(cfg, "norm1")(x)))
-        h = _rms_norm(cfg, "norm2")(x)
-        # `mlp` beside flax's `attn`: the profiler's scope for this half
-        # of the block (hvd.profile), dense or routed; no module and no
-        # parameter name.
-        return x + out("norm2_out", _feed_forward(cfg, self.moe, h))
+        cfg, layer = self.cfg, self.layer
+        streams = cfg.hc_mult > 1
+        if streams:
+            connection = nn.remat(HyperConnection, policy=_keep_hc_stat()) \
+                if cfg.hc_remat else HyperConnection
+        for mixer, norm, out_norm in zip(layer.branches, layer.norms,
+                                         layer.out_norms):
+            f, around, inside = _mixer(cfg, layer, mixer, positions)
+            with _scope(around):
+                if streams:
+                    with jax.named_scope(profile.HC):
+                        h, h_post, h_res = connection(
+                            cfg, name="hc_mlp" if mixer in ("mlp", "moe")
+                            else "hc_attn")(x)
+                else:
+                    h = x
+                h = _rms_norm(cfg, norm)(h)
+                with _scope(inside):
+                    y = f(h)
+                    if out_norm is not None:
+                        y = _rms_norm(cfg, out_norm)(y)
+                    if streams:
+                        with jax.named_scope(profile.HC), \
+                                jax.named_scope(profile.HC_MIX):
+                            x = hc_write(h_res, h_post, x, y)
+                    else:
+                        x = x + y
+        return x
 
 
 # Plain functions, not methods: flax names a scope after every method it
 # wraps, and the blocks' scope paths stay what they were.
+
+def _scope(name):
+    """The profiler's `name` around what follows, where there is one."""
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
+def _mixer(cfg, layer, mixer, positions):
+    """(a branch's mixer as a function of its normed input; the profiler's
+    name around the branch, its norm and its add, or None; the name around
+    the mixer and the add alone, or None). A layer with a kind has its
+    attention half under the kind's name: two kinds share a kernel name and
+    a shape, and the trace tells them apart. Flax's module names are `attn`
+    for whatever stands in attention's place, `ssm`, `moe_mlp`; `mlp` is
+    the profiler's scope for the feed-forward, dense or routed, no module
+    and no parameter name."""
+    if mixer in ("attn", "latent"):
+        module = Attention(cfg, kind=layer.kind, name="attn") \
+            if mixer == "attn" else LatentAttention(cfg, name="attn")
+        return (lambda h: module(h, positions),
+                profile.ATTN_KINDS[layer.kind] if layer.kind else None, None)
+    if mixer == "kda":
+        return KimiDeltaAttention(cfg, name="attn"), profile.KDA, None
+    if mixer == "ssm":
+        return Mamba2(cfg, name="ssm"), None, profile.SSM
+    if mixer in ("mlp", "moe"):
+        return lambda h: _feed_forward(cfg, mixer == "moe", h), None, None
+    raise ValueError("Layer.branches names %r: attn, latent, kda, ssm, mlp "
+                     "or moe" % (mixer,))
+
 
 def _feed_forward(cfg, moe, h):
     """A block's feed-forward branch on the normed `h`, dense or routed,
     under the profiler's `mlp`."""
     if moe:
         from horovod_tpu.parallel.expert import MoeMlp
-        new = {}  # only what a configuration sets: the others' modules
-        if cfg.moe_scoring != "softmax":  # stay as they were
-            new.update(scoring=cfg.moe_scoring,
-                       route_scale=cfg.moe_route_scale)
-        if cfg.moe_held is not None:
-            new["held"] = cfg.moe_held
-        if cfg.moe_shared_dim is not None:
-            new["shared_dim"] = cfg.moe_shared_dim
-        if cfg.moe_latent_dim is not None:
-            new["latent_dim"] = cfg.moe_latent_dim
-        if cfg.moe_act != "silu":
-            new["act"] = cfg.moe_act
-        if not cfg.moe_shared_gated:
-            new["shared_gated"] = False
         with jax.named_scope("mlp"):
             return MoeMlp(num_experts=cfg.moe_experts,
                           mlp_dim=cfg.moe_dim or cfg.mlp_dim,
@@ -1099,7 +1165,12 @@ def _feed_forward(cfg, moe, h):
                           ep_axis=cfg.ep_axis, ep_size=cfg.ep_size,
                           top_k=cfg.moe_top_k, gated=cfg.moe_gated,
                           renormalize=cfg.moe_renormalize,
-                          dtype=cfg.dtype, name="moe_mlp", **new)(h)
+                          dtype=cfg.dtype, scoring=cfg.moe_scoring,
+                          route_scale=cfg.moe_route_scale,
+                          held=cfg.moe_held, shared_dim=cfg.moe_shared_dim,
+                          act=cfg.moe_act,
+                          shared_gated=cfg.moe_shared_gated,
+                          latent_dim=cfg.moe_latent_dim, name="moe_mlp")(h)
     dense = lambda n, name: nn.Dense(  # noqa: E731
         n, dtype=cfg.dtype, param_dtype=jnp.float32, use_bias=False,
         name=name)
@@ -1115,30 +1186,6 @@ def _feed_forward(cfg, moe, h):
             # product over the local hidden slice is a partial sum.
             h = lax.psum(h, cfg.tp_axis)
     return h
-
-
-def _hyper_connected(cfg, moe, X, positions, attention, out):
-    """The block on streams X [n, B, L, C]: each branch inside its own
-    hyper-connection, X' = H_res X + H_post^T F(H_pre X), the branch F
-    with its pre-norm as in the plain block. The connection's work lies
-    under `hvd_hc` (`hvd_hc_map`, `hvd_hc_mix` inside), the branches
-    under `attn` and `mlp` as ever."""
-    connection = nn.remat(HyperConnection, policy=_keep_hc_stat()) \
-        if cfg.hc_remat else HyperConnection
-
-    def connected(X, name, branch):
-        with jax.named_scope(profile.HC):
-            h, h_post, h_res = connection(cfg, name=name)(X)
-        y = branch(h)
-        with jax.named_scope(profile.HC), \
-                jax.named_scope(profile.HC_MIX):
-            return hc_write(h_res, h_post, X, y)
-
-    X = connected(X, "hc_attn", lambda h: out(
-        "norm1_out", attention(cfg, name="attn")(
-            _rms_norm(cfg, "norm1")(h), positions)))
-    return connected(X, "hc_mlp", lambda h: out(
-        "norm2_out", _feed_forward(cfg, moe, _rms_norm(cfg, "norm2")(h))))
 
 
 class Transformer(nn.Module):
@@ -1181,107 +1228,74 @@ class Transformer(nn.Module):
         # The scopes are the profiler's names for the model's parts
         # (hvd.profile); flax's module names (`block_3/attn`) sit inside.
         with jax.named_scope(profile.EMBED):
-            x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
-                         param_dtype=jnp.float32, dtype=cfg.dtype,
-                         name="embed")(tokens)
-        blocks = []
-        for i in range(cfg.num_layers):
-            moe = (cfg.moe_experts is not None and
-                   i >= cfg.first_k_dense and
-                   i % cfg.moe_every == cfg.moe_every - 1)
-            block = nn.remat(Block, policy=_keep_hc_stat()) \
-                if i < cfg.block_remat else Block
-            kind = None if cfg.layer_types is None else cfg.layer_types[i]
-            new = {} if cfg.attention_types is None \
-                else {"attention_kind": cfg.attention_types[i]}
-            blocks.append(block(cfg, moe=moe, kind=kind,
-                                name="block_%d" % i, **new))
+            embedded = nn.Embed(cfg.vocab_size, cfg.embed_dim,
+                                param_dtype=jnp.float32, dtype=cfg.dtype,
+                                name="embed")(tokens)
+        table = cfg.layers()
+        blocks = [
+            (nn.remat(Block, policy=_keep_hc_stat()) if layer.remat
+             else Block)(cfg, layer, name="block_%d" % i)
+            for i, layer in enumerate(table[:cfg.num_layers])]
         norm_f = _rms_norm(cfg, "norm_f")
-        if cfg.hc_mult > 1 or cfg.mtp_depth:
-            return _streams(cfg, positions, return_hidden, x, blocks, norm_f)
+        n = cfg.hc_mult
 
-        def loop_scope(name):
-            # The loop's names exist only where there is a loop.
-            return jax.named_scope(name) if cfg.num_passes > 1 \
-                else contextlib.nullcontext()
+        def fill(h):   # the streams start as copies
+            return jnp.broadcast_to(h, (n,) + h.shape) if n > 1 else h
 
+        def close(X):  # and end as their sum
+            return jnp.sum(X, axis=0, dtype=jnp.float32).astype(X.dtype) \
+                if n > 1 else X
+
+        # The loop's names exist only where there is a loop.
+        looped = cfg.num_passes > 1
         # The same blocks and the one final norm, `num_passes` times: a
         # pass's normed output is its exit's hidden state and the next
         # pass's input. Unrolled, so that every pass keeps its own name in
         # the profiler's trace.
-        exits = []
-        with loop_scope(profile.LOOP):
+        x, exits = embedded, []
+        with _scope(profile.LOOP if looped else None):
             for t in range(cfg.num_passes):
-                with loop_scope(profile.LOOP_PASS % (t + 1)):
+                with _scope(profile.LOOP_PASS % (t + 1) if looped
+                            else None):
+                    X = fill(x)
                     for block in blocks:
                         with jax.named_scope(profile.BLOCK):
-                            x = block(x, positions)
+                            X = block(X, positions)
                     with jax.named_scope(profile.HEAD):
-                        x = norm_f(x)
+                        last = close(X)
+                        x = norm_f(last)
                 exits.append(x)
+        hidden = x
+        # The gate and the module are formed in either mode, so that `init`
+        # makes them beside the head; unused, they are no part of the
+        # program.
         if cfg.exit_gate:
-            # Formed in either mode, so that `init` makes the gate beside
-            # the head; unused, it is no part of the program.
             with jax.named_scope(profile.EXIT):
-                hidden = jnp.stack(exits)
                 # In f32: a matrix of one column, and its logits decide
                 # every exit's weight in the loss.
-                gates = nn.Dense(1, dtype=jnp.float32,
-                                 param_dtype=jnp.float32,
-                                 name="exit_gate")(hidden)[..., 0]
-            if return_hidden:
-                return hidden, gates
+                stacked = jnp.stack(exits)
+                hidden = stacked, nn.Dense(
+                    1, dtype=jnp.float32, param_dtype=jnp.float32,
+                    name="exit_gate")(stacked)[..., 0]
+        if cfg.mtp_depth:
+            with jax.named_scope(profile.MTP):
+                # The NEXT token's embedding is the embedded sequence turned
+                # by one position: no second lookup.
+                e_next = jnp.roll(embedded, -1, axis=1)
+                h = nn.Dense(cfg.embed_dim, dtype=cfg.dtype,
+                             param_dtype=jnp.float32, use_bias=False,
+                             name="mtp_proj")(jnp.concatenate(
+                                 [_rms_norm(cfg, "mtp_norm_h")(last),
+                                  _rms_norm(cfg, "mtp_norm_e")(e_next)],
+                                 axis=-1))
+                with jax.named_scope(profile.BLOCK):
+                    X = Block(cfg, table[-1], name="mtp_block")(
+                        fill(h), positions)
+                hidden = x, _rms_norm(cfg, "mtp_norm_f")(close(X))
         if return_hidden:
-            return x
+            return hidden
         with jax.named_scope(profile.HEAD):
             logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
                               param_dtype=jnp.float32, use_bias=False,
                               name="lm_head")(x)
             return logits.astype(jnp.float32)
-
-
-def _streams(cfg, positions, return_hidden, x, blocks, norm_f):
-    """The forward pass where the residual path is several streams
-    (`hc_mult`) or a prediction module follows the stack (`mtp_depth`):
-    one pass (`num_passes` > 1 is refused beside them)."""
-    n = cfg.hc_mult
-
-    def fill(h):   # the streams start as copies
-        return jnp.broadcast_to(h, (n,) + h.shape) if n > 1 else h
-
-    def close(X):  # and end as their sum
-        return jnp.sum(X, axis=0, dtype=jnp.float32).astype(X.dtype) \
-            if n > 1 else X
-
-    embedded = x
-    X = fill(x)
-    for block in blocks:
-        with jax.named_scope(profile.BLOCK):
-            X = block(X, positions)
-    with jax.named_scope(profile.HEAD):
-        last = close(X)
-        x = norm_f(last)
-    hidden_mtp = None
-    if cfg.mtp_depth:
-        # Formed in either mode, so that `init` makes the module.
-        with jax.named_scope(profile.MTP):
-            # The NEXT token's embedding is the embedded sequence turned by
-            # one position: no second lookup.
-            e_next = jnp.roll(embedded, -1, axis=1)
-            h = nn.Dense(cfg.embed_dim, dtype=cfg.dtype,
-                         param_dtype=jnp.float32, use_bias=False,
-                         name="mtp_proj")(jnp.concatenate(
-                             [_rms_norm(cfg, "mtp_norm_h")(last),
-                              _rms_norm(cfg, "mtp_norm_e")(e_next)],
-                             axis=-1))
-            with jax.named_scope(profile.BLOCK):
-                X = Block(cfg, moe=cfg.moe_experts is not None,
-                          name="mtp_block")(fill(h), positions)
-            hidden_mtp = _rms_norm(cfg, "mtp_norm_f")(close(X))
-    if return_hidden:
-        return (x, hidden_mtp) if cfg.mtp_depth else x
-    with jax.named_scope(profile.HEAD):
-        logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
-                          param_dtype=jnp.float32, use_bias=False,
-                          name="lm_head")(x)
-        return logits.astype(jnp.float32)

@@ -1,39 +1,12 @@
-"""Fused Pallas BatchNorm statistics for TPU.
+"""BatchNorm for TPU inside XLA's fusion graph (`LeanBatchNorm`,
+`lean_batch_norm_train`): one-pass statistics, a custom VJP that keeps
+(x, mean, rstd) alone, sync BN over a mesh axis or a process group, ghost
+BN. The section below says what it does and why.
 
-Built to attack the PERF.md profile's biggest non-conv line
-(`convert_reduce_fusion`, ~29 ms/step on ResNet-50 batch 256).
-MEASURED OUTCOME (v5e, PERF.md "negative result" section): the stats
-kernels beat XLA's reductions (~17.6 vs 29 ms/step) but the 53 Pallas
-islands per direction cost ~80 ms/step in fusion-boundary copies/
-reshapes/unfused masks — stock XLA BN wins for deep conv nets. Use
-`PallasBatchNorm` where norm layers are few and wide; it is also the
-package's sync-BN implementation (`axis_name`). Both reductions the
-op needs —
-
-* forward: per-channel sum and sum-of-squares of the activation, and
-* backward: per-channel sum(dy) and sum(dy * x_hat)
-
-— are computed by ONE Pallas kernel each: a single bf16 read of the
-activation block, f32 accumulation in registers, both reductions of the
-pair emitted together (XLA's lowering builds convert+reduce fusions per
-reduction). The normalize / dx elementwise math stays in XLA on purpose:
-there it fuses into neighboring producers/consumers (residual adds, ReLU
-masks — the `multiply_add_fusion` lines), which a Pallas island cannot.
-
-The reference delegates BN to cuDNN (no analogue source); this is the
-TPU-native equivalent of its fused-BN dependence. Correctness is pinned
-against `flax.linen.BatchNorm` in tests (interpret mode on CPU); on the
-v5e it lost to XLA's fusions (1348 against 2355 img/s, r04 capture;
-PERF.md section 6), and no benchmark cell runs it.
-
-Layout contract: activations reshaped to (M, C), stats over axis 0.
-M must be divisible by the block size (the caller picks the largest
-power-of-two divisor within a VMEM byte budget; if that is < 8 rows the
-plain XLA path is used — tiny inputs don't carry the bottleneck).
-Narrow-channel layers (C <= 64, i.e. k*C stays within the 128-lane
-register) are lane-packed: k rows fold into the lane dimension so every
-VPU lane is live, with a (k, C) sum after the kernel. 64 < C < 128
-cannot pack a whole row and keeps C lanes live.
+The Pallas statistics kernels that stood here until PR 60 lost their one
+chip measurement (v5e, ResNet-50 at batch 256: 1348 against stock XLA
+BatchNorm's 2355 img/s; 53 kernel islands a direction cost ~80 ms a step
+in copies at the fusion borders) and went with their option.
 """
 
 import functools
@@ -41,229 +14,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-from horovod_tpu import profile
-
-# ~16 MB VMEM/core; blocks are double-buffered (and the grad kernel
-# reads two operands), so stay well under: 4 MB for the one-input
-# stats pass, 2 MB per input for the two-input grad pass.
-_STATS_BLOCK_BYTES = 4 * 1024 * 1024
-_GRAD_BLOCK_BYTES = 2 * 1024 * 1024
-
-
-def _pick_bm(M, C, itemsize, cap_bytes):
-    """Largest power-of-two divisor of M whose (bm, C) block fits the
-    byte budget. Blocks must be BIG: a 1024-row cap put the ResNet-50
-    stem (M=3.2M) at ~3.1k sequential grid steps, and per-step overhead
-    across 53 BN layers fwd+bwd cost more than the fused read saved
-    (measured 189 vs 110 ms/step on v5e). At 4 MB the stem is 98
-    steps."""
-    # VMEM pads the lane dim to the next 128 multiple (C=64 -> 128,
-    # C=288 -> 384), so budget by the padded width.
-    padded_c = ((C + 127) // 128) * 128
-    cap_rows = max(8, cap_bytes // (padded_c * itemsize))
-    bm = 1
-    while bm * 2 <= cap_rows and M % (bm * 2) == 0:
-        bm *= 2
-    return bm
-
-
-def _pack_factor(M, C, itemsize, cap_bytes):
-    """Lane packing: view (M, C) as (M/k, k*C) so narrow-channel layers
-    (ResNet stem C=64) fill the VPU's 128 lanes; channel c lives at
-    lanes c, C+c, ..., folded by a cheap (2, k, C) sum after the call.
-    Only pack when the packed shape still yields a >=8-row block."""
-    k = 1
-    while C * (k * 2) <= 128 and M % (k * 2) == 0:
-        k *= 2
-    while k > 1 and _pick_bm(M // k, k * C, itemsize, cap_bytes) < 8:
-        k //= 2
-    return k
-
-
-def _plan(shape, dtype, block_m, cap_bytes):
-    """(k, Mp, Cp, bm) for a (M, C) reduction: pack factor, packed
-    shape, block rows. An explicit block_m disables packing (tests pin
-    block-size semantics on the unpacked layout)."""
-    M, C = shape
-    itemsize = jnp.dtype(dtype).itemsize
-    k = 1 if block_m else _pack_factor(M, C, itemsize, cap_bytes)
-    Mp, Cp = M // k, k * C
-    bm = block_m or _pick_bm(Mp, Cp, itemsize, cap_bytes)
-    return k, Mp, Cp, bm
-
-
-def _fold(out, k, C):
-    """Undo lane packing on a (2, k*C) kernel output."""
-    return out.reshape(2, k, C).sum(axis=1) if k > 1 else out
-
-
-def _stats_kernel(x_ref, out_ref):
-    i = pl.program_id(0)
-    xb = x_ref[...].astype(jnp.float32)
-    blk = jnp.stack([jnp.sum(xb, axis=0), jnp.sum(xb * xb, axis=0)])
-
-    @pl.when(i == 0)
-    def _():
-        out_ref[...] = blk
-
-    @pl.when(i > 0)
-    def _():
-        out_ref[...] = out_ref[...] + blk
-
-
-def batch_norm_stats(x2d, interpret=False, block_m=None):
-    """Per-channel (sum, sum_of_squares) of a (M, C) array in one
-    bf16-read f32-accumulate pass. Returns two (C,) f32 arrays."""
-    M, C = x2d.shape
-    k, Mp, Cp, bm = _plan(x2d.shape, x2d.dtype, block_m,
-                          _STATS_BLOCK_BYTES)
-    xp = x2d.reshape(Mp, Cp) if k > 1 else x2d
-    out = pl.pallas_call(
-        _stats_kernel,
-        name=profile.BN_STATS,
-        grid=(Mp // bm,),
-        in_specs=[pl.BlockSpec((bm, Cp), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((2, Cp), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, Cp), jnp.float32),
-        interpret=interpret,
-    )(xp)
-    out = _fold(out, k, C)
-    return out[0], out[1]
-
-
-def _grad_stats_kernel(dy_ref, x_ref, mean_ref, rstd_ref, out_ref):
-    i = pl.program_id(0)
-    dy = dy_ref[...].astype(jnp.float32)
-    xb = x_ref[...].astype(jnp.float32)
-    xhat = (xb - mean_ref[...]) * rstd_ref[...]
-    blk = jnp.stack([jnp.sum(dy, axis=0), jnp.sum(dy * xhat, axis=0)])
-
-    @pl.when(i == 0)
-    def _():
-        out_ref[...] = blk
-
-    @pl.when(i > 0)
-    def _():
-        out_ref[...] = out_ref[...] + blk
-
-
-def batch_norm_grad_stats(dy2d, x2d, mean, rstd, interpret=False,
-                          block_m=None):
-    """Per-channel (sum(dy), sum(dy * x_hat)) — i.e. (d_beta, d_gamma)
-    — in one fused read of dy and x. mean/rstd are (C,) f32."""
-    M, C = x2d.shape
-    # Budget by the wider operand: the public API allows f32 dy with
-    # bf16 x, and the dy block must fit the per-input budget too.
-    wider = max((dy2d.dtype, x2d.dtype), key=lambda d: jnp.dtype(d).itemsize)
-    k, Mp, Cp, bm = _plan(x2d.shape, wider, block_m, _GRAD_BLOCK_BYTES)
-    dyp = dy2d.reshape(Mp, Cp) if k > 1 else dy2d
-    xp = x2d.reshape(Mp, Cp) if k > 1 else x2d
-    # Packed lane l holds channel l % C, so tile the per-channel stats.
-    meanp = jnp.tile(mean, k) if k > 1 else mean
-    rstdp = jnp.tile(rstd, k) if k > 1 else rstd
-    out = pl.pallas_call(
-        _grad_stats_kernel,
-        name=profile.BN_GRAD_STATS,
-        grid=(Mp // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, Cp), lambda i: (i, 0)),
-            pl.BlockSpec((bm, Cp), lambda i: (i, 0)),
-            pl.BlockSpec((1, Cp), lambda i: (0, 0)),
-            pl.BlockSpec((1, Cp), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((2, Cp), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((2, Cp), jnp.float32),
-        interpret=interpret,
-    )(dyp, xp, meanp.reshape(1, Cp), rstdp.reshape(1, Cp))
-    out = _fold(out, k, C)
-    return out[0], out[1]
-
-
-def _use_kernel(M):
-    # The max(8, ...) floor in _pick_bm means the kernel-usable test
-    # reduces to "M has a power-of-two divisor >= 8".
-    return M % 8 == 0
-
-
-def _stats(x2d, interpret):
-    M, C = x2d.shape
-    if interpret is not None and _use_kernel(M):
-        s, ss = batch_norm_stats(x2d, interpret)
-    else:
-        xf = x2d.astype(jnp.float32)
-        s, ss = jnp.sum(xf, axis=0), jnp.sum(xf * xf, axis=0)
-    return s, ss
-
-
-def _bn_train_fwd(x2d, gamma, beta, eps, interpret, axis_name=None):
-    M, C = x2d.shape
-    s, ss = _stats(x2d, interpret)
-    if axis_name is not None:
-        # Cross-replica (sync) BN: the kernels produce per-device
-        # partial sums; one packed psum over the data axis makes the
-        # statistics global. M_g = M * group size (equal shards).
-        s, ss = jax.lax.psum((s, ss), axis_name)
-        M = M * jax.lax.psum(1, axis_name)
-    mean = s / M
-    var = jnp.maximum(ss / M - mean * mean, 0.0)
-    rstd = jax.lax.rsqrt(var + eps)
-    a = gamma * rstd
-    b = beta - mean * a
-    # Normalize stays in XLA: it fuses with neighbors (residual/ReLU).
-    y = (x2d.astype(jnp.float32) * a + b).astype(x2d.dtype)
-    return (y, mean, var), (x2d, gamma, mean, rstd)
-
-
-def _bn_train_bwd(eps, interpret, axis_name, res, cotangents):
-    gy, gmean, gvar = cotangents
-    x2d, gamma, mean, rstd = res
-    M, C = x2d.shape
-    gyf = gy.astype(jnp.float32) if gy.dtype != jnp.float32 else gy
-    xf = x2d.astype(jnp.float32)
-    xhat = (xf - mean) * rstd
-    if interpret is not None and _use_kernel(M):
-        dbeta, dgamma = batch_norm_grad_stats(gy, x2d, mean, rstd,
-                                              interpret)
-    else:
-        dbeta = jnp.sum(gyf, axis=0)
-        dgamma = jnp.sum(gyf * xhat, axis=0)
-    if axis_name is not None:
-        # dx needs the GLOBAL reductions over the sync group; the
-        # returned dgamma/dbeta stay local — the training loop's
-        # gradient allreduce completes them (matching what autodiff
-        # of a psum-of-stats formulation yields).
-        dbeta_g, dgamma_g = jax.lax.psum((dbeta, dgamma), axis_name)
-        Mg = M * jax.lax.psum(1, axis_name)
-    else:
-        dbeta_g, dgamma_g, Mg = dbeta, dgamma, M
-    dx = (gamma * rstd) * (gyf - dbeta_g / Mg - xhat * (dgamma_g / Mg))
-    # Direct mean/var cotangent terms (zero in training use — running
-    # stats aren't differentiated — and XLA folds the add-zeros away;
-    # kept exact so jax.grad through mean/var is still correct).
-    dx = dx + gmean / Mg + gvar * (2.0 / Mg) * (xf - mean)
-    return dx.astype(x2d.dtype), dgamma, dbeta
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def fused_batch_norm_train(x2d, gamma, beta, eps=1e-5, interpret=False,
-                           axis_name=None):
-    """Training-mode BN over (M, C): returns (y, mean, var) with the
-    Pallas stats kernels on both the forward and the VJP path. mean /
-    var are f32 batch statistics for the caller's running-stats
-    update. `axis_name` enables cross-replica (sync) BN: statistics
-    are psummed over that mesh axis (kernels stay per-device; one
-    packed psum each way rides the ICI)."""
-    return _bn_train_fwd(x2d, gamma, beta, eps, interpret, axis_name)[0]
-
-
-def _bn_train_vjp_fwd(x2d, gamma, beta, eps, interpret, axis_name):
-    return _bn_train_fwd(x2d, gamma, beta, eps, interpret, axis_name)
-
-
-fused_batch_norm_train.defvjp(_bn_train_vjp_fwd, _bn_train_bwd)
-
 
 # ---------------------------------------------------------------------------
 # Traffic-lean BatchNorm (round 10): the graph-level answer to the round-4
@@ -461,68 +211,6 @@ def bn_remat_policy():
 try:
     import flax.linen as nn
 
-    class PallasBatchNorm(nn.Module):
-        """Drop-in for `nn.BatchNorm` (the subset ResNet uses) with the
-        fused Pallas statistics path in training mode. Eval mode (
-        `use_running_average=True`) is pure elementwise math and stays
-        in XLA entirely."""
-        use_running_average: bool = False
-        momentum: float = 0.9
-        epsilon: float = 1e-5
-        dtype: Any = None
-        param_dtype: Any = jnp.float32
-        scale_init: Callable = nn.initializers.ones
-        bias_init: Callable = nn.initializers.zeros
-        axis_name: str = None  # sync BN: psum stats over this mesh axis
-        # Ghost BN (virtual batches normalized independently): routed
-        # through the graph-level lean path — per-group stats would
-        # multiply the kernel islands, the exact round-4 failure mode.
-        virtual_batch_size: int = None
-        interpret: bool = False
-
-        @nn.compact
-        def __call__(self, x):
-            C = x.shape[-1]
-            scale = self.param("scale", self.scale_init, (C,),
-                               self.param_dtype)
-            bias = self.param("bias", self.bias_init, (C,),
-                              self.param_dtype)
-            ra_mean = self.variable("batch_stats", "mean",
-                                    lambda: jnp.zeros(C, jnp.float32))
-            ra_var = self.variable("batch_stats", "var",
-                                   lambda: jnp.ones(C, jnp.float32))
-            if self.use_running_average:
-                a = scale * jax.lax.rsqrt(ra_var.value + self.epsilon)
-                b = bias - ra_mean.value * a
-                return (x.astype(jnp.float32) * a + b).astype(
-                    self.dtype or x.dtype)
-            x2d = x.reshape(-1, C)
-            if self.virtual_batch_size:
-                N = x.shape[0]
-                if N % self.virtual_batch_size:
-                    raise ValueError(
-                        "virtual_batch_size=%d does not divide the "
-                        "batch %d" % (self.virtual_batch_size, N))
-                groups = N // self.virtual_batch_size
-                y, mean, var = lean_batch_norm_train(
-                    x2d, scale, bias, self.epsilon, False,
-                    groups, self.axis_name,
-                    None, "lean_bn/%s" % "/".join(self.scope.path))
-                if groups > 1:  # (G, C) group stats -> (C,) running
-                    mean, var = mean.mean(axis=0), var.mean(axis=0)
-            else:
-                interpret = self.interpret
-                if jax.default_backend() != "tpu" and not interpret:
-                    interpret = None  # plain-XLA fallback off-TPU
-                y, mean, var = fused_batch_norm_train(
-                    x2d, scale, bias, self.epsilon, interpret,
-                    self.axis_name)
-            if not self.is_initializing():
-                m = self.momentum
-                ra_mean.value = m * ra_mean.value + (1 - m) * mean
-                ra_var.value = m * ra_var.value + (1 - m) * var
-            return y.reshape(x.shape).astype(self.dtype or x.dtype)
-
     class LeanBatchNorm(nn.Module):
         """Drop-in for ``nn.BatchNorm`` (the subset the conv zoo uses)
         on the traffic-lean graph-level path: one-pass variadic-reduce
@@ -605,5 +293,4 @@ try:
             y = checkpoint_name(y, "hvd_bn_norm")
             return y.astype(self.dtype or x.dtype)
 except ImportError:  # pragma: no cover - flax is baked into this env
-    PallasBatchNorm = None
     LeanBatchNorm = None

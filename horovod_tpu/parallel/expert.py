@@ -467,27 +467,26 @@ class MoeMlp(nn.Module):
             w_gate = None
             w_in = expert("w_in", R, self.mlp_dim)
             w_out = expert("w_out", self.mlp_dim, R)
-        new = {}  # only what a layer sets: the others' calls stay as they were
-        if self.act != "silu":
-            new["act"] = ACTIVATIONS[self.act]
+        act, rows = ACTIVATIONS[self.act], None
         with jax.named_scope(profile.MOE):
             if self.latent_dim is not None:
                 with jax.named_scope(profile.MOE_LATENT):
-                    new["rows"] = dense(R, "latent_in")(
+                    rows = dense(R, "latent_in")(
                         x.reshape(-1, D)).astype(self.dtype)
             y, stats = moe_ffn(x.reshape(-1, D).astype(self.dtype), router_w,
                                w_in, w_out,
                                capacity_factor=self.capacity_factor,
-                               ep_axis=self.ep_axis, top_k=self.top_k,
-                               w_gate=w_gate, renormalize=self.renormalize,
+                               ep_axis=self.ep_axis, act=act,
+                               top_k=self.top_k, w_gate=w_gate,
+                               renormalize=self.renormalize,
                                scoring=self.scoring, bias=bias,
-                               scale=self.route_scale, held=self.held, **new)
+                               scale=self.route_scale, held=self.held,
+                               rows=rows)
             if self.latent_dim is not None:
                 with jax.named_scope(profile.MOE_LATENT):
                     y = dense(D, "latent_out")(y)
             if self.shared_dim is not None:
                 with jax.named_scope(profile.MOE_SHARED):
-                    act = ACTIVATIONS[self.act]
                     xs = x.reshape(-1, D)
                     if self.shared_gated:
                         h = act(dense(self.shared_dim, "shared_gate")(xs)) \

@@ -184,8 +184,6 @@ FLASH_BWD = "hvd_flash_bwd"  # dQ, dK and dV in one kernel (resident)
 RING_ATTN = "hvd_ring_attn"          # one forward step of ring attention
 RING_ATTN_DQ = "hvd_ring_attn_dq"    # one backward step: the dQ part
 RING_ATTN_DKV = "hvd_ring_attn_dkv"  # one backward step: the dK/dV part
-BN_STATS = "hvd_bn_stats"
-BN_GRAD_STATS = "hvd_bn_grad_stats"
 MOE_GMM = "hvd_moe_gmm"            # grouped matmul of the experts, forward
 MOE_GMM_DLHS = "hvd_moe_gmm_dlhs"  # backward: the gradient of the rows
 MOE_GMM_DRHS = "hvd_moe_gmm_drhs"  # backward: the gradient of the matrices
@@ -225,8 +223,8 @@ KDA_WY = "hvd_kda_wy"          # (W | Q e^G, U, qk, K e^(G_last - G), e^G_last)
 KDA_WY_BWD = "hvd_kda_wy_bwd"  # (dq, dk, dv, dG, dbeta, the squares')
 KDA_KERNELS = (KDA_SCORES, KDA_SCORES_BWD, KDA_WY, KDA_WY_BWD)
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
-           RING_ATTN_DQ, RING_ATTN_DKV, BN_STATS,
-           BN_GRAD_STATS) + MOE_GMM_KERNELS + (HC_STAT, HC_STAT_DPHI) \
+           RING_ATTN_DQ, RING_ATTN_DKV) + MOE_GMM_KERNELS \
+    + (HC_STAT, HC_STAT_DPHI) \
     + MOE_ROWS_KERNELS + MOE_ACT_KERNELS + KDA_KERNELS
 
 # Host spans a traced window shows: the program's only per-call Python

@@ -1,202 +1,25 @@
-"""Fused Pallas BatchNorm correctness, pinned against flax BatchNorm
-(interpret mode on CPU; `tests/test_chip_compile.py` compiles the
-kernels for the v5e)."""
+"""The traffic-lean BatchNorm (`ops/batch_norm.py`), pinned against flax
+BatchNorm on the CPU: values, statistics, gradients, sync BN over a mesh
+axis and over the host plane, ghost BN."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.ops.batch_norm import (LeanBatchNorm, PallasBatchNorm,
-                                        batch_norm_stats,
-                                        batch_norm_grad_stats,
-                                        bn_remat_policy,
-                                        fused_batch_norm_train,
+from horovod_tpu.ops.batch_norm import (LeanBatchNorm, bn_remat_policy,
                                         lean_batch_norm_train)
 
 jax.config.update("jax_default_matmul_precision", "highest")
 
 
-def test_stats_kernel_matches_numpy():
-    rng = np.random.RandomState(0)
-    x = rng.randn(512, 192).astype(np.float32)
-    s, ss = batch_norm_stats(jnp.asarray(x), interpret=True)
-    np.testing.assert_allclose(np.asarray(s), x.sum(0), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(ss), (x * x).sum(0), rtol=1e-5)
-
-
-def test_stats_kernel_bf16_read_f32_accumulate():
-    rng = np.random.RandomState(1)
-    x = rng.randn(2048, 128).astype(np.float32)
-    xb = jnp.asarray(x).astype(jnp.bfloat16)
-    s, ss = batch_norm_stats(xb, interpret=True)
-    assert s.dtype == jnp.float32
-    # Accumulation error must be f32-like (bf16 inputs, not bf16 sums).
-    ref = np.asarray(xb.astype(jnp.float32)).sum(0)
-    np.testing.assert_allclose(np.asarray(s), ref, rtol=1e-5, atol=1e-3)
-
-
-def test_grad_stats_kernel_matches_numpy():
-    rng = np.random.RandomState(2)
-    x = rng.randn(256, 64).astype(np.float32)
-    dy = rng.randn(256, 64).astype(np.float32)
-    mean = x.mean(0)
-    rstd = 1.0 / np.sqrt(x.var(0) + 1e-5)
-    dbeta, dgamma = batch_norm_grad_stats(
-        jnp.asarray(dy), jnp.asarray(x), jnp.asarray(mean),
-        jnp.asarray(rstd), interpret=True)
-    xhat = (x - mean) * rstd
-    np.testing.assert_allclose(np.asarray(dbeta), dy.sum(0), rtol=1e-4)
-    np.testing.assert_allclose(np.asarray(dgamma), (dy * xhat).sum(0),
-                               rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("M,C", [(512, 128), (392, 64)])
-def test_fused_bn_train_matches_flax(M, C):
-    """Forward outputs, batch stats, AND gradients (x, gamma, beta)
-    must match flax.linen.BatchNorm in training mode. M=392 = 8*49
-    exercises the small-power-of-two block path."""
-    import flax.linen as nn
-
-    rng = np.random.RandomState(3)
-    x = jnp.asarray(rng.randn(M, C).astype(np.float32)) * 2.0 + 0.5
-    gamma = jnp.asarray(rng.rand(C).astype(np.float32) + 0.5)
-    beta = jnp.asarray(rng.randn(C).astype(np.float32))
-
-    bn = nn.BatchNorm(use_running_average=False, momentum=0.9,
-                      epsilon=1e-5)
-    variables = {"params": {"scale": gamma, "bias": beta},
-                 "batch_stats": {"mean": jnp.zeros(C),
-                                 "var": jnp.ones(C)}}
-
-    def flax_loss(x, gamma, beta):
-        v = {"params": {"scale": gamma, "bias": beta},
-             "batch_stats": variables["batch_stats"]}
-        y, _ = bn.apply(v, x, mutable=["batch_stats"])
-        return jnp.sum(y ** 2), y
-
-    def fused_loss(x, gamma, beta):
-        y, mean, var = fused_batch_norm_train(x, gamma, beta, 1e-5, True)
-        return jnp.sum(y.astype(jnp.float32) ** 2), (y, mean, var)
-
-    (l1, y1), g1 = jax.value_and_grad(flax_loss, argnums=(0, 1, 2),
-                                      has_aux=True)(x, gamma, beta)
-    (l2, (y2, mean, var)), g2 = jax.value_and_grad(
-        fused_loss, argnums=(0, 1, 2), has_aux=True)(x, gamma, beta)
-
-    np.testing.assert_allclose(np.asarray(y2), np.asarray(y1),
-                               rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(mean), np.asarray(x).mean(0),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(var),
-                               np.asarray(x).var(0), rtol=1e-4, atol=1e-4)
-    for a, b, nm in zip(g2, g1, ("dx", "dgamma", "dbeta")):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-3, atol=2e-3, err_msg=nm)
-
-
-def test_pallas_bn_module_train_eval_roundtrip():
-    """The flax module: training updates running stats like
-    nn.BatchNorm; eval mode uses them identically."""
-    import flax.linen as nn
-
-    rng = np.random.RandomState(4)
-    x = jnp.asarray(rng.randn(4, 8, 8, 32).astype(np.float32))
-
-    ours_t = PallasBatchNorm(use_running_average=False, momentum=0.9,
-                             epsilon=1e-5, interpret=True)
-    flax_t = nn.BatchNorm(use_running_average=False, momentum=0.9,
-                          epsilon=1e-5)
-    v0 = flax_t.init(jax.random.PRNGKey(0), x)
-    y_f, upd_f = flax_t.apply(v0, x, mutable=["batch_stats"])
-    y_o, upd_o = ours_t.apply(v0, x, mutable=["batch_stats"])
-    np.testing.assert_allclose(np.asarray(y_o), np.asarray(y_f),
-                               rtol=2e-4, atol=2e-4)
-    for k in ("mean", "var"):
-        np.testing.assert_allclose(
-            np.asarray(upd_o["batch_stats"][k]),
-            np.asarray(upd_f["batch_stats"][k]), rtol=1e-4, atol=1e-5)
-
-    ours_e = PallasBatchNorm(use_running_average=True, epsilon=1e-5)
-    flax_e = nn.BatchNorm(use_running_average=True, epsilon=1e-5)
-    v1 = {"params": v0["params"], "batch_stats": upd_f["batch_stats"]}
-    np.testing.assert_allclose(
-        np.asarray(ours_e.apply(v1, x)),
-        np.asarray(flax_e.apply(v1, x)), rtol=2e-4, atol=2e-4)
-
-
-def test_sync_bn_matches_global_batch():
-    """axis_name sync BN over a 4-way sharded batch must equal plain BN
-    over the concatenated batch under the canonical DP loss contract
-    (each shard computes a LOCAL loss; total = implicit sum over
-    shards; param grads are per-shard contributions the gradient
-    allreduce completes): outputs, batch stats, dx per shard, and
-    summed dgamma/dbeta must all match the global-batch run. No
-    explicit loss psum — under check_vma=False its transpose is
-    another psum, which would scale every cotangent by n."""
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    n, M, C = 4, 64, 32
-    rng = np.random.RandomState(5)
-    x = jnp.asarray(rng.randn(n * M, C).astype(np.float32)) * 1.5 + 0.3
-    # Random linear loss weights: sum(y*w) has a non-degenerate dx
-    # (sum(y^2)'s dx is ~1e-5 — BN outputs are nearly invariant to
-    # input perturbations — and would vacuously pass any atol).
-    w = jnp.asarray(rng.randn(n * M, C).astype(np.float32))
-    gamma = jnp.asarray(rng.rand(C).astype(np.float32) + 0.5)
-    beta = jnp.asarray(rng.randn(C).astype(np.float32))
-    mesh = Mesh(np.array(jax.devices("cpu")[:n]), ("dp",))
-
-    def global_loss(x, gamma, beta):
-        y, mean, var = fused_batch_norm_train(x, gamma, beta, 1e-5, True)
-        return jnp.sum(y * w), (mean, var)
-
-    def sharded_loss(xs, gamma, beta, ws):
-        y, mean, var = fused_batch_norm_train(
-            xs, gamma, beta, 1e-5, True, "dp")
-        return jnp.sum(y * ws), (mean, var)
-
-    (l_g, (mean_g, var_g)), g_g = jax.value_and_grad(
-        global_loss, argnums=(0, 1, 2), has_aux=True)(x, gamma, beta)
-
-    fwd = jax.jit(jax.shard_map(
-        lambda xs, gamma, beta: fused_batch_norm_train(
-            xs, gamma, beta, 1e-5, True, "dp"),
-        mesh=mesh, in_specs=(P("dp"), P(), P()),
-        out_specs=(P("dp"), P(None), P(None)), check_vma=False))
-    y_s, mean_s, var_s = fwd(x, gamma, beta)
-
-    grad = jax.jit(jax.shard_map(
-        jax.grad(lambda *a: sharded_loss(*a)[0], argnums=(0, 1, 2)),
-        mesh=mesh, in_specs=(P("dp"), P(), P(), P("dp")),
-        out_specs=(P("dp"), P("dp"), P("dp")), check_vma=False))
-    dx_s, dgamma_s, dbeta_s = grad(x, gamma, beta, w)
-
-    np.testing.assert_allclose(float(jnp.sum(y_s * w)), float(l_g),
-                               rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(mean_s), np.asarray(mean_g),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(var_s), np.asarray(var_g),
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(dx_s), np.asarray(g_g[0]),
-                               rtol=1e-4, atol=1e-5)
-    # Per-shard param-grad contributions; their sum (the gradient
-    # allreduce) equals the global-batch parameter gradient.
-    np.testing.assert_allclose(
-        np.asarray(dgamma_s).reshape(n, C).sum(0), np.asarray(g_g[1]),
-        rtol=1e-4, atol=1e-4)
-    np.testing.assert_allclose(
-        np.asarray(dbeta_s).reshape(n, C).sum(0), np.asarray(g_g[2]),
-        rtol=1e-4, atol=1e-4)
-
-
-def test_resnet_sync_bn_wiring():
+@pytest.mark.parametrize("norm", ["batch", "lean"])
+def test_resnet_sync_bn_wiring(norm):
     """ResNet(bn_axis_name='dp'): training forward over a 4-way
     sharded batch produces the same outputs and running-stat updates
     as the unsharded model (sync BN sees the global batch either
     way). Covers the model-level wiring of both norm paths' axis_name
-    plumb-through (the pallas module falls back to XLA stats off-TPU
-    but keeps the psum)."""
+    plumb-through."""
     from jax.sharding import Mesh, PartitionSpec as P
     from horovod_tpu.models.resnet import ResNet, BottleneckBlock
 
@@ -208,7 +31,7 @@ def test_resnet_sync_bn_wiring():
     def build(axis):
         return ResNet(stage_sizes=[1], block_cls=BottleneckBlock,
                       num_classes=5, num_filters=8, dtype=jnp.float32,
-                      norm="pallas", bn_axis_name=axis)
+                      norm=norm, bn_axis_name=axis)
 
     variables = build(None).init(jax.random.PRNGKey(0), x, train=False)
     y_ref, upd_ref = build(None).apply(
@@ -238,48 +61,21 @@ def test_resnet_sync_bn_wiring():
             rtol=1e-4, atol=1e-5, err_msg=str(path))
 
 
-def test_resnet_pallas_variant_one_step():
-    """ResNet50PBN: one train step runs, loss finite, batch_stats
-    update present (CPU falls back to the plain-XLA stats path via the
-    same fused_batch_norm_train custom-VJP)."""
-    from horovod_tpu.models import ResNet50PBN
+@pytest.mark.parametrize("norm", ["pallas", "bacth"])
+def test_unknown_norm_is_refused_by_name(norm):
+    """A `norm` the model does not know is refused with the ones there
+    are, not handed to flax's BatchNorm in silence ("pallas" was a value
+    until PR 60)."""
+    from horovod_tpu.models.resnet import ResNet, BottleneckBlock
 
-    model = ResNet50PBN(num_classes=10, dtype=jnp.float32)
-    x = jnp.ones((2, 32, 32, 3), jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-
-    def loss_fn(params):
-        logits, upd = model.apply(
-            {"params": params, "batch_stats": variables["batch_stats"]},
-            x, train=True, mutable=["batch_stats"])
-        return jnp.mean(logits ** 2)
-
-    loss, grads = jax.value_and_grad(loss_fn)(variables["params"])
-    assert np.isfinite(float(loss))
-    flat = jax.tree_util.tree_leaves(grads)
-    assert all(np.all(np.isfinite(np.asarray(g))) for g in flat)
-
-
-def test_inception_pallas_variant_one_step():
-    """InceptionV3 with norm='pallas' (the zoo's most BN-bound model):
-    one train step, finite loss and grads."""
-    from horovod_tpu.models import InceptionV3
-
-    model = InceptionV3(norm="pallas", num_classes=10, dtype=jnp.float32)
-    x = jnp.ones((2, 96, 96, 3), jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-
-    def loss_fn(params):
-        logits, _ = model.apply(
-            {"params": params, "batch_stats": variables["batch_stats"]},
-            x, train=True, mutable=["batch_stats"],
-            rngs={"dropout": jax.random.PRNGKey(1)})
-        return jnp.mean(logits ** 2)
-
-    loss, grads = jax.value_and_grad(loss_fn)(variables["params"])
-    assert np.isfinite(float(loss))
-    assert all(np.all(np.isfinite(np.asarray(g)))
-               for g in jax.tree_util.tree_leaves(grads))
+    model = ResNet(stage_sizes=[1], block_cls=BottleneckBlock, num_classes=5,
+                   num_filters=8, dtype=jnp.float32, norm=norm)
+    with pytest.raises(ValueError) as refused:
+        model.init(jax.random.PRNGKey(0), jnp.ones((2, 16, 16, 3)),
+                   train=False)
+    for known in ("batch", "none", "group", "lean"):
+        assert repr(known) in str(refused.value)
+    assert repr(norm) in str(refused.value)
 
 
 # --- round 10: the traffic-lean graph-level BN -----------------------------
@@ -459,6 +255,17 @@ def test_lean_module_train_eval_roundtrip_and_ghost():
     np.testing.assert_allclose(
         np.asarray(upd_g["batch_stats"]["mean"]),
         0.9 * 0.0 + 0.1 * means.mean(0), rtol=1e-4, atol=1e-5)
+
+    # One ghost group is plain BN: the running statistics stay (C,)-shaped
+    # and match flax's (a groups == 1 path once collapsed them to a
+    # cross-channel scalar).
+    one = LeanBatchNorm(momentum=0.9, epsilon=1e-5, virtual_batch_size=8)
+    _, upd_1 = one.apply(v0, x, mutable=["batch_stats"])
+    for k in ("mean", "var"):
+        got = np.asarray(upd_1["batch_stats"][k])
+        assert got.shape == (16,), got.shape
+        np.testing.assert_allclose(
+            got, np.asarray(upd_f["batch_stats"][k]), rtol=1e-4, atol=1e-5)
 
     # virtual_batch_size must divide the batch.
     with pytest.raises(ValueError):
@@ -675,27 +482,3 @@ def test_sync_batch_norm_stats_wrapper():
                                atol=1e-6)
     np.testing.assert_allclose(np.asarray(var), x.var(0), rtol=1e-4,
                                atol=1e-5)
-
-
-def test_pallas_ghost_bn_degenerate_single_group():
-    """PallasBatchNorm(virtual_batch_size == batch): one ghost group is
-    plain BN — running stats must stay (C,)-shaped and match flax (a
-    groups==1 path once collapsed them to a cross-channel scalar)."""
-    import flax.linen as nn
-
-    rng = np.random.RandomState(12)
-    x = jnp.asarray(rng.randn(4, 4, 4, 16).astype(np.float32))
-    flax_t = nn.BatchNorm(use_running_average=False, momentum=0.9,
-                          epsilon=1e-5)
-    v0 = flax_t.init(jax.random.PRNGKey(0), x)
-    _, upd_f = flax_t.apply(v0, x, mutable=["batch_stats"])
-    mod = PallasBatchNorm(use_running_average=False, momentum=0.9,
-                          epsilon=1e-5, virtual_batch_size=4,
-                          interpret=True)
-    _, upd_o = mod.apply(v0, x, mutable=["batch_stats"])
-    for k in ("mean", "var"):
-        got = np.asarray(upd_o["batch_stats"][k])
-        assert got.shape == (16,), got.shape
-        np.testing.assert_allclose(
-            got, np.asarray(upd_f["batch_stats"][k]),
-            rtol=1e-4, atol=1e-5)

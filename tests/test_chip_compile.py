@@ -22,7 +22,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from chip_smoke import kernel_calls as _kernels, kernel_named as _named
-from horovod_tpu.ops import BandMask, BlockDiffusionMask, batch_norm
+from horovod_tpu.ops import BandMask, BlockDiffusionMask
 from horovod_tpu import profile
 from horovod_tpu.ops.flash_attention import (_flash, _flash_shared,
                                              _pallas_forward_lse,
@@ -252,26 +252,6 @@ def test_ring_backward_step_compiles_for_v5e(one_chip, group):
         ((BG // group, L, D), f32), ((), i32), ((), i32))
     # dQ, dK/dV
     assert _kernels(text) == 2, text[:2000]
-
-
-# (M, C) of the largest and the smallest BatchNorm of ResNet-50 at batch
-# 256 (bf16 activations, as the model feeds them).
-_BN_SHAPES = [(256 * 112 * 112, 64), (256 * 7 * 7, 2048)]
-
-
-@pytest.mark.parametrize("M,C", _BN_SHAPES)
-def test_bn_stats_compiles_for_v5e(one_chip, M, C):
-    text = _compile(one_chip, batch_norm.batch_norm_stats,
-                    ((M, C), jnp.bfloat16))
-    assert _kernels(text) == 1, text[:2000]
-
-
-@pytest.mark.parametrize("M,C", _BN_SHAPES)
-def test_bn_grad_stats_compiles_for_v5e(one_chip, M, C):
-    f32, bf16 = jnp.float32, jnp.bfloat16
-    text = _compile(one_chip, batch_norm.batch_norm_grad_stats,
-                    ((M, C), bf16), ((M, C), bf16), ((C,), f32), ((C,), f32))
-    assert _kernels(text) == 1, text[:2000]
 
 
 # The experts of OLMoE-1B-7B on one chip: 8 x 4096 assigned rows in 64
