@@ -9,11 +9,15 @@ against the jnp form where that fits; the unit-lower-triangular solve alone;
 and the chunk stage's kernels alone (the own blocks' two in the model's
 layout; `hvd_kda_wy` as the primal call and as the rule's forward, which also
 saves; `hvd_kda_wy_bwd`) at each `--chunks` (chunks a grid step,
-`kda.BLOCK_CHUNKS`) and `--side` (chunks a loop iteration, `kda.SIDE`). Each
-kernel form is held to the jnp one before it is timed.
+`kda.BLOCK_CHUNKS`) and `--side` (chunks a loop iteration, `kda.SIDE`); and
+the scan over the chunks alone, forward and with its backward, `lax.scan` in
+jnp against the two kernels (`hvd_kda_scan` as the primal call and as the
+rule's forward, which also saves the states; `hvd_kda_scan_bwd`) at each
+`--heads` (heads a grid step, `kda.SCAN_HEADS`), ms a call beside the least
+the bytes allow. Each kernel form is held to the jnp one before it is timed.
 
 Usage: python examples/kda_sweep.py [--blocks 32 64 128] [--groups 1 2 4 8]
-       [--chunks 4 8 16] [--side 2] [--iters 10] [--cpu]
+       [--chunks 4 8 16] [--side 2] [--heads 8 16 32] [--iters 10] [--cpu]
        (--cpu: tiny shapes, the interpreter, no times; an option with no
        value skips its part)
 """
@@ -45,6 +49,16 @@ def timed(fn, args, iters):
     return 1e3 * (time.perf_counter() - t0) / iters
 
 
+def worst(got, ref):
+    """The largest error of any array of `got`, relative to the largest
+    entry of its twin in `ref`."""
+    f32 = jnp.float32
+    return max(
+        float(jnp.max(jnp.abs(a.astype(f32) - b.astype(f32)))
+              / jnp.maximum(jnp.max(jnp.abs(b.astype(f32))), 1e-30))
+        for a, b in zip(got, ref))
+
+
 def chunk_stage(args, inputs, interpret):
     """The chunk stage's kernels alone by chunks a grid step, each held to
     the jnp form (every result; the five gradients of a scalar of them)."""
@@ -58,9 +72,9 @@ def chunk_stage(args, inputs, interpret):
     own_block = kda.own_plan(L // sub, sub, D, interpret)
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
 
-    def in_jnp(*a):  # the jnp form's results laid as the scan reads them
-        return tuple(jnp.moveaxis(t, 2, 0)
-                     for t in kda._chunk_stage_jnp(*a, C, sub, interpret))
+    def in_jnp(*a):  # the jnp form's results laid as the kernels write them
+        *four, keep = kda._chunk_stage_jnp(*a, C, sub, interpret)
+        return tuple(jnp.moveaxis(t, 2, 0) for t in four) + (keep[..., 0],)
 
     want = jax.jit(in_jnp)(*inputs)
     cot = tuple(jax.random.normal(kk, t.shape).astype(t.dtype)
@@ -72,11 +86,6 @@ def chunk_stage(args, inputs, interpret):
 
     want_grad = jax.jit(jax.grad(lambda *a: scalar(in_jnp(*a)),
                                  argnums=(0, 1, 2, 3, 4)))(*inputs)
-    worst = lambda got, ref: max(  # noqa: E731
-        float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                              - b.astype(jnp.float32)))
-              / jnp.maximum(jnp.max(jnp.abs(b.astype(jnp.float32))), 1e-30))
-        for a, b in zip(got, ref))
     rows = []
     if not args.cpu and args.chunks:
         rows.append({"own_blocks_ms": timed(
@@ -115,11 +124,82 @@ def chunk_stage(args, inputs, interpret):
                "bwd_rel_err": worst(got_grad, want_grad)}
         if not args.cpu:
             saved = saving(*flat)[5:]
-            kcot = cot[:4] + (jnp.moveaxis(cot[4][..., 0], 0, 2),)
             row["wy_ms"] = timed(primal, flat, args.iters)
             row["wy_saving_ms"] = timed(saving, flat, args.iters)
-            row["wy_bwd_ms"] = timed(backward, flat + [kcot, tuple(saved)],
+            row["wy_bwd_ms"] = timed(backward, flat + [cot, tuple(saved)],
                                      args.iters)
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows
+
+
+HBM_BYTES_A_S = 819e9  # benchmark/peaks.json, "TPU v5 lite"
+
+
+def scan_alone(args, inputs, interpret):
+    """The scan over the chunks alone by heads a grid step: `lax.scan` in jnp
+    and the kernels, each forward and forward + backward (the five operands'
+    cotangents from a fixed cotangent of o), the kernels one at a time too;
+    the kernels held to the jnp scan; ms beside the least by bytes (every
+    operand read once, every result written once)."""
+    B, L, H, D = inputs[0].shape
+    steps = kda._chunk_stage_kernels(
+        *inputs, 64, 16, kda.chunk_plan(B, L, H, D, D, 64, 16, interpret),
+        bool(interpret))
+    do = jax.random.normal(jax.random.PRNGKey(2), (B, L, H, D))
+    nbytes = lambda ts: sum(t.size * t.dtype.itemsize for t in ts)  # noqa
+    states = 4 * B * H * D * D * (L // 64)
+    least = {"fwd": nbytes(steps) + nbytes([do]),
+             "bwd": 2 * nbytes(steps) + nbytes([do]) + states}
+    least["saving"] = least["fwd"] + states
+    least_ms = {k: 1e3 * v / HBM_BYTES_A_S for k, v in least.items()}
+
+    def in_jnp(*s):
+        return kda._scan_jnp(*s[:4], jnp.moveaxis(s[4], 2, 0)[..., None])[0]
+
+    def with_backward(f):  # the last argument: o's cotangent
+        def both(*s):
+            out, vjp = jax.vjp(f, *s[:-1])
+            return out, vjp(s[-1])
+        return jax.jit(both)
+
+    want = with_backward(in_jnp)(*steps, do)
+    rows = []
+    if not args.cpu and args.heads:
+        rows.append({"scan": "jnp", "least_ms": least_ms,
+                     "fwd_ms": timed(jax.jit(in_jnp), steps, args.iters),
+                     "fwd_bwd_ms": timed(with_backward(in_jnp),
+                                         tuple(steps) + (do,), args.iters)})
+        print(json.dumps(rows[-1]), flush=True)
+    for heads in args.heads:
+        # a block of o [B, L, H, Dv] is a tile's eight sublanes, or all heads
+        if H % heads or (not args.cpu and heads % 8 and heads != H):
+            continue
+
+        def kernels(*s):
+            return kda._scan_kernels(*s, heads, bool(interpret))[0]
+
+        def one(save):
+            return lambda *s: kda._pallas_scan(
+                *s, None, None, heads, save, bool(interpret))
+
+        got = with_backward(kernels)(*steps, do)
+        row = {"scan": "kernels", "heads": heads,
+               "fwd_rel_err": worst(got[:1], want[:1]),
+               "bwd_rel_err": worst(got[1], want[1])}
+        if not args.cpu:
+            saved = one(True)(*steps)[3]
+            cot = (do, jnp.zeros((B, H, D, D), jnp.float32))
+            row.update(
+                fwd_ms=timed(jax.jit(kernels), steps, args.iters),
+                fwd_bwd_ms=timed(with_backward(kernels),
+                                 tuple(steps) + (do,), args.iters),
+                scan_ms=timed(one(False), steps, args.iters),
+                scan_saving_ms=timed(one(True), steps, args.iters),
+                scan_bwd_ms=timed(
+                    lambda *s: kda._pallas_scan(*s[:5], s[5], s[6], heads,
+                                                False, False),
+                    tuple(steps) + (cot, saved), args.iters))
         rows.append(row)
         print(json.dumps(row), flush=True)
     return rows
@@ -131,6 +211,7 @@ def main():
     ap.add_argument("--groups", type=int, nargs="*", default=[1, 2, 4, 8])
     ap.add_argument("--chunks", type=int, nargs="*", default=[4, 8, 16])
     ap.add_argument("--side", type=int, default=kda.SIDE)
+    ap.add_argument("--heads", type=int, nargs="*", default=[8, 16, 32])
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--cpu", action="store_true")
     args = ap.parse_args()
@@ -208,6 +289,7 @@ def main():
             print(json.dumps(row), flush=True)
     out["own_block_scores"] = rows
     out["chunk_stage"] = chunk_stage(args, (q, k, v, g, beta), interpret)
+    out["scan"] = scan_alone(args, (q, k, v, g, beta), interpret)
     if not args.cpu:
         C = 64
         a = jnp.tril(jax.random.normal(ks[5], (B, H, L // C, C, C)), -1) \

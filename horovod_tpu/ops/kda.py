@@ -1,7 +1,8 @@
 """Kimi Delta Attention's recurrence in its chunked form (Kimi Linear,
 arXiv:2510.26692: a gated delta rule whose decay is a vector a head and
-token): Pallas kernels for what is made a chunk at a time, a `lax.scan` over
-the chunks, and the same in jnp for the calls the kernels do not take.
+token): Pallas kernels for what is made a chunk at a time and for the scan
+over the chunks, and the same in jnp (a `lax.scan`) for the calls the kernels
+do not take.
 
 Per head, with a state S [D, Dv], a key k_t of norm 1, a decay alpha_t =
 exp(g_t) in (0, 1]^D and a step beta_t in (0, 1):
@@ -21,8 +22,8 @@ starts from S (G_t = sum_{r <= t} g_r over the chunk's tokens, f32, <= 0):
     S'   = Diag(exp(G_last)) S + (K (.) exp(G_last - G))^T V'
 
 A, the solve, W and U are made for all chunks at once (the chunk stage); the
-L / C chunks are tied by a `lax.scan` whose body is the last three lines.
-Never a loop over the tokens, never an [L, L] array.
+L / C chunks are tied by a scan whose body is the last three lines. Never a
+loop over the tokens, never an [L, L] array.
 
 The decayed scores do not factor into one product: exp(G_t) exp(-G_s) has a
 factor that overflows (64 tokens at alpha = 1e-3 are e^442). They are made
@@ -50,17 +51,26 @@ and makes the two [C, C] score blocks, (I + A)^-1 as a finite product of
 decayed operands in VMEM, a chunk at a time, and writes what the scan's
 body reads, chunks leading; `hvd_kda_wy_bwd` turns those results'
 cotangents into dq, dk, dv, dG, dbeta and the squares' cotangents from the
-five inputs, the saved inverse and the saved k-k scores. Any other call
-(the CPU) makes the same numbers in jnp around `own_block_scores` (`own_plan`:
-its two kernels on [N, sub, D] operands or, off a TPU, jnp too);
-`interpret=True` runs whichever kernels in Pallas' interpreter.
+five inputs, the saved inverse and the saved k-k scores. The scan of such a
+call is `hvd_kda_scan`: its grid walks a head's chunks in order with the
+state resident in VMEM, `SCAN_HEADS` heads a grid step side by side (a
+chunk's products wait on each other, the heads' do not), reads the chunk
+stage's results as they lie and writes o as the mixer reads it ([B, L, H,
+Dv], which the TPU tiles by heads: a head is a sublane of a token's tile);
+under `jax.vjp` it also writes S at the start of every chunk, and
+`hvd_kda_scan_bwd` walks the chunks in reverse with the state's cotangent
+resident. Any other call (the CPU) makes the same numbers in jnp around
+`own_block_scores` (`own_plan`: its two kernels on [N, sub, D] operands or,
+off a TPU, jnp too) and ties the chunks by `lax.scan` (`_scan_jnp`, the
+kernels' oracle); `interpret=True` runs whichever kernels in Pallas'
+interpreter.
 
 Cumulative decays, the term-by-term blocks, the inverse, U and the carried
 state are f32, and so is every product of f32 operands (six bf16 passes of
 the matrix unit); the operands of the other products are rounded to the
 dtype of `v` (bf16 in training) and accumulate in f32, as `ssd_scan`'s.
 Everything before the scan lies under the scope `hvd_kda_chunk` (the
-kernels too), the scan under `hvd_kda_carry`.
+kernels too), the scan, kernels or jnp, under `hvd_kda_carry`.
 """
 
 import functools
@@ -379,6 +389,13 @@ def _column(row):
     return jnp.sum(jnp.where(rows == cols, row, 0.0), axis=1, keepdims=True)
 
 
+def _row(column):
+    """[C, 1] -> [1, C] through the diagonal."""
+    rows, cols = _square_iotas(column.shape[0])
+    return jnp.sum(jnp.where(rows == cols, column, 0.0), axis=0,
+                   keepdims=True)
+
+
 def _chunk_of(refs, c, chunk):
     """Chunk c of a grid step's token blocks, f32."""
     at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
@@ -594,13 +611,15 @@ _wy_kernels.defvjp(_wy_kernels_fwd, _wy_kernels_bwd)
 
 
 def chunk_plan(B, L, H, D, Dv, chunk, sub, interpret=None):
-    """How a call's chunk stage is made: the chunks a grid step of the two
-    kernels `hvd_kda_wy` / `hvd_kda_wy_bwd` takes, or None for the jnp form
-    (no TPU and no interpreter asked for; a head or a value no multiple of
-    128 wide; another chunk than 64 or sub-block than 16: what the kernels'
-    tiles and the inverse's product are written for; chunks no block
-    divides, a block the tiling does not take, or sub-blocks the own
-    blocks' kernels do not take, `own_plan`)."""
+    """How a call's chunk stage is made, and with it its scan (`hvd_kda_scan`
+    / `hvd_kda_scan_bwd` where this gives a block, `_scan_jnp` where None):
+    the chunks a grid step of the two kernels `hvd_kda_wy` /
+    `hvd_kda_wy_bwd` takes, or None for the jnp form (no TPU and no
+    interpreter asked for; a head or a value no multiple of 128 wide;
+    another chunk than 64 or sub-block than 16: what the kernels' tiles and
+    the inverse's product are written for; chunks no block divides, a block
+    the tiling does not take, or sub-blocks the own blocks' kernels do not
+    take, `own_plan`)."""
     if L % chunk or own_plan(L // sub, sub, D, interpret) is None:
         return None
     if Dv % 128 or chunk != 64 or sub != 16:
@@ -714,12 +733,215 @@ def _chunk_stage_operands(q, k, v, g, beta, chunk, sub, interpret):
 
 def _chunk_stage_kernels(q, k, v, g, beta, chunk, sub, block, interpret):
     """The chunk stage through its kernels (`chunk_plan`), the scan's
-    operands chunks leading: (W over Q e^G [nc, B, H, 2C, D], U f32, the
-    lower q-k scores, K e^(G_last - G), e^G_last [nc, B, H, D, 1] f32)."""
-    wq, u, qk, k_out, keep = _wy_kernels(
+    operands as its kernels read them: (W over Q e^G [nc, B, H, 2C, D], U
+    f32, the lower q-k scores, K e^(G_last - G), all chunks leading, and
+    e^G_last [B, H, nc, D] f32, a chunk a row)."""
+    return _wy_kernels(
         *_chunk_stage_operands(q, k, v, g, beta, chunk, sub, interpret),
         chunk, sub, block, interpret)
-    return wq, u, qk, k_out, jnp.moveaxis(keep, 2, 0)[..., None]
+
+
+# Heads a grid step of the scan's two kernels takes side by side. A chunk's
+# products wait on each other (S -> W S -> V' -> S'); the heads are
+# independent and one's products fill another's wait.
+SCAN_HEADS = 8
+
+
+def _scan_jnp(wq, u, qk, k_out, keep):
+    """The chunks tied in jnp, a `lax.scan` over them (a call `chunk_plan`
+    does not take; the kernels' oracle): W over Q e^G [nc, B, H, 2C, D], U
+    [nc, B, H, C, Dv] f32, the lower q-k scores [nc, B, H, C, C],
+    K e^(G_last - G) [nc, B, H, C, D], e^G_last [nc, B, H, D, 1] f32 -> (o
+    [B, L, H, Dv] f32, the final state [B, H, D, Dv] f32, the largest |S| a
+    chunk ends in)."""
+    nc, B, H, C, Dv = u.shape
+    f32, dt = jnp.float32, wq.dtype
+
+    def carry(state, step):
+        S, largest = state
+        wq_c, u_c, qk_c, k_c, keep_c = step
+        Sd = S.astype(dt)
+        both = jnp.einsum("bhtd,bhdv->bhtv", wq_c, Sd,
+                          preferred_element_type=f32)
+        new = u_c - both[..., :C, :]
+        newd = new.astype(dt)
+        o = both[..., C:, :] + jnp.einsum(
+            "bhts,bhsv->bhtv", qk_c, newd, preferred_element_type=f32)
+        S = keep_c * S + jnp.einsum("bhsd,bhsv->bhdv", k_c, newd,
+                                    preferred_element_type=f32)
+        return (S, jnp.maximum(largest, jnp.max(jnp.abs(
+            lax.stop_gradient(S))))), o
+
+    (final, largest), o = lax.scan(
+        carry, (jnp.zeros((B, H, k_out.shape[-1], Dv), f32),
+                jnp.zeros((), f32)), (wq, u, qk, k_out, keep))
+    # [nc, B, H, C, Dv] -> [B, L, H, Dv]
+    return o.transpose(1, 0, 3, 2, 4).reshape(B, nc * C, H, Dv), final, \
+        largest
+
+
+def _scan_kernel(wq_ref, u_ref, qk_ref, ko_ref, keep_ref, o_ref, s_ref,
+                 top_ref, *saved, chunk):
+    """`hvd_kda_scan`: chunk c = the grid's last index of `heads` heads, the
+    state S [heads, D, Dv] f32 resident in VMEM over a head's chunks (the
+    final state's block, zeroed at c = 0, written out when the heads
+    change). A head's W over Q e^G [2C, D], U [C, Dv] f32, lower q-k scores
+    [C, C], K e^(G_last - G) [C, D] and the row c of e^G_last [nc, D] ->
+    the chunk's rows of o [C, heads, Dv] f32 as the mixer reads them, the
+    largest |S| a chunk ends in (eight rows a head: reduced outside) and,
+    for the backward rule, S at the chunk's START. The heads' bodies are
+    independent straight-line code: one's products fill another's wait."""
+    C, narrow = chunk, wq_ref.dtype
+    heads, D, Dv = s_ref.shape
+    c = pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+        top_ref[...] = jnp.zeros_like(top_ref)
+
+    for h in range(heads):
+        S = s_ref[h]
+        if saved:
+            saved[0][h] = S
+        both = _narrow(wq_ref[h], S.astype(narrow))              # [2C, Dv]
+        newd = (u_ref[h] - both[:C]).astype(narrow)
+        o_ref[:, h, :] = both[C:] + _narrow(qk_ref[h], newd)
+        S = _column(keep_ref[h, pl.ds(c, 1), :]) * S \
+            + _narrow(ko_ref[h], newd, ((0,), (0,)))
+        s_ref[h] = S
+        top_ref[h] = jnp.maximum(top_ref[h], jnp.max(
+            jnp.abs(S).reshape(D // 8, 8, Dv), axis=0))
+
+
+def _scan_bwd_kernel(wq_ref, u_ref, qk_ref, ko_ref, keep_ref, s_ref, do_ref,
+                     dfinal_ref, dwq_ref, du_ref, dqk_ref, dko_ref,
+                     dkeep_ref, ds_ref, *, chunk):
+    """`hvd_kda_scan_bwd`: the chunks in reverse, the state's cotangent dS
+    [heads, D, Dv] f32 in VMEM scratch (the final state's cotangent at the
+    last chunk). From a chunk's rows of do, the S it started from (saved)
+    and the five operands, V' again by one product:
+
+        dV'   = qk^T do + K_out dS        dqk = do V'^T    dK_out = V' dS^T
+        dkeep = sum_v dS (.) S            dU  = dV'
+        d(W over Q e^G) = [-dV' ; do] S^T
+        dS    = keep (.) dS + (W over Q e^G)^T [-dV' ; do]
+
+    Operands of a product rounded to the model's dtype, f32 accumulation."""
+    C, narrow = chunk, wq_ref.dtype
+    heads, D, Dv = ds_ref.shape
+    step = pl.program_id(2)
+    c = keep_ref.shape[1] - 1 - step
+    across, down = ((1,), (1,)), ((0,), (0,))
+
+    @pl.when(step == 0)
+    def _():
+        ds_ref[...] = dfinal_ref[...]
+
+    for h in range(heads):
+        S, dS = s_ref[h], ds_ref[h]
+        Sd, dSd = S.astype(narrow), dS.astype(narrow)
+        wq, qk, ko = wq_ref[h], qk_ref[h], ko_ref[h]
+        newd = (u_ref[h] - _narrow(wq[:C], Sd)).astype(narrow)
+        do = do_ref[:, h, :]
+        dod = do.astype(narrow)
+        dnew = _narrow(qk, dod, down) + _narrow(ko, dSd)         # [C, Dv]
+        du_ref[h] = dnew
+        dqk_ref[h] = _narrow(dod, newd, across).astype(dqk_ref.dtype)
+        dko_ref[h] = _narrow(newd, dSd, across).astype(dko_ref.dtype)
+        at = (h, pl.ds(c, 1), slice(None))
+        dkeep_ref[at] = _row(jnp.sum(dS * S, axis=1, keepdims=True))
+        dboth = jnp.concatenate([-dnew, do], axis=0).astype(narrow)
+        dwq_ref[h] = _narrow(dboth, Sd, across).astype(dwq_ref.dtype)
+        ds_ref[h] = _column(keep_ref[at]) * dS + _narrow(wq, dboth, down)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "save", "interpret"))
+def _pallas_scan(wq, u, qk, k_out, keep, cot, saved, heads, save, interpret):
+    """The scan's kernels on the chunk stage's results as `hvd_kda_wy` writes
+    them (`_chunk_stage_kernels`), `heads` heads a grid step. With `cot`
+    None the forward one -> (o [B, L, H, Dv] f32, the final state [B, H, D,
+    Dv] f32, the largest |S| a chunk ends in [B, H, 8, Dv]) and, where
+    `save`, S at each chunk's start [nc, B, H, D, Dv] f32; with `cot` = (do
+    [B, L, H, Dv] f32, the final state's cotangent) and `saved` the backward
+    one -> the five operands' cotangents. o and do are the mixer's 4-D
+    arrays as the TPU lays them, tiled by (heads, Dv): a block is [C, heads,
+    Dv] with a head a sublane of each token's tile (`heads` a multiple of 8,
+    or all), written and read by sublane. As [B, L, H Dv], a head a block of
+    columns, the kernels' time is the same and XLA re-lays the array around
+    the mixer's gated head norm: 675.0 ms a step for 651.5 in the
+    Kimi-Linear cell (my chip runs, PR 61)."""
+    nc, B, H, C, Dv = u.shape
+    D = k_out.shape[-1]
+    f32 = jnp.float32
+    leading = lambda t, d: pl.BlockSpec(  # noqa: E731
+        (None, None, heads, t, d), lambda b, j, c: (c, b, j, 0, 0))
+    a_head = lambda t, d: pl.BlockSpec(  # noqa: E731
+        (None, heads, t, d), lambda b, j, c: (b, j, 0, 0))
+    rows = pl.BlockSpec((None, C, heads, Dv), lambda b, j, c: (b, c, j, 0))
+    operands = [leading(2 * C, D), leading(C, Dv), leading(C, C),
+                leading(C, D), a_head(nc, D)]
+    # A head's blocks of the backward kernel are 0.3 MB in two pipeline
+    # buffers each, e^G_last and its cotangent whole a head 0.25 MB more.
+    how = dict(grid=(B, H // heads, nc), interpret=interpret,
+               compiler_params=pltpu.CompilerParams(
+                   dimension_semantics=("parallel", "parallel",
+                                        "arbitrary"),
+                   vmem_limit_bytes=min(16 + 3 * heads, 96) << 20))
+    states = jax.ShapeDtypeStruct((nc, B, H, D, Dv), f32)
+    if cot is None:
+        return pl.pallas_call(
+            functools.partial(_scan_kernel, chunk=C),
+            name=profile.KDA_SCAN, in_specs=operands,
+            out_specs=[rows, a_head(D, Dv), a_head(8, Dv)]
+            + [leading(D, Dv)] * save,
+            out_shape=[jax.ShapeDtypeStruct((B, nc * C, H, Dv), f32),
+                       jax.ShapeDtypeStruct((B, H, D, Dv), f32),
+                       jax.ShapeDtypeStruct((B, H, 8, Dv), f32)]
+            + [states] * save, **how)(wq, u, qk, k_out, keep)
+    # the chunks in reverse: grid step c is chunk nc - 1 - c
+    back = lambda spec: pl.BlockSpec(  # noqa: E731
+        spec.block_shape, lambda b, j, c: spec.index_map(b, j, nc - 1 - c))
+    operands = [back(spec) for spec in operands]
+    return pl.pallas_call(
+        functools.partial(_scan_bwd_kernel, chunk=C),
+        name=profile.KDA_SCAN_BWD,
+        in_specs=operands + [back(leading(D, Dv)), back(rows),
+                             a_head(D, Dv)],
+        out_specs=operands,
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype)
+                   for t in (wq, u, qk, k_out, keep)],
+        scratch_shapes=[pltpu.VMEM((heads, D, Dv), f32)],
+        **how)(wq, u, qk, k_out, keep, saved, *cot)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _scan_kernels(wq, u, qk, k_out, keep, heads, interpret):
+    return tuple(_pallas_scan(wq, u, qk, k_out, keep, None, None, heads,
+                              False, interpret))
+
+
+def _scan_kernels_fwd(wq, u, qk, k_out, keep, heads, interpret):
+    *out, saved = _pallas_scan(wq, u, qk, k_out, keep, None, None, heads,
+                               True, interpret)
+    return tuple(out), ((wq, u, qk, k_out, keep), saved)
+
+
+def _scan_kernels_bwd(heads, interpret, res, cot):
+    operands, saved = res
+    # the largest |S| is a counter: its cotangent is no part of the rule
+    return tuple(_pallas_scan(*operands, tuple(cot[:2]), saved, heads, False,
+                              interpret))
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
+
+
+def scan_heads(H):
+    """The heads a grid step of `hvd_kda_scan` / `hvd_kda_scan_bwd` takes:
+    `SCAN_HEADS` (a tile's eight sublanes of o [B, L, H, Dv]) where that
+    divides the heads, else all of them."""
+    return SCAN_HEADS if H % SCAN_HEADS == 0 else H
 
 
 def kda_chunked(q, k, v, g, beta, chunk=64, sub=16, interpret=None):
@@ -735,7 +957,6 @@ def kda_chunked(q, k, v, g, beta, chunk=64, sub=16, interpret=None):
         raise ValueError("kda_chunked: length %d is no multiple of the chunk "
                          "%d, or the chunk of its sub-block %d"
                          % (L, chunk, sub))
-    f32, dt = jnp.float32, v.dtype
     block = chunk_plan(B, L, H, D, Dv, chunk, sub, interpret)
     with jax.named_scope(profile.KDA_CHUNK):
         if block is None:
@@ -743,27 +964,8 @@ def kda_chunked(q, k, v, g, beta, chunk=64, sub=16, interpret=None):
         else:
             steps = _chunk_stage_kernels(q, k, v, g, beta, chunk, sub, block,
                                          bool(interpret))
-
-    def carry(state, step):
-        S, largest = state
-        wq_c, u_c, qk_c, k_c, keep_c = step
-        Sd = S.astype(dt)
-        both = jnp.einsum("bhtd,bhdv->bhtv", wq_c, Sd,
-                          preferred_element_type=f32)
-        new = u_c - both[..., :chunk, :]
-        newd = new.astype(dt)
-        o = both[..., chunk:, :] + jnp.einsum(
-            "bhts,bhsv->bhtv", qk_c, newd, preferred_element_type=f32)
-        S = keep_c * S + jnp.einsum("bhsd,bhsv->bhdv", k_c, newd,
-                                    preferred_element_type=f32)
-        return (S, jnp.maximum(largest, jnp.max(jnp.abs(
-            lax.stop_gradient(S))))), o
-
     with jax.named_scope(profile.KDA_CARRY):
-        (final, largest), o = lax.scan(
-            carry, (jnp.zeros((B, H, D, Dv), f32), jnp.zeros((), f32)),
-            steps if block is not None else tuple(
-                jnp.moveaxis(t, 2, 0) for t in steps))
-        # [nc, B, H, C, Dv] -> [B, L, H, Dv]
-        o = o.transpose(1, 0, 3, 2, 4).reshape(B, L, H, Dv)
-    return o, final, largest
+        if block is None:
+            return _scan_jnp(*(jnp.moveaxis(t, 2, 0) for t in steps))
+        o, final, top = _scan_kernels(*steps, scan_heads(H), bool(interpret))
+        return o, final, lax.stop_gradient(jnp.max(top))

@@ -113,7 +113,8 @@ SSM_SCOPES = (SSM, SSM_CONV, SSD, SSM_PROJ, SSM_GATE)
 # `ops/kda.py` `KDA_CHUNK` around what is made for all chunks at once (the
 # cumulative decays, the decayed scores, the solve, W and U: the kernels
 # `KDA_WY` / `KDA_WY_BWD` below, or jnp around `KDA_SCORES` /
-# `KDA_SCORES_BWD`) and `KDA_CARRY` around the scan over the chunks. Not in
+# `KDA_SCORES_BWD`) and `KDA_CARRY` around the scan over the chunks (the
+# kernels `KDA_SCAN` / `KDA_SCAN_BWD` below, or a `lax.scan`). Not in
 # MODEL_SCOPES.
 KDA = "hvd_kda"
 KDA_PROJ = "hvd_kda_proj"
@@ -222,10 +223,21 @@ KDA_SCORES_BWD = "hvd_kda_scores_bwd"  # (dq, dk, dG) from their cotangents
 KDA_WY = "hvd_kda_wy"          # (W | Q e^G, U, qk, K e^(G_last - G), e^G_last)
 KDA_WY_BWD = "hvd_kda_wy_bwd"  # (dq, dk, dv, dG, dbeta, the squares')
 KDA_KERNELS = (KDA_SCORES, KDA_SCORES_BWD, KDA_WY, KDA_WY_BWD)
+# The scan over the chunks as kernels, under `KDA_CARRY` and so NOT in
+# `KDA_KERNELS` (`kda_kernel_ms` keeps meaning the chunk stage): wherever
+# `chunk_plan` takes the call, one call a direction. `KDA_SCAN` walks a
+# head's chunks with the state S [D, Dv] f32 resident in VMEM, several heads
+# a grid step side by side (V' = U - W S, the output as the mixer lays it,
+# the state's update; as the rule's forward it also saves S at each chunk's
+# start); `KDA_SCAN_BWD` walks them in reverse with the state's cotangent
+# resident and writes the five operands' cotangents.
+KDA_SCAN = "hvd_kda_scan"          # (o, the final state, the largest |S|)
+KDA_SCAN_BWD = "hvd_kda_scan_bwd"  # (dW|Qe^G, dU, dqk, dK_out, dkeep)
+KDA_SCAN_KERNELS = (KDA_SCAN, KDA_SCAN_BWD)
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
            RING_ATTN_DQ, RING_ATTN_DKV) + MOE_GMM_KERNELS \
     + (HC_STAT, HC_STAT_DPHI) \
-    + MOE_ROWS_KERNELS + MOE_ACT_KERNELS + KDA_KERNELS
+    + MOE_ROWS_KERNELS + MOE_ACT_KERNELS + KDA_KERNELS + KDA_SCAN_KERNELS
 
 # Host spans a traced window shows: the program's only per-call Python
 # (`span`), and `step.place`, which is a `phase` (below) and so shows there

@@ -541,10 +541,15 @@ def test_chunked_scan_compiles_for_v5e(one_chip):
 # 256] f32 products at full precision, the transposed products of the
 # backward, squares placed and taken by slices along the lanes), the own
 # blocks' `hvd_kda_scores` and `hvd_kda_scores_bwd` once each in the same
-# layout (a [1024, 128] slab of the same [1, 8192, 4096] operand), one scan
-# over the 128 chunks, no [L, L], libtpu's solve gone, and no copy of an
-# activation between the operands and the kernels or between the kernels and
-# the scan.
+# layout (a [1024, 128] slab of the same [1, 8192, 4096] operand), the
+# chunks tied by `hvd_kda_scan` and `hvd_kda_scan_bwd` once each (PR 61: a
+# grid step a chunk of 8 heads, the state [8, 128, 128] f32 resident in VMEM
+# over a head's 128 chunks, K e^(G_last - G) and W over Q e^G entering
+# products by their first axis, the output written and its cotangent read as
+# the mixer has them, [1, 8192, 32, 128] tiled by heads: a head a sublane of
+# a token's tile), no `while`, no [L, L], libtpu's solve gone, and no copy of
+# an activation between the operands and the kernels or between the chunk
+# stage's kernels and the scan's.
 def test_chunked_kda_compiles_for_v5e(one_chip, monkeypatch):
     import re
 
@@ -566,10 +571,15 @@ def test_chunked_kda_compiles_for_v5e(one_chip, monkeypatch):
     text = _compile(one_chip, fwd_bwd, *[((1, L, H * D), bf16)] * 3,
                     ((1, L, H * D), f32), ((1, L, H), f32),
                     ((1, L, H * D), f32))
-    for name in profile.KDA_KERNELS:
-        assert len(re.findall(r"\b%s/pallas_call" % name, text)) == 1
-    assert _kernels(text) == 4
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    for name in profile.KDA_KERNELS + profile.KDA_SCAN_KERNELS:
+        assert len([line for line in calls if re.search(
+            r"\b%s/pallas_call" % name, line)]) == 1
+    assert _kernels(text) == 6
     assert profile.KDA_CHUNK in text and profile.KDA_CARRY in text
+    # the chunks are a kernel's grid: no loop of XLA's under the scan's scope
+    assert not [line for line in text.splitlines()
+                if profile.KDA_CARRY in line and " while(" in line]
     # never an [L, L] array a head; libtpu's 64-step solve is gone
     assert "8192,8192" not in text
     assert "riangular" not in text and "1,32,128,1,64,64" not in text
@@ -587,6 +597,7 @@ def test_chunked_kda_compiles_for_v5e(one_chip, monkeypatch):
         return found
 
     assert passes(profile.KDA_CHUNK) == []
+    assert passes(profile.KDA_CARRY) == []
 
 
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------

@@ -6,6 +6,7 @@ position in the fourth, sigmoid experts beside a shared one) against
 and what is refused by name.
 """
 
+import functools
 import os
 import re
 import sys
@@ -224,9 +225,10 @@ def _stage_case(name):
 
 
 def _stage_jnp(*a):
-    """The jnp form's five results laid as the scan reads them."""
-    return tuple(jnp.moveaxis(t, 2, 0)
-                 for t in kda._chunk_stage_jnp(*a, 64, 16, None))
+    """The jnp form's five results laid as the kernels write them: chunks
+    leading, e^G_last [B, H, nc, D] a chunk a row."""
+    *four, keep = kda._chunk_stage_jnp(*a, 64, 16, None)
+    return tuple(jnp.moveaxis(t, 2, 0) for t in four) + (keep[..., 0],)
 
 
 def _stage_kernels(q, k, v, g, beta):
@@ -364,34 +366,174 @@ def test_chunk_plan_says_which_calls_take_the_kernels(shape, interpret,
     assert block == min(kda.BLOCK_CHUNKS, chunks) and chunks % block == 0
 
 
-def test_chunked_kda_through_the_kernels_agrees_with_the_recurrence(
-        monkeypatch):
-    monkeypatch.setattr(kda, "BLOCK_SUBS", 8)
+# --- the scan over the chunks: `hvd_kda_scan` / `hvd_kda_scan_bwd` ---------
+
+SCAN = ("output", "state", "largest")
+SCAN_OPERANDS = ("w_and_qe", "u", "qk", "k_out", "keep")
+
+
+def _scan_oracle(wq, u, qk, k_out, keep):
+    """The jnp scan on the operands as the kernels read them."""
+    return kda._scan_jnp(wq, u, qk, k_out,
+                         jnp.moveaxis(keep, 2, 0)[..., None])
+
+
+def _scan_through_kernels(heads):
+    def scan(*operands):
+        o, final, top = kda._scan_kernels(*operands, heads, True)
+        return o, final, jnp.max(top)
+    return scan
+
+
+def _scan_scalar(scan, shapes):
+    cot_o = jax.random.normal(jax.random.PRNGKey(7), shapes[0])
+    cot_s = jax.random.normal(jax.random.PRNGKey(8), shapes[1])
+    return lambda *s: (lambda r: jnp.sum(r[0] * cot_o)
+                       + jnp.sum(r[1] * cot_s))(scan(*s))
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_readings(case, heads, alpha=None):
+    """{"results": (kernels', jnp's), "gradients": (kernels', jnp's)} of the
+    scan alone on the jnp chunk stage's operands: four heads of four chunks
+    (`case` "f32": decays down to 1e-3 a token, or `alpha` on every channel;
+    "bf16": rounded W, scores and keys, the mixer's spread of decays)."""
+    q, k, v, g, beta = _recurrence_case(L=256, H=4, D=128, Dv=128, seed=21)
+    if alpha is not None:
+        g = jnp.full_like(g, np.log(alpha))
+    if case == "bf16":
+        g = -jnp.exp(jax.random.uniform(jax.random.PRNGKey(22), g.shape,
+                                        minval=-7.0, maxval=0.5))
+        q, k, v = (t.astype(jnp.bfloat16)
+                   for t in (q * 128 ** -0.5, k, v))
+    operands = _stage_jnp(q, k, v, g, beta)
+    kernels, both = _scan_through_kernels(heads), {}
+    both["results"] = kernels(*operands), _scan_oracle(*operands)
+    shapes = tuple(t.shape for t in both["results"][1][:2])
+    both["gradients"] = tuple(
+        jax.grad(_scan_scalar(f, shapes), argnums=(0, 1, 2, 3, 4))(*operands)
+        for f in (kernels, _scan_oracle))
+    return both
+
+
+SCAN_READINGS = SCAN + tuple("d_" + n for n in SCAN_OPERANDS)
+
+
+def _scan_reading(read, what):
+    """(the kernels', jnp's) reading `what` of `_scan_readings`' result."""
+    if what in SCAN:
+        return tuple(r[SCAN.index(what)] for r in read["results"])
+    return tuple(r[SCAN_OPERANDS.index(what[2:])] for r in read["gradients"])
+
+
+@pytest.mark.parametrize("heads", [1, 2, 4])
+@pytest.mark.parametrize("what", SCAN_READINGS)
+def test_the_scan_kernels_agree_with_the_jnp_scan(what, heads):
+    """`hvd_kda_scan` / `hvd_kda_scan_bwd` in Pallas' interpreter, 1, 2 and
+    4 heads a grid step, against `lax.scan` over the same operands: the
+    output as the mixer lays it, the final state, the largest |S| a chunk
+    ends in; the five operands' cotangents against `jax.grad` of the jnp
+    scan (f32 operands: both round nowhere)."""
+    got, want = _scan_reading(_scan_readings("f32", heads), what)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.all(np.isfinite(np.asarray(got)))
+    _close(got, want, 1e-6 if what in SCAN else 5e-5)
+
+
+@pytest.mark.parametrize("what", SCAN_READINGS)
+def test_the_scan_kernels_round_where_the_jnp_scan_rounds(what):
+    """bf16 operands: the state and V' rounded to bf16 where `carry` rounds
+    them, f32 accumulation; the forward to f32 rounding, the cotangents to a
+    bf16 step (autodiff rounds a bf16 operand's cotangent a chunk at a
+    time, the kernel keeps f32 between its products)."""
+    got, want = _scan_reading(_scan_readings("bf16", 2), what)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    _close(got.astype(jnp.float32), want.astype(jnp.float32),
+           1e-6 if what in SCAN else 2e-2)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("alpha", [1e-3, 1.0])
+def test_the_scan_kernels_at_the_decays_ends(alpha, direction):
+    """alpha = 1e-3 on every channel (a chunk keeps e^-442 of the state: 0
+    in f32) and alpha exactly 1 (the state kept whole): no inf, no nan, and
+    the jnp scan's numbers."""
+    read = _scan_readings("f32", 2, alpha)[
+        "results" if direction == "forward" else "gradients"]
+    for got, want in zip(*read):
+        assert np.all(np.isfinite(np.asarray(got)))
+        _close(got, want, 1e-6 if direction == "forward" else 5e-5)
+
+
+def test_scan_heads_are_a_tile_of_the_output_or_all():
+    """A block of o [B, L, H, Dv] is [C, heads, Dv]: the tile's eight
+    sublanes, or every head."""
+    assert kda.SCAN_HEADS == 8
+    assert [kda.scan_heads(H) for H in (32, 16, 8, 12, 4, 2, 1)] == [
+        8, 8, 8, 12, 4, 2, 1]
+
+
+ALL_KERNELS = profile.KDA_KERNELS + profile.KDA_SCAN_KERNELS
+
+
+@functools.lru_cache(maxsize=None)
+def _whole_call_readings():
+    """`kda_chunked` through all six kernels in the interpreter and the
+    token-by-token recurrence: (results, gradients of a scalar of the output
+    and the final state) each side, and the kernels of the program."""
+    was = kda.BLOCK_SUBS
+    kda.BLOCK_SUBS = 8
     jax.clear_caches()
-    args = _recurrence_case(L=128, H=2, D=128, Dv=128, seed=3)
-    cot = jax.random.normal(jax.random.PRNGKey(5), args[2].shape)
+    try:
+        args = _recurrence_case(L=256, H=2, D=128, Dv=128, seed=3)
 
-    def chunked(*a):
-        return kda.kda_chunked(*a, chunk=64, interpret=True)[0]
+        def chunked(*a):
+            return kda.kda_chunked(*a, chunk=64, interpret=True)
 
-    def sequential(*a):
-        return reference.kda_recurrence(*(t[0] for t in a))[0][None]
+        def sequential(*a):
+            o, s, top = reference.kda_recurrence(*(t[0] for t in a))
+            return o[None], s[None], top
 
-    _close(chunked(*args), sequential(*args), 2e-5)
-    got = jax.grad(lambda *a: jnp.sum(chunked(*a) * cot),
-                   argnums=(0, 1, 2, 3, 4))(*args)
-    want = jax.grad(lambda *a: jnp.sum(sequential(*a) * cot),
-                    argnums=(0, 1, 2, 3, 4))(*args)
-    for a, b in zip(got, want):
-        _close(a, b, 5e-5)
+        want = sequential(*args)
+        shapes = tuple(t.shape for t in want[:2])
+        text = str(jax.make_jaxpr(jax.grad(_scan_scalar(chunked, shapes)))(
+            *args))
+        return tuple(
+            (f(*args), jax.grad(_scan_scalar(f, shapes),
+                                argnums=(0, 1, 2, 3, 4))(*args))
+            for f in (chunked, sequential)) + (
+                {n for n in ALL_KERNELS
+                 if re.search(r"name=%s\b" % n, text)},)
+    finally:
+        kda.BLOCK_SUBS = was
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("what", ["output", "state", "state_max"]
+                         + ["d" + n for n in NAMES])
+def test_chunked_kda_through_the_kernels_agrees_with_the_recurrence(what):
+    """The whole call through its six kernels (the own blocks' two, the
+    chunk stage's two, the scan's two) against the token-by-token
+    recurrence: outputs, the final state, the counter, the five inputs'
+    gradients."""
+    got, want, kernels = _whole_call_readings()
+    assert kernels == set(ALL_KERNELS)
+    if what.startswith("d"):
+        i = NAMES.index(what[1:])
+        _close(got[1][i], want[1][i], 5e-5)
+    else:
+        i = ("output", "state", "state_max").index(what)
+        _close(got[0][i], want[0][i], 2e-5)
 
 
 @pytest.mark.parametrize("kernels", [False, True])
 def test_decayed_scores_never_form_an_l_by_l_array_or_a_token_loop(kernels):
-    """The program of the chunked form, in jnp and through the chunk stage's
-    kernels (forward and backward): one scan over the L / C chunks (and,
-    inside a kernel, one loop over a grid step's chunks), no array with two
-    axes of the sequence's length."""
+    """The program of the chunked form, forward and backward. In jnp: one
+    scan over the L / C chunks and its transpose. Through the kernels: no
+    scan of that length (the chunks are the grid of `hvd_kda_scan` /
+    `hvd_kda_scan_bwd`), only the chunk stage's kernels' loops over a grid
+    step's chunks and sub-blocks. Never an array with two axes of the
+    sequence's length."""
     L, chunk = (2048, 64) if kernels else (256, 32)
     args = _recurrence_case(L=L, D=128 if kernels else 16,
                             Dv=128 if kernels else 16)
@@ -404,13 +546,15 @@ def test_decayed_scores_never_form_an_l_by_l_array_or_a_token_loop(kernels):
     text = str(jaxpr)
     block = kda.chunk_plan(1, L, 2, 128, 128, chunk, 16, kernels or None)
     assert (block == kda.BLOCK_CHUNKS) is kernels
-    # the chunks' scan and its transpose; a kernel's loop over its chunks
+    # the chunks' scan and its transpose, or a kernel's loop over its chunks
     lengths = [int(n) for n in re.findall(r"length=(\d+)", text)]
     assert len(lengths) == text.count("scan[")
-    assert lengths.count(L // chunk) == 2
-    assert set(lengths) <= {L // chunk} | (
-        {block // kda.SIDE, kda.BLOCK_SUBS // kda.GROUP} if kernels
-        else set())
+    if kernels:
+        assert set(lengths) == {block // kda.SIDE,
+                                kda.BLOCK_SUBS // kda.GROUP}
+        assert all(re.search(r"name=%s\b" % n, text) for n in ALL_KERNELS)
+    else:
+        assert lengths == [L // chunk] * 2
     assert "while[" not in text
     assert ("pallas_call" in text) is kernels
 
