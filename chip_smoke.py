@@ -79,6 +79,11 @@ SIZES = dict(
     # benchmark's `mellum12b_1chip`: the same call under the causal band
     # the kernels take by rule, a query on itself and the 1023 keys before.
     attn_band=(1, 32, 4, 8192, 128, 1024),
+    # (B, H, G, L, D, window or None) of the two calls of the benchmark's
+    # `laguna33b_1chip`: a full layer's 48 heads on 8 (group 6) under the
+    # causal triangle, a window layer's 64 on 8 (group 8) under a band of
+    # 512 keys, one k block wide: every tile a window layer visits is cut.
+    attn_by_kind=[(1, 48, 8, 8192, 128, None), (1, 64, 8, 8192, 128, 512)],
     # (n, T, C, K) of a hyper-connection at the benchmark's `xing29b_1chip`:
     # four streams of 4096 tokens, 3584 wide, onto phi's 24 columns.
     hc=(4, 4096, 3584, 24),
@@ -368,7 +373,8 @@ def compile_with_text(jitted, *call_args):
     return compiled, compiled.as_text(), time.perf_counter() - t0
 
 
-def attention_case(B, H, G, L, D, dtype, seed, mask=None):
+def attention_case(B, H, G, L, D, dtype, seed, mask=None,
+                   dense_causal=False):
     """flash_attention forward and backward alone at one shape, and
     _blockwise_reference doing the same: (name, kernel, reference,
     (q, k, v, cotangent)), both jitted and returning (out, dq, dk, dv).
@@ -377,7 +383,8 @@ def attention_case(B, H, G, L, D, dtype, seed, mask=None):
     benchmark's plain reference of the rule's model (`references/sdar.py`:
     the mask from the block-diffusion rule's three clauses;
     `references/mellum.py`: the band's two comparisons), a block of query
-    rows at a time."""
+    rows at a time. `dense_causal`: the causal call against that dense
+    masked softmax too (a band as long as the sequence)."""
     import jax
     import jax.numpy as jnp
 
@@ -395,7 +402,10 @@ def attention_case(B, H, G, L, D, dtype, seed, mask=None):
             return flash_attention(q, k, v, mask=mask)
         return flash_attention(q, k, v, causal=True)
 
-    def reference(q, k, v):
+    def reference(q, k, v, mask=mask):
+        if mask is None and dense_causal:
+            from horovod_tpu.ops import BandMask
+            mask = BandMask(L)
         if mask is not None:
             # Not the program's `rule.visible`: the dense mask written
             # from the rule's three clauses, as the benchmark's reference
@@ -947,6 +957,18 @@ def phase_kernels(args):
         TOL["attn_bf16"], flash_kernels(*shape, mask=rule))
     backward_forms_agree(B, H, G, L, D, jnp.bfloat16, args.seed + 1, rule,
                          TOL["attn_bf16"])
+    for i, (B, H, G, L, D, window) in enumerate(SIZES["attn_by_kind"]):
+        rule = None if window is None else BandMask(window)
+        shape = (B, H, G, L, D, jnp.bfloat16)
+        print("  %d query heads on %d at L=%d, %s:" % (
+            H, G, L, "causal" if rule is None else "a band of %d keys"
+            % window), flush=True)
+        # the triangle's tiles are counted by the band as long as it
+        print_flash_plan(*shape, mask=rule or BandMask(L))
+        attention_vs_reference(
+            attention_case(*shape, args.seed + 7 + i, mask=rule,
+                           dense_causal=True),
+            TOL["attn_bf16"], flash_kernels(*shape, mask=rule))
     print("  between the projections and the kernels (a grouped call takes "
           "every operand in the public layout, [B, L, heads x D]):",
           flush=True)

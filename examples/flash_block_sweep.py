@@ -33,6 +33,13 @@ a query sees itself and the W - 1 keys before it), a rule likewise. The
 window layers' call of `mellum12b_1chip`:
     --B 1 --H 32 --G 4 --L 8192 --window 1024 --path q-held \
     --bqp 64,128,256 --bk 128,256,512,1024
+The two calls of `laguna33b_1chip` (a full layer's 48 query heads on 8,
+group 6, causal; a window layer's 64 on 8 under a band of ONE k block, every
+tile visited cut at an edge; PERF.md s6, PR 62, has the tables):
+    --B 1 --H 48 --G 8 --L 8192 --path q-held --bqp 64,128,256 \
+    --bk 256,512,1024
+    --B 1 --H 64 --G 8 --L 8192 --window 512 --path q-held --bqp 64,128 \
+    --bk 256,512 --cut-k 128,256,512
 `--cut-k`: under a rule, the widths of the sub-tile that the one-kernel
 backward held by the q block takes alone where a cut k block has one in sight
 (`flash_attention._CUT_K`; at the k block's own width it walks k blocks

@@ -26,7 +26,8 @@ from .resnet import (ResNet, ResNet18, ResNet34, ResNet50, ResNet50GN,  # noqa: 
                      ResNet152)
 from .mnist import MnistCNN  # noqa: F401
 from .word2vec import SkipGram  # noqa: F401
-from .transformer import (Layer, Transformer, TransformerConfig,  # noqa: F401
+from .transformer import (AttentionShape, Layer, Transformer,  # noqa: F401
+                          TransformerConfig,
                           Yarn, hc_stats, kda_stats, ssd_stats)
 from .block_diffusion import (block_diffusion_batch,  # noqa: F401
                               block_diffusion_noisy_half,

@@ -42,6 +42,23 @@ class Yarn(NamedTuple):
     mscale_all_dim: float = 0.0
 
 
+class AttentionShape(NamedTuple):
+    """What an attention KIND's layers are beyond their mask
+    (`TransformerConfig.attention_shapes`, a `Layer`'s `shape`; Laguna's
+    `num_attention_heads_per_layer` and `rope_parameters` by `layer_types`):
+    each field None where the kind keeps the stack's own (`num_heads`;
+    `rope_base`; the whole head rotated; `rope_yarn` on the full layers)."""
+    # Query heads, on the stack's `num_kv_heads` (which must divide them).
+    num_heads: Optional[int] = None
+    rope_base: Optional[float] = None
+    # The FIRST `rotary_dim` channels of each head of q and k are rotated
+    # (rotate-half pairs inside that slice; `partial_rotary_factor` x
+    # head_dim, transformers' convention), the others pass as they are.
+    rotary_dim: Optional[int] = None
+    # YaRN's rescaling, its frequencies formed over the rotated slice.
+    rope_yarn: Optional[Yarn] = None
+
+
 class Layer(NamedTuple):
     """What ONE block of the stack is (`TransformerConfig.layers` makes
     them, `Block` runs them): its branches in order, each
@@ -67,6 +84,9 @@ class Layer(NamedTuple):
     # Whether the block keeps only its input for the backward pass and
     # runs its forward again there.
     remat: bool = False
+    # What the kind's attention is beyond mask and name (heads, rotary base
+    # and width, YaRN); None: the configuration's own fields.
+    shape: Optional[AttentionShape] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,6 +169,19 @@ class TransformerConfig:
     # "flash".
     attention_types: Optional[Tuple[str, ...]] = None
     attention_window: Optional[int] = None
+    # What a kind's layers are beyond their mask, `(kind, AttentionShape)`
+    # pairs for "full" and / or "window" (one record by kind, hashable):
+    # query heads on the same `num_kv_heads`, rotary base, how many of a
+    # head's channels are rotated, YaRN over that slice (Laguna: 48 heads,
+    # half the head on YaRN's frequencies at base 500000 on a full layer; 64
+    # heads, the whole head at base 10000 on a window layer). A kind without
+    # a pair, and a field left None, keep `num_heads`, `rope_base`, the whole
+    # head and `rope_yarn` on the full layers.
+    attention_shapes: Optional[Tuple[Tuple[str, AttentionShape], ...]] = None
+    # "head": every head's output times sigmoid(the branch's normed input
+    # x `gate` [embed_dim, heads]) before `out`, the sigmoid in f32 (Laguna's
+    # `gating` per head). None: no gate.
+    attention_gate: Optional[str] = None
     # A "kda" layer of `attention_types` is a Kimi Delta Attention mixer
     # (`KimiDeltaAttention`; Kimi Linear, arXiv:2510.26692) in attention's
     # place: `num_heads` heads of `kda_head_dim` channels (the two low ranks
@@ -326,6 +359,8 @@ class TransformerConfig:
             ("qk_norm='head'", self.qk_norm == "head"),
             ("attention_mask", self.attention_mask is not None),
             ("attention_types", self.attention_types is not None),
+            ("attention_shapes", self.attention_shapes is not None),
+            ("attention_gate", self.attention_gate is not None),
             ("layer_types", self.layer_types is not None),
             ("rotary=False", not self.rotary),
             ("moe_latent_dim", self.moe_latent_dim is not None),
@@ -364,6 +399,24 @@ class TransformerConfig:
                              "rotation (give attention_types)")
         if self.attention_types is not None:
             self._check_attention_types()
+        if self.attention_shapes is not None:
+            self._check_attention_shapes()
+        if self.attention_gate not in (None, "head"):
+            raise ValueError("attention_gate=%r: 'head' (one sigmoid a head) "
+                             "or None" % (self.attention_gate,))
+        if self.attention_gate is not None:
+            # `Attention` alone has the gate: latent attention and the KDA
+            # mixer (which has a gate of its own) know none.
+            for field, on in (
+                    ("kv_lora_rank", self.kv_lora_rank is not None),
+                    ("'kda' layers in attention_types",
+                     "kda" in (self.attention_types or ())),
+                    ("layer_types", self.layer_types is not None)):
+                if on:
+                    raise ValueError("attention_gate cannot be combined "
+                                     "with %s (the gate is plain "
+                                     "attention's, in the two-branch block)"
+                                     % field)
         if self.hc_mult < 1:
             raise ValueError("hc_mult=%d: the residual path has at least "
                              "one stream" % self.hc_mult)
@@ -419,6 +472,41 @@ class TransformerConfig:
                                  "under attention='dense' or 'flash')"
                                  % field)
 
+    def _check_attention_shapes(self):
+        """What a shape by kind must be, and cannot be placed beside."""
+        shapes = self.attention_shapes
+        kinds = [pair[0] for pair in shapes]
+        types = self.attention_types or ()
+        if (len(set(kinds)) != len(kinds)
+                or any(k not in ("full", "window") or k not in types
+                       for k in kinds)
+                or any(not isinstance(s, AttentionShape) for _, s in shapes)):
+            raise ValueError("attention_shapes=%r: (kind, AttentionShape) "
+                             "pairs, a kind once, each 'full' or 'window' "
+                             "and named in attention_types=%r"
+                             % (shapes, self.attention_types))
+        if self.kv_lora_rank is not None:
+            raise ValueError("attention_shapes cannot be combined with "
+                             "kv_lora_rank (latent attention has its own "
+                             "widths and its one rotary slice)")
+        head_dim = self.head_dim or self.embed_dim // self.num_heads
+        kv_heads = self.num_kv_heads or self.num_heads
+        for kind, shape in shapes:
+            if shape.num_heads is not None and (
+                    self.head_dim is None or shape.num_heads % kv_heads):
+                raise ValueError("attention_shapes gives %r layers %d heads: "
+                                 "give head_dim (embed_dim // num_heads "
+                                 "would differ by kind) and a multiple of "
+                                 "the %d kv heads"
+                                 % (kind, shape.num_heads, kv_heads))
+            if shape.rotary_dim is not None and not (
+                    0 < shape.rotary_dim <= head_dim
+                    and shape.rotary_dim % 2 == 0):
+                raise ValueError("attention_shapes gives %r layers "
+                                 "rotary_dim=%d: an even number of a head's "
+                                 "%d channels" % (kind, shape.rotary_dim,
+                                                  head_dim))
+
     def _check_layer_types(self):
         """What a layer pattern cannot be placed beside, by name."""
         kinds = ("ssm", "attn", "moe", "mlp")
@@ -458,16 +546,19 @@ class TransformerConfig:
         """What each block of the model is, a `Layer` a block: `num_layers`
         of them, `block_<i>`'s at i, and behind them the prediction
         module's `mtp_block` where `mtp_depth` is set. The one reader of
-        the fields that spell a layer (`layer_types`; `attention_types`;
-        `moe_every` and `first_k_dense`; `kv_lora_rank` as a choice of
-        attention; `sandwich_norm` as names; `block_remat`)."""
+        the fields that spell a layer (`layer_types`; `attention_types`
+        and `attention_shapes`; `moe_every` and `first_k_dense`;
+        `kv_lora_rank` as a choice of attention; `sandwich_norm` as names;
+        `block_remat`)."""
         attention = "latent" if self.kv_lora_rank is not None else "attn"
+        shapes = dict(self.attention_shapes or ())
 
         def two_branch(mixer, routed, kind=None, remat=False):
             norms = ("norm1", "norm2")
             return Layer((mixer, "moe" if routed else "mlp"), norms,
                          tuple(name + "_out" if self.sandwich_norm else None
-                               for name in norms), kind, remat)
+                               for name in norms), kind, remat,
+                         shapes.get(kind))
 
         table = []
         for i in range(self.num_layers):
@@ -785,29 +876,59 @@ class Attention(nn.Module):
     # The layer's kind under `attention_types` ("full" | "window"); None:
     # the stack's one kind (causal, or `attention_mask`).
     kind: Optional[str] = None
+    # What the kind's layers are beyond their mask (`Layer.shape`); a field
+    # it leaves None is the configuration's own.
+    shape: Optional[AttentionShape] = None
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
+        shape = self.shape or AttentionShape()
         mask = cfg.attention_mask
         if self.kind == "window":
             from horovod_tpu.ops import BandMask
             mask = BandMask(cfg.attention_window)
         head_dim = cfg.head_dim or cfg.embed_dim // cfg.num_heads
+        H = shape.num_heads or cfg.num_heads
         G = cfg.num_kv_heads or cfg.num_heads
-        if cfg.num_heads % G:
+        if H % G:
             raise ValueError(
-                "num_kv_heads=%d must divide num_heads=%d"
-                % (G, cfg.num_heads))
+                "num_kv_heads=%d must divide num_heads=%d" % (G, H))
+        base = cfg.rope_base if shape.rope_base is None else shape.rope_base
+        yarn = shape.rope_yarn or (cfg.rope_yarn if self.kind == "full"
+                                   else None)
+        rot = shape.rotary_dim or head_dim
+
+        def rotate(t):
+            """The first `rot` channels of each head of t by the layer's
+            frequencies; the others as they are."""
+            part = t if rot == head_dim else t[..., :rot]
+            if yarn is not None:
+                # YaRN over the rotated slice: cos and sin both times its
+                # factor, so a rotated q.k carries the factor's square.
+                m = (yarn_mscale(yarn.factor, yarn.mscale)
+                     / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+                part = _rotary_freq(part, positions,
+                                    yarn_inv_freq(rot, base, yarn), m)
+            else:
+                part = _rotary(part, positions, base)
+            return part if rot == head_dim else jnp.concatenate(
+                [part, t[..., rot:]], axis=-1)
+
         heads = lambda n, name: nn.DenseGeneral(  # noqa: E731
             (n, head_dim), dtype=cfg.dtype,
             param_dtype=jnp.float32, use_bias=False, name=name)
         # The work outside the kernels under the profiler's three names
         # (`profile.ATTN_PARTS`): no module and no parameter name.
         with jax.named_scope(profile.ATTN_PROJ):
-            q = heads(cfg.num_heads, "query")(x)
+            q = heads(H, "query")(x)
             k = heads(G, "key")(x)
             v = heads(G, "value")(x)
+        if cfg.attention_gate == "head":
+            with jax.named_scope(profile.ATTN_GATE):
+                gate = jax.nn.sigmoid(nn.Dense(
+                    H, dtype=cfg.dtype, param_dtype=jnp.float32,
+                    use_bias=False, name="gate")(x).astype(jnp.float32))
         with jax.named_scope(profile.ATTN_NORM):
             if cfg.qk_norm == "head":
                 # Over each head's own width: the norm acts on the last
@@ -820,19 +941,8 @@ class Attention(nn.Module):
                     return _rms_norm(cfg, name)(flat).reshape(t.shape)
                 q, k = whole(q, "q_norm"), whole(k, "k_norm")
         with jax.named_scope(profile.ATTN_ROPE):
-            if cfg.rotary and self.kind == "full" \
-                    and cfg.rope_yarn is not None:
-                # YaRN on the whole head: cos and sin both times its
-                # factor, so a rotated q.k carries the factor's square.
-                yarn = cfg.rope_yarn
-                inv_freq = yarn_inv_freq(head_dim, cfg.rope_base, yarn)
-                m = (yarn_mscale(yarn.factor, yarn.mscale)
-                     / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
-                q = _rotary_freq(q, positions, inv_freq, m)
-                k = _rotary_freq(k, positions, inv_freq, m)
-            elif cfg.rotary:
-                q = _rotary(q, positions, cfg.rope_base)
-                k = _rotary(k, positions, cfg.rope_base)
+            if cfg.rotary:
+                q, k = rotate(q), rotate(k)
         if cfg.attention == "ring":
             o = ring_attention(q, k, v, cfg.sp_axis, causal=True,
                                schedule=cfg.sp_schedule)
@@ -845,9 +955,9 @@ class Attention(nn.Module):
             else:
                 o = flash_attention(q, k, v, causal=True)
         else:
-            if G != cfg.num_heads:
-                k = jnp.repeat(k, cfg.num_heads // G, axis=2)
-                v = jnp.repeat(v, cfg.num_heads // G, axis=2)
+            if G != H:
+                k = jnp.repeat(k, H // G, axis=2)
+                v = jnp.repeat(v, H // G, axis=2)
             s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                            preferred_element_type=jnp.float32)
             s = s * (head_dim ** -0.5)
@@ -859,6 +969,9 @@ class Attention(nn.Module):
             s = jnp.where(seen[None, None], s, -jnp.inf)
             p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+        if cfg.attention_gate == "head":
+            with jax.named_scope(profile.ATTN_GATE):
+                o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
         with jax.named_scope(profile.ATTN_PROJ):
             out = nn.DenseGeneral(
                 cfg.embed_dim, axis=(-2, -1), dtype=cfg.dtype,
@@ -1139,7 +1252,8 @@ def _mixer(cfg, layer, mixer, positions):
     the profiler's scope for the feed-forward, dense or routed, no module
     and no parameter name."""
     if mixer in ("attn", "latent"):
-        module = Attention(cfg, kind=layer.kind, name="attn") \
+        module = Attention(cfg, kind=layer.kind, shape=layer.shape,
+                           name="attn") \
             if mixer == "attn" else LatentAttention(cfg, name="attn")
         return (lambda h: module(h, positions),
                 profile.ATTN_KINDS[layer.kind] if layer.kind else None, None)

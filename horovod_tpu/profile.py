@@ -168,6 +168,12 @@ ATTN_PROJ = "hvd_attn_proj"
 ATTN_NORM = "hvd_attn_norm"
 ATTN_ROPE = "hvd_attn_rope"
 ATTN_PARTS = (ATTN_PROJ, ATTN_NORM, ATTN_ROPE)
+# The per-head output gate of `Attention` (`attention_gate="head"`), inside
+# flax's `attn`: the gate's projection of the branch's normed input, its
+# sigmoid in f32 and the product with the heads' outputs before `out`. Kept
+# OUT of `ATTN_PARTS` and `ATTN_KINDS`, which readers of older cells walk: a
+# stack without a gate carries no such name.
+ATTN_GATE = "hvd_attn_gate"
 
 # Block-diffusion training (`models/block_diffusion.py`), inside FWD_BWD and
 # outside the model: the noise draw, the doubled ids, positions and row

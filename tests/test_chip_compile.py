@@ -219,6 +219,42 @@ def test_ruled_flash_compiles_for_v5e(one_chip, H, G, S, rule, held):
     assert "[%d,%d]" % (S, S) not in text
 
 
+# The two flash calls of the benchmark's `laguna33b_1chip`, forward and
+# backward: a full layer's 48 query heads on 8 (group 6: a tile of 6 x 256
+# rows, the shape `_grouped_blocks` had swept at group 3 only) under the
+# causal triangle, and a window layer's 64 on 8 (group 8) under a band of
+# 512 keys, one k block wide, so that every k block a query tile visits is
+# cut at an edge. At 8192 positions the k-held backward does not fit either:
+# the one kernel held by the q block, 29 MiB of VMEM at 1536 rows.
+@pytest.mark.parametrize("H,rule", [(48, None), (64, BandMask(512))])
+def test_the_laguna_cells_flash_calls_compile_for_v5e(one_chip, H, rule):
+    G, S, D = 8, 8192, 128
+
+    def fwd_bwd(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v: _flash(q, k, v, D ** -0.5, rule is None, False,
+                                   rule), q, k, v)
+        return (out,) + vjp(g)
+
+    bf16 = jnp.bfloat16
+    text = _compile(one_chip, fwd_bwd, ((1, H, S, D), bf16),
+                    ((1, G, S, D), bf16), ((1, G, S, D), bf16),
+                    ((1, H, S, D), bf16))
+    plans = {name: p for backward in (False, True)
+             for name, p in flash_plan(
+                 1, H, S, D, H // G, bf16, backward,
+                 **({} if rule is None else {"mask": rule})).items()}
+    assert {name: (p.path, p.held) for name, p in plans.items()} == {
+        profile.FLASH_FWD: ("resident", "q"),
+        profile.FLASH_BWD: ("resident", "q")}
+    assert plans[profile.FLASH_FWD].block_q == (1536 if H == 48 else 1024)
+    assert _kernels(text) == 2, text[:2000]
+    for name in (profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV,
+                 profile.FLASH_BWD):
+        assert _named(text, name) == (name in plans), name
+    assert "[%d,%d]" % (S, S) not in text
+
+
 # The ring LM of `chip_smoke.py --chips 4`: B2 x H6 per chip, L=8192 over
 # four chips, D=128; and the same shard with its query heads three to a kv
 # head: the ring's kernels take a group's rows as the plain kernels do
