@@ -374,10 +374,15 @@ def compile_with_text(jitted, *call_args):
 
 
 def attention_case(B, H, G, L, D, dtype, seed, mask=None,
-                   dense_causal=False):
+                   dense_causal=False, gated=False):
     """flash_attention forward and backward alone at one shape, and
     _blockwise_reference doing the same: (name, kernel, reference,
     (q, k, v, cotangent)), both jitted and returning (out, dq, dk, dv).
+    `gated`: the call with a head gate [B, L, H] (`flash_attention`'s
+    ``gate``: a sigmoid's values in f32, applied inside the kernels as a
+    change of the rows' normalisers) against the reference's output times
+    the gate in f32; the operands are then (q, k, v, gate, cotangent) and
+    dgate is the fifth result.
     `mask`: a rule in place of the causal triangle (L counts all its
     positions); the reference is then the dense masked softmax of the
     benchmark's plain reference of the rule's model (`references/sdar.py`:
@@ -396,13 +401,19 @@ def attention_case(B, H, G, L, D, dtype, seed, mask=None,
     k = jax.random.normal(kk, (B, L, G, D), jnp.float32).astype(dtype)
     v = jax.random.normal(kv, (B, L, G, D), jnp.float32).astype(dtype)
     w = jax.random.normal(kw, (B, L, H, D), jnp.float32)
+    gate = (jax.nn.sigmoid(jax.random.normal(
+        jax.random.fold_in(kw, 1), (B, L, H), jnp.float32)),) * gated
 
-    def kernel(q, k, v):
+    def kernel(q, k, v, *gate):
+        gate = {"gate": gate[0]} if gate else {}
         if mask is not None:
-            return flash_attention(q, k, v, mask=mask)
-        return flash_attention(q, k, v, causal=True)
+            return flash_attention(q, k, v, mask=mask, **gate)
+        return flash_attention(q, k, v, causal=True, **gate)
 
-    def reference(q, k, v, mask=mask):
+    def reference(q, k, v, *gate, mask=mask):
+        if gate:  # in f32, rounded once, as the kernels round
+            return (reference(q, k, v, mask=mask).astype(jnp.float32)
+                    * gate[0][..., None]).astype(q.dtype)
         if mask is None and dense_causal:
             from horovod_tpu.ops import BandMask
             mask = BandMask(L)
@@ -421,20 +432,21 @@ def attention_case(B, H, G, L, D, dtype, seed, mask=None,
         return t(_blockwise_reference(t(q), t(k), t(v), D ** -0.5, True))
 
     def both(fn):
-        def f(q, k, v, w):
-            out, vjp = jax.vjp(fn, q, k, v)
+        def f(*operands):
+            *operands, w = operands
+            out, vjp = jax.vjp(fn, *operands)
             return (out,) + vjp(w.astype(out.dtype))
         return jax.jit(f)
 
-    name = "B%d H%d G%d L%d D%d%s %s" % (
+    name = "B%d H%d G%d L%d D%d%s%s %s" % (
         B, H, G, L, D,
         "" if mask is None else " %s%r" % (type(mask).__name__,
                                             tuple(mask)),
-        jnp.dtype(dtype).name)
-    return name, both(kernel), both(reference), (q, k, v, w)
+        " gated" * gated, jnp.dtype(dtype).name)
+    return name, both(kernel), both(reference), (q, k, v) + gate + (w,)
 
 
-def flash_kernels(B, H, G, L, D, dtype, mask=None):
+def flash_kernels(B, H, G, L, D, dtype, mask=None, gate=False):
     """The names of the kernels a forward and backward of this shape run
     (`hvd.profile.flash_plan`): the forward's, then the backward's one
     (`hvd_flash_bwd`) or two."""
@@ -442,7 +454,7 @@ def flash_kernels(B, H, G, L, D, dtype, mask=None):
 
     return [name for backward in (False, True)
             for name in profile.flash_plan(B, H, L, D, H // G, dtype,
-                                           backward, mask=mask)]
+                                           backward, mask=mask, gate=gate)]
 
 
 def model_flash_kernels(model, batch, length, dtype):
@@ -453,7 +465,8 @@ def model_flash_kernels(model, batch, length, dtype):
                          model["embed_dim"] // heads, dtype)
 
 
-def print_flash_plan(B, H, G, L, D, dtype, shared_dim=0, mask=None):
+def print_flash_plan(B, H, G, L, D, dtype, shared_dim=0, mask=None,
+                     gate=False):
     """Which path each flash kernel of this shape takes (`hvd.profile`);
     `shared_dim`: the width of a second score product on one shared key;
     `mask`: a rule in place of the causal triangle, whose plans count the
@@ -462,18 +475,22 @@ def print_flash_plan(B, H, G, L, D, dtype, shared_dim=0, mask=None):
     sub-tiles it visits and masks (`cut_k` under the k block, fewer
     sub-tiles than four a tile: the one-kernel backward's walk engaged). A
     backward kernel's line says which side a grid step holds a block of (the
-    one kernel has a resident form of either kind)."""
+    one kernel has a resident form of either kind). `gate`: the call has a
+    head gate, and a line ends in how it reaches the kernel (`kernel`: the
+    forward takes its reciprocals, a factor of the rows' normalisers; `lse`:
+    a backward kernel is the ungated one on the gated rows' lse and delta /
+    gate)."""
     from horovod_tpu import profile
 
     for backward in (False, True):
         for name, plan in profile.flash_plan(
                 B, H, L, D, H // G, dtype, backward, shared_dim=shared_dim,
-                mask=mask).items():
+                mask=mask, gate=gate).items():
             path = plan.path + (" held by the %s block" % plan.held
                                 if name in (profile.FLASH_DKV,
                                             profile.FLASH_BWD) else "")
             print("  %s: %s, blocks %d x %d, grid %s = %d steps, VMEM %.1f "
-                  "MiB%s%s" % (name, path, plan.block_q, plan.block_k,
+                  "MiB%s%s%s" % (name, path, plan.block_q, plan.block_k,
                                plan.grid, plan.grid_steps,
                                plan.vmem_bytes / 2 ** 20,
                                "" if plan.vmem_limit_bytes is None else
@@ -486,7 +503,9 @@ def print_flash_plan(B, H, G, L, D, dtype, shared_dim=0, mask=None):
                                % (plan.tiles_visited, plan.tiles_masked,
                                   plan.tiles_skipped, plan.cut_k,
                                   plan.subtiles_visited,
-                                  plan.subtiles_masked)),
+                                  plan.subtiles_masked),
+                               "" if plan.gate is None else
+                               "; the gate: %s" % plan.gate),
                   flush=True)
 
 
@@ -507,13 +526,14 @@ def attention_vs_reference(case, tol, kernels):
     jax.block_until_ready((got, want))
     errs = [rel_err(g, r) for g, r in zip(got, want)]
     check(max(errs) <= tol,
-          "flash %s vs _blockwise_reference on the chip: out %.2e dq %.2e "
-          "dk %.2e dv %.2e (max rel to max |ref|, tol %.0e)"
-          % ((name,) + tuple(errs) + (tol,)))
+          "flash %s vs _blockwise_reference on the chip: %s (max rel to max "
+          "|ref|, tol %.0e)" % (name, " ".join(
+              "%s %.2e" % pair for pair in zip(
+                  ("out", "dq", "dk", "dv", "dgate"), errs)), tol))
 
 
 def attention_block_copies(B, H, G, L, D, dtype, seed, mask=None,
-                           window=None):
+                           window=None, gate=False):
     """What lies between an attention block's projections and its flash
     kernels, forward and backward: `hvd.profile.attention_layout_copies` of
     the model's own `Attention` (the two claimed cells' width: 2048 onto H
@@ -523,7 +543,11 @@ def attention_block_copies(B, H, G, L, D, dtype, seed, mask=None,
     operand in the public layout, so none is re-laid for THEIR sake; XLA's
     own copies between its matmuls' layouts and the kernels' are counted and
     named (information: the count is no measure of time, PERF.md s6, PR
-    57)."""
+    57). `gate`: the block with `attention_gate="head"`, whose gate is
+    applied inside the flash kernels (PR 63): between `hvd_flash_fwd` and the
+    out-projection the program holds no fusion that multiplies an o-sized
+    array, at most a plain copy; what carries the gate's scope
+    (`hvd.profile.fused_scopes`) is printed with each result's shape."""
     import jax
     import jax.numpy as jnp
 
@@ -533,7 +557,7 @@ def attention_block_copies(B, H, G, L, D, dtype, seed, mask=None,
     cfg = TransformerConfig(
         num_layers=1, num_heads=H, num_kv_heads=G, head_dim=D,
         embed_dim=2048, attention="flash", qk_norm="head", dtype=dtype,
-        attention_mask=mask, **(
+        attention_mask=mask, attention_gate="head" if gate else None, **(
             {} if window is None else
             {"attention_types": ("window",), "attention_window": window}))
     layer = Attention(cfg, kind=None if window is None else "window")
@@ -546,9 +570,9 @@ def attention_block_copies(B, H, G, L, D, dtype, seed, mask=None,
         return jnp.sum(layer.apply(params, x, positions).astype(jnp.float32)
                        ** 2)
 
-    what = "causal" if mask is None and window is None else (
+    what = ("causal" if mask is None and window is None else (
         "%s%r" % (type(mask).__name__, tuple(mask)) if window is None
-        else "BandMask(%d)" % window)
+        else "BandMask(%d)" % window)) + ", gated" * gate
     for name, fn, calls in (
             ("forward", jax.jit(lambda p, x: layer.apply(p, x, positions)),
              1), ("gradient", jax.jit(jax.grad(loss, argnums=(0, 1))), 2)):
@@ -561,6 +585,13 @@ def attention_block_copies(B, H, G, L, D, dtype, seed, mask=None,
               % (H, G, L, what, name, got["calls"], got["copies"],
                  got["bytes"] / 2 ** 20,
                  ", ".join(got["instructions"]) or "none", secs))
+        if gate:
+            print("    fusions under %s: %s" % (profile.ATTN_GATE, "; ".join(
+                "%s %s" % (fusion, re.search(
+                    r"%%?%s = (.+?) fusion\(" % re.escape(fusion),
+                    text).group(1))
+                for fusion in profile.fused_scopes(
+                    text, (profile.ATTN_GATE,))) or "none"), flush=True)
 
 
 def backward_forms_agree(B, H, G, L, D, dtype, seed, mask, tol):
@@ -969,12 +1000,23 @@ def phase_kernels(args):
             attention_case(*shape, args.seed + 7 + i, mask=rule,
                            dense_causal=True),
             TOL["attn_bf16"], flash_kernels(*shape, mask=rule))
+        # The same call with the cell's head gate, applied inside the
+        # forward kernel (the backward's is the ungated one on the gated
+        # rows' lse and delta / gate): out, dq, dk, dv and dgate.
+        print_flash_plan(*shape, mask=rule, gate=True)
+        attention_vs_reference(
+            attention_case(*shape, args.seed + 9 + i, mask=rule,
+                           dense_causal=True, gated=True),
+            TOL["attn_bf16"], flash_kernels(*shape, mask=rule, gate=True))
     print("  between the projections and the kernels (a grouped call takes "
           "every operand in the public layout, [B, L, heads x D]):",
           flush=True)
     attention_block_copies(B, H, G, L, D, jnp.bfloat16, args.seed)
     attention_block_copies(B, H, G, L, D, jnp.bfloat16, args.seed,
                            window=window)
+    for B, H, G, L, D, window in SIZES["attn_by_kind"]:
+        attention_block_copies(B, H, G, L, D, jnp.bfloat16, args.seed,
+                               window=window, gate=True)
     B, H, G, half, D, block = SIZES["attn_block_diffusion"]
     attention_block_copies(B, H, G, 2 * half, D, jnp.bfloat16, args.seed,
                            mask=BlockDiffusionMask(half, block))

@@ -56,6 +56,16 @@ form since PR 56; held by the q block the whole-sequence operands have one
 buffer each. The Kanana cell's call (`kanana30b_1chip`):
     --B 1 --H 32 --L 8192 --D2 64 --path q-held,split --bqp 256,512,1024 \
     --bk 512,1024
+`--gate`: the call with a head gate (`flash_attention`'s ``gate``: one f32
+scalar a head and position; the forward multiplies its rows' normalisers by
+the gates' reciprocals, the backward kernels are the ungated ones on the
+gated rows' lse and on delta / gate, which is the gate's gradient and is
+timed with them). The two
+calls of `laguna33b_1chip` at the plan's blocks, beside PR 62's ungated 8.865
+| 13.877 and 5.083 | 5.579 (PERF.md s6, PR 63):
+    --B 1 --H 48 --G 8 --L 8192 --path q-held --bqp "" --bk "" --gate
+    --B 1 --H 64 --G 8 --L 8192 --window 512 --path q-held --bqp "" \
+    --bk "" --gate
 GQA/MQA (--G < --H) sweeps the grouped calls (a kv head's query heads the
 rows of one tile, head by head): the q-block candidates become bqp*group
 rows. The `_grouped_blocks` policy was
@@ -153,6 +163,9 @@ def main():
                     help="under a rule: widths of the sub-tile the q-held "
                          "backward takes alone, to try in turn, with commas "
                          "(none: the program's own)")
+    ap.add_argument("--gate", action="store_true",
+                    help="the call with a head gate [B, H, L] f32, applied "
+                         "inside the forward kernel")
     ap.add_argument("--kernels", default="all", choices=("all", "bwd"),
                     help="bwd: leave the forward kernel out")
     ap.add_argument("--bqp", default="128,256,512",
@@ -166,8 +179,9 @@ def main():
     if args.mask_block and args.window:
         ap.error("--mask-block and --window are one rule each: give one")
     D2 = args.D2
-    if D2 and (args.mask_block or args.window):
-        ap.error("a mask by rule has one score product: --D2 or a rule")
+    if D2 and (args.mask_block or args.window or args.gate):
+        ap.error("a mask by rule, and a gated call, has one score product: "
+                 "--D2, or a rule and --gate")
     rule = (fa.BlockDiffusionMask(L // 2, args.mask_block)
             if args.mask_block else
             fa.BandMask(args.window) if args.window else None)
@@ -181,10 +195,15 @@ def main():
     shared = (jnp.asarray(rng.randn(B, H, L, D2), jnp.bfloat16),
               jnp.asarray(rng.randn(B, 1, L, D2), jnp.bfloat16)) if D2 \
         else None
+    # what a gated call hands the kernels beside the rest, and its plan
+    gated = {"gate": jax.nn.sigmoid(jnp.asarray(
+        rng.randn(B, H, L), jnp.float32))} if args.gate else {}
+    gated_plan = {"gate": True} if gated else {}
     scale = (D + D2) ** -0.5
     rows = L * group
     out, lse = jax.jit(lambda q, k, v: fa._pallas_forward_lse(
-        q, k, v, scale, causal, False, shared=shared, rule=rule))(q, k, v)
+        q, k, v, scale, causal, False, shared=shared, rule=rule, **gated))(
+            q, k, v)
 
     print("shape B=%d L=%d H=%d G=%d D=%d%s%s (kernel layout, %d rows/slab)"
           % (B, L, H, G, D, " D2=%d" % D2 if D2 else "",
@@ -192,7 +211,7 @@ def main():
     for backward in (False, True):
         for name, plan in fa.flash_plan(B, H, L, D, group, q.dtype,
                                         backward, shared_dim=D2,
-                                        mask=rule).items():
+                                        mask=rule, **gated_plan).items():
             print("default plan %s: %s" % (name, plan._asdict()))
     by_path = forms(B, H, L, D, group, q.dtype, rule, D2)
     paths = tuple(by_path) if args.path == "all" else tuple(
@@ -225,16 +244,16 @@ def main():
             def fwd(q, bq=bq, bk=bk):
                 return fa._pallas_forward_lse(
                     q, k, v, scale, causal, False, bq, bk, budget,
-                    shared=shared, rule=rule)[0]
+                    shared=shared, rule=rule, **gated)[0]
 
             def bwd(q, bq=bq, bk=bk):
                 return fa._pallas_backward(
                     q, k, v, out, lse, g, scale, causal, False, bq, bk,
-                    budget, shared=shared, rule=rule)
+                    budget, shared=shared, rule=rule, **gated)
 
             try:
                 plan = fa.flash_plan(B, H, L, D, group, q.dtype, True, bq,
-                                     bk, budget, D2, rule)
+                                     bk, budget, D2, rule, **gated_plan)
             except ValueError:  # blocks the rule's length does not take
                 continue
             t_fwd = ms(fwd) if args.kernels == "all" else "-"
@@ -244,7 +263,8 @@ def main():
             else:
                 if path == "resident" and bq is not None:
                     continue  # blocks that do not tile: the split row's
-                # (dq, dk, dv) and, under a second product, (dq2, dk2)
+                # (dq, dk, dv) and, under a second product, (dq2, dk2);
+                # gated, (dq, dk, dv, dgate): the gate's gradient with dQ
                 t_dq = ms(lambda q: total(*bwd(q)[0::3]))
                 t_dkv = ms(lambda q: total(*[
                     x for i, x in enumerate(bwd(q)) if i % 3]))

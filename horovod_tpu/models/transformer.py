@@ -950,10 +950,14 @@ class Attention(nn.Module):
             o = ulysses_attention(q, k, v, cfg.sp_axis, causal=True)
         elif cfg.attention == "flash":
             from horovod_tpu.ops import flash_attention
+            # The head gate goes INTO the kernels (a scale of a tile's rows:
+            # `flash_attention`'s ``gate``); every other branch multiplies
+            # below.
+            gated = {} if cfg.attention_gate != "head" else {"gate": gate}
             if mask is not None:
-                o = flash_attention(q, k, v, mask=mask)
+                o = flash_attention(q, k, v, mask=mask, **gated)
             else:
-                o = flash_attention(q, k, v, causal=True)
+                o = flash_attention(q, k, v, causal=True, **gated)
         else:
             if G != H:
                 k = jnp.repeat(k, H // G, axis=2)
@@ -969,7 +973,7 @@ class Attention(nn.Module):
             s = jnp.where(seen[None, None], s, -jnp.inf)
             p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
             o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
-        if cfg.attention_gate == "head":
+        if cfg.attention_gate == "head" and cfg.attention != "flash":
             with jax.named_scope(profile.ATTN_GATE):
                 o = (o.astype(jnp.float32) * gate[..., None]).astype(o.dtype)
         with jax.named_scope(profile.ATTN_PROJ):

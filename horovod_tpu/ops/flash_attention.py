@@ -124,6 +124,19 @@ sub-tiles, from the same runs. The forward and dQ take a rule in their
 resident form only (k and v whole in VMEM: at D=128 up to 24576 positions);
 where that does not fit the call is the blockwise jnp form.
 
+A head GATE (`gate=`: gated attention's sigmoid a head and position, one
+f32 scalar a ROW of a tile) is a change of the row's NORMALISER: acc g / l =
+acc / (l / g). The forward multiplies a block's normalisers by the gates'
+reciprocals where it finalizes the block, a pass over a column, and is
+otherwise the ungated kernel: out = acc / l' and lse' = m + log l' = lse - log
+g. The backward kernels are the ungated ones in every form: exp(s - lse') is
+g p, the gated row's weights, and they are handed delta / g where an ungated
+call hands them delta = rowsum(dO o), which is also the gate's gradient. No
+q-sized array is read, written or kept for the gate's sake, and no product
+with dO runs anywhere. The forward's operand is [B, G, L, group] f32
+(`_gate_spec`); `flash_plan(..., gate=True)` names the forms. `gate=None` is
+the call it was, to the kernels' text.
+
 Backward: custom VJP over saved per-row log-sum-exp (FlashAttention-2
 style). On non-TPU backends the same kernels run in Pallas interpret
 mode (tests) or fall back to the blockwise JAX implementation.
@@ -249,6 +262,28 @@ def _slab_spec(group, n, width, index_map):
     return pl.BlockSpec((None if group == 1 else group, n, width), index_map)
 
 
+def _gate_operand(gate, group):
+    """A scalar a head and position [B, H, L] f32 (the gates' reciprocals) as
+    the forward kernels take it: [B, G, L, group], a kv head's `group` values
+    side by side on the lanes of a position's row (HBM pads them to a lane
+    tile: 512 bytes a position and kv head, an eighth of a stripe a head in
+    lse's form)."""
+    B, H, L = gate.shape
+    return gate.reshape(B, H // group, group, L).transpose(0, 1, 3, 2)
+
+
+def _gate_spec(group, G, n, index_map):
+    """The block spec of the gate (`_gate_operand`): `n` positions of a kv
+    head's `group` gates, at the (batch x kv head, block of positions, 0)
+    that `index_map` gives. In VMEM `_q_rows` stacks its lanes to the
+    tile's rows, a column [group * n, 1], as it stacks a wide operand's."""
+    def of_kv_head(*grid):
+        b, i, _ = index_map(*grid)
+        return b // G, b % G, i, 0
+
+    return pl.BlockSpec((None, None, n, group), of_kv_head)
+
+
 def _q_rows(ref, group, at=slice(None), lanes=slice(None)):
     """Positions `at` of a q-side operand in VMEM as the ROWS of a score
     tile: the kv head's `group` query heads STACKED, row r = head r // n at
@@ -350,7 +385,8 @@ def _online_softmax_update(s, v_ref, acc_ref, m_ref, l_ref, guard_empty):
         preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(*refs, scale, causal, num_kb, bqp, group, shared=False):
+def _fwd_kernel(*refs, scale, causal, num_kb, bqp, group, shared=False,
+                gate=False):
     # q_ref: [BQ, D] (a group's block: [bqp, group * D] by position or
     # [group, bqp, D] in slabs, taken as rows by `_q_rows`, here and in
     # every kernel below); k_ref/v_ref: [BK, D];
@@ -358,10 +394,15 @@ def _fwd_kernel(*refs, scale, causal, num_kb, bqp, group, shared=False):
     # scratch: acc [BQ, D] f32, m/l [BQ, 128] f32 (state across k steps).
     # bqp = BQ // group: positions per q block (grouped GQA).
     # `shared`: q2_ref [BQ, D2] and k2_ref [BK, D2] follow v (`_scores2`).
+    # `gate`: gate_ref [bqp, group] follows v: the RECIPROCALS of the rows'
+    # gates (`_gate_spec`), by which the rows' normalisers are multiplied.
     second = None
     if shared:
         second = refs[3:5]
         refs = refs[:3] + refs[5:]
+    if gate:
+        gate_ref = refs[3]
+        refs = refs[:3] + refs[4:]
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     qi = pl.program_id(1)
     kj = pl.program_id(2)
@@ -394,6 +435,8 @@ def _fwd_kernel(*refs, scale, causal, num_kb, bqp, group, shared=False):
     def _finalize():
         l = l_ref[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)  # rows with no visible keys
+        if gate:  # as `_fwd_resident_kernel`
+            l = l * _q_rows(gate_ref, group)
         _put_q_rows(o_ref, group, (acc_ref[...] / l).astype(o_ref.dtype))
         # Log-sum-exp per row, saved for the backward recompute.
         _put_q_rows(lse_ref, group, jnp.broadcast_to(
@@ -888,8 +931,8 @@ FlashKernelPlan = collections.namedtuple(
     "FlashKernelPlan",
     "path held block_q block_k grid grid_steps resident_bytes vmem_bytes "
     "vmem_limit_bytes tiles_visited tiles_masked tiles_skipped "
-    "cut_k subtiles_visited subtiles_masked",
-    defaults=(None,) * 6)
+    "cut_k subtiles_visited subtiles_masked gate",
+    defaults=(None,) * 7)
 FlashKernelPlan.__doc__ = """How one flash kernel of a call runs.
 
 path: "resident" (grid (B*G, blocks); the other sequence whole in VMEM,
@@ -925,7 +968,13 @@ cut_k] SUB-tiles it computes and masks, from the runs it walks: a k block's
 worth a turn, one for such a turn. The area under the mask pass is
 tiles_masked x block_k keys a query tile by k blocks alone and
 subtiles_masked x cut_k as walked; cut_k ==
-block_k reads subtiles_* == tiles_*."""
+block_k reads subtiles_* == tiles_*. gate (of a gated call only, else None):
+how the head gate reaches the kernel: "kernel" (the forward: it reads the
+reciprocals of the rows' gates, one q-side operand more, and multiplies the
+rows' normalisers by them) or "lse" (a backward kernel, of any form: the
+ungated kernel, to the letter; the gate is inside the log-normaliser the
+forward saved, lse - log gate, and in delta / gate, which it is handed as
+ever it is handed lse and delta)."""
 
 
 def _vmem(rows, cols, itemsize):
@@ -1053,13 +1102,18 @@ def _cut_k(plan, kernel):
 
 
 def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
-                 vmem_budget, D2=0, rule=None, held=None):
+                 vmem_budget, D2=0, rule=None, held=None, gated=False):
     """``held``: the side a grid step holds a block of, "k" for the kernels
     of `_K_HELD` and "q" for the others unless given: the one-kernel
-    backward has a form of either kind (`flash_plan` tries `_BWD_HELD`)."""
+    backward has a form of either kind (`flash_plan` tries `_BWD_HELD`).
+    ``gated``: the call has a head gate, which the forward takes as one
+    more q-side block ([block_q // group, group] f32, its lanes padded in
+    VMEM); a backward kernel's operands are the ungated call's."""
     backward = kernel != profile.FLASH_FWD
     k_held = (kernel in _K_HELD) if held is None else held == "k"
     held = "k" if k_held else "q"
+    takes_gate = gated and not backward
+    named = {"gate": "kernel" if takes_gate else "lse"} if gated else {}
     n_q, n_k, n_stripes = _OPERANDS[kernel]
     n_q2, n_k2 = _SHARED_OPERANDS[kernel]
     # The whole backward in one kernel, one buffer each: held by the k
@@ -1077,7 +1131,8 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
 
     def q_side(n):  # one pipeline buffer of n rows of every q-side operand
         return (_vmem(n, D, n_q * isz) + n_stripes * _vmem(n, 8, 4)
-                + _vmem(n, D2, n_q2 * isz))
+                + _vmem(n, D2, n_q2 * isz)
+                + (_vmem(n // group, group, 4) if takes_gate else 0))
 
     def k_side(n):
         return _vmem(n, D, n_k * isz) + _vmem(n, D2, n_k2 * isz)
@@ -1115,7 +1170,7 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
             grid = (BG, L // bk if k_held else rows // bq)
             return FlashKernelPlan(
                 "resident", held, bq, bk, grid, grid[0] * grid[1], resident,
-                buffers, max(_DEFAULT_VMEM_LIMIT, limit))
+                buffers, max(_DEFAULT_VMEM_LIMIT, limit), **named)
     if fused:
         return None  # no gridded form: a grid carries dQ or dK/dV, not both
     dkv = kernel == profile.FLASH_DKV  # gridded, it holds a k block
@@ -1128,12 +1183,13 @@ def _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q, block_k,
     grid = (BG, num_kb, num_qb) if dkv else (BG, num_qb, num_kb)
     return FlashKernelPlan("gridded", "k" if dkv else "q", bq, bk, grid,
                            BG * num_qb * num_kb, 0,
-                           2 * (q_side(bq) + k_side(bk)) + scratch, None)
+                           2 * (q_side(bq) + k_side(bk)) + scratch, None,
+                           **named)
 
 
 def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
                block_q=None, block_k=None, vmem_budget=RESIDENT_VMEM_BUDGET,
-               shared_dim=0, mask=None):
+               shared_dim=0, mask=None, gate=False):
     """How `flash_attention` runs q [B, H, L, D] against H // group kv
     heads: {kernel name: FlashKernelPlan} for the forward kernel
     (`hvd_flash_fwd`) or, with ``backward``, the backward: ONE kernel
@@ -1203,6 +1259,18 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
     ``{}``, no kernel at all (the one case left), and the call is the
     blockwise jnp form; a second score product is refused beside a rule.
 
+    ``gate``: the call has a head gate (`flash_attention`'s ``gate``: one
+    f32 scalar a head and position, a scale of a row of a tile). The same
+    choice of path, kernels and blocks, and every plan says in its `gate`
+    field how the gate reaches its kernel: the forward kernel, in either
+    form, multiplies its rows' normalisers by the gates' reciprocals
+    ("kernel": a q-side block more, [block_q // group, group] f32), so that
+    what it saves is the gated rows' log-normaliser; a backward kernel, in
+    every form, is the ungated one on that and on delta / gate ("lse": no
+    operand, no product, the parent's text). An ungated call's plans read
+    None there and are what they were. A second score product is refused
+    beside a gate.
+
     `_pallas_forward_lse` and `_pallas_backward` run what this returns,
     so it is also the counter that says which path a program took
     (docs/TRACING.md; `hvd.profile.flash_plan`)."""
@@ -1211,10 +1279,12 @@ def flash_plan(B, H, L, D, group=1, dtype=jnp.bfloat16, backward=False,
 
     if mask is not None and shared_dim:
         raise ValueError("a mask by rule has one score product")
+    if gate and shared_dim:
+        raise ValueError("a gated call has one score product")
 
     def plan(kernel, held=None):
         p = _kernel_plan(BG, rows, L, D, group, isz, kernel, block_q,
-                         block_k, vmem_budget, shared_dim, mask, held)
+                         block_k, vmem_budget, shared_dim, mask, held, gate)
         if mask is None or p is None:
             return p
         bqp, cut_k = p.block_q // group, _cut_k(p, kernel)
@@ -1337,17 +1407,27 @@ def _mask_tile(s, rule, q_off, kv_off, group):
 
 
 def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group,
-                         shared=False, rule=None):
+                         shared=False, rule=None, gate=False):
     # q_ref/o_ref: [BQ, D]; k_ref/v_ref: [L, D], fetched once per b (the
     # block index does not change across q blocks); lse_ref [BQ, 8]. The
     # online-softmax state (acc, m, l) is carried by the loop.
     # `shared`: q2_ref [BQ, D2] and k2_ref [L, D2] follow v (`_scores2`).
     # `rule`: a mask by rule in place of the causal triangle; the loop
     # walks the rule's runs of k blocks (`_walk_runs`).
+    # `gate`: gate_ref [bqp, group] follows v and holds the RECIPROCALS of
+    # the rows' gates. A gated row is acc g / l = acc / (l / g): the row's
+    # normaliser is multiplied by 1 / g, a pass over a COLUMN, and the rest is
+    # the ungated kernel's: o = acc / l', in f32 before the one rounding, and
+    # lse' = m + log l' = lse - log g, the log-normaliser of the GATED row,
+    # with which the backward kernels, unchanged, form p' = exp(s - lse') =
+    # g p (`_pallas_backward`).
     if shared:
         q2_ref, k2_ref = refs[3:5]
         refs = refs[:3] + refs[5:]
         q2 = _q_rows(q2_ref, group)
+    if gate:
+        gate_ref = refs[3]
+        refs = refs[:3] + refs[4:]
     q_ref, k_ref, v_ref, o_ref, lse_ref = refs
     qi = pl.program_id(1)
     q = _q_rows(q_ref, group)
@@ -1384,6 +1464,8 @@ def _fwd_resident_kernel(*refs, scale, causal, bk, bqp, group,
                 jnp.zeros((bq, 1), jnp.float32)),
         qi, bqp, bk, k_ref.shape[0] // bk, causal, rule)
     l = jnp.where(l == 0.0, 1.0, l)  # rows with no visible keys
+    if gate:
+        l = l * _q_rows(gate_ref, group)
     _put_q_rows(o_ref, group, (acc / l).astype(o_ref.dtype))
     _put_q_rows(lse_ref, group, jnp.broadcast_to(
         m + jnp.log(l), (bq, lse_ref.shape[-1])))
@@ -1758,7 +1840,7 @@ def _ruled_call(call, name, rule, plan, inputs, *static):
 def _pallas_forward_lse(q, k, v, scale, causal, interpret,
                         block_q=None, block_k=None,
                         vmem_budget=RESIDENT_VMEM_BUDGET, shared=None,
-                        rule=None):
+                        rule=None, gate=None):
     """q [B, H, L, D], k/v [B, G, L, D] with G | H. Returns
     (out [B,H,L,D], lse [B*H, L, 8] f32) — lse is the per-row log-sum-exp
     the backward kernels need, a slab a head (`_slab_spec`)
@@ -1768,7 +1850,11 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     path and the blocks; ``vmem_budget`` is its argument (tests and the
     block sweep force a path with it). ``shared``: (q2 [B, H, L, D2], k2
     [B, 1, L, D2]), the second score product's operands. ``rule``: a mask
-    by rule in place of ``causal`` (resident only: `flash_plan`)."""
+    by rule in place of ``causal`` (resident only: `flash_plan`). ``gate``
+    [B, H, L] f32: out is the attention's output times it, row by row (the
+    kernel multiplies a row's normaliser l by 1 / gate where it finalizes a
+    block), and lse is the GATED row's log-normaliser, lse - log gate (+inf
+    at a gate of 0, where the row is 0)."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -1777,8 +1863,9 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     qf = _q_operand(q, group, by_position)
     kf = _k_operand(k, by_position)
     vf = _k_operand(v, by_position)
+    gated = {} if gate is None else {"gate": True}
     plans = flash_plan(B, H, L, D, group, q.dtype, False, block_q, block_k,
-                       vmem_budget, D2, rule)
+                       vmem_budget, D2, rule, gate is not None)
     if shared:
         q2f, k2f, of_batch = _shared_operands(shared, B, G, group,
                                               by_position)
@@ -1789,7 +1876,8 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
     plan = plans[profile.FLASH_FWD]
     bq, bk = plan.block_q, plan.block_k
     bqp = bq // group
-    inputs = [qf, kf, vf] + ([q2f, k2f] if shared else [])
+    inputs = [qf, kf, vf] + ([q2f, k2f] if shared else []) + (
+        [] if gate is None else [_gate_operand(1.0 / gate, group)])
     q_im, kv_spec = _q_walk_specs(plan, L, D, group, causal, by_position, G)
     if plan.path == "resident":
         kernel = functools.partial(_fwd_resident_kernel, scale=scale,
@@ -1797,12 +1885,13 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
                                    group=group,
                                    **({"shared": True} if shared else {}),
                                    **({} if rule is None
-                                      else {"rule": rule}))
+                                      else {"rule": rule}), **gated)
         scratch = []
     else:
         kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                    num_kb=L // bk, bqp=bqp, group=group,
-                                   **({"shared": True} if shared else {}))
+                                   **({"shared": True} if shared else {}),
+                                   **gated)
         scratch = [
             pltpu.VMEM((bq, D), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
@@ -1816,7 +1905,8 @@ def _pallas_forward_lse(q, k, v, scale, causal, interpret,
         kernel,
         name=profile.FLASH_FWD,
         grid=plan.grid,
-        in_specs=[q_spec, kv_spec, kv_spec] + shared_specs,
+        in_specs=[q_spec, kv_spec, kv_spec] + shared_specs + (
+            [] if gate is None else [_gate_spec(group, G, bqp, q_im)]),
         out_specs=[q_spec, _slab_spec(group, bqp, 8, q_im)],
         out_shape=[
             jax.ShapeDtypeStruct(qf.shape, q.dtype),
@@ -2295,14 +2385,28 @@ def _bwd_dkv_kernel(*refs, scale, causal, num_qb, bqp, group, rule=None,
 def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
                      block_q=None, block_k=None,
                      vmem_budget=RESIDENT_VMEM_BUDGET, shared=None,
-                     rule=None):
+                     rule=None, gate=None):
     """Pallas backward: q/out/g [B,H,L,D], k/v [B,G,L,D], lse a slab a head
     as the forward returned it. Returns (dq [B,H,L,D], dk/dv [B,G,L,D]) in the
     inputs' dtypes. Path and blocks per kernel from `flash_plan`. With
     ``shared`` = (q2 [B,H,L,D2], k2 [B,1,L,D2]) also (dq2, dk2) of those
     shapes, dk2 summed over the heads in f32. ``rule``: a mask by rule in
     place of ``causal`` (dQ resident only, the one kernel in either of its
-    forms, dK/dV resident or gridded)."""
+    forms, dK/dV resident or gridded).
+
+    ``gate`` [B, H, L] f32: out and lse are the GATED call's
+    (`_pallas_forward_lse`: out = gate x the attention's rows, lse = the
+    attention's - log gate) and g is out's cotangent; a fourth result
+    follows, the gate's gradient [B, H, L] f32. The kernels are the ungated
+    ones, whatever the plan: a gated row is P' v with P' = gate x softmax =
+    exp(s - lse), whose rows sum to the gate, so ds = P' (dP' - rowsum(dP'
+    softmax)) with dP' = g v^T, and rowsum(dP' softmax) = g . out / gate =
+    delta / gate. So the kernels are handed delta / gate where an ungated
+    call hands them delta = rowsum(g out), and that number IS the gate's
+    gradient, rowsum(g . out / gate): no pass over a q-sized array, no
+    product with dO anywhere, no ungated output to keep. Where the gate is 0
+    in f32 (a sigmoid's underflow) out and delta are 0, lse is +inf (every p
+    is 0) and the gradient returned is 0."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -2315,13 +2419,15 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
     # kernels per q block (recomputing it per grid step would redo the
     # reduction num_kb/num_qb times).
     if group == 1:
-        delta = jnp.broadcast_to(
-            jnp.sum(gf.astype(jnp.float32) * outf.astype(jnp.float32),
-                    axis=-1, keepdims=True), lse.shape)
+        delta = jnp.sum(gf.astype(jnp.float32) * outf.astype(jnp.float32),
+                        axis=-1, keepdims=True)
     else:  # a row a head and position, as lse's: from the heads apart
-        delta = jnp.broadcast_to(
-            jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).reshape(B * H, L, 1), lse.shape)
+        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1).reshape(B * H, L, 1)
+    if gate is not None:  # delta / gate: the kernels' delta, and dgate
+        dgate = jnp.where(gate > 0, delta.reshape(B, H, L) / gate, 0.0)
+        delta = dgate.reshape(B * H, L, 1)
+    delta = jnp.broadcast_to(delta, lse.shape)
     rows = L * group
     # Backward blocks are independent of the forward's (lse/delta
     # stripes are block-agnostic); see _resident_blocks and
@@ -2507,15 +2613,17 @@ def _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
 
     return (_from_q_operand(dq, q.shape, group, by_position),
             _from_k_operand(dk, k.shape, by_position),
-            _from_k_operand(dv, v.shape, by_position))
+            _from_k_operand(dv, v.shape, by_position)) + (
+                () if gate is None else (dgate,))
 
 
-def _blockwise_reference(q, k, v, scale, causal, rule=None):
+def _blockwise_reference(q, k, v, scale, causal, rule=None, gate=None):
     """Blockwise JAX attention, O(BLOCK_Q * L) live memory; used for the
     backward recompute and as the non-TPU fallback. q [B,H,L,D], k/v
     [B,G,L,D] — GQA repeats kv across each head group here (the kernel
     path never materializes that). ``rule``: a mask by rule in place of
-    ``causal``."""
+    ``causal``. ``gate`` [B, H, L] f32: a row's output times its gate, in
+    f32 before the rounding."""
     B, H, L, D = q.shape
     G = k.shape[1]
     group = H // G
@@ -2537,8 +2645,11 @@ def _blockwise_reference(q, k, v, scale, causal, rule=None):
             cols = lax.broadcasted_iota(jnp.int32, (size, L), 1)
             s = jnp.where((rows >= cols)[None, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", p,
-                          v.astype(jnp.float32)).astype(q.dtype)
+        o = jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
+        if gate is not None:
+            o = o * lax.slice_in_dim(gate, start, start + size,
+                                     axis=2)[..., None]
+        return o.astype(q.dtype)
 
     # Ceil-divide over q so a sequence remainder (L % block_q != 0) gets
     # its own (smaller, still static-shaped) tail block.
@@ -2578,6 +2689,39 @@ def _flash_bwd(scale, causal, interpret, rule, res, g):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_gated(q, k, v, gate, scale, causal, interpret, rule=None):
+    """`_flash` with a head gate [B, H, L] f32: a row's output times its
+    gate, applied inside the kernels (`flash_plan(..., gate=True)`)."""
+    if interpret is None:
+        return _blockwise_reference(q, k, v, scale, causal, rule, gate)
+    return _pallas_forward_lse(q, k, v, scale, causal, interpret, rule=rule,
+                               gate=gate)[0]
+
+
+def _flash_gated_fwd(q, k, v, gate, scale, causal, interpret, rule=None):
+    if interpret is None:
+        return (_blockwise_reference(q, k, v, scale, causal, rule, gate),
+                (q, k, v, gate, None, None))
+    out, lse = _pallas_forward_lse(q, k, v, scale, causal, interpret,
+                                   rule=rule, gate=gate)
+    return out, (q, k, v, gate, out, lse)
+
+
+def _flash_gated_bwd(scale, causal, interpret, rule, res, g):
+    q, k, v, gate, out, lse = res
+    if interpret is None:
+        _, vjp = jax.vjp(
+            lambda q, k, v, gate: _blockwise_reference(
+                q, k, v, scale, causal, rule, gate), q, k, v, gate)
+        return vjp(g)
+    return _pallas_backward(q, k, v, out, lse, g, scale, causal, interpret,
+                            rule=rule, gate=gate)
+
+
+_flash_gated.defvjp(_flash_gated_fwd, _flash_gated_bwd)
 
 
 def _blockwise_shared(q, k, v, q2, k2, scale, causal):
@@ -2645,7 +2789,7 @@ def analytic_attention_flops(B, H, L, D, causal=True, training=False):
 
 
 def flash_attention(q, k, v, causal=True, scale=None, q_shared=None,
-                    k_shared=None, mask=None):
+                    k_shared=None, mask=None, gate=None):
     """Flash attention over [B, L, H, D] inputs (same layout as
     `parallel.ring.ring_attention`); returns [B, L, H, D] in q.dtype.
 
@@ -2673,6 +2817,24 @@ def flash_attention(q, k, v, causal=True, scale=None, q_shared=None,
     mask only those it cuts (`flash_plan(..., mask=)` counts them); the second
     score product is refused beside it.
 
+    ``gate`` [B, L, H] (gated attention's head gate, a sigmoid's value: one
+    scalar a head and position, taken in f32): head h's output at a position
+    is multiplied by it, in f32 before the one rounding to q.dtype, and the
+    call is differentiable in it. The gate is applied INSIDE the kernels, as
+    a change of a row's normaliser (acc g / l = acc / (l / g)): the forward
+    multiplies a block's normalisers by 1 / gate where it finalizes the
+    block and saves the gated rows' log-normaliser, lse - log gate; the
+    backward kernels are the ungated ones in every form, handed that and
+    delta / gate (delta = rowsum(dO o), the number the backward forms
+    anyway), which is also the gate's gradient (`flash_plan(..., gate=True)`
+    names the forms). No q-sized array is read or written for the gate's
+    sake, no product with dO runs, and the ungated output is never kept. The
+    gate's domain is a sigmoid's, > 0; at a gate of exactly 0 (the sigmoid's
+    underflow in f32) the output row is 0 and the gate's gradient is
+    returned as 0 (what the sigmoid's own g (1 - g) makes of it in the model
+    anyway). `None`: the call, its operands and its kernels' text are what
+    they were. A second score product is refused beside it.
+
     L must be a multiple of 128 to hit the Pallas kernel; other shapes
     (and non-TPU backends without interpret mode) use the blockwise JAX
     fallback, which is numerically identical.
@@ -2698,6 +2860,13 @@ def flash_attention(q, k, v, causal=True, scale=None, q_shared=None,
             raise ValueError("mask=%r cannot be combined with q_shared / "
                              "k_shared" % (mask,))
         mask.check(L, 1, 1)
+    if gate is not None:
+        if D2:
+            raise ValueError("gate cannot be combined with q_shared / "
+                             "k_shared")
+        if gate.shape != (B, L, H):
+            raise ValueError("gate %s: want [B, L, H] = %s"
+                             % (gate.shape, (B, L, H)))
     if scale is None:
         scale = (D + D2) ** -0.5
     # Kernel layout: [B, H, L, D] / [B, G, L, D].
@@ -2705,14 +2874,20 @@ def flash_attention(q, k, v, causal=True, scale=None, q_shared=None,
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
 
+    def attend(causal, interpret, rule=None):
+        if gate is None:
+            return _flash(qt, kt, vt, scale, causal, interpret, rule)
+        return _flash_gated(qt, kt, vt,
+                            gate.astype(jnp.float32).transpose(0, 2, 1),
+                            scale, causal, interpret, rule)
+
     on_tpu = jax.default_backend() == "tpu"
     if mask is not None:
         # The rule's kernels tile what it says; no plan, no kernel.
         kernel_ok = on_tpu and mask.tiled(L) % BLOCK_Q == 0 and all(
             flash_plan(B, H, L, D, group, q.dtype, backward, mask=mask)
             for backward in (False, True))
-        out = _flash(qt, kt, vt, scale, False, False if kernel_ok else None,
-                     mask)
+        out = attend(False, False if kernel_ok else None, mask)
         return out.transpose(0, 2, 1, 3)
     kernel_ok = (
         on_tpu and L % BLOCK_Q == 0 and
@@ -2725,5 +2900,5 @@ def flash_attention(q, k, v, causal=True, scale=None, q_shared=None,
                             k_shared.transpose(0, 2, 1, 3), scale, causal,
                             False if kernel_ok else None)
         return out.transpose(0, 2, 1, 3)
-    out = _flash(qt, kt, vt, scale, causal, False if kernel_ok else None)
+    out = attend(causal, False if kernel_ok else None)
     return out.transpose(0, 2, 1, 3)

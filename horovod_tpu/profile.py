@@ -874,8 +874,23 @@ def flash_plan(*args, **kwargs):
     `cut_k` keys a query tile, against `tiles_masked` x `block_k` by k
     blocks alone. The
     forward and dQ take a rule resident only, dK/dV in any of its forms,
-    ``{}`` otherwise. The kernels run what this returns, so like
-    `grad_collectives` it needs no chip."""
+    ``{}`` otherwise. With ``gate=True`` the call has a head gate
+    (`flash_attention(..., gate=)`: gated attention's sigmoid a head and
+    position, > 0) and every plan says in its `gate` field how the gate
+    reaches its kernel: "kernel" (the forward, in either form: it reads the
+    reciprocals of the rows' gates, one q-side operand more, and multiplies
+    the rows' normalisers by them: acc g / l = acc / (l / g)) or "lse" (a
+    backward kernel, in every form: the ungated kernel to the letter, handed
+    the gated rows' log-normaliser, lse - log gate, which the forward saved,
+    and delta / gate, which is the gate's gradient too; no product with dO
+    runs anywhere). So `{"fwd": plans_fwd[FLASH_FWD].gate, "bwd":
+    plans_bwd[...].gate}` reads `{"fwd": "kernel", "bwd": "lse"}` at both
+    calls of `laguna33b_1chip`, and a trace's time under `hvd_attn_gate` is
+    the gate's projection, its sigmoid, the operand's forming and delta's
+    division only: what the gate costs the kernels (a column's pass a q
+    block of the forward) is inside the flash kernels' own time. Without it
+    the field is None and the plans are what they were. The kernels run what
+    this returns, so like `grad_collectives` it needs no chip."""
     # `ops.flash_attention` imports this module for its kernels' names.
     from horovod_tpu.ops.flash_attention import flash_plan as plan
 

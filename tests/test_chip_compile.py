@@ -24,7 +24,8 @@ from jax.sharding import SingleDeviceSharding
 from chip_smoke import kernel_calls as _kernels, kernel_named as _named
 from horovod_tpu.ops import BandMask, BlockDiffusionMask
 from horovod_tpu import profile
-from horovod_tpu.ops.flash_attention import (_flash, _flash_shared,
+from horovod_tpu.ops.flash_attention import (_flash, _flash_gated,
+                                             _flash_shared,
                                              _pallas_forward_lse,
                                              flash_plan,
                                              flash_ring_bwd_step,
@@ -253,6 +254,57 @@ def test_the_laguna_cells_flash_calls_compile_for_v5e(one_chip, H, rule):
                  profile.FLASH_BWD):
         assert _named(text, name) == (name in plans), name
     assert "[%d,%d]" % (S, S) not in text
+
+
+# The same two calls with the cell's head gate (PR 63: `flash_attention`'s
+# ``gate``, [1, H, 8192] f32 as `_flash_gated` takes it): the forward takes
+# the gates' reciprocals as one more q-side operand (f32[1, 8, 8192, group]:
+# a q block's stacked to the tile's rows in VMEM, a factor of the rows'
+# normalisers), the backward is the ungated kernel on the gated rows' lse and
+# on delta / gate; still two `tpu_custom_call`s a call, the gate's gradient a
+# [1, H, 8192] division of delta. The programs they compile to, hashed as
+# `_PLAIN_PROGRAMS`' are: a change MEANT to move the gated calls replaces
+# these.
+_GATED_PROGRAMS = {(48, None): "cba8b8dd0dc424d0",
+                   (64, BandMask(512)): "809ce64502faba9a"}
+
+
+@pytest.mark.parametrize("H,rule", list(_GATED_PROGRAMS))
+def test_the_laguna_cells_gated_calls_compile_to_their_text(one_chip, H,
+                                                            rule):
+    import hashlib
+
+    from benchmark.rehearse_text import without_locations
+
+    G, S, D = 8, 8192, 128
+
+    def fwd_bwd(q, k, v, gate, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v, gate: _flash_gated(
+                q, k, v, gate, D ** -0.5, rule is None, False, rule),
+            q, k, v, gate)
+        return (out,) + vjp(g)
+
+    bf16 = jnp.bfloat16
+    text = _compile(one_chip, fwd_bwd, ((1, H, S, D), bf16),
+                    ((1, G, S, D), bf16), ((1, G, S, D), bf16),
+                    ((1, H, S), jnp.float32), ((1, H, S, D), bf16))
+    plans = {name: p for backward in (False, True)
+             for name, p in flash_plan(
+                 1, H, S, D, H // G, bf16, backward, gate=True,
+                 **({} if rule is None else {"mask": rule})).items()}
+    assert {name: (p.path, p.held, p.gate) for name, p in plans.items()} == {
+        profile.FLASH_FWD: ("resident", "q", "kernel"),
+        profile.FLASH_BWD: ("resident", "q", "lse")}
+    assert _kernels(text) == 2, text[:2000]
+    for name in plans:
+        assert _named(text, name), name
+    # the forward's one operand more: a kv head's values a position's lanes
+    assert text.count("f32[1,%d,%d,%d]{3,2,1,0}" % (G, S, H // G)) == 1
+    assert "[%d,%d]" % (S, S) not in text
+    text, _ = without_locations(text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _GATED_PROGRAMS[H, rule]
 
 
 # The ring LM of `chip_smoke.py --chips 4`: B2 x H6 per chip, L=8192 over

@@ -133,10 +133,14 @@ def interpreted(monkeypatch):
     """The flash kernels themselves, in interpret mode, under
     `ops.flash_attention` on the CPU (which takes the blockwise jnp form
     otherwise)."""
-    real = fa._flash
+    real, real_gated = fa._flash, fa._flash_gated
     monkeypatch.setattr(
         fa, "_flash", lambda q, k, v, scale, causal, interpret, rule=None:
         real(q, k, v, scale, causal, True, rule))
+    # a gated stack's calls (PR 63: the gate's product inside the kernels)
+    monkeypatch.setattr(
+        fa, "_flash_gated", lambda q, k, v, gate, scale, causal, interpret,
+        rule=None: real_gated(q, k, v, gate, scale, causal, True, rule))
 
 
 # --- (a) the model against the reference ------------------------------------
@@ -313,6 +317,46 @@ def test_the_gate_is_one_sigmoid_a_head_on_the_heads_output(kind):
         x[0], dict(params, gate={"kernel": kernel}), kind, _arch(_cfg()))
     _close(run(kernel)[0], branch, 2e-5)
     _close(gate, jax.nn.sigmoid(x[0] @ kernel), 1e-6)
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_the_gate_inside_the_kernels_is_the_dense_branchs_product(
+        kind, interpreted, monkeypatch):
+    """`Attention` under `attention="flash"` hands the kernels its gate (PR
+    63: no product of its own) and under "dense" multiplies: the same output
+    and the same gradients of the gate's, the queries' and the output
+    projection's matrices, at 128 positions and a window of 40, the kernels
+    themselves in the interpreter (group 3 causal, group 4 under the band)."""
+    length = 128
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, length, HIDDEN))
+    w = jax.random.normal(jax.random.PRNGKey(10), (1, length, HIDDEN))
+    pos = jnp.arange(length)[None]
+
+    def module(attention):
+        return Attention(_cfg(attention, length=length, window=40),
+                         kind=kind, shape=dict(SHAPES)[kind])
+
+    params = jax.tree_util.tree_map(
+        lambda t: 3.0 * t,
+        module("dense").init(jax.random.PRNGKey(8), x, pos)["params"])
+
+    def run(attention):
+        return jax.value_and_grad(lambda p: jnp.sum(
+            module(attention).apply({"params": p}, x, pos) * w))(params)
+
+    gates, real = [], fa._flash_gated
+    monkeypatch.setattr(fa, "_flash_gated", lambda *a: gates.append(
+        a[3].shape) or real(*a))
+    got, got_grads = run("flash")
+    assert gates == [(1, HEADS[kind], length)]  # the gate went in, [B, H, L]
+    want, want_grads = run("dense")
+    _close(got, want, TOL)
+    _close(module("flash").apply({"params": params}, x, pos),
+           module("dense").apply({"params": params}, x, pos), TOL)
+    for name in ("gate", "query", "out"):
+        assert np.max(np.abs(want_grads[name]["kernel"])) > 0
+        _close(got_grads[name]["kernel"], want_grads[name]["kernel"],
+               TOL_GRAD)
 
 
 # --- (c) the shares of an 8-way group add up to the uncut layer --------------

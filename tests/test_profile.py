@@ -880,6 +880,7 @@ def test_flash_plans_of_the_cells_are_the_parents(cell):
         assert got.pop("held") == (
             "k" if name == "hvd_flash_dkv" or (
                 name == "hvd_flash_bwd" and cell not in _Q_HELD_BWD) else "q")
+        assert got.pop("gate") is None  # PR 63's: no cell's plan here has one
         # The parent's fields, then PR 53's (`cut_k`, `subtiles_visited`,
         # `subtiles_masked`): with no rule None; under the cell's rule the
         # forward by k blocks alone, the backward's lone sub-tiles alone.
