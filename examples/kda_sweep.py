@@ -16,13 +16,26 @@ rule's forward, which also saves the states; `hvd_kda_scan_bwd`) at each
 `--heads` (heads a grid step, `kda.SCAN_HEADS`), ms a call beside the least
 the bytes allow. Each kernel form is held to the jnp one before it is timed.
 
+With `--conv`, that part alone: the mixer's short convolutions with SiLU and
+the head norms (`ops/kda_conv.py::kda_qkv`) at the layer's `proj`
+[1, 8192, 12576 padded to 99 lane tiles], the kernel pair (`hvd_kda_qkv`, `hvd_kda_qkv_bwd`) at each
+`--rows` x `--lanes` x `--chunk-rows` x `--unroll` (`kda_conv.BLOCK_ROWS`,
+`BLOCK_LANES`, `CHUNK_ROWS`, `CHUNK_UNROLL`) against the jnp pair, forward and
+forward + backward, ms a call and GB/s of `conv_plan`'s bytes against the
+chip's 819; `--chain` calls a program, each behind a barrier on the one
+before and with taps of its own (one call a program is under the host's
+0.19 ms a dispatch).
+
 Usage: python examples/kda_sweep.py [--blocks 32 64 128] [--groups 1 2 4 8]
        [--chunks 4 8 16] [--side 2] [--heads 8 16 32] [--iters 10] [--cpu]
        (--cpu: tiny shapes, the interpreter, no times; an option with no
        value skips its part)
+       python examples/kda_sweep.py --conv [--rows 512 1024] [--lanes 512]
+       [--chunk-rows 32 64] [--unroll 1 2] [--chain 4] [--iters 5] [--cpu]
 """
 
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -36,7 +49,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from horovod_tpu.ops import kda  # noqa: E402
+from horovod_tpu.ops import kda, kda_conv  # noqa: E402
 
 
 def timed(fn, args, iters):
@@ -205,6 +218,98 @@ def scan_alone(args, inputs, interpret):
     return rows
 
 
+def chained(fn, n):
+    """fn(proj, w, *rest) n times in one program, each call behind a barrier
+    on the results of the one before and with taps of its own (w, 2 w, ...:
+    equal calls are one call once XLA has dropped the barriers); every
+    call's results are the program's."""
+    def run(proj, w, *rest):
+        outs = []
+        for i in range(n):
+            out = fn(proj, (1.0 + i) * w, *rest)
+            (proj, w, rest), out = lax.optimization_barrier(
+                ((proj, w, rest), out))
+            outs.append(out)
+        return outs
+    return jax.jit(run)
+
+
+def conv_sweep(args):
+    """The short convolutions with SiLU and the head norms: the jnp pair,
+    then the kernel pair by block, each held to the jnp one."""
+    B, L, H, D, taps = (2, 128, 2, 128, 4) if args.cpu \
+        else (1, 8192, 32, 128, 4)
+    interpret = True if args.cpu else None
+    inner, bf16 = H * D, jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    # the layer's 3 H D + 2 D + H = 12576 columns as the chip lays them out
+    # behind the in-projection's matmul, padded to whole lane tiles: an
+    # array of 12576 columns made outside a program lies tokens-minor (no
+    # padding that way) and every form here would start with a copy of it
+    width = -(-(3 * inner + 2 * D + H) // 128) * 128
+    proj = jax.random.normal(ks[0], (B, L, width)).astype(bf16)
+    w = 0.5 * jax.random.normal(ks[1], (taps, 3 * inner))
+    cot = tuple(jax.random.normal(kk, (B, L, inner)).astype(bf16)
+                for kk in ks[2:])
+
+    def with_backward(f):
+        def both(proj, w, *cot):
+            out, vjp = jax.vjp(f, proj, w)
+            return out, vjp(cot)
+        return both
+
+    def in_jnp(proj, w):
+        return kda_conv._qkv_jnp(proj, w, H, D)
+
+    def kernels(proj, w):
+        return kda_conv.kda_qkv(proj, w, H, D, interpret)
+
+    def ms_and_rate(fn, operands, moved):
+        ms = timed(chained(fn, args.chain), operands, args.iters) \
+            / args.chain
+        rate = moved / ms / 1e6
+        return {"ms": ms, "GB/s": rate,
+                "share_of_819": rate / (HBM_BYTES_A_S / 1e9)}
+
+    want = jax.jit(with_backward(in_jnp))(proj, w, *cot)
+    rows = []
+    least = kda_conv.conv_plan(B, L, H, D, taps, bf16, interpret)["bytes"]
+    if not args.cpu:
+        rows.append({"conv": "jnp", "least_bytes": least,
+                     "fwd": ms_and_rate(in_jnp, (proj, w), least["forward"]),
+                     "fwd_bwd": ms_and_rate(
+                         with_backward(in_jnp), (proj, w) + cot,
+                         least["forward"] + least["backward"])})
+        print(json.dumps(rows[-1]), flush=True)
+    for block_rows, lanes, chunk_rows, unroll in itertools.product(
+            args.rows, args.lanes, args.chunk_rows, args.unroll):
+        kda_conv.BLOCK_ROWS, kda_conv.BLOCK_LANES, \
+            kda_conv.CHUNK_ROWS, kda_conv.CHUNK_UNROLL = \
+            block_rows, lanes, chunk_rows, unroll
+        plan = kda_conv.conv_plan(B, L, H, D, taps, bf16, interpret)
+        if plan["path"] != "kernel":
+            continue
+        got = jax.jit(with_backward(kernels))(proj, w, *cot)
+        row = {"conv": "kernels", "plan": plan, "unroll": unroll,
+               "fwd_rel_err": worst(got[0], want[0]),
+               "bwd_rel_err": worst(got[1], want[1])}
+        if not args.cpu:
+            blocks = kda_conv._blocks(L, H, D, taps, interpret)
+            moved = plan["bytes"]
+            row.update(
+                fwd=ms_and_rate(kernels, (proj, w), moved["forward"]),
+                bwd=ms_and_rate(
+                    lambda proj, w, *cot: kda_conv._pallas_qkv(
+                        proj, w, cot, H, D, blocks, False),
+                    (proj, w) + cot, moved["backward"]),
+                fwd_bwd=ms_and_rate(
+                    with_backward(kernels), (proj, w) + cot,
+                    moved["forward"] + moved["backward"]))
+        rows.append(row)
+        print(json.dumps(row), flush=True)
+    return rows
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--blocks", type=int, nargs="*", default=[32, 64, 128])
@@ -214,11 +319,24 @@ def main():
     ap.add_argument("--heads", type=int, nargs="*", default=[8, 16, 32])
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--cpu", action="store_true")
+    ap.add_argument("--conv", action="store_true")
+    ap.add_argument("--rows", type=int, nargs="*",
+                    default=[kda_conv.BLOCK_ROWS])
+    ap.add_argument("--lanes", type=int, nargs="*",
+                    default=[kda_conv.BLOCK_LANES])
+    ap.add_argument("--chunk-rows", type=int, nargs="*",
+                    default=[kda_conv.CHUNK_ROWS])
+    ap.add_argument("--unroll", type=int, nargs="*",
+                    default=[kda_conv.CHUNK_UNROLL])
+    ap.add_argument("--chain", type=int, default=4)
     args = ap.parse_args()
     kda.SIDE = args.side
     if not args.cpu and jax.default_backend() != "tpu":
         raise SystemExit("kda_sweep: needs a TPU (or --cpu for the forms "
                          "alone)")
+    if args.conv:
+        print(json.dumps({"conv": conv_sweep(args)}), flush=True)
+        return
     B, L, H, D = (1, 256, 2, 128) if args.cpu else (1, 8192, 32, 128)
     interpret = True if args.cpu else None
     ks = jax.random.split(jax.random.PRNGKey(0), 7)

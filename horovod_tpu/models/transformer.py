@@ -1114,17 +1114,18 @@ class KimiDeltaAttention(nn.Module):
         o_t = S_t^T q_t
         out = out_proj(rms_D(o) norm * sigmoid(g_up z))  norm [D], the heads'
 
-    The recurrence is `ops.kda.kda_chunked`; the convolution, the norms, the
-    decay and the gate are f32. No projection and no convolution has a
-    bias; `dt_bias` and `A_log` start as `Mamba2`'s. With |k| = 1 and beta
-    in (0, 1) the transition is a contraction: nothing is clamped. Sows
-    ``kda_state_max`` under ``intermediates`` (the largest |S| a chunk ends
-    in)."""
+    The recurrence is `ops.kda.kda_chunked`, the second and third lines
+    `ops.kda_conv.kda_qkv`; the convolution, the norms, the decay and the
+    gate are f32. No projection and no convolution has a bias; `dt_bias`
+    and `A_log` start as `Mamba2`'s. With |k| = 1 and beta in (0, 1) the
+    transition is a contraction: nothing is clamped. Sows ``kda_state_max``
+    under ``intermediates`` (the largest |S| a chunk ends in)."""
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x):
         from horovod_tpu.ops.kda import kda_chunked
+        from horovod_tpu.ops.kda_conv import kda_qkv
         cfg = self.cfg
         H, D, taps = cfg.num_heads, cfg.kda_head_dim, cfg.kda_conv
         inner = H * D
@@ -1142,32 +1143,22 @@ class KimiDeltaAttention(nn.Module):
         def heads(t):
             return t.reshape(B, L, H, D)
 
-        def l2(t):
-            return t * lax.rsqrt(jnp.sum(jnp.square(t), axis=-1,
-                                         keepdims=True) + 1e-6)
-
         with jax.named_scope(profile.KDA_PROJ):
             proj = dense(3 * inner + 2 * D + H, "in_proj")(x)
-        qkv = proj[..., :3 * inner]
         f = proj[..., 3 * inner:3 * inner + D]
         z = proj[..., 3 * inner + D:3 * inner + 2 * D]
         b = proj[..., 3 * inner + 2 * D:]
         with jax.named_scope(profile.KDA_CONV):
-            w = self.param("conv_kernel", around_zero, (taps, 3 * inner), f32)
-            # Tap j reads the token taps - 1 - j behind, zeros before the
-            # sequence; widened a tap at a time, as `Mamba2`'s.
-            padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
-            qkv = nn.silu(sum(w[j] * padded[:, j:j + L].astype(f32)
-                              for j in range(taps)))
-        q, k, v = (heads(qkv[..., i * inner:(i + 1) * inner])
-                   for i in range(3))
+            # q, k, v [B, L, H D] from the first 3 H D columns of `proj`:
+            # the convolutions, SiLU, the l2 norm a head of q and k, q's
+            # D^-1/2, the one rounding
+            q, k, v = (heads(t) for t in kda_qkv(
+                proj, self.param("conv_kernel", around_zero,
+                                 (taps, 3 * inner), f32), H, D))
         with jax.named_scope(profile.KDA_PROJ):
             f = dense(inner, "f_up")(f)
             z = dense(inner, "g_up")(z)
         with jax.named_scope(profile.KDA_GATE):
-            q = (l2(q) * D ** -0.5).astype(cfg.dtype)
-            k = l2(k).astype(cfg.dtype)
-            v = v.astype(cfg.dtype)
             g = -jnp.exp(self.param("A_log", a_log_init, (H,), f32))[
                 :, None] * jax.nn.softplus(heads(
                     f.astype(f32) + self.param("dt_bias", dt_bias_init,

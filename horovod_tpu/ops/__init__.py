@@ -22,6 +22,11 @@ played by Pallas TPU kernels:
 * :mod:`.moe_act` — the experts' activation between the grouped matmuls
   of such a layer (``act(g) * h``, or ``act(h)``): the live tiles of its
   buffers alone, zeros to the end of the last one, custom VJP.
+* :mod:`.kda_conv` — the short convolutions of a Kimi Delta Attention
+  mixer with their SiLU and the head norms of q and k: the q | k | v
+  columns of the in-projection read once, q, k, v written once as the
+  recurrence's kernels read them, custom VJP (the pre-activation formed
+  again, the taps' gradient resident).
 
 Beside them, in jnp (XLA's fusions own it until a trace says otherwise):
 

@@ -108,8 +108,10 @@ SSM_SCOPES = (SSM, SSM_CONV, SSD, SSM_PROJ, SSM_GATE)
 # too), and opened in the mixer itself `KDA_PROJ` around its projections (the
 # in-projection of q, k, v, the two low ranks' down sides and beta; the two
 # up sides; the output projection), `KDA_CONV` around the causal depthwise
-# convolutions, `KDA_GATE` around the elementwise part in f32 (the l2 norms,
-# the decay's softplus, beta's sigmoid, the gated head norm), and in
+# convolutions with their SiLU and, since PR 64, the l2 norms of q and k
+# (`ops/kda_conv.py`: the kernels `KDA_QKV` / `KDA_QKV_BWD` below, or jnp),
+# `KDA_GATE` around the rest of the elementwise part in f32 (the decay's
+# softplus, beta's sigmoid, the gated head norm), and in
 # `ops/kda.py` `KDA_CHUNK` around what is made for all chunks at once (the
 # cumulative decays, the decayed scores, the solve, W and U: the kernels
 # `KDA_WY` / `KDA_WY_BWD` below, or jnp around `KDA_SCORES` /
@@ -240,10 +242,22 @@ KDA_KERNELS = (KDA_SCORES, KDA_SCORES_BWD, KDA_WY, KDA_WY_BWD)
 KDA_SCAN = "hvd_kda_scan"          # (o, the final state, the largest |S|)
 KDA_SCAN_BWD = "hvd_kda_scan_bwd"  # (dW|Qe^G, dU, dqk, dK_out, dkeep)
 KDA_SCAN_KERNELS = (KDA_SCAN, KDA_SCAN_BWD)
+# What a KDA mixer does to the q | k | v columns of its in-projection before
+# the recurrence (`ops/kda_conv.py`, PR 64), under `KDA_CONV` and so in
+# NEITHER `KDA_KERNELS` nor `KDA_SCAN_KERNELS`: the causal depthwise
+# convolutions, SiLU, the l2 norm a head of q and k and q's scale, one call
+# a direction wherever `kda_conv_plan` takes the call. `KDA_QKV` reads the
+# columns once and writes q, k, v as the recurrence's kernels read them;
+# `KDA_QKV_BWD` forms the pre-activation again and walks the blocks of rows
+# in reverse.
+KDA_QKV = "hvd_kda_qkv"          # (q, k, v) [B, L, H D]
+KDA_QKV_BWD = "hvd_kda_qkv_bwd"  # (the three parts' cotangents, the taps')
+KDA_CONV_KERNELS = (KDA_QKV, KDA_QKV_BWD)
 KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD, RING_ATTN,
            RING_ATTN_DQ, RING_ATTN_DKV) + MOE_GMM_KERNELS \
     + (HC_STAT, HC_STAT_DPHI) \
-    + MOE_ROWS_KERNELS + MOE_ACT_KERNELS + KDA_KERNELS + KDA_SCAN_KERNELS
+    + MOE_ROWS_KERNELS + MOE_ACT_KERNELS + KDA_KERNELS + KDA_SCAN_KERNELS \
+    + KDA_CONV_KERNELS
 
 # Host spans a traced window shows: the program's only per-call Python
 # (`span`), and `step.place`, which is a `phase` (below) and so shows there
@@ -974,5 +988,23 @@ def moe_act_plan(*args, **kwargs):
     buffer's (`parallel.routing_stats`' `held_share`). The op runs what
     this returns."""
     from horovod_tpu.ops.moe_act import act_plan as plan
+
+    return plan(*args, **kwargs)
+
+
+# --- how a KDA mixer's short convolutions run -------------------------------
+
+def kda_conv_plan(*args, **kwargs):
+    """How `ops.kda_conv.kda_qkv` runs a call, the short convolutions of a
+    Kimi Delta Attention mixer with their SiLU and the head norms of q and
+    k: `ops.kda_conv.conv_plan(B, L, H, D, taps, dtype)` (its arguments and
+    result). The path (`kernel`: `KDA_QKV` and `KDA_QKV_BWD`, each one read
+    of its operands, where a head is a whole number of lane tiles, a block
+    of rows divides the sequence and a TPU runs it; `jnp`: XLA's fusions of
+    the plain expression), the rows and the lane tiles of a grid step's
+    block, the rows a kernel's loop holds in registers, the grid steps a
+    call issues and the bytes a call moves in each direction. The op runs
+    what this returns."""
+    from horovod_tpu.ops.kda_conv import conv_plan as plan
 
     return plan(*args, **kwargs)

@@ -688,6 +688,58 @@ def test_chunked_kda_compiles_for_v5e(one_chip, monkeypatch):
     assert passes(profile.KDA_CARRY) == []
 
 
+# The same layer's short convolutions (PR 64): `proj` [1, 8192, 12576] as
+# the in-projection's matmul leaves it, of which `hvd_kda_qkv` and
+# `hvd_kda_qkv_bwd` read the first 12288 columns through their BlockSpecs (a
+# grid step [1024, 512] of each of q, k, v with the 16 rows before it; loops
+# over a head's 64 rows with sublane rotations for the taps, a lane reduction
+# for a head's norm; the backward's blocks of rows in reverse, the taps'
+# gradient resident), once each; q, k, v come out [1, 8192, 4096] bf16 as the
+# chunk stage's kernels read them, and no f32 array of an activation's size
+# lies anywhere under the scope.
+def test_kda_qkv_compiles_for_v5e(one_chip, monkeypatch):
+    import re
+
+    from horovod_tpu.ops import kda_conv
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    L, H, D, C, taps = 8192, 32, 128, 2304, 4
+    W = 3 * H * D + 2 * D + H
+    plan = profile.kda_conv_plan(1, L, H, D, taps, jnp.bfloat16)
+    assert (plan["path"], plan["block_rows"], plan["lane_tiles"]) == (
+        "kernel", kda_conv.BLOCK_ROWS, kda_conv.BLOCK_LANES // 128)
+
+    def fwd_bwd(x, w_in, w, *cot):
+        def mixer(x, w_in, w):
+            with jax.named_scope(profile.KDA_PROJ):
+                proj = jnp.einsum("blc,cw->blw", x, w_in)
+            with jax.named_scope(profile.KDA_CONV):
+                return kda_conv.kda_qkv(proj, w, H, D)
+
+        out, vjp = jax.vjp(mixer, x, w_in, w)
+        return out, vjp(cot)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    text = _compile(one_chip, fwd_bwd, ((1, L, C), bf16), ((C, W), bf16),
+                    ((taps, 3 * H * D), f32), *[((1, L, H * D), bf16)] * 3)
+    calls = [line for line in text.splitlines() if " custom-call(" in line]
+    for name in profile.KDA_CONV_KERNELS:
+        assert len([line for line in calls if re.search(
+            r"\b%s/pallas_call" % name, line)]) == 1
+    assert _kernels(text) == 2
+    under = [line for line in text.splitlines() if profile.KDA_CONV in line]
+    assert under
+    # no pass of XLA's writes an f32 array of an activation's size under
+    # the scope (the parent's backward: four padded f32[1, 8192 + 3, 12288]
+    # and more)
+    for line in under:
+        m = re.search(r"= \(?f32\[([0-9,]+)\]\S* (fusion|copy|transpose|"
+                      r"reshape)\(", line)
+        assert not m or functools.reduce(
+            lambda a, b: a * int(b), m.group(1).split(","),
+            1) < L * H * D, line[:200]
+
+
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------
 
 def _lm_step(topo, chips, monkeypatch, **more):

@@ -777,6 +777,29 @@ def test_the_program_names_the_mixers_parts():
         assert "%s/%s" % (profile.ATTN_ROPE, turn) not in text
 
 
+@pytest.mark.parametrize("kernel", profile.KDA_CONV_KERNELS)
+def test_the_program_names_the_convolutions_kernels(kernel, monkeypatch):
+    """A mixer whose heads are whole lane tiles runs `ops.kda_conv.kda_qkv`'s
+    two kernels (here in the interpreter), called under `hvd_kda_conv`
+    through one jitted function, so that a model's layers share a lowering
+    of each; they are in `profile.KERNELS` and in neither tuple of the
+    recurrence's."""
+    from horovod_tpu.ops import kda_conv
+    monkeypatch.setattr(kda_conv, "kda_qkv", functools.partial(
+        kda_conv.kda_qkv, interpret=True))
+    cfg = _cfg(kda_head_dim=128)
+    module = transformer.KimiDeltaAttention(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, LENGTH, HIDDEN))
+    p = module.init(jax.random.PRNGKey(1), x)["params"]
+    text = jax.jit(jax.grad(lambda p: jnp.sum(module.apply(
+        {"params": p}, x, mutable=["intermediates"])[0]))).lower(
+            p).as_text(debug_info=True)
+    assert "%s/jit(_pallas_qkv)" % profile.KDA_CONV in text
+    assert "%s/pallas_call" % kernel in text
+    assert kernel in profile.KERNELS and kernel not in ALL_KERNELS
+    assert len(profile.KERNELS) == len(set(profile.KERNELS)) == 24
+
+
 # --------------------------------------------------------------------------
 # (e) What is not built is refused by name
 # --------------------------------------------------------------------------

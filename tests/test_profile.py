@@ -1031,6 +1031,25 @@ def _name_choices(value):
     return [ast.unparse(value)]
 
 
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_the_kda_convolutions_kernels_carry_their_names(direction):
+    """`ops.kda_conv.kda_qkv`'s two kernels, a tuple of their own in
+    `KERNELS` (PR 64): the forward's program holds `hvd_kda_qkv` alone, the
+    gradient's `hvd_kda_qkv_bwd` too."""
+    from horovod_tpu.ops.kda_conv import kda_qkv
+    proj = jnp.ones((1, 32, 3 * 128 + 8), jnp.float32)
+    w = jnp.ones((4, 3 * 128), jnp.float32)
+
+    def forward(proj, w):
+        return sum(jnp.sum(t) for t in kda_qkv(proj, w, 1, 128,
+                                               interpret=True))
+
+    fn = forward if direction == "forward" else jax.grad(forward, (0, 1))
+    assert _kernel_names(str(jax.make_jaxpr(fn)(proj, w))) == set(
+        profile.KDA_CONV_KERNELS[:1 if direction == "forward" else 2])
+    assert profile.KERNELS[-2:] == profile.KDA_CONV_KERNELS
+
+
 def _pallas_call_names(path):
     """The `name=` keyword of every `pl.pallas_call(...)` in a source file
     (each arm of a conditional one), None where one has no name."""
