@@ -28,6 +28,13 @@ from horovod_tpu import profile
 from horovod_tpu.parallel.ring import ring_attention, ulysses_attention
 
 
+# What a layer of `TransformerConfig.attention_types` may be: plain attention
+# under the causal triangle ("full") or the causal band ("window"), or a mixer
+# of its own in attention's place ("kda": `KimiDeltaAttention`; "conv":
+# `GatedShortConv`).
+ATTENTION_KINDS = ("full", "window", "kda", "conv")
+
+
 class Yarn(NamedTuple):
     """YaRN's static rescaling of the rotary frequencies (Peng et al.,
     arXiv:2309.00071, as DeepSeek-V2's `rope_scaling` states it): the
@@ -68,8 +75,8 @@ class Layer(NamedTuple):
     configuration's; a new kind of layer is a new value here and an entry
     in `_mixer`."""
     # The mixers: "attn" (`Attention`) | "latent" (`LatentAttention`) |
-    # "kda" (`KimiDeltaAttention`) | "ssm" (`Mamba2`) | "mlp" (the dense
-    # feed-forward) | "moe" (the routed one).
+    # "kda" (`KimiDeltaAttention`) | "conv" (`GatedShortConv`) | "ssm"
+    # (`Mamba2`) | "mlp" (the dense feed-forward) | "moe" (the routed one).
     branches: Tuple[str, ...] = ("attn", "mlp")
     # The parameter name of each branch's norm, and of the sandwich norm
     # on its output (None: no such norm).
@@ -159,7 +166,8 @@ class TransformerConfig:
     # caller's and may repeat. attention="dense" or "flash".
     attention_mask: Optional[Any] = None
     # An attention kind a layer of the two-branch block, `num_layers` of
-    # "full" | "window" (Gemma's and Qwen's `layer_types` of
+    # `ATTENTION_KINDS`: "kda" and "conv" are mixers of their own (below),
+    # "full" | "window" plain attention (Gemma's and Qwen's `layer_types` of
     # `full_attention` / `sliding_attention`): a "window" layer's query sees
     # itself and the `attention_window` - 1 keys before it
     # (`ops.BandMask`), a "full" layer's every key before it. Both rotate
@@ -194,6 +202,17 @@ class TransformerConfig:
     kda_head_dim: int = 128
     kda_conv: int = 4
     kda_chunk: int = 64
+    # A "conv" layer of `attention_types` is a double-gated short
+    # convolution (`GatedShortConv`; LFM2, arXiv:2511.23404) in attention's
+    # place: `embed_dim` channels, a causal depthwise convolution of
+    # `conv_taps` taps (LFM2's `conv_L_cache`) between two gates, no
+    # activation, no bias. It reads no position.
+    conv_taps: int = 3
+    # The head is the embedding: no `lm_head`; the logits are the normed
+    # state times the table transposed, and a loss takes
+    # `params["embed"]["embedding"].T` as its kernel, so the table's gradient
+    # is the lookup's plus the head's.
+    tie_embeddings: bool = False
     norm_eps: float = 1e-6        # every RMSNorm's epsilon
     # Passes over the ONE stack of blocks, on the same weights (a looped
     # or universal transformer; Ouro's `total_ut_steps`): `norm_f` closes
@@ -365,7 +384,8 @@ class TransformerConfig:
             ("rotary=False", not self.rotary),
             ("moe_latent_dim", self.moe_latent_dim is not None),
             ("moe_act", self.moe_act != "silu"),
-            ("moe_shared_gated=False", not self.moe_shared_gated)) if on]
+            ("moe_shared_gated=False", not self.moe_shared_gated),
+            ("tie_embeddings", self.tie_embeddings)) if on]
         for field in ("tp_axis", "sp_axis", "num_passes"):
             on = (self.num_passes > 1 if field == "num_passes"
                   else getattr(self, field) is not None)
@@ -420,6 +440,9 @@ class TransformerConfig:
         if self.hc_mult < 1:
             raise ValueError("hc_mult=%d: the residual path has at least "
                              "one stream" % self.hc_mult)
+        if self.conv_taps < 1:
+            raise ValueError("conv_taps=%d: a convolution has at least the "
+                             "tap on the current token" % self.conv_taps)
         if self.mtp_depth not in (0, 1):
             raise ValueError("mtp_depth=%d: one multi-token prediction "
                              "module is built, not a chain of them"
@@ -439,7 +462,7 @@ class TransformerConfig:
     def _check_attention_types(self):
         """What an attention kind a layer cannot be placed beside, by
         name."""
-        kinds = ("full", "window", "kda")
+        kinds = ATTENTION_KINDS
         types = self.attention_types
         if len(types) != self.num_layers or any(t not in kinds
                                                 for t in types):
@@ -467,10 +490,10 @@ class TransformerConfig:
                 ("mtp_depth", self.mtp_depth > 0)):
             if on:
                 raise ValueError("attention_types cannot be combined with "
-                                 "%s (window and full layers are built for "
-                                 "the two-branch block's plain attention "
+                                 "%s (%s layers are built for the "
+                                 "two-branch block, its plain attention "
                                  "under attention='dense' or 'flash')"
-                                 % field)
+                                 % (field, ", ".join(kinds)))
 
     def _check_attention_shapes(self):
         """What a shape by kind must be, and cannot be placed beside."""
@@ -546,10 +569,11 @@ class TransformerConfig:
         """What each block of the model is, a `Layer` a block: `num_layers`
         of them, `block_<i>`'s at i, and behind them the prediction
         module's `mtp_block` where `mtp_depth` is set. The one reader of
-        the fields that spell a layer (`layer_types`; `attention_types`
-        and `attention_shapes`; `moe_every` and `first_k_dense`;
-        `kv_lora_rank` as a choice of attention; `sandwich_norm` as names;
-        `block_remat`)."""
+        the fields that spell a layer (`layer_types`; `attention_types`,
+        whose "kda" and "conv" layers are mixers of their own in
+        attention's place, and `attention_shapes`; `moe_every` and
+        `first_k_dense`; `kv_lora_rank` as a choice of attention;
+        `sandwich_norm` as names; `block_remat`)."""
         attention = "latent" if self.kv_lora_rank is not None else "attn"
         shapes = dict(self.attention_shapes or ())
 
@@ -572,7 +596,7 @@ class TransformerConfig:
             routed = (self.moe_experts is not None
                       and i >= self.first_k_dense
                       and i % self.moe_every == self.moe_every - 1)
-            mixer, kind = ("kda", None) if kind == "kda" \
+            mixer, kind = (kind, None) if kind in ("kda", "conv") \
                 else (attention, kind)
             table.append(two_branch(mixer, routed, kind, remat))
         if self.mtp_depth:  # routed wherever the model is, never recomputed
@@ -1188,6 +1212,42 @@ def kda_stats(intermediates):
     return jnp.max(jnp.stack(found))
 
 
+class GatedShortConv(nn.Module):
+    """A double-gated short convolution mixer (LFM2, arXiv:2511.23404;
+    `transformers`' `lfm2` / `lfm2_moe` modelling code states the same
+    lines) on the normed state x [B, L, C]:
+
+        [B | G | z] = in_proj x            three equal column blocks of C
+        c = conv_causal_depthwise(B * z)   `conv_taps` taps a channel, tap
+                                           taps - 1 on the current token,
+                                           zeros before the sequence; no
+                                           activation, no bias
+        out = out_proj(G * c)
+
+    The pass between the projections is `ops.sconv.gated_conv`, f32 from
+    the widened projection to its one rounding. No projection and no
+    convolution has a bias; the taps start as torch's Conv1d draws them."""
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from horovod_tpu.ops.sconv import gated_conv
+        cfg = self.cfg
+        C, taps = cfg.embed_dim, cfg.conv_taps
+
+        def dense(n, name):
+            return nn.Dense(n, dtype=cfg.dtype, param_dtype=jnp.float32,
+                            use_bias=False, name=name)
+
+        with jax.named_scope(profile.SCONV_PROJ):
+            proj = dense(3 * C, "in_proj")(x)
+        with jax.named_scope(profile.SCONV_GATE):
+            y = gated_conv(proj, self.param(
+                "conv_kernel", _conv_init(taps), (taps, C), jnp.float32))
+        with jax.named_scope(profile.SCONV_PROJ):
+            return dense(C, "out_proj")(y)
+
+
 class Block(nn.Module):
     """One block of the stack on x [B, L, C] (the streams [n, B, L, C]
     under `cfg.hc_mult` = n > 1): for each branch of `layer`,
@@ -1254,12 +1314,14 @@ def _mixer(cfg, layer, mixer, positions):
                 profile.ATTN_KINDS[layer.kind] if layer.kind else None, None)
     if mixer == "kda":
         return KimiDeltaAttention(cfg, name="attn"), profile.KDA, None
+    if mixer == "conv":
+        return GatedShortConv(cfg, name="attn"), profile.SCONV, None
     if mixer == "ssm":
         return Mamba2(cfg, name="ssm"), None, profile.SSM
     if mixer in ("mlp", "moe"):
         return lambda h: _feed_forward(cfg, mixer == "moe", h), None, None
-    raise ValueError("Layer.branches names %r: attn, latent, kda, ssm, mlp "
-                     "or moe" % (mixer,))
+    raise ValueError("Layer.branches names %r: attn, latent, kda, ssm, "
+                     "conv, mlp or moe" % (mixer,))
 
 
 def _feed_forward(cfg, moe, h):
@@ -1304,10 +1366,11 @@ class Transformer(nn.Module):
     ``return_hidden=True`` skips the lm_head projection and returns the
     final normed hidden states — pair with
     `horovod_tpu.ops.losses.chunked_softmax_cross_entropy` (and the
-    lm_head kernel from the params tree) to train without ever
-    materializing the [B, L, vocab] logits: that loss projects a chunk
-    of rows at a time and forms both gradients in the same pass, so
-    its peak is O(rows x vocab) plus the [D, vocab] f32 and [B, L, D]
+    lm_head kernel from the params tree; under ``cfg.tie_embeddings`` the
+    embedding table transposed, there being no `lm_head`) to train
+    without ever materializing the [B, L, vocab] logits: that loss projects
+    a chunk of rows at a time and forms both gradients in the same pass,
+    so its peak is O(rows x vocab) plus the [D, vocab] f32 and [B, L, D]
     gradients it hands to the backward.
 
     With ``cfg.num_passes`` > 1 the blocks run that many times on the same
@@ -1336,10 +1399,11 @@ class Transformer(nn.Module):
                 tokens.shape)
         # The scopes are the profiler's names for the model's parts
         # (hvd.profile); flax's module names (`block_3/attn`) sit inside.
+        embed = nn.Embed(cfg.vocab_size, cfg.embed_dim,
+                         param_dtype=jnp.float32, dtype=cfg.dtype,
+                         name="embed")
         with jax.named_scope(profile.EMBED):
-            embedded = nn.Embed(cfg.vocab_size, cfg.embed_dim,
-                                param_dtype=jnp.float32, dtype=cfg.dtype,
-                                name="embed")(tokens)
+            embedded = embed(tokens)
         table = cfg.layers()
         blocks = [
             (nn.remat(Block, policy=_keep_hc_stat()) if layer.remat
@@ -1404,7 +1468,10 @@ class Transformer(nn.Module):
         if return_hidden:
             return hidden
         with jax.named_scope(profile.HEAD):
-            logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
-                              param_dtype=jnp.float32, use_bias=False,
-                              name="lm_head")(x)
+            if cfg.tie_embeddings:  # the table transposed: no `lm_head`
+                logits = embed.attend(x)
+            else:
+                logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
+                                  param_dtype=jnp.float32, use_bias=False,
+                                  name="lm_head")(x)
             return logits.astype(jnp.float32)

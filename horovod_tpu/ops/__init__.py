@@ -37,6 +37,9 @@ Beside them, in jnp (XLA's fusions own it until a trace says otherwise):
   in its chunked form: the decayed scores by sub-blocks, one
   unit-lower-triangular solve a chunk (the WY form), a scan over the chunks
   whose body multiplies WITH the carried state.
+* :mod:`.sconv` — the pass between the two projections of a double-gated
+  short convolution mixer (LFM2): u = B * z, a few causal taps a channel as
+  shifted sums, G * c, f32 to one rounding.
 """
 
 from .flash_attention import (  # noqa: F401

@@ -126,6 +126,23 @@ KDA_CHUNK = "hvd_kda_chunk"
 KDA_CARRY = "hvd_kda_carry"
 KDA_SCOPES = (KDA, KDA_PROJ, KDA_CONV, KDA_GATE, KDA_CHUNK, KDA_CARRY)
 
+# A double-gated short convolution mixer (`models/transformer.py::
+# GatedShortConv`, a layer of kind "conv" in `attention_types`; LFM2), inside
+# `BLOCK` and around the attention half of the two-branch block as `KDA` is
+# around a "kda" layer's: `SCONV` around all of it (the norm before it and
+# the residual add too), and opened in the mixer itself `SCONV_PROJ` around
+# its two projections (`in_proj` to the three column blocks B | G | z,
+# `out_proj`) and `SCONV_GATE` around the pass between them
+# (`ops/sconv.py::gated_conv`: u = B * z, the causal taps, G * c, in f32 to
+# one rounding; both directions). What is left under `SCONV` alone is the
+# norm and the add. A tuple of its own: in NEITHER `KDA_SCOPES`,
+# `SSM_SCOPES`, `ATTN_PARTS` nor `ATTN_KINDS`, which readers of older cells
+# walk. Not in MODEL_SCOPES: the half still reads `hvd_block/attn`.
+SCONV = "hvd_sconv"
+SCONV_PROJ = "hvd_sconv_proj"
+SCONV_GATE = "hvd_sconv_gate"
+SCONV_SCOPES = (SCONV, SCONV_PROJ, SCONV_GATE)
+
 # The hyper-connection around each of a block's two branches
 # (`models/transformer.py`, `hc_mult` > 1), inside `BLOCK` and beside the
 # branches' own `attn` / `mlp`: `HC` around all of it, `HC_MAP` (the norm
@@ -1006,5 +1023,17 @@ def kda_conv_plan(*args, **kwargs):
     call issues and the bytes a call moves in each direction. The op runs
     what this returns."""
     from horovod_tpu.ops.kda_conv import conv_plan as plan
+
+    return plan(*args, **kwargs)
+
+
+def sconv_plan(*args, **kwargs):
+    """How `ops.sconv.gated_conv` runs a call, the pass between the two
+    projections of a double-gated short convolution mixer:
+    `ops.sconv.gate_plan(B, L, C, taps, dtype)` (its arguments and result).
+    The path (`jnp`: XLA's fusions of the plain expression; no kernel yet)
+    and the bytes a ONE-PASS form moves in each direction, which
+    `sconv_gate_roofline` is counted on whatever runs the call."""
+    from horovod_tpu.ops.sconv import gate_plan as plan
 
     return plan(*args, **kwargs)

@@ -256,6 +256,39 @@ def test_the_laguna_cells_flash_calls_compile_for_v5e(one_chip, H, rule):
     assert "[%d,%d]" % (S, S) not in text
 
 
+# The LFM2 cell's call (`lfm2moe8b_1chip`, PR 65): 2 x 32 query heads on 8 kv
+# heads at head width 64, 8192 positions, causal: a grouped call at D = 64,
+# "a slab a head" (every other cell's grouped call is D = 128 and goes by
+# position), swept until then at L = 1024 and 2048 only. Both directions
+# Pallas kernels, resident, the backward ONE kernel held by the q block at
+# exactly the 24 MiB limit of whole-sequence operands.
+def test_the_lfm2_cells_flash_call_compiles_for_v5e(one_chip):
+    B, H, G, S, D = 2, 32, 8, 8192, 64
+
+    def fwd_bwd(q, k, v, g):
+        out, vjp = jax.vjp(
+            lambda q, k, v: _flash(q, k, v, D ** -0.5, True, False), q, k, v)
+        return (out,) + vjp(g)
+
+    bf16 = jnp.bfloat16
+    text = _compile(one_chip, fwd_bwd, ((B, H, S, D), bf16),
+                    ((B, G, S, D), bf16), ((B, G, S, D), bf16),
+                    ((B, H, S, D), bf16))
+    plans = {name: p for backward in (False, True)
+             for name, p in flash_plan(B, H, S, D, H // G, bf16,
+                                       backward).items()}
+    assert {name: (p.path, p.held, p.block_q, p.block_k)
+            for name, p in plans.items()} == {
+        profile.FLASH_FWD: ("resident", "q", 2048, 512),
+        profile.FLASH_BWD: ("resident", "q", 2048, 512)}
+    assert plans[profile.FLASH_BWD].resident_bytes == 24 << 20
+    assert _kernels(text) == 2, text[:2000]
+    for name in (profile.FLASH_FWD, profile.FLASH_DQ, profile.FLASH_DKV,
+                 profile.FLASH_BWD):
+        assert _named(text, name) == (name in plans), name
+    assert "[%d,%d]" % (S, S) not in text
+
+
 # The same two calls with the cell's head gate (PR 63: `flash_attention`'s
 # ``gate``, [1, H, 8192] f32 as `_flash_gated` takes it): the forward takes
 # the gates' reciprocals as one more q-side operand (f32[1, 8, 8192, group]:
