@@ -1248,6 +1248,39 @@ class GatedShortConv(nn.Module):
             return dense(C, "out_proj")(y)
 
 
+@jax.custom_vjp
+def _hold_cotangent(h):
+    """`h`, and in the backward its cotangent held as a value of the
+    program (`lax.optimization_barrier`, under `profile.SCONV_HOLD`).
+
+    Between a "conv" layer's norm and its mixer. The cotangent is the
+    in-projection's data gradient [B, L, 3 C] x [3 C, C], and the norm's
+    backward reads it three ways: for `dx`, for a sum along each of its rows
+    and for the scale's gradient, a sum over all of them. Left to itself
+    libtpu puts both reductions INTO the product's fusion
+    (`multiply_reduce_fusion`, three results); held, the product is a plain
+    fusion of one result and the norm's backward a pass of its own over `dh`
+    and `x`. On the v5e at [16384, 6144] x [6144, 2048] the joined fusion
+    took 2.44 ms in five layers and 2.99 in three, the plain product 2.41
+    and the pass 0.09-0.18, and the layers' weight gradients and recomputed
+    in-projections lost 3 ms more beside it: 4.1 of 522.8 ms a step
+    (`lfm2moe8b_1chip`, PR 66; `PERF.md` s6). Timed alone the two forms
+    are equal at every cell's shape (`examples/norm_grad_sweep.py`), so no
+    other mixer holds its cotangent. The forward is `h` itself, the
+    gradient the same arithmetic, and no memory is held: `dh` was a result
+    of the joined form too. ONE function for every layer, for
+    `_keep_hc_stat`'s reason."""
+    return h
+
+
+def _hold_cotangent_bwd(_, g):
+    with jax.named_scope(profile.SCONV_HOLD):
+        return (lax.optimization_barrier(g),)
+
+
+_hold_cotangent.defvjp(lambda h: (h, None), _hold_cotangent_bwd)
+
+
 class Block(nn.Module):
     """One block of the stack on x [B, L, C] (the streams [n, B, L, C]
     under `cfg.hc_mult` = n > 1): for each branch of `layer`,
@@ -1276,6 +1309,8 @@ class Block(nn.Module):
                 else:
                     h = x
                 h = _rms_norm(cfg, norm)(h)
+                if mixer == "conv":
+                    h = _hold_cotangent(h)
                 with _scope(inside):
                     y = f(h)
                     if out_norm is not None:

@@ -142,6 +142,13 @@ SCONV = "hvd_sconv"
 SCONV_PROJ = "hvd_sconv_proj"
 SCONV_GATE = "hvd_sconv_gate"
 SCONV_SCOPES = (SCONV, SCONV_PROJ, SCONV_GATE)
+# Under `SCONV`, in the backward alone: the in-projection's data gradient
+# held as a value of the program between the product and the norm's backward
+# (`models/transformer.py::_hold_cotangent`, an `optimization_barrier`: no
+# instruction of its own in a trace, so no time). Not in `SCONV_SCOPES`, which
+# the benchmark's readers walk: the norm's backward, a pass of its own behind
+# it, counts under `SCONV` alone as the norm's forward does.
+SCONV_HOLD = "hvd_sconv_hold"
 
 # The hyper-connection around each of a block's two branches
 # (`models/transformer.py`, `hc_mult` > 1), inside `BLOCK` and beside the
@@ -671,6 +678,31 @@ _HLO_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
 _SCOPE_SPLIT = re.compile(r"[/()]")
 
 
+def _instructions(text):
+    """(computation, name, opcode, result type, `op_name` or "", the
+    computation a fusion calls or None) of every instruction of a compiled
+    program's text."""
+    comp = None
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = _HLO_LINE.match(line)
+        if not m or comp is None:
+            continue
+        name, rest = m.groups()
+        end = _type_end(rest)
+        op = _HLO_OPCODE.match(rest, end)
+        if not op:
+            continue
+        found = _HLO_OP_NAME.search(rest, end)
+        called = _HLO_CALLS.search(rest, end) \
+            if op.group(1) == "fusion" else None
+        yield (comp, name, op.group(1), rest[:end],
+               found.group(1) if found else "", called and called.group(1))
+
+
 def fused_scopes(text, scopes):
     """Which fusions of a compiled step (`compiled.as_text()`) hold work
     under `scopes` (names of this module, e.g. `ATTN_PARTS`), and how pure
@@ -699,28 +731,13 @@ def fused_scopes(text, scopes):
     # computation -> [(scope or None, the computation it calls if a fusion)]
     held = {}
     fusions = []  # (fusion, the computation it is in, the one it calls, scope)
-    comp = None
-    for line in text.splitlines():
-        m = _COMPUTATION.match(line)
-        if m:
-            comp = m.group(1)
-            held[comp] = []
+    for comp, name, op, _, op_name, called in _instructions(text):
+        if op in ("parameter", "constant"):
             continue
-        m = _HLO_LINE.match(line)
-        if not m or comp is None:
-            continue
-        name, rest = m.groups()
-        end = _type_end(rest)
-        op = _HLO_OPCODE.match(rest, end)
-        if not op or op.group(1) in ("parameter", "constant"):
-            continue
-        found = _HLO_OP_NAME.search(rest, end)
-        scope = scope_of(found.group(1)) if found else None
-        called = _HLO_CALLS.search(rest, end) \
-            if op.group(1) == "fusion" else None
-        held[comp].append((scope, called and called.group(1)))
+        scope = scope_of(op_name)
+        held.setdefault(comp, []).append((scope, called))
         if called:
-            fusions.append((name, comp, called.group(1), scope))
+            fusions.append((name, comp, called, scope))
 
     counted = {}  # computation -> {scope or None: instructions}, all depths
 
@@ -744,6 +761,59 @@ def fused_scopes(text, scopes):
             continue
         out[name] = {"scope": scope, "inner": dict(inner),
                      "mixed": len(named) > 1}
+    return out
+
+
+def product_fusions(text):
+    """The fusions of a compiled program (`compiled.as_text()`) that hold a
+    matrix product (`convolution`, as libtpu writes one; `dot` elsewhere),
+    and what each computes beside it:
+
+        {fusion: {"op_name": its own, "results": [array type, ...],
+                  "reduces": [array type, ...]}}
+
+    `results` are the arrays of the fusion's result type (`"bf16[2,8192,
+    2048]"`, no layout), `reduces` the results of the `reduce` instructions
+    in its computation at all depths (a producer fusion inside counted by
+    its contents, as `fused_scopes` counts). A data gradient whose fusion
+    also sums ALONG its output rows and ACROSS them (an RMS norm's backward
+    behind it: results dh, f32[rows], f32[columns]) is the form a "conv"
+    layer's `_hold_cotangent` (`models/transformer.py`) takes apart; the
+    fusions with "reduces" name it wherever else it stands (every norm in
+    front of a projection: `examples/norm_grad_sweep.py` times it). Reads
+    text and needs no chip."""
+    held = {}  # computation -> its `_instructions`
+    for inst in _instructions(text):
+        held.setdefault(inst[0], []).append(inst)
+
+    def arrays(result_type):
+        return ["%s[%s]" % a for a in _HLO_ARRAY.findall(result_type)]
+
+    def inside(comp):
+        """(whether a product is in `comp`, its reduces' results)."""
+        product, reduces = False, []
+        for _, _, op, result_type, _, called in held.get(comp, ()):
+            if called:
+                p, r = inside(called)
+                product, reduces = product or p, reduces + r
+            elif op in ("convolution", "dot"):
+                product = True
+            elif op == "reduce":
+                reduces += arrays(result_type)
+        return product, reduces
+
+    fused = {i[5] for insts in held.values() for i in insts if i[5]}
+    out = {}
+    for comp, insts in held.items():
+        if comp in fused:
+            continue  # a fusion inside a fusion: counted with its caller
+        for _, name, _, result_type, op_name, called in insts:
+            if called:
+                product, reduces = inside(called)
+                if product:
+                    out[name] = {"op_name": op_name,
+                                 "results": arrays(result_type),
+                                 "reduces": reduces}
     return out
 
 

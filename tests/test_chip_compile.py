@@ -773,6 +773,72 @@ def test_kda_qkv_compiles_for_v5e(one_chip, monkeypatch):
             1) < L * H * D, line[:200]
 
 
+# --- a conv layer's in-projection data gradient (PR 66) ---------------------
+
+# The LFM2 cell's conv branch alone, x + GatedShortConv(rms_norm(x)) at
+# [2, 8192, 2048] bf16, forward and backward. With the identity where the
+# layer holds the cotangent (`bare`: the parent's program) libtpu joins the
+# data gradient [16384, 6144] x [6144, 2048] with the norm's backward, one
+# fusion of three results: the product, a sum along each of its rows and the
+# scale's gradient, a sum across all of them. Held, the product is a plain
+# fusion of ONE result and the two reductions a pass of their own under the
+# mixer's scope alone (worth 4 ms of the cell's 523 a step: `PERF.md` s6,
+# PR 66). If `bare` ever compiles to the plain form too, `_hold_cotangent`
+# can go.
+@pytest.mark.parametrize("form", ["held", "bare"])
+def test_a_conv_layers_data_gradient_is_a_plain_product_when_held(
+        one_chip, monkeypatch, form):
+    from horovod_tpu import models
+    from horovod_tpu.models import transformer
+
+    if form == "bare":
+        monkeypatch.setattr(transformer, "_hold_cotangent", lambda h: h)
+    B, L, C = 2, 8192, 2048
+    cfg = models.TransformerConfig(
+        vocab_size=128, num_layers=1, num_heads=32, embed_dim=C,
+        max_seq_len=L, conv_taps=3, attention_types=("conv",),
+        norm_eps=1e-5, dtype=jnp.bfloat16)
+    block = transformer.Block(cfg, transformer.Layer(
+        branches=("conv",), norms=("norm1",), out_norms=(None,)))
+    x = jax.ShapeDtypeStruct((B, L, C), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: block.init(
+            jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype), None)))
+
+    def fwd_bwd(params, x, cot):
+        out, vjp = jax.vjp(lambda p, x: block.apply(p, x, None), params, x)
+        return out, vjp(cot)
+
+    with jax.default_matmul_precision("default"):
+        text = jax.jit(fwd_bwd).lower(params, x, x).compile().as_text()
+    products = profile.product_fusions(text)
+    # the in-projection's data gradient: the backward product under
+    # `in_proj` whose result is an activation, not the weight's gradient
+    dgrad = [f for f in products.values()
+             if "transpose(" in f["op_name"] and "in_proj" in f["op_name"]
+             and "bf16[%d,%d,%d]" % (B, L, C) in f["results"]]
+    assert len(dgrad) == 1
+    rows, columns = "f32[%d,%d]" % (B, L), "f32[%d]" % C
+    scopes = profile.fused_scopes(text, profile.SCONV_SCOPES)
+    # the pass that sums dh * x along the rows and across them
+    norm_bwd = [name for name, f in scopes.items()
+                if f["scope"] == profile.SCONV and not f["mixed"]
+                and name.startswith("multiply_reduce_fusion")]
+    if form == "held":
+        assert dgrad[0]["results"] == ["bf16[%d,%d,%d]" % (B, L, C)]
+        assert not any(columns in f["reduces"] for f in products.values())
+        assert len(norm_bwd) == 1
+        # the new name is the barrier's, which libtpu drops once the fusions
+        # are decided: it names no instruction of a compiled program or trace
+        assert profile.SCONV_HOLD not in text
+    else:
+        assert sorted(dgrad[0]["results"]) == sorted(
+            [rows, columns, "bf16[%d,%d,%d]" % (B, L, C)])
+        assert sorted(dgrad[0]["reduces"]) == sorted([rows, columns])
+        assert not norm_bwd
+
+
 # --- the data-parallel step's gradient all-reduces (PR 25) -----------------
 
 def _lm_step(topo, chips, monkeypatch, **more):

@@ -479,3 +479,59 @@ def test_the_program_names_the_mixer_and_its_parts():
         p["lm_head"]["kernel"], jnp.roll(tokens, -1, axis=1),
         chunk=16))).lower(params).as_text(debug_info=True)
     assert profile.SCONV not in text
+
+
+# --- (h) the in-projection's data gradient, held ----------------------------
+
+HELD_KINDS = ("conv", "conv", "full")
+
+
+def _loss_and_gradients(block_remat):
+    cfg = _cfg(attention_types=HELD_KINDS, num_layers=len(HELD_KINDS),
+               block_remat=block_remat)
+    model, params, tokens = _seeded(cfg)
+    return jax.jit(jax.value_and_grad(
+        lambda p: _system_loss(model, p, tokens)))(params)
+
+
+@pytest.mark.parametrize("block_remat", [0, len(HELD_KINDS)])
+def test_a_held_cotangent_changes_no_bit(monkeypatch, block_remat):
+    """`_hold_cotangent` between a conv layer's norm and its mixer is the
+    identity forward and the same arithmetic backward: the loss and every
+    gradient leaf in f32, with it and with the identity in its place."""
+    held = _loss_and_gradients(block_remat)
+    monkeypatch.setattr(models.transformer, "_hold_cotangent", lambda h: h)
+    bare = _loss_and_gradients(block_remat)
+    flat = jax.tree_util.tree_leaves_with_path(held)
+    assert len(flat) > 20
+    for (path, a), b in zip(flat, jax.tree_util.tree_leaves(bare)):
+        # no gradient reaches the selection bias; every other leaf is live
+        assert a.dtype == jnp.float32 and (np.any(np.asarray(a) != 0) or
+                                           "select_bias" in str(path)), path
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("kinds,holds", [
+    (HELD_KINDS, 2), (("full", "full", "full"), 0), (None, 0)])
+def test_a_conv_layers_backward_alone_holds_its_cotangent(kinds, holds):
+    """One `optimization_barrier` a conv layer in the gradient's jaxpr,
+    under the name the program gives it; a stack with no such layer holds
+    none and names nothing: the program it was."""
+    cfg = _cfg(attention_types=kinds, num_layers=3,
+               tie_embeddings=kinds is not None)
+    tokens = jnp.zeros((1, LENGTH), jnp.int32)
+    model = models.Transformer(cfg)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens)["params"])
+    gradient = jax.grad(lambda p: jnp.sum(model.apply(
+        {"params": p}, tokens, return_hidden=True).astype(jnp.float32)))
+    assert str(jax.make_jaxpr(gradient)(params)).count(
+        "optimization_barrier") == holds
+    text = jax.jit(gradient).lower(params).as_text(debug_info=True)
+    assert profile.SCONV_HOLD not in profile.SCONV_SCOPES
+    for i, kind in enumerate(kinds or ()):
+        assert ("block_%d/%s/%s/optimization_barrier" % (
+            i, profile.SCONV, profile.SCONV_HOLD) in text) \
+            == (kind == "conv")
+    assert (profile.SCONV_HOLD in text) == bool(holds)

@@ -483,6 +483,24 @@ def test_fused_scopes_tells_a_clean_fusion_from_a_mixed_one():
     assert profile.fused_scopes("", profile.ATTN_PARTS) == {}
 
 
+def test_product_fusions_says_what_rides_with_a_matmul():
+    query = ("jit(shard_step)/hvd_fwd_bwd/jvp(Transformer)/hvd_block/block_0/"
+             "attn/hvd_attn_proj/query/dot_general")
+    # the fusion with the reduce holds no product and is not listed
+    assert profile.product_fusions(_FUSED_TEXT) == {
+        "fusion.1": {"op_name": query, "results": ["bf16[8,4]"],
+                     "reduces": []}}
+    # a product beside a reduction: both results, and the reduce's own (a
+    # producer fusion inside is counted by what it holds)
+    joined = profile.product_fusions(
+        _FUSED_TEXT.replace(" cosine(", " convolution("))
+    assert set(joined) == {"fusion.1", "fusion.2"}
+    assert joined["fusion.2"]["results"] == ["bf16[8,4]", "f32[8]"]
+    assert joined["fusion.2"]["reduces"] == ["f32[8]"]
+    assert joined["fusion.2"]["op_name"].endswith("hvd_attn_rope/cos")
+    assert profile.product_fusions("") == {}
+
+
 @pytest.mark.parametrize("case", ["head_norm", "ssm"])
 def test_fused_scopes_reads_a_compiled_step(case):
     fun, params = _inner_grad(case)
